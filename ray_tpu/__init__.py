@@ -306,10 +306,9 @@ def exit_actor() -> None:
 
 def get_tpu_ids() -> List[int]:
     """Chip ids leased to this worker (reference: ray.get_gpu_ids /
-    get_tpu_ids — reads the TPU_VISIBLE_CHIPS pin the node's chip
-    allocator exported at worker spawn).  Empty in the driver or on
-    unpinned workers."""
-    raw = os.environ.get("TPU_VISIBLE_CHIPS", "")
+    get_tpu_ids — the lease the node's chip allocator exported at
+    worker spawn).  Empty in the driver and in CPU workers."""
+    raw = os.environ.get("RAY_TPU_CHIPS", "")
     return [int(c) for c in raw.split(",") if c != ""]
 
 

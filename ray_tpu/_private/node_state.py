@@ -160,12 +160,12 @@ class WorkerHandle:
                  "last_idle_time", "pid", "bundle_key", "image")
 
     def __init__(self, worker_id: bytes, proc: subprocess.Popen,
-                 tpu: bool, image: Optional[str] = None) -> None:
+                 tpu: int, image: Optional[str] = None) -> None:
         self.worker_id = worker_id
         self.conn_send: Optional[Callable[[dict], None]] = None
         self.proc = proc
         self.state = "starting"    # starting | idle | busy | blocked | dead
-        self.tpu = tpu
+        self.tpu = tpu             # chips leased (0 = a CPU worker)
         # Container image this worker runs inside (runtime_env
         # image_uri); image workers only take matching tasks.
         self.image = image

@@ -27,9 +27,9 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import attention, attention_with_lse
+from ray_tpu.ops.attention import attention_with_lse, uses_flash
 from ray_tpu.ops.ring_attention import ring_attention
-from ray_tpu.parallel.sharding import constrain
+from ray_tpu.parallel.sharding import _mesh_trivial, constrain, spec_for
 
 
 @dataclasses.dataclass(frozen=True)
@@ -307,6 +307,35 @@ def _moe_block(cfg: TransformerConfig, mesh, h, p):
     return out, aux
 
 
+def _attention(cfg: TransformerConfig, mesh, q, k, v):
+    """Causal attention for [B, H, S, Dh] q / [B, Hkv, S, Dh] k, v with
+    the sequence unsharded.
+
+    Both dispatcher outputs arrive tagged remat-saveable ("attn_out" /
+    "attn_lse") by the dispatcher/custom-vjp, so the remat policy never
+    re-runs the forward kernel in the backward pass; lse is consumed
+    only as a bwd residual.
+
+    XLA cannot partition a Mosaic kernel, so on a sharded mesh the
+    flash path runs per shard under `shard_map` over the batch and head
+    axes (attention is independent along both); the einsum reference is
+    left to the SPMD partitioner like the rest of the layer."""
+    def attend(q, k, v):
+        return attention_with_lse(q, k, v, causal=True,
+                                  impl=cfg.attn_impl,
+                                  block_q=cfg.attn_block_q,
+                                  block_k=cfg.attn_block_k)[0]
+
+    if (mesh is None or _mesh_trivial(mesh)
+            or not uses_flash(cfg.attn_impl)):
+        return attend(q, k, v)
+    q_spec = spec_for(("batch", "heads", None, None), mesh=mesh)
+    kv_spec = spec_for(("batch", "kv_heads", None, None), mesh=mesh)
+    return jax.shard_map(attend, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
+
+
 def _layer_body(cfg: TransformerConfig, mesh, x, p, positions):
     """One decoder layer. x: [B, S, D]."""
     rms = cfg.arch == "llama"
@@ -327,14 +356,7 @@ def _layer_body(cfg: TransformerConfig, mesh, x, p, positions):
         o = ring_attention(q, k, v, mesh, axis_name="sp", causal=True)
         o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
     else:
-        # Both outputs arrive tagged remat-saveable ("attn_out"/
-        # "attn_lse") by the dispatcher/custom-vjp, so the dots policy
-        # never re-runs the forward kernel in the backward pass; lse is
-        # consumed only as a bwd residual.
-        o, _ = attention_with_lse(q, k, v, causal=True,
-                                  impl=cfg.attn_impl,
-                                  block_q=cfg.attn_block_q,
-                                  block_k=cfg.attn_block_k)
+        o = _attention(cfg, mesh, q, k, v)
     o = o.transpose(0, 2, 1, 3)   # [B, S, H, Dh]
     attn_out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
     x = x + constrain(attn_out, ("batch", "seq", "embed"), mesh=mesh)

@@ -254,11 +254,28 @@ def _ensure_pallas():
         pltpu = _pltpu
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def compiled_on_tpu(fn, *args):
+    """`fn(*args, interpret=...)`: the compiled Mosaic kernel where the
+    program is lowered for a TPU, the Pallas interpreter on any other
+    platform.  Chosen per lowering rather than from the process's
+    default backend, so an ahead-of-time compile for a TPU topology
+    from a CPU host builds the real kernel, and a program lowered for
+    the CPU never asks Mosaic for one."""
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(fn, interpret=False),
+        default=functools.partial(fn, interpret=True))
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, group):
+    return compiled_on_tpu(
+        functools.partial(_flash_fwd_call, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, group=group),
+        q, k, v)
+
+
+def _flash_fwd_call(q, k, v, *, scale, causal, block_q, block_k, group,
+                    interpret):
     _ensure_pallas()
     bh, sq, d = q.shape
     sk = k.shape[1]
@@ -291,7 +308,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, group):
             pltpu.VMEM((8, block_q), jnp.float32),
             pltpu.VMEM((8, block_q), jnp.float32),
         ],
-        interpret=_interpret_default(),
+        interpret=interpret,
     )(q, k, v)
     return jnp.swapaxes(o_t, 1, 2), lse
 
@@ -300,15 +317,23 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, causal, block_q, block_k,
                group):
     """Shared backward. dlse folds into the delta row constant:
     ds = p * (dp - delta + dlse)  (d lse_i / d s_ij = p_ij)."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, None, :]  # (bh, 1, sq)
+    if dlse is not None:
+        delta = delta - dlse
+    return compiled_on_tpu(
+        functools.partial(_flash_bwd_call, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, group=group),
+        q, k, v, do, lse, delta)
+
+
+def _flash_bwd_call(q, k, v, do, lse, delta, *, scale, causal, block_q,
+                    block_k, group, interpret):
     _ensure_pallas()
     bh, sq, d = q.shape
     bhkv, sk = k.shape[0], k.shape[1]
     offset = sk - sq
     nq, nk = sq // block_q, sk // block_k
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, None, :]  # (bh, 1, sq)
-    if dlse is not None:
-        delta = delta - dlse
 
     def kv_index_kq(b, ki, qi):
         return (b // group, ki, 0)
@@ -339,7 +364,7 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=_interpret_default(),
+        interpret=interpret,
     )(q, k, v, do, lse, delta)
     if group > 1:
         dk = dk.reshape(bhkv, group, sk, d).sum(axis=1)
@@ -363,7 +388,7 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, scale, causal, block_q, block_k,
                                lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret_default(),
+        interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -521,25 +546,24 @@ def attention_reference_with_lse(q, k, v, causal: bool = True,
             lse.reshape(b, hq, sq))
 
 
-def _flash_ok(q, k, causal: bool) -> bool:
-    sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
-    return (sq % 128 == 0 and sk % 128 == 0 and d % 64 == 0
-            and q.shape[1] % k.shape[1] == 0
-            and not (causal and sq > sk))
+def uses_flash(impl: str) -> bool:
+    """Whether `impl` selects the Pallas kernel.  "auto" means the
+    kernel on a TPU backend — where a shape it cannot take raises from
+    `_validate_flash` with the shape and the reason — and the reference
+    on any other backend."""
+    if impl not in ("auto", "flash", "reference"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    return impl == "flash" or (impl == "auto"
+                               and jax.default_backend() == "tpu")
 
 
 def attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
               impl: str = "auto",
               block_q: int = DEFAULT_BLOCK_Q,
               block_k: int = DEFAULT_BLOCK_K) -> jax.Array:
-    """Dispatcher: pallas flash on TPU when shapes tile cleanly, else the
-    reference path (CPU meshes, ragged shapes, causal sq > sk)."""
-    if impl == "reference":
-        return attention_reference(q, k, v, causal, scale)
-    if impl == "flash":
-        return flash_attention(q, k, v, causal, scale, block_q, block_k)
-    on_tpu = any(dev.platform == "tpu" for dev in jax.devices())
-    if _flash_ok(q, k, causal) and on_tpu:
+    """Dispatcher: pallas flash on TPU, the reference path elsewhere
+    (see `uses_flash`); impl="reference" is for callers that mean it."""
+    if uses_flash(impl):
         return flash_attention(q, k, v, causal, scale, block_q, block_k)
     return attention_reference(q, k, v, causal, scale)
 
@@ -561,14 +585,7 @@ def attention_with_lse(q, k, v, causal: bool = True,
     copy while the bwd still consumed the untagged residual — re-running
     the whole forward kernel in the backward pass just to regenerate lse.
     The reference path has no custom vjp, so tagging here suffices."""
-    if impl == "reference":
-        return _tag_saveable(*attention_reference_with_lse(
-            q, k, v, causal, scale))
-    if impl == "flash":
-        return flash_attention_with_lse(q, k, v, causal, scale,
-                                        block_q, block_k)
-    on_tpu = any(dev.platform == "tpu" for dev in jax.devices())
-    if _flash_ok(q, k, causal) and on_tpu:
+    if uses_flash(impl):
         return flash_attention_with_lse(q, k, v, causal, scale,
                                         block_q, block_k)
     return _tag_saveable(*attention_reference_with_lse(

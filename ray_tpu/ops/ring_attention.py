@@ -20,8 +20,8 @@ capability.  Design (Liu et al. ring attention, blockwise formulation):
   (SPMD lockstep — every device executes the same program).
 
 The per-step attention uses the pallas flash kernel (with lse output,
-differentiable via its custom VJP) when shapes tile on TPU; otherwise
-the einsum reference path.
+differentiable via its custom VJP) on a TPU backend and the einsum
+reference path on any other.
 """
 
 from __future__ import annotations
@@ -38,11 +38,10 @@ from ray_tpu.ops.attention import (NEG_INF, attention_reference_with_lse,
 
 
 def _partial_attn(q, k, v, scale, causal):
-    """(o, lse) for one kv shard; flash kernel when tileable on TPU."""
-    sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
-    tileable = (sq % 128 == 0 and sk % 128 == 0 and d % 64 == 0
-                and q.shape[1] % k.shape[1] == 0)
-    if tileable and jax.default_backend() == "tpu":
+    """(o, lse) for one kv shard: the flash kernel on a TPU backend
+    (an untileable shard shape raises there), the einsum reference on
+    any other backend."""
+    if jax.default_backend() == "tpu":
         # save_residuals=False: per-step partials must NOT be tagged
         # remat-saveable — the dots policy would save all R ring steps'
         # partial o/lse instead of only the final combined output.
@@ -104,11 +103,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     q/k/v: [B, H, S, D] GLOBAL arrays whose S dim is (to be) sharded over
     `axis_name`.  Returns [B, H, S, D] sharded the same way.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map           # jax >= 0.8
-    except ImportError:                     # pragma: no cover
-        from jax.experimental.shard_map import shard_map
 
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)

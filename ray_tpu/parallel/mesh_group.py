@@ -40,9 +40,9 @@ class _MeshHostWorker:
         self.world = world
         if platform == "cpu":
             n = max(local_devices, 1)
-            # XLA_FLAGS first: it is read at backend init, so it works
-            # on every jax version as long as this process has not
-            # touched devices yet (a fresh gang worker has not).
+            # XLA_FLAGS is read at backend init, and this process has
+            # not touched devices yet (a fresh gang worker has not); an
+            # inherited device count (the test suite's) is replaced.
             import os
             import re
             flags = re.sub(
@@ -53,25 +53,7 @@ class _MeshHostWorker:
             ).strip()
             import jax
             jax.config.update("jax_platforms", "cpu")
-            try:
-                jax.config.update("jax_num_cpu_devices", n)
-            except AttributeError:
-                # jax < 0.5 has no jax_num_cpu_devices option; the
-                # XLA_FLAGS override above provides the device count.
-                pass
-            if world > 1:
-                try:
-                    # Multi-host CPU collectives need gloo on jax
-                    # 0.4.x ("Multiprocess computations aren't
-                    # implemented on the CPU backend" otherwise).
-                    # World-1 gangs (elastic shrink floor) must NOT
-                    # set it: gloo requires a distributed client, and
-                    # a single host never calls
-                    # jax.distributed.initialize.
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo")
-                except AttributeError:
-                    pass  # newer jax selects CPU collectives itself
+            jax.config.update("jax_num_cpu_devices", n)
 
     def choose_coordinator(self) -> str:
         """Rank 0 picks the coordinator address ON ITS OWN HOST — the

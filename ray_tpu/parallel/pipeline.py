@@ -20,10 +20,7 @@ from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map           # jax >= 0.8
-except ImportError:                     # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -108,20 +105,11 @@ def pipeline_apply(stage_params, x, mesh, layer_fn: Callable,
         outs = jax.lax.psum(outs, "pp")
         return outs
 
-    try:
-        fn = shard_map(
-            device_fn, mesh=mesh,
-            in_specs=(jax.tree.map(lambda _: P("pp"), stage_params),
-                      xspec),
-            out_specs=xspec,
-            check_vma=False)
-    except TypeError:   # jax < 0.7 spells check_vma as check_rep
-        fn = shard_map(
-            device_fn, mesh=mesh,
-            in_specs=(jax.tree.map(lambda _: P("pp"), stage_params),
-                      xspec),
-            out_specs=xspec,
-            check_rep=False)
+    fn = shard_map(
+        device_fn, mesh=mesh,
+        in_specs=(jax.tree.map(lambda _: P("pp"), stage_params), xspec),
+        out_specs=xspec,
+        check_vma=False)
     out = fn(stage_params, x_mb)
     return out.reshape(B, S, D)
 

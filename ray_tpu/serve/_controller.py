@@ -732,9 +732,16 @@ class ServeController:
         for name, r, tmo in targets:
             key = (name, r._actor_id)
             if key not in pending:
+                # The probe deadline applies once a replica has
+                # answered anything (its engine-tags probe): until then
+                # it is still constructing — an LLM replica claims its
+                # chip and builds its weights first, well past any
+                # steady-state timeout — and a constructor that dies is
+                # reported through the probe's ref by the actor runtime.
+                deadline = (now + tmo if r._actor_id in known_tags
+                            else float("inf"))
                 try:
-                    pending[key] = (r.check_health.remote(),
-                                    now + tmo, r)
+                    pending[key] = (r.check_health.remote(), deadline, r)
                 except Exception:
                     self.report_replica_failure(name, r._actor_id)
             if tags_pending is not None \

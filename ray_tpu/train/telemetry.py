@@ -96,24 +96,29 @@ KV_SNAP_NS = "__train_telemetry__"
 KV_SEQ_NS = "__train_report_seq__"
 _SEP = "\x1f"
 
-# bf16 peak per chip (moved here from bench.py so live MFU and the
-# bench agree on the denominator).
+# bf16 peak FLOP/s per chip, keyed by jax `device_kind` (published
+# peaks: Google Cloud TPU documentation, per-generation system
+# architecture pages) — the one denominator live MFU and the bench use.
 PEAK_FLOPS = {
     "TPU v5 lite": 197e12,   # v5e
     "TPU v5": 459e12,        # v5p
     "TPU v4": 275e12,
     "TPU v6 lite": 918e12,   # v6e
-    "cpu": 1e11,
 }
 
 
 def peak_flops_for(device) -> float:
-    """Peak bf16 FLOPs/s for a jax device (CPU fallback 1e11)."""
-    kind = getattr(device, "device_kind", "cpu")
+    """Peak bf16 FLOP/s for a jax device.  A device that is not in the
+    table is an error: a utilization against a made-up peak is not a
+    measurement."""
+    kind = device.device_kind
     for name, peak in PEAK_FLOPS.items():
         if kind.startswith(name):
             return peak
-    return PEAK_FLOPS["cpu"]
+    raise ValueError(
+        f"no published peak for device_kind {kind!r} "
+        f"(known: {sorted(PEAK_FLOPS)}); add it to PEAK_FLOPS with "
+        f"its source")
 
 
 def transformer_flops_per_token(n_params: int, n_layers: int,

@@ -7,7 +7,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import (attention_reference, flash_attention)
+from ray_tpu.ops.attention import (attention, attention_reference,
+                                   flash_attention)
 
 
 def _inputs(b=2, hq=4, hkv=4, sq=256, sk=256, d=64, dtype=jnp.float32,
@@ -134,3 +135,33 @@ def test_flash_with_lse_matches_and_differentiates():
     for gf, gr, name in zip(g_f, g_r, "qkv"):
         np.testing.assert_allclose(gf, gr, atol=5e-4, rtol=5e-4,
                                    err_msg=f"grad d{name} (lse path)")
+
+
+def lower_for_tpu(fn, *args) -> str:
+    """StableHLO of `fn` lowered for the TPU platform from this CPU
+    host: the Pallas kernels take their compiled (Mosaic) branch, so a
+    block shape the TPU lowering refuses fails here, without a chip."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_kernels_lower_for_tpu(d):
+    q = jax.ShapeDtypeStruct((1, 4, 256, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 2, 256, d), jnp.bfloat16)
+    hlo = lower_for_tpu(
+        jax.grad(lambda q, k, v: flash_attention(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), q, kv, kv)
+    # forward, dk/dv and dq: compiled kernels, not interpreter expansions
+    assert hlo.count("tpu_custom_call") == 3
+
+
+def test_auto_off_tpu_is_the_reference_and_bad_impl_raises():
+    q, k, v = _inputs(sq=48, sk=48, d=16)    # a shape flash refuses
+    np.testing.assert_allclose(
+        attention(q, k, v), attention_reference(q, k, v),
+        atol=1e-6, rtol=1e-6)
+    with pytest.raises(ValueError, match="head_dim 16"):
+        attention(q, k, v, impl="flash")     # asked for: loud
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        attention(q, k, v, impl="pallas")

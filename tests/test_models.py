@@ -148,8 +148,6 @@ def test_dp_equals_single_device(cpu_mesh_devices):
     tp = run(MeshSpec(fsdp=2, tp=2, dp=2))
     np.testing.assert_allclose(single, dp, rtol=2e-4)
     # The fsdp/tp leg reduces matmul partials in a different order
-    # than the single-device program; on jax 0.4.37's CPU backend
-    # that costs ~0.5% in the loss after a few steps (newer jax
-    # matches to 2e-4).  Computation is f32 throughout — the
-    # tolerance, not the math, absorbs the backend difference.
+    # than the single-device program.  Computation is f32 throughout —
+    # the tolerance, not the math, absorbs the difference.
     np.testing.assert_allclose(single, tp, rtol=1e-2)

@@ -19,10 +19,7 @@ if "--xla_force_host_platform_device_count" not in os.environ.get(
                                ).strip()
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass  # jax < 0.5: XLA_FLAGS above provides the 8 host devices
+jax.config.update("jax_num_cpu_devices", 8)
 
 import numpy as np
 from ray_tpu.models import transformer as tfm

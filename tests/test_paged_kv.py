@@ -269,15 +269,18 @@ def test_paged_attention_reference_matches_dense_math():
     NB = 1 + B * W
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(k1, (B, H, D), jnp.float32)
-    kp = jax.random.normal(k2, (NB, BS, HKV, D), jnp.float32)
-    vp = jax.random.normal(k3, (NB, BS, HKV, D), jnp.float32)
+    kp = jax.random.normal(k2, (NB, HKV, BS, D), jnp.float32)
+    vp = jax.random.normal(k3, (NB, HKV, BS, D), jnp.float32)
     bt = (1 + np.arange(B * W, dtype=np.int32)).reshape(B, W)
     lens = np.asarray([3, 11, 20], np.int32)
     out = paged_attention_reference(q, kp, vp, jnp.asarray(bt),
                                     jnp.asarray(lens))
-    # Dense oracle: materialize each row's window and do plain attention.
-    kd = np.asarray(kp)[bt].reshape(B, W * BS, HKV, D)
-    vd = np.asarray(vp)[bt].reshape(B, W * BS, HKV, D)
+    # Dense oracle: materialize each row's window ([B, W, Hkv, bs, D]
+    # -> [B, M, Hkv, D]) and do plain attention.
+    kd = np.asarray(kp)[bt].transpose(0, 1, 3, 2, 4).reshape(
+        B, W * BS, HKV, D)
+    vd = np.asarray(vp)[bt].transpose(0, 1, 3, 2, 4).reshape(
+        B, W * BS, HKV, D)
     groups = H // HKV
     qg = np.asarray(q).reshape(B, HKV, groups, D)
     s = np.einsum("bhgk,bmhk->bhgm", qg, kd) / np.sqrt(D)
@@ -295,21 +298,63 @@ def test_paged_attention_kernel_matches_reference():
     import jax.numpy as jnp
     from ray_tpu.ops.paged_attention import (paged_attention_kernel,
                                              paged_attention_reference)
-    B, H, HKV, D, BS, W = 2, 4, 2, 16, 4, 4
+    B, H, HKV, D, BS, W = 2, 4, 2, 16, 8, 4
     NB = 1 + B * W
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(k1, (B, H, D), jnp.float32)
-    kp = jax.random.normal(k2, (NB, BS, HKV, D), jnp.float32)
-    vp = jax.random.normal(k3, (NB, BS, HKV, D), jnp.float32)
+    kp = jax.random.normal(k2, (NB, HKV, BS, D), jnp.float32)
+    vp = jax.random.normal(k3, (NB, HKV, BS, D), jnp.float32)
     rng = np.random.RandomState(0)
     bt = rng.permutation(np.arange(1, NB, dtype=np.int32)).reshape(B, W)
-    lens = np.asarray([6, 15], np.int32)
+    lens = np.asarray([11, 30], np.int32)
     ref = paged_attention_reference(q, kp, vp, jnp.asarray(bt),
                                     jnp.asarray(lens))
     out = paged_attention_kernel(q, kp, vp, jnp.asarray(bt),
                                  jnp.asarray(lens))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("h,hkv", [(32, 8), (12, 12)])
+def test_paged_kernel_lowers_for_tpu(h, hkv):
+    """The compiled kernel at the llama-1b (GQA 32/8) and gpt2-small
+    (MHA 12/12) head layouts, lowered for the TPU platform from this
+    CPU host (tests/test_attention.py lower_for_tpu): a BlockSpec the
+    TPU lowering refuses — the [NB, bs, Hkv, D] pool's (1, D) minor
+    tile did — fails here without a chip."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.paged_attention import paged_attention_kernel
+    from test_attention import lower_for_tpu
+    B, D, BS, W = 8, 64, 16, 16
+    S = jax.ShapeDtypeStruct
+    pool = S((1 + B * W, hkv, BS, D), jnp.bfloat16)
+    hlo = lower_for_tpu(paged_attention_kernel,
+                        S((B, h, D), jnp.bfloat16), pool, pool,
+                        S((B, W), jnp.int32), S((B,), jnp.int32))
+    assert hlo.count("tpu_custom_call") == 1
+    with pytest.raises(ValueError, match="block size 4"):
+        paged_attention_kernel(
+            jnp.zeros((B, h, D)), jnp.zeros((9, hkv, 4, D)),
+            jnp.zeros((9, hkv, 4, D)), jnp.zeros((B, 2), jnp.int32),
+            jnp.zeros((B,), jnp.int32))
+
+
+def test_warmup_failure_is_loud_not_a_healthy_looking_engine():
+    """A decode step that cannot be built (here: an attention impl that
+    does not exist) fails the request queued behind warm-up AND every
+    later submit with the cause, instead of an engine that stays up and
+    answers nothing."""
+    bat = _paged(_tiny_params(), _tiny_cfg(), attn_impl="no-such-impl")
+    try:
+        bat._thread.join(timeout=120)
+        assert not bat._thread.is_alive() and not bat._warmed
+        for _ in range(2):
+            with pytest.raises(RuntimeError, match="warm-up") as ei:
+                bat.submit([1, 2, 3], max_new=2)
+            assert "no-such-impl" in repr(ei.value.__cause__)
+    finally:
+        bat.stop()
 
 
 def test_paged_decode_matches_dense_decode_step():

@@ -79,6 +79,18 @@ def test_offline_compute_bound_and_rates():
         s["tokens_per_s"] * 2.0 / 1e6, rel=1e-6)
 
 
+def test_peak_flops_unknown_device_raises():
+    """A utilization against a made-up peak is not a measurement."""
+    from types import SimpleNamespace
+
+    from ray_tpu.train.telemetry import peak_flops_for
+    assert peak_flops_for(
+        SimpleNamespace(device_kind="TPU v5 lite")) == 197e12
+    assert peak_flops_for(SimpleNamespace(device_kind="TPU v5")) == 459e12
+    with pytest.raises(ValueError, match="no published peak"):
+        peak_flops_for(SimpleNamespace(device_kind="cpu"))
+
+
 def test_compile_detected_via_jit_cache_miss():
     """A step whose jitted fn traced (cache grew) lands in `compile`,
     a cache-hit step lands in `step`."""
