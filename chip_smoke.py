@@ -91,7 +91,7 @@ def train_loop(config):
     assert len(devices) == chips, (devices, chips)
     batch, seq = config["batch"], config["seq"]
 
-    # bench.py's llama-1b recipe: "names" remat + Adafactor is what lets
+    # The one-chip recipe: "names" remat + Adafactor is what lets
     # 1.5 B f32 parameters train in 16 GB; the flash kernel is forced.
     cfg = dataclasses.replace(
         tfm.PRESETS[config["model"]], max_seq=seq, remat=True,

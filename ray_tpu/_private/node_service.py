@@ -3481,8 +3481,16 @@ class NodeService(ObjectPlaneMixin, PlacementGroupMixin,
             and chips_for(r.spec.get("resources")) == tpu
             and image_of(r.spec.get("runtime_env")) == image
         ) or 1
-        alive = sum(1 for w in self.workers.values() if w.state != "dead")
-        want = min(demand - starting, self._max_workers - alive)
+        # The cap bounds the POOL: workers a task can still be handed
+        # to, start-ups included.  A worker bound to an actor has left
+        # the pool for the actor's lifetime and is bounded by what the
+        # actor holds (reference: the raylet's soft limit counts pooled
+        # workers, not actor processes) — counted here, _max_workers
+        # zero-CPU actors would starve every later task and actor on
+        # this node for ever.
+        pooled = sum(1 for w in self.workers.values()
+                     if w.state != "dead" and w.actor_id is None)
+        want = min(demand - starting, self._max_workers - pooled)
         for _ in range(max(want, 0)):
             self._spawn_worker(tpu, image=image)
 

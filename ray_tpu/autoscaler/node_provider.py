@@ -119,17 +119,23 @@ class LocalNodeProvider(NodeProvider):
         self._node_ids[name] = node_id
         return name
 
-    def terminate_node(self, name: str) -> None:
+    def terminate_node(self, name: str, force: bool = False) -> None:
+        """SIGTERM asks the node to drain and leave (running work may
+        finish inside the grace); force=True is SIGKILL, a node lost
+        without notice."""
         proc = self._procs.pop(name, None)
         self._node_ids.pop(name, None)
         if proc is None:
             return
         if proc.poll() is None:
-            proc.terminate()
-            try:
-                proc.wait(timeout=10)
-            except subprocess.TimeoutExpired:
-                proc.kill()
+            if not force:
+                proc.terminate()
+                try:
+                    proc.wait(timeout=10)
+                    return
+                except subprocess.TimeoutExpired:
+                    pass
+            proc.kill()
 
     def non_terminated_nodes(self) -> List[str]:
         return [n for n, p in self._procs.items() if p.poll() is None]

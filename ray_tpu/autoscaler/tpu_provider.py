@@ -110,7 +110,7 @@ class LocalQueuedResourcesApi(QueuedResourcesApi):
         if not info:
             return
         for node in info["hosts"]:
-            self._local.terminate_node(node)
+            self._local.terminate_node(node, force=True)
 
     # -- QueuedResourcesApi ------------------------------------------------
     def create_queued_resource(self, name: str, slice_type: str,

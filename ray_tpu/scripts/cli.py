@@ -1160,11 +1160,10 @@ def _bench_diff(fresh: dict, baseline: dict,
 
 
 def cmd_bench_diff(args) -> int:
-    """Regression gate over bench captures: compare a fresh
-    BENCH_*/MICROBENCH_*/SERVE_BENCH_* JSON against a last-good one,
-    direction-aware per metric (throughput-shaped metrics must not
-    drop, latency-shaped must not rise, beyond --tolerance).  Exit 1
-    on any regression, 0 otherwise."""
+    """Regression gate over bench captures: compare a fresh result
+    JSON against a last-good one, direction-aware per metric
+    (throughput-shaped metrics must not drop, latency-shaped must not
+    rise, beyond --tolerance).  Exit 1 on any regression, 0 otherwise."""
     with open(args.fresh) as f:
         fresh = json.load(f)
     with open(args.baseline) as f:
@@ -1391,8 +1390,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         "bench-diff",
         help="compare a fresh bench capture against a last-good one "
              "(direction-aware; exit 1 on regression)")
-    p.add_argument("fresh", help="fresh capture JSON "
-                                 "(BENCH_*/MICROBENCH_*/SERVE_BENCH_*)")
+    p.add_argument("fresh", help="fresh capture JSON")
     p.add_argument("baseline", help="last-good capture JSON")
     p.add_argument("--tolerance", type=float, default=0.10,
                    help="allowed fractional change before a "

@@ -1,10 +1,10 @@
 """Training telemetry & goodput plane: per-step decomposition, live
 MFU, ingest-vs-compute attribution, straggler detection.
 
-The train loop has been blind so far: MFU existed only as a post-hoc
-average in bench.py, and nothing per-step reached the observability
-plane.  This module is the instrument the ingest-disaggregation and
-sharded-weight-update work (ROADMAP items 2/3) will be measured with:
+Without it the train loop is blind: nothing per-step reaches the
+observability plane.  This module is the instrument the
+ingest-disaggregation and sharded-weight-update work (ROADMAP items
+2/3) will be measured with:
 
 * **Per-step decomposition** — each step's wall clock is split into
   ``data_wait`` (blocked on the next batch — the ingest-vs-compute
@@ -43,8 +43,7 @@ contract) and registered with the leak ledger.
 
 Offline mode: constructed with ``client=None`` (no runtime), the
 session still decomposes steps, keeps the ledger, and records
-process-local metrics — bench.py uses this for its steady-state MFU
-capture.
+process-local metrics.
 """
 
 from __future__ import annotations
@@ -124,7 +123,8 @@ def peak_flops_for(device) -> float:
 def transformer_flops_per_token(n_params: int, n_layers: int,
                                 seq: int, d_model: int) -> float:
     """Model FLOPs per trained token: 6N + attention 12·L·s·d (PaLM
-    appendix B) — the formula bench.py has always used, shared."""
+    appendix B).  N is the caller's count: benchmarks/lib/model.py
+    leaves the input embedding table (a gather) out of it."""
     return 6.0 * n_params + 12.0 * n_layers * seq * d_model
 
 

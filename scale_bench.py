@@ -1,35 +1,24 @@
 """Scalability-envelope harness: control-plane throughput vs node count.
 
 Analog of the reference's standing envelope suite
-(release/benchmarks/README.md:7-12 — many_nodes/many_actors/many_pgs —
-with results checked into release/release_logs/<version>/benchmarks/).
+(release/benchmarks/README.md:7-12 — many_nodes/many_actors/many_pgs).
 Runs against the in-process virtual cluster (cluster_utils.Cluster: a
 real GCS + N real node-service subprocesses on this host), so the
 numbers measure the CONTROL PLANE — scheduling, dispatch, GCS, PG 2PC
-— not worker compute.
-
-Measures, at 1/2/4/8 virtual nodes:
+— not worker compute.  Per node count:
   * tasks/s          — drain N no-op tasks spread over the cluster
   * actors/s         — create+ping K actors, then kill
   * pg create/remove — sequential placement-group 2PC latency
-plus a 200-actor churn (create/kill loop) at the largest size.
+plus an actor churn (create/ping/kill in batches) at the largest size.
 
-Writes SCALE_<round>.json (SCALE_ROUND env, default r07) and prints
-one JSON line.  tests/test_scale_envelope.py runs a shrunk version as
-the CI regression gate.  Reference baselines for orientation (64-node
+tests/test_scale_envelope.py runs a shrunk envelope as the tier-1
+regression gate; it is this module's only caller.  Device numbers come
+from benchmarks/run.py.  Reference baselines for orientation (64-node
 cluster, BASELINE.md): 334-589 tasks/s, 580 actors/s, PG 0.91/0.86 ms.
-
-Focused microbench legs (each writes into MICROBENCH_<round>.json):
-  SCALE_DAG=1              compiled-graph per-hop overhead
-  SCALE_OBJECT_TRANSFER=1  windowed binary object pull
-  SCALE_SCHED=1            scheduler placement throughput + decision
-                           latency p50/p95 on a 2-node cluster
 """
 
 from __future__ import annotations
 
-import json
-import os
 import time
 from typing import Dict, List
 
@@ -96,288 +85,6 @@ def measure_actor_churn(ray_tpu, total: int, batch: int = 50) -> float:
     return total / (time.perf_counter() - t0)
 
 
-def measure_object_transfer(size_mb: int = 256) -> dict:
-    """Inter-node object-transfer throughput on a loopback two-node
-    cluster: one `size_mb` object produced on the worker node, pulled
-    by the head (driver) node — window=1 (the stop-and-wait
-    control-plane baseline) vs the default windowed binary stream.
-    Reported as MB/s of the driver-side get()."""
-    import numpy as np
-
-    import ray_tpu
-    from ray_tpu._private.config import config as _cfg
-    from ray_tpu.cluster_utils import Cluster
-
-    store = (size_mb + 192) * 1024 * 1024
-    cluster = Cluster()
-    cluster.add_node(resources={"CPU": 2.0, "remote": 1.0},
-                     store_capacity=2 * store)
-    ray_tpu.init(num_cpus=2, gcs_address=cluster.gcs_address,
-                 object_store_memory=2 * store)
-    out: dict = {"object_mb": size_mb}
-    try:
-        cluster.wait_for_nodes(2)
-
-        @ray_tpu.remote(resources={"remote": 1}, num_returns=2)
-        def produce(n):
-            return np.arange(n // 8, dtype=np.float64), "done"
-
-        def one_pull() -> float:
-            big_ref, done_ref = produce.remote(size_mb << 20)
-            # The small sentinel proves the big object is produced
-            # remotely WITHOUT arming a pull for it: the measured get()
-            # below is pure transfer.
-            assert ray_tpu.get(done_ref, timeout=120) == "done"
-            t0 = time.perf_counter()
-            arr = ray_tpu.get(big_ref, timeout=300)
-            dt = time.perf_counter() - t0
-            assert arr[4096] == 4096.0
-            del arr, big_ref, done_ref
-            time.sleep(0.5)     # let the freed objects drain
-            return size_mb / dt
-
-        # warm both worker pools + the peer connection
-        ray_tpu.get(list(produce.remote(1 << 20)), timeout=120)
-        default_window = _cfg.object_transfer_window
-        _cfg.set("object_transfer_window", 1)
-        try:
-            out["window1_mb_s"] = round(one_pull(), 1)
-        finally:
-            _cfg.set("object_transfer_window", default_window)
-        out["windowed_mb_s"] = round(one_pull(), 1)
-        out["window"] = _cfg.object_transfer_window
-        out["speedup"] = round(out["windowed_mb_s"]
-                               / max(out["window1_mb_s"], 1e-9), 2)
-    finally:
-        ray_tpu.shutdown()
-        cluster.shutdown()
-    return out
-
-
-def _percentiles_us(lat_s: List[float], hops: int) -> Dict[str, float]:
-    import numpy as np
-    arr = np.asarray(sorted(lat_s)) * 1e6
-    return {
-        "round_trip_us_p50": round(float(np.percentile(arr, 50)), 1),
-        "round_trip_us_p95": round(float(np.percentile(arr, 95)), 1),
-        "per_hop_us_p50": round(float(np.percentile(arr, 50)) / hops, 1),
-        "per_hop_us_p95": round(float(np.percentile(arr, 95)) / hops, 1),
-        "hops": hops,
-    }
-
-
-def _measure_compiled_chain(ray_tpu, actors, iters: int,
-                            warm: int) -> Dict[str, float]:
-    """Compiled actor chain, two views: serial execute+get round trips
-    (latency; per-hop = round trip / edges) and a pipelined window of
-    in-flight executes (throughput; per-hop = wall / items / edges —
-    the steady-state overhead the fast lane is built for: waits
-    overlap, every stage's channel poll stays in its spin budget)."""
-    from ray_tpu.dag import InputNode
-    hops = len(actors) + 1
-    with InputNode() as inp:
-        out = inp
-        for a in actors:
-            out = a.step.bind(out)
-    dag = out.experimental_compile(capacity=16)
-    try:
-        for _ in range(warm):
-            assert dag.execute(1).get(timeout=60) == 1
-        lat = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            dag.execute(1).get(timeout=60)
-            lat.append(time.perf_counter() - t0)
-        # Pipelined: a sliding window of 8 in-flight executes.  The
-        # steady-state per-hop overhead — the number the fast lane is
-        # built for — is the p50/p95 of inter-completion times over
-        # the edge count (waits overlap across stages, so every
-        # stage's channel poll stays inside its spin budget).
-        window, pending = 8, []
-        t0 = time.perf_counter()
-        last = None
-        deltas = []
-        for i in range(iters):
-            pending.append(dag.execute(1))
-            if len(pending) >= window:
-                pending.pop(0).get(timeout=60)
-                now = time.perf_counter()
-                if last is not None:
-                    deltas.append(now - last)
-                last = now
-        for r in pending:
-            r.get(timeout=60)
-        wall = time.perf_counter() - t0
-    finally:
-        dag.teardown()
-    res = {f"serial_{k}": v
-           for k, v in _percentiles_us(lat, hops).items()}
-    piped = _percentiles_us(deltas, hops)
-    res.update({
-        "hops": hops,
-        "per_hop_us_p50": piped["per_hop_us_p50"],
-        "per_hop_us_p95": piped["per_hop_us_p95"],
-        "pipelined_items_per_s": round(iters / wall, 1),
-    })
-    return res
-
-
-def _measure_legacy_chain(ray_tpu, actors, iters: int,
-                          warm: int) -> Dict[str, float]:
-    """The per-call baseline: the same chain as chained actor tasks
-    (each hop pays Python scheduling + dispatch), measured the same
-    two ways — serial round trips and a pipelined window of chains —
-    and normalized to the same hop count."""
-    hops = len(actors) + 1
-
-    def submit():
-        ref = 1
-        for a in actors:
-            ref = a.step.remote(ref)
-        return ref
-
-    def once() -> float:
-        t0 = time.perf_counter()
-        ray_tpu.get(submit(), timeout=60)
-        return time.perf_counter() - t0
-
-    for _ in range(warm):
-        once()
-    lat = [once() for _ in range(iters)]
-    window, pending = 8, []
-    last = None
-    deltas = []
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        pending.append(submit())
-        if len(pending) >= window:
-            ray_tpu.get(pending.pop(0), timeout=60)
-            now = time.perf_counter()
-            if last is not None:
-                deltas.append(now - last)
-            last = now
-    for r in pending:
-        ray_tpu.get(r, timeout=60)
-    wall = time.perf_counter() - t0
-    res = {f"serial_{k}": v for k, v in _percentiles_us(lat, hops).items()}
-    piped = _percentiles_us(deltas, hops)
-    res.update({
-        "hops": hops,
-        "per_hop_us_p50": piped["per_hop_us_p50"],
-        "per_hop_us_p95": piped["per_hop_us_p95"],
-        "pipelined_items_per_s": round(iters / wall, 1),
-    })
-    return res
-
-
-def measure_dag(quick: bool = False) -> dict:
-    """Compiled-graph microbench (SCALE_DAG=1): p50/p95 per-hop
-    overhead of a 3-stage actor pipeline on compiled channels vs the
-    legacy per-call task path — same-node, plus a 2-node loopback leg
-    (skipped under SCALE_QUICK) whose cross-node edges ride the binary
-    transfer plane."""
-    import ray_tpu
-
-    iters = 300 if quick else 2000
-    warm = 20 if quick else 100
-
-    @ray_tpu.remote
-    class Stage:
-        def step(self, x):
-            return x
-
-    out: dict = {"stages": 3, "iters": iters}
-    ray_tpu.init(num_cpus=4)
-    try:
-        actors = [Stage.remote() for _ in range(3)]
-        out["same_node"] = _measure_compiled_chain(ray_tpu, actors,
-                                                   iters, warm)
-        out["same_node_legacy"] = _measure_legacy_chain(
-            ray_tpu, actors, iters, warm)
-        out["speedup_p50"] = round(
-            out["same_node_legacy"]["per_hop_us_p50"]
-            / max(out["same_node"]["per_hop_us_p50"], 1e-9), 2)
-        out["serial_speedup_p50"] = round(
-            out["same_node_legacy"]["serial_per_hop_us_p50"]
-            / max(out["same_node"]["serial_per_hop_us_p50"], 1e-9), 2)
-    finally:
-        ray_tpu.shutdown()
-    if quick:
-        return out
-
-    from ray_tpu.cluster_utils import Cluster
-    cluster = Cluster()
-    cluster.add_node(resources={"CPU": 2.0, "remote": 1.0})
-    ray_tpu.init(num_cpus=2, gcs_address=cluster.gcs_address)
-    try:
-        cluster.wait_for_nodes(2)
-        mid = Stage.options(resources={"remote": 1}).remote()
-        actors = [Stage.remote(), mid, Stage.remote()]
-        out["two_node"] = _measure_compiled_chain(
-            ray_tpu, actors, max(iters // 4, 100), warm)
-        out["two_node_legacy"] = _measure_legacy_chain(
-            ray_tpu, actors, max(iters // 4, 100), warm)
-    finally:
-        ray_tpu.shutdown()
-        cluster.shutdown()
-    return out
-
-
-def measure_sched(ray_tpu, quick: bool = False) -> dict:
-    """Scheduler decision microbench (SCALE_SCHED=1): placement
-    throughput draining no-op tasks over a 2-node cluster, plus the
-    decision-latency histogram (submit -> terminal placement) and the
-    outcome mix from the decision trace.  Latency percentiles come
-    from the head node's ray_tpu_sched_placement_seconds aggregate
-    (bucket-resolution); outcomes are cluster-merged."""
-    from ray_tpu.util import state as state_api
-    from ray_tpu.util.metrics import (SCHED_PLACEMENT_SECONDS_METRIC,
-                                      hist_quantile)
-
-    @ray_tpu.remote
-    def noop(i):
-        return i
-
-    def _hist_snapshot() -> dict:
-        agg = {"buckets": {}, "sum": 0.0, "count": 0}
-        for s in ray_tpu._ensure_connected().metrics_scrape():
-            if s.get("name") != SCHED_PLACEMENT_SECONDS_METRIC:
-                continue
-            for b, c in (s.get("buckets") or {}).items():
-                agg["buckets"][b] = agg["buckets"].get(b, 0) + c
-            agg["count"] += int(s.get("count") or 0)
-            agg["sum"] += float(s.get("sum") or 0.0)
-        return agg
-
-    n = 100 if quick else 400
-    ray_tpu.get([noop.remote(i) for i in range(8)])   # warm pools
-    base = _hist_snapshot()
-    t0 = time.perf_counter()
-    ray_tpu.get([noop.remote(i) for i in range(n)])
-    wall = time.perf_counter() - t0
-
-    summary = state_api.summarize_scheduling()
-    # Bench-window delta: warm-up placements wait on worker-pool
-    # spawn (seconds) and would drown the steady-state percentiles.
-    after = _hist_snapshot()
-    merged = {
-        "buckets": {b: c - base["buckets"].get(b, 0)
-                    for b, c in after["buckets"].items()},
-        "sum": after["sum"] - base["sum"],
-        "count": after["count"] - base["count"],
-    }
-    return {
-        "tasks": n,
-        "placements_per_s": round(n / wall, 1),
-        "decision_latency_ms_p50": round(
-            hist_quantile(merged, 0.50) * 1000.0, 3),
-        "decision_latency_ms_p95": round(
-            hist_quantile(merged, 0.95) * 1000.0, 3),
-        "decisions_recorded": summary["decisions"],
-        "outcomes": summary["outcomes"],
-    }
-
-
 def run_envelope(node_counts: List[int], n_tasks: int, n_actors: int,
                  n_pgs: int, churn: int) -> dict:
     import ray_tpu
@@ -406,81 +113,4 @@ def run_envelope(node_counts: List[int], n_tasks: int, n_actors: int,
         finally:
             ray_tpu.shutdown()
             cluster.shutdown()
-    return {
-        "metric": "scale_envelope",
-        "host_cpus": os.cpu_count(),
-        "n_tasks": n_tasks, "n_actors": n_actors, "n_pgs": n_pgs,
-        "churn_actors": churn,
-        "levels": results,
-        "reference": {"tasks_per_s_64node": 589,
-                      "actors_per_s_64node": 580,
-                      "pg_create_ms": 0.91, "pg_remove_ms": 0.86,
-                      "source": "BASELINE.md (64x64-core cluster)"},
-    }
-
-
-def _merge_microbench(rnd: str, key: str, res: dict) -> None:
-    path = f"MICROBENCH_{rnd}.json"
-    blob = {}
-    if os.path.exists(path):
-        with open(path) as f:
-            blob = json.load(f)
-    blob[key] = res
-    with open(path, "w") as f:
-        json.dump(blob, f, indent=1)
-
-
-def main() -> None:
-    rnd = os.environ.get("SCALE_ROUND", "r07")
-    quick = os.environ.get("SCALE_QUICK", "") not in ("", "0", "false")
-    if os.environ.get("SCALE_SCHED", "") not in ("", "0", "false"):
-        # Scheduler decision microbench: placements/s + decision
-        # latency p50/p95 over a 2-node cluster, from the decision
-        # trace this round introduced.
-        import ray_tpu
-        from ray_tpu.cluster_utils import Cluster
-        cluster = Cluster()
-        cluster.add_node(resources={"CPU": 2.0})
-        ray_tpu.init(num_cpus=2, gcs_address=cluster.gcs_address)
-        try:
-            cluster.wait_for_nodes(2)
-            res = measure_sched(ray_tpu, quick=quick)
-        finally:
-            ray_tpu.shutdown()
-            cluster.shutdown()
-        _merge_microbench(rnd, "sched", res)
-        print(json.dumps({"metric": "sched", **res}))
-        return
-    if os.environ.get("SCALE_DAG", "") not in ("", "0", "false"):
-        # Compiled-graph microbench: 3-stage actor pipeline, per-hop
-        # overhead on compiled channels vs the legacy per-call path.
-        # SCALE_QUICK shrinks iterations and skips the 2-node leg so
-        # it runs in seconds locally.
-        res = measure_dag(quick=quick)
-        _merge_microbench(rnd, "dag", res)
-        print(json.dumps({"metric": "dag", **res}))
-        return
-    if os.environ.get("SCALE_OBJECT_TRANSFER", "") not in ("", "0",
-                                                           "false"):
-        # Object-transfer microbench only: loopback two-node pull of a
-        # 256 MiB object, stop-and-wait (window=1) vs windowed binary
-        # stream.  Recorded into MICROBENCH_<round>.json next to the
-        # single-node microbench numbers.
-        size = int(os.environ.get("SCALE_TRANSFER_MB", "256"))
-        res = measure_object_transfer(size)
-        _merge_microbench(rnd, "object_transfer", res)
-        print(json.dumps({"metric": "object_transfer", **res}))
-        return
-    if quick:
-        out = run_envelope([1, 2], n_tasks=60, n_actors=8, n_pgs=5,
-                           churn=20)
-    else:
-        out = run_envelope([1, 2, 4, 8], n_tasks=400, n_actors=40,
-                           n_pgs=20, churn=200)
-    with open(f"SCALE_{rnd}.json", "w") as f:
-        json.dump(out, f, indent=1)
-    print(json.dumps(out))
-
-
-if __name__ == "__main__":
-    main()
+    return {"levels": results}

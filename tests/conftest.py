@@ -6,7 +6,10 @@ virtual 8-device CPU mesh for all sharding/parallelism tests (the TPU-build
 equivalent of the reference's fake multi-node cluster_utils.Cluster).
 """
 
+import faulthandler
 import os
+import signal
+import sys
 
 # Force an 8-device CPU platform for jax BEFORE jax is imported anywhere:
 # sharding/pjit tests exercise real multi-device meshes this way, and the
@@ -27,6 +30,28 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
+
+# The one time limit of every test, in seconds (the slowest takes 74 s
+# alone; six xdist workers on one host may triple that).
+LIMIT = 240.0
+
+
+def _on_limit(signum, frame):
+    faulthandler.dump_traceback(file=sys.__stderr__, all_threads=True)
+    raise TimeoutError(f"test ran past {LIMIT:.0f} s; every thread's "
+                       "stack is in the captured stderr")
+
+
+@pytest.fixture(autouse=True)
+def _time_limit():
+    """A hung test becomes one failure with its stacks in the log, and
+    the other fixtures' shutdown() still runs.  The timer repeats, so a
+    teardown that hangs in its turn is interrupted too."""
+    previous = signal.signal(signal.SIGALRM, _on_limit)
+    signal.setitimer(signal.ITIMER_REAL, LIMIT, LIMIT)
+    yield
+    signal.setitimer(signal.ITIMER_REAL, 0)
+    signal.signal(signal.SIGALRM, previous)
 
 
 @pytest.fixture

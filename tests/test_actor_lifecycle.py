@@ -233,3 +233,26 @@ def test_handle_gc_releases_actor(ray_start):
     time.sleep(0.5)
     keeper = ray_tpu.get_actor("keeper")
     assert ray_tpu.get(keeper.ping.remote(), timeout=30) == 1
+
+
+def test_actors_beyond_worker_pool_cap_start(ray_start):
+    """Zero-CPU actors hold no resource, so more of them than the
+    node's pooled-worker cap (8 at num_cpus=4) must all start, and a
+    task submitted beside them must still get a worker: an actor's
+    worker has left the pool and does not count against the cap.
+    (The envelope's 12-actor churn leg hung on its ninth actor.)"""
+    @ray_tpu.remote
+    class A:
+        def ping(self):
+            return 1
+
+    @ray_tpu.remote
+    def one():
+        return 1
+
+    actors = [A.remote() for _ in range(12)]
+    assert ray_tpu.get([a.ping.remote() for a in actors],
+                       timeout=30) == [1] * 12
+    assert ray_tpu.get(one.remote(), timeout=30) == 1
+    for a in actors:
+        ray_tpu.kill(a)

@@ -290,8 +290,8 @@ def test_cross_host_backpressure_and_oversize(cluster):
 
 @pytest.mark.slow
 def test_two_node_dag_bench_smoke(cluster):
-    """Shrunk 2-node leg of the SCALE_DAG microbench (slow: tier-1
-    budget) — cross-node pipeline sustains pipelined executes."""
+    """Cross-node pipeline sustains pipelined executes (slow: tier-1
+    budget)."""
     a = Stage.remote(1)
     b = Stage.options(resources={"remote": 1}).remote(1)
     c2 = Stage.remote(1)

@@ -1,7 +1,8 @@
 """The minimum end-to-end slice (SURVEY.md §7): a transformer trained
 through the full stack — TpuTrainer worker actor, jax mesh + compiled
 sharded step, Dataset input pipeline, orbax checkpointing, failure
-resume.  This is the integration contract bench.py scales up on TPU.
+resume.  This is the integration contract benchmarks/run.py's training
+cells scale up on TPU.
 """
 
 import json
