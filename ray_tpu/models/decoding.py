@@ -450,8 +450,11 @@ def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
     blk_w = jnp.where(active,
                       caches.block_tables[batch_ix, pos_c // bs], 0)
     off_w = pos_c % bs
-    # Valid positions INCLUDE the token scattered this step.
-    ctx_lens = jnp.minimum(caches.lengths + 1, M)
+    # Valid positions INCLUDE the token scattered this step.  An
+    # inactive slot attends to nothing: a retired slot keeps its last
+    # length until the next prefill, and the kernel's work follows
+    # these lengths (the host drops such a slot's output anyway).
+    ctx_lens = jnp.where(active, jnp.minimum(caches.lengths + 1, M), 0)
 
     def layer(x, inputs):
         p, k_pool, v_pool = inputs

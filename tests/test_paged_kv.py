@@ -292,13 +292,39 @@ def test_paged_attention_reference_matches_dense_math():
     np.testing.assert_allclose(np.asarray(out), want, atol=1e-5)
 
 
-def test_paged_attention_kernel_matches_reference():
-    """Pallas kernel (interpret mode off-TPU) == gather reference."""
+def _ragged_lengths(B, W, bs):
+    """Context lengths that walk the edges of the kernel's loops, the
+    sharpest first: an empty row, one token, exactly one page, one page
+    group (128 positions, or the whole table where that is shorter)
+    and one position either side of it, mid-table, ...; then a full
+    table whose first W - 1 pages the last row shares."""
+    full = W * bs
+    group = min(full, max(bs, 128 // bs * bs))
+    edges = [0, 1, bs, group - 1, min(group + 1, full), group,
+             full // 2 + 3, bs - 1, bs + 1, min(2 * group, full),
+             full - 1]
+    return np.asarray([edges[i % len(edges)] for i in range(B - 2)]
+                      + [full, (W - 1) * bs + 1], np.int32)
+
+
+# (H, Hkv, D, bs, W, B): the benchmark's serving cell (Mistral-7B
+# heads; D 128 takes the kernel's whole-page DMA path), llama-1b and
+# gpt2-small heads (D 64: the block-table BlockSpec path), a small MHA
+# table that is one short group, and tables that are not a whole number
+# of 8-page groups on either path.
+@pytest.mark.parametrize("H,HKV,D,BS,W,B", [
+    (32, 8, 128, 16, 48, 32), (32, 8, 64, 16, 16, 8),
+    (12, 12, 64, 16, 16, 8), (4, 4, 64, 8, 5, 3),
+    (8, 2, 128, 16, 11, 12), (4, 2, 64, 16, 11, 12),
+], ids=str)
+def test_paged_attention_kernel_matches_reference(H, HKV, D, BS, W, B):
+    """Pallas kernel (interpret mode off-TPU) == gather reference, for
+    ragged lengths and with two rows sharing physical pages (a common
+    prefix) while a third row's table is scattered differently."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.paged_attention import (paged_attention_kernel,
                                              paged_attention_reference)
-    B, H, HKV, D, BS, W = 2, 4, 2, 16, 8, 4
     NB = 1 + B * W
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(k1, (B, H, D), jnp.float32)
@@ -306,38 +332,123 @@ def test_paged_attention_kernel_matches_reference():
     vp = jax.random.normal(k3, (NB, HKV, BS, D), jnp.float32)
     rng = np.random.RandomState(0)
     bt = rng.permutation(np.arange(1, NB, dtype=np.int32)).reshape(B, W)
-    lens = np.asarray([11, 30], np.int32)
+    lens = _ragged_lengths(B, W, BS)
+    bt[B - 1, :W - 1] = bt[B - 2, :W - 1]     # a shared prefix
     ref = paged_attention_reference(q, kp, vp, jnp.asarray(bt),
                                     jnp.asarray(lens))
     out = paged_attention_kernel(q, kp, vp, jnp.asarray(bt),
                                  jnp.asarray(lens))
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_array_equal(np.asarray(out)[lens == 0], 0.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("h,hkv", [(32, 8), (12, 12)])
-def test_paged_kernel_lowers_for_tpu(h, hkv):
+def test_paged_attention_kernel_bf16_pool_and_dead_table_entries():
+    """The serving dtype on the whole-page DMA path: bf16 q and pools,
+    table entries beyond each row's length pointing at scratch block 0
+    (as the engine pads them) and a batch that is mostly empty rows."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.paged_attention import (paged_attention_kernel,
+                                             paged_attention_reference)
+    B, H, HKV, D, BS, W = 8, 8, 2, 128, 16, 20
+    NB = 1 + B * W
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(k1, (B, H, D), jnp.bfloat16)
+    kp = jax.random.normal(k2, (NB, HKV, BS, D), jnp.bfloat16)
+    vp = jax.random.normal(k3, (NB, HKV, BS, D), jnp.bfloat16)
+    lens = np.asarray([0, 0, 200, 0, 0, 129, 0, 17], np.int32)
+    bt = (1 + np.arange(B * W, dtype=np.int32)).reshape(B, W)
+    bt[np.arange(W)[None, :] * BS >= lens[:, None]] = 0
+    args = (q, kp, vp, jnp.asarray(bt), jnp.asarray(lens))
+    out = np.asarray(paged_attention_kernel(*args), np.float32)
+    ref = np.asarray(paged_attention_reference(*args), np.float32)
+    np.testing.assert_array_equal(out[lens == 0], 0.0)
+    np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("h,hkv,d,w,b", [(32, 8, 64, 16, 8),
+                                         (12, 12, 64, 16, 8),
+                                         (32, 8, 128, 48, 32)])
+def test_paged_kernel_lowers_for_tpu(h, hkv, d, w, b):
     """The compiled kernel at the llama-1b (GQA 32/8) and gpt2-small
-    (MHA 12/12) head layouts, lowered for the TPU platform from this
-    CPU host (tests/test_attention.py lower_for_tpu): a BlockSpec the
-    TPU lowering refuses — the [NB, bs, Hkv, D] pool's (1, D) minor
-    tile did — fails here without a chip."""
+    (MHA 12/12) head layouts and at the benchmark's serving cell
+    (Mistral-7B heads of 128, 32 slots, 48-page tables), lowered for
+    the TPU platform from this CPU host (tests/test_attention.py
+    lower_for_tpu): ONE Mosaic call whatever the path, so a trace
+    counts one `paged_attention` operation per layer and step.  (What
+    Mosaic itself refuses shows in tests/test_tpu_aot.py.)"""
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.paged_attention import paged_attention_kernel
     from test_attention import lower_for_tpu
-    B, D, BS, W = 8, 64, 16, 16
+    BS = 16
     S = jax.ShapeDtypeStruct
-    pool = S((1 + B * W, hkv, BS, D), jnp.bfloat16)
+    pool = S((1 + b * w, hkv, BS, d), jnp.bfloat16)
     hlo = lower_for_tpu(paged_attention_kernel,
-                        S((B, h, D), jnp.bfloat16), pool, pool,
-                        S((B, W), jnp.int32), S((B,), jnp.int32))
+                        S((b, h, d), jnp.bfloat16), pool, pool,
+                        S((b, w), jnp.int32), S((b,), jnp.int32))
     assert hlo.count("tpu_custom_call") == 1
     with pytest.raises(ValueError, match="block size 4"):
         paged_attention_kernel(
-            jnp.zeros((B, h, D)), jnp.zeros((9, hkv, 4, D)),
-            jnp.zeros((9, hkv, 4, D)), jnp.zeros((B, 2), jnp.int32),
-            jnp.zeros((B,), jnp.int32))
+            jnp.zeros((b, h, d)), jnp.zeros((9, hkv, 4, d)),
+            jnp.zeros((9, hkv, 4, d)), jnp.zeros((b, 2), jnp.int32),
+            jnp.zeros((b,), jnp.int32))
+
+
+@pytest.mark.parametrize("impl", ["reference", "kernel"])
+def test_paged_decode_inactive_slots_attend_to_nothing(impl):
+    """The zero-context gate: slots the engine marks inactive (retired,
+    their last length and table still on the device) take no part in a
+    decode step.  The active slots' tokens, lengths and pool writes are
+    those of an engine in which the inactive slots never existed, and
+    an inactive slot's `lengths` / `last_token` stay as they were."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import decoding
+    cfg, params = _tiny_cfg(), _tiny_params()
+    B, BS, MAXLEN, STEPS = 4, 8, 40, 3
+    W = decoding.paged_table_width(MAXLEN, BS)
+    empty = decoding.init_paged_caches(cfg, B, B * W, BS, MAXLEN)
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    tables = (1 + np.arange(B * W, dtype=np.int32)).reshape(B, W)
+    caches = empty._replace(
+        kp=jax.random.normal(ks[0], empty.kp.shape, cfg.dtype),
+        vp=jax.random.normal(ks[1], empty.vp.shape, cfg.dtype),
+        block_tables=jnp.asarray(tables),
+        lengths=jnp.asarray([5, 17, 8, 30], jnp.int32),
+        last_token=jnp.asarray([3, 9, 27, 81], jnp.int32))
+    live = np.asarray([0, 2])
+    active = jnp.asarray([True, False, True, False])
+
+    def steps(c, act):
+        toks = []
+        for _ in range(STEPS):
+            c, t = decoding._paged_decode_core(params, c, act, cfg, impl)
+            toks.append(np.asarray(t))
+        return c, np.stack(toks)
+
+    got, got_toks = steps(caches, active)
+    alone = caches._replace(block_tables=caches.block_tables[live],
+                            lengths=caches.lengths[live],
+                            last_token=caches.last_token[live])
+    want, want_toks = steps(alone, jnp.asarray([True, True]))
+    np.testing.assert_array_equal(got_toks[:, live], want_toks)
+    np.testing.assert_array_equal(np.asarray(got.lengths),
+                                  [5 + STEPS, 17, 8 + STEPS, 30])
+    np.testing.assert_array_equal(np.asarray(got.last_token)[live],
+                                  np.asarray(want.last_token))
+    np.testing.assert_array_equal(np.asarray(got.last_token)[[1, 3]],
+                                  [9, 81])
+    # Every block but the scratch block (0, where gated writes land).
+    for pool, pool_alone, before in ((got.kp, want.kp, caches.kp),
+                                     (got.vp, want.vp, caches.vp)):
+        np.testing.assert_array_equal(np.asarray(pool)[:, 1:],
+                                      np.asarray(pool_alone)[:, 1:])
+        inactive = tables[[1, 3]].ravel()
+        np.testing.assert_array_equal(np.asarray(pool)[:, inactive],
+                                      np.asarray(before)[:, inactive])
 
 
 def test_warmup_failure_is_loud_not_a_healthy_looking_engine():

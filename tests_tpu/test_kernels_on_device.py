@@ -119,26 +119,36 @@ def _paged_inputs(h, hkv, d=64, bs=16, b=8, w=16, dtype=jnp.bfloat16,
     bt = np.random.RandomState(seed).permutation(
         np.arange(1, nb, dtype=np.int32)).reshape(b, w)
     # Ragged: an empty slot, one token, a block boundary on either
-    # side, mid-table, and a completely full table.
-    lens = np.asarray([0, 1, bs - 1, bs, bs + 1, 5 * bs + 3,
-                       w * bs - 1, w * bs], np.int32)[:b]
+    # side, mid-table, and a completely full table; beyond those eight,
+    # the 128-position page group's boundary on either side, empty
+    # slots between live ones, and lengths drawn over the whole table.
+    lens = [0, 1, bs - 1, bs, bs + 1, 5 * bs + 3, w * bs - 1, w * bs,
+            127, 128, 129, 0, 0, 2 * 128, 2 * 128 + 1, 0]
+    lens += list(np.random.RandomState(seed + 1).randint(
+        1, w * bs + 1, max(0, b - len(lens))))
+    lens = np.asarray(lens[:b], np.int32)
     return q, kp, vp, jnp.asarray(bt), jnp.asarray(lens)
 
 
-@pytest.mark.parametrize("h,hkv", [(32, 8), (12, 12)],
-                         ids=["gqa-32-8", "mha-12-12"])
+@pytest.mark.parametrize("h,hkv,d,w,b", [(32, 8, 64, 16, 8),
+                                         (12, 12, 64, 16, 8),
+                                         (32, 8, 128, 48, 32)],
+                         ids=["gqa-32-8", "mha-12-12", "cell-d128-w48-b32"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-def test_paged_attention_matches_reference_on_tpu(h, hkv, dtype):
+def test_paged_attention_matches_reference_on_tpu(h, hkv, d, w, b, dtype):
     """The compiled paged kernel at the llama-1b (GQA 32/8) and
-    gpt2-small (MHA 12/12) head layouts, D 64, block 16."""
-    args = _paged_inputs(h, hkv, dtype=dtype)
+    gpt2-small (MHA 12/12) head layouts, D 64, block 16, and at the
+    benchmark's serving cell: Mistral-7B heads of 128, 32 slots with
+    48-page tables and ragged lengths (the whole-page DMA path)."""
+    args = _paged_inputs(h, hkv, d=d, w=w, b=b, dtype=dtype)
     out = jax.jit(paged_attention_kernel)(*args)
     ref = paged_attention_reference(*args)
-    assert out.shape == ref.shape == (8, h, 64)
+    assert out.shape == ref.shape == (b, h, d)
     out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
     assert np.isfinite(out).all()
-    np.testing.assert_array_equal(out[0], 0.0)      # zero-length slot
+    np.testing.assert_array_equal(                  # zero-length slots
+        out[np.asarray(args[-1]) == 0], 0.0)
     np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
 
 
