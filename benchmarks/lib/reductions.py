@@ -11,7 +11,9 @@ counter | series | trace) and one `reduction`:
   share_of_window exposed collective time / window, %
   roofline_share  for `kernels` [{op_pattern, cost_fn}]: the least time
                   the chip could take for the calls the trace shows /
-                  the time they took, %
+                  the time they took, %.  `cost_fn` names a function of
+                  the cell's kind (kinds/<kind>.py COST_FNS) or of the
+                  shared table (lib/peaks.py)
 
 A reader that finds nothing to read returns None, and the harness leaves
 that metric out of the line.
@@ -39,7 +41,8 @@ def percentile(values: Sequence[float], q: float) -> Optional[float]:
 def read_metric(spec: Dict[str, Any], obs: Dict[str, Any]
                 ) -> Optional[float]:
     """`obs`: {"counters": {}, "series": {}, "trace": summary or {},
-    "config": {}, "shapes": {}, "device_kind": str}."""
+    "config": {}, "shapes": {}, "device_kind": str, "cost_fns": the cell's
+    table (spec.cost_fns: its kind's, then the shared one)}."""
     red = spec["reduction"]
     counters, series, tr = obs["counters"], obs["series"], obs["trace"]
     if red == "value":
@@ -72,8 +75,8 @@ def read_metric(spec: Dict[str, Any], obs: Dict[str, Any]
         for k in spec["kernels"]:
             n = trace_reduce.matching(tr, k["op_pattern"], "op_counts")
             t = trace_reduce.matching(tr, k["op_pattern"])
-            flops, bytes_ = peaks.COST_FNS[k["cost_fn"]](
-                obs["config"], obs["shapes"])
+            flops, bytes_ = obs.get("cost_fns", peaks.COST_FNS)[
+                k["cost_fn"]](obs["config"], obs["shapes"])
             least += n * peaks.roofline_seconds(
                 flops, bytes_, obs["device_kind"])[0]
             took += t

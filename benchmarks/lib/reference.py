@@ -14,8 +14,36 @@ or the causal mask errs by ~1, two orders above the tolerance.
 from __future__ import annotations
 
 import math
+from typing import Any, Dict, List, Sequence, Tuple
 
 TOLERANCE = 2e-2
+
+
+def judge(tolerances: Dict[str, float], required: Sequence[str],
+          checks: Dict[str, Any]
+          ) -> Tuple[List[str], Dict[str, List[float]]]:
+    """A kind's parity() readings against its TOLERANCES.  `required` is
+    the kind's CHECKS[where]: the entries a cell of that sort must compare.
+    A fault for each that parity() did not return, that has no limit, or
+    that is not under its limit (a NaN is not), and for a kind that names
+    none: a run that compared nothing is not correct.  Also each number
+    compared beside its limit, for the result line."""
+    faults, compared = [], {}
+    if not required:
+        faults.append("the kind names no check for this cell: nothing was "
+                      "compared with the plain reference")
+    for name in required:
+        if name not in tolerances:
+            faults.append(f"{name} has no limit in the kind's TOLERANCES")
+        elif name not in checks:
+            faults.append(f"{name} is missing from parity(): got "
+                          f"{sorted(checks)}")
+        else:
+            compared[name] = [checks[name], tolerances[name]]
+            if not checks[name] < tolerances[name]:
+                faults.append(f"{name} {checks[name]:.3g} is not under its "
+                              f"limit {tolerances[name]:g} (plain reference)")
+    return faults, compared
 
 
 def attention(q, k, v):
@@ -67,17 +95,34 @@ def max_abs_err(got, want) -> float:
                                  - want.astype(jnp.float32))))
 
 
-def flash_parity(cfg, seed: int, seq: int = 512) -> dict:
-    """The program's flash forward against `attention`, at this
-    configuration's heads and head size."""
+def _flash_inputs(cfg, seed: int, seq: int):
     import jax
     import jax.numpy as jnp
-    from ray_tpu.ops import attention as prog
     ks = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 3)
     q = jax.random.normal(ks[0], (1, cfg.n_heads, seq, cfg.head_dim),
                           jnp.bfloat16)
     k, v = (jax.random.normal(kk, (1, cfg.kv_heads, seq, cfg.head_dim),
                               jnp.bfloat16) for kk in ks[1:])
+    return q, k, v
+
+
+def _paged_inputs(caches, cfg, seed: int):
+    import jax
+    import jax.numpy as jnp
+    B = caches.lengths.shape[0]
+    q = jax.random.normal(jax.random.PRNGKey(seed % (2 ** 31)),
+                          (B, cfg.n_heads, cfg.head_dim), cfg.dtype)
+    M = caches.block_tables.shape[1] * caches.kp.shape[3]
+    return (q, caches.kp[0], caches.vp[0], caches.block_tables,
+            jnp.minimum(caches.lengths + 1, M))
+
+
+def flash_parity(cfg, seed: int, seq: int = 512) -> dict:
+    """The program's flash forward against `attention`, at this
+    configuration's heads and head size."""
+    import jax
+    from ray_tpu.ops import attention as prog
+    q, k, v = _flash_inputs(cfg, seed, seq)
     auto = jax.jit(lambda *a: prog.attention(*a, impl="auto"))
     lowered = auto.lower(q, k, v).as_text()
     return {"flash_err": max_abs_err(auto(q, k, v), attention(q, k, v)),
@@ -90,14 +135,33 @@ def paged_parity(caches, cfg, seed: int) -> dict:
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops import paged_attention as prog
-    B = caches.lengths.shape[0]
-    q = jax.random.normal(jax.random.PRNGKey(seed % (2 ** 31)),
-                          (B, cfg.n_heads, cfg.head_dim), cfg.dtype)
-    M = caches.block_tables.shape[1] * caches.kp.shape[3]
-    args = (q, caches.kp[0], caches.vp[0], caches.block_tables,
-            jnp.minimum(caches.lengths + 1, M))
+    args = _paged_inputs(caches, cfg, seed)
     auto = jax.jit(lambda *a: prog.paged_attention(*a, impl="auto"))
     lowered = auto.lower(*args).as_text()
     return {"paged_err": max_abs_err(auto(*args), paged_attention(*args)),
             "paged_is_kernel": "tpu_custom_call" in lowered,
             "paged_live_positions": int(jnp.sum(args[-1]))}
+
+
+# -- the control: what `correct` has been shown to refuse ------------------
+# The plain reference put in the program's place, computed in the nearest
+# precision BELOW the configuration's bfloat16: q, k, v (and the pool)
+# rounded to fp8 (e4m3, 3 mantissa bits), the step a later PR would be
+# tempted by.  It must read over the kind's limit (tests/test_control.py;
+# on the chip at the cells' own size: PERF.md section 2).
+def _fp8(x):
+    import jax.numpy as jnp
+    return x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
+
+
+def flash_control(cfg, seed: int, seq: int = 512) -> float:
+    q, k, v = _flash_inputs(cfg, seed, seq)
+    return max_abs_err(attention(_fp8(q), _fp8(k), _fp8(v)),
+                       attention(q, k, v))
+
+
+def paged_control(caches, cfg, seed: int) -> float:
+    q, kp, vp, tables, lens = _paged_inputs(caches, cfg, seed)
+    return max_abs_err(
+        paged_attention(_fp8(q), _fp8(kp), _fp8(vp), tables, lens),
+        paged_attention(q, kp, vp, tables, lens))

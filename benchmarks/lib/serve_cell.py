@@ -1,19 +1,25 @@
-"""A serving cell: one `closed_loop` or `open_loop` traffic file against
+"""A serving cell: one traffic file of a serving kind against
 serve.run(serve.deployment(BenchLLM)) — the program's LLMDeployment with
 the few methods the benchmark needs inside the process that holds the
 chip.  The load comes from this (jax-free) driver process: one thread per
 request in flight, each reading its token stream and stamping every token
-with the host clock."""
+with the host clock.
+
+How the requests are offered is the traffic kind's (traffic_kinds/<kind>.py:
+`clients`, `drive`), what the architecture needs is the model kind's
+(kinds/<kind>.py); both are found by name (lib/spec.py).  The request
+record, the two ways to send one, the window, the drain and every
+end-to-end formula are here, and are the only ones."""
 
 from __future__ import annotations
 
 import random
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional
 
-from benchmarks.lib import model, reductions, reference, traffic
+from benchmarks.lib import (reductions, reference, spec, traffic,
+                            worker_util)
 from ray_tpu.serve.llm import LLMDeployment
 
 TRACE_SECONDS = 4.0       # traced part of the window: its last seconds
@@ -30,13 +36,13 @@ class BenchLLM(LLMDeployment):
     def __init__(self, config: Dict[str, Any], seed: int, rehearsal: bool,
                  trace_dir: str, keep_trace: bool) -> None:
         import jax
-        from benchmarks.lib import worker_util
         from ray_tpu.models import transformer
 
+        self._kind = spec.model_kind(config["kind"])
         self._compiles = worker_util.CompileCounter()
         self._device = worker_util.device_info(require_tpu=not rehearsal)
         sv = config["serve"]
-        cfg_kwargs = model.with_dtypes(model.transformer_kwargs(
+        cfg_kwargs = worker_util.with_dtypes(self._kind.transformer_kwargs(
             config, max_seq=sv["max_len"], param_dtype=sv["param_dtype"]))
         cfg = transformer.TransformerConfig(**cfg_kwargs)
         # One program makes every weight on the device, in the type it is
@@ -79,7 +85,8 @@ class BenchLLM(LLMDeployment):
         return out
 
     def bench_info(self) -> Dict[str, Any]:
-        from benchmarks.lib import worker_util
+        """`engine`: every number the program's stats() holds (for a paged
+        engine that includes kv_stats()), under its dotted path."""
         st = self.stats()
         return {"device": self._device, "params": self._n_params,
                 "memory_peak_bytes": worker_util.memory_peak_bytes(),
@@ -87,14 +94,12 @@ class BenchLLM(LLMDeployment):
                 "compiles": self._compiles.count, "steps": st["steps"],
                 "warmed": st["warmed"], "engine_error": st["engine_error"],
                 "warmup_s": st["warmup_s"],
-                "prefix_cache": st.get("prefix_cache")}
+                "engine": worker_util.numeric_leaves(st)}
 
     def kernel_parity(self) -> Dict[str, Any]:
         """Only while the engine is idle: a dispatch donates the pool."""
-        out = reference.flash_parity(self.batcher.cfg, self._seed)
-        out.update(reference.paged_parity(self.batcher.caches,
-                                          self.batcher.cfg, self._seed))
-        return out
+        return self._kind.parity("serve", self.batcher.cfg, self._seed,
+                                 caches=self.batcher.caches)
 
     def trace_start(self) -> float:
         self._trace.start()
@@ -110,9 +115,10 @@ class BenchLLM(LLMDeployment):
         return self._trace.summary(keep=self._keep_trace)
 
 
-class _Record:
+class Record:
+    """One request, as the client saw it."""
     __slots__ = ("index", "prompt_len", "max_new", "due", "sent", "stamps",
-                 "bad_token", "error", "breakdown")
+                 "tokens", "bad_token", "error", "breakdown")
 
     def __init__(self, index: int, prompt_len: int, max_new: int,
                  due: float) -> None:
@@ -120,6 +126,7 @@ class _Record:
         self.due = due
         self.sent = 0.0
         self.stamps: List[float] = []
+        self.tokens: List[int] = []
         self.bad_token = False
         self.error: Optional[str] = None
         self.breakdown: Optional[Dict[str, Any]] = None
@@ -130,7 +137,7 @@ class _Record:
                 and len(self.stamps) == self.max_new)
 
 
-def _unary_one(handle, rec: _Record, prompt: List[int], vocab: int) -> None:
+def unary_one(handle, rec: Record, prompt: List[int], vocab: int) -> None:
     """A caller that waits for its whole reply (`"reply": "unary"`): all
     its tokens are delivered at once, when `generate` returns."""
     import ray_tpu
@@ -139,6 +146,7 @@ def _unary_one(handle, rec: _Record, prompt: List[int], vocab: int) -> None:
         out = ray_tpu.get(handle.generate.remote(prompt, max_new=rec.max_new),
                           timeout=DRAIN_DEADLINE_S)
         rec.stamps = [time.time()] * len(out["tokens"])
+        rec.tokens = list(out["tokens"])
         rec.bad_token = not all(isinstance(t, int) and 0 <= t < vocab
                                 for t in out["tokens"])
         rec.breakdown = dict(out["ttft_breakdown"],
@@ -147,7 +155,7 @@ def _unary_one(handle, rec: _Record, prompt: List[int], vocab: int) -> None:
         rec.error = f"{type(e).__name__}: {e}"
 
 
-def _stream_one(handle, rec: _Record, prompt: List[int], vocab: int) -> None:
+def stream_one(handle, rec: Record, prompt: List[int], vocab: int) -> None:
     import ray_tpu
     rec.sent = time.time()
     try:
@@ -156,6 +164,7 @@ def _stream_one(handle, rec: _Record, prompt: List[int], vocab: int) -> None:
         for ref in gen:
             tok = ray_tpu.get(ref, timeout=DRAIN_DEADLINE_S)
             rec.stamps.append(time.time())
+            rec.tokens.append(tok)
             if not (isinstance(tok, int) and 0 <= tok < vocab):
                 rec.bad_token = True
     except Exception as e:            # counted as a failed request
@@ -176,94 +185,11 @@ def _wait_warm(handle, deadline_s: float) -> Dict[str, Any]:
         time.sleep(0.25)
 
 
-def _client_count(tr: Dict[str, Any], sv: Dict[str, Any]) -> int:
-    """Requests in flight at most: the closed loop's callers, or the open
-    loop's thread pool."""
-    if tr["kind"] == "open_loop":
-        return int(tr["max_in_flight"])
-    return int(tr.get("clients") or tr["clients_per_slot"] * sv["num_slots"])
+def send_fn(tr: Dict[str, Any]):
+    return {"unary": unary_one, "stream": stream_one}[tr["reply"]]
 
 
-def _send_fn(tr: Dict[str, Any]):
-    return {"unary": _unary_one, "stream": _stream_one}[tr["reply"]]
-
-
-def _closed_loop(handle, tr, sv, vocab, rng, seconds, on_window):
-    send = _send_fn(tr)
-    clients = _client_count(tr, sv)
-    first_wave = min(clients, sv["num_slots"])
-    plan = traffic.closed_loop_plan(tr, first_wave, rng)
-    prompts = [traffic.prompt_tokens(p, vocab, rng) for p, _ in plan]
-    records: List[_Record] = []
-    lock = threading.Lock()
-    stop = threading.Event()
-
-    def client() -> None:
-        while not stop.is_set():
-            with lock:
-                i = len(records)
-                p, o = plan[i % len(plan)]
-                rec = _Record(i, p, o, due=time.time())
-                records.append(rec)
-            send(handle, rec, prompts[i % len(plan)], vocab)
-
-    threads = [threading.Thread(target=client, daemon=True,
-                                name=f"bench-client-{i}")
-               for i in range(clients)]
-    for t in threads:
-        t.start()
-    # The window opens when the whole first wave has been admitted (the
-    # ramp is set-up): streamed, when each has its first token; unary,
-    # when the first (shortest, see traffic.stagger) reply is back.
-    need = first_wave if tr["reply"] == "stream" else 1
-    while True:
-        with lock:
-            wave = records[:first_wave]
-        if len(wave) == first_wave and sum(
-                1 for r in wave if r.stamps or r.error) >= need:
-            break
-        time.sleep(0.005)
-    t0 = time.time()
-    on_window(t0)
-    time.sleep(seconds)
-    stop.set()
-    for t in threads:
-        t.join(timeout=DRAIN_DEADLINE_S)
-    # Every request sent is followed to its end and checked.
-    return t0, records, {"clients": clients}
-
-
-def _open_loop(handle, tr, sv, vocab, rng, seconds, on_window):
-    send = _send_fn(tr)
-    plan = traffic.open_loop_plan(tr, seconds, rng)
-    prompts = [traffic.prompt_tokens(p, vocab, rng) for _, p, _ in plan]
-    records = [_Record(i, p, o, due) for i, (due, p, o) in enumerate(plan)]
-    pool = ThreadPoolExecutor(max_workers=_client_count(tr, sv),
-                              thread_name_prefix="bench-client")
-    t0 = time.time() + 0.05
-    on_window(t0)
-    futures = []
-    for rec, prompt in zip(records, prompts):
-        rec.due += t0                       # timed from when it was DUE
-        delay = rec.due - time.time()
-        if delay > 0:
-            time.sleep(delay)
-        futures.append(pool.submit(send, handle, rec, prompt, vocab))
-    remaining = t0 + seconds - time.time()
-    if remaining > 0:
-        time.sleep(remaining)
-    deadline = time.time() + DRAIN_DEADLINE_S
-    for f in futures:
-        try:
-            f.result(timeout=max(deadline - time.time(), 0.1))
-        except Exception:
-            pass
-    pool.shutdown(wait=False, cancel_futures=True)
-    return t0, records, {"offered": len(records),
-                         "rate_per_s": tr["rate_per_s"]}
-
-
-def _tokens_in_window(r: "_Record", t0: float, t1: float) -> float:
+def _tokens_in_window(r: "Record", t0: float, t1: float) -> float:
     """Output tokens of `r` that fall inside [t0, t1].  Streamed: by each
     token's own stamp.  Unary: the reply's tokens spread evenly from its
     first token (sent + the reply's own ttft_breakdown) to its arrival —
@@ -312,7 +238,8 @@ def _live_context(records, ta: float, tb: float, samples: int = 64) -> float:
     return total / samples
 
 
-def run(cell: Dict[str, Any], args, trace_dir: str) -> Dict[str, Any]:
+def run(cell: Dict[str, Any], args, trace_dir: str, scratch: str = ""
+        ) -> Dict[str, Any]:
     import ray_tpu
     from ray_tpu import serve
 
@@ -320,7 +247,9 @@ def run(cell: Dict[str, Any], args, trace_dir: str) -> Dict[str, Any]:
     sv, vocab = cfg["serve"], cfg["vocab_size"]
     seconds = float(args.seconds)
     rng = random.Random(args.seed)
-    in_flight = _client_count(tr, sv)
+    kind = spec.model_kind(cfg["kind"])
+    drive = spec.traffic_kind(tr["kind"])
+    in_flight = drive.clients(tr, sv)
     options = ({"num_cpus": 1} if args.rehearsal else {"num_tpus": 1})
     llm = serve.deployment(BenchLLM, name="bench_llm", num_replicas=1,
                            max_concurrent_queries=in_flight + 8,
@@ -334,9 +263,9 @@ def run(cell: Dict[str, Any], args, trace_dir: str) -> Dict[str, Any]:
 
     # Through the whole path once (router, stream plane), which also
     # leaves live blocks in the pool for the parity check.
-    warm = [_Record(-1 - i, 32, 12, 0.0) for i in range(WARM_REQUESTS)]
+    warm = [Record(-1 - i, 32, 12, 0.0) for i in range(WARM_REQUESTS)]
     warm_threads = [threading.Thread(
-        target=_stream_one,
+        target=stream_one,
         args=(handle, r, traffic.prompt_tokens(r.prompt_len, vocab, rng),
               vocab)) for r in warm]
     for t in warm_threads:
@@ -369,8 +298,8 @@ def run(cell: Dict[str, Any], args, trace_dir: str) -> Dict[str, Any]:
                                            daemon=True))
             tracer[-1].start()
 
-    loop = _closed_loop if tr["kind"] == "closed_loop" else _open_loop
-    t0, records, extra = loop(handle, tr, sv, vocab, rng, seconds, on_window)
+    t0, records, extra = drive.drive(handle, tr, sv, vocab, rng, seconds,
+                                     on_window)
     for t in tracer:
         t.join(timeout=400)
     if args.trace:
@@ -397,21 +326,14 @@ def run(cell: Dict[str, Any], args, trace_dir: str) -> Dict[str, Any]:
     steps = after["steps"] - before["steps"]
     delivered = sum(len(r.stamps) for r in records)
 
-    faults = []
-    for k in ("flash_err", "paged_err"):
-        if checks[k] >= reference.TOLERANCE:
-            faults.append(f"{k} {checks[k]:.3g} vs the plain reference")
-    if not args.rehearsal and not (checks["flash_is_kernel"]
-                                   and checks["paged_is_kernel"]):
-        faults.append("impl 'auto' did not lower to the Pallas kernels")
-    if checks["paged_live_positions"] <= 0:
-        faults.append("the parity check saw an empty pool")
+    faults, compared = reference.judge(kind.TOLERANCES, kind.CHECKS["serve"],
+                                       checks)
     if after["compiles"] != before["compiles"]:
         faults.append(f"{after['compiles'] - before['compiles']} "
                       f"compilations inside the window")
-    if after["params"] != model.param_counts(cfg)["total"]:
+    if after["params"] != kind.param_counts(cfg)["total"]:
         faults.append(f"{after['params']} parameters, the file's sizes "
-                      f"give {model.param_counts(cfg)['total']}")
+                      f"give {kind.param_counts(cfg)['total']}")
     wrong = [b for b in breakdowns if b["finish_reason"] != "length"]
     if wrong:
         faults.append(f"{len(wrong)} requests did not finish by length: "
@@ -429,10 +351,15 @@ def run(cell: Dict[str, Any], args, trace_dir: str) -> Dict[str, Any]:
         "device": after["device"],
         "memory_peak_bytes": after["memory_peak_bytes"],
         "window_start_unix": t0,
-        "counters": {
-            "output_tokens": float(delivered), "engine_steps": float(steps),
-            "requests": float(len(records)),
-        },
+        # Everything the engine counts, `after - before` (the ramp, the
+        # window and the drain: what every request sent caused), beside
+        # what the client alone knows.  A layer_metrics/<name>.json reads
+        # any of them by name.
+        "counters": worker_util.beside(
+            worker_util.deltas(before["engine"], after["engine"]),
+            {"output_tokens": float(delivered),
+             "engine_steps": float(steps), "requests": float(len(records)),
+             "prompt_tokens": float(sum(r.prompt_len for r in records))}),
         "series": {
             "tpot_ms": tpot, "ttft_ms": ttft, "stream_gap_ms": gaps,
             "queue_ms": [b["queue_s"] * 1e3 for b in breakdowns],
@@ -443,7 +370,8 @@ def run(cell: Dict[str, Any], args, trace_dir: str) -> Dict[str, Any]:
                   if not k.endswith("_unix")},
         "shapes": {"slots": sv["num_slots"],
                    "live_context": _live_context(records, ta, tb)},
-        "checks": dict(checks, engine_warmup_s=info["warmup_s"]),
+        "checks": dict(checks, engine_warmup_s=info["warmup_s"],
+                       compared=compared),
         "extra": dict(
             extra, generator_lateness_p95_ms=reductions.percentile(
                 lateness, 0.95),

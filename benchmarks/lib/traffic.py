@@ -1,10 +1,16 @@
 """The one general traffic generator.  A traffic mix is a data file
-(traffic/<name>.json) of one of three kinds:
+(traffic/<name>.json) whose `"kind"` names the file that offers it
+(traffic_kinds/<kind>.py, found by lib/spec.py):
 
   train_job    sequences of `seq_len` tokens, a fresh batch every step
   closed_loop  `clients` callers (or `clients_per_slot` x the engine's
                slots), each sending its next request when its reply ends
   open_loop    `rate_per_s` independent arrivals over the window
+  sessions     a closed loop of conversations behind tenants' system
+               prompts (its plan is in its own file)
+
+Here are the pieces the kinds share: lengths, pairs, arrivals, tokens, the
+closed loop's blocks and stagger.
 
 Two invariants make runs of one cell comparable (tests/test_traffic.py):
 for ANY seed the request lengths are the same multiset and, in an open

@@ -1,6 +1,9 @@
 """A training cell: one `train_job` traffic file run through
 TpuTrainer -> CompiledTrainStep in ONE worker that leases the cell's chips.
-`train_loop` runs in that worker; `run` in the jax-free driver."""
+`train_loop` runs in that worker; `run` in the jax-free driver.  What the
+architecture needs (the program's config, parameters and FLOPs from the
+file's sizes, the parity checks and their limits) is the model kind's,
+found by the configuration's `"kind"` (lib/spec.py)."""
 
 from __future__ import annotations
 
@@ -8,7 +11,7 @@ import math
 import time
 from typing import Any, Dict
 
-from benchmarks.lib import model, reference
+from benchmarks.lib import reference, spec
 
 WARMUP_STEPS = 2          # compile + one steady step, counted as set-up
 TRACE_STEPS = 3           # traced steps: the last ones of the window
@@ -32,14 +35,16 @@ def train_loop(config: Dict[str, Any]) -> None:
     if len(devices) != chips:
         raise RuntimeError(f"{len(devices)} devices for a {chips}-chip cell")
     mc, job, seed = config["config"], config["traffic"], config["seed"]
+    kind = spec.model_kind(mc["kind"])
     tr = mc["train"]
     seq, batch = job["seq_len"], tr["batch_per_chip"] * chips
 
-    cfg = tfm.TransformerConfig(**model.with_dtypes(model.transformer_kwargs(
-        mc, max_seq=seq, param_dtype=tr["param_dtype"], remat=True,
-        remat_policy=tr["remat_policy"], xent_chunk=tr["xent_chunk"],
-        attn_block_k=tr["attn_block_k"], attn_impl="flash")))
-    checks = reference.flash_parity(cfg, seed, seq=min(seq, 512))
+    cfg = tfm.TransformerConfig(**worker_util.with_dtypes(
+        kind.transformer_kwargs(
+            mc, max_seq=seq, param_dtype=tr["param_dtype"], remat=True,
+            remat_policy=tr["remat_policy"], xent_chunk=tr["xent_chunk"],
+            attn_block_k=tr["attn_block_k"], attn_impl="flash")))
+    checks = kind.parity("train", cfg, seed, seq=min(seq, 512))
 
     mesh = make_mesh(MeshSpec(fsdp=chips), devices=devices)
     step = CompiledTrainStep(cfg, mesh, optimizer=make_optimizer(
@@ -64,7 +69,7 @@ def train_loop(config: Dict[str, Any]) -> None:
                 .map_batches(make_rows)
                 .iter_device_batches(batch, sharding=step.data_sharding))
 
-    flops_per_token = model.train_flops_per_token(mc, seq)
+    flops_per_token = kind.train_flops_per_token(mc, seq)
     tel = session.get_context().telemetry(
         tokens_per_step=batch * seq, flops_per_token=flops_per_token,
         jit_fns=[step])
@@ -86,6 +91,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     seconds = config["seconds"]
     losses, recs = [], []
     compiles_before = compiles.count
+    tel_before = worker_util.numeric_leaves(tel.snapshot())
     window_start_unix = time.time()
     t0 = time.perf_counter()
     tracing = False
@@ -113,8 +119,14 @@ def train_loop(config: Dict[str, Any]) -> None:
     step_ms = [r["wall"] * 1e3 for r in recs]
     data_wait = sum(r["phases"].get("data_wait", 0.0) for r in recs)
     tokens_per_s_per_chip = steps * batch * seq / elapsed / chips
-    counters = {"data_wait_s": data_wait, "window_s": elapsed,
-                "steps": steps, "params": n_params}
+    # Everything the trainer's telemetry counts, `after - before` over the
+    # window, beside the benchmark's own: a layer_metrics/<name>.json
+    # reads any of them by name.
+    counters = worker_util.beside(
+        worker_util.deltas(tel_before,
+                           worker_util.numeric_leaves(tel.snapshot())),
+        {"data_wait_s": data_wait, "window_s": elapsed, "steps": steps,
+         "params": n_params})
     if not rehearsal:       # a utilization exists only against a real peak
         counters["train_mfu_pct"] = (
             100.0 * tokens_per_s_per_chip * flops_per_token
@@ -138,7 +150,7 @@ def train_loop(config: Dict[str, Any]) -> None:
             losses_finite=all(math.isfinite(x) for x in losses),
             compiles_in_window=compiles_in_window,
             state_devices=state_devices, state_sharded=sharded,
-            params=n_params, params_expected=model.param_counts(mc)["total"]),
+            params=n_params, params_expected=kind.param_counts(mc)["total"]),
     })
 
 
@@ -168,11 +180,9 @@ def run(cell: Dict[str, Any], args, trace_dir: str, storage: str
     if not rep or "checks" not in rep:
         raise RuntimeError("the train worker reported nothing")
     c = rep["checks"]
-    faults = []
-    if c["flash_err"] >= reference.TOLERANCE:
-        faults.append(f"flash vs plain reference: {c['flash_err']:.3g}")
-    if not rehearsal and not c["flash_is_kernel"]:
-        faults.append("attention impl 'auto' did not lower to the kernel")
+    kind = spec.model_kind(cell["config"]["kind"])
+    faults, c["compared"] = reference.judge(
+        kind.TOLERANCES, kind.CHECKS["train"], c)
     if abs(c["first_loss"] - c["ln_vocab"]) >= 1.5:
         faults.append(f"first loss {c['first_loss']:.3f} is not near "
                       f"ln(vocab) {c['ln_vocab']:.3f}")

@@ -32,6 +32,51 @@ class CompileCounter:
                 self.count += 1
 
 
+def with_dtypes(kw: Dict[str, Any]) -> Dict[str, Any]:
+    """A kind's transformer_kwargs carry dtypes as strings (they are made
+    in the jax-free driver too): here they become jnp dtypes."""
+    import jax.numpy as jnp
+    out = dict(kw)
+    for k in ("dtype", "param_dtype"):
+        out[k] = jnp.dtype(out[k]).type
+    return out
+
+
+def numeric_leaves(tree: Dict[str, Any], prefix: str = ""
+                   ) -> Dict[str, float]:
+    """Every number in a nested dict of the program's counters, under its
+    dotted path ("prefix_cache.hit_tokens"); strings, lists, None and
+    booleans are left out."""
+    out: Dict[str, float] = {}
+    for k, v in tree.items():
+        if isinstance(v, dict):
+            out.update(numeric_leaves(v, f"{prefix}{k}."))
+        elif isinstance(v, (int, float)) and not isinstance(v, bool):
+            out[f"{prefix}{k}"] = float(v)
+    return out
+
+
+def deltas(before: Dict[str, float], after: Dict[str, float]
+           ) -> Dict[str, float]:
+    """`after - before` of every number both readings hold, under its
+    dotted path.  For a count that is what the run caused; for a gauge
+    (`prefix_cache.cached_blocks`, `blocks.used`) it is only the change
+    from the first reading to the last, and for a constant 0: a metric
+    file names the counts."""
+    return {k: after[k] - before[k] for k in after if k in before}
+
+
+def beside(program: Dict[str, float], own: Dict[str, float]
+           ) -> Dict[str, float]:
+    """The program's counters and the benchmark's own in one table.  A name
+    both hold stays the benchmark's (a metric file that reads it keeps its
+    meaning when a later PR makes the program count something under that
+    name) and the program's number is then under `engine.<name>`: nothing is
+    shadowed, and no later PR has to edit the benchmark."""
+    out = {(f"engine.{k}" if k in own else k): v for k, v in program.items()}
+    return {**out, **own}
+
+
 def device_info(require_tpu: bool) -> Dict[str, Any]:
     import jax
     devs = jax.devices()

@@ -21,6 +21,7 @@ import time
 T_START = time.time()       # set-up is counted from here
 
 import argparse             # noqa: E402
+import importlib            # noqa: E402
 import json                 # noqa: E402
 import os                   # noqa: E402
 import shutil               # noqa: E402
@@ -97,13 +98,6 @@ def main() -> int:
                     help="leave the .xplane.pb in .bench_trace/")
     args = ap.parse_args()
 
-    from benchmarks.lib import reductions, spec
-    cell = spec.load_cell(args.workload, args.traffic, args.benchmark)
-    if args.seconds is None:
-        args.seconds = float(cell["run_seconds"])
-    chips = cell["cell"]["chips"]
-    kind = cell["traffic"]["kind"]
-
     try:
         import ray_tpu
         from ray_tpu._private.accelerators import (detect_num_chips,
@@ -111,6 +105,16 @@ def main() -> int:
     except ImportError as e:
         log(f"the system under test is not here: {e}")
         return 3
+    # Every name the cell gives (configuration, model kind, traffic mix and
+    # kind, per-layer metrics, cost functions) is resolved here, before a
+    # worker starts: an unknown one fails with the list of what was found.
+    from benchmarks.lib import reductions, spec
+    cell = spec.load_cell(args.workload, args.traffic, args.benchmark)
+    if args.seconds is None:
+        args.seconds = float(cell["run_seconds"])
+    chips = cell["cell"]["chips"]
+    runner = importlib.import_module(
+        "benchmarks.lib." + spec.traffic_kind(cell["traffic"]["kind"]).CELL)
     if args.rehearsal:
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
@@ -141,14 +145,7 @@ def main() -> int:
         tempfile.gettempdir(), "ray_tpu")})
     out = None
     try:
-        if kind == "train_job":
-            from benchmarks.lib import train_cell
-            out = train_cell.run(cell, args, trace_dir, scratch)
-        elif kind in ("closed_loop", "open_loop"):
-            from benchmarks.lib import serve_cell
-            out = serve_cell.run(cell, args, trace_dir)
-        else:
-            raise ValueError(f"traffic kind {kind!r}")
+        out = runner.run(cell, args, trace_dir, scratch)
     except BaseException as e:
         import traceback
         traceback.print_exc()
@@ -163,6 +160,8 @@ def main() -> int:
         return 1
 
     rep = out["report"]
+    for name, (value, limit) in rep["checks"].get("compared", {}).items():
+        log(f"compared: {name} {value:.4g}, limit {limit:g}")
     for f in out["faults"]:
         log(f"not correct: {f}")
     values = dict(out["end_to_end"],
@@ -170,7 +169,8 @@ def main() -> int:
     if args.trace:
         obs = {"counters": rep["counters"], "series": rep["series"],
                "trace": rep["trace"], "config": cell["config"],
-               "shapes": rep["shapes"], "device_kind": rep["device"]["kind"]}
+               "shapes": rep["shapes"], "device_kind": rep["device"]["kind"],
+               "cost_fns": cell["cost_fns"]}
         metrics = {}
         for m in cell["layer_metrics"]:
             v = reductions.read_metric(m, obs)
@@ -184,7 +184,8 @@ def main() -> int:
     line = {"correct": not out["faults"], "attempted": out["attempted"],
             "failed": out["failed"], "metrics": metrics, "device": device,
             "workload": args.workload, "seed": args.seed,
-            "checks": rep["checks"], "extra": rep.get("extra", {})}
+            "checks": rep["checks"], "counters": rep["counters"],
+            "extra": rep.get("extra", {})}
     if args.trace and rep["trace"]:
         from benchmarks.lib import trace_reduce
         device["busy_s"] = rep["trace"]["busy_s"]

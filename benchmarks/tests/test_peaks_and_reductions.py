@@ -3,7 +3,9 @@ import os
 
 import pytest
 
-from benchmarks.lib import model, peaks, reductions, spec, trace_reduce
+from benchmarks.lib import peaks, reductions, spec, trace_reduce
+
+model = spec.model_kind("dense-llama")
 
 
 def test_unknown_device_is_an_error():
@@ -88,9 +90,9 @@ def test_every_metric_and_cell_of_the_benchmark_resolves():
     e2e = {m["name"] for m in bench["end_to_end"]}
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"])
-        assert cell["traffic"]["kind"] in ("train_job", "closed_loop",
-                                           "open_loop")
-        model.check(cell["config"])
+        assert spec.traffic_kind(cell["traffic"]["kind"]).CELL in (
+            "train_cell", "serve_cell")
+        spec.model_kind(cell["config"]["kind"]).check(cell["config"])
         names = {m["name"] for m in cell["end_to_end"]}
         assert "setup_s" in names and len(names) >= 2
         assert cell["layer_metrics"]
