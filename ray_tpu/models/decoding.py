@@ -23,6 +23,12 @@ the decode loop itself, shaped for XLA:
 
 Everything reuses transformer.py's parameter layout (init_params),
 norms and RoPE, so any trained checkpoint serves unchanged.
+
+Arch "afmoe" (models/afmoe.py: window and full layers mixed, expert
+layers) has its paged steps at the end of this file, built from that
+module's one layer definition; the public entry points
+(paged_prefill_decode_packed, paged_decode_steps, paged_decode_step)
+branch to them and return the expert layers' counts as one more value.
 """
 
 from __future__ import annotations
@@ -394,6 +400,8 @@ class PagedDecodeCaches(NamedTuple):
 
     kp: jax.Array            # [L, NB, Hkv, bs, Dh] block pool — (bs, Dh)
     vp: jax.Array            # minor: the tile the paged kernel loads
+    # (arch "afmoe", whose layers are unrolled: a tuple of L pools
+    # [NB, Hkv, bs, Dh], each its own buffer, written in place)
     block_tables: jax.Array  # [B, W] int32 — physical block per logical
     lengths: jax.Array       # [B] int32 — tokens currently cached
     last_token: jax.Array    # [B] int32 — input to the next decode step
@@ -412,9 +420,15 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
     w = paged_table_width(max_len, block_size)
     shape = (cfg.n_layers, num_blocks + 1, cfg.kv_heads, block_size,
              cfg.head_dim)
+
+    def pools():
+        if cfg.arch == "afmoe":
+            return tuple(jnp.zeros(shape[1:], cfg.dtype)
+                         for _ in range(cfg.n_layers))
+        return jnp.zeros(shape, cfg.dtype)
+
     return PagedDecodeCaches(
-        kp=jnp.zeros(shape, cfg.dtype),
-        vp=jnp.zeros(shape, cfg.dtype),
+        kp=pools(), vp=pools(),
         block_tables=jnp.zeros((num_slots, w), jnp.int32),
         lengths=jnp.zeros((num_slots,), jnp.int32),
         last_token=jnp.zeros((num_slots,), jnp.int32))
@@ -501,7 +515,12 @@ def paged_decode_step(params: Dict[str, Any], caches: PagedDecodeCaches,
                       active: jax.Array, cfg: TransformerConfig,
                       attn_impl: str = "auto"
                       ) -> Tuple[PagedDecodeCaches, jax.Array]:
-    """One token for every slot; returns (caches', next_tokens [B])."""
+    """One token for every slot; returns (caches', next_tokens [B]);
+    arch "afmoe" also its expert layers' counts (afmoe.MOE_COUNTS)."""
+    if cfg.arch == "afmoe":
+        caches, tok, _, counts = _afmoe_decode_core(
+            params, caches, active, cfg, attn_impl)
+        return caches, tok, counts
     return _paged_decode_core(params, caches, active, cfg, attn_impl)
 
 
@@ -513,7 +532,11 @@ def paged_decode_steps(params: Dict[str, Any], caches: PagedDecodeCaches,
                        num_steps: int, attn_impl: str = "auto"
                        ) -> Tuple[PagedDecodeCaches, jax.Array]:
     """num_steps tokens per slot in ONE dispatch (lax.scan): returns
-    (caches', tokens [num_steps, B])."""
+    (caches', tokens [num_steps, B]); arch "afmoe" also its expert
+    layers' counts."""
+    if cfg.arch == "afmoe":
+        return _afmoe_decode_scan(params, caches, active, cfg, num_steps,
+                                  attn_impl)
 
     def body(c, _):
         return _paged_decode_core(params, c, active, cfg, attn_impl)
@@ -638,6 +661,13 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
       rows 0..N-1: [suffix_tokens[0:P] | suffix_len | prefix_len |
                     slot | valid | block_table[0:W]]
       row  N:      active mask for the B decode slots in cols 0..B-1.
+
+    `valid` 1: the row ends its prompt, its slot decodes from this
+    dispatch on.  2: a chunk of a prompt longer than P with more to come:
+    its K/V are written, its slot stays out of the decode steps, and a
+    later dispatch brings the next chunk with prefix_len moved on (the
+    host loop: serve/llm.py).  Arch "afmoe" returns its expert layers'
+    counts as a fourth value.
     """
     P = prompt_pad
     B = caches.lengths.shape[0]
@@ -648,14 +678,214 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
     slots = packed[:-1, P + 2]
     valid = packed[:-1, P + 3] > 0
     new_bt = packed[:-1, P + 4:P + 4 + W]
+    starts = packed[:-1, P + 3] == 1
     active = packed[-1, :B] > 0
+    if cfg.arch == "afmoe":
+        caches, first, counts = _afmoe_prefill_core(
+            params, caches, tokens, suffix_lens, prefix_lens, slots, valid,
+            new_bt, cfg, attn_impl)
+        active = active.at[slots].set(
+            jnp.where(starts, True, active[slots]))
+        caches, toks, more = _afmoe_decode_scan(
+            params, caches, active, cfg, num_steps, attn_impl)
+        return caches, first, toks, counts + more
     caches, first = _paged_prefill_core(params, caches, tokens,
                                         suffix_lens, prefix_lens, slots,
                                         valid, new_bt, cfg)
-    active = active.at[slots].set(jnp.where(valid, True, active[slots]))
+    active = active.at[slots].set(jnp.where(starts, True, active[slots]))
 
     def body(c, _):
         return _paged_decode_core(params, c, active, cfg, attn_impl)
 
     caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
     return caches, first, toks
+
+
+# ===========================================================================
+# arch "afmoe": the paged steps over models/afmoe.py's one layer definition
+# ===========================================================================
+# The layers are unrolled (their kinds differ in shape) and each has a pool
+# of its own.  `paged_prefill_layer` and `paged_decode_layer` are what the
+# engine's dispatches are made of, one layer at a time: a caller that
+# cannot hold every layer's weights at once (the benchmark's comparison
+# with the plain reference at published widths) runs these very functions
+# layer by layer.
+
+
+class PrefillRows(NamedTuple):
+    """What every layer of one prefill needs of its rows (N rows of P)."""
+
+    positions: jax.Array     # [N, P] absolute positions
+    tables: jax.Array        # [N, W] each row's block table
+    prefix_lens: jax.Array   # [N] cached before this chunk
+    suffix_lens: jax.Array   # [N] live positions of the chunk, 0: no row
+    live: jax.Array          # [N, P] bool: a real token of a valid row
+    blocks: jax.Array        # [N, P] pool block each position is written to
+    offsets: jax.Array       # [N, P] and where in it (scratch 0: not live)
+
+
+class DecodeRows(NamedTuple):
+    """What every layer of one decode step needs of its B slots."""
+
+    positions: jax.Array     # [B, 1]
+    tables: jax.Array        # [B, W]
+    context_lens: jax.Array  # [B] positions attended, the new one among
+    active: jax.Array        # [B] bool    them; 0 for a slot that is not
+    blocks: jax.Array        # [B] where the new position is written
+    offsets: jax.Array       # [B]
+
+
+def prefill_rows(tables, prefix_lens, suffix_lens, valid, P: int,
+                 block_size: int) -> PrefillRows:
+    M = tables.shape[1] * block_size
+    positions = prefix_lens[:, None] + jnp.arange(P, dtype=jnp.int32)
+    live = valid[:, None] & (jnp.arange(P)[None, :] < suffix_lens[:, None])
+    abs_pos = jnp.minimum(positions, M - 1)
+    blocks = jnp.take_along_axis(tables, abs_pos // block_size, axis=1)
+    return PrefillRows(positions, tables, prefix_lens,
+                       jnp.where(valid, suffix_lens, 0), live,
+                       jnp.where(live, blocks, 0), abs_pos % block_size)
+
+
+def decode_rows(tables, lengths, active, block_size: int) -> DecodeRows:
+    B = lengths.shape[0]
+    M = tables.shape[1] * block_size
+    pos_c = jnp.minimum(lengths, M - 1)
+    blocks = jnp.where(active, tables[jnp.arange(B), pos_c // block_size], 0)
+    return DecodeRows(lengths[:, None], tables,
+                      jnp.where(active, jnp.minimum(lengths + 1, M), 0),
+                      active, blocks, pos_c % block_size)
+
+
+def _write_rows(pool, blocks, offsets, new):
+    """pool [NB, Hkv, bs, D] with new [..., Hkv, D] written at (blocks,
+    :, offsets) [...].  As a scatter of D-wide rows into the pool seen as
+    [NB * Hkv * bs, D]: a scatter indexed on dimensions 0 and 2 makes XLA
+    keep the pool in a layout of its own and copy the WHOLE pool to and
+    from the kernel's on every layer and step.  Positions that are not
+    live arrive here pointed at the scratch block 0, which nothing reads
+    unmasked, so they are written like any other (no gather of what was
+    there)."""
+    NB, hkv, bs, D = pool.shape
+    at = ((blocks[..., None] * hkv + jnp.arange(hkv)) * bs
+          + offsets[..., None]).reshape(-1)
+    return pool.reshape(NB * hkv * bs, D).at[at].set(
+        new.reshape(-1, D).astype(pool.dtype)).reshape(pool.shape)
+
+
+def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
+                        rows: PrefillRows, attn_impl: str = "auto",
+                        tap=None):
+    """One layer over a chunk x [N, P, D]: its K/V go into the pool, its
+    queries attend to the pool (prefix and chunk alike, under the layer's
+    window).  -> (x', k_pool', v_pool', afmoe.MOE_COUNTS)."""
+    from ray_tpu.models import afmoe
+    from ray_tpu.ops import paged_attention as _pa
+    pools = []
+
+    def attend(q, k, v):
+        kp = _write_rows(k_pool, rows.blocks, rows.offsets, k)
+        vp = _write_rows(v_pool, rows.blocks, rows.offsets, v)
+        pools.extend((kp, vp))
+        return _pa.prefix_attention(
+            q, kp, vp, rows.tables, rows.prefix_lens, rows.suffix_lens,
+            impl=attn_impl, window=afmoe.window_of(cfg, kind))
+
+    x, counts = afmoe.layer(cfg, kind, p, x, rows.positions, attend,
+                            valid=rows.live, moe_name="moe_experts_prefill",
+                            tap=tap)
+    return x, pools[0], pools[1], counts
+
+
+def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
+                       rows: DecodeRows, attn_impl: str = "auto", tap=None):
+    """One layer over one new position per slot, x [B, 1, D]."""
+    from ray_tpu.models import afmoe
+    from ray_tpu.ops import paged_attention as _pa
+    pools = []
+
+    def attend(q, k, v):
+        kp = _write_rows(k_pool, rows.blocks, rows.offsets, k[:, 0])
+        vp = _write_rows(v_pool, rows.blocks, rows.offsets, v[:, 0])
+        pools.extend((kp, vp))
+        return _pa.paged_attention(
+            q[:, 0], kp, vp, rows.tables, rows.context_lens,
+            impl=attn_impl, window=afmoe.window_of(cfg, kind))[:, None]
+
+    x, counts = afmoe.layer(cfg, kind, p, x, rows.positions, attend,
+                            valid=rows.active[:, None],
+                            moe_name="moe_experts_decode", tap=tap)
+    return x, pools[0], pools[1], counts
+
+
+def _afmoe_layers(cfg, params, caches, x, rows, layer_fn, attn_impl):
+    from ray_tpu.models import afmoe
+    kps, vps, counts = [], [], afmoe.no_counts()
+    for i, (kind, p) in enumerate(zip(cfg.layer_kinds, params["layers"])):
+        x, kp, vp, c = layer_fn(cfg, kind, p, x, caches.kp[i], caches.vp[i],
+                                rows, attn_impl)
+        kps.append(kp)
+        vps.append(vp)
+        counts = counts + c
+    return x, tuple(kps), tuple(vps), counts
+
+
+def _afmoe_decode_core(params, caches: PagedDecodeCaches, active, cfg,
+                       attn_impl):
+    """One decode step; -> (caches', next tokens [B], logits [B, V],
+    counts).  Slots that are not active attend to nothing, write to the
+    scratch block and are routed to no expert."""
+    from ray_tpu.models import afmoe
+    rows = decode_rows(caches.block_tables, caches.lengths, active,
+                       caches.kp[0].shape[2])
+    x = afmoe.embed(cfg, params["tok_embed"], caches.last_token[:, None])
+    x, kps, vps, counts = _afmoe_layers(cfg, params, caches, x, rows,
+                                        paged_decode_layer, attn_impl)
+    logits = afmoe.logits(cfg, params, x[:, 0])
+    next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return PagedDecodeCaches(
+        kp=kps, vp=vps, block_tables=caches.block_tables,
+        lengths=jnp.where(active, caches.lengths + 1, caches.lengths),
+        last_token=jnp.where(active, next_tok, caches.last_token)
+    ), next_tok, logits, counts
+
+
+def _afmoe_decode_scan(params, caches, active, cfg, num_steps, attn_impl):
+    def body(carry, _):
+        c, counts = carry
+        c, tok, _, more = _afmoe_decode_core(params, c, active, cfg,
+                                             attn_impl)
+        return (c, counts + more), tok
+
+    from ray_tpu.models import afmoe
+    (caches, counts), toks = jax.lax.scan(
+        body, (caches, afmoe.no_counts()), None, length=num_steps)
+    return caches, toks, counts
+
+
+def _afmoe_prefill_core(params, caches: PagedDecodeCaches, tokens,
+                        suffix_lens, prefix_lens, slots, valid, new_bt, cfg,
+                        attn_impl):
+    """`_paged_prefill_core` for arch "afmoe": -> (caches', first tokens
+    [N], counts).  Rows and positions that are padding are routed to no
+    expert."""
+    from ray_tpu.models import afmoe
+    N, P = tokens.shape
+    bt = caches.block_tables.at[slots].set(
+        jnp.where(valid[:, None], new_bt, caches.block_tables[slots]))
+    rows = prefill_rows(bt[slots], prefix_lens, suffix_lens, valid, P,
+                        caches.kp[0].shape[2])
+    x = afmoe.embed(cfg, params["tok_embed"], tokens)
+    x, kps, vps, counts = _afmoe_layers(cfg, params, caches, x, rows,
+                                        paged_prefill_layer, attn_impl)
+    last = x[jnp.arange(N), jnp.clip(suffix_lens - 1, 0, P - 1)]
+    first_tok = jnp.argmax(afmoe.logits(cfg, params, last),
+                           axis=-1).astype(jnp.int32)
+    return PagedDecodeCaches(
+        kp=kps, vp=vps, block_tables=bt,
+        lengths=caches.lengths.at[slots].set(
+            jnp.where(valid, prefix_lens + suffix_lens,
+                      caches.lengths[slots])),
+        last_token=caches.last_token.at[slots].set(
+            jnp.where(valid, first_tok, caches.last_token[slots]))
+    ), first_tok, counts

@@ -41,7 +41,7 @@ class TransformerConfig:
     n_kv_heads: Optional[int] = None      # None => MHA
     d_ff: Optional[int] = None            # None => arch default
     max_seq: int = 2048
-    arch: str = "llama"                   # "llama" | "gpt2"
+    arch: str = "llama"                   # "llama" | "gpt2" | "afmoe"
     rope_theta: float = 500_000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16             # activation/compute dtype
@@ -62,6 +62,34 @@ class TransformerConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # A head size of its own (None => d_model // n_heads).
+    d_head: Optional[int] = None
+    # arch "afmoe" (models/afmoe.py; serving and `forward` only): one
+    # (mixer, feed-forward) pair per layer, mixer "sliding" | "full",
+    # feed-forward "dense" | "experts".  Sliding layers see the last
+    # `sliding_window` positions and carry the rotary embedding; expert
+    # layers route every token to `moe_top_k` of `moe_experts` experts of
+    # width `moe_d_ff` by sigmoid scores (no capacity, no dropped token)
+    # and add `moe_shared_experts` shared ones; `d_ff` is the dense width.
+    layer_kinds: Optional[Tuple[Tuple[str, str], ...]] = None
+    sliding_window: int = 0
+    moe_d_ff: Optional[int] = None
+    moe_shared_experts: int = 0
+    moe_route_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.layer_kinds is not None:
+            kinds = tuple((str(m), str(f)) for m, f in self.layer_kinds)
+            object.__setattr__(self, "layer_kinds", kinds)  # hashable
+        if self.arch == "afmoe":
+            kinds = self.layer_kinds or ()
+            if len(kinds) != self.n_layers or any(
+                    m not in ("sliding", "full")
+                    or f not in ("dense", "experts") for m, f in kinds):
+                raise ValueError(
+                    f"afmoe needs one (sliding|full, dense|experts) pair "
+                    f"per layer, got {self.layer_kinds!r} for "
+                    f"{self.n_layers} layers")
 
     @property
     def kv_heads(self) -> int:
@@ -69,7 +97,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
 
     @property
     def ff_dim(self) -> int:
@@ -97,7 +125,8 @@ PRESETS: Dict[str, TransformerConfig] = {
                                   d_ff=14_336, max_seq=8192),
     # BASELINE.json config #3 ("Mixtral 8x7B MoE expert-parallel"):
     # Mixtral-shaped MoE — 8 experts, top-2 routing, expert-parallel
-    # over the `ep` mesh axis.
+    # over the `ep` mesh axis.  No path can serve it (decoding._mlp has no
+    # expert branch for it) and its training path drops tokens over capacity.
     "mixtral-8x7b": TransformerConfig(vocab_size=32_000, d_model=4096,
                                       n_layers=32, n_heads=32,
                                       n_kv_heads=8, d_ff=14_336,
@@ -110,7 +139,11 @@ PRESETS: Dict[str, TransformerConfig] = {
 # init
 # ---------------------------------------------------------------------------
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
-    """Returns the parameter pytree (per-layer params stacked on axis 0)."""
+    """Returns the parameter pytree (per-layer params stacked on axis 0;
+    arch "afmoe": a tuple of per-layer trees, models/afmoe.py)."""
+    if cfg.arch == "afmoe":
+        from ray_tpu.models import afmoe
+        return afmoe.init_params(cfg, key)
     keys = jax.random.split(key, 8)
     d, h, hkv, dh, f = (cfg.d_model, cfg.n_heads, cfg.kv_heads,
                         cfg.head_dim, cfg.ff_dim)
@@ -174,6 +207,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
 
 def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Pytree (matching init_params) of logical axis-name tuples."""
+    if cfg.arch == "afmoe":
+        from ray_tpu.models import afmoe
+        return afmoe.logical_axes(cfg)
     layer = {
         "attn_norm": ("embed",),
         "wq": ("embed", "heads", "head_dim"),
@@ -408,6 +444,10 @@ def forward_hidden_aux(params: Dict[str, Any], tokens: jax.Array,
                        ) -> Tuple[jax.Array, jax.Array]:
     """tokens: [B, S] int32 -> (final-norm hidden [B, S, D],
     summed MoE aux loss — zero for dense models)."""
+    if cfg.arch == "afmoe":
+        from ray_tpu.models import afmoe
+        return (afmoe.forward_hidden(params, tokens, cfg),
+                jnp.zeros((), jnp.float32))
     B, S = tokens.shape
     # Shard the indices BEFORE the lookup: a replicated-index gather from
     # the (vocab/embed)-sharded table comes out embed-sharded, and moving
@@ -505,6 +545,12 @@ def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Next-token cross-entropy (+ MoE load-balance aux when MoE).
     tokens: [B, S]; predicts tokens[:,1:]."""
+    if cfg.arch == "afmoe":
+        # No quiet fall-back to _moe_block's capacity routing: that drops
+        # tokens, and this architecture's routing drops none.
+        raise NotImplementedError(
+            "arch 'afmoe' has no training path: its expert layer has no "
+            "backward pass yet (serving and `forward` only)")
     targets = tokens[:, 1:]
     if cfg.xent_chunk is None:
         x, aux = forward_hidden_aux(params, tokens[:, :-1], cfg, mesh)

@@ -50,6 +50,13 @@ tiles, which a (1, D) slice of a [.., Hkv, D] pool is not.
 Pool block 0 is reserved as a scratch/null block by the engine (table
 padding and retired-slot writes are redirected there), so garbage reads
 through padded table entries are always masked by context_lens.
+
+A sliding-window layer passes `window`: only the last `window` positions
+are attended, and the kernel's page stream starts at the group that
+holds the first of them.  The second half of this file is
+`prefix_attention`: a prefill chunk's queries over their paged prefix,
+group by group with a running softmax, for prompts longer than one
+prefill.
 """
 
 from __future__ import annotations
@@ -80,7 +87,8 @@ _VMEM_BUDGET = 8 * 2 ** 20
 def paged_attention_reference(q: jax.Array, k_pool: jax.Array,
                               v_pool: jax.Array, block_tables: jax.Array,
                               context_lens: jax.Array,
-                              scale: Optional[float] = None) -> jax.Array:
+                              scale: Optional[float] = None,
+                              window: Optional[int] = None) -> jax.Array:
     """Gather-based paged attention (the CPU/tier-1 path).
 
     Gathers each sequence's blocks into a [B, Hkv, W*bs, D] window with
@@ -94,17 +102,19 @@ def paged_attention_reference(q: jax.Array, k_pool: jax.Array,
     M = W * bs
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
-    def window(pool):       # [B, W, Hkv, bs, D] -> [B, Hkv, M, D]
+    def rows(pool):         # [B, W, Hkv, bs, D] -> [B, Hkv, M, D]
         return jnp.take(pool, block_tables, axis=0).transpose(
             0, 2, 1, 3, 4).reshape(B, hkv, M, D)
 
-    k, v = window(k_pool), window(v_pool)
+    k, v = rows(k_pool), rows(v_pool)
     groups = H // hkv
     qg = q.reshape(B, hkv, groups, D)
     s = jnp.einsum("bhgk,bhmk->bhgm", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    mask = (jnp.arange(M)[None, :] < context_lens[:, None]
-            )[:, None, None, :]                              # [B,1,1,M]
+    mask = jnp.arange(M)[None, :] < context_lens[:, None]
+    if window is not None:      # the query sits at context_lens - 1
+        mask &= jnp.arange(M)[None, :] >= context_lens[:, None] - window
+    mask = mask[:, None, None, :]                            # [B,1,1,M]
     s = jnp.where(mask, s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
     # A zero-length row's softmax is all-NaN (every score -inf); the
@@ -128,9 +138,11 @@ def _pages_per_group(W, hkv, bs, D, itemsize):
     return pages
 
 
-def _attend_group(q, k, v, first_pos, ctx, scale, m_ref, l_ref, acc_ref):
+def _attend_group(q, k, v, first_pos, ctx, scale, m_ref, l_ref, acc_ref,
+                  lo=None):
     """Online-softmax update with one group of cached positions.
-    q: (Hkv, G, D); k, v: (Hkv, span, D), position `first_pos` first.
+    q: (Hkv, G, D); k, v: (Hkv, span, D), position `first_pos` first;
+    positions in [lo, ctx) are attended (lo None: from the first).
     Scores are (Hkv, G, span) with the running max / normalizer as
     (Hkv, G, 1) columns, so every rescale broadcasts along lanes.
     Softmax, accumulation and p . v are f32, like the reference; q and
@@ -139,11 +151,24 @@ def _attend_group(q, k, v, first_pos, ctx, scale, m_ref, l_ref, acc_ref):
     s = jnp.einsum("hgd,htd->hgt", q, k.astype(q.dtype),
                    preferred_element_type=jnp.float32) * scale
     kpos = first_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(kpos < ctx, s, NEG_INF)
+    seen = kpos < ctx if lo is None else (kpos < ctx) & (kpos >= lo)
+    _softmax_update(s, seen, v, m_ref, l_ref, acc_ref)
+
+
+def _softmax_update(s, seen, v, m_ref, l_ref, acc_ref, rows_differ=False):
+    """s: (Hkv, R, span) f32 scores, `seen` which of them count.  Where
+    every row sees something in the first group it meets (one query a
+    sequence), a masked score's exp(NEG_INF - m) is 0 by itself; with
+    `rows_differ` (a chunk's queries, each with its own causal and window
+    bounds) a row may have seen nothing yet, keeps m at NEG_INF, and its
+    masked scores would count exp(0): they are set to 0."""
+    s = jnp.where(seen, s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
+    if rows_differ:
+        p = jnp.where(seen, p, 0.0)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
     m_ref[...] = m_new
     acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
@@ -163,9 +188,33 @@ def _write_out(o_ref, l_ref, acc_ref):
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def _copy_pages(bt_ref, row, first, live, block_size, pools, bufs, sem,
+                slot, wait):
+    """Start (or wait for) the copies of `live` pages of `row`'s table,
+    from page `first` on, out of the HBM pools into buffer `slot`: a loop
+    over what is live, not over a group's width, in the kernel and in its
+    trace."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def page(j, carry):
+        phys = bt_ref[row, first + j]
+        dst = pl.ds(pl.multiple_of(j * block_size, block_size), block_size)
+        for i, (pool, buf) in enumerate(zip(pools, bufs)):
+            copy = pltpu.make_async_copy(
+                pool.at[phys], buf.at[slot, :, dst, :], sem.at[i, slot])
+            if wait:
+                copy.wait()
+            else:
+                copy.start()
+        return carry
+
+    jax.lax.fori_loop(0, live, page, None)
+
+
 def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                   k_buf, v_buf, sem, cursor, m_ref, l_ref, acc_ref, *,
-                  scale, block_size, pages):
+                  scale, block_size, pages, window):
     """One sequence: every kv head, a group of `pages` pages at a time.
 
     The grid is the batch, run in order, and the (sequence, group)
@@ -175,9 +224,10 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     sequence (`cursor` carries the buffer parity across programs).  A
     page with no live position starts no DMA and a sequence of length 0
     runs no group at all, so DMAs and loop trips follow what is
-    cached, not the table's width."""
+    cached, not the table's width.  With a `window` (a sliding layer) the
+    stream of a sequence starts at the group that holds its first
+    attended position, context - window, and that group is masked."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
     last_b = pl.num_programs(0) - 1
@@ -185,28 +235,20 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     ctx = len_ref[b]
     n_groups = pl.cdiv(ctx, span)
 
+    def first_group(row):
+        if window is None:
+            return 0
+        return jnp.maximum(len_ref[row] - window, 0) // span
+
+    g0 = first_group(b)
+    trips = n_groups - g0
+    lo = None if window is None else ctx - window
+
     def copy_group(row, group, slot, wait=False):
-        """Start (or wait for) the copies of the live pages of `row`'s
-        `group` into buffer `slot`: a loop over what is live, not over
-        the group's width, in the kernel and in its trace."""
         first = group * pages
         live = jnp.clip(pl.cdiv(len_ref[row], block_size) - first, 0, pages)
-
-        def page(j, carry):
-            phys = bt_ref[row, first + j]
-            dst = pl.ds(pl.multiple_of(j * block_size, block_size),
-                        block_size)
-            for i, (pool, buf) in enumerate(((k_hbm, k_buf),
-                                             (v_hbm, v_buf))):
-                copy = pltpu.make_async_copy(
-                    pool.at[phys], buf.at[slot, :, dst, :], sem.at[i, slot])
-                if wait:
-                    copy.wait()
-                else:
-                    copy.start()
-            return carry
-
-        jax.lax.fori_loop(0, live, page, None)
+        _copy_pages(bt_ref, row, first, live, block_size, (k_hbm, v_hbm),
+                    (k_buf, v_buf), sem, slot, wait)
 
     first_slot = jnp.where(b == 0, 0, cursor[0])
     next_row = jnp.minimum(b + 1, last_b)
@@ -223,37 +265,42 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     # here — and the first sequence starts its own.
     @pl.when(jnp.where(n_groups == 0, b < last_b, b == 0))
     def _():
-        copy_group(jnp.where(n_groups == 0, next_row, b), 0, first_slot)
+        row = jnp.where(n_groups == 0, next_row, b)
+        copy_group(row, first_group(row), first_slot)
 
     _reset(m_ref, l_ref, acc_ref)
 
-    def group_step(g, carry):
-        slot = (first_slot + g) % 2
-        more = g + 1 < n_groups
+    def group_step(i, carry):
+        g = g0 + i
+        slot = (first_slot + i) % 2
+        more = i + 1 < trips
 
         @pl.when(jnp.logical_or(more, b < last_b))
         def _():
             copy_group(jnp.where(more, b, next_row),
-                       jnp.where(more, g + 1, 0), 1 - slot)
+                       jnp.where(more, g + 1, first_group(next_row)),
+                       1 - slot)
 
         copy_group(b, g, slot, wait=True)
         _attend_group(q_ref[0], k_buf[slot], v_buf[slot], g * span, ctx,
-                      scale, m_ref, l_ref, acc_ref)
+                      scale, m_ref, l_ref, acc_ref, lo)
         return carry
 
-    jax.lax.fori_loop(0, n_groups, group_step, None)
-    cursor[0] = (first_slot + n_groups) % 2
+    jax.lax.fori_loop(0, trips, group_step, None)
+    cursor[0] = (first_slot + trips) % 2
     _write_out(o_ref, l_ref, acc_ref)
 
 
 def _paged_kernel_narrow(bt_ref, len_ref, q_ref, *refs, scale, block_size,
-                         pages):
+                         pages, window):
     """One (sequence, page group) program for a head size Mosaic cannot
     slice out of an HBM pool (D not a multiple of 128 lanes).  The
     group's pages are `pages` whole-page k and v blocks the pipeline
     fetched through the block table; a dead page's index map stays on
     the block it held, so it is not fetched again, and a dead group
-    does no arithmetic.  Same mathematics as `_paged_kernel`."""
+    does no arithmetic.  Same mathematics as `_paged_kernel` (a `window`
+    masks here, and skips the arithmetic of the groups before it, not
+    their fetches)."""
     from jax.experimental import pallas as pl
 
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
@@ -266,12 +313,17 @@ def _paged_kernel_narrow(bt_ref, len_ref, q_ref, *refs, scale, block_size,
     def _init():
         _reset(m_ref, l_ref, acc_ref)
 
-    @pl.when(g * span < ctx)
+    lo = None if window is None else ctx - window
+    live = g * span < ctx
+    if window is not None:
+        live = jnp.logical_and(live, (g + 1) * span > lo)
+
+    @pl.when(live)
     def _body():
         k, v = (jnp.concatenate([r[0] for r in rs], axis=1)
                 for rs in (k_refs, v_refs))
         _attend_group(q_ref[0], k, v, g * span, ctx, scale,
-                      m_ref, l_ref, acc_ref)
+                      m_ref, l_ref, acc_ref, lo)
 
     @pl.when(g == pl.num_programs(1) - 1)
     def _finish():
@@ -279,7 +331,7 @@ def _paged_kernel_narrow(bt_ref, len_ref, q_ref, *refs, scale, block_size,
 
 
 def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *, scale,
-               interpret):
+               window, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -331,7 +383,8 @@ def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *, scale,
     # The kernels walk the table as far as the lengths say.
     context_lens = jnp.minimum(context_lens.astype(jnp.int32), W * bs)
     o = pl.pallas_call(
-        functools.partial(kernel, scale=scale, block_size=bs, pages=pages),
+        functools.partial(kernel, scale=scale, block_size=bs, pages=pages,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid,
             in_specs=[pl.BlockSpec((1, hkv, gp, D), q_index)] + kv_specs,
@@ -363,9 +416,10 @@ def _validate_paged(q, k_pool, v_pool):
             f"multiple of {_SUBLANES} (the (bs, D) tile's sublane dim)")
 
 
-@functools.partial(jax.jit, static_argnames=("scale",))
+@functools.partial(jax.jit, static_argnames=("scale", "window"))
 def paged_attention_kernel(q, k_pool, v_pool, block_tables, context_lens,
-                           scale: Optional[float] = None) -> jax.Array:
+                           scale: Optional[float] = None,
+                           window: Optional[int] = None) -> jax.Array:
     """Pallas paged attention: the compiled kernel where the program is
     lowered for a TPU, the Pallas interpreter elsewhere (CPU parity
     tests).  Jitted so that a process traces the kernel once per shape:
@@ -374,17 +428,21 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, context_lens,
     programs — traced each time, the kernel was most of a warm start."""
     _validate_paged(q, k_pool, v_pool)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[2])
-    return compiled_on_tpu(functools.partial(_paged_fwd, scale=scale),
-                           q, k_pool, v_pool, block_tables, context_lens)
+    return compiled_on_tpu(
+        functools.partial(_paged_fwd, scale=scale, window=window),
+        q, k_pool, v_pool, block_tables, context_lens)
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, context_lens: jax.Array,
                     scale: Optional[float] = None,
-                    impl: str = "auto") -> jax.Array:
+                    impl: str = "auto",
+                    window: Optional[int] = None) -> jax.Array:
     """Dispatcher.  "auto" is the Pallas kernel on a TPU backend — a
     shape the kernel cannot take raises there, it never quietly becomes
-    the gather — and the gather reference on any other backend.
+    the gather — and the gather reference on any other backend.  With a
+    `window` only the last `window` positions are attended (the query,
+    at context_lens - 1, among them).
 
     Decode has no backward pass, so there is no custom VJP — the
     reference path stays differentiable by construction if anyone ever
@@ -392,12 +450,211 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     """
     if impl == "reference":
         return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                         context_lens, scale)
+                                         context_lens, scale, window)
     if impl == "kernel" or (impl == "auto"
                             and jax.default_backend() == "tpu"):
         return paged_attention_kernel(q, k_pool, v_pool, block_tables,
-                                      context_lens, scale)
+                                      context_lens, scale, window)
     if impl != "auto":
         raise ValueError(f"unknown paged attention impl {impl!r}")
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                     context_lens, scale)
+                                     context_lens, scale, window)
+
+
+# ===========================================================================
+# Prefix attention: a prefill chunk's queries over their paged prefix
+# ===========================================================================
+# A chunk of P new positions per sequence attends to what the sequence has
+# cached (its prefix, possibly thousands of positions, shared pages among
+# them) and causally to itself.  The chunk's own K/V are scattered into
+# the pool BEFORE this runs, so every key is read from the pool through
+# the block table and one position rule covers both parts:
+#
+#   key j is visible to the query at position i  iff  j <= i
+#                                   and (no window or  i - j < window)
+#
+# q:           [N, P, H, D]       queries of the chunk, position
+#                                 prefix_lens[n] + p
+# k/v pool:    [NB, Hkv, bs, D]   one layer's pool
+# block_tables [N, W]             each row's table
+# prefix_lens, suffix_lens [N]    cached before the chunk; live queries
+# -> out       [N, P, H, D]       (rows p >= suffix_lens[n]: unspecified)
+_PREFIX_SPAN = 256          # cached positions one DMA group spans
+_PREFIX_QUERIES = 128       # queries one program holds (x the GQA group)
+
+
+def prefix_attention_reference(q, k_pool, v_pool, block_tables,
+                               prefix_lens, suffix_lens,
+                               scale: Optional[float] = None,
+                               window: Optional[int] = None) -> jax.Array:
+    """Gather the whole table window and mask by position (small sizes:
+    the scores are [N, H, P, W * bs] float32)."""
+    N, P, H, D = q.shape
+    hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    M = block_tables.shape[1] * bs
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+
+    def rows(pool):         # [N, W, Hkv, bs, D] -> [N, Hkv, M, D]
+        return jnp.take(pool, block_tables, axis=0).transpose(
+            0, 2, 1, 3, 4).reshape(N, hkv, M, D).astype(jnp.float32)
+
+    k, v = rows(k_pool), rows(v_pool)
+    qg = q.reshape(N, P, hkv, H // hkv, D).astype(jnp.float32)
+    s = jnp.einsum("nphgd,nhmd->nhgpm", qg, k) * scale
+    qpos = prefix_lens[:, None] + jnp.arange(P)[None, :]        # [N, P]
+    kpos = jnp.arange(M)[None, None, :]
+    seen = (kpos <= qpos[..., None]) \
+        & (kpos < (prefix_lens + suffix_lens)[:, None, None])
+    if window is not None:
+        seen &= kpos > qpos[..., None] - window
+    seen = seen[:, None, None]                                  # [N,1,1,P,M]
+    w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    w = jnp.where(seen, w, 0.0)             # a dead query row -> zeros
+    o = jnp.einsum("nhgpm,nhmd->nphgd", w, v)
+    return o.reshape(N, P, H, D).astype(q.dtype)
+
+
+def _prefix_kernel(bt_ref, pre_ref, suf_ref, qoff_ref, q_ref, k_hbm, v_hbm,
+                   o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *,
+                   scale, block_size, pages, tq, window):
+    """One (sequence, tile of `tq` queries) program: every kv head, the
+    tile's queries of every head of the group as rows (query-major), a
+    group of `pages` pages at a time from the first position any of the
+    tile's live queries may see to the last, double-buffered.  A tile
+    with no live query does nothing."""
+    from jax.experimental import pallas as pl
+
+    n, t = pl.program_id(0), pl.program_id(1)
+    span = pages * block_size
+    pre = pre_ref[n]
+    live_q = jnp.clip(suf_ref[n] - t * tq, 0, tq)
+    first_q = pre + t * tq                    # position of the first query
+    hi = jnp.where(live_q > 0, first_q + live_q, 0)   # keys below hi
+    lo = 0 if window is None else jnp.maximum(first_q - (window - 1), 0)
+    g0 = lo // span
+    trips = jnp.maximum(pl.cdiv(hi, span) - g0, 0)
+
+    def copy_group(group, slot, wait=False):
+        first = group * pages
+        live = jnp.clip(pl.cdiv(hi, block_size) - first, 0, pages)
+        _copy_pages(bt_ref, n, first, live, block_size, (k_hbm, v_hbm),
+                    (k_buf, v_buf), sem, slot, wait)
+
+    @pl.when(jnp.logical_and(n == 0, t == 0))
+    def _first():
+        # Page slots a partly live group leaves unfilled are read under
+        # the mask with p == 0: they must hold numbers, not NaN.
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    @pl.when(trips > 0)
+    def _():
+        copy_group(g0, 0)
+
+    _reset(m_ref, l_ref, acc_ref)
+    q = q_ref[0]                              # (Hkv, tq * G, D)
+    qpos = first_q + qoff_ref[...]            # (tq * G, 1)
+
+    def group_step(i, carry):
+        g = g0 + i
+        slot = i % 2
+
+        @pl.when(i + 1 < trips)
+        def _():
+            copy_group(g + 1, 1 - slot)
+
+        copy_group(g, slot, wait=True)
+        s = jnp.einsum("hrd,htd->hrt", q, k_buf[slot].astype(q.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        kpos = g * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        seen = (kpos <= qpos[None]) & (kpos < hi)
+        if window is not None:
+            seen &= kpos > qpos[None] - window
+        _softmax_update(s, seen, v_buf[slot], m_ref, l_ref, acc_ref,
+                        rows_differ=True)
+        return carry
+
+    jax.lax.fori_loop(0, trips, group_step, None)
+    _write_out(o_ref, l_ref, acc_ref)
+
+
+def _prefix_fwd(q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
+                *, scale, window, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, P, H, D = q.shape
+    hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    W = block_tables.shape[1]
+    G = H // hkv
+    tq = min(_PREFIX_QUERIES, P)
+    if P % tq:
+        raise ValueError(f"prefix attention kernel: a chunk of {P} queries "
+                         f"must be a multiple of {tq}")
+    # (Mosaic slices a page out of an HBM pool only where the head size is
+    # a multiple of 128 lanes, and refuses any other when it compiles.)
+    pages = max(1, min(_PREFIX_SPAN // bs, W))
+    rows = tq * G
+    # query-major rows: row r of a tile is query r // G, head r % G
+    qg = q.reshape(N, P, hkv, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        N, hkv, P * G, D).astype(jnp.promote_types(q.dtype, k_pool.dtype))
+    qoff = (jnp.arange(rows, dtype=jnp.int32) // G)[:, None]
+
+    def q_index(n, t, *_):
+        return (n, 0, t, 0)
+
+    hi_all = jnp.minimum(prefix_lens + suffix_lens, W * bs).astype(jnp.int32)
+    prefix_lens = jnp.minimum(prefix_lens.astype(jnp.int32), hi_all)
+    o = pl.pallas_call(
+        functools.partial(_prefix_kernel, scale=scale, block_size=bs,
+                          pages=pages, tq=tq, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(N, P // tq),
+            in_specs=[pl.BlockSpec((rows, 1), lambda n, t, *_: (0, 0)),
+                      pl.BlockSpec((1, hkv, rows, D), q_index),
+                      pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                      pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+            out_specs=pl.BlockSpec((1, hkv, rows, D), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((2, hkv, pages * bs, D), k_pool.dtype),
+                pltpu.VMEM((2, hkv, pages * bs, D), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hkv, rows, 1), jnp.float32),
+                pltpu.VMEM((hkv, rows, 1), jnp.float32),
+                pltpu.VMEM((hkv, rows, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((N, hkv, P * G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 * 2 ** 20),
+        interpret=interpret,
+        name="prefix_attention",
+    )(block_tables.astype(jnp.int32), prefix_lens,
+      (hi_all - prefix_lens).astype(jnp.int32), qoff, qg, k_pool, v_pool)
+    return o.reshape(N, hkv, P, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        N, P, H, D)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window"))
+def prefix_attention_kernel(q, k_pool, v_pool, block_tables, prefix_lens,
+                            suffix_lens, scale: Optional[float] = None,
+                            window: Optional[int] = None) -> jax.Array:
+    _validate_paged(q[:, 0], k_pool, v_pool)
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[3])
+    return compiled_on_tpu(
+        functools.partial(_prefix_fwd, scale=scale, window=window),
+        q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens)
+
+
+def prefix_attention(q, k_pool, v_pool, block_tables, prefix_lens,
+                     suffix_lens, scale: Optional[float] = None,
+                     impl: str = "auto",
+                     window: Optional[int] = None) -> jax.Array:
+    """Dispatcher, as `paged_attention`: the kernel on a TPU backend, the
+    gather elsewhere."""
+    args = (q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
+            scale, window)
+    if impl == "kernel" or (impl == "auto"
+                            and jax.default_backend() == "tpu"):
+        return prefix_attention_kernel(*args)
+    if impl not in ("auto", "reference"):
+        raise ValueError(f"unknown prefix attention impl {impl!r}")
+    return prefix_attention_reference(*args)

@@ -33,6 +33,14 @@ multiplex.merge_adapter, LRU-resident) without recompiling — same
 shapes, new weights — and each model keys its own radix tree so prefix
 reuse never crosses models.
 
+Long prompts: `prompt_pad` is the longest prompt `submit` accepts; the
+widest prefill the paged engine compiles is min(prompt_pad,
+PREFILL_CHUNK).  A prompt (or uncached suffix) longer than that is
+prefilled in chunks over successive dispatches, each against the blocks
+the earlier chunks wrote; the request holds its slot meanwhile without
+decoding, the other slots decode on in the same dispatches, and the
+radix tree takes the prompt's blocks as they are dispatched.
+
 Pipelining (shared by both engines): a loop that synchronizes with the
 device once per step (dispatch → block on the token read → repeat)
 leaves the chip idle for every host round trip.  The engine keeps up
@@ -109,7 +117,11 @@ class _Request:
     model_id: str = ""
     cache_hit: bool = False
     cached_tokens: int = 0
-    _prefix_len: int = 0
+    # Chunked prefill (paged engine): prompt positions already sent to the
+    # cache (the matched prefix among them), and whether chunks are still
+    # to come — the request then holds its slot without decoding.
+    _prefilled: int = 0
+    _prefilling: bool = False
     # Paged bookkeeping: max total positions (prompt + generated) this
     # request's block allocation covers (0 = dense engine: global cap),
     # and the pool blocks it holds a reference on.
@@ -119,6 +131,15 @@ class _Request:
     # Set for streaming consumers: tokens are ALSO pushed here as the
     # engine processes decode reads, ending with _STREAM_END.
     stream_q: Optional["queue.Queue"] = None
+    # Called once, from the engine's thread, when the request is done (an
+    # asyncio caller's wake-up: LLMDeployment.generate).
+    _on_done: Optional[Any] = None
+
+    def _finish(self) -> None:
+        self.done.set()
+        wake = self._on_done
+        if wake is not None:
+            wake()
 
     def stream(self, timeout: float = 300.0) -> Iterator[int]:
         """Yield tokens as they are decoded (requires submit(...,
@@ -197,6 +218,13 @@ class ContinuousBatcher:
         self._shutdown = False
         self._work = threading.Event()
         self.steps = 0
+        # Where the engine's two threads spend their time, in seconds
+        # (stats()["host"]).  Dispatcher: waiting for a pipeline permit
+        # (the device is ahead: healthy), building and launching a
+        # dispatch, and starved (no live slot, nothing waiting).
+        # Processor: waiting for a dispatch's tokens, and handing them out.
+        self.host_s = {"permit_wait": 0.0, "dispatch": 0.0, "starved": 0.0,
+                       "read_wait": 0.0, "process": 0.0}
         # Device-resident active-mask cache: skips one host->device
         # transfer per decode dispatch.  In steady state the mask rarely
         # changes (drained-readmission keeps slots full), so the device
@@ -395,7 +423,7 @@ class ContinuousBatcher:
         with self._state_lock:
             if self._owner[slot] is req:
                 self._owner[slot] = None
-        req.done.set()
+        req._finish()
         if req.stream_q is not None:
             req.stream_q.put(_STREAM_END)
 
@@ -408,7 +436,7 @@ class ContinuousBatcher:
             req.error = error
         if reason:
             req.finish_reason = reason
-        req.done.set()
+        req._finish()
         if req.stream_q is not None:
             req.stream_q.put(_STREAM_END)
 
@@ -485,8 +513,10 @@ class ContinuousBatcher:
     def _fused_dispatch(self, jnp, batch: List[tuple], active,
                         chunk: int):
         """Pack + launch the fused prefill/decode for `batch`
-        ([(slot, req)]); returns (first, dtoks, admitted).  The packed
-        wire format and kernel are the engine-variant parts."""
+        ([(slot, req)]); returns ((first, dtoks, ...), rows) with rows
+        [(row, slot, req)]: the device arrays the processor reads (any
+        beyond the first two go to _count_dispatch).  The packed wire
+        format and kernel are the engine-variant parts."""
         # Two compiled widths (narrow + full), both precompiled at
         # engine start — more widths meant mid-run compile stalls.
         N = (self._narrow_width
@@ -506,23 +536,27 @@ class ContinuousBatcher:
         self.caches, first, dtoks = self._dec.prefill_decode_packed(
             self.params, self.caches, jnp.asarray(packed),
             self.cfg, chunk, P)
-        return first, dtoks, admitted
+        return (first, dtoks), admitted
 
-    def _decode_dispatch(self, chunk: int):
-        """Decode-only device step for every slot; returns dtoks
-        [chunk, B] (engine-variant kernel)."""
+    def _decode_dispatch(self, chunk: int) -> tuple:
+        """Decode-only device step for every slot; returns (dtoks
+        [chunk, B], ...) (engine-variant kernel)."""
         if chunk > 1:
             self.caches, dtoks = self._dec.decode_steps(
                 self.params, self.caches, self._active_dev,
                 self.cfg, chunk)
-            return dtoks
+            return (dtoks,)
         self.caches, tok = self._dec.decode_step(
             self.params, self.caches, self._active_dev, self.cfg)
-        return tok[None]
+        return (tok[None],)
 
-    def _post_admit(self, admitted: List[tuple]) -> None:
+    def _post_admit(self, rows: List[tuple]) -> None:
         """Engine-variant bookkeeping after a fused dispatch launched
         (PagedBatcher: radix insertion + gauges)."""
+
+    def _count_dispatch(self, extras: tuple) -> None:
+        """What a dispatch returned beyond its tokens (PagedBatcher: an
+        expert model's counts), once it has been read."""
 
     def _dispatch(self, jnp) -> bool:
         """One device dispatch per tick: chunked decode of every live
@@ -547,7 +581,7 @@ class ContinuousBatcher:
                     if r is None or (self.eos_id is None
                                      and self._drained(i, r))]
             live = [(i, r) for i, r in enumerate(self._owner)
-                    if r is not None
+                    if r is not None and not r._prefilling
                     and self._disp_len[i] < self._req_cap(r)]
             # Near the cache end, fall back to single-token dispatches
             # (and no admissions) so requests run all the way to
@@ -575,8 +609,7 @@ class ContinuousBatcher:
             # lands in prefill_s, not queue_s.
             admit_t = time.time()
             try:
-                first, dtoks, admitted = self._fused_dispatch(
-                    jnp, batch, active, chunk)
+                devs, rows = self._fused_dispatch(jnp, batch, active, chunk)
             except Exception as e:
                 # The batch is already out of _waiting/_pending with
                 # KV blocks held, but not yet in _owner — _fail_all
@@ -588,22 +621,26 @@ class ContinuousBatcher:
                     req.error = e
                     self._retire(slot, req)
                 raise
+            # A row whose prompt has chunks still to come holds its slot
+            # and yields no token yet; the others are admitted for good.
+            admitted = [a for a in rows if not a[2]._prefilling]
             with self._state_lock:
-                for _, slot, req in admitted:
+                for _, slot, req in rows:
                     self._owner[slot] = req
-                    req._admit_t = admit_t
+                    req._admit_t = req._admit_t or admit_t
                     # prompt + the chunk the fused step decodes for it
-                    self._disp_len[slot] = len(req.prompt) + chunk
-            self._post_admit(admitted)
+                    self._disp_len[slot] = (
+                        req._prefilled if req._prefilling
+                        else len(req.prompt) + chunk)
+            self._post_admit(rows)
             pairs = live + [(slot, req) for _, slot, req in admitted]
-            entry = ("fused", (first, dtoks), (admitted, pairs))
+            entry = ("fused", devs, (admitted, pairs))
         else:
             key = active.tobytes()
             if key != self._active_key:
                 self._active_key = key
                 self._active_dev = jnp.asarray(active)
-            entry = ("decode", (self._decode_dispatch(chunk),),
-                     (None, live))
+            entry = ("decode", self._decode_dispatch(chunk), (None, live))
         for dev in entry[1]:
             try:
                 dev.copy_to_host_async()
@@ -626,9 +663,19 @@ class ContinuousBatcher:
 
     def _process_entry(self, entry) -> None:
         kind, devs, (admitted, pairs) = entry
+        t_read = time.perf_counter()
+        first_dev = np.asarray(devs[0])     # waits for the dispatch
+        t_got = time.perf_counter()
+        self.host_s["read_wait"] += t_got - t_read
+        try:
+            self._hand_out(kind, devs, first_dev, admitted, pairs)
+        finally:
+            self.host_s["process"] += time.perf_counter() - t_got
+
+    def _hand_out(self, kind, devs, first_dev, admitted, pairs) -> None:
         now = time.time()
         if kind == "fused":
-            firsts = np.asarray(devs[0])
+            firsts = first_dev
             for row, slot, req in admitted:
                 req.ttft_s = now - req._t0
                 admit = req._admit_t or now
@@ -640,8 +687,10 @@ class ContinuousBatcher:
                 if self._finished(req, tok):
                     self._retire(slot, req)
             rows = np.asarray(devs[1])
+            self._count_dispatch(devs[2:])
         else:
-            rows = np.asarray(devs[0])
+            rows = first_dev
+            self._count_dispatch(devs[1:])
         # SLO windows (serve autoscaler): TTFT for this entry's
         # admissions; an inter-token-latency sample from the entry
         # cadence — each entry carries len(rows) decode steps, so
@@ -712,12 +761,19 @@ class ContinuousBatcher:
             try:
                 # Acquire a pipeline slot, then dispatch; the processor
                 # releases slots as it drains entries.
-                if not self._slots_sem.acquire(timeout=0.05):
+                t_a = time.perf_counter()
+                got = self._slots_sem.acquire(timeout=0.05)
+                t_b = time.perf_counter()
+                self.host_s["permit_wait"] += t_b - t_a
+                if not got:
                     continue
-                if not self._dispatch(jnp):
+                if self._dispatch(jnp):
+                    self.host_s["dispatch"] += time.perf_counter() - t_b
+                else:
                     self._slots_sem.release()
                     self._work.wait(timeout=0.05)
                     self._work.clear()
+                    self.host_s["starved"] += time.perf_counter() - t_b
             except Exception as e:
                 # An engine failure (e.g. device error) must surface to
                 # every waiting caller, not die with the thread and
@@ -819,6 +875,10 @@ class BlockAllocator:
         self._free: List[int] = list(range(num_blocks, 0, -1))
         self._ref: Dict[int, int] = {}
         self._cached: set = set()
+        # counts(), kept as the states change: it is read at every
+        # admission and every retirement, and a recount walks the pool
+        self._used = 0          # refcount > 0
+        self._idle_cached = 0   # refcount 0, retained by the radix tree
 
     def available(self) -> int:
         return len(self._free)
@@ -843,24 +903,47 @@ class BlockAllocator:
             self._ref[b] = 1
             if leaksan._ENABLED:
                 self._ls_reg(b)
+        self._used += n
         return out
 
     def incref(self, bid: int) -> None:
-        self._ref[bid] = self._ref.get(bid, 0) + 1
+        self.incref_many((bid,))
 
     def decref(self, bid: int) -> None:
-        r = self._ref.get(bid)
-        if r is None or r <= 0:
-            raise RuntimeError(
-                f"KV block {bid} double-free (refcount {r!r})")
-        r -= 1
-        if r == 0 and bid not in self._cached:
-            del self._ref[bid]
-            self._free.append(bid)
-            if leaksan._ENABLED:
-                self._ls_dis(bid)
-        else:
-            self._ref[bid] = r
+        self.decref_many((bid,))
+
+    def incref_many(self, bids) -> None:
+        """One more holder of each block (a prefix hit takes a thousand at
+        once: one call, not one per block)."""
+        ref, cached = self._ref, self._cached
+        for bid in bids:
+            r = ref.get(bid, 0)
+            if r == 0:
+                self._used += 1
+                if bid in cached:
+                    self._idle_cached -= 1
+            ref[bid] = r + 1
+
+    def decref_many(self, bids) -> None:
+        ref, cached = self._ref, self._cached
+        for bid in bids:
+            r = ref.get(bid)
+            if r is None or r <= 0:
+                raise RuntimeError(
+                    f"KV block {bid} double-free (refcount {r!r})")
+            r -= 1
+            if r == 0:
+                self._used -= 1
+                if bid in cached:
+                    self._idle_cached += 1
+                    ref[bid] = 0
+                else:
+                    del ref[bid]
+                    self._free.append(bid)
+                    if leaksan._ENABLED:
+                        self._ls_dis(bid)
+            else:
+                ref[bid] = r
 
     def refcount(self, bid: int) -> int:
         return self._ref.get(bid, 0)
@@ -868,12 +951,18 @@ class BlockAllocator:
     def mark_cached(self, bid: int) -> None:
         """The radix tree now retains this block (refcount-0 keeps it
         out of the free list until evicted)."""
-        self._cached.add(bid)
+        if bid not in self._cached:
+            self._cached.add(bid)
+            if self._ref.get(bid, 0) == 0:
+                self._idle_cached += 1
 
     def release_cached(self, bid: int) -> None:
         """The radix tree evicted this block; if no request holds it,
         it returns to the free list."""
-        self._cached.discard(bid)
+        if bid in self._cached:
+            self._cached.discard(bid)
+            if self._ref.get(bid, 0) == 0:
+                self._idle_cached -= 1
         if self._ref.get(bid, 0) == 0:
             self._ref.pop(bid, None)
             self._free.append(bid)
@@ -881,10 +970,7 @@ class BlockAllocator:
                 self._ls_dis(bid)
 
     def counts(self) -> Dict[str, int]:
-        used = sum(1 for r in self._ref.values() if r > 0)
-        cached = sum(1 for b in self._cached
-                     if self._ref.get(b, 0) == 0)
-        return {"used": used, "cached": cached,
+        return {"used": self._used, "cached": self._idle_cached,
                 "free": len(self._free)}
 
 
@@ -920,12 +1006,13 @@ class RadixCache:
         self._tick = 0
         self.size = 0          # cached nodes/blocks in this tree
 
-    def _touch(self, node: "_RadixNode") -> None:
+    def _now(self) -> int:
+        """One reading for a whole walk: the nodes of one prompt's path are
+        used together (a 16 k prompt is a thousand of them)."""
         if self._clock is not None:
-            node.last_used = self._clock()
-        else:
-            self._tick += 1
-            node.last_used = self._tick
+            return self._clock()
+        self._tick += 1
+        return self._tick
 
     def match(self, tokens: List[int]) -> List[int]:
         """Longest cached block-prefix of `tokens`, capped at
@@ -936,12 +1023,13 @@ class RadixCache:
         out: List[int] = []
         node = self.root
         limit = (len(tokens) - 1) // bs
+        now = self._now()
         for i in range(limit):
             chunk = tuple(tokens[i * bs:(i + 1) * bs])
             child = node.children.get(chunk)
             if child is None:
                 break
-            self._touch(child)
+            child.last_used = now
             out.append(child.block)
             node = child
         return out
@@ -958,6 +1046,7 @@ class RadixCache:
         node = self.root
         added = 0
         n = min(len(tokens) // bs, len(blocks))
+        now = self._now()
         for i in range(n):
             chunk = tuple(tokens[i * bs:(i + 1) * bs])
             child = node.children.get(chunk)
@@ -972,7 +1061,7 @@ class RadixCache:
                 # Same-prefix race within one admission batch: keep
                 # the cached block, the caller keeps its private copy.
                 pass
-            self._touch(child)
+            child.last_used = now
             node = child
         return added
 
@@ -1001,6 +1090,14 @@ class RadixCache:
         self.size -= 1
 
 
+# The widest prefill the paged engine compiles.  `prompt_pad` is what a
+# client may send; a prompt (or its uncached suffix) longer than this is
+# prefilled in chunks of this width over successive dispatches, each
+# against the blocks the earlier chunks wrote, while the other slots go on
+# decoding in the same dispatches.
+PREFILL_CHUNK = 512
+
+
 class PagedBatcher(ContinuousBatcher):
     """Paged-KV continuous batcher: block-pool cache + radix prefix
     cache + multiplexed adapter hot-swap (see module docstring).
@@ -1008,9 +1105,9 @@ class PagedBatcher(ContinuousBatcher):
     Inherits the pipelined dispatch/process machinery and swaps the
     cache layer: admission allocates refcounted blocks (evicting cold
     cached blocks, then QUEUEING under pressure), prefill runs only
-    the prompt's uncached suffix via paged_prefill_decode_packed, and
-    decode gathers KV through block tables with the ragged paged
-    attention kernel.
+    the prompt's uncached suffix via paged_prefill_decode_packed (in
+    chunks of PREFILL_CHUNK where it is longer), and decode gathers KV
+    through block tables with the ragged paged attention kernel.
     """
 
     supports_multiplex = True
@@ -1058,8 +1155,16 @@ class PagedBatcher(ContinuousBatcher):
         # saved.  Each admission batch picks the narrowest precompiled
         # width that fits its longest suffix, so all-hit batches pay a
         # block-sized prefill instead of a prompt-sized one.
+        self._prefill_pad = min(prompt_pad, PREFILL_CHUNK)
+        if prompt_pad > self._prefill_pad \
+                and self._prefill_pad % self.block_size:
+            raise ValueError(
+                f"prompts longer than {PREFILL_CHUNK} are prefilled in "
+                f"chunks of that many tokens, which must be whole blocks "
+                f"of kv_block_size {self.block_size}")
         self._suffix_pads = sorted({
-            min(max(self.block_size, 16), prompt_pad), prompt_pad})
+            min(max(self.block_size, 16), self._prefill_pad),
+            self._prefill_pad})
         self._alloc = BlockAllocator(self.num_blocks)
         self._radix: Dict[str, RadixCache] = {}
         # One LRU clock shared by every model's tree (comparable
@@ -1082,6 +1187,19 @@ class PagedBatcher(ContinuousBatcher):
         self._cache_hits = 0
         self._cache_hit_tokens = 0
         self._evictions = 0
+        # Counted for stats(): prefill rows and their tokens, requests
+        # that took more than one chunk; what an expert model's layers
+        # report per dispatch (models/afmoe.py MOE_COUNTS); and, per
+        # dispatch over owned slots and sliding layers, the positions
+        # held against those still inside a window (one block id serves
+        # every layer, so none beyond a window is freed yet).
+        self._prefill_counts = {"chunks": 0, "chunk_tokens": 0,
+                                "multi_chunk_requests": 0}
+        self._moe_counts = [0, 0, 0, 0]
+        self._sliding_layers = sum(
+            1 for m, _ in (cfg.layer_kinds or ()) if m == "sliding")
+        self._sliding_held = 0
+        self._sliding_in_window = 0
         # super().__init__ LAST: it starts the engine threads, which
         # immediately use the state above.
         super().__init__(params, cfg, num_slots=num_slots,
@@ -1112,19 +1230,18 @@ class PagedBatcher(ContinuousBatcher):
                 pw = max(P + 4 + self.table_width, self.num_slots)
                 packed = np.zeros((N + 1, pw), np.int32)
                 packed[:N, P + 2] = np.arange(N)
-                self.caches, _, _ = \
-                    self._dec.paged_prefill_decode_packed(
-                        self.params, self.caches, jnp.asarray(packed),
-                        self.cfg, self.decode_chunk, P,
-                        attn_impl=self._attn_impl)
+                self.caches = self._dec.paged_prefill_decode_packed(
+                    self.params, self.caches, jnp.asarray(packed),
+                    self.cfg, self.decode_chunk, P,
+                    attn_impl=self._attn_impl)[0]
         if self.decode_chunk > 1:
             self.caches, toks = self._dec.paged_decode_steps(
                 self.params, self.caches, active, self.cfg,
-                self.decode_chunk, attn_impl=self._attn_impl)
+                self.decode_chunk, attn_impl=self._attn_impl)[:2]
             np.asarray(toks)
         self.caches, toks = self._dec.paged_decode_step(
             self.params, self.caches, active, self.cfg,
-            attn_impl=self._attn_impl)
+            attn_impl=self._attn_impl)[:2]
         np.asarray(toks)
 
     # -- allocator / prefix cache ------------------------------------------
@@ -1206,6 +1323,15 @@ class PagedBatcher(ContinuousBatcher):
                 },
                 "models_resident": list(self._models),
                 "model_id": self._model_id,
+                "prefill": dict(self._prefill_counts),
+                "moe": dict(zip(("layer_steps", "routed_rows",
+                                 "busiest_expert_rows", "experts_touched"),
+                                self._moe_counts)),
+                "kv": {"sliding_positions_held": self._sliding_held,
+                       "sliding_positions_in_window":
+                           self._sliding_in_window,
+                       "sliding_positions_dead":
+                           self._sliding_held - self._sliding_in_window},
             }
 
     def resident_models(self) -> List[str]:
@@ -1305,15 +1431,14 @@ class PagedBatcher(ContinuousBatcher):
                 # Hold the matched blocks BEFORE the eviction sweep so
                 # it can never reclaim them out from under the hit (the
                 # sweep skips refcount > 0).
-                for b in prefix_blocks:
-                    self._alloc.incref(b)
+                self._alloc.incref_many(prefix_blocks)
             try:
                 need = total_blocks - len(prefix_blocks)
                 if need > self._alloc.available():
                     self._evict_locked(need - self._alloc.available())
                 if need > self._alloc.available():
-                    for b in prefix_blocks:  # backpressure: undo hold
-                        self._alloc.decref(b)
+                    # backpressure: undo hold
+                    self._alloc.decref_many(prefix_blocks)
                     return None
                 # Count queries/hits per ADMITTED request, not per
                 # attempt: a backpressured request retries admission
@@ -1335,12 +1460,10 @@ class PagedBatcher(ContinuousBatcher):
                 # eviction sweep / metric sink): the prefix holds would
                 # leak forever — _retire only frees blocks that made it
                 # into req._blocks.  RT013 self-finding.
-                for b in prefix_blocks:
-                    self._alloc.decref(b)
+                self._alloc.decref_many(prefix_blocks)
                 raise
-        req._prefix_len = len(prefix_blocks) * bs
+        req.cached_tokens = req._prefilled = len(prefix_blocks) * bs
         req.cache_hit = bool(prefix_blocks)
-        req.cached_tokens = req._prefix_len
         req._pos_cap = alloc_tokens
         return True
 
@@ -1380,8 +1503,7 @@ class PagedBatcher(ContinuousBatcher):
         with self._kv_lock:
             if req._blocks and not req._blocks_freed:
                 req._blocks_freed = True
-                for b in req._blocks:
-                    self._alloc.decref(b)
+                self._alloc.decref_many(req._blocks)
         self._update_kv_gauges()
 
     def _flush_prefix_cache_locked(self) -> None:
@@ -1445,64 +1567,101 @@ class PagedBatcher(ContinuousBatcher):
                 self._waiting.append(self._pending.get_nowait())
             except queue.Empty:
                 break
-        if free and not tail and self._waiting:
-            return self._admit(free)
-        return []
+        if tail:
+            return []
+        # Requests whose prompt has chunks still to come go first: they
+        # hold their slots already.
+        with self._state_lock:
+            batch = [(i, r) for i, r in enumerate(self._owner)
+                     if r is not None and r._prefilling
+                     and not r.done.is_set()]
+        if free and self._waiting:
+            batch += self._admit(free)
+        return batch
 
     def _fused_dispatch(self, jnp, batch: List[tuple], active,
                         chunk: int):
         N = (self._narrow_width
              if len(batch) <= self._narrow_width
              else self.num_slots)
-        max_suf = max(len(req.prompt) - req._prefix_len
-                      for _, req in batch)
-        P = next(p for p in self._suffix_pads if p >= max_suf)
+        takes = [min(len(req.prompt) - req._prefilled, self._prefill_pad)
+                 for _, req in batch]
+        P = next(p for p in self._suffix_pads if p >= max(takes))
         W = self.table_width
         packed = np.zeros((N + 1, max(P + 4 + W, self.num_slots)),
                           np.int32)
-        admitted = []
-        for row, (slot, req) in enumerate(batch):
-            suffix = req.prompt[req._prefix_len:]
-            packed[row, :len(suffix)] = suffix
-            packed[row, P] = len(suffix)
-            packed[row, P + 1] = req._prefix_len
+        rows = []
+        for row, ((slot, req), take) in enumerate(zip(batch, takes)):
+            done = req._prefilled
+            more = done + take < len(req.prompt)
+            packed[row, :take] = req.prompt[done:done + take]
+            packed[row, P] = take
+            packed[row, P + 1] = done
             packed[row, P + 2] = slot
-            packed[row, P + 3] = 1
-            row_bt = np.zeros(W, np.int32)
-            row_bt[:len(req._blocks)] = req._blocks
-            packed[row, P + 4:P + 4 + W] = row_bt
-            admitted.append((row, slot, req))
-        self._fill_pad_rows(packed, len(batch), N, admitted, P + 2)
+            packed[row, P + 3] = 2 if more else 1
+            packed[row, P + 4:P + 4 + len(req._blocks)] = req._blocks
+            rows.append((row, slot, req))
+        self._fill_pad_rows(packed, len(batch), N, rows, P + 2)
         packed[N, :self.num_slots] = active
-        self.caches, first, dtoks = \
-            self._dec.paged_prefill_decode_packed(
-                self.params, self.caches, jnp.asarray(packed),
-                self.cfg, chunk, P, attn_impl=self._attn_impl)
-        return first, dtoks, admitted
+        self.caches, *devs = self._dec.paged_prefill_decode_packed(
+            self.params, self.caches, jnp.asarray(packed),
+            self.cfg, chunk, P, attn_impl=self._attn_impl)
+        # Launched: only now do the requests move on.
+        for (_, req), take in zip(batch, takes):
+            req._prefilled += take
+            more = req._prefilled < len(req.prompt)
+            if more and not req._prefilling:    # its first chunk of several
+                self._prefill_counts["multi_chunk_requests"] += 1
+            req._prefilling = more
+        self._prefill_counts["chunks"] += len(batch)
+        self._prefill_counts["chunk_tokens"] += sum(takes)
+        return tuple(devs), rows
 
-    def _decode_dispatch(self, chunk: int):
+    def _decode_dispatch(self, chunk: int) -> tuple:
         if chunk > 1:
-            self.caches, dtoks = self._dec.paged_decode_steps(
+            self.caches, *devs = self._dec.paged_decode_steps(
                 self.params, self.caches, self._active_dev,
                 self.cfg, chunk, attn_impl=self._attn_impl)
-            return dtoks
-        self.caches, tok = self._dec.paged_decode_step(
+            return tuple(devs)
+        self.caches, tok, *extras = self._dec.paged_decode_step(
             self.params, self.caches, self._active_dev, self.cfg,
             attn_impl=self._attn_impl)
-        return tok[None]
+        return (tok[None], *extras)
 
-    def _post_admit(self, admitted: List[tuple]) -> None:
-        # Optimistic radix insertion AFTER the batch is packed:
-        # in-order device execution guarantees these blocks are
-        # written before any LATER dispatch's prefill gathers
-        # them, but rows within THIS batch run concurrently — so
-        # same-batch duplicates must miss (each keeps a private
-        # copy) and only future admissions share.
+    def _count_dispatch(self, extras: tuple) -> None:
+        if extras:
+            counts = np.asarray(extras[0]).tolist()
+            with self._kv_lock:
+                self._moe_counts = [a + b for a, b in
+                                    zip(self._moe_counts, counts)]
+
+    def _dispatch(self, jnp) -> bool:
+        ran = super()._dispatch(jnp)
+        if ran and self._sliding_layers:
+            with self._state_lock:
+                held = [self._disp_len[i]
+                        for i, r in enumerate(self._owner) if r is not None]
+            window = self.cfg.sliding_window
+            with self._kv_lock:
+                self._sliding_held += self._sliding_layers * sum(held)
+                self._sliding_in_window += self._sliding_layers * sum(
+                    min(n, window) for n in held)
+        return ran
+
+    def _post_admit(self, rows: List[tuple]) -> None:
+        # Optimistic radix insertion AFTER the batch is packed, of the
+        # prompt as far as it has been dispatched (a chunked prompt's
+        # later blocks follow with their chunks): in-order device
+        # execution guarantees these blocks are written before any
+        # LATER dispatch's prefill gathers them, but rows within THIS
+        # batch run concurrently — so same-batch duplicates must miss
+        # (each keeps a private copy) and only future admissions share.
         if self.prefix_cache_enabled:
             with self._kv_lock:
-                for _, _, req in admitted:
+                for _, _, req in rows:
                     self._radix_for(req.model_id).insert(
-                        req.prompt, req._blocks, self._alloc)
+                        req.prompt[:req._prefilled], req._blocks,
+                        self._alloc)
         self._update_kv_gauges()
 
 
@@ -1601,10 +1760,24 @@ class LLMDeployment:
         route_t0 = _time.time()
         req = self.batcher.submit(prompt, max_new,
                                   model_id=self._request_model_id())
+        # The engine wakes this coroutine itself.  (Parking a thread of the
+        # loop's default executor on req.done.wait let only min(32, CPUs +
+        # 4) replies be watched at a time: with more requests in flight a
+        # finished reply waited behind older ones, and a closed loop of
+        # short dispatches ran its slots dry.)
         loop = asyncio.get_running_loop()
-        finished = await loop.run_in_executor(None, req.done.wait, 300.0)
-        if not finished:
-            raise TimeoutError("generation timed out after 300s")
+        woken = loop.create_future()
+
+        def wake() -> None:
+            loop.call_soon_threadsafe(
+                lambda: woken.done() or woken.set_result(True))
+        req._on_done = wake
+        if req.done.is_set():       # finished before the hook was there
+            wake()
+        try:
+            await asyncio.wait_for(woken, 300.0)
+        except asyncio.TimeoutError:
+            raise TimeoutError("generation timed out after 300s") from None
         if req.error is not None:
             raise req.error
         # TTFT decomposition spans: route (replica hop -> engine
@@ -1661,7 +1834,8 @@ class LLMDeployment:
                # None where the backend keeps no statistics (CPU).
                "peak_bytes": (dev.memory_stats() or {}).get(
                    "peak_bytes_in_use"),
-               "pid": os.getpid(), "chips": ray_tpu.get_tpu_ids()}
+               "pid": os.getpid(), "chips": ray_tpu.get_tpu_ids(),
+               "host": dict(b.host_s)}
         if isinstance(b, PagedBatcher):
             out.update(b.kv_stats())
         return out

@@ -123,7 +123,7 @@ def peak_flops_for(device) -> float:
 def transformer_flops_per_token(n_params: int, n_layers: int,
                                 seq: int, d_model: int) -> float:
     """Model FLOPs per trained token: 6N + attention 12·L·s·d (PaLM
-    appendix B).  N is the caller's count: benchmarks/lib/model.py
+    appendix B).  N is the caller's count: benchmarks/kinds/
     leaves the input embedding table (a gather) out of it."""
     return 6.0 * n_params + 12.0 * n_layers * seq * d_model
 

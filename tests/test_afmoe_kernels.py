@@ -1,0 +1,152 @@
+"""The kernels arch "afmoe" brought, in the Pallas interpreter on the CPU:
+the grouped expert FFN against a per-expert loop, the paged decode kernel
+with a window and the prefix attention of a prefill chunk against plain
+position-masked attention.  The same at published widths on the chip:
+tests_tpu/test_kernels_on_device.py."""
+
+import math
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops import grouped_ffn as gf
+from ray_tpu.ops import paged_attention as pa
+
+
+def _expert_loop(x, idx, w, valid, wg, wu, wd):
+    """sum_k w[t, k] FFN_{idx[t, k]}(x[t]), one expert at a time."""
+    x32 = x.astype(jnp.float32)
+    y = jnp.zeros_like(x32)
+    for e in range(wg.shape[0]):
+        out = (jax.nn.silu(x32 @ wg[e].astype(jnp.float32))
+               * (x32 @ wu[e].astype(jnp.float32))) \
+            @ wd[e].astype(jnp.float32)
+        weight = jnp.sum(jnp.where(idx == e, w, 0.0), axis=1)
+        y = y + jnp.where(valid, weight, 0.0)[:, None] * out
+    return y
+
+
+@pytest.mark.parametrize("T,K,E,impl", [(24, 2, 8, "kernel"),
+                                        (24, 2, 8, "reference"),
+                                        (2100, 2, 4, "kernel")])
+def test_grouped_ffn_matches_a_per_expert_loop(T, K, E, impl):
+    """Small tiles (a decode step's rows) and large ones (a prefill
+    chunk's); rows that are not valid are routed nowhere: zeros out, not
+    counted."""
+    D, F = 32, 48
+    ks = jax.random.split(jax.random.PRNGKey(T), 6)
+    x = jax.random.normal(ks[0], (T, D), jnp.float32)
+    wg, wu = (jax.random.normal(k, (E, D, F)) / math.sqrt(D) for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (E, F, D)) / math.sqrt(F)
+    idx = jnp.argsort(jax.random.uniform(ks[4], (T, E)), axis=1)[:, :K]
+    w = jax.random.uniform(ks[5], (T, K), minval=0.1)
+    valid = jnp.arange(T) % 5 != 3
+    assert gf.tile_rows(T * K) == (16 if T == 24 else 256)
+    with jax.default_matmul_precision("highest"):
+        y, sizes = gf.grouped_ffn(x, idx.astype(jnp.int32), w, valid,
+                                  wg, wu, wd, name="moe_experts_decode",
+                                  impl=impl)
+        want = _expert_loop(x, idx, w, valid, wg, wu, wd)
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-4)
+    assert float(jnp.max(jnp.abs(y[~valid]))) == 0.0
+    assert int(jnp.sum(sizes)) == int(jnp.sum(valid)) * K
+    counted = np.bincount(np.asarray(idx[valid]).ravel(), minlength=E)
+    np.testing.assert_array_equal(sizes, counted)
+
+
+def test_grouped_ffn_with_every_row_on_one_expert():
+    """The busiest case: one group of many tiles, the others empty."""
+    T, K, E, D, F = 40, 1, 4, 32, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(ks[0], (T, D))
+    wg, wu = (jax.random.normal(k, (E, D, F)) for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (E, F, D))
+    idx = jnp.full((T, K), 2, jnp.int32)
+    w = jnp.ones((T, K))
+    valid = jnp.ones((T,), bool)
+    with jax.default_matmul_precision("highest"):
+        y, sizes = gf.grouped_ffn(x, idx, w, valid, wg, wu, wd,
+                                  impl="kernel")
+        want = _expert_loop(x, idx, w, valid, wg, wu, wd)
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-3)
+    assert sizes.tolist() == [0, 0, 40, 0]
+
+
+def _plain_attention(q, k, v, qpos, total, window):
+    """q [P, H, D] at positions qpos over keys k, v [M, Hkv, D] of which
+    `total` exist: key j visible iff j <= i and i - j < window."""
+    H, hkv = q.shape[1], k.shape[1]
+    k, v = (jnp.repeat(a, H // hkv, axis=1) for a in (k, v))
+    s = jnp.einsum("phd,mhd->hpm", q, k) / math.sqrt(q.shape[-1])
+    j = jnp.arange(k.shape[0])[None, :]
+    seen = (j <= qpos[:, None]) & (j < total)
+    if window is not None:
+        seen &= qpos[:, None] - j < window
+    p = jax.nn.softmax(jnp.where(seen[None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("hpm,mhd->phd", jnp.where(seen[None], p, 0.0), v)
+
+
+def _pool(key, NB, hkv, bs, D, B, W):
+    k1, k2, k3 = jax.random.split(key, 3)
+    kp = jax.random.normal(k1, (NB, hkv, bs, D), jnp.float32)
+    vp = jax.random.normal(k2, (NB, hkv, bs, D), jnp.float32)
+    tables = jax.random.permutation(k3, jnp.arange(1, NB))[:B * W].reshape(
+        B, W).astype(jnp.int32)
+    return kp, vp, tables
+
+
+def _rows_of(pool, table):          # [W] -> [W * bs, Hkv, D]
+    return pool[table].transpose(0, 2, 1, 3).reshape(
+        -1, pool.shape[1], pool.shape[3])
+
+
+@pytest.mark.parametrize("window", [None, 64, 130])
+def test_paged_kernel_with_a_window(window):
+    """Contexts inside the window, at it, one past it and far past it (so
+    that the stream starts at a later page group), and an empty slot."""
+    B, H, hkv, D, bs, W = 6, 4, 2, 32, 16, 24
+    kp, vp, tables = _pool(jax.random.PRNGKey(0), B * W + 1, hkv, bs, D, B, W)
+    q = jax.random.normal(jax.random.PRNGKey(1), (B, H, D), jnp.float32)
+    ctx = jnp.asarray([40, 64, 65, 300, 0, 383], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        got = pa.paged_attention(q, kp, vp, tables, ctx, impl="kernel",
+                                 window=window)
+        ref = pa.paged_attention(q, kp, vp, tables, ctx, impl="reference",
+                                 window=window)
+        for b in range(B):
+            n = int(ctx[b])
+            want = _plain_attention(
+                q[b][None], _rows_of(kp, tables[b]), _rows_of(vp, tables[b]),
+                jnp.asarray([n - 1]), n, window)[0] if n else 0.0
+            np.testing.assert_allclose(got[b], want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("impl", ["kernel", "reference"])
+@pytest.mark.parametrize("window", [None, 24])
+def test_prefix_attention_matches_plain_attention(impl, window):
+    """A chunk of 16 queries per row over a paged prefix: no prefix, a
+    prefix longer than the window with a partly filled chunk, one that
+    spans several page groups, and a row that is padding."""
+    N, P, H, hkv, D, bs, W = 4, 16, 4, 2, 32, 16, 24
+    kp, vp, tables = _pool(jax.random.PRNGKey(2), N * W + 1, hkv, bs, D, N, W)
+    q = jax.random.normal(jax.random.PRNGKey(3), (N, P, H, D), jnp.float32)
+    prefix = jnp.asarray([0, 48, 320, 64], jnp.int32)
+    suffix = jnp.asarray([16, 9, 16, 0], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        got = pa.prefix_attention(q, kp, vp, tables, prefix, suffix,
+                                  impl=impl, window=window)
+        for n in range(N):
+            live = int(suffix[n])
+            if not live:
+                continue
+            qpos = int(prefix[n]) + jnp.arange(P)
+            want = _plain_attention(
+                q[n], _rows_of(kp, tables[n]), _rows_of(vp, tables[n]),
+                qpos, int(prefix[n]) + live, window)
+            np.testing.assert_allclose(got[n, :live], want[:live],
+                                       rtol=1e-4, atol=1e-4)
+    assert bool(jnp.all(jnp.isfinite(got)))

@@ -62,6 +62,43 @@ def test_allocator_alloc_free_refcount():
     assert a.counts() == {"used": 0, "cached": 0, "free": 8}
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_allocator_counts_match_a_recount(seed):
+    """counts() is kept as blocks change state (it is read at every
+    admission and retirement): after any sequence of allocations, shared
+    holds, releases, caching and eviction it equals a walk of the pool."""
+    import random
+    rng = random.Random(seed)
+    a = BlockAllocator(24)
+    held, cached = [], set()
+    for _ in range(400):
+        op = rng.choice(["alloc", "share", "drop", "cache", "evict"])
+        if op == "alloc":
+            got = a.alloc(rng.randint(1, 4))
+            held += got or []
+        elif op == "share" and held:
+            some = rng.sample(held, min(len(held), 3))
+            a.incref_many(some)
+            held += some
+        elif op == "drop" and held:
+            rng.shuffle(held)
+            some, held = held[:3], held[3:]
+            a.decref_many(some)
+        elif op == "cache" and held:
+            b = rng.choice(held)
+            a.mark_cached(b)
+            cached.add(b)
+        elif op == "evict" and cached:
+            b = rng.choice(sorted(cached))
+            cached.discard(b)
+            a.release_cached(b)
+        used = sum(1 for r in a._ref.values() if r > 0)
+        idle = sum(1 for b in a._cached if a._ref.get(b, 0) == 0)
+        assert a.counts() == {"used": used, "cached": idle,
+                              "free": a.available()}
+        assert used + idle + a.available() == 24
+
+
 def test_allocator_double_free_raises():
     a = BlockAllocator(2)
     (b,) = a.alloc(1)

@@ -99,3 +99,55 @@ def test_paged_serving_steps_compile_for_v5e(v5e_devices, model):
         attn_impl="kernel").compile()) >= 1
     assert _custom_calls(decoding.paged_decode_step.lower(
         params, caches, active, cfg, attn_impl="kernel").compile()) >= 1
+
+
+def test_afmoe_serving_steps_compile_for_v5e(v5e_devices, monkeypatch):
+    """benchmarks/configs/trinity-mini-l5.json as the cell runs it: the
+    widest fused prefill + decode and the decode-only chunk, with the
+    window in the paged kernel, `prefix_attention` and the grouped expert
+    product as Mosaic kernels, inside one chip's memory beside 8.5 GB of
+    weights.  (`impl="auto"` asks jax.default_backend(): steered here, in
+    the test, as it would read on the chip.)"""
+    import json
+    import os
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from benchmarks.lib import spec, worker_util
+    from ray_tpu.models import decoding, transformer as tfm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("x",)),
+                            PartitionSpec())
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=on_chip), tree)
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "trinity-mini-l5.json")) as f:
+        config = json.load(f)
+    sv = config["serve"]
+    cfg = tfm.TransformerConfig(**worker_util.with_dtypes(
+        spec.model_kind("afmoe").transformer_kwargs(
+            config, max_seq=sv["max_len"], param_dtype=sv["param_dtype"])))
+    W = decoding.paged_table_width(sv["max_len"], sv["kv_block_size"])
+    params = shapes(jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
+        cfg, sv["num_slots"], sv["kv_num_blocks"], sv["kv_block_size"],
+        sv["max_len"])))
+    N, P = sv["num_slots"], 512
+    packed = jax.ShapeDtypeStruct((N + 1, P + 4 + W), jnp.int32,
+                                  sharding=on_chip)
+    fused = decoding.paged_prefill_decode_packed.lower(
+        params, caches, packed, cfg, sv["decode_chunk"], P,
+        attn_impl="kernel").compile()
+    # per layer: prefix attention + (4 of 5) experts, then paged + experts
+    assert _custom_calls(fused) >= 5 + 4 + 5 + 4
+    mem = fused.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    active = jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=on_chip)
+    assert _custom_calls(decoding.paged_decode_steps.lower(
+        params, caches, active, cfg, sv["decode_chunk"],
+        attn_impl="kernel").compile()) >= 9

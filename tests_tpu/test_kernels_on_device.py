@@ -157,3 +157,112 @@ def test_paged_auto_is_the_kernel_on_tpu():
     hlo = jax.jit(lambda *a: paged_attention(*a, impl="auto")).lower(
         *args).as_text()
     assert "tpu_custom_call" in hlo
+
+
+# -- arch "afmoe": the window, the prefix attention, the expert product -----
+# At Trinity-Mini's widths: 32 query / 4 KV heads of 128, blocks of 16,
+# 128 experts of 2048 x 1024.
+def _afmoe_pool(B, W, seed=0, hkv=4, bs=16, d=128):
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    nb = B * W + 1
+    kp = jax.random.normal(k1, (nb, hkv, bs, d), jnp.bfloat16)
+    vp = jax.random.normal(k2, (nb, hkv, bs, d), jnp.bfloat16)
+    tables = jax.random.permutation(k3, jnp.arange(1, nb)).reshape(
+        B, W).astype(jnp.int32)
+    return kp, vp, tables
+
+
+@pytest.mark.parametrize("window", [2048, None])
+def test_windowed_paged_kernel_at_afmoe_widths_on_tpu(window):
+    """Contexts inside the window, at it, one past it, far past it."""
+    ctx = jnp.asarray([100, 2048, 2049, 16500, 0, 5000, 17151, 1],
+                      jnp.int32)
+    kp, vp, tables = _afmoe_pool(8, 1072)
+    q = jax.random.normal(jax.random.PRNGKey(9), (8, 32, 128), jnp.bfloat16)
+    got = paged_attention(q, kp, vp, tables, ctx, impl="kernel",
+                          window=window)
+    with jax.default_matmul_precision("highest"):
+        want = paged_attention_reference(q, kp, vp, tables, ctx,
+                                         window=window)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("window", [2048, None])
+def test_prefix_attention_at_afmoe_widths_on_tpu(window):
+    """A 512 chunk over prefixes of 0, 448, 2,000 and 16,384 positions, a
+    partly filled chunk and a row that is padding; the plain attention is
+    computed row by row over each row's own keys."""
+    from ray_tpu.ops.paged_attention import prefix_attention
+    N, P, H, hkv, D, bs, W = 6, 512, 32, 4, 128, 16, 1072
+    kp, vp, tables = _afmoe_pool(N, W, seed=1)
+    q = jax.random.normal(jax.random.PRNGKey(2), (N, P, H, D), jnp.bfloat16)
+    prefix = jnp.asarray([0, 448, 2000, 16384, 9000, 64], jnp.int32)
+    suffix = jnp.asarray([512, 512, 300, 512, 40, 0], jnp.int32)
+    got = prefix_attention(q, kp, vp, tables, prefix, suffix, impl="kernel",
+                           window=window)
+
+    @jax.jit
+    def plain(kp, vp, qn, table, pre, live):    # the pools as arguments:
+        def rows(pool):                         # closed over, constants
+            return pool[table].transpose(0, 2, 1, 3).reshape(
+                W * bs, hkv, D).astype(jnp.float32)
+        k, v = (jnp.repeat(rows(p), H // hkv, axis=1) for p in (kp, vp))
+        s = jnp.einsum("phd,mhd->hpm", qn.astype(jnp.float32), k) \
+            / np.sqrt(D)
+        qpos = pre + jnp.arange(P)[:, None]
+        j = jnp.arange(W * bs)[None, :]
+        seen = (j <= qpos) & (j < pre + live)
+        if window is not None:
+            seen &= qpos - j < window
+        p = jax.nn.softmax(jnp.where(seen[None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("hpm,mhd->phd", p, v)
+
+    with jax.default_matmul_precision("highest"):
+        for n in range(N):
+            live = int(suffix[n])
+            if live:
+                want = plain(kp, vp, q[n], tables[n], prefix[n], suffix[n])
+                np.testing.assert_allclose(
+                    np.asarray(got[n, :live], np.float32),
+                    np.asarray(want[:live]), atol=2e-2, rtol=2e-2)
+    assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("rows", [32, 2048])
+def test_grouped_ffn_at_afmoe_widths_on_tpu(rows):
+    """32 tokens x top-8 = 256 rows (a decode step) and 2,048 x 8 = 16,384
+    rows (a prefill's), against a loop over the 128 experts."""
+    from ray_tpu.ops.grouped_ffn import grouped_ffn
+    E, D, F, K = 128, 2048, 1024, 8
+    ks = jax.random.split(jax.random.PRNGKey(rows), 6)
+    x = jax.random.normal(ks[0], (rows, D), jnp.bfloat16)
+    wg, wu = (jax.random.normal(k, (E, D, F), jnp.bfloat16) / np.sqrt(D)
+              for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (E, F, D), jnp.bfloat16) / np.sqrt(F)
+    idx = jnp.argsort(jax.random.uniform(ks[4], (rows, E)), axis=1)[:, :K]
+    w = jax.random.uniform(ks[5], (rows, K), minval=0.1)
+    valid = jnp.arange(rows) % 7 != 3
+    y, sizes = grouped_ffn(x, idx.astype(jnp.int32), w, valid, wg, wu, wd,
+                           name="moe_experts_decode", impl="kernel")
+
+    @jax.jit
+    def loop(x, idx, w, valid, wg, wu, wd):
+        x32 = x.astype(jnp.float32)
+
+        def one(acc, e):
+            out = (jax.nn.silu(x32 @ wg[e].astype(jnp.float32))
+                   * (x32 @ wu[e].astype(jnp.float32))) \
+                @ wd[e].astype(jnp.float32)
+            weight = jnp.sum(jnp.where(idx == e, w, 0.0), axis=1)
+            return acc + jnp.where(valid, weight, 0.0)[:, None] * out, None
+        return jax.lax.scan(one, jnp.zeros_like(x32), jnp.arange(E))[0]
+
+    with jax.default_matmul_precision("highest"):
+        want = loop(x, idx, w, valid, wg, wu, wd)
+    err = np.abs(np.asarray(y, np.float32) - np.asarray(want))
+    assert float(err.max()) < 0.1 and float(err.mean()) < 0.01, \
+        (float(err.max()), float(err.mean()), float(np.abs(want).mean()))
+    assert int(jnp.sum(sizes)) == int(jnp.sum(valid)) * K
+    assert float(jnp.max(jnp.abs(y[~valid].astype(jnp.float32)))) == 0.0
