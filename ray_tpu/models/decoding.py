@@ -378,18 +378,18 @@ def set_last_tokens(caches: DecodeCaches,
 # addresses them through per-slot block tables, so short sequences use
 # blocks proportional to their length and FULL prompt blocks are
 # refcount-shareable across requests (the serve/llm.py prefix cache).
-# Decode attention goes through ops/paged_attention.py (Pallas ragged
-# paged attention on a TPU backend, jnp.take gather reference on any
-# other).
+# Attention goes through ops/paged_attention.py: `paged_attention` for a
+# decode step, `prefix_attention` for a prefill's rows (Pallas kernels on
+# a TPU backend, jnp.take gather references on any other).
 #
 # Invariants the engine (serve/llm.py) maintains, which these kernels
 # rely on:
 #   * pool block 0 is a reserved scratch block: never allocated, table
 #     padding points at it, and gated/over-capacity writes are
-#     redirected to it — so duplicate scatter targets always carry the
-#     same value and garbage positions are always masked by length;
-#   * a request's prefix_len is a multiple of the block size (only
-#     FULL blocks are shared), so every suffix/decode write lands in a
+#     redirected to it — so garbage positions are always masked by
+#     length and nothing reads the scratch block unmasked;
+#   * a request's cached prefix is a multiple of the block size (only
+#     FULL blocks are shared), so every prefill/decode write lands in a
 #     block owned exclusively by that slot;
 #   * admission pre-allocates blocks for prompt + max_new tokens, so
 #     decode never needs to allocate (and never runs out mid-decode).
@@ -432,284 +432,6 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
         block_tables=jnp.zeros((num_slots, w), jnp.int32),
         lengths=jnp.zeros((num_slots,), jnp.int32),
         last_token=jnp.zeros((num_slots,), jnp.int32))
-
-
-def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
-                       active: jax.Array, cfg: TransformerConfig,
-                       attn_impl: str = "auto"
-                       ) -> Tuple[PagedDecodeCaches, jax.Array]:
-    """One decode step over the block pool (traceable).  Mirrors
-    _decode_core exactly, with the scatter routed through the block
-    table and attention through ops.paged_attention.  Safe to run extra
-    steps on retired/drained slots: their writes are clamped into their
-    own private tail blocks or redirected to scratch block 0, and their
-    garbage outputs are dropped host-side."""
-    from ray_tpu.ops import paged_attention as _pa
-
-    B = caches.lengths.shape[0]
-    bs = caches.kp.shape[3]
-    M = caches.block_tables.shape[1] * bs
-    tokens = caches.last_token[:, None]                      # [B,1]
-    pos = caches.lengths[:, None]                            # [B,1]
-    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [B,1,D]
-    if cfg.arch == "gpt2":
-        x = x + params["pos_embed"][
-            jnp.clip(pos, 0, cfg.max_seq - 1)].astype(cfg.dtype)
-    rms = cfg.arch == "llama"
-    batch_ix = jnp.arange(B)
-    # Clamp the write position for slots decoding past their
-    # allocation (drained slots kept hot by the dispatcher); the
-    # active gate below redirects inactive slots to scratch block 0.
-    pos_c = jnp.minimum(caches.lengths, M - 1)
-    blk_w = jnp.where(active,
-                      caches.block_tables[batch_ix, pos_c // bs], 0)
-    off_w = pos_c % bs
-    # Valid positions INCLUDE the token scattered this step.  An
-    # inactive slot attends to nothing: a retired slot keeps its last
-    # length until the next prefill, and the kernel's work follows
-    # these lengths (the host drops such a slot's output anyway).
-    ctx_lens = jnp.where(active, jnp.minimum(caches.lengths + 1, M), 0)
-
-    def layer(x, inputs):
-        p, k_pool, v_pool = inputs
-        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
-                  cfg.norm_eps, rms)
-        q, k_new, v_new = _qkv(p, h, cfg, pos)
-        gate = active[:, None, None]
-        # [blk, :, off] on a [NB, Hkv, bs, Dh] pool: one [Hkv, Dh] row
-        # per slot (the indexed dims lead, so shapes match k_new[:, 0]).
-        k_pool = k_pool.at[blk_w, :, off_w].set(
-            jnp.where(gate, k_new[:, 0].astype(k_pool.dtype),
-                      k_pool[blk_w, :, off_w]))
-        v_pool = v_pool.at[blk_w, :, off_w].set(
-            jnp.where(gate, v_new[:, 0].astype(v_pool.dtype),
-                      v_pool[blk_w, :, off_w]))
-        o = _pa.paged_attention(q[:, 0], k_pool, v_pool,
-                                caches.block_tables, ctx_lens,
-                                impl=attn_impl)              # [B,H,Dh]
-        attn = jnp.einsum("bshk,hkd->bsd", o[:, None].astype(cfg.dtype),
-                          p["wo"].astype(cfg.dtype))
-        x = x + attn
-        x = _mlp(p, x, cfg)
-        return x, (k_pool, v_pool)
-
-    x, (kp_all, vp_all) = jax.lax.scan(
-        layer, x, (params["layers"], caches.kp, caches.vp))
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"),
-              cfg.norm_eps, rms)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x.astype(jnp.float32),
-        _w_out(params, cfg).astype(jnp.float32))[:, 0]       # [B,V]
-    next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    new_last = jnp.where(active, next_tok, caches.last_token)
-    new_len = jnp.where(active, caches.lengths + 1, caches.lengths)
-    return PagedDecodeCaches(kp=kp_all, vp=vp_all,
-                             block_tables=caches.block_tables,
-                             lengths=new_len,
-                             last_token=new_last), next_tok
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "attn_impl"),
-                   donate_argnums=(1,))
-def paged_decode_step(params: Dict[str, Any], caches: PagedDecodeCaches,
-                      active: jax.Array, cfg: TransformerConfig,
-                      attn_impl: str = "auto"
-                      ) -> Tuple[PagedDecodeCaches, jax.Array]:
-    """One token for every slot; returns (caches', next_tokens [B]);
-    arch "afmoe" also its expert layers' counts (afmoe.MOE_COUNTS)."""
-    if cfg.arch == "afmoe":
-        caches, tok, _, counts = _afmoe_decode_core(
-            params, caches, active, cfg, attn_impl)
-        return caches, tok, counts
-    return _paged_decode_core(params, caches, active, cfg, attn_impl)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "num_steps", "attn_impl"),
-                   donate_argnums=(1,))
-def paged_decode_steps(params: Dict[str, Any], caches: PagedDecodeCaches,
-                       active: jax.Array, cfg: TransformerConfig,
-                       num_steps: int, attn_impl: str = "auto"
-                       ) -> Tuple[PagedDecodeCaches, jax.Array]:
-    """num_steps tokens per slot in ONE dispatch (lax.scan): returns
-    (caches', tokens [num_steps, B]); arch "afmoe" also its expert
-    layers' counts."""
-    if cfg.arch == "afmoe":
-        return _afmoe_decode_scan(params, caches, active, cfg, num_steps,
-                                  attn_impl)
-
-    def body(c, _):
-        return _paged_decode_core(params, c, active, cfg, attn_impl)
-
-    caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
-    return caches, toks
-
-
-def _paged_prefill_core(params: Dict[str, Any],
-                        caches: PagedDecodeCaches, tokens: jax.Array,
-                        suffix_lens: jax.Array, prefix_lens: jax.Array,
-                        slots: jax.Array, valid: jax.Array,
-                        new_bt: jax.Array, cfg: TransformerConfig
-                        ) -> Tuple[PagedDecodeCaches, jax.Array]:
-    """Suffix prefill against a paged prefix (traceable).
-
-    tokens [N, P] hold only each prompt's UNCACHED suffix; the cached
-    prefix (prefix_lens tokens, whole blocks, already resident in the
-    pool via the request's block table) is attended by gather, never
-    recomputed — this is where a prefix-cache hit saves its FLOPs.
-    Suffix queries sit at absolute positions prefix_len + i (RoPE /
-    learned positions stay correct), attend all prefix positions plus
-    causally within the suffix, and their K/V are scattered into the
-    slot's private blocks.  prefix_lens == 0 degenerates to the dense
-    prefill math.  Invalid rows rewrite existing state (gather-then-
-    scatter no-op), exactly like _prefill_insert_core."""
-    N, P = tokens.shape
-    bs = caches.kp.shape[3]
-    W = caches.block_tables.shape[1]
-    M = W * bs
-    bt = caches.block_tables.at[slots].set(
-        jnp.where(valid[:, None], new_bt, caches.block_tables[slots]))
-    bt_rows = bt[slots]                                      # [N, W]
-    positions = prefix_lens[:, None] + jnp.arange(P, dtype=jnp.int32)
-    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [N,P,D]
-    if cfg.arch == "gpt2":
-        x = x + params["pos_embed"][
-            jnp.clip(positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
-    rms = cfg.arch == "llama"
-    causal = (jnp.arange(P)[:, None] >= jnp.arange(P)[None, :])
-    padmask = jnp.arange(P)[None, :] < suffix_lens[:, None]  # [N,P]
-    ctx_mask = jnp.arange(M)[None, :] < prefix_lens[:, None]  # [N,M]
-    # keys layout: [0..M) gathered pool window, [M..M+P) in-flight
-    # suffix — full mask [N, P, M+P].
-    mask_full = jnp.concatenate([
-        jnp.broadcast_to(ctx_mask[:, None, :], (N, P, M)),
-        causal[None] & padmask[:, None, :],
-    ], axis=-1)
-    # Scatter targets for the suffix K/V (clamped + gated to scratch).
-    abs_pos = jnp.minimum(positions, M - 1)                  # [N,P]
-    blkidx = jnp.take_along_axis(bt_rows, abs_pos // bs, axis=1)
-    offidx = abs_pos % bs
-    wgate = valid[:, None] & padmask                         # [N,P]
-    blk_w = jnp.where(wgate, blkidx, 0)
-    groups = cfg.n_heads // cfg.kv_heads
-
-    def layer(x, inputs):
-        p, k_pool, v_pool = inputs
-        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
-                  cfg.norm_eps, rms)
-        q, k, v = _qkv(p, h, cfg, positions)
-        k_pool = k_pool.at[blk_w, :, offidx].set(
-            jnp.where(wgate[..., None, None], k.astype(k_pool.dtype),
-                      k_pool[blk_w, :, offidx]))
-        v_pool = v_pool.at[blk_w, :, offidx].set(
-            jnp.where(wgate[..., None, None], v.astype(v_pool.dtype),
-                      v_pool[blk_w, :, offidx]))
-        # Prefix window gather (suffix positions in it are masked off):
-        # [N, W, Hkv, bs, Dh] -> [N, M, Hkv, Dh].
-        k_ctx = jnp.take(k_pool, bt_rows, axis=0).transpose(
-            0, 1, 3, 2, 4).reshape(N, M, cfg.kv_heads, cfg.head_dim)
-        v_ctx = jnp.take(v_pool, bt_rows, axis=0).transpose(
-            0, 1, 3, 2, 4).reshape(N, M, cfg.kv_heads, cfg.head_dim)
-        k_all = jnp.concatenate([k_ctx.astype(k.dtype), k], axis=1)
-        v_all = jnp.concatenate([v_ctx.astype(v.dtype), v], axis=1)
-        qg = q.reshape(N, P, cfg.kv_heads, groups, cfg.head_dim)
-        s = jnp.einsum("bqhgk,bmhk->bhgqm", qg.astype(jnp.float32),
-                       k_all.astype(jnp.float32)) / (cfg.head_dim ** 0.5)
-        s = jnp.where(mask_full[:, None, None], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqm,bmhk->bqhgk", w, v_all.astype(jnp.float32))
-        o = o.reshape(N, P, cfg.n_heads, cfg.head_dim).astype(cfg.dtype)
-        attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
-        x = x + attn
-        x = _mlp(p, x, cfg)
-        return x, (k_pool, v_pool)
-
-    x, (kp_all, vp_all) = jax.lax.scan(
-        layer, x, (params["layers"], caches.kp, caches.vp))
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"),
-              cfg.norm_eps, rms)
-    last_ix = jnp.clip(suffix_lens - 1, 0, P - 1)
-    last = x[jnp.arange(N), last_ix]                         # [N,D]
-    logits = last.astype(jnp.float32) @ _w_out(params, cfg).astype(
-        jnp.float32)
-    first_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    total = prefix_lens + suffix_lens
-    new_len = caches.lengths.at[slots].set(
-        jnp.where(valid, total, caches.lengths[slots]))
-    new_last = caches.last_token.at[slots].set(
-        jnp.where(valid, first_tok, caches.last_token[slots]))
-    return PagedDecodeCaches(kp=kp_all, vp=vp_all, block_tables=bt,
-                             lengths=new_len,
-                             last_token=new_last), first_tok
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "num_steps",
-                                             "prompt_pad", "attn_impl"),
-                   donate_argnums=(1,))
-def paged_prefill_decode_packed(params: Dict[str, Any],
-                                caches: PagedDecodeCaches,
-                                packed: jax.Array,
-                                cfg: TransformerConfig, num_steps: int,
-                                prompt_pad: int, attn_impl: str = "auto"
-                                ) -> Tuple[PagedDecodeCaches, jax.Array,
-                                           jax.Array]:
-    """Fused suffix-prefill + chunked decode with ALL host inputs in
-    ONE int32 upload (the paged analog of prefill_decode_packed).
-
-    packed: [N+1, Wp] int32 with W = table width and
-    Wp = max(prompt_pad + 4 + W, num_slots);
-      rows 0..N-1: [suffix_tokens[0:P] | suffix_len | prefix_len |
-                    slot | valid | block_table[0:W]]
-      row  N:      active mask for the B decode slots in cols 0..B-1.
-
-    `valid` 1: the row ends its prompt, its slot decodes from this
-    dispatch on.  2: a chunk of a prompt longer than P with more to come:
-    its K/V are written, its slot stays out of the decode steps, and a
-    later dispatch brings the next chunk with prefix_len moved on (the
-    host loop: serve/llm.py).  Arch "afmoe" returns its expert layers'
-    counts as a fourth value.
-    """
-    P = prompt_pad
-    B = caches.lengths.shape[0]
-    W = caches.block_tables.shape[1]
-    tokens = packed[:-1, :P]
-    suffix_lens = packed[:-1, P]
-    prefix_lens = packed[:-1, P + 1]
-    slots = packed[:-1, P + 2]
-    valid = packed[:-1, P + 3] > 0
-    new_bt = packed[:-1, P + 4:P + 4 + W]
-    starts = packed[:-1, P + 3] == 1
-    active = packed[-1, :B] > 0
-    if cfg.arch == "afmoe":
-        caches, first, counts = _afmoe_prefill_core(
-            params, caches, tokens, suffix_lens, prefix_lens, slots, valid,
-            new_bt, cfg, attn_impl)
-        active = active.at[slots].set(
-            jnp.where(starts, True, active[slots]))
-        caches, toks, more = _afmoe_decode_scan(
-            params, caches, active, cfg, num_steps, attn_impl)
-        return caches, first, toks, counts + more
-    caches, first = _paged_prefill_core(params, caches, tokens,
-                                        suffix_lens, prefix_lens, slots,
-                                        valid, new_bt, cfg)
-    active = active.at[slots].set(jnp.where(starts, True, active[slots]))
-
-    def body(c, _):
-        return _paged_decode_core(params, c, active, cfg, attn_impl)
-
-    caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
-    return caches, first, toks
-
-
-# ===========================================================================
-# arch "afmoe": the paged steps over models/afmoe.py's one layer definition
-# ===========================================================================
-# The layers are unrolled (their kinds differ in shape) and each has a pool
-# of its own.  `paged_prefill_layer` and `paged_decode_layer` are what the
-# engine's dispatches are made of, one layer at a time: a caller that
-# cannot hold every layer's weights at once (the benchmark's comparison
-# with the plain reference at published widths) runs these very functions
-# layer by layer.
 
 
 class PrefillRows(NamedTuple):
@@ -771,6 +493,260 @@ def _write_rows(pool, blocks, offsets, new):
           + offsets[..., None]).reshape(-1)
     return pool.reshape(NB * hkv * bs, D).at[at].set(
         new.reshape(-1, D).astype(pool.dtype)).reshape(pool.shape)
+
+
+def _scan_layers(layer, x, layers, caches: PagedDecodeCaches):
+    """The layer scan of arch "llama" / "gpt2" over the stacked pool
+    [L, NB, Hkv, bs, Dh], carried as ONE pool of L * NB blocks: `layer`
+    ((x, k_pool, v_pool), (p, first)) addresses layer i's block b as
+    first + b (first = i * NB) in its writes and in the tables it hands
+    the kernels.  Nothing slices a layer's pool out of the stack or puts
+    it back: the scatter updates the carry in place and the kernels read
+    pages through the table.  -> (x', kp', vp')."""
+    shape = caches.kp.shape
+    L, NB = shape[:2]
+    flat = (L * NB,) + shape[2:]
+    (x, kp, vp), _ = jax.lax.scan(
+        layer, (x, caches.kp.reshape(flat), caches.vp.reshape(flat)),
+        (layers, jnp.arange(L, dtype=jnp.int32) * NB))
+    return x, kp.reshape(shape), vp.reshape(shape)
+
+
+def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
+                       active: jax.Array, cfg: TransformerConfig,
+                       attn_impl: str = "auto"
+                       ) -> Tuple[PagedDecodeCaches, jax.Array]:
+    """One decode step over the block pool (traceable).  Mirrors
+    _decode_core exactly, with the write routed through the block table
+    and attention through ops.paged_attention.  Safe to run extra steps
+    on retired/drained slots: a slot past its allocation writes into its
+    own last position, an inactive one into scratch block 0 and attends
+    to nothing (a retired slot keeps its last length until the next
+    prefill, and the kernel's work follows the lengths it is given); the
+    host drops their outputs."""
+    from ray_tpu.ops import paged_attention as _pa
+
+    rows = decode_rows(caches.block_tables, caches.lengths, active,
+                       caches.kp.shape[3])
+    x = params["tok_embed"][caches.last_token[:, None]].astype(cfg.dtype)
+    if cfg.arch == "gpt2":
+        x = x + params["pos_embed"][
+            jnp.clip(rows.positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
+    rms = cfg.arch == "llama"
+
+    def layer(carry, inputs):
+        x, k_pool, v_pool = carry
+        p, first = inputs
+        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
+                  cfg.norm_eps, rms)
+        q, k_new, v_new = _qkv(p, h, cfg, rows.positions)
+        # one [Hkv, Dh] row per slot
+        k_pool = _write_rows(k_pool, first + rows.blocks, rows.offsets,
+                             k_new[:, 0])
+        v_pool = _write_rows(v_pool, first + rows.blocks, rows.offsets,
+                             v_new[:, 0])
+        o = _pa.paged_attention(q[:, 0], k_pool, v_pool,
+                                first + rows.tables, rows.context_lens,
+                                impl=attn_impl)              # [B,H,Dh]
+        attn = jnp.einsum("bshk,hkd->bsd", o[:, None].astype(cfg.dtype),
+                          p["wo"].astype(cfg.dtype))
+        return (_mlp(p, x + attn, cfg), k_pool, v_pool), None
+
+    x, kp_all, vp_all = _scan_layers(layer, x, params["layers"], caches)
+    x = _norm(x, params["final_norm"], params.get("final_norm_b"),
+              cfg.norm_eps, rms)
+    logits = jnp.einsum(
+        "bsd,dv->bsv", x.astype(jnp.float32),
+        _w_out(params, cfg).astype(jnp.float32))[:, 0]       # [B,V]
+    next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    new_last = jnp.where(active, next_tok, caches.last_token)
+    new_len = jnp.where(active, caches.lengths + 1, caches.lengths)
+    return PagedDecodeCaches(kp=kp_all, vp=vp_all,
+                             block_tables=caches.block_tables,
+                             lengths=new_len,
+                             last_token=new_last), next_tok
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "attn_impl"),
+                   donate_argnums=(1,))
+def paged_decode_step(params: Dict[str, Any], caches: PagedDecodeCaches,
+                      active: jax.Array, cfg: TransformerConfig,
+                      attn_impl: str = "auto"
+                      ) -> Tuple[PagedDecodeCaches, jax.Array]:
+    """One token for every slot; returns (caches', next_tokens [B]);
+    arch "afmoe" also its expert layers' counts (afmoe.MOE_COUNTS)."""
+    if cfg.arch == "afmoe":
+        caches, tok, _, counts = _afmoe_decode_core(
+            params, caches, active, cfg, attn_impl)
+        return caches, tok, counts
+    return _paged_decode_core(params, caches, active, cfg, attn_impl)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "num_steps", "attn_impl"),
+                   donate_argnums=(1,))
+def paged_decode_steps(params: Dict[str, Any], caches: PagedDecodeCaches,
+                       active: jax.Array, cfg: TransformerConfig,
+                       num_steps: int, attn_impl: str = "auto"
+                       ) -> Tuple[PagedDecodeCaches, jax.Array]:
+    """num_steps tokens per slot in ONE dispatch (lax.scan): returns
+    (caches', tokens [num_steps, B]); arch "afmoe" also its expert
+    layers' counts."""
+    if cfg.arch == "afmoe":
+        return _afmoe_decode_scan(params, caches, active, cfg, num_steps,
+                                  attn_impl)
+
+    def body(c, _):
+        return _paged_decode_core(params, c, active, cfg, attn_impl)
+
+    caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
+    return caches, toks
+
+
+def _paged_prefill_core(params: Dict[str, Any],
+                        caches: PagedDecodeCaches, tokens: jax.Array,
+                        suffix_lens: jax.Array, prefix_lens: jax.Array,
+                        slots: jax.Array, valid: jax.Array,
+                        closes: jax.Array, new_bt: jax.Array,
+                        cfg: TransformerConfig, attn_impl: str = "auto"):
+    """Prefill of N rows of P tokens against what their requests have in
+    the pool (traceable) -> (caches', first tokens [N], an expert model's
+    counts or None).
+
+    A row is a TILE of one request's uncached tokens: tokens [N, P] hold
+    suffix_lens[n] of them, at absolute positions prefix_lens[n] + i (RoPE /
+    learned positions stay correct), and new_bt[n] is the request's block
+    table.  What lies before a row (the cached prefix: whole blocks shared
+    through the table and never recomputed, this is where a prefix-cache
+    hit saves its FLOPs; and the tiles before it, of an earlier call or of
+    this one) is read from the pool: every layer writes the whole call's
+    K/V (`_write_rows`) BEFORE it attends (`prefix_attention`), so a later
+    tile of the same call sees an earlier one as prefix.  Several rows may
+    therefore name one slot; only the row that ends its prompt (`closes`)
+    yields the request's first token and hands the slot its table and
+    length.  A row that is not `valid` writes to the scratch block only."""
+    N, P = tokens.shape
+    rows = prefill_rows(new_bt, prefix_lens, suffix_lens, valid, P,
+                        caches.kp[0].shape[-2])
+    last_ix = (jnp.arange(N), jnp.clip(suffix_lens - 1, 0, P - 1))
+    if cfg.arch == "afmoe":
+        from ray_tpu.models import afmoe
+        x = afmoe.embed(cfg, params["tok_embed"], tokens)
+        x, kp, vp, counts = _afmoe_layers(cfg, params, caches, x, rows,
+                                          paged_prefill_layer, attn_impl)
+        logits = afmoe.logits(cfg, params, x[last_ix])
+    else:
+        x, kp, vp = _dense_prefill_layers(cfg, params, caches, tokens, rows,
+                                          attn_impl)
+        counts = None
+        last = _norm(x[last_ix], params["final_norm"],
+                     params.get("final_norm_b"), cfg.norm_eps,
+                     cfg.arch == "llama")                    # [N, D]
+        logits = last.astype(jnp.float32) @ _w_out(params, cfg).astype(
+            jnp.float32)
+    first_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    # A scatter whose in-range indices are distinct: at most one row of a
+    # slot closes, and every other row is sent out of range and dropped.
+    at = jnp.where(closes, slots, caches.lengths.shape[0])
+    return PagedDecodeCaches(
+        kp=kp, vp=vp,
+        block_tables=caches.block_tables.at[at].set(new_bt, mode="drop"),
+        lengths=caches.lengths.at[at].set(prefix_lens + suffix_lens,
+                                          mode="drop"),
+        last_token=caches.last_token.at[at].set(first_tok, mode="drop")
+    ), first_tok, counts
+
+
+def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
+                          attn_impl):
+    """Arch "llama" / "gpt2": the layer scan of one prefill over the
+    stacked pool -> (x' [N, P, D], kp', vp')."""
+    from ray_tpu.ops import paged_attention as _pa
+    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [N,P,D]
+    if cfg.arch == "gpt2":
+        x = x + params["pos_embed"][
+            jnp.clip(rows.positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
+    rms = cfg.arch == "llama"
+
+    def layer(carry, inputs):
+        x, k_pool, v_pool = carry
+        p, first = inputs
+        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
+                  cfg.norm_eps, rms)
+        q, k, v = _qkv(p, h, cfg, rows.positions)
+        k_pool = _write_rows(k_pool, first + rows.blocks, rows.offsets, k)
+        v_pool = _write_rows(v_pool, first + rows.blocks, rows.offsets, v)
+        o = _pa.prefix_attention(q, k_pool, v_pool, first + rows.tables,
+                                 rows.prefix_lens, rows.suffix_lens,
+                                 impl=attn_impl)             # [N,P,H,Dh]
+        attn = jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
+                          p["wo"].astype(cfg.dtype))
+        return (_mlp(p, x + attn, cfg), k_pool, v_pool), None
+
+    return _scan_layers(layer, x, params["layers"], caches)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "num_steps",
+                                             "prompt_pad", "attn_impl"),
+                   donate_argnums=(1,))
+def paged_prefill_decode_packed(params: Dict[str, Any],
+                                caches: PagedDecodeCaches,
+                                packed: jax.Array,
+                                cfg: TransformerConfig, num_steps: int,
+                                prompt_pad: int, attn_impl: str = "auto"
+                                ) -> Tuple[PagedDecodeCaches, jax.Array,
+                                           jax.Array]:
+    """Fused suffix-prefill + chunked decode with ALL host inputs in
+    ONE int32 upload (the paged analog of prefill_decode_packed).
+
+    packed: [N+1, Wp] int32 with W = table width, P = prompt_pad (the
+    width of a row: serve/llm.py PREFILL_TILE) and
+    Wp = max(P + 4 + W, num_slots);
+      rows 0..N-1: [tokens[0:P] | suffix_len | prefix_len |
+                    slot | valid | block_table[0:W]]
+      row  N:      active mask for the B decode slots in cols 0..B-1.
+
+    A row is a tile of one request's uncached tokens; a request longer
+    than P takes several rows, in this call or over several (the host
+    loop: serve/llm.py).  `valid` 0: no row.  1: the row ends its prompt:
+    it yields the first token and its slot decodes from this dispatch on.
+    2: more of the prompt is to come: its K/V are written and its slot
+    stays out of the decode steps.  Arch "afmoe" returns its expert
+    layers' counts as a fourth value.
+    """
+    P = prompt_pad
+    B = caches.lengths.shape[0]
+    W = caches.block_tables.shape[1]
+    flag = packed[:-1, P + 3]
+    closes = flag == 1
+    slots = packed[:-1, P + 2]
+    caches, first, counts = _paged_prefill_core(
+        params, caches, packed[:-1, :P], packed[:-1, P], packed[:-1, P + 1],
+        slots, flag > 0, closes, packed[:-1, P + 4:P + 4 + W], cfg,
+        attn_impl)
+    active = (packed[-1, :B] > 0).at[jnp.where(closes, slots, B)].set(
+        True, mode="drop")
+    if cfg.arch == "afmoe":
+        caches, toks, more = _afmoe_decode_scan(
+            params, caches, active, cfg, num_steps, attn_impl)
+        return caches, first, toks, counts + more
+
+    def body(c, _):
+        return _paged_decode_core(params, c, active, cfg, attn_impl)
+
+    caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
+    return caches, first, toks
+
+
+# ===========================================================================
+# arch "afmoe": the paged steps over models/afmoe.py's one layer definition
+# ===========================================================================
+# The layers are unrolled (their kinds differ in shape) and each has a pool
+# of its own.  `paged_prefill_layer` and `paged_decode_layer` are what the
+# engine's dispatches are made of, one layer at a time: a caller that
+# cannot hold every layer's weights at once (the benchmark's comparison
+# with the plain reference at published widths) runs these very functions
+# layer by layer.
 
 
 def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
@@ -861,31 +837,3 @@ def _afmoe_decode_scan(params, caches, active, cfg, num_steps, attn_impl):
     (caches, counts), toks = jax.lax.scan(
         body, (caches, afmoe.no_counts()), None, length=num_steps)
     return caches, toks, counts
-
-
-def _afmoe_prefill_core(params, caches: PagedDecodeCaches, tokens,
-                        suffix_lens, prefix_lens, slots, valid, new_bt, cfg,
-                        attn_impl):
-    """`_paged_prefill_core` for arch "afmoe": -> (caches', first tokens
-    [N], counts).  Rows and positions that are padding are routed to no
-    expert."""
-    from ray_tpu.models import afmoe
-    N, P = tokens.shape
-    bt = caches.block_tables.at[slots].set(
-        jnp.where(valid[:, None], new_bt, caches.block_tables[slots]))
-    rows = prefill_rows(bt[slots], prefix_lens, suffix_lens, valid, P,
-                        caches.kp[0].shape[2])
-    x = afmoe.embed(cfg, params["tok_embed"], tokens)
-    x, kps, vps, counts = _afmoe_layers(cfg, params, caches, x, rows,
-                                        paged_prefill_layer, attn_impl)
-    last = x[jnp.arange(N), jnp.clip(suffix_lens - 1, 0, P - 1)]
-    first_tok = jnp.argmax(afmoe.logits(cfg, params, last),
-                           axis=-1).astype(jnp.int32)
-    return PagedDecodeCaches(
-        kp=kps, vp=vps, block_tables=bt,
-        lengths=caches.lengths.at[slots].set(
-            jnp.where(valid, prefix_lens + suffix_lens,
-                      caches.lengths[slots])),
-        last_token=caches.last_token.at[slots].set(
-            jnp.where(valid, first_tok, caches.last_token[slots]))
-    ), first_tok, counts

@@ -648,12 +648,16 @@ def prefix_attention(q, k_pool, v_pool, block_tables, prefix_lens,
                      suffix_lens, scale: Optional[float] = None,
                      impl: str = "auto",
                      window: Optional[int] = None) -> jax.Array:
-    """Dispatcher, as `paged_attention`: the kernel on a TPU backend, the
-    gather elsewhere."""
+    """Dispatcher: "auto" is the kernel on a TPU backend where the head
+    size is whole 128-lane tiles (Mosaic slices a page out of an HBM pool
+    at no other), the gather elsewhere and for every other head size: the
+    prefill of arch "llama" / "gpt2" gathered every row's table at every
+    head size before it came here."""
     args = (q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
             scale, window)
     if impl == "kernel" or (impl == "auto"
-                            and jax.default_backend() == "tpu"):
+                            and jax.default_backend() == "tpu"
+                            and q.shape[3] % _LANES == 0):
         return prefix_attention_kernel(*args)
     if impl not in ("auto", "reference"):
         raise ValueError(f"unknown prefix attention impl {impl!r}")

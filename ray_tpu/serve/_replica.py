@@ -90,6 +90,7 @@ class Replica:
                              multiplexed_model_id: str = "") -> Any:
         """Run one request on the user instance (async so batched /
         concurrent user methods interleave on the actor's event loop)."""
+        import asyncio
         import time
         from ray_tpu.serve.multiplex import (_current_model_id,
                                              _set_current_model_id)
@@ -108,7 +109,16 @@ class Replica:
                     RequestRejectedError
                 target = getattr(self._user, method)
                 try:
-                    out = target(*args, **(kwargs or {}))
+                    if inspect.iscoroutinefunction(target):
+                        out = target(*args, **(kwargs or {}))
+                    else:
+                        # A synchronous method runs on a thread, not on
+                        # this loop: while it ran here no coroutine of
+                        # the replica could go on, so one slow call (a
+                        # profiler stopping for 20 s) held back every
+                        # other request's reply.
+                        out = await asyncio.to_thread(
+                            target, *args, **(kwargs or {}))
                     if inspect.isawaitable(out):
                         out = await out
                 except RequestRejectedError as e:

@@ -33,13 +33,17 @@ multiplex.merge_adapter, LRU-resident) without recompiling — same
 shapes, new weights — and each model keys its own radix tree so prefix
 reuse never crosses models.
 
-Long prompts: `prompt_pad` is the longest prompt `submit` accepts; the
-widest prefill the paged engine compiles is min(prompt_pad,
-PREFILL_CHUNK).  A prompt (or uncached suffix) longer than that is
-prefilled in chunks over successive dispatches, each against the blocks
-the earlier chunks wrote; the request holds its slot meanwhile without
-decoding, the other slots decode on in the same dispatches, and the
-radix tree takes the prompt's blocks as they are dispatched.
+Prefill: `prompt_pad` is the longest prompt `submit` accepts.  A row of
+the paged engine's fused prefill is a TILE of PREFILL_TILE tokens of one
+request's uncached suffix, not a request: a suffix longer than a tile
+takes several rows, each against the blocks the rows before it wrote.  A
+dispatch runs the narrowest of a short ladder of compiled row counts that
+holds the tiles it is given, and the widest is the most prefill one
+dispatch carries (PREFILL_CHUNK tokens).  What does not fit waits for the
+next dispatch, first in first served: the request holds its slot
+meanwhile without decoding, the other slots decode on in the same
+dispatches, and the radix tree takes the prompt's blocks as they are
+dispatched.
 
 Pipelining (shared by both engines): a loop that synchronizes with the
 device once per step (dispatch → block on the token read → repeat)
@@ -1090,12 +1094,24 @@ class RadixCache:
         self.size -= 1
 
 
-# The widest prefill the paged engine compiles.  `prompt_pad` is what a
-# client may send; a prompt (or its uncached suffix) longer than this is
-# prefilled in chunks of this width over successive dispatches, each
-# against the blocks the earlier chunks wrote, while the other slots go on
-# decoding in the same dispatches.
-PREFILL_CHUNK = 512
+# A row of the fused prefill holds this many tokens of one request's
+# uncached suffix (a tile), and a dispatch carries at most PREFILL_CHUNK
+# tokens of prefill: the widest program the engine compiles.  What an
+# admission brings that does not fit waits for the next dispatch, holding
+# its slot, while the other slots go on decoding.  64 because a session
+# turn's suffix (a message, the last reply, a partial block) is 20-70
+# tokens: PERF.md section 6, PR 28 has the measurement against 128.
+PREFILL_TILE = 64
+PREFILL_CHUNK = 2048
+
+
+def prefill_shapes(num_slots: int, prompt_pad: int):
+    """(tokens a row, [rows of each compiled fused prefill]) of an engine:
+    the widest program is a tile for every slot, within PREFILL_CHUNK
+    tokens; three more halve down from it."""
+    tile = min(PREFILL_TILE, PREFILL_CHUNK, prompt_pad)
+    widest = max(1, min(num_slots, PREFILL_CHUNK // tile))
+    return tile, sorted({max(1, widest >> k) for k in range(4)})
 
 
 class PagedBatcher(ContinuousBatcher):
@@ -1105,9 +1121,10 @@ class PagedBatcher(ContinuousBatcher):
     Inherits the pipelined dispatch/process machinery and swaps the
     cache layer: admission allocates refcounted blocks (evicting cold
     cached blocks, then QUEUEING under pressure), prefill runs only
-    the prompt's uncached suffix via paged_prefill_decode_packed (in
-    chunks of PREFILL_CHUNK where it is longer), and decode gathers KV
-    through block tables with the ragged paged attention kernel.
+    the prompt's uncached suffix via paged_prefill_decode_packed (as
+    tiles of PREFILL_TILE, at most PREFILL_CHUNK tokens a dispatch), and
+    decode gathers KV through block tables with the ragged paged
+    attention kernel.
     """
 
     supports_multiplex = True
@@ -1149,22 +1166,18 @@ class PagedBatcher(ContinuousBatcher):
         # threads hand work to it through _pending and failures
         # through _waiting_fail, never by mutating the deque.
         self._kv_lock = threading.Lock()
-        # Suffix-prefill width tiers: a prefix-cache hit leaves a short
-        # uncached suffix, and running it through the full prompt_pad-
-        # wide compiled prefill would spend the FLOPs the hit just
-        # saved.  Each admission batch picks the narrowest precompiled
-        # width that fits its longest suffix, so all-hit batches pay a
-        # block-sized prefill instead of a prompt-sized one.
-        self._prefill_pad = min(prompt_pad, PREFILL_CHUNK)
-        if prompt_pad > self._prefill_pad \
-                and self._prefill_pad % self.block_size:
+        # The fused prefill's shapes: rows of `_tile` tokens, and a ladder
+        # of row counts; the widest is the budget of one dispatch.  A
+        # dispatch costs what it admits, to within a rung: a prefix-cache
+        # hit that leaves a short suffix pays for one tile, not for a
+        # prompt-wide row.
+        self._tile, self._prefill_rows = prefill_shapes(num_slots,
+                                                        prompt_pad)
+        if prompt_pad > self._tile and self._tile % self.block_size:
             raise ValueError(
-                f"prompts longer than {PREFILL_CHUNK} are prefilled in "
-                f"chunks of that many tokens, which must be whole blocks "
-                f"of kv_block_size {self.block_size}")
-        self._suffix_pads = sorted({
-            min(max(self.block_size, 16), self._prefill_pad),
-            self._prefill_pad})
+                f"prompts longer than {self._tile} are prefilled in tiles "
+                f"of that many tokens, which must be whole blocks of "
+                f"kv_block_size {self.block_size}")
         self._alloc = BlockAllocator(self.num_blocks)
         self._radix: Dict[str, RadixCache] = {}
         # One LRU clock shared by every model's tree (comparable
@@ -1187,13 +1200,17 @@ class PagedBatcher(ContinuousBatcher):
         self._cache_hits = 0
         self._cache_hit_tokens = 0
         self._evictions = 0
-        # Counted for stats(): prefill rows and their tokens, requests
-        # that took more than one chunk; what an expert model's layers
+        # Counted for stats(): prefill chunks (one per request and
+        # dispatch) and their tokens, the positions the dispatches' rows
+        # held (rows x tile: chunk_tokens / padded_tokens is how full they
+        # were), requests that took more than one dispatch; what an
+        # expert model's layers
         # report per dispatch (models/afmoe.py MOE_COUNTS); and, per
         # dispatch over owned slots and sliding layers, the positions
         # held against those still inside a window (one block id serves
         # every layer, so none beyond a window is freed yet).
         self._prefill_counts = {"chunks": 0, "chunk_tokens": 0,
+                                "padded_tokens": 0,
                                 "multi_chunk_requests": 0}
         self._moe_counts = [0, 0, 0, 0]
         self._sliding_layers = sum(
@@ -1220,20 +1237,19 @@ class PagedBatcher(ContinuousBatcher):
         return self._dec.init_paged_caches(
             cfg, num_slots, self.num_blocks, self.block_size, max_len)
 
-    def _packed_width(self, prompt_pad: int, num_slots: int) -> int:
-        return max(prompt_pad + 4 + self.table_width, num_slots)
+    def _pack(self, rows: int):
+        """The fused dispatch's one upload, empty (decoding.
+        paged_prefill_decode_packed has the format)."""
+        return np.zeros((rows + 1, max(self._tile + 4 + self.table_width,
+                                       self.num_slots)), np.int32)
 
     def _warmup(self, jnp) -> None:
         active = jnp.zeros((self.num_slots,), bool)
-        for N in sorted({self._narrow_width, self.num_slots}):
-            for P in self._suffix_pads:
-                pw = max(P + 4 + self.table_width, self.num_slots)
-                packed = np.zeros((N + 1, pw), np.int32)
-                packed[:N, P + 2] = np.arange(N)
-                self.caches = self._dec.paged_prefill_decode_packed(
-                    self.params, self.caches, jnp.asarray(packed),
-                    self.cfg, self.decode_chunk, P,
-                    attn_impl=self._attn_impl)[0]
+        for N in self._prefill_rows:
+            self.caches = self._dec.paged_prefill_decode_packed(
+                self.params, self.caches, jnp.asarray(self._pack(N)),
+                self.cfg, self.decode_chunk, self._tile,
+                attn_impl=self._attn_impl)[0]
         if self.decode_chunk > 1:
             self.caches, toks = self._dec.paged_decode_steps(
                 self.params, self.caches, active, self.cfg,
@@ -1467,12 +1483,17 @@ class PagedBatcher(ContinuousBatcher):
         req._pos_cap = alloc_tokens
         return True
 
-    def _admit(self, free: List[int]) -> List[tuple]:
+    def _tiles_left(self, req: "_Request") -> int:
+        return -(-(len(req.prompt) - req._prefilled) // self._tile)
+
+    def _admit(self, free: List[int], room: int) -> List[tuple]:
         """FIFO admission with head-of-line backpressure: pop waiting
-        requests while slots AND blocks last; a model mismatch at the
-        head drains current-model slots, then hot-swaps."""
+        requests while slots, blocks AND the dispatch's `room` (prefill
+        rows) last; the last one admitted may bring more tiles than are
+        left and finishes over the next dispatches.  A model mismatch at
+        the head drains current-model slots, then hot-swaps."""
         admitted: List[tuple] = []
-        while self._waiting and len(admitted) < len(free):
+        while self._waiting and len(admitted) < len(free) and room > 0:
             req = self._waiting[0]
             if req.done.is_set():          # failed/cancelled upstream
                 self._waiting.popleft()
@@ -1496,6 +1517,7 @@ class PagedBatcher(ContinuousBatcher):
                 self._finish_request(req, reason="cache")
                 continue
             admitted.append((free[len(admitted)], req))
+            room -= self._tiles_left(req)
         return admitted
 
     def _retire(self, slot: int, req: "_Request") -> None:
@@ -1569,52 +1591,70 @@ class PagedBatcher(ContinuousBatcher):
                 break
         if tail:
             return []
-        # Requests whose prompt has chunks still to come go first: they
-        # hold their slots already.
+        # One dispatch carries at most the widest program's rows.  Requests
+        # whose prompt has tiles still to come go first, oldest admission
+        # first (they hold their slots already; one admission's requests
+        # took slots in ascending order); then the queue, while rows last.
         with self._state_lock:
-            batch = [(i, r) for i, r in enumerate(self._owner)
-                     if r is not None and r._prefilling
-                     and not r.done.is_set()]
+            held = sorted(((i, r) for i, r in enumerate(self._owner)
+                           if r is not None and r._prefilling
+                           and not r.done.is_set()),
+                          key=lambda sr: sr[1]._admit_t)
+        room = self._prefill_rows[-1]
+        batch = []
+        for slot, req in held:
+            if room <= 0:
+                break
+            batch.append((slot, req))
+            room -= self._tiles_left(req)
         if free and self._waiting:
-            batch += self._admit(free)
+            batch += self._admit(free, room)
         return batch
 
     def _fused_dispatch(self, jnp, batch: List[tuple], active,
                         chunk: int):
-        N = (self._narrow_width
-             if len(batch) <= self._narrow_width
-             else self.num_slots)
-        takes = [min(len(req.prompt) - req._prefilled, self._prefill_pad)
-                 for _, req in batch]
-        P = next(p for p in self._suffix_pads if p >= max(takes))
-        W = self.table_width
-        packed = np.zeros((N + 1, max(P + 4 + W, self.num_slots)),
-                          np.int32)
-        rows = []
-        for row, ((slot, req), take) in enumerate(zip(batch, takes)):
-            done = req._prefilled
-            more = done + take < len(req.prompt)
-            packed[row, :take] = req.prompt[done:done + take]
-            packed[row, P] = take
-            packed[row, P + 1] = done
-            packed[row, P + 2] = slot
-            packed[row, P + 3] = 2 if more else 1
-            packed[row, P + 4:P + 4 + len(req._blocks)] = req._blocks
-            rows.append((row, slot, req))
-        self._fill_pad_rows(packed, len(batch), N, rows, P + 2)
+        """`batch` as _pop_admissions cut it: every request in it gets at
+        least one row.  Its uncached tokens go into rows of `_tile`, in
+        order, until the widest program is full; a request cut short there
+        comes back with the next dispatch.  -> (device arrays, [(row of
+        its last tile, slot, req)])."""
+        T = self._tile
+        room = self._prefill_rows[-1]
+        takes = []
+        for _, req in batch:
+            tiles = min(self._tiles_left(req), room)
+            takes.append(min(len(req.prompt) - req._prefilled, tiles * T))
+            room -= tiles
+        N = next(n for n in self._prefill_rows
+                 if n >= self._prefill_rows[-1] - room)
+        packed = self._pack(N)
+        rows, row = [], 0
+        for (slot, req), take in zip(batch, takes):
+            done, end = req._prefilled, req._prefilled + take
+            first = row
+            for start in range(done, end, T):
+                n = min(T, end - start)
+                packed[row, :n] = req.prompt[start:start + n]
+                packed[row, T:T + 4] = (n, start, slot, 2)
+                row += 1
+            packed[first:row, T + 4:T + 4 + len(req._blocks)] = req._blocks
+            if end == len(req.prompt):
+                packed[row - 1, T + 3] = 1
+            rows.append((row - 1, slot, req))
         packed[N, :self.num_slots] = active
         self.caches, *devs = self._dec.paged_prefill_decode_packed(
             self.params, self.caches, jnp.asarray(packed),
-            self.cfg, chunk, P, attn_impl=self._attn_impl)
+            self.cfg, chunk, T, attn_impl=self._attn_impl)
         # Launched: only now do the requests move on.
         for (_, req), take in zip(batch, takes):
             req._prefilled += take
             more = req._prefilled < len(req.prompt)
-            if more and not req._prefilling:    # its first chunk of several
+            if more and not req._prefilling:    # the first of several
                 self._prefill_counts["multi_chunk_requests"] += 1
             req._prefilling = more
         self._prefill_counts["chunks"] += len(batch)
         self._prefill_counts["chunk_tokens"] += sum(takes)
+        self._prefill_counts["padded_tokens"] += N * T
         return tuple(devs), rows
 
     def _decode_dispatch(self, chunk: int) -> tuple:

@@ -182,9 +182,11 @@ def _engine_logits(cfg, params, prompt, chunk, steps, block=8):
         take = min(chunk, len(prompt) - done)
         toks = jnp.zeros((1, chunk), jnp.int32).at[0, :take].set(
             jnp.asarray(prompt[done:done + take]))
-        caches, first, _ = decoding._afmoe_prefill_core(
+        caches, first, _ = decoding._paged_prefill_core(
             params, caches, toks, jnp.asarray([take]), jnp.asarray([done]),
-            jnp.asarray([0]), jnp.asarray([True]), table, cfg, "reference")
+            jnp.asarray([0]), jnp.asarray([True]),
+            jnp.asarray([done + take == len(prompt)]), table, cfg,
+            "reference")
         done += take
     active = jnp.asarray([True, False])
     logits, toks = [], [int(first[0])]
@@ -236,7 +238,7 @@ def test_engine_prefills_long_prompts_in_chunks(model, monkeypatch):
                            prompt_pad=64, decode_chunk=4, kv_block_size=8,
                            kv_num_blocks=40, attn_impl="reference")
     try:
-        assert eng._suffix_pads == [16]
+        assert (eng._tile, eng._prefill_rows) == (16, [1])
         base = tokens(50, seed=3).tolist()
         first = eng.submit(base, max_new=12)
         assert first.done.wait(200) and first.error is None

@@ -31,6 +31,30 @@ def test_deploy_and_call(serve_session):
     assert ray_tpu.get(h.remote(21), timeout=60) == 42
 
 
+def test_slow_sync_method_does_not_hold_back_other_replies(serve_session):
+    """A synchronous method runs on a thread of the replica, not on its
+    event loop: while one call sleeps for seconds, a coroutine of the same
+    replica still answers (on the loop it waited for the sleeper: a
+    profiler stopping for 20 s held back every reply of an LLM replica)."""
+    @serve.deployment(num_replicas=1)
+    class Mixed:
+        def slow(self, seconds):
+            time.sleep(seconds)
+            return "slept"
+
+        async def quick(self):
+            return "quick"
+
+    h = serve.run(Mixed)
+    assert ray_tpu.get(h.quick.remote(), timeout=60) == "quick"
+    sleeper = h.slow.remote(6.0)
+    time.sleep(0.5)                     # the sleeper is running
+    t0 = time.time()
+    assert ray_tpu.get(h.quick.remote(), timeout=60) == "quick"
+    assert time.time() - t0 < 3.0
+    assert ray_tpu.get(sleeper, timeout=60) == "slept"
+
+
 def test_multi_replica_routing(serve_session):
     @serve.deployment(num_replicas=2)
     class Who:

@@ -64,13 +64,8 @@ def test_llama_1b_train_step_compiles_for_v5e(v5e_devices, chips, batch):
     assert per_chip < 15.75 * 2 ** 30, f"{per_chip / 2 ** 30:.2f} GiB"
 
 
-@pytest.mark.parametrize("model", ["llama-1b", "gpt2-small"])
-def test_paged_serving_steps_compile_for_v5e(v5e_devices, model):
-    """Every shape PagedBatcher's warm-up compiles, at LLMDeployment's
-    defaults, with the Pallas paged kernel."""
+def _on_chip_shapes(v5e_devices):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from ray_tpu.models import decoding, transformer as tfm
-
     on_chip = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("x",)),
                             PartitionSpec())
 
@@ -78,6 +73,20 @@ def test_paged_serving_steps_compile_for_v5e(v5e_devices, model):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=on_chip), tree)
 
+    return on_chip, shapes
+
+
+@pytest.mark.parametrize("model", ["llama-1b", "gpt2-small"])
+def test_paged_serving_steps_compile_for_v5e(v5e_devices, model,
+                                             monkeypatch):
+    """Every shape PagedBatcher's warm-up compiles, at LLMDeployment's
+    defaults, as `impl="auto"` reads on the chip: the Pallas paged kernel
+    in the decode steps, and (heads of 64) the gather in the prefill."""
+    from ray_tpu.models import decoding, transformer as tfm
+    from ray_tpu.serve import llm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip, shapes = _on_chip_shapes(v5e_devices)
     cfg = tfm.PRESETS[model]
     slots, max_len, bs, prompt_pad, chunk = 8, 256, 16, 64, 8
     W = decoding.paged_table_width(max_len, bs)
@@ -86,68 +95,74 @@ def test_paged_serving_steps_compile_for_v5e(v5e_devices, model):
     caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
         cfg, slots, slots * W, bs, max_len)))
     active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=on_chip)
-    for N in (4, slots):
-        for P in (16, prompt_pad):
-            packed = jax.ShapeDtypeStruct(
-                (N + 1, max(P + 4 + W, slots)), jnp.int32,
-                sharding=on_chip)
-            assert _custom_calls(decoding.paged_prefill_decode_packed.lower(
-                params, caches, packed, cfg, chunk, P,
-                attn_impl="kernel").compile()) >= 1
+    tile, ladder = llm.prefill_shapes(slots, prompt_pad)
+    assert (tile, ladder) == (64, [1, 2, 4, 8])
+    for N in ladder:
+        packed = jax.ShapeDtypeStruct(
+            (N + 1, max(tile + 4 + W, slots)), jnp.int32, sharding=on_chip)
+        assert _custom_calls(decoding.paged_prefill_decode_packed.lower(
+            params, caches, packed, cfg, chunk, tile).compile()) >= 1
     assert _custom_calls(decoding.paged_decode_steps.lower(
-        params, caches, active, cfg, chunk,
-        attn_impl="kernel").compile()) >= 1
+        params, caches, active, cfg, chunk).compile()) >= 1
     assert _custom_calls(decoding.paged_decode_step.lower(
-        params, caches, active, cfg, attn_impl="kernel").compile()) >= 1
+        params, caches, active, cfg).compile()) >= 1
 
 
-def test_afmoe_serving_steps_compile_for_v5e(v5e_devices, monkeypatch):
-    """benchmarks/configs/trinity-mini-l5.json as the cell runs it: the
-    widest fused prefill + decode and the decode-only chunk, with the
-    window in the paged kernel, `prefix_attention` and the grouped expert
-    product as Mosaic kernels, inside one chip's memory beside 8.5 GB of
-    weights.  (`impl="auto"` asks jax.default_backend(): steered here, in
-    the test, as it would read on the chip.)"""
+# per layer of a fused program: a prefill's `prefix_attention` and a
+# decode step's `paged_attention`; Trinity's 4 expert layers add a grouped
+# product to each
+CELL_KERNELS = {"mistral-7b-l16": 2, "trinity-mini-l5": 5 + 4 + 5 + 4}
+
+
+@pytest.mark.parametrize("config_name", sorted(CELL_KERNELS))
+def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
+                                             config_name):
+    """The serving cells as they run (benchmarks/configs/): every fused
+    prefill + decode of the engine's ladder (rows of PREFILL_TILE tokens:
+    Mistral's 32 / 8 heads of 128 under 48-column tables, Trinity's 32 / 4
+    under 1,072 columns, with its window in the paged kernel and the
+    grouped expert product) and the decode-only chunk, as Mosaic kernels,
+    inside one chip's memory beside the weights.  (`impl="auto"` asks
+    jax.default_backend(): steered here, in the test, as it would read on
+    the chip.)"""
     import json
     import os
 
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
     from benchmarks.lib import spec, worker_util
     from ray_tpu.models import decoding, transformer as tfm
+    from ray_tpu.serve import llm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    on_chip = NamedSharding(Mesh(np.array(v5e_devices[:1]), ("x",)),
-                            PartitionSpec())
-
-    def shapes(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=on_chip), tree)
-
+    on_chip, shapes = _on_chip_shapes(v5e_devices)
     with open(os.path.join(spec.BENCH_DIR, "configs",
-                           "trinity-mini-l5.json")) as f:
+                           config_name + ".json")) as f:
         config = json.load(f)
     sv = config["serve"]
     cfg = tfm.TransformerConfig(**worker_util.with_dtypes(
-        spec.model_kind("afmoe").transformer_kwargs(
+        spec.model_kind(config["kind"]).transformer_kwargs(
             config, max_seq=sv["max_len"], param_dtype=sv["param_dtype"])))
     W = decoding.paged_table_width(sv["max_len"], sv["kv_block_size"])
+    assert W == {"mistral-7b-l16": 48, "trinity-mini-l5": 1072}[config_name]
     params = shapes(jax.eval_shape(
         lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
     caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
         cfg, sv["num_slots"], sv["kv_num_blocks"], sv["kv_block_size"],
         sv["max_len"])))
-    N, P = sv["num_slots"], 512
-    packed = jax.ShapeDtypeStruct((N + 1, P + 4 + W), jnp.int32,
-                                  sharding=on_chip)
-    fused = decoding.paged_prefill_decode_packed.lower(
-        params, caches, packed, cfg, sv["decode_chunk"], P,
-        attn_impl="kernel").compile()
-    # per layer: prefix attention + (4 of 5) experts, then paged + experts
-    assert _custom_calls(fused) >= 5 + 4 + 5 + 4
-    mem = fused.memory_analysis()
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            < 15.75 * 2 ** 30)
+    tile, ladder = llm.prefill_shapes(sv["num_slots"], sv["prompt_pad"])
+    assert tile * ladder[-1] == llm.PREFILL_CHUNK and len(ladder) <= 4
+    for N in ladder:
+        packed = jax.ShapeDtypeStruct(
+            (N + 1, max(tile + 4 + W, sv["num_slots"])), jnp.int32,
+            sharding=on_chip)
+        fused = decoding.paged_prefill_decode_packed.lower(
+            params, caches, packed, cfg, sv["decode_chunk"], tile,
+            attn_impl="kernel").compile()
+        assert _custom_calls(fused) >= CELL_KERNELS[config_name]
+        mem = fused.memory_analysis()
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                < 15.75 * 2 ** 30)
+    N = sv["num_slots"]
     active = jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=on_chip)
     assert _custom_calls(decoding.paged_decode_steps.lower(
         params, caches, active, cfg, sv["decode_chunk"],
-        attn_impl="kernel").compile()) >= 9
+        attn_impl="kernel").compile()) >= CELL_KERNELS[config_name] // 2

@@ -189,17 +189,13 @@ def test_windowed_paged_kernel_at_afmoe_widths_on_tpu(window):
                                atol=2e-2, rtol=2e-2)
 
 
-@pytest.mark.parametrize("window", [2048, None])
-def test_prefix_attention_at_afmoe_widths_on_tpu(window):
-    """A 512 chunk over prefixes of 0, 448, 2,000 and 16,384 positions, a
-    partly filled chunk and a row that is padding; the plain attention is
-    computed row by row over each row's own keys."""
+def _check_prefix_attention(kp, vp, tables, q, prefix, suffix, window):
+    """The kernel against plain attention, computed row by row over each
+    row's own keys."""
     from ray_tpu.ops.paged_attention import prefix_attention
-    N, P, H, hkv, D, bs, W = 6, 512, 32, 4, 128, 16, 1072
-    kp, vp, tables = _afmoe_pool(N, W, seed=1)
-    q = jax.random.normal(jax.random.PRNGKey(2), (N, P, H, D), jnp.bfloat16)
-    prefix = jnp.asarray([0, 448, 2000, 16384, 9000, 64], jnp.int32)
-    suffix = jnp.asarray([512, 512, 300, 512, 40, 0], jnp.int32)
+    N, P, H, D = q.shape
+    hkv, bs = kp.shape[1], kp.shape[2]
+    W = tables.shape[1]
     got = prefix_attention(q, kp, vp, tables, prefix, suffix, impl="kernel",
                            window=window)
 
@@ -228,6 +224,44 @@ def test_prefix_attention_at_afmoe_widths_on_tpu(window):
                     np.asarray(got[n, :live], np.float32),
                     np.asarray(want[:live]), atol=2e-2, rtol=2e-2)
     assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+
+
+@pytest.mark.parametrize("window", [2048, None])
+def test_prefix_attention_at_afmoe_widths_on_tpu(window):
+    """A 512 chunk over prefixes of 0, 448, 2,000 and 16,384 positions, a
+    partly filled chunk and a row that is padding."""
+    N, P, H, W = 6, 512, 32, 1072
+    kp, vp, tables = _afmoe_pool(N, W, seed=1)
+    q = jax.random.normal(jax.random.PRNGKey(2), (N, P, H, 128),
+                          jnp.bfloat16)
+    _check_prefix_attention(
+        kp, vp, tables, q,
+        jnp.asarray([0, 448, 2000, 16384, 9000, 64], jnp.int32),
+        jnp.asarray([512, 512, 300, 512, 40, 0], jnp.int32), window)
+
+
+@pytest.mark.parametrize("tile", [64, 128])
+@pytest.mark.parametrize("hkv,W,window", [(8, 48, None), (4, 1072, 2048),
+                                          (4, 1072, None)])
+def test_prefix_attention_at_tile_rows_on_tpu(hkv, W, window, tile):
+    """The engine's fused prefill as it packs it (serve/llm.py): 32 rows of
+    one tile, at Mistral's 32 / 8 heads of 128 under 48-column tables and
+    Trinity's 32 / 4 under 1,072.  Rows 0-2 are three tiles of ONE request
+    (one table; each sees the tiles before it as prefix), then a whole
+    prompt of one tile, session turns of 20-70 tokens behind cached
+    prefixes, and rows that are padding."""
+    N, H = 32, 32
+    kp, vp, tables = _afmoe_pool(N, W, seed=3, hkv=hkv)
+    tables = tables.at[1:3].set(tables[0])
+    q = jax.random.normal(jax.random.PRNGKey(4), (N, tile, H, 128),
+                          jnp.bfloat16)
+    far = W * 16 - tile - 16                   # the table's last tile
+    prefix = [256, 256 + tile, 256 + 2 * tile, 0, 448, 320, 16, far, 0]
+    suffix = [tile, tile, 17, tile, 45, min(70, tile), 20, tile, 1]
+    pad = N - len(prefix)
+    _check_prefix_attention(
+        kp, vp, tables, q, jnp.asarray(prefix + [0] * pad, jnp.int32),
+        jnp.asarray(suffix + [0] * pad, jnp.int32), window)
 
 
 @pytest.mark.parametrize("rows", [32, 2048])
