@@ -249,11 +249,6 @@ _D("prefix_cache_enabled", bool, True,
    "Paged-KV serving: keep retired requests' full prompt blocks in a "
    "per-model radix tree so later prompts sharing the prefix decode "
    "from cached blocks (prefill runs only the uncached suffix).")
-_D("kv_eviction_policy", str, "lru",
-   "Paged-KV serving: how cached (refcount-0) prefix blocks are "
-   "reclaimed when the free pool empties.  Only 'lru' is implemented; "
-   "the knob exists so a different policy is a config change, not an "
-   "API change.")
 _D("serve_compiled_pipeline", bool, False,
    "Serve fast lane: route unary deployment requests through a "
    "per-replica compiled graph (router handoff writes into the "

@@ -539,7 +539,11 @@ class StreamChannelMixin:
         self._emit_event(ev)
 
     def _h_timeline(self, ctx: _ConnCtx, m: dict) -> None:
-        events = list(self._events)
+        # Under the lock: _h_task_done registers a task's result (which
+        # wakes its getter) and emits its lifecycle record in one hold,
+        # so a caller that has seen the result also sees the record.
+        with self.lock:
+            events = list(self._events)
         if m.get("cluster") and self.multinode:
             replies, _ = self._fanout_peers({"type": "timeline",
                                              "cluster": False})

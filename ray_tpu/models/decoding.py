@@ -4,25 +4,25 @@ The reference serves LLMs by delegating to vLLM on GPU (e.g.
 doc/source/serve/doc_code/vllm_example.py); the TPU-native build owns
 the decode loop itself, shaped for XLA:
 
-* FIXED shapes everywhere — prefill and decode_step compile ONCE and
-  are reused for the server's lifetime.  Two cache layouts share that
-  property: the dense per-slot cache [B, M, Hkv, Dh] (DecodeCaches,
-  every slot reserves M max positions) and the PAGED cache
-  (PagedDecodeCaches below: a [NB, Hkv, bs, Dh] block pool addressed
-  through per-slot block tables, so memory scales with tokens actually
-  cached and full blocks are shareable across requests).
-* decode_step advances every active slot one token per call (the inner
-  loop of continuous batching): one [B,1,D] layer pass, scatter the new
-  k/v into the caches with static-shape advanced indexing, attend
-  against the full cache under a per-slot length mask (paged variants
-  scatter/gather through the block table instead).
-* prefill runs the prompt through the stacked layers once (causal
-  within the prompt), returning per-layer k/v to be inserted into a
-  free slot; the paged analog prefills only the prompt's uncached
-  SUFFIX against a gathered cached-prefix window.
+* FIXED shapes everywhere: each prefill width and each decode chunk
+  compiles ONCE and is reused for the server's lifetime.
+* One cache layout, PagedDecodeCaches: a [NB, Hkv, bs, Dh] block pool
+  per layer addressed through per-slot block tables, so memory scales
+  with the tokens actually cached and full blocks are shareable across
+  requests.
+* A decode step advances every active slot one token per call (the inner
+  loop of continuous batching): one [B,1,D] layer pass, the new k/v
+  written into the pool through the block table, attention over the
+  slot's pages under its length.  Greedy argmax and the last-token
+  feedback happen ON DEVICE, so the host costs one small [B]-int
+  transfer per read.
+* A prefill runs rows of a prompt's uncached SUFFIX through the layers
+  (causal within the prompt), against what the request already has in
+  the pool: the cached prefix is never recomputed.
 
 Everything reuses transformer.py's parameter layout (init_params),
-norms and RoPE, so any trained checkpoint serves unchanged.
+norms and RoPE, so any trained checkpoint serves unchanged, and the
+tests hold every step to greedy transformer.forward.
 
 Arch "afmoe" (models/afmoe.py: window and full layers mixed, expert
 layers) has its paged steps at the end of this file, built from that
@@ -41,26 +41,6 @@ import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (TransformerConfig, _norm, _rope,
                                         _w_out)
-
-
-class DecodeCaches(NamedTuple):
-    """Per-layer KV caches + per-slot bookkeeping (all fixed-shape)."""
-
-    k: jax.Array          # [L, B, M, Hkv, Dh]
-    v: jax.Array          # [L, B, M, Hkv, Dh]
-    lengths: jax.Array    # [B] int32 — tokens currently cached per slot
-    last_token: jax.Array  # [B] int32 — input to the next decode step
-
-
-def init_caches(cfg: TransformerConfig, num_slots: int,
-                max_len: int) -> DecodeCaches:
-    shape = (cfg.n_layers, num_slots, max_len, cfg.kv_heads,
-             cfg.head_dim)
-    return DecodeCaches(
-        k=jnp.zeros(shape, cfg.dtype),
-        v=jnp.zeros(shape, cfg.dtype),
-        lengths=jnp.zeros((num_slots,), jnp.int32),
-        last_token=jnp.zeros((num_slots,), jnp.int32))
 
 
 def _qkv(p, h, cfg: TransformerConfig, positions):
@@ -90,293 +70,12 @@ def _mlp(p, x, cfg: TransformerConfig):
     return x + down
 
 
-def _gqa_scores(q, k_cache, cfg: TransformerConfig):
-    """q: [B,1,H,Dh], k_cache: [B,M,Hkv,Dh] -> scores [B,H,M] (f32)."""
-    groups = cfg.n_heads // cfg.kv_heads
-    B, M = k_cache.shape[0], k_cache.shape[1]
-    qg = q[:, 0].reshape(B, cfg.kv_heads, groups, cfg.head_dim)
-    s = jnp.einsum("bhgk,bmhk->bhgm", qg.astype(jnp.float32),
-                   k_cache.astype(jnp.float32))
-    return s.reshape(B, cfg.n_heads, M) / (cfg.head_dim ** 0.5)
-
-
-def _decode_core(params: Dict[str, Any], caches: DecodeCaches,
-                 active: jax.Array, cfg: TransformerConfig
-                 ) -> Tuple[DecodeCaches, jax.Array]:
-    """One decode step (traceable): greedy argmax and the last-token
-    feedback happen ON DEVICE, so the host costs one small [B]-int
-    transfer per read.  Safe to run extra steps on retired slots: every
-    cache position is overwritten by its owner BEFORE it is first
-    attended (scatter-at-pos precedes the mask reaching pos), so a
-    reused slot never reads a predecessor's leftovers."""
-    B = caches.lengths.shape[0]
-    tokens = caches.last_token[:, None]                      # [B,1]
-    pos = caches.lengths[:, None]                            # [B,1]
-    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [B,1,D]
-    if cfg.arch == "gpt2":
-        x = x + params["pos_embed"][
-            jnp.clip(pos, 0, cfg.max_seq - 1)].astype(cfg.dtype)
-    rms = cfg.arch == "llama"
-    batch_ix = jnp.arange(B)
-    M = caches.k.shape[2]
-    # j attends iff j <= current position (cache holds pos new entries
-    # after the scatter below, indices 0..pos inclusive of the new one).
-    mask = jnp.arange(M)[None, :] <= pos                     # [B,M]
-
-    def layer(x, inputs):
-        p, k_cache, v_cache = inputs
-        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
-                  cfg.norm_eps, rms)
-        q, k_new, v_new = _qkv(p, h, cfg, pos)
-        # Inactive slots must keep their cache untouched: a later,
-        # shorter prompt reusing the slot would otherwise attend to the
-        # garbage written at its old length position.
-        gate = active[:, None, None]
-        k_cache = k_cache.at[batch_ix, caches.lengths].set(
-            jnp.where(gate, k_new[:, 0],
-                      k_cache[batch_ix, caches.lengths]))
-        v_cache = v_cache.at[batch_ix, caches.lengths].set(
-            jnp.where(gate, v_new[:, 0],
-                      v_cache[batch_ix, caches.lengths]))
-        s = _gqa_scores(q, k_cache, cfg)                     # [B,H,M]
-        s = jnp.where(mask[:, None, :], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        groups = cfg.n_heads // cfg.kv_heads
-        wg = w.reshape(B, cfg.kv_heads, groups, M)
-        o = jnp.einsum("bhgm,bmhk->bhgk", wg,
-                       v_cache.astype(jnp.float32))
-        o = o.reshape(B, 1, cfg.n_heads, cfg.head_dim).astype(cfg.dtype)
-        attn = jnp.einsum("bshk,hkd->bsd", o,
-                          p["wo"].astype(cfg.dtype))
-        x = x + attn
-        x = _mlp(p, x, cfg)
-        return x, (k_cache, v_cache)
-
-    def scan_fn(x, inputs):
-        x, kv = layer(x, inputs)
-        return x, kv
-
-    x, (k_all, v_all) = jax.lax.scan(
-        scan_fn, x, (params["layers"], caches.k, caches.v))
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"),
-              cfg.norm_eps, rms)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x.astype(jnp.float32),
-        _w_out(params, cfg).astype(jnp.float32))[:, 0]       # [B,V]
-    next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    new_last = jnp.where(active, next_tok, caches.last_token)
-    new_len = jnp.where(active, caches.lengths + 1, caches.lengths)
-    return DecodeCaches(k=k_all, v=v_all, lengths=new_len,
-                        last_token=new_last), next_tok
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
-def decode_step(params: Dict[str, Any], caches: DecodeCaches,
-                active: jax.Array, cfg: TransformerConfig
-                ) -> Tuple[DecodeCaches, jax.Array]:
-    """One token for every slot; returns (caches', next_tokens [B])."""
-    return _decode_core(params, caches, active, cfg)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "num_steps"),
-                   donate_argnums=(1,))
-def decode_steps(params: Dict[str, Any], caches: DecodeCaches,
-                 active: jax.Array, cfg: TransformerConfig,
-                 num_steps: int) -> Tuple[DecodeCaches, jax.Array]:
-    """num_steps tokens per slot in ONE dispatch (lax.scan): returns
-    (caches', tokens [num_steps, B]): the per-read host round trip
-    amortizes over num_steps * B tokens instead of B."""
-
-    def body(c, _):
-        c, tok = _decode_core(params, c, active, cfg)
-        return c, tok
-
-    caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
-    return caches, toks
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def prefill(params: Dict[str, Any], tokens: jax.Array, length: jax.Array,
-            cfg: TransformerConfig
-            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Prompt pass.  tokens: [1, P] int32 (padded), length: true length.
-    Returns (k [L,P,Hkv,Dh], v [L,P,Hkv,Dh], last_logits [vocab])."""
-    P = tokens.shape[1]
-    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [1,P,D]
-    positions = jnp.arange(P, dtype=jnp.int32)[None]
-    if cfg.arch == "gpt2":
-        x = x + params["pos_embed"][:P][None].astype(cfg.dtype)
-    rms = cfg.arch == "llama"
-    causal = (jnp.arange(P)[:, None] >= jnp.arange(P)[None, :])
-    padmask = jnp.arange(P)[None, :] < length                # [1,P]
-
-    def layer(x, p):
-        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
-                  cfg.norm_eps, rms)
-        q, k, v = _qkv(p, h, cfg, positions)
-        groups = cfg.n_heads // cfg.kv_heads
-        qg = q.reshape(1, P, cfg.kv_heads, groups, cfg.head_dim)
-        s = jnp.einsum("bqhgk,bmhk->bhgqm", qg.astype(jnp.float32),
-                       k.astype(jnp.float32)) / (cfg.head_dim ** 0.5)
-        s = jnp.where(causal[None, None, None], s, -jnp.inf)
-        s = jnp.where(padmask[:, None, None, None, :], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqm,bmhk->bqhgk", w, v.astype(jnp.float32))
-        o = o.reshape(1, P, cfg.n_heads, cfg.head_dim).astype(cfg.dtype)
-        attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
-        x = x + attn
-        x = _mlp(p, x, cfg)
-        return x, (k[0], v[0])
-
-    x, (k_all, v_all) = jax.lax.scan(layer, x, params["layers"])
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"),
-              cfg.norm_eps, rms)
-    last = x[0, jnp.clip(length - 1, 0, P - 1)]
-    logits = last.astype(jnp.float32) @ _w_out(params, cfg).astype(
-        jnp.float32)
-    return k_all, v_all, logits
-
-
-def _prefill_insert_core(params: Dict[str, Any], caches: DecodeCaches,
-                         tokens: jax.Array, lengths: jax.Array,
-                         slots: jax.Array, valid: jax.Array,
-                         cfg: TransformerConfig
-                         ) -> Tuple[DecodeCaches, jax.Array]:
-    """Traceable body shared by prefill_insert and the fused
-    admission+decode step."""
-    N, P = tokens.shape
-    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [N,P,D]
-    positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (N, P))
-    if cfg.arch == "gpt2":
-        x = x + params["pos_embed"][:P][None].astype(cfg.dtype)
-    rms = cfg.arch == "llama"
-    causal = (jnp.arange(P)[:, None] >= jnp.arange(P)[None, :])
-    padmask = jnp.arange(P)[None, :] < lengths[:, None]      # [N,P]
-
-    def layer(x, p):
-        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
-                  cfg.norm_eps, rms)
-        q, k, v = _qkv(p, h, cfg, positions)
-        groups = cfg.n_heads // cfg.kv_heads
-        qg = q.reshape(N, P, cfg.kv_heads, groups, cfg.head_dim)
-        s = jnp.einsum("bqhgk,bmhk->bhgqm", qg.astype(jnp.float32),
-                       k.astype(jnp.float32)) / (cfg.head_dim ** 0.5)
-        s = jnp.where(causal[None, None, None], s, -jnp.inf)
-        s = jnp.where(padmask[:, None, None, None, :], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqm,bmhk->bqhgk", w, v.astype(jnp.float32))
-        o = o.reshape(N, P, cfg.n_heads, cfg.head_dim).astype(cfg.dtype)
-        attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(cfg.dtype))
-        x = x + attn
-        x = _mlp(p, x, cfg)
-        return x, (k, v)
-
-    x, (k_all, v_all) = jax.lax.scan(layer, x, params["layers"])
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"),
-              cfg.norm_eps, rms)
-    last_ix = jnp.clip(lengths - 1, 0, P - 1)
-    last = x[jnp.arange(N), last_ix]                         # [N,D]
-    logits = last.astype(jnp.float32) @ _w_out(params, cfg).astype(
-        jnp.float32)
-    first_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    # Scatter into the cache: [L,N,P,...] -> positions [:, slot, :P].
-    gate = valid[None, :, None, None, None]
-    old_k = caches.k[:, slots, :P]
-    old_v = caches.v[:, slots, :P]
-    ck = caches.k.at[:, slots, :P].set(
-        jnp.where(gate, k_all.astype(caches.k.dtype), old_k))
-    cv = caches.v.at[:, slots, :P].set(
-        jnp.where(gate, v_all.astype(caches.v.dtype), old_v))
-    new_len = caches.lengths.at[slots].set(
-        jnp.where(valid, lengths, caches.lengths[slots]))
-    new_last = caches.last_token.at[slots].set(
-        jnp.where(valid, first_tok, caches.last_token[slots]))
-    return DecodeCaches(k=ck, v=cv, lengths=new_len,
-                        last_token=new_last), first_tok
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",),
-                   donate_argnums=(1,))
-def prefill_insert(params: Dict[str, Any], caches: DecodeCaches,
-                   tokens: jax.Array, lengths: jax.Array,
-                   slots: jax.Array, valid: jax.Array,
-                   cfg: TransformerConfig
-                   ) -> Tuple[DecodeCaches, jax.Array]:
-    """Batched prefill of up to N prompts + cache insertion in ONE
-    dispatch.  tokens: [N, P] int32 (padded), lengths/slots/valid: [N].
-    Invalid rows rewrite their target slot with its existing contents
-    (gather-then-scatter no-op).  Returns (caches', first_tokens [N]).
-
-    Serving admission is the other latency cliff besides decode reads:
-    batching makes 16 admissions cost one dispatch and one sync, not
-    sixteen."""
-    return _prefill_insert_core(params, caches, tokens, lengths, slots,
-                                valid, cfg)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "num_steps",
-                                             "prompt_pad"),
-                   donate_argnums=(1,))
-def prefill_decode_packed(params: Dict[str, Any], caches: DecodeCaches,
-                          packed: jax.Array, cfg: TransformerConfig,
-                          num_steps: int, prompt_pad: int
-                          ) -> Tuple[DecodeCaches, jax.Array,
-                                     jax.Array]:
-    """prefill_decode_fused with ALL host-side inputs in ONE int32
-    array — the engine packs tokens/lengths/slots/valid/active into a
-    single upload, one host->device transfer per dispatch.
-
-    packed: [N+1, W] int32 with W = max(prompt_pad + 3, num_slots);
-      rows 0..N-1: [tokens[0:P] | length | slot | valid]
-      row  N:      active mask for the B decode slots in cols 0..B-1.
-    """
-    P = prompt_pad
-    B = caches.lengths.shape[0]
-    tokens = packed[:-1, :P]
-    lengths = packed[:-1, P]
-    slots = packed[:-1, P + 1]
-    valid = packed[:-1, P + 2] > 0
-    active = packed[-1, :B] > 0
-    caches, first = _prefill_insert_core(params, caches, tokens,
-                                         lengths, slots, valid, cfg)
-    active = active.at[slots].set(jnp.where(valid, True, active[slots]))
-
-    def body(c, _):
-        return _decode_core(params, c, active, cfg)
-
-    caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
-    return caches, first, toks
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def insert_slot(caches: DecodeCaches, slot: jax.Array, k: jax.Array,
-                v: jax.Array, length: jax.Array, first_token: jax.Array
-                ) -> DecodeCaches:
-    """Install a prefilled request into a decode slot.  k/v: [L,P,...];
-    P <= M (cache width) — padded positions beyond `length` are masked
-    by the per-slot length at attention time."""
-    P = k.shape[1]
-    ck = caches.k.at[:, slot, :P].set(k.astype(caches.k.dtype))
-    cv = caches.v.at[:, slot, :P].set(v.astype(caches.v.dtype))
-    return DecodeCaches(
-        k=ck, v=cv,
-        lengths=caches.lengths.at[slot].set(length),
-        last_token=caches.last_token.at[slot].set(first_token))
-
-
-def set_last_tokens(caches: DecodeCaches,
-                    tokens: jax.Array) -> DecodeCaches:
-    return caches._replace(last_token=tokens)
-
-
 # ===========================================================================
 # Paged KV cache (block pool + per-slot block tables)
 # ===========================================================================
-# The dense DecodeCaches above reserves max_len positions per slot; the
-# paged variant stores KV in fixed-size blocks from a shared pool and
-# addresses them through per-slot block tables, so short sequences use
-# blocks proportional to their length and FULL prompt blocks are
+# KV lives in fixed-size blocks from a shared pool, addressed through
+# per-slot block tables: a sequence uses blocks in proportion to its
+# length (no slot reserves max_len positions), and FULL prompt blocks are
 # refcount-shareable across requests (the serve/llm.py prefix cache).
 # Attention goes through ops/paged_attention.py: `paged_attention` for a
 # decode step, `prefix_attention` for a prefill's rows (Pallas kernels on
@@ -516,9 +215,9 @@ def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
                        active: jax.Array, cfg: TransformerConfig,
                        attn_impl: str = "auto"
                        ) -> Tuple[PagedDecodeCaches, jax.Array]:
-    """One decode step over the block pool (traceable).  Mirrors
-    _decode_core exactly, with the write routed through the block table
-    and attention through ops.paged_attention.  Safe to run extra steps
+    """One decode step over the block pool (traceable): the write is
+    routed through the block table and attention goes through
+    ops.paged_attention.  Safe to run extra steps
     on retired/drained slots: a slot past its allocation writes into its
     own last position, an inactive one into scratch block 0 and attends
     to nothing (a retired slot keeps its last length until the next
@@ -697,7 +396,7 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
                                 ) -> Tuple[PagedDecodeCaches, jax.Array,
                                            jax.Array]:
     """Fused suffix-prefill + chunked decode with ALL host inputs in
-    ONE int32 upload (the paged analog of prefill_decode_packed).
+    ONE int32 upload: one host->device transfer per dispatch.
 
     packed: [N+1, Wp] int32 with W = table width, P = prompt_pad (the
     width of a row: serve/llm.py PREFILL_TILE) and

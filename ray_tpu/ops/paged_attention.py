@@ -14,8 +14,8 @@ Two implementations behind one dispatcher:
 * `paged_attention_reference` — pure JAX (`jnp.take` gather through the
   block table + masked softmax), runs everywhere and is the numerics
   oracle the CPU tier-1 suite exercises.  Mathematically identical to
-  the dense decode attention in models/decoding.py (_gqa_scores +
-  length mask), just addressed through the table.
+  the attention of transformer.forward at one query position (GQA
+  scores under a length mask), just addressed through the table.
 * `paged_attention_kernel` — a Pallas TPU kernel whose work follows the
   positions that are cached, not the table's width.  The grid is the
   batch: ONE program per sequence, all kv heads inside it.  The pools
@@ -92,9 +92,9 @@ def paged_attention_reference(q: jax.Array, k_pool: jax.Array,
     """Gather-based paged attention (the CPU/tier-1 path).
 
     Gathers each sequence's blocks into a [B, Hkv, W*bs, D] window with
-    `jnp.take`, then runs exactly the dense decode attention math:
-    f32 scores, -inf mask beyond context_lens, softmax, f32 weighted
-    sum — so paged decode matches dense `decode_step` numerics.
+    `jnp.take`, then runs plain attention over it: f32 scores, -inf
+    mask beyond context_lens, softmax, f32 weighted sum — so a paged
+    decode step matches `transformer.forward` at that position.
     """
     B, H, D = q.shape
     hkv, bs = k_pool.shape[1], k_pool.shape[2]

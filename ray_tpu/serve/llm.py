@@ -1,17 +1,13 @@
-"""Continuous-batched LLM serving on TPU — paged KV edition.
+"""Continuous-batched LLM serving on TPU over a paged KV cache.
 
 The reference's serving north star (BASELINE.json: "Llama-3 8B Ray
 Serve continuous batching") delegates the engine to vLLM/GPU; here the
 engine is native.  New requests are admitted into free slots between
-decode steps (iteration-level scheduling, the Orca/vLLM idea), so one
-fixed-shape compiled step serves everything — no recompilation, no
+decode steps (iteration-level scheduling, the Orca/vLLM idea), so a few
+fixed-shape compiled steps serve everything — no recompilation, no
 dynamic shapes, MXU fed by the [B,1,D] batch.
 
-Round-4 engine: PAGED KV.  The original engine (kept as
-`ContinuousBatcher`, the `paged_kv=False` escape hatch for one
-release) reserves a dense max_len KV slab per slot, so every 30-token
-request pays for 256 positions and the cache caps slot count.
-`PagedBatcher` replaces the slab with a shared pool of fixed-size KV
+The engine is `PagedBatcher`.  KV lives in a shared pool of fixed-size
 *blocks* (kv_block_size tokens each) addressed through per-request
 block tables: admission allocates exactly ceil((prompt + max_new) /
 block_size) blocks, decode gathers through the table with the ragged
@@ -21,22 +17,28 @@ radix/prefix cache: retired requests leave their full prompt blocks in
 a per-model radix tree, a new prompt's longest cached block-prefix is
 refcount-shared into its table, and device prefill runs only the
 uncached suffix — a cache-hit TTFT is route + queue + a suffix-sized
-prefill (the PR-1 TTFT decomposition now carries `cache_hit`).  Cold
-blocks are LRU-evicted back to the free pool under pressure; when the
-pool is empty a new request *queues* for blocks (backpressure) instead
-of dying, and finish-reason "cache" is reserved for a single request
-that exceeds the whole pool (or its table), never for transient
-exhaustion.  The engine also folds in serve.multiplex: requests tagged
-with a `multiplexed_model_id` hot-swap LoRA adapters (fetched by
-ObjectRef over the PR-4 binary transfer plane, merged via
-multiplex.merge_adapter, LRU-resident) without recompiling — same
-shapes, new weights — and each model keys its own radix tree so prefix
-reuse never crosses models.
+prefill (the TTFT decomposition carries `cache_hit`).  Cold blocks are
+LRU-evicted back to the free pool under pressure; when the pool is
+empty a new request *queues* for blocks (backpressure) instead of
+dying, and finish-reason "cache" is reserved for a single request that
+exceeds the whole pool (or its table), never for transient exhaustion.
+The engine also folds in serve.multiplex: requests tagged with a
+`multiplexed_model_id` hot-swap LoRA adapters (fetched by ObjectRef
+over the binary transfer plane, merged via multiplex.merge_adapter,
+LRU-resident) without recompiling — same shapes, new weights — and each
+model keys its own radix tree so prefix reuse never crosses models.
+
+A dispatch is one device program per tick of the dispatcher thread:
+`decode_chunk` decode steps of every live slot, with whatever prefill
+is waiting FUSED into the same program (paged_prefill_decode_packed),
+so an admission costs no dispatch of its own; with nothing to admit it
+is the decode-only program (paged_decode_steps).  All host inputs of a
+dispatch travel in one int32 upload.
 
 Prefill: `prompt_pad` is the longest prompt `submit` accepts.  A row of
-the paged engine's fused prefill is a TILE of PREFILL_TILE tokens of one
-request's uncached suffix, not a request: a suffix longer than a tile
-takes several rows, each against the blocks the rows before it wrote.  A
+the fused prefill is a TILE of PREFILL_TILE tokens of one request's
+uncached suffix, not a request: a suffix longer than a tile takes
+several rows, each against the blocks the rows before it wrote.  A
 dispatch runs the narrowest of a short ladder of compiled row counts that
 holds the tiles it is given, and the widest is the most prefill one
 dispatch carries (PREFILL_CHUNK tokens).  What does not fit waits for the
@@ -45,21 +47,18 @@ meanwhile without decoding, the other slots decode on in the same
 dispatches, and the radix tree takes the prompt's blocks as they are
 dispatched.
 
-Pipelining (shared by both engines): a loop that synchronizes with the
-device once per step (dispatch → block on the token read → repeat)
-leaves the chip idle for every host round trip.  The engine keeps up
-to `pipeline_depth` dispatches in flight, starts device→host token
-copies asynchronously at dispatch time (`copy_to_host_async`), and
-only materializes the OLDEST in-flight result — so the chip computes
-chunk k+1 while chunk k's tokens travel to the host.  Whether the
-device still waits on the host at real widths, and so whether
-`decode_chunk` / `pipeline_depth` need to be options at all, is
-ROADMAP S6's measurement.  Correctness under lag: every dispatch is tagged
-with its (slot → request) ownership at dispatch time; a slot retired
-while later dispatches were already in flight just has its extra
-tokens dropped (decode_core is safe on retired slots), and the slot is
-only re-admitted after the retiring read was processed — in-order
-processing makes the attribution exact.
+Pipelining: a loop that synchronizes with the device once per step
+(dispatch → block on the token read → repeat) leaves the chip idle for
+every host round trip.  The engine keeps up to `pipeline_depth`
+dispatches in flight, starts device→host token copies asynchronously at
+dispatch time (`copy_to_host_async`), and only materializes the OLDEST
+in-flight result — so the chip computes chunk k+1 while chunk k's
+tokens travel to the host.  Correctness under lag: every dispatch is
+tagged with its (slot → request) ownership at dispatch time; a slot
+retired while later dispatches were already in flight just has its
+extra tokens dropped (a decode step is safe on retired slots), and the
+slot is only re-admitted after the retiring read was processed —
+in-order processing makes the attribution exact.
 
 Streaming: `submit` returns a _Request whose tokens can be consumed
 incrementally via `stream()` (a blocking iterator fed as decode reads
@@ -113,22 +112,22 @@ class _Request:
     slot: int = -1
     error: Optional[Exception] = None
     # "eos" | "length" (hit max_new) | "cache" (request exceeded the KV
-    # pool/table; with the paged engine transient exhaustion QUEUES the
-    # request instead — "cache" means this one request can never fit)
+    # pool/table; transient exhaustion QUEUES the request instead —
+    # "cache" means this one request can never fit)
     finish_reason: str = ""
-    # Multiplexing + prefix cache (paged engine): the adapter/model the
-    # request routed with, and whether admission reused cached blocks.
+    # Multiplexing + prefix cache: the adapter/model the request routed
+    # with, and whether admission reused cached blocks.
     model_id: str = ""
     cache_hit: bool = False
     cached_tokens: int = 0
-    # Chunked prefill (paged engine): prompt positions already sent to the
-    # cache (the matched prefix among them), and whether chunks are still
+    # Chunked prefill: prompt positions already sent to the cache (the
+    # matched prefix among them), and whether chunks are still
     # to come — the request then holds its slot without decoding.
     _prefilled: int = 0
     _prefilling: bool = False
-    # Paged bookkeeping: max total positions (prompt + generated) this
-    # request's block allocation covers (0 = dense engine: global cap),
-    # and the pool blocks it holds a reference on.
+    # Max total positions (prompt + generated) this request's block
+    # allocation covers (set at admission), and the pool blocks it holds
+    # a reference on.
     _pos_cap: int = 0
     _blocks: List[int] = field(default_factory=list)
     _blocks_freed: bool = False
@@ -159,663 +158,7 @@ class _Request:
             yield item
 
 
-class ContinuousBatcher:
-    """Slot-based continuous batching engine (host loop + jitted steps).
 
-    Thread-safe submit(); a dedicated engine thread interleaves
-    admissions (batched prefill_insert) with chunked decode_steps
-    dispatches, keeping `pipeline_depth` dispatches in flight.
-
-    This is the DENSE engine (per-slot max_len KV slabs) — the
-    `paged_kv=False` escape hatch.  PagedBatcher below subclasses the
-    pipeline/submit machinery and swaps the cache for a paged block
-    pool with prefix caching and model multiplexing.
-    """
-
-    supports_multiplex = False
-
-    def __init__(self, params, cfg, num_slots: int = 8,
-                 max_len: int = 512, prompt_pad: int = 64,
-                 eos_id: Optional[int] = None,
-                 decode_chunk: int = 8,
-                 pipeline_depth: int = 2,
-                 max_queue: int = 0) -> None:
-        from ray_tpu.models import decoding
-        self._dec = decoding
-        self.params = params
-        self.cfg = cfg
-        self.num_slots = num_slots
-        self.max_len = max_len
-        self.prompt_pad = prompt_pad
-        self.eos_id = eos_id
-        # Admission backstop: submit() sheds (typed rejection) once
-        # this many requests are queued ahead of slot admission.
-        # 0 = unlimited.  The check runs BEFORE anything touches the
-        # KV path, so a shed request never allocates blocks or
-        # queries the prefix cache.
-        self.max_queue = max(int(max_queue), 0)
-        # SLO windows for the serve autoscaler (slo_snapshot): engine
-        # TTFT samples and inter-token latency derived from decode
-        # entry processing cadence.  Guarded by _slo_lock (processor
-        # thread appends, actor threads snapshot).
-        self._slo_lock = threading.Lock()
-        self._ttft_win: deque = deque(maxlen=128)
-        self._itl_win: deque = deque(maxlen=256)
-        self._last_entry_t: Optional[float] = None
-        # Tokens decoded per device dispatch: >1 amortizes dispatch
-        # overhead at the cost of admission/EOS granularity.
-        self.decode_chunk = max(decode_chunk, 1)
-        self.pipeline_depth = max(pipeline_depth, 1)
-        self.caches = self._init_caches(cfg, num_slots, max_len)
-        # Slot ownership/length AT DISPATCH TIME (the engine's view of
-        # the device); processing updates the per-request state.
-        self._owner: List[Optional[_Request]] = [None] * num_slots
-        self._disp_len = [0] * num_slots
-        self._pending: "queue.Queue[_Request]" = queue.Queue()
-        # In-flight dispatches, oldest first:
-        #   ("prefill", firsts_dev, [(row, slot, req)])
-        #   ("decode", toks_dev, [(slot, req)])
-        self._inflight: deque = deque()
-        self._narrow_width = min(4, num_slots)
-        # Packed-upload width (prefill_decode_packed wire format).
-        self._pack_w = self._packed_width(prompt_pad, num_slots)
-        self._shutdown = False
-        self._work = threading.Event()
-        self.steps = 0
-        # Where the engine's two threads spend their time, in seconds
-        # (stats()["host"]).  Dispatcher: waiting for a pipeline permit
-        # (the device is ahead: healthy), building and launching a
-        # dispatch, and starved (no live slot, nothing waiting).
-        # Processor: waiting for a dispatch's tokens, and handing them out.
-        self.host_s = {"permit_wait": 0.0, "dispatch": 0.0, "starved": 0.0,
-                       "read_wait": 0.0, "process": 0.0}
-        # Device-resident active-mask cache: skips one host->device
-        # transfer per decode dispatch.  In steady state the mask rarely
-        # changes (drained-readmission keeps slots full), so the device
-        # array is keyed by the mask bytes.
-        self._active_key: Optional[bytes] = None
-        self._active_dev = None
-        # Dispatcher/processor split: one thread submits dispatches
-        # while another blocks on result reads, so submission never
-        # waits behind result processing.  _state_lock guards
-        # _owner/_disp_len (both threads mutate them); _inflight moves
-        # entries from dispatcher to processor; _slots_sem bounds the
-        # pipeline depth.
-        self._state_lock = threading.Lock()
-        self._proc_wake = threading.Event()
-        self._slots_sem = threading.Semaphore(self.pipeline_depth)
-        # Warm-up (every dispatch shape compiled) runs on the engine
-        # thread; requests submitted meanwhile queue behind it.
-        self._warmed = False
-        self.warmup_s = 0.0        # compile + first run of every shape
-        self._engine_error: Optional[Exception] = None
-        self._thread = threading.Thread(target=self._engine_loop,
-                                        daemon=True, name="rtpu-llm")
-        self._thread.start()
-        self._proc_thread = threading.Thread(
-            target=self._process_loop, daemon=True, name="rtpu-llm-proc")
-        self._proc_thread.start()
-        leaksan.track_thread(self._thread)
-        leaksan.track_thread(self._proc_thread)
-
-    # -- engine-variant hooks (overridden by PagedBatcher) -----------------
-    def _init_caches(self, cfg, num_slots: int, max_len: int):
-        return self._dec.init_caches(cfg, num_slots, max_len)
-
-    def _packed_width(self, prompt_pad: int, num_slots: int) -> int:
-        return max(prompt_pad + 3, num_slots)
-
-    def _req_cap(self, req: "_Request") -> int:
-        """Max total positions (prompt + generated) for this request:
-        the dense engine's global cache cap, or the request's own
-        block allocation for the paged engine."""
-        return req._pos_cap or self._cap()
-
-    def _warmup(self, jnp) -> None:
-        """Compile every dispatch shape up front (both fused widths +
-        the decode-only chunk) so no request ever stalls behind a
-        mid-run XLA compile."""
-        active = jnp.zeros((self.num_slots,), bool)
-        for N in sorted({self._narrow_width, self.num_slots}):
-            packed = np.zeros((N + 1, self._pack_w), np.int32)
-            packed[:N, self.prompt_pad + 1] = np.arange(N)
-            self.caches, _, _ = self._dec.prefill_decode_packed(
-                self.params, self.caches, jnp.asarray(packed),
-                self.cfg, self.decode_chunk, self.prompt_pad)
-        if self.decode_chunk > 1:
-            self.caches, toks = self._dec.decode_steps(
-                self.params, self.caches, active, self.cfg,
-                self.decode_chunk)
-            np.asarray(toks)
-        # Single-step shape too: the near-cache tail falls back to it.
-        self.caches, toks = self._dec.decode_step(
-            self.params, self.caches, active, self.cfg)
-        np.asarray(toks)
-
-    # -- public ------------------------------------------------------------
-    def queue_depth(self) -> int:
-        """Requests queued ahead of slot admission (not yet decoding).
-        The paged engine adds its dispatcher-side waiting deque."""
-        return self._pending.qsize()
-
-    def slo_snapshot(self) -> Dict[str, Any]:
-        """The serve autoscaler's engine-side SLO view (consumed via
-        the replica's __rtpu_slo_stats__ hook): engine queue depth,
-        TTFT p95, and decode inter-token latency p95 over the rolling
-        time-decayed windows (one shared window constant + percentile
-        helper with the replica's request-latency signal)."""
-        from ray_tpu.serve._replica import _SLO_WINDOW_S, _p95_ms
-
-        def p95(xs):
-            v = _p95_ms(xs)
-            return round(v, 3) if v is not None else None
-
-        cutoff = time.time() - _SLO_WINDOW_S
-        with self._slo_lock:
-            ttfts = [v for t, v in self._ttft_win if t >= cutoff]
-            itls = [v for t, v in self._itl_win if t >= cutoff]
-        return {"queue_depth": self.queue_depth(),
-                "ttft_p95_ms": p95(ttfts),
-                "itl_p95_ms": p95(itls)}
-
-    def submit(self, prompt: List[int], max_new: int = 32,
-               streaming: bool = False, model_id: str = "") -> _Request:
-        """Enqueue a request.  `model_id` selects a multiplexed
-        adapter (paged engine only; the dense escape-hatch engine
-        serves the single base model).
-
-        With `max_queue` set, a submit that finds that many requests
-        already queued raises the typed RequestRejectedError HERE —
-        before the request touches the engine at all.  For the paged
-        engine that ordering is load-bearing: a shed request must
-        never query the prefix cache or hold KV blocks, so rejection
-        can never evict a live request's cache entries.  The
-        "llm-engine" label is a placeholder: the serving Replica
-        re-tags the rejection with its real deployment name (and
-        counts the shed there) on the way out."""
-        self._raise_if_dead()
-        if self.max_queue and self.queue_depth() >= self.max_queue:
-            from ray_tpu.serve._admission import RequestRejectedError
-            raise RequestRejectedError(
-                deployment="llm-engine", reason="queue_full",
-                retry_after_s=0.5)
-        if len(prompt) > self.prompt_pad:
-            raise ValueError(f"prompt of {len(prompt)} tokens exceeds "
-                             f"prompt budget {self.prompt_pad}")
-        if model_id and not self.supports_multiplex:
-            raise ValueError(
-                "model multiplexing requires the paged engine "
-                "(paged_kv=True)")
-        req = _Request(prompt=list(prompt), max_new=max_new,
-                       model_id=model_id,
-                       stream_q=queue.Queue() if streaming else None)
-        req._t0 = time.time()
-        self._pending.put(req)
-        self._work.set()
-        self._raise_if_dead()       # warm-up failed while we enqueued
-        return req
-
-    def _raise_if_dead(self) -> None:
-        if self._engine_error is not None:
-            raise RuntimeError(
-                f"LLM engine failed its warm-up and serves nothing: "
-                f"{self._engine_error!r}") from self._engine_error
-
-    def generate(self, prompt: List[int], max_new: int = 32,
-                 timeout: float = 300.0,
-                 model_id: str = "") -> Dict[str, Any]:
-        req = self.submit(prompt, max_new, model_id=model_id)
-        if not req.done.wait(timeout):
-            raise TimeoutError("generation timed out")
-        if req.error is not None:
-            raise req.error
-        return {"tokens": req.tokens, "ttft_s": req.ttft_s,
-                "queue_s": req.queue_s, "prefill_s": req.prefill_s,
-                "cache_hit": req.cache_hit,
-                "cached_tokens": req.cached_tokens,
-                "finish_reason": req.finish_reason}
-
-    def generate_stream(self, prompt: List[int], max_new: int = 32,
-                        timeout: float = 300.0,
-                        model_id: str = "") -> Iterator[int]:
-        """Blocking token iterator (the serve streaming data plane)."""
-        req = self.submit(prompt, max_new, streaming=True,
-                          model_id=model_id)
-        return req.stream(timeout=timeout)
-
-    def stop(self) -> None:
-        self._shutdown = True
-        self._work.set()
-        self._proc_wake.set()
-        # Join the engine threads: exiting the process while a daemon
-        # thread is inside an XLA compile/dispatch (e.g. stop() racing
-        # warmup) crashes interpreter teardown.  Both loops observe
-        # _shutdown at the next iteration, so this is bounded by one
-        # warmup/dispatch.
-        for t in (self._thread, self._proc_thread):
-            if t is not threading.current_thread():
-                t.join(timeout=120.0)
-            # Only a thread that actually EXITED leaves the ledger: a
-            # join that timed out (wedged dispatch) must stay visible
-            # — that is the class the ledger exists to catch.
-            if not t.is_alive():
-                leaksan.discharge_thread(t)
-        # Terminal discharge: anything still owned/queued can never
-        # finish now that the loops are gone.  Leaving it parked
-        # strands its caller until the generate() timeout — and, on
-        # the paged engine, keeps its KV blocks refcounted forever
-        # (leak-ledger self-finding).  The paged _fail_all also drops
-        # the prefix cache, so a stopped engine holds zero blocks.
-        self._fail_all(RuntimeError("engine stopped"))
-
-    # -- engine ------------------------------------------------------------
-    def _push_token(self, req: _Request, tok: int) -> None:
-        req.tokens.append(tok)
-        if req.stream_q is not None:
-            req.stream_q.put(tok)
-
-    def _finished(self, req: _Request, tok: int) -> bool:
-        if self.eos_id is not None and tok == self.eos_id:
-            req.finish_reason = "eos"
-            return True
-        if len(req.tokens) >= req.max_new:
-            req.finish_reason = "length"
-            return True
-        return False
-
-    def _retire(self, slot: int, req: _Request) -> None:
-        with self._state_lock:
-            if self._owner[slot] is req:
-                self._owner[slot] = None
-        req._finish()
-        if req.stream_q is not None:
-            req.stream_q.put(_STREAM_END)
-
-    def _finish_request(self, req: "_Request",
-                        error: Optional[Exception] = None,
-                        reason: str = "") -> None:
-        """Terminal bookkeeping for a request that never reaches
-        _retire (failed, rejected, or swept before getting a slot)."""
-        if error is not None:
-            req.error = error
-        if reason:
-            req.finish_reason = reason
-        req._finish()
-        if req.stream_q is not None:
-            req.stream_q.put(_STREAM_END)
-
-    def _fail_all(self, e: Exception) -> None:
-        # Snapshot the slot table under _state_lock (the dispatcher
-        # mutates _owner concurrently; an RT010 self-finding), then
-        # retire outside it — _retire takes the lock itself.
-        with self._state_lock:
-            owned = [(i, req) for i, req in enumerate(self._owner)
-                     if req is not None]
-        for i, req in owned:
-            req.error = e
-            self._retire(i, req)
-        while not self._pending.empty():
-            try:
-                req = self._pending.get_nowait()
-            except queue.Empty:
-                break
-            self._finish_request(req, error=e)
-        # Drain (don't clear): each in-flight entry holds a pipeline
-        # permit that must come back, and popleft is atomic against a
-        # concurrently-draining processor.
-        while True:
-            try:
-                self._inflight.popleft()
-            except IndexError:
-                break
-            self._slots_sem.release()
-
-    # True cache capacity: position max_len - 1 is the last decodable
-    # token (the scatter at the final step writes position max_len - 2).
-    def _cap(self) -> int:
-        return self.max_len - 1
-
-    def _tail_throttle(self, req: "_Request") -> bool:
-        """Whether nearing this request's cap must force single-token
-        dispatches.  Dense: always — the cap is the physical cache
-        end, and overshooting it a chunk early truncates the request
-        (see the tail comment in _dispatch)."""
-        return True
-
-    def _drained(self, slot: int, req: "_Request") -> bool:
-        """Everything `req` needs is already dispatched (caller holds
-        _state_lock)."""
-        gen = 1 + self._disp_len[slot] - len(req.prompt)
-        return (gen >= req.max_new
-                or self._disp_len[slot] >= self._req_cap(req))
-
-    def _pop_admissions(self, free: List[int],
-                        tail: bool) -> List[tuple]:
-        """Pair waiting requests with free slots: [(slot, req)].
-        PagedBatcher overrides this with allocator/radix admission."""
-        batch: List[tuple] = []
-        if free and not tail and not self._pending.empty():
-            while len(batch) < len(free):
-                try:
-                    req = self._pending.get_nowait()
-                except queue.Empty:
-                    break
-                batch.append((free[len(batch)], req))
-        return batch
-
-    def _fill_pad_rows(self, packed, n_batch: int, N: int,
-                       admitted: List[tuple], slot_col: int) -> None:
-        # Rows without a request still need DISTINCT target slots
-        # (their write is a rewrite of existing contents):
-        # duplicate scatter indices have undefined order and could
-        # clobber a real insert.
-        used = {s for _, s, _ in admitted}
-        remaining = [s for s in range(self.num_slots) if s not in used]
-        for row in range(n_batch, N):
-            packed[row, slot_col] = remaining[row - n_batch]
-
-    def _fused_dispatch(self, jnp, batch: List[tuple], active,
-                        chunk: int):
-        """Pack + launch the fused prefill/decode for `batch`
-        ([(slot, req)]); returns ((first, dtoks, ...), rows) with rows
-        [(row, slot, req)]: the device arrays the processor reads (any
-        beyond the first two go to _count_dispatch).  The packed wire
-        format and kernel are the engine-variant parts."""
-        # Two compiled widths (narrow + full), both precompiled at
-        # engine start — more widths meant mid-run compile stalls.
-        N = (self._narrow_width
-             if len(batch) <= self._narrow_width
-             else self.num_slots)
-        P = self.prompt_pad
-        packed = np.zeros((N + 1, self._pack_w), np.int32)
-        admitted = []
-        for row, (slot, req) in enumerate(batch):
-            packed[row, :len(req.prompt)] = req.prompt
-            packed[row, P] = len(req.prompt)
-            packed[row, P + 1] = slot
-            packed[row, P + 2] = 1
-            admitted.append((row, slot, req))
-        self._fill_pad_rows(packed, len(batch), N, admitted, P + 1)
-        packed[N, :self.num_slots] = active
-        self.caches, first, dtoks = self._dec.prefill_decode_packed(
-            self.params, self.caches, jnp.asarray(packed),
-            self.cfg, chunk, P)
-        return (first, dtoks), admitted
-
-    def _decode_dispatch(self, chunk: int) -> tuple:
-        """Decode-only device step for every slot; returns (dtoks
-        [chunk, B], ...) (engine-variant kernel)."""
-        if chunk > 1:
-            self.caches, dtoks = self._dec.decode_steps(
-                self.params, self.caches, self._active_dev,
-                self.cfg, chunk)
-            return (dtoks,)
-        self.caches, tok = self._dec.decode_step(
-            self.params, self.caches, self._active_dev, self.cfg)
-        return (tok[None],)
-
-    def _post_admit(self, rows: List[tuple]) -> None:
-        """Engine-variant bookkeeping after a fused dispatch launched
-        (PagedBatcher: radix insertion + gauges)."""
-
-    def _count_dispatch(self, extras: tuple) -> None:
-        """What a dispatch returned beyond its tokens (PagedBatcher: an
-        expert model's counts), once it has been read."""
-
-    def _dispatch(self, jnp) -> bool:
-        """One device dispatch per tick: chunked decode of every live
-        slot, with any waiting admissions FUSED into the same dispatch
-        (prefill_decode_packed), so an admission costs no dispatch of
-        its own.  The pipeline bookkeeping here is shared by both
-        engines; the pack format, kernels, and admission policy are
-        the _pop_admissions/_fused_dispatch/_decode_dispatch/
-        _post_admit hooks."""
-        with self._state_lock:
-            # A slot is admittable when empty OR "drained": every token
-            # its current request needs is already covered by in-flight
-            # dispatches (predictable for length/cache finishes — the
-            # dispatcher knows max_new).  Re-admitting a drained slot
-            # immediately removes the retire->readmit pipeline bubble
-            # that cost ~25% of throughput; the old request's entries
-            # still deliver its tokens (per-entry pairs + take bounds),
-            # and in-order device execution puts the new prefill after
-            # the old request's last chunk.  With an eos_id the finish
-            # point is NOT predictable, so only empty slots qualify.
-            free = [i for i, r in enumerate(self._owner)
-                    if r is None or (self.eos_id is None
-                                     and self._drained(i, r))]
-            live = [(i, r) for i, r in enumerate(self._owner)
-                    if r is not None and not r._prefilling
-                    and self._disp_len[i] < self._req_cap(r)]
-            # Near the cache end, fall back to single-token dispatches
-            # (and no admissions) so requests run all the way to
-            # max_len - 1 instead of being truncated a chunk early.
-            tail = any(self._disp_len[i] + self.decode_chunk
-                       > self._req_cap(r) and self._tail_throttle(r)
-                       for i, r in live)
-        chunk = 1 if tail else self.decode_chunk
-        batch = self._pop_admissions(free, tail)
-        # NOTE: slots whose request already has max_new covered by
-        # in-flight dispatches stay in the batch anyway — the decode is
-        # fixed-shape, so excluding them saves nothing, while skipping
-        # the dispatch when "nothing needs tokens" drains the pipeline
-        # and costs ~30% throughput (measured).  Their extra tokens are
-        # dropped at processing time.
-        if not live and not batch:
-            return False
-        active = np.zeros((self.num_slots,), bool)
-        for i, _ in live:
-            active[i] = True
-
-        if batch:
-            # Admission happens HERE (slots are committed); stamp it
-            # before the prefill dispatch so compile/dispatch time
-            # lands in prefill_s, not queue_s.
-            admit_t = time.time()
-            try:
-                devs, rows = self._fused_dispatch(jnp, batch, active, chunk)
-            except Exception as e:
-                # The batch is already out of _waiting/_pending with
-                # KV blocks held, but not yet in _owner — _fail_all
-                # can't reach it.  Fail + retire each request here
-                # (retire frees paged blocks) before re-raising into
-                # the engine loop's recovery path, or callers hang to
-                # timeout and the blocks leak for the engine's life.
-                for slot, req in batch:
-                    req.error = e
-                    self._retire(slot, req)
-                raise
-            # A row whose prompt has chunks still to come holds its slot
-            # and yields no token yet; the others are admitted for good.
-            admitted = [a for a in rows if not a[2]._prefilling]
-            with self._state_lock:
-                for _, slot, req in rows:
-                    self._owner[slot] = req
-                    req._admit_t = req._admit_t or admit_t
-                    # prompt + the chunk the fused step decodes for it
-                    self._disp_len[slot] = (
-                        req._prefilled if req._prefilling
-                        else len(req.prompt) + chunk)
-            self._post_admit(rows)
-            pairs = live + [(slot, req) for _, slot, req in admitted]
-            entry = ("fused", devs, (admitted, pairs))
-        else:
-            key = active.tobytes()
-            if key != self._active_key:
-                self._active_key = key
-                self._active_dev = jnp.asarray(active)
-            entry = ("decode", self._decode_dispatch(chunk), (None, live))
-        for dev in entry[1]:
-            try:
-                dev.copy_to_host_async()
-            except Exception:
-                pass
-        admitted_slots = ({slot for _, slot, _ in entry[2][0]}
-                          if entry[0] == "fused" else set())
-        with self._state_lock:
-            for i, _ in live:
-                # A drained-readmitted slot already had its _disp_len
-                # reset to prompt + chunk above; adding chunk again
-                # would report it "drained" one chunk early and strand
-                # its final chunk.
-                if i not in admitted_slots:
-                    self._disp_len[i] += chunk
-        self._inflight.append(entry)
-        self._proc_wake.set()
-        self.steps += chunk
-        return True
-
-    def _process_entry(self, entry) -> None:
-        kind, devs, (admitted, pairs) = entry
-        t_read = time.perf_counter()
-        first_dev = np.asarray(devs[0])     # waits for the dispatch
-        t_got = time.perf_counter()
-        self.host_s["read_wait"] += t_got - t_read
-        try:
-            self._hand_out(kind, devs, first_dev, admitted, pairs)
-        finally:
-            self.host_s["process"] += time.perf_counter() - t_got
-
-    def _hand_out(self, kind, devs, first_dev, admitted, pairs) -> None:
-        now = time.time()
-        if kind == "fused":
-            firsts = first_dev
-            for row, slot, req in admitted:
-                req.ttft_s = now - req._t0
-                admit = req._admit_t or now
-                req.queue_s = max(admit - req._t0, 0.0)
-                req.prefill_s = max(now - admit, 0.0)
-                req.slot = slot
-                tok = int(firsts[row])
-                self._push_token(req, tok)
-                if self._finished(req, tok):
-                    self._retire(slot, req)
-            rows = np.asarray(devs[1])
-            self._count_dispatch(devs[2:])
-        else:
-            rows = first_dev
-            self._count_dispatch(devs[1:])
-        # SLO windows (serve autoscaler): TTFT for this entry's
-        # admissions; an inter-token-latency sample from the entry
-        # cadence — each entry carries len(rows) decode steps, so
-        # wall time between consecutive processed entries / chunk is
-        # the per-token latency a streaming client observes.
-        t_proc = time.time()
-        with self._slo_lock:
-            for _, _, req in (admitted or ()):
-                self._ttft_win.append((t_proc, req.ttft_s))
-            if pairs:
-                if self._last_entry_t is not None:
-                    self._itl_win.append(
-                        (t_proc,
-                         max(t_proc - self._last_entry_t, 0.0)
-                         / max(len(rows), 1)))
-                self._last_entry_t = t_proc
-        # Column-major with one C-level tolist() + bulk extends:
-        # per-token Python in this loop contends the GIL with the
-        # dispatcher thread at chunk x B = 256 tokens per entry.
-        # Slots are independent streams, so slot-by-slot processing is
-        # equivalent to token-major order.
-        cols = rows.T.tolist()                # [B][chunk]
-        for slot, req in pairs:
-            if req.done.is_set():
-                continue                      # finished by an earlier entry
-            cap = self._req_cap(req)
-            col = cols[slot]
-            take = min(len(col),
-                       req.max_new - len(req.tokens),
-                       cap - len(req.prompt) - len(req.tokens))
-            seg = col[:max(take, 0)]
-            if self.eos_id is not None and self.eos_id in seg:
-                seg = seg[:seg.index(self.eos_id) + 1]
-                req.finish_reason = "eos"
-            req.tokens.extend(seg)
-            if req.stream_q is not None:
-                for t in seg:
-                    req.stream_q.put(t)
-            if req.finish_reason == "eos":
-                self._retire(slot, req)
-            elif len(req.tokens) >= req.max_new:
-                req.finish_reason = "length"
-                self._retire(slot, req)
-            elif len(req.prompt) + len(req.tokens) >= cap:
-                # Dispatch stops at the cap margin, so retire here too
-                # or a capped slot would stall unretired.
-                req.finish_reason = "cache"
-                self._retire(slot, req)
-
-    def _engine_loop(self) -> None:
-        import jax.numpy as jnp
-        t0 = time.time()
-        try:
-            self._warmup(jnp)
-        except Exception as e:
-            # A step that cannot compile or run will not start working
-            # later: say so once, fail what is queued, refuse every
-            # later submit with the cause, and stop — a replica that
-            # stayed up would look healthy and answer nothing.
-            import traceback
-            traceback.print_exc()
-            self._engine_error = e
-            self._fail_all(e)
-            return
-        self.warmup_s = time.time() - t0
-        self._warmed = True
-        while not self._shutdown:
-            try:
-                # Acquire a pipeline slot, then dispatch; the processor
-                # releases slots as it drains entries.
-                t_a = time.perf_counter()
-                got = self._slots_sem.acquire(timeout=0.05)
-                t_b = time.perf_counter()
-                self.host_s["permit_wait"] += t_b - t_a
-                if not got:
-                    continue
-                if self._dispatch(jnp):
-                    self.host_s["dispatch"] += time.perf_counter() - t_b
-                else:
-                    self._slots_sem.release()
-                    self._work.wait(timeout=0.05)
-                    self._work.clear()
-                    self.host_s["starved"] += time.perf_counter() - t_b
-            except Exception as e:
-                # An engine failure (e.g. device error) must surface to
-                # every waiting caller, not die with the thread and
-                # zombify the replica.
-                self._slots_sem.release()
-                self._fail_all(e)
-                time.sleep(0.1)
-
-    def _process_loop(self) -> None:
-        while not self._shutdown:
-            try:
-                entry = self._inflight.popleft()
-            except IndexError:
-                # Idle: break the ITL cadence chain, or the first
-                # entry after an idle gap would record (gap / chunk)
-                # as an inter-token-latency sample and spuriously
-                # trip the autoscaler's ITL SLO at light load.
-                with self._slo_lock:
-                    self._last_entry_t = None
-                self._proc_wake.wait(timeout=0.05)
-                self._proc_wake.clear()
-                continue
-            try:
-                self._process_entry(entry)
-            except Exception as e:
-                self._fail_all(e)
-                time.sleep(0.1)
-            finally:
-                # One permit per drained entry, whether it processed
-                # cleanly or died — pipeline depth must never shrink.
-                self._slots_sem.release()
-                self._work.set()
-
-
-
-# ===========================================================================
-# Paged KV engine
-# ===========================================================================
 _kv_metrics: Optional[Dict[str, Any]] = None
 
 
@@ -1114,20 +457,21 @@ def prefill_shapes(num_slots: int, prompt_pad: int):
     return tile, sorted({max(1, widest >> k) for k in range(4)})
 
 
-class PagedBatcher(ContinuousBatcher):
-    """Paged-KV continuous batcher: block-pool cache + radix prefix
-    cache + multiplexed adapter hot-swap (see module docstring).
+class PagedBatcher:
+    """Slot-based continuous batching engine over a paged KV cache: a
+    block pool, a radix prefix cache and multiplexed adapter hot-swap
+    (host loop + jitted steps; see the module docstring).
 
-    Inherits the pipelined dispatch/process machinery and swaps the
-    cache layer: admission allocates refcounted blocks (evicting cold
-    cached blocks, then QUEUEING under pressure), prefill runs only
+    Thread-safe submit(); a dispatcher thread interleaves admissions
+    (fused into the decode dispatch) with chunked decode dispatches,
+    keeping `pipeline_depth` of them in flight, and a processor thread
+    reads their tokens.  Admission allocates refcounted blocks (evicting
+    cold cached blocks, then QUEUEING under pressure), prefill runs only
     the prompt's uncached suffix via paged_prefill_decode_packed (as
     tiles of PREFILL_TILE, at most PREFILL_CHUNK tokens a dispatch), and
     decode gathers KV through block tables with the ragged paged
     attention kernel.
     """
-
-    supports_multiplex = True
 
     def __init__(self, params, cfg, num_slots: int = 8,
                  max_len: int = 512, prompt_pad: int = 64,
@@ -1145,6 +489,23 @@ class PagedBatcher(ContinuousBatcher):
 
         from ray_tpu._private.config import config
         from ray_tpu.models import decoding
+        self._dec = decoding
+        self.params = params
+        self.cfg = cfg
+        self.num_slots = num_slots
+        self.max_len = max_len
+        self.prompt_pad = prompt_pad
+        self.eos_id = eos_id
+        # Admission backstop: submit() sheds (typed rejection) once
+        # this many requests are queued ahead of slot admission.
+        # 0 = unlimited.  The check runs BEFORE anything touches the
+        # KV path, so a shed request never allocates blocks or
+        # queries the prefix cache.
+        self.max_queue = max(int(max_queue), 0)
+        # Tokens decoded per device dispatch: >1 amortizes dispatch
+        # overhead at the cost of admission/EOS granularity.
+        self.decode_chunk = max(decode_chunk, 1)
+        self.pipeline_depth = max(pipeline_depth, 1)
         self.block_size = int(kv_block_size or config.kv_block_size)
         if self.block_size < 1:
             raise ValueError("kv_block_size must be >= 1")
@@ -1156,13 +517,9 @@ class PagedBatcher(ContinuousBatcher):
         if prefix_cache is None:
             prefix_cache = bool(config.prefix_cache_enabled)
         self.prefix_cache_enabled = prefix_cache
-        policy = str(config.kv_eviction_policy).lower()
-        if policy != "lru":
-            raise ValueError(
-                f"unknown kv_eviction_policy {policy!r} (only 'lru')")
-        # All engine-state below is shared between the dispatcher and
-        # processor threads -> guarded by _kv_lock (allocator, radix
-        # trees, counters).  _waiting is dispatcher-only: other
+        # The allocator, the radix trees and the counters below are
+        # shared between the dispatcher and processor threads ->
+        # guarded by _kv_lock.  _waiting is dispatcher-only: other
         # threads hand work to it through _pending and failures
         # through _waiting_fail, never by mutating the deque.
         self._kv_lock = threading.Lock()
@@ -1217,97 +574,179 @@ class PagedBatcher(ContinuousBatcher):
             1 for m, _ in (cfg.layer_kinds or ()) if m == "sliding")
         self._sliding_held = 0
         self._sliding_in_window = 0
-        # super().__init__ LAST: it starts the engine threads, which
-        # immediately use the state above.
-        super().__init__(params, cfg, num_slots=num_slots,
-                         max_len=max_len, prompt_pad=prompt_pad,
-                         eos_id=eos_id, decode_chunk=decode_chunk,
-                         pipeline_depth=pipeline_depth,
-                         max_queue=max_queue)
+        self.caches = decoding.init_paged_caches(
+            cfg, num_slots, self.num_blocks, self.block_size, max_len)
+        # SLO windows for the serve autoscaler (slo_snapshot): engine
+        # TTFT samples and inter-token latency derived from decode
+        # entry processing cadence.  Guarded by _slo_lock (processor
+        # thread appends, actor threads snapshot).
+        self._slo_lock = threading.Lock()
+        self._ttft_win: deque = deque(maxlen=128)
+        self._itl_win: deque = deque(maxlen=256)
+        self._last_entry_t: Optional[float] = None
+        # Slot ownership/length AT DISPATCH TIME (the engine's view of
+        # the device); processing updates the per-request state.
+        self._owner: List[Optional[_Request]] = [None] * num_slots
+        self._disp_len = [0] * num_slots
+        self._pending: "queue.Queue[_Request]" = queue.Queue()
+        # In-flight dispatches, oldest first: (kind, device arrays,
+        # (admitted, pairs)) with
+        #   "fused":  admitted [(row, slot, req)] take a first token,
+        #             pairs [(slot, req)] the chunk's decode tokens
+        #   "decode": admitted None
+        self._inflight: deque = deque()
+        self._shutdown = False
+        self._work = threading.Event()
+        self.steps = 0
+        # Where the engine's two threads spend their time, in seconds
+        # (stats()["host"]).  Dispatcher: waiting for a pipeline permit
+        # (the device is ahead: healthy), building and launching a
+        # dispatch, and starved (no live slot, nothing waiting).
+        # Processor: waiting for a dispatch's tokens, and handing them out.
+        self.host_s = {"permit_wait": 0.0, "dispatch": 0.0, "starved": 0.0,
+                       "read_wait": 0.0, "process": 0.0}
+        # Device-resident active-mask cache: skips one host->device
+        # transfer per decode dispatch.  In steady state the mask rarely
+        # changes (drained-readmission keeps slots full), so the device
+        # array is keyed by the mask bytes.
+        self._active_key: Optional[bytes] = None
+        self._active_dev = None
+        # Dispatcher/processor split: one thread submits dispatches
+        # while another blocks on result reads, so submission never
+        # waits behind result processing.  _state_lock guards
+        # _owner/_disp_len (both threads mutate them); _inflight moves
+        # entries from dispatcher to processor; _slots_sem bounds the
+        # pipeline depth.
+        self._state_lock = threading.Lock()
+        self._proc_wake = threading.Event()
+        self._slots_sem = threading.Semaphore(self.pipeline_depth)
+        # Warm-up (every dispatch shape compiled) runs on the engine
+        # thread; requests submitted meanwhile queue behind it.
+        self._warmed = False
+        self.warmup_s = 0.0        # compile + first run of every shape
+        self._engine_error: Optional[Exception] = None
+        self._thread = threading.Thread(target=self._engine_loop,
+                                        daemon=True, name="rtpu-llm")
+        self._thread.start()
+        self._proc_thread = threading.Thread(
+            target=self._process_loop, daemon=True, name="rtpu-llm-proc")
+        self._proc_thread.start()
+        leaksan.track_thread(self._thread)
+        leaksan.track_thread(self._proc_thread)
 
+    # -- public ------------------------------------------------------------
     def queue_depth(self) -> int:
-        # The dispatcher-side waiting deque holds requests already
-        # popped from _pending but still blockless (backpressure);
-        # len() is a GIL-atomic read, good enough for a shed
-        # threshold.
+        """Requests queued ahead of slot admission (not yet decoding):
+        the submit queue plus the dispatcher-side waiting deque, which
+        holds requests already popped from _pending but still blockless
+        (backpressure).  len() is a GIL-atomic read, good enough for a
+        shed threshold."""
         return self._pending.qsize() + len(self._waiting)
 
-    # -- hooks -------------------------------------------------------------
-    def _init_caches(self, cfg, num_slots: int, max_len: int):
-        return self._dec.init_paged_caches(
-            cfg, num_slots, self.num_blocks, self.block_size, max_len)
+    def slo_snapshot(self) -> Dict[str, Any]:
+        """The serve autoscaler's engine-side SLO view (consumed via
+        the replica's __rtpu_slo_stats__ hook): engine queue depth,
+        TTFT p95, and decode inter-token latency p95 over the rolling
+        time-decayed windows (one shared window constant + percentile
+        helper with the replica's request-latency signal)."""
+        from ray_tpu.serve._replica import _SLO_WINDOW_S, _p95_ms
 
-    def _pack(self, rows: int):
-        """The fused dispatch's one upload, empty (decoding.
-        paged_prefill_decode_packed has the format)."""
-        return np.zeros((rows + 1, max(self._tile + 4 + self.table_width,
-                                       self.num_slots)), np.int32)
+        def p95(xs):
+            v = _p95_ms(xs)
+            return round(v, 3) if v is not None else None
 
-    def _warmup(self, jnp) -> None:
-        active = jnp.zeros((self.num_slots,), bool)
-        for N in self._prefill_rows:
-            self.caches = self._dec.paged_prefill_decode_packed(
-                self.params, self.caches, jnp.asarray(self._pack(N)),
-                self.cfg, self.decode_chunk, self._tile,
-                attn_impl=self._attn_impl)[0]
-        if self.decode_chunk > 1:
-            self.caches, toks = self._dec.paged_decode_steps(
-                self.params, self.caches, active, self.cfg,
-                self.decode_chunk, attn_impl=self._attn_impl)[:2]
-            np.asarray(toks)
-        self.caches, toks = self._dec.paged_decode_step(
-            self.params, self.caches, active, self.cfg,
-            attn_impl=self._attn_impl)[:2]
-        np.asarray(toks)
+        cutoff = time.time() - _SLO_WINDOW_S
+        with self._slo_lock:
+            ttfts = [v for t, v in self._ttft_win if t >= cutoff]
+            itls = [v for t, v in self._itl_win if t >= cutoff]
+        return {"queue_depth": self.queue_depth(),
+                "ttft_p95_ms": p95(ttfts),
+                "itl_p95_ms": p95(itls)}
 
-    # -- allocator / prefix cache ------------------------------------------
-    def _radix_for(self, model_id: str) -> RadixCache:
-        tree = self._radix.get(model_id)
-        if tree is None:
-            tree = self._radix[model_id] = RadixCache(
-                self.block_size, clock=self._radix_clock)
-        return tree
+    def submit(self, prompt: List[int], max_new: int = 32,
+               streaming: bool = False, model_id: str = "") -> _Request:
+        """Enqueue a request.  `model_id` selects a multiplexed
+        adapter ("" is the base model).
 
-    def _evict_locked(self, need: int) -> int:
-        """Free up to `need` blocks by LRU-evicting refcount-0 cached
-        leaves across ALL models' radix trees (global LRU).  Caller
-        holds _kv_lock."""
-        freed = 0
-        while freed < need:
-            candidates = []
-            for tree in self._radix.values():
-                for last_used, node in tree.evictable():
-                    if self._alloc.refcount(node.block) == 0:
-                        candidates.append((last_used, node, tree))
-            if not candidates:
-                break
-            candidates.sort(key=lambda c: c[0])
-            for _, node, tree in candidates:
-                if freed >= need:
-                    break
-                if node.children or node.parent is None:
-                    continue       # a sibling eviction re-parented it
-                tree.remove_leaf(node, self._alloc)
-                freed += 1
-                self._evictions += 1
-        if freed:
-            km = _get_kv_metrics()
-            if km is not None:
-                km["evictions"].inc(freed)
-        return freed
+        With `max_queue` set, a submit that finds that many requests
+        already queued raises the typed RequestRejectedError HERE —
+        before the request touches the engine at all.  That ordering
+        is load-bearing: a shed request must never query the prefix
+        cache or hold KV blocks, so rejection can never evict a live
+        request's cache entries.  The "llm-engine" label is a
+        placeholder: the serving Replica re-tags the rejection with its
+        real deployment name (and counts the shed there) on the way
+        out."""
+        self._raise_if_dead()
+        if self.max_queue and self.queue_depth() >= self.max_queue:
+            from ray_tpu.serve._admission import RequestRejectedError
+            raise RequestRejectedError(
+                deployment="llm-engine", reason="queue_full",
+                retry_after_s=0.5)
+        if len(prompt) > self.prompt_pad:
+            raise ValueError(f"prompt of {len(prompt)} tokens exceeds "
+                             f"prompt budget {self.prompt_pad}")
+        req = _Request(prompt=list(prompt), max_new=max_new,
+                       model_id=model_id,
+                       stream_q=queue.Queue() if streaming else None)
+        req._t0 = time.time()
+        self._pending.put(req)
+        self._work.set()
+        self._raise_if_dead()       # warm-up failed while we enqueued
+        return req
 
-    def _update_kv_gauges(self) -> None:
-        km = _get_kv_metrics()
-        if km is None:
-            return
-        with self._kv_lock:
-            counts = self._alloc.counts()
-        for state, n in counts.items():
-            km["blocks"].set(n, tags={"state": state,
-                                      "engine": self._engine_tag})
+    def _raise_if_dead(self) -> None:
+        if self._engine_error is not None:
+            raise RuntimeError(
+                f"LLM engine failed its warm-up and serves nothing: "
+                f"{self._engine_error!r}") from self._engine_error
+
+    def generate(self, prompt: List[int], max_new: int = 32,
+                 timeout: float = 300.0,
+                 model_id: str = "") -> Dict[str, Any]:
+        req = self.submit(prompt, max_new, model_id=model_id)
+        if not req.done.wait(timeout):
+            raise TimeoutError("generation timed out")
+        if req.error is not None:
+            raise req.error
+        return {"tokens": req.tokens, "ttft_s": req.ttft_s,
+                "queue_s": req.queue_s, "prefill_s": req.prefill_s,
+                "cache_hit": req.cache_hit,
+                "cached_tokens": req.cached_tokens,
+                "finish_reason": req.finish_reason}
+
+    def generate_stream(self, prompt: List[int], max_new: int = 32,
+                        timeout: float = 300.0,
+                        model_id: str = "") -> Iterator[int]:
+        """Blocking token iterator (the serve streaming data plane)."""
+        req = self.submit(prompt, max_new, streaming=True,
+                          model_id=model_id)
+        return req.stream(timeout=timeout)
 
     def stop(self) -> None:
-        super().stop()
+        self._shutdown = True
+        self._work.set()
+        self._proc_wake.set()
+        # Join the engine threads: exiting the process while a daemon
+        # thread is inside an XLA compile/dispatch (e.g. stop() racing
+        # warmup) crashes interpreter teardown.  Both loops observe
+        # _shutdown at the next iteration, so this is bounded by one
+        # warmup/dispatch.
+        for t in (self._thread, self._proc_thread):
+            if t is not threading.current_thread():
+                t.join(timeout=120.0)
+            # Only a thread that actually EXITED leaves the ledger: a
+            # join that timed out (wedged dispatch) must stay visible
+            # — that is the class the ledger exists to catch.
+            if not t.is_alive():
+                leaksan.discharge_thread(t)
+        # Terminal discharge: anything still owned/queued can never
+        # finish now that the loops are gone.  Leaving it parked
+        # strands its caller until the generate() timeout — and keeps
+        # its KV blocks refcounted forever (leak-ledger self-finding).
+        # _fail_all also drops the prefix cache, so a stopped engine
+        # holds zero blocks.
+        self._fail_all(RuntimeError("engine stopped"))
         # Threads are joined now; remove this engine's gauge series —
         # remove() queues one final zero sample, so a cleanly-stopped
         # engine neither leaves stale occupancy in the node-side
@@ -1356,6 +795,65 @@ class PagedBatcher(ContinuousBatcher):
         # request thread.
         with self._kv_lock:
             return [m for m in self._models if m]
+
+    # -- allocator / prefix cache ------------------------------------------
+    def _radix_for(self, model_id: str) -> RadixCache:
+        tree = self._radix.get(model_id)
+        if tree is None:
+            tree = self._radix[model_id] = RadixCache(
+                self.block_size, clock=self._radix_clock)
+        return tree
+
+    def _evict_locked(self, need: int) -> int:
+        """Free up to `need` blocks by LRU-evicting refcount-0 cached
+        leaves across ALL models' radix trees (global LRU).  Caller
+        holds _kv_lock."""
+        freed = 0
+        while freed < need:
+            candidates = []
+            for tree in self._radix.values():
+                for last_used, node in tree.evictable():
+                    if self._alloc.refcount(node.block) == 0:
+                        candidates.append((last_used, node, tree))
+            if not candidates:
+                break
+            candidates.sort(key=lambda c: c[0])
+            for _, node, tree in candidates:
+                if freed >= need:
+                    break
+                if node.children or node.parent is None:
+                    continue       # a sibling eviction re-parented it
+                tree.remove_leaf(node, self._alloc)
+                freed += 1
+                self._evictions += 1
+        if freed:
+            km = _get_kv_metrics()
+            if km is not None:
+                km["evictions"].inc(freed)
+        return freed
+
+    def _update_kv_gauges(self) -> None:
+        km = _get_kv_metrics()
+        if km is None:
+            return
+        with self._kv_lock:
+            counts = self._alloc.counts()
+        for state, n in counts.items():
+            km["blocks"].set(n, tags={"state": state,
+                                      "engine": self._engine_tag})
+
+    def _flush_prefix_cache_locked(self) -> None:
+        """Drop every cached prefix across all models' trees.
+        Refcount-0 blocks return to the free list via release_cached;
+        a block some racing admission still holds is merely unmarked
+        and frees on its last decref.  Caller holds _kv_lock."""
+        for tree in self._radix.values():
+            stack = list(tree.root.children.values())
+            while stack:
+                node = stack.pop()
+                stack.extend(node.children.values())
+                self._alloc.release_cached(node.block)
+        self._radix = {}
 
     # -- multiplexing ------------------------------------------------------
     def _load_model(self, model_id: str):
@@ -1408,16 +906,6 @@ class PagedBatcher(ContinuousBatcher):
         with self._state_lock:
             busy = any(r is not None for r in self._owner)
         return not busy and not self._inflight
-
-    def _tail_throttle(self, req: "_Request") -> bool:
-        # Only a capacity-CLAMPED allocation needs the single-token
-        # tail (it must run all the way to its cap before the "cache"
-        # truncation).  An unclamped request ends exactly at max_new
-        # via the processing take-bound, and its overshoot writes land
-        # in private tail blocks / scratch block 0 — throttling the
-        # whole engine for every non-chunk-aligned max_new would cost
-        # ~chunk x dispatch overhead and starve admissions.
-        return (req._pos_cap or 0) < len(req.prompt) + req.max_new
 
     # -- admission ---------------------------------------------------------
     def _try_admit(self, req: "_Request"):
@@ -1520,62 +1008,10 @@ class PagedBatcher(ContinuousBatcher):
             room -= self._tiles_left(req)
         return admitted
 
-    def _retire(self, slot: int, req: "_Request") -> None:
-        super()._retire(slot, req)
-        with self._kv_lock:
-            if req._blocks and not req._blocks_freed:
-                req._blocks_freed = True
-                self._alloc.decref_many(req._blocks)
-        self._update_kv_gauges()
-
-    def _flush_prefix_cache_locked(self) -> None:
-        """Drop every cached prefix across all models' trees.
-        Refcount-0 blocks return to the free list via release_cached;
-        a block some racing admission still holds is merely unmarked
-        and frees on its last decref.  Caller holds _kv_lock."""
-        for tree in self._radix.values():
-            stack = list(tree.root.children.values())
-            while stack:
-                node = stack.pop()
-                stack.extend(node.children.values())
-                self._alloc.release_cached(node.block)
-        self._radix = {}
-
-    def _fail_all(self, e: Exception) -> None:
-        super()._fail_all(e)
-        # _post_admit inserts a batch's blocks into the radix tree at
-        # LAUNCH, so a dispatch that later fails device-side leaves
-        # cached blocks whose KV was never written — a prefix hit on
-        # them would silently decode garbage.  super() retired every
-        # owner (blocks decref'd); drop the whole prefix cache so
-        # nothing can match unwritten KV.
-        with self._kv_lock:
-            self._flush_prefix_cache_locked()
-        self._update_kv_gauges()
-        # _waiting is dispatcher-only and _admit's peek-then-popleft
-        # is not atomic, so a processor-thread failure must not drain
-        # the deque here — park the error and let the dispatcher fail
-        # the queue at its next _pop_admissions tick.  On the
-        # dispatcher thread itself draining now is safe (and keeps the
-        # parked error from leaking onto requests submitted AFTER the
-        # failure).
-        if threading.current_thread() is self._thread \
-                or (self._shutdown and not self._thread.is_alive()):
-            # Dispatcher thread itself, or stop() after the join —
-            # either way no dispatcher can race the deque.
-            self._drain_waiting(e)
-        else:
-            self._waiting_fail = e
-
-    def _drain_waiting(self, e: Exception) -> None:
-        while self._waiting:
-            req = self._waiting.popleft()
-            if not req.done.is_set():
-                self._finish_request(req, error=e)
-
-    # -- dispatch hooks ----------------------------------------------------
     def _pop_admissions(self, free: List[int],
                         tail: bool) -> List[tuple]:
+        """This dispatch's prefill: [(slot, req)], requests that hold a
+        slot with tiles still to come first, then new admissions."""
         # Apply a parked failure BEFORE pulling new submissions out of
         # _pending: only requests that were already waiting when the
         # engine failed get the error — anything submitted after the
@@ -1610,6 +1046,147 @@ class PagedBatcher(ContinuousBatcher):
         if free and self._waiting:
             batch += self._admit(free, room)
         return batch
+
+    # -- engine ------------------------------------------------------------
+    def _push_token(self, req: _Request, tok: int) -> None:
+        req.tokens.append(tok)
+        if req.stream_q is not None:
+            req.stream_q.put(tok)
+
+    def _finished(self, req: _Request, tok: int) -> bool:
+        if self.eos_id is not None and tok == self.eos_id:
+            req.finish_reason = "eos"
+            return True
+        if len(req.tokens) >= req.max_new:
+            req.finish_reason = "length"
+            return True
+        return False
+
+    def _retire(self, slot: int, req: _Request) -> None:
+        with self._state_lock:
+            if self._owner[slot] is req:
+                self._owner[slot] = None
+        req._finish()
+        if req.stream_q is not None:
+            req.stream_q.put(_STREAM_END)
+        with self._kv_lock:
+            if req._blocks and not req._blocks_freed:
+                req._blocks_freed = True
+                self._alloc.decref_many(req._blocks)
+        self._update_kv_gauges()
+
+    def _finish_request(self, req: "_Request",
+                        error: Optional[Exception] = None,
+                        reason: str = "") -> None:
+        """Terminal bookkeeping for a request that never reaches
+        _retire (failed, rejected, or swept before getting a slot)."""
+        if error is not None:
+            req.error = error
+        if reason:
+            req.finish_reason = reason
+        req._finish()
+        if req.stream_q is not None:
+            req.stream_q.put(_STREAM_END)
+
+    def _fail_all(self, e: Exception) -> None:
+        # Snapshot the slot table under _state_lock (the dispatcher
+        # mutates _owner concurrently; an RT010 self-finding), then
+        # retire outside it — _retire takes the lock itself.
+        with self._state_lock:
+            owned = [(i, req) for i, req in enumerate(self._owner)
+                     if req is not None]
+        for i, req in owned:
+            req.error = e
+            self._retire(i, req)
+        while not self._pending.empty():
+            try:
+                req = self._pending.get_nowait()
+            except queue.Empty:
+                break
+            self._finish_request(req, error=e)
+        # Drain (don't clear): each in-flight entry holds a pipeline
+        # permit that must come back, and popleft is atomic against a
+        # concurrently-draining processor.
+        while True:
+            try:
+                self._inflight.popleft()
+            except IndexError:
+                break
+            self._slots_sem.release()
+        # _post_admit inserts a batch's blocks into the radix tree at
+        # LAUNCH, so a dispatch that later fails device-side leaves
+        # cached blocks whose KV was never written — a prefix hit on
+        # them would silently decode garbage.  Every owner is retired
+        # above (blocks decref'd); drop the whole prefix cache so
+        # nothing can match unwritten KV.
+        with self._kv_lock:
+            self._flush_prefix_cache_locked()
+        self._update_kv_gauges()
+        # _waiting is dispatcher-only and _admit's peek-then-popleft
+        # is not atomic, so a processor-thread failure must not drain
+        # the deque here — park the error and let the dispatcher fail
+        # the queue at its next _pop_admissions tick.  On the
+        # dispatcher thread itself draining now is safe (and keeps the
+        # parked error from leaking onto requests submitted AFTER the
+        # failure).
+        if threading.current_thread() is self._thread \
+                or (self._shutdown and not self._thread.is_alive()):
+            # Dispatcher thread itself, or stop() after the join —
+            # either way no dispatcher can race the deque.
+            self._drain_waiting(e)
+        else:
+            self._waiting_fail = e
+
+    def _drain_waiting(self, e: Exception) -> None:
+        while self._waiting:
+            req = self._waiting.popleft()
+            if not req.done.is_set():
+                self._finish_request(req, error=e)
+
+    def _tail_throttle(self, req: "_Request") -> bool:
+        # Only a capacity-CLAMPED allocation needs the single-token
+        # tail (it must run all the way to its cap before the "cache"
+        # truncation).  An unclamped request ends exactly at max_new
+        # via the processing take-bound, and its overshoot writes land
+        # in private tail blocks / scratch block 0 — throttling the
+        # whole engine for every non-chunk-aligned max_new would cost
+        # ~chunk x dispatch overhead and starve admissions.
+        return req._pos_cap < len(req.prompt) + req.max_new
+
+    def _drained(self, slot: int, req: "_Request") -> bool:
+        """Everything `req` needs is already dispatched (caller holds
+        _state_lock)."""
+        gen = 1 + self._disp_len[slot] - len(req.prompt)
+        return (gen >= req.max_new
+                or self._disp_len[slot] >= req._pos_cap)
+
+    def _pack(self, rows: int):
+        """The fused dispatch's one upload, empty (decoding.
+        paged_prefill_decode_packed has the format)."""
+        return np.zeros((rows + 1, max(self._tile + 4 + self.table_width,
+                                       self.num_slots)), np.int32)
+
+    def _warmup(self, jnp) -> None:
+        """Compile every dispatch shape up front (each fused width + the
+        decode-only chunk) so no request ever stalls behind a mid-run
+        XLA compile."""
+        active = jnp.zeros((self.num_slots,), bool)
+        for N in self._prefill_rows:
+            self.caches = self._dec.paged_prefill_decode_packed(
+                self.params, self.caches, jnp.asarray(self._pack(N)),
+                self.cfg, self.decode_chunk, self._tile,
+                attn_impl=self._attn_impl)[0]
+        if self.decode_chunk > 1:
+            self.caches, toks = self._dec.paged_decode_steps(
+                self.params, self.caches, active, self.cfg,
+                self.decode_chunk, attn_impl=self._attn_impl)[:2]
+            np.asarray(toks)
+        # Single-step shape too: the tail of a clamped allocation falls
+        # back to it.
+        self.caches, toks = self._dec.paged_decode_step(
+            self.params, self.caches, active, self.cfg,
+            attn_impl=self._attn_impl)[:2]
+        np.asarray(toks)
 
     def _fused_dispatch(self, jnp, batch: List[tuple], active,
                         chunk: int):
@@ -1658,6 +1235,8 @@ class PagedBatcher(ContinuousBatcher):
         return tuple(devs), rows
 
     def _decode_dispatch(self, chunk: int) -> tuple:
+        """Decode-only device step for every slot; returns (dtoks
+        [chunk, B], ...)."""
         if chunk > 1:
             self.caches, *devs = self._dec.paged_decode_steps(
                 self.params, self.caches, self._active_dev,
@@ -1669,26 +1248,17 @@ class PagedBatcher(ContinuousBatcher):
         return (tok[None], *extras)
 
     def _count_dispatch(self, extras: tuple) -> None:
+        """What a dispatch returned beyond its tokens (an expert model's
+        counts), once it has been read."""
         if extras:
             counts = np.asarray(extras[0]).tolist()
             with self._kv_lock:
                 self._moe_counts = [a + b for a, b in
                                     zip(self._moe_counts, counts)]
 
-    def _dispatch(self, jnp) -> bool:
-        ran = super()._dispatch(jnp)
-        if ran and self._sliding_layers:
-            with self._state_lock:
-                held = [self._disp_len[i]
-                        for i, r in enumerate(self._owner) if r is not None]
-            window = self.cfg.sliding_window
-            with self._kv_lock:
-                self._sliding_held += self._sliding_layers * sum(held)
-                self._sliding_in_window += self._sliding_layers * sum(
-                    min(n, window) for n in held)
-        return ran
-
     def _post_admit(self, rows: List[tuple]) -> None:
+        """Bookkeeping after a fused dispatch launched: radix insertion
+        and the gauges."""
         # Optimistic radix insertion AFTER the batch is packed, of the
         # prompt as far as it has been dispatched (a chunked prompt's
         # later blocks follow with their chunks): in-order device
@@ -1704,10 +1274,265 @@ class PagedBatcher(ContinuousBatcher):
                         self._alloc)
         self._update_kv_gauges()
 
+    def _dispatch(self, jnp) -> bool:
+        """One device dispatch per tick: chunked decode of every live
+        slot, with any waiting admissions FUSED into the same dispatch
+        (paged_prefill_decode_packed), so an admission costs no
+        dispatch of its own."""
+        with self._state_lock:
+            # A slot is admittable when empty OR "drained": every token
+            # its current request needs is already covered by in-flight
+            # dispatches (predictable for length/cache finishes — the
+            # dispatcher knows max_new).  Re-admitting a drained slot
+            # immediately removes the retire->readmit pipeline bubble
+            # that cost ~25% of throughput; the old request's entries
+            # still deliver its tokens (per-entry pairs + take bounds),
+            # and in-order device execution puts the new prefill after
+            # the old request's last chunk.  With an eos_id the finish
+            # point is NOT predictable, so only empty slots qualify.
+            free = [i for i, r in enumerate(self._owner)
+                    if r is None or (self.eos_id is None
+                                     and self._drained(i, r))]
+            live = [(i, r) for i, r in enumerate(self._owner)
+                    if r is not None and not r._prefilling
+                    and self._disp_len[i] < r._pos_cap]
+            # Near the end of a clamped allocation, fall back to
+            # single-token dispatches (and no admissions) so the request
+            # runs all the way to its cap instead of being truncated a
+            # chunk early.
+            tail = any(self._disp_len[i] + self.decode_chunk
+                       > r._pos_cap and self._tail_throttle(r)
+                       for i, r in live)
+        chunk = 1 if tail else self.decode_chunk
+        batch = self._pop_admissions(free, tail)
+        # NOTE: slots whose request already has max_new covered by
+        # in-flight dispatches stay in the batch anyway — the decode is
+        # fixed-shape, so excluding them saves nothing, while skipping
+        # the dispatch when "nothing needs tokens" drains the pipeline
+        # and costs ~30% throughput (measured).  Their extra tokens are
+        # dropped at processing time.
+        if not live and not batch:
+            return False
+        active = np.zeros((self.num_slots,), bool)
+        for i, _ in live:
+            active[i] = True
+
+        if batch:
+            # Admission happens HERE (slots are committed); stamp it
+            # before the prefill dispatch so compile/dispatch time
+            # lands in prefill_s, not queue_s.
+            admit_t = time.time()
+            try:
+                devs, rows = self._fused_dispatch(jnp, batch, active, chunk)
+            except Exception as e:
+                # The batch is already out of _waiting/_pending with
+                # KV blocks held, but not yet in _owner — _fail_all
+                # can't reach it.  Fail + retire each request here
+                # (retire frees its blocks) before re-raising into
+                # the engine loop's recovery path, or callers hang to
+                # timeout and the blocks leak for the engine's life.
+                for slot, req in batch:
+                    req.error = e
+                    self._retire(slot, req)
+                raise
+            # A row whose prompt has chunks still to come holds its slot
+            # and yields no token yet; the others are admitted for good.
+            admitted = [a for a in rows if not a[2]._prefilling]
+            with self._state_lock:
+                for _, slot, req in rows:
+                    self._owner[slot] = req
+                    req._admit_t = req._admit_t or admit_t
+                    # prompt + the chunk the fused step decodes for it
+                    self._disp_len[slot] = (
+                        req._prefilled if req._prefilling
+                        else len(req.prompt) + chunk)
+            self._post_admit(rows)
+            pairs = live + [(slot, req) for _, slot, req in admitted]
+            entry = ("fused", devs, (admitted, pairs))
+        else:
+            key = active.tobytes()
+            if key != self._active_key:
+                self._active_key = key
+                self._active_dev = jnp.asarray(active)
+            entry = ("decode", self._decode_dispatch(chunk), (None, live))
+        for dev in entry[1]:
+            try:
+                dev.copy_to_host_async()
+            except Exception:
+                pass
+        admitted_slots = ({slot for _, slot, _ in entry[2][0]}
+                          if entry[0] == "fused" else set())
+        with self._state_lock:
+            for i, _ in live:
+                # A drained-readmitted slot already had its _disp_len
+                # reset to prompt + chunk above; adding chunk again
+                # would report it "drained" one chunk early and strand
+                # its final chunk.
+                if i not in admitted_slots:
+                    self._disp_len[i] += chunk
+        self._inflight.append(entry)
+        self._proc_wake.set()
+        self.steps += chunk
+        if self._sliding_layers:
+            with self._state_lock:
+                held = [self._disp_len[i]
+                        for i, r in enumerate(self._owner) if r is not None]
+            window = self.cfg.sliding_window
+            with self._kv_lock:
+                self._sliding_held += self._sliding_layers * sum(held)
+                self._sliding_in_window += self._sliding_layers * sum(
+                    min(n, window) for n in held)
+        return True
+
+    def _process_entry(self, entry) -> None:
+        kind, devs, (admitted, pairs) = entry
+        t_read = time.perf_counter()
+        first_dev = np.asarray(devs[0])     # waits for the dispatch
+        t_got = time.perf_counter()
+        self.host_s["read_wait"] += t_got - t_read
+        try:
+            self._hand_out(kind, devs, first_dev, admitted, pairs)
+        finally:
+            self.host_s["process"] += time.perf_counter() - t_got
+
+    def _hand_out(self, kind, devs, first_dev, admitted, pairs) -> None:
+        now = time.time()
+        if kind == "fused":
+            firsts = first_dev
+            for row, slot, req in admitted:
+                req.ttft_s = now - req._t0
+                admit = req._admit_t or now
+                req.queue_s = max(admit - req._t0, 0.0)
+                req.prefill_s = max(now - admit, 0.0)
+                req.slot = slot
+                tok = int(firsts[row])
+                self._push_token(req, tok)
+                if self._finished(req, tok):
+                    self._retire(slot, req)
+            rows = np.asarray(devs[1])
+            self._count_dispatch(devs[2:])
+        else:
+            rows = first_dev
+            self._count_dispatch(devs[1:])
+        # SLO windows (serve autoscaler): TTFT for this entry's
+        # admissions; an inter-token-latency sample from the entry
+        # cadence — each entry carries len(rows) decode steps, so
+        # wall time between consecutive processed entries / chunk is
+        # the per-token latency a streaming client observes.
+        t_proc = time.time()
+        with self._slo_lock:
+            for _, _, req in (admitted or ()):
+                self._ttft_win.append((t_proc, req.ttft_s))
+            if pairs:
+                if self._last_entry_t is not None:
+                    self._itl_win.append(
+                        (t_proc,
+                         max(t_proc - self._last_entry_t, 0.0)
+                         / max(len(rows), 1)))
+                self._last_entry_t = t_proc
+        # Column-major with one C-level tolist() + bulk extends:
+        # per-token Python in this loop contends the GIL with the
+        # dispatcher thread at chunk x B = 256 tokens per entry.
+        # Slots are independent streams, so slot-by-slot processing is
+        # equivalent to token-major order.
+        cols = rows.T.tolist()                # [B][chunk]
+        for slot, req in pairs:
+            if req.done.is_set():
+                continue                      # finished by an earlier entry
+            cap = req._pos_cap
+            col = cols[slot]
+            take = min(len(col),
+                       req.max_new - len(req.tokens),
+                       cap - len(req.prompt) - len(req.tokens))
+            seg = col[:max(take, 0)]
+            if self.eos_id is not None and self.eos_id in seg:
+                seg = seg[:seg.index(self.eos_id) + 1]
+                req.finish_reason = "eos"
+            req.tokens.extend(seg)
+            if req.stream_q is not None:
+                for t in seg:
+                    req.stream_q.put(t)
+            if req.finish_reason == "eos":
+                self._retire(slot, req)
+            elif len(req.tokens) >= req.max_new:
+                req.finish_reason = "length"
+                self._retire(slot, req)
+            elif len(req.prompt) + len(req.tokens) >= cap:
+                # Dispatch stops at the cap margin, so retire here too
+                # or a capped slot would stall unretired.
+                req.finish_reason = "cache"
+                self._retire(slot, req)
+
+    def _engine_loop(self) -> None:
+        import jax.numpy as jnp
+        t0 = time.time()
+        try:
+            self._warmup(jnp)
+        except Exception as e:
+            # A step that cannot compile or run will not start working
+            # later: say so once, fail what is queued, refuse every
+            # later submit with the cause, and stop — a replica that
+            # stayed up would look healthy and answer nothing.
+            import traceback
+            traceback.print_exc()
+            self._engine_error = e
+            self._fail_all(e)
+            return
+        self.warmup_s = time.time() - t0
+        self._warmed = True
+        while not self._shutdown:
+            try:
+                # Acquire a pipeline slot, then dispatch; the processor
+                # releases slots as it drains entries.
+                t_a = time.perf_counter()
+                got = self._slots_sem.acquire(timeout=0.05)
+                t_b = time.perf_counter()
+                self.host_s["permit_wait"] += t_b - t_a
+                if not got:
+                    continue
+                if self._dispatch(jnp):
+                    self.host_s["dispatch"] += time.perf_counter() - t_b
+                else:
+                    self._slots_sem.release()
+                    self._work.wait(timeout=0.05)
+                    self._work.clear()
+                    self.host_s["starved"] += time.perf_counter() - t_b
+            except Exception as e:
+                # An engine failure (e.g. device error) must surface to
+                # every waiting caller, not die with the thread and
+                # zombify the replica.
+                self._slots_sem.release()
+                self._fail_all(e)
+                time.sleep(0.1)
+
+    def _process_loop(self) -> None:
+        while not self._shutdown:
+            try:
+                entry = self._inflight.popleft()
+            except IndexError:
+                # Idle: break the ITL cadence chain, or the first
+                # entry after an idle gap would record (gap / chunk)
+                # as an inter-token-latency sample and spuriously
+                # trip the autoscaler's ITL SLO at light load.
+                with self._slo_lock:
+                    self._last_entry_t = None
+                self._proc_wake.wait(timeout=0.05)
+                self._proc_wake.clear()
+                continue
+            try:
+                self._process_entry(entry)
+            except Exception as e:
+                self._fail_all(e)
+                time.sleep(0.1)
+            finally:
+                # One permit per drained entry, whether it processed
+                # cleanly or died — pipeline depth must never shrink.
+                self._slots_sem.release()
+                self._work.set()
+
 
 class LLMDeployment:
-    """Serve deployment wrapping a PagedBatcher (default) or the dense
-    ContinuousBatcher (`paged_kv=False` escape hatch, one release).
+    """Serve deployment wrapping a PagedBatcher.
 
     Constructor builds (or loads) model params in the replica process —
     on TPU each replica owns the chip its actor reserved.  With
@@ -1723,7 +1548,6 @@ class LLMDeployment:
                  seed: int = 0, params: Any = None,
                  decode_chunk: int = 8,
                  pipeline_depth: int = 2,
-                 paged_kv: bool = True,
                  kv_block_size: Optional[int] = None,
                  kv_num_blocks: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -1738,47 +1562,28 @@ class LLMDeployment:
             params = jax.block_until_ready(transformer.init_params(
                 cfg, jax.random.PRNGKey(seed)))
         self._params_s = time.time() - t0
-        if paged_kv:
-            self.batcher: ContinuousBatcher = PagedBatcher(
-                params, cfg, num_slots=num_slots, max_len=max_len,
-                prompt_pad=prompt_pad, decode_chunk=decode_chunk,
-                pipeline_depth=pipeline_depth,
-                kv_block_size=kv_block_size,
-                kv_num_blocks=kv_num_blocks,
-                prefix_cache=prefix_cache, adapters=adapters,
-                max_resident_models=max_resident_models,
-                max_queue=max_queue)
-        else:
-            if adapters:
-                raise ValueError("adapters/multiplexing requires "
-                                 "paged_kv=True")
-            self.batcher = ContinuousBatcher(
-                params, cfg, num_slots=num_slots, max_len=max_len,
-                prompt_pad=prompt_pad, decode_chunk=decode_chunk,
-                pipeline_depth=pipeline_depth, max_queue=max_queue)
+        self.batcher = PagedBatcher(
+            params, cfg, num_slots=num_slots, max_len=max_len,
+            prompt_pad=prompt_pad, decode_chunk=decode_chunk,
+            pipeline_depth=pipeline_depth,
+            kv_block_size=kv_block_size,
+            kv_num_blocks=kv_num_blocks,
+            prefix_cache=prefix_cache, adapters=adapters,
+            max_resident_models=max_resident_models,
+            max_queue=max_queue)
         # Router probe hook: multiplex-aware pow-2 prefers replicas
         # whose engine already holds the requested adapter merged.
-        self.__rtpu_resident_models__ = self._resident_models
+        self.__rtpu_resident_models__ = self.batcher.resident_models
         # Controller hooks: the autoscaler reads real engine SLO
         # signals (queue depth / TTFT p95 / inter-token p95) instead
         # of whole-request latency, and the health sweep caches the
         # engine's per-instance gauge tags so an unclean replica
         # death can zero its ray_tpu_kv_blocks series.
-        self.__rtpu_slo_stats__ = self._slo_stats
+        self.__rtpu_slo_stats__ = self.batcher.slo_snapshot
         self.__rtpu_kv_engine_tags__ = self._kv_engine_tags
 
-    def _resident_models(self) -> List[str]:
-        if isinstance(self.batcher, PagedBatcher):
-            return self.batcher.resident_models()
-        return []
-
-    def _slo_stats(self) -> Dict[str, Any]:
-        return self.batcher.slo_snapshot()
-
     def _kv_engine_tags(self) -> List[str]:
-        if isinstance(self.batcher, PagedBatcher):
-            return [self.batcher._engine_tag]
-        return []
+        return [self.batcher._engine_tag]
 
     @staticmethod
     def _request_model_id() -> str:
@@ -1791,8 +1596,8 @@ class LLMDeployment:
     async def generate(self, prompt: List[int],
                        max_new: int = 32) -> Dict[str, Any]:
         """Generate up to `max_new` tokens.  Returns the tokens plus a
-        TTFT decomposition; with the paged engine the breakdown also
-        carries `cache_hit`/`cached_tokens` (prefix-cache reuse: a hit
+        TTFT decomposition; the breakdown also carries
+        `cache_hit`/`cached_tokens` (prefix-cache reuse: a hit
         skips device prefill for the cached prefix, so hit TTFT is
         route + queue + suffix prefill only)."""
         import asyncio
@@ -1864,18 +1669,15 @@ class LLMDeployment:
         import ray_tpu
         b = self.batcher
         dev = jax.devices()[0]
-        out = {"steps": b.steps, "warmed": b._warmed,
-               "params_s": self._params_s, "warmup_s": b.warmup_s,
-               "engine_error": (repr(b._engine_error)
-                                if b._engine_error is not None else None),
-               "backend": jax.default_backend(),
-               "device_kind": dev.device_kind,
-               "device_count": jax.device_count(),
-               # None where the backend keeps no statistics (CPU).
-               "peak_bytes": (dev.memory_stats() or {}).get(
-                   "peak_bytes_in_use"),
-               "pid": os.getpid(), "chips": ray_tpu.get_tpu_ids(),
-               "host": dict(b.host_s)}
-        if isinstance(b, PagedBatcher):
-            out.update(b.kv_stats())
-        return out
+        return {"steps": b.steps, "warmed": b._warmed,
+                "params_s": self._params_s, "warmup_s": b.warmup_s,
+                "engine_error": (repr(b._engine_error)
+                                 if b._engine_error is not None else None),
+                "backend": jax.default_backend(),
+                "device_kind": dev.device_kind,
+                "device_count": jax.device_count(),
+                # None where the backend keeps no statistics (CPU).
+                "peak_bytes": (dev.memory_stats() or {}).get(
+                    "peak_bytes_in_use"),
+                "pid": os.getpid(), "chips": ray_tpu.get_tpu_ids(),
+                "host": dict(b.host_s), **b.kv_stats()}

@@ -90,3 +90,27 @@ def cpu_mesh_devices():
     devs = jax.devices("cpu")
     assert len(devs) >= 8, "conftest must force 8 host devices"
     return devs
+
+
+@pytest.fixture(scope="session")
+def lm_params():
+    """make(cfg, seed) -> transformer.init_params with every parameter a
+    trained model has non-zero.  init_params leaves arch "gpt2"'s biases
+    at zero and its learned positions at a hundredth of the token
+    embedding, where a serving path that dropped one of them would still
+    agree with the full-forward oracle."""
+    def make(cfg, seed=0):
+        from ray_tpu.models import transformer
+        params = transformer.init_params(cfg, jax.random.PRNGKey(seed))
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
+        key = jax.random.PRNGKey(seed + 1000)
+        out = []
+        for i, (path, leaf) in enumerate(leaves):
+            name = path[-1].key
+            if name.endswith("_b") or name.startswith("b_") \
+                    or name == "pos_embed":
+                leaf = leaf + 0.3 * jax.random.normal(
+                    jax.random.fold_in(key, i), leaf.shape, leaf.dtype)
+            out.append(leaf)
+        return jax.tree_util.tree_unflatten(treedef, out)
+    return make

@@ -251,7 +251,7 @@ def test_notice_deadline_read_leaks_no_fds(tmp_path):
 
 
 def test_engine_stop_fails_outstanding_requests():
-    """ContinuousBatcher.stop() with work still queued/decoding must
+    """PagedBatcher.stop() with work still queued/decoding must
     fail those requests (callers were left hanging to their timeout)
     and free every KV block — the leak-ledger engine self-finding."""
     import jax

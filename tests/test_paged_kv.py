@@ -1,6 +1,6 @@
 """Paged-KV serving tests: block allocator invariants, radix prefix
-cache, LRU eviction, paged-vs-dense decode numerics (JAX reference
-path), backpressure/finish-reason semantics, and multiplexed per-model
+cache, LRU eviction, paged decode numerics against the full forward
+pass, backpressure/finish-reason semantics, and multiplexed per-model
 prefix-cache isolation (serve/llm.py PagedBatcher +
 ops/paged_attention.py)."""
 
@@ -11,17 +11,27 @@ import time
 import numpy as np
 import pytest
 
-from ray_tpu.serve.llm import (BlockAllocator, ContinuousBatcher,
-                               PagedBatcher, RadixCache)
+from ray_tpu.serve.llm import BlockAllocator, PagedBatcher, RadixCache
 
 
-def _tiny_cfg():
+def _tiny_cfg(arch="llama"):
     import jax.numpy as jnp
     from ray_tpu.models.transformer import TransformerConfig
     return TransformerConfig(vocab_size=97, d_model=32, n_heads=4,
                              n_kv_heads=2, n_layers=2, d_ff=64,
                              max_seq=128, dtype=jnp.float32,
-                             remat=False)
+                             remat=False, arch=arch)
+
+
+def _greedy(params, cfg, prompt, n):
+    """The oracle: `n` greedy tokens by repeated full forward passes."""
+    from ray_tpu.models import transformer
+    seq = list(prompt)
+    for _ in range(n):
+        logits = transformer.forward(
+            params, np.asarray([seq], np.int32), cfg)
+        seq.append(int(np.argmax(np.asarray(logits[0, -1]))))
+    return seq[len(prompt):]
 
 
 def _tiny_params(seed=0):
@@ -505,80 +515,61 @@ def test_warmup_failure_is_loud_not_a_healthy_looking_engine():
         bat.stop()
 
 
-def test_paged_decode_matches_dense_decode_step():
-    """paged_decode_step == decode_step logits/tokens for the same
-    model state (the tier-1 CPU reference-path parity check)."""
-    import jax
+def test_paged_decode_matches_full_forward():
+    """One packed prefill + 6 decode steps (paged_prefill_decode_packed,
+    the engine's fused program) == greedy transformer.forward, token for
+    token (the tier-1 CPU reference-path parity check)."""
     import jax.numpy as jnp
-    from ray_tpu.models import decoding, transformer
+    from ray_tpu.models import decoding
     cfg = _tiny_cfg()
     params = _tiny_params(seed=3)
     num_slots, max_len, bs = 2, 32, 4
     prompts = [[5, 9, 11, 2], [60, 2, 8]]
-    # Dense: packed prefill + N decode steps.
-    dense = decoding.init_caches(cfg, num_slots, max_len)
     W = max_len // bs
     paged = decoding.init_paged_caches(cfg, num_slots,
                                        num_slots * W, bs, max_len)
     P = 8
-    packed_d = np.zeros((num_slots + 1, max(P + 3, num_slots)), np.int32)
     packed_p = np.zeros((num_slots + 1,
                          max(P + 4 + W, num_slots)), np.int32)
     for row, p in enumerate(prompts):
-        packed_d[row, :len(p)] = p
-        packed_d[row, P:P + 3] = (len(p), row, 1)
         packed_p[row, :len(p)] = p
         packed_p[row, P] = len(p)          # suffix == whole prompt
         packed_p[row, P + 1] = 0           # no cached prefix
         packed_p[row, P + 2:P + 4] = (row, 1)
         packed_p[row, P + 4:P + 4 + W] = np.arange(
             1 + row * W, 1 + (row + 1) * W)
-    packed_d[num_slots, :num_slots] = 0
-    packed_p[num_slots, :num_slots] = 0
     steps = 6
-    dense, fd, td = decoding.prefill_decode_packed(
-        params, dense, jnp.asarray(packed_d), cfg, steps, P)
-    paged, fp, tp = decoding.paged_prefill_decode_packed(
+    paged, first, toks = decoding.paged_prefill_decode_packed(
         params, paged, jnp.asarray(packed_p), cfg, steps, P,
         attn_impl="reference")
-    np.testing.assert_array_equal(np.asarray(fd), np.asarray(fp))
-    np.testing.assert_array_equal(np.asarray(td), np.asarray(tp))
-    np.testing.assert_array_equal(np.asarray(dense.lengths),
-                                  np.asarray(paged.lengths))
+    first, toks = np.asarray(first), np.asarray(toks)
+    for row, p in enumerate(prompts):
+        # The prefill's first token, then one per decode step.
+        assert [int(first[row])] + toks[:, row].tolist() == _greedy(
+            params, cfg, p, 1 + steps), p
+    np.testing.assert_array_equal(np.asarray(paged.lengths),
+                                  [len(p) + steps for p in prompts])
 
 
-def test_paged_engine_matches_dense_engine_and_oracle():
-    """End-to-end: PagedBatcher greedy tokens == ContinuousBatcher ==
-    full-forward oracle, including a prefix-cache-hit re-run."""
-    import jax
-    from ray_tpu.models import transformer
-    cfg = _tiny_cfg()
-    params = _tiny_params(seed=0)
+@pytest.mark.parametrize("arch", ["llama", "gpt2"])
+def test_paged_engine_matches_oracle(arch, lm_params):
+    """End-to-end: PagedBatcher greedy tokens == the full-forward
+    oracle, including a prefix-cache-hit re-run."""
+    cfg = _tiny_cfg(arch)
+    params = lm_params(cfg, 0)
     prompts = [[5, 9, 11], [3], [60, 2, 8, 40, 7]]
-    dense = ContinuousBatcher(params, cfg, num_slots=2, max_len=48,
-                              prompt_pad=16, decode_chunk=4)
     paged = _paged(params, cfg)
     try:
-        outs_d = [dense.generate(p, max_new=8, timeout=120)
-                  for p in prompts]
-        outs_p = [paged.generate(p, max_new=8, timeout=120)
-                  for p in prompts]
+        outs = [paged.generate(p, max_new=8, timeout=120)
+                for p in prompts]
         # Re-run: the 5-token prompt now hits its cached first block.
         hit = paged.generate(prompts[2], max_new=8, timeout=120)
         assert hit["cache_hit"] and hit["cached_tokens"] == 4
     finally:
-        dense.stop()
         paged.stop()
-    for p, od, op in zip(prompts, outs_d, outs_p):
-        assert od["tokens"] == op["tokens"], (p, od["tokens"],
-                                              op["tokens"])
-        seq = list(p)
-        for _ in range(8):
-            logits = transformer.forward(
-                params, np.asarray([seq], np.int32), cfg)
-            seq.append(int(np.argmax(np.asarray(logits[0, -1]))))
-        assert op["tokens"] == seq[len(p):]
-    assert hit["tokens"] == outs_p[2]["tokens"]
+    for p, out in zip(prompts, outs):
+        assert out["tokens"] == _greedy(params, cfg, p, 8), p
+    assert hit["tokens"] == outs[2]["tokens"]
 
 
 # ===========================================================================
@@ -631,8 +622,8 @@ def test_oversized_request_reports_cache():
 
 def test_request_capped_by_table_width_truncates_with_cache():
     """A request whose allocation is clamped to its table width decodes
-    to the cap and reports "cache" (the dense-engine semantic kept for
-    the one case it still means something)."""
+    to the cap and reports "cache" (the one case where the reason still
+    means a truncated reply)."""
     cfg = _tiny_cfg()
     params = _tiny_params()
     bat = _paged(params, cfg, num_slots=2, max_len=16, kv_block_size=4,
@@ -830,18 +821,6 @@ def test_multiplex_single_resident_model_swaps():
         m1b = bat.generate(prompt, max_new=6, timeout=120,
                            model_id="m1")
         assert m1b["tokens"] == m1["tokens"]
-    finally:
-        bat.stop()
-
-
-def test_dense_engine_rejects_model_id():
-    cfg = _tiny_cfg()
-    params = _tiny_params()
-    bat = ContinuousBatcher(params, cfg, num_slots=2, max_len=48,
-                            prompt_pad=16)
-    try:
-        with pytest.raises(ValueError, match="paged engine"):
-            bat.submit([1, 2, 3], model_id="m1")
     finally:
         bat.stop()
 

@@ -70,32 +70,42 @@ def test_quantize_params_structure():
     assert param_bytes(qp) < 0.4 * param_bytes(p)
 
 
+def _prefilled(params, prompts, block_size=8, max_len=32):
+    """Paged caches holding `prompts`, one per slot, after the serving
+    path's packed prefill and its first decode step."""
+    B, P = len(prompts), 8
+    W = decoding.paged_table_width(max_len, block_size)
+    packed = np.zeros((B + 1, max(P + 4 + W, B)), np.int32)
+    for row, prompt in enumerate(prompts):
+        packed[row, :len(prompt)] = prompt
+        packed[row, P:P + 4] = (len(prompt), 0, row, 1)
+        packed[row, P + 4:P + 4 + W] = 1 + row * W + np.arange(W)
+    caches = decoding.init_paged_caches(CFG, B, B * W, block_size, max_len)
+    caches, _, toks = decoding.paged_prefill_decode_packed(
+        params, caches, jnp.asarray(packed), CFG, 1, P)
+    return caches, toks[0]
+
+
 def test_quantized_prefill_decode_close_to_fp():
     """Greedy decode over quantized weights tracks the fp32 model."""
     p = tfm.init_params(CFG, jax.random.PRNGKey(0))
     qp = quantize_params(p, CFG)
     toks = jnp.array([[5, 9, 2, 7]])
-    _, _, logits = decoding.prefill(p, toks, jnp.array(4), CFG)
-    _, _, logits_q = decoding.prefill(qp, toks, jnp.array(4), CFG)
+    logits = tfm.forward(p, toks, CFG)[0, -1]
+    logits_q = tfm.forward(qp, toks, CFG)[0, -1]
     rel = float(jnp.max(jnp.abs(logits - logits_q))
                 / (jnp.max(jnp.abs(logits)) + 1e-9))
     assert rel < 0.05, f"quantized prefill drifted {rel:.3f}"
 
-    caches = decoding.init_caches(CFG, 2, 32)
-    caches_q = decoding.init_caches(CFG, 2, 32)
     active = jnp.ones((2,), bool)
-    lens = jnp.array([3, 4], jnp.int32)
-    prompts = jnp.array([[5, 9, 2, 0], [1, 2, 3, 4]], jnp.int32)
-    slots = jnp.arange(2, dtype=jnp.int32)
-    valid = jnp.ones((2,), bool)
-    caches, _ = decoding.prefill_insert(p, caches, prompts, lens, slots,
-                                        valid, CFG)
-    caches_q, _ = decoding.prefill_insert(qp, caches_q, prompts, lens,
-                                          slots, valid, CFG)
-    agree = 0
-    for _ in range(8):
-        caches, t = decoding.decode_step(p, caches, active, CFG)
-        caches_q, tq = decoding.decode_step(qp, caches_q, active, CFG)
+    prompts = [[5, 9, 2], [1, 2, 3, 4]]
+    caches, t = _prefilled(p, prompts)
+    caches_q, tq = _prefilled(qp, prompts)
+    agree = int(jnp.sum(t == tq))
+    for _ in range(7):
+        caches, t = decoding.paged_decode_step(p, caches, active, CFG)
+        caches_q, tq = decoding.paged_decode_step(qp, caches_q, active,
+                                                  CFG)
         agree += int(jnp.sum(t == tq))
     # Random tiny model: near-argmax ties can flip, but the two decodes
     # must be substantially the same trajectory.
@@ -105,9 +115,9 @@ def test_quantized_prefill_decode_close_to_fp():
 def test_init_quantized_params_no_f32_stage():
     qp = init_quantized_params(CFG, jax.random.PRNGKey(1))
     assert isinstance(qp["layers"]["w_up"], QuantizedArray)
-    caches = decoding.init_caches(CFG, 4, 64)
+    caches = decoding.init_paged_caches(CFG, 4, 16, 16, 64)
     active = jnp.ones((4,), bool)
-    _, tok = decoding.decode_step(qp, caches, active, CFG)
+    _, tok = decoding.paged_decode_step(qp, caches, active, CFG)
     assert tok.shape == (4,) and tok.dtype == jnp.int32
 
 
@@ -123,11 +133,11 @@ def test_8b_memory_math_fits_v5e():
 
 
 def test_continuous_batcher_on_quantized_params():
-    from ray_tpu.serve.llm import ContinuousBatcher
+    from ray_tpu.serve.llm import PagedBatcher
     qp = init_quantized_params(CFG, jax.random.PRNGKey(2))
-    bat = ContinuousBatcher(qp, CFG, num_slots=2, max_len=48,
-                            prompt_pad=16, decode_chunk=4,
-                            pipeline_depth=2)
+    bat = PagedBatcher(qp, CFG, num_slots=2, max_len=48,
+                       prompt_pad=16, decode_chunk=4,
+                       pipeline_depth=2, kv_block_size=4)
     try:
         out = bat.generate([1, 2, 3], max_new=6, timeout=120)
         assert len(out["tokens"]) == 6

@@ -157,41 +157,32 @@ def test_replica_failure_recovery(serve_session):
     assert ok >= 6                  # service keeps answering
 
 
-def _tiny_cfg():
+def _tiny_cfg(arch):
     from ray_tpu.models.transformer import TransformerConfig
     import jax.numpy as jnp
     return TransformerConfig(vocab_size=97, d_model=32, n_heads=4,
                              n_kv_heads=2, n_layers=2, d_ff=64,
                              max_seq=128, dtype=jnp.float32,
-                             remat=False)
+                             remat=False, arch=arch)
 
 
-def _make_batcher(paged, params, cfg, num_slots, max_len,
-                  prompt_pad=16):
-    """Either engine behind one knob, mirroring LLMDeployment's
-    paged_kv flag (the dense engine is the paged_kv=False escape
-    hatch for one release — both must serve identically)."""
-    from ray_tpu.serve.llm import ContinuousBatcher, PagedBatcher
-    if paged:
-        return PagedBatcher(params, cfg, num_slots=num_slots,
-                            max_len=max_len, prompt_pad=prompt_pad,
-                            kv_block_size=4)
-    return ContinuousBatcher(params, cfg, num_slots=num_slots,
-                             max_len=max_len, prompt_pad=prompt_pad)
+def _make_batcher(params, cfg, num_slots, max_len, prompt_pad=16):
+    from ray_tpu.serve.llm import PagedBatcher
+    return PagedBatcher(params, cfg, num_slots=num_slots,
+                        max_len=max_len, prompt_pad=prompt_pad,
+                        kv_block_size=4)
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_continuous_batcher_matches_full_forward(paged):
+@pytest.mark.parametrize("arch", ["llama", "gpt2"])
+def test_continuous_batcher_matches_full_forward(arch, lm_params):
     """Greedy decode through the KV-cache engine == greedy decode via
-    repeated full forward passes (the no-cache oracle), in BOTH the
-    paged and dense (escape-hatch) modes."""
-    import jax
+    repeated full forward passes (the no-cache oracle), for both dense
+    architectures (gpt2: learned positions and biases)."""
     from ray_tpu.models import transformer
 
-    cfg = _tiny_cfg()
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    bat = _make_batcher(paged, params, cfg, num_slots=4, max_len=64)
+    cfg = _tiny_cfg(arch)
+    params = lm_params(cfg, 0)
+    bat = _make_batcher(params, cfg, num_slots=4, max_len=64)
     prompts = [[5, 9, 11], [3], [60, 2, 8, 40, 7]]
     outs = [bat.generate(p, max_new=8) for p in prompts]
     bat.stop()
@@ -208,17 +199,14 @@ def test_continuous_batcher_matches_full_forward(paged):
         assert out["tokens"] == want, (prompt, out["tokens"], want)
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_continuous_batcher_concurrent_slots(paged):
-    """Interleaved requests (continuous batching) decode correctly in
-    both engine modes."""
-    import jax
+@pytest.mark.parametrize("arch", ["llama", "gpt2"])
+def test_continuous_batcher_concurrent_slots(arch, lm_params):
+    """Interleaved requests (continuous batching) decode correctly."""
     from ray_tpu.models import transformer
 
-    cfg = _tiny_cfg()
-    params = transformer.init_params(cfg, jax.random.PRNGKey(1))
-    bat = _make_batcher(paged, params, cfg, num_slots=2, max_len=64)
+    cfg = _tiny_cfg(arch)
+    params = lm_params(cfg, 1)
+    bat = _make_batcher(params, cfg, num_slots=2, max_len=64)
     # 5 concurrent requests through 2 slots forces queueing + slot reuse.
     reqs = [bat.submit([i + 1, i + 2], max_new=6) for i in range(5)]
     for r in reqs:

@@ -93,23 +93,17 @@ def test_http_sse_streaming(serve_session):
     assert events[-1]["event"] == "end"
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_llm_engine_stream_matches_generate(serve_session, paged):
+@pytest.mark.parametrize("arch", ["llama", "gpt2"])
+def test_llm_engine_stream_matches_generate(serve_session, arch,
+                                            lm_params):
     from ray_tpu.models import transformer
-    import jax
+    from ray_tpu.serve.llm import PagedBatcher
     cfg = transformer.TransformerConfig(
         vocab_size=128, d_model=64, n_layers=2, n_heads=2, max_seq=64,
-        arch="llama", remat=False, xent_chunk=None,
+        arch=arch, remat=False, xent_chunk=None,
         attn_impl="reference")
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    from ray_tpu.serve.llm import ContinuousBatcher, PagedBatcher
-    if paged:
-        bat = PagedBatcher(params, cfg, num_slots=2, max_len=48,
-                           prompt_pad=8, kv_block_size=4)
-    else:
-        bat = ContinuousBatcher(params, cfg, num_slots=2, max_len=48,
-                                prompt_pad=8)
+    bat = PagedBatcher(lm_params(cfg, 0), cfg, num_slots=2, max_len=48,
+                       prompt_pad=8, kv_block_size=4)
     try:
         ref_out = bat.generate([1, 2, 3], max_new=6)
         streamed = list(bat.generate_stream([1, 2, 3], max_new=6))
@@ -118,15 +112,14 @@ def test_llm_engine_stream_matches_generate(serve_session, paged):
         bat.stop()
 
 
-@pytest.mark.parametrize("paged", [False, True],
-                         ids=["dense", "paged"])
-def test_llm_deployment_streams_tokens(serve_session, paged):
+@pytest.mark.parametrize("arch", ["llama", "gpt2"])
+def test_llm_deployment_streams_tokens(serve_session, arch):
     from ray_tpu.serve.llm import LLMDeployment
     dep = serve.deployment(LLMDeployment).bind(
         cfg_kwargs=dict(vocab_size=128, d_model=64, n_layers=2,
-                        n_heads=2, max_seq=64, arch="llama",
+                        n_heads=2, max_seq=64, arch=arch,
                         remat=False, attn_impl="reference"),
-        num_slots=2, max_len=48, prompt_pad=8, paged_kv=paged)
+        num_slots=2, max_len=48, prompt_pad=8)
     h = serve.run(dep, name="llm")
     # Generous timeouts: under a full parallel suite on the 1-vCPU
     # host, engine warmup compiles contend with every other test.
@@ -144,13 +137,13 @@ def test_engine_eos_retirement(serve_session):
     token; slots still recycle for later requests."""
     import jax
     from ray_tpu.models import transformer
-    from ray_tpu.serve.llm import ContinuousBatcher
+    from ray_tpu.serve.llm import PagedBatcher
     cfg = transformer.TransformerConfig(
         vocab_size=64, d_model=32, n_layers=1, n_heads=2, max_seq=64,
         arch="llama", remat=False, attn_impl="reference")
     params = transformer.init_params(cfg, jax.random.PRNGKey(3))
-    bat = ContinuousBatcher(params, cfg, num_slots=2, max_len=48,
-                            prompt_pad=8, decode_chunk=4)
+    bat = PagedBatcher(params, cfg, num_slots=2, max_len=48,
+                       prompt_pad=8, decode_chunk=4, kv_block_size=4)
     try:
         # Find what the greedy model emits, then declare one of the
         # early tokens as EOS for a second batcher run.
@@ -159,8 +152,9 @@ def test_engine_eos_retirement(serve_session):
         bat.stop()
     eos = probe[2]
     first = probe.index(eos)             # stops at the FIRST occurrence
-    bat = ContinuousBatcher(params, cfg, num_slots=2, max_len=48,
-                            prompt_pad=8, decode_chunk=4, eos_id=eos)
+    bat = PagedBatcher(params, cfg, num_slots=2, max_len=48,
+                       prompt_pad=8, decode_chunk=4, kv_block_size=4,
+                       eos_id=eos)
     try:
         out = bat.generate([1, 2], max_new=8)
         assert out["finish_reason"] == "eos"

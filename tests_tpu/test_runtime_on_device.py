@@ -51,23 +51,20 @@ def exact_f32_matmuls():
     jax.config.update("jax_default_matmul_precision", None)
 
 
-@pytest.mark.parametrize("engine", ["dense", "paged"])
-def test_engine_decode_matches_full_forward_on_tpu(engine,
-                                                   exact_f32_matmuls):
-    """Both continuous-batching engines (pipelined dispatches, async
-    device->host copies; the paged one through the compiled paged
-    kernel and its block tables) decode EXACTLY what repeated full
-    forward passes produce — on the real chip, where dispatch/copy
-    overlap is real concurrency, not interpreter sequencing."""
+def test_engine_decode_matches_full_forward_on_tpu(exact_f32_matmuls):
+    """The continuous-batching engine (pipelined dispatches, async
+    device->host copies, the compiled paged kernel and its block
+    tables) decodes EXACTLY what repeated full forward passes produce —
+    on the real chip, where dispatch/copy overlap is real concurrency,
+    not interpreter sequencing."""
     from ray_tpu.models import transformer
-    from ray_tpu.serve.llm import ContinuousBatcher, PagedBatcher
+    from ray_tpu.serve.llm import PagedBatcher
 
     cfg = _tiny_cfg()
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    kw = dict(num_slots=4, max_len=64, prompt_pad=16, decode_chunk=4,
-              pipeline_depth=3)
-    bat = (ContinuousBatcher(params, cfg, **kw) if engine == "dense"
-           else PagedBatcher(params, cfg, kv_block_size=8, **kw))
+    bat = PagedBatcher(params, cfg, num_slots=4, max_len=64,
+                       prompt_pad=16, decode_chunk=4, pipeline_depth=3,
+                       kv_block_size=8)
     try:
         reqs = [bat.submit(p, max_new=8) for p in _PROMPTS]
         for r in reqs:
@@ -138,13 +135,13 @@ def test_engine_streaming_on_tpu():
     """Streaming consumer receives tokens incrementally while the
     pipelined engine keeps dispatching (SSE data-plane path)."""
     from ray_tpu.models import transformer
-    from ray_tpu.serve.llm import ContinuousBatcher
+    from ray_tpu.serve.llm import PagedBatcher
 
     cfg = _tiny_cfg()
     params = transformer.init_params(cfg, jax.random.PRNGKey(1))
-    bat = ContinuousBatcher(params, cfg, num_slots=2, max_len=64,
-                            prompt_pad=16, decode_chunk=4,
-                            pipeline_depth=2)
+    bat = PagedBatcher(params, cfg, num_slots=2, max_len=64,
+                       prompt_pad=16, decode_chunk=4,
+                       pipeline_depth=2, kv_block_size=8)
     try:
         toks = list(bat.generate_stream([7, 8, 9], max_new=12))
         assert len(toks) == 12
