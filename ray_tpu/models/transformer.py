@@ -29,7 +29,8 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.attention import attention_with_lse, uses_flash
 from ray_tpu.ops.ring_attention import ring_attention
-from ray_tpu.parallel.sharding import _mesh_trivial, constrain, spec_for
+from ray_tpu.parallel.sharding import (_mesh_trivial, constrain,
+                                       shard_count, spec_for)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -509,26 +510,53 @@ def fused_cross_entropy(x: jax.Array, w_out: jax.Array, targets: jax.Array,
     """Chunked softmax cross-entropy that never materializes the full
     [B, S, V] logits (f32 logits for gpt2-small at B=32,S=1k are ~6 GB).
 
-    Scans over token chunks; each step computes one [chunk, V] logits
-    block, reduces it to per-token nll, and is rematerialized in the
-    backward pass (jax.checkpoint), so peak memory is one block.
+    A scan over BLOCKS; each trip computes one block's float32 logits,
+    reduces them to per-token nll, and is rematerialized in the backward
+    pass (jax.checkpoint), so peak memory is one block.
+
+    On one device a block is `cfg.xent_chunk` consecutive tokens of the
+    flattened batch.  Under a mesh that splits "batch" b ways (read off
+    the ambient mesh through the rule table) EVERY device flattens the
+    rows it holds and a block is the same `xent_chunk` tokens of each of
+    them, [b, chunk, D] with b constrained to "batch" (flattened to
+    [b * chunk, D] inside the trip): `xent_chunk` stays the bound on one
+    device's logits block whatever the mesh, the scanned dimension is
+    never a sharded one, and no token leaves its device.  Where "seq" is
+    split (`sp`) the sequence is gathered first, as the unchunked loss in
+    `loss_fn` has it: the sp devices of one batch shard compute the same
+    blocks.  Each device's tokens are padded to whole blocks; padded
+    positions carry target -1 and add nothing.
+
+    The head is cast and gathered ONCE, before the scan: its "embed"
+    (contraction) dimension is sharded under `fsdp`, and a product with a
+    shard of it leaves partial logits that must be all-reduced, one
+    logits-sized collective a trip, forward and backward.  Gathered, the
+    tokens stay where they are, the head's gradient is summed on each
+    device over the trips and reduced once after the scan, and under `tp`
+    ("vocab") only [tokens]-sized maxima, sums and picks cross devices.
+    Invariant (tests/test_tpu_aot.py, tests/test_xent_sharding.py): NO
+    COLLECTIVE OF LOGITS SIZE INSIDE THE SCAN.
     """
     B, S, D = x.shape
-    N = B * S
+    b = shard_count("batch")
+    N = B * S // b                                # one device's tokens
     chunk = min(cfg.xent_chunk or N, N)
-    xf = x.reshape(N, D)
-    tf = targets.reshape(N)
     n = -(-N // chunk)
-    pad = n * chunk - N
-    if pad:
-        xf = jnp.pad(xf, ((0, pad), (0, 0)))
-        tf = jnp.pad(tf, (0, pad), constant_values=-1)
-    wd = w_out.astype(cfg.dtype)
+    pad = ((0, 0), (0, n * chunk - N))
+    xb = jnp.pad(x.reshape(b, N, D), pad + ((0, 0),))
+    tb = jnp.pad(targets.reshape(b, N), pad, constant_values=-1)
+    xb = constrain(jnp.moveaxis(xb.reshape(b, n, chunk, D), 1, 0),
+                   (None, "batch", None, None))
+    tb = constrain(jnp.moveaxis(tb.reshape(b, n, chunk), 1, 0),
+                   (None, "batch", None))
+    wd = constrain(w_out.astype(cfg.dtype), (None, "vocab"))
 
     def body(carry, inp):
-        xc, tc = inp
-        logits = jnp.einsum("cd,dv->cv", xc, wd,
-                            preferred_element_type=jnp.float32)
+        xc, tc = inp[0].reshape(b * chunk, D), inp[1].reshape(b * chunk)
+        logits = constrain(
+            jnp.einsum("cd,dv->cv", xc, wd,
+                       preferred_element_type=jnp.float32),
+            ("batch", "vocab"))
         lse = jax.nn.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(
             logits, jnp.maximum(tc, 0)[:, None], axis=1)[:, 0]
@@ -536,9 +564,8 @@ def fused_cross_entropy(x: jax.Array, w_out: jax.Array, targets: jax.Array,
         return carry + jnp.sum(nll), None
 
     total, _ = jax.lax.scan(
-        jax.checkpoint(body), jnp.zeros((), jnp.float32),
-        (xf.reshape(n, chunk, D), tf.reshape(n, chunk)))
-    return total / N
+        jax.checkpoint(body), jnp.zeros((), jnp.float32), (xb, tb))
+    return total / (B * S)
 
 
 def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None

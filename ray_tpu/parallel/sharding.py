@@ -13,6 +13,7 @@ Standard logical axes: "batch", "seq", "embed", "heads", "kv_heads",
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
@@ -94,8 +95,21 @@ def constrain(x, logical_axes: Sequence[Optional[str]],
         x, NamedSharding(mesh, spec_for(logical_axes, rules, mesh)))
 
 
+def shard_count(logical_axis: str, rules: Optional[Rules] = None,
+                mesh: Optional[Mesh] = None) -> int:
+    """How many ways `constrain` splits a logical axis on the (ambient)
+    mesh; 1 without a mesh.  For code that must size a block by what ONE
+    device holds."""
+    mesh = mesh or _current_mesh()
+    if mesh is None:
+        return 1
+    spec = spec_for((logical_axis,), rules, mesh)
+    parts = spec[0] if len(spec) else ()
+    return math.prod(mesh.shape[a] for a in
+                     ((parts,) if isinstance(parts, str) else parts))
+
+
 def _mesh_trivial(mesh: Mesh) -> bool:
-    import math
     return math.prod(mesh.shape.values()) == 1
 
 

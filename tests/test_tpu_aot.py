@@ -32,36 +32,91 @@ def _custom_calls(compiled) -> int:
                for line in compiled.as_text().splitlines())
 
 
+def _per_chip_bytes(compiled, what: str) -> int:
+    """The ahead-of-time plan of one chip's memory, printed (`-s`) so the
+    next reader sees its size."""
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"{what}: plan {per_chip / 2 ** 30:.2f} GiB a chip "
+          f"(temporaries {mem.temp_size_in_bytes / 2 ** 30:.2f})")
+    return per_chip
+
+
+def _compile_fsdp_step(cfg, devices, chips, batch, seq, optimizer):
+    """The whole train step under `MeshSpec(fsdp=chips)`, from shapes."""
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+    from ray_tpu.train.train_step import CompiledTrainStep
+
+    step = CompiledTrainStep(
+        cfg, make_mesh(MeshSpec(fsdp=chips), devices=devices[:chips]),
+        optimizer=optimizer)
+    state = jax.eval_shape(step._init, jax.random.PRNGKey(0))
+    return step._step.lower(
+        state, jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32)).compile()
+
+
+def _assert_loss_keeps_its_tokens(compiled, cfg):
+    """`fused_cross_entropy`'s invariant: no all-reduce or all-to-all of
+    logits size (one device's `xent_chunk` x vocabulary) inside a loop."""
+    from test_xent_sharding import loop_collectives
+    big = [c for c in loop_collectives(compiled.as_text())
+           if c[1] >= cfg.xent_chunk * cfg.vocab_size]
+    assert not big, big
+
+
 @pytest.mark.parametrize("chips,batch", [(1, 2), (4, 8)])
 def test_llama_1b_train_step_compiles_for_v5e(v5e_devices, chips, batch):
     """chip_smoke.py's train step.  Under fsdp=4 the flash kernel must
     run per shard (shard_map): XLA cannot partition a Mosaic call."""
     from ray_tpu.models import transformer as tfm
-    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
-    from ray_tpu.train.train_step import CompiledTrainStep, make_optimizer
+    from ray_tpu.train.train_step import make_optimizer
 
     seq = 2048
     cfg = dataclasses.replace(
         tfm.PRESETS["llama-1b"], max_seq=seq, remat=True,
         remat_policy="names", xent_chunk=2048, attn_block_k=1024,
         attn_impl="flash")
-    mesh = make_mesh(MeshSpec(fsdp=chips), devices=v5e_devices[:chips])
-    step = CompiledTrainStep(
-        cfg, mesh, optimizer=make_optimizer(total_steps=1000,
-                                            kind="adafactor"))
-    state = jax.eval_shape(step._init, jax.random.PRNGKey(0))
-    compiled = step._step.lower(
-        state, jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32)).compile()
+    compiled = _compile_fsdp_step(
+        cfg, v5e_devices, chips, batch, seq,
+        make_optimizer(total_steps=1000, kind="adafactor"))
     # One forward and two backward kernels in the layer scan: the
     # remat policy keeps the forward's residuals, also under shard_map.
     assert _custom_calls(compiled) == 3
     # The ahead-of-time plan is pessimistic (the chip ran batch 4
     # against a plan of 16.9 GiB, PERF.md), so a plan that fits is a
     # step that fits.
-    mem = compiled.memory_analysis()
-    per_chip = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    per_chip = _per_chip_bytes(compiled, f"llama-1b fsdp={chips}")
     assert per_chip < 15.75 * 2 ** 30, f"{per_chip / 2 ** 30:.2f} GiB"
+    _assert_loss_keeps_its_tokens(compiled, cfg)
+
+
+def test_fsdp4_cell_step_keeps_its_tokens(v5e_devices):
+    """`train-4k-fsdp4`'s own step (benchmarks/lib/train_cell.py: the
+    `train` block of mistral-7b-l16, batch 16 x 4,097 under fsdp=4).  Its
+    plan over-states (the chip runs it: PERF.md), so only the loss's
+    invariant is held here."""
+    import json
+    import os
+
+    from benchmarks.lib import spec, worker_util
+    from ray_tpu.models import transformer as tfm
+    from ray_tpu.train.train_step import make_optimizer
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "mistral-7b-l16.json")) as f:
+        mc = json.load(f)
+    tr, seq, chips = mc["train"], 4096, 4
+    cfg = tfm.TransformerConfig(**worker_util.with_dtypes(
+        spec.model_kind(mc["kind"]).transformer_kwargs(
+            mc, max_seq=seq, param_dtype=tr["param_dtype"], remat=True,
+            remat_policy=tr["remat_policy"], xent_chunk=tr["xent_chunk"],
+            attn_block_k=tr["attn_block_k"], attn_impl="flash")))
+    compiled = _compile_fsdp_step(
+        cfg, v5e_devices, chips, tr["batch_per_chip"] * chips, seq,
+        make_optimizer(total_steps=10_000, kind=tr["optimizer"]))
+    _per_chip_bytes(compiled, "mistral-7b-l16 fsdp=4")
+    _assert_loss_keeps_its_tokens(compiled, cfg)
 
 
 def _on_chip_shapes(v5e_devices):
