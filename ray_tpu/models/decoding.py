@@ -34,7 +34,7 @@ branch to them and return the expert layers' counts as one more value.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -133,6 +133,27 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
         last_token=jnp.zeros((num_slots,), jnp.int32))
 
 
+# A request's paged prefix is streamed once per this many of its queries:
+# rows narrower than this that follow each other in one request attend as
+# ONE row (QueryGroups), so that what a row rounds a request up to for the
+# dense products (serve/llm.py PREFILL_TILE) does not multiply the reads of
+# a long prefix.
+ATTENTION_ROW = 64
+
+
+class QueryGroups(NamedTuple):
+    """The R attention rows of a prefill whose N rows of P are narrower
+    than ATTENTION_ROW: each is up to K = ATTENTION_ROW // P rows of one
+    slot that follow each other (a row that starts where the one before it,
+    full, ended)."""
+
+    take: jax.Array          # [R, K] the rows an attention row is made of
+    tables: jax.Array        # [R, W]
+    prefix_lens: jax.Array   # [R] cached before its first row
+    suffix_lens: jax.Array   # [R] live queries, 0: no attention row
+    back: jax.Array          # [N] each row's place among the R * K
+
+
 class PrefillRows(NamedTuple):
     """What every layer of one prefill needs of its rows (N rows of P)."""
 
@@ -143,6 +164,7 @@ class PrefillRows(NamedTuple):
     live: jax.Array          # [N, P] bool: a real token of a valid row
     blocks: jax.Array        # [N, P] pool block each position is written to
     offsets: jax.Array       # [N, P] and where in it (scratch 0: not live)
+    groups: Optional[QueryGroups] = None    # rows that attend together
 
 
 class DecodeRows(NamedTuple):
@@ -157,15 +179,69 @@ class DecodeRows(NamedTuple):
 
 
 def prefill_rows(tables, prefix_lens, suffix_lens, valid, P: int,
-                 block_size: int) -> PrefillRows:
+                 block_size: int, slots=None,
+                 num_slots: int = 0) -> PrefillRows:
+    """`slots` [N] (which of `num_slots` requests a row belongs to) lets
+    rows narrower than ATTENTION_ROW attend in groups; without it every
+    row attends alone."""
     M = tables.shape[1] * block_size
     positions = prefix_lens[:, None] + jnp.arange(P, dtype=jnp.int32)
     live = valid[:, None] & (jnp.arange(P)[None, :] < suffix_lens[:, None])
     abs_pos = jnp.minimum(positions, M - 1)
     blocks = jnp.take_along_axis(tables, abs_pos // block_size, axis=1)
-    return PrefillRows(positions, tables, prefix_lens,
-                       jnp.where(valid, suffix_lens, 0), live,
-                       jnp.where(live, blocks, 0), abs_pos % block_size)
+    suffix_lens = jnp.where(valid, suffix_lens, 0)
+    groups = None
+    if slots is not None and tables.shape[0] > 1 and ATTENTION_ROW // P > 1:
+        groups = _query_groups(tables, prefix_lens, suffix_lens, valid,
+                               slots, P, num_slots)
+    return PrefillRows(positions, tables, prefix_lens, suffix_lens, live,
+                       jnp.where(live, blocks, 0), abs_pos % block_size,
+                       groups)
+
+
+def _query_groups(tables, prefix_lens, suffix_lens, valid, slots,
+                  P: int, num_slots: int) -> QueryGroups:
+    N, K = slots.shape[0], ATTENTION_ROW // P
+    n = jnp.arange(N, dtype=jnp.int32)
+
+    def before(a):
+        return jnp.roll(a, 1)
+
+    follows = (valid & before(valid) & (slots == before(slots))
+               & (before(suffix_lens) == P)
+               & (prefix_lens == before(prefix_lens) + P)).at[0].set(False)
+    place = (n - jax.lax.cummax(jnp.where(follows, 0, n))) % K
+    heads = valid & (place == 0)
+    group = jnp.cumsum(heads, dtype=jnp.int32) - 1
+    # every request's rows in groups of K, the last of them partial
+    R = min(N, (N + num_slots * (K - 1)) // K)
+    first = jnp.zeros((R,), jnp.int32).at[
+        jnp.where(heads, group, R)].set(n, mode="drop")
+    return QueryGroups(
+        take=jnp.minimum(first[:, None] + jnp.arange(K, dtype=jnp.int32),
+                         N - 1),
+        tables=tables[first], prefix_lens=prefix_lens[first],
+        suffix_lens=jnp.zeros((R,), jnp.int32).at[
+            jnp.where(valid, group, R)].add(suffix_lens, mode="drop"),
+        back=jnp.clip(group * K + place, 0, R * K - 1))
+
+
+def _attend_rows(q, k_pool, v_pool, rows: PrefillRows, first_block=0,
+                 **kw):
+    """prefix_attention of a prefill's queries [N, P, H, D], in the rows'
+    groups where they have any; `first_block` is added to the tables (a
+    layer's pool inside the stacked one)."""
+    from ray_tpu.ops import paged_attention as _pa
+    g = rows.groups
+    if g is None:
+        return _pa.prefix_attention(q, k_pool, v_pool,
+                                    first_block + rows.tables,
+                                    rows.prefix_lens, rows.suffix_lens, **kw)
+    (R, K), (N, P, H, D) = g.take.shape, q.shape
+    o = _pa.prefix_attention(q[g.take].reshape(R, K * P, H, D), k_pool,
+                             v_pool, first_block + g.tables, g.prefix_lens,
+                             g.suffix_lens, **kw)
+    return o.reshape(R * K, P, H, D)[g.back]
 
 
 def decode_rows(tables, lengths, active, block_size: int) -> DecodeRows:
@@ -321,12 +397,15 @@ def _paged_prefill_core(params: Dict[str, Any],
     this one) is read from the pool: every layer writes the whole call's
     K/V (`_write_rows`) BEFORE it attends (`prefix_attention`), so a later
     tile of the same call sees an earlier one as prefix.  Several rows may
-    therefore name one slot; only the row that ends its prompt (`closes`)
+    therefore name one slot, and those that follow each other attend as
+    one row of up to ATTENTION_ROW queries (QueryGroups: the prefix is
+    streamed once for them); only the row that ends its prompt (`closes`)
     yields the request's first token and hands the slot its table and
     length.  A row that is not `valid` writes to the scratch block only."""
     N, P = tokens.shape
     rows = prefill_rows(new_bt, prefix_lens, suffix_lens, valid, P,
-                        caches.kp[0].shape[-2])
+                        caches.kp[0].shape[-2], slots,
+                        caches.lengths.shape[0])
     last_ix = (jnp.arange(N), jnp.clip(suffix_lens - 1, 0, P - 1))
     if cfg.arch == "afmoe":
         from ray_tpu.models import afmoe
@@ -360,7 +439,6 @@ def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
                           attn_impl):
     """Arch "llama" / "gpt2": the layer scan of one prefill over the
     stacked pool -> (x' [N, P, D], kp', vp')."""
-    from ray_tpu.ops import paged_attention as _pa
     x = params["tok_embed"][tokens].astype(cfg.dtype)        # [N,P,D]
     if cfg.arch == "gpt2":
         x = x + params["pos_embed"][
@@ -375,9 +453,8 @@ def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
         q, k, v = _qkv(p, h, cfg, rows.positions)
         k_pool = _write_rows(k_pool, first + rows.blocks, rows.offsets, k)
         v_pool = _write_rows(v_pool, first + rows.blocks, rows.offsets, v)
-        o = _pa.prefix_attention(q, k_pool, v_pool, first + rows.tables,
-                                 rows.prefix_lens, rows.suffix_lens,
-                                 impl=attn_impl)             # [N,P,H,Dh]
+        o = _attend_rows(q, k_pool, v_pool, rows, first,
+                         impl=attn_impl)                     # [N,P,H,Dh]
         attn = jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
                           p["wo"].astype(cfg.dtype))
         return (_mlp(p, x + attn, cfg), k_pool, v_pool), None
@@ -405,9 +482,12 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
                     slot | valid | block_table[0:W]]
       row  N:      active mask for the B decode slots in cols 0..B-1.
 
-    A row is a tile of one request's uncached tokens; a request longer
-    than P takes several rows, in this call or over several (the host
-    loop: serve/llm.py).  `valid` 0: no row.  1: the row ends its prompt:
+    A row is a tile of one request's uncached tokens (a KV block or two:
+    N x P positions are what the dense products see, N one of the host
+    loop's ladder of widths); a request longer than P takes several rows,
+    one after the other, in this call or over several (the host loop:
+    serve/llm.py), and those of one call attend in groups of up to
+    ATTENTION_ROW queries.  `valid` 0: no row.  1: the row ends its prompt:
     it yields the first token and its slot decodes from this dispatch on.
     2: more of the prompt is to come: its K/V are written and its slot
     stays out of the decode steps.  Arch "afmoe" returns its expert
@@ -455,16 +535,14 @@ def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
     queries attend to the pool (prefix and chunk alike, under the layer's
     window).  -> (x', k_pool', v_pool', afmoe.MOE_COUNTS)."""
     from ray_tpu.models import afmoe
-    from ray_tpu.ops import paged_attention as _pa
     pools = []
 
     def attend(q, k, v):
         kp = _write_rows(k_pool, rows.blocks, rows.offsets, k)
         vp = _write_rows(v_pool, rows.blocks, rows.offsets, v)
         pools.extend((kp, vp))
-        return _pa.prefix_attention(
-            q, kp, vp, rows.tables, rows.prefix_lens, rows.suffix_lens,
-            impl=attn_impl, window=afmoe.window_of(cfg, kind))
+        return _attend_rows(q, kp, vp, rows, impl=attn_impl,
+                            window=afmoe.window_of(cfg, kind))
 
     x, counts = afmoe.layer(cfg, kind, p, x, rows.positions, attend,
                             valid=rows.live, moe_name="moe_experts_prefill",

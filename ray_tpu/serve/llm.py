@@ -36,16 +36,19 @@ is the decode-only program (paged_decode_steps).  All host inputs of a
 dispatch travel in one int32 upload.
 
 Prefill: `prompt_pad` is the longest prompt `submit` accepts.  A row of
-the fused prefill is a TILE of PREFILL_TILE tokens of one request's
-uncached suffix, not a request: a suffix longer than a tile takes
-several rows, each against the blocks the rows before it wrote.  A
-dispatch runs the narrowest of a short ladder of compiled row counts that
-holds the tiles it is given, and the widest is the most prefill one
-dispatch carries (PREFILL_CHUNK tokens).  What does not fit waits for the
-next dispatch, first in first served: the request holds its slot
-meanwhile without decoding, the other slots decode on in the same
-dispatches, and the radix tree takes the prompt's blocks as they are
-dispatched.
+the fused prefill is a TILE of PREFILL_TILE tokens (a KV block or two) of
+one request's uncached suffix, not a request: a request rounds up to whole
+rows, a suffix longer than a tile takes several, each against the blocks
+the rows before it wrote (and consecutive rows of one request attend
+together, so its cached prefix is not read once a row).  A dispatch runs
+the narrowest of a short ladder of compiled widths (PREFILL_RUNGS, in
+positions) that holds the rows it is given, and the widest is the most
+prefill one dispatch carries: PREFILL_CHUNK tokens, however many slots
+the engine has.  What
+does not fit waits for the next dispatch, first in first served: the
+request holds its slot meanwhile without decoding, the other slots decode
+on in the same dispatches, and the radix tree takes the prompt's blocks
+as they are dispatched.
 
 Pipelining: a loop that synchronizes with the device once per step
 (dispatch → block on the token read → repeat) leaves the chip idle for
@@ -437,24 +440,40 @@ class RadixCache:
         self.size -= 1
 
 
-# A row of the fused prefill holds this many tokens of one request's
-# uncached suffix (a tile), and a dispatch carries at most PREFILL_CHUNK
-# tokens of prefill: the widest program the engine compiles.  What an
-# admission brings that does not fit waits for the next dispatch, holding
-# its slot, while the other slots go on decoding.  64 because a session
-# turn's suffix (a message, the last reply, a partial block) is 20-70
-# tokens: PERF.md section 6, PR 28 has the measurement against 128.
-PREFILL_TILE = 64
+# What the fused prefill rounds up to.  A row holds PREFILL_TILE tokens of
+# ONE request's uncached suffix, so a request rounds up to whole rows: a
+# block or two (kv_block_size 16 is the floor: a prefix-cache hit is whole
+# blocks), since a session turn's suffix (a message, the last reply, a
+# partial block) is 17-40 tokens and a row of 64 was half padding (PERF.md
+# section 6, PR 33 has the measurement against 64 and 32).  A dispatch
+# rounds up to the next of PREFILL_RUNGS positions: the dense products see
+# rows x tile positions whatever the rows are.  Every rung is a program
+# every engine traces and loads at warm-up (a twentieth of a serving
+# cell's set-up each), so there are four, as before, placed by the
+# admitted positions a dispatch that the serving cells' counters show (a
+# lone chat prompt under 256; session turns 400-640 two dispatches in
+# three, under 896 nearly all the rest), not at powers of two: measured
+# against 256 / 512 / 768 / 1,024 / 2,048 in the same section.  A dispatch
+# carries at most PREFILL_CHUNK tokens of prefill, the widest program; what
+# an admission brings that does not fit waits for the next dispatch,
+# holding its slot, while the other slots go on decoding.
+PREFILL_TILE = 16
 PREFILL_CHUNK = 2048
+PREFILL_RUNGS = (256, 640, 896, 2048)
 
 
-def prefill_shapes(num_slots: int, prompt_pad: int):
+def prefill_shapes(num_slots: int, prompt_pad: int, block_size: int):
     """(tokens a row, [rows of each compiled fused prefill]) of an engine:
-    the widest program is a tile for every slot, within PREFILL_CHUNK
-    tokens; three more halve down from it."""
-    tile = min(PREFILL_TILE, PREFILL_CHUNK, prompt_pad)
-    widest = max(1, min(num_slots, PREFILL_CHUNK // tile))
-    return tile, sorted({max(1, widest >> k) for k in range(4)})
+    a row is PREFILL_TILE tokens in whole KV blocks, the widest program
+    holds PREFILL_CHUNK tokens whatever the slots are (or every slot's
+    longest prompt, where that is less), the others are the rungs of
+    PREFILL_RUNGS below it."""
+    tile = min(-(-PREFILL_TILE // block_size) * block_size, PREFILL_CHUNK,
+               prompt_pad)
+    widest = max(1, min(PREFILL_CHUNK // tile,
+                        num_slots * -(-prompt_pad // tile)))
+    return tile, sorted({min(-(-r // tile), widest) for r in PREFILL_RUNGS}
+                        | {widest})
 
 
 class PagedBatcher:
@@ -468,7 +487,8 @@ class PagedBatcher:
     reads their tokens.  Admission allocates refcounted blocks (evicting
     cold cached blocks, then QUEUEING under pressure), prefill runs only
     the prompt's uncached suffix via paged_prefill_decode_packed (as
-    tiles of PREFILL_TILE, at most PREFILL_CHUNK tokens a dispatch), and
+    rows of PREFILL_TILE tokens in the narrowest program of PREFILL_RUNGS
+    positions that holds them, at most PREFILL_CHUNK tokens a dispatch), and
     decode gathers KV through block tables with the ragged paged
     attention kernel.
     """
@@ -526,10 +546,10 @@ class PagedBatcher:
         # The fused prefill's shapes: rows of `_tile` tokens, and a ladder
         # of row counts; the widest is the budget of one dispatch.  A
         # dispatch costs what it admits, to within a rung: a prefix-cache
-        # hit that leaves a short suffix pays for one tile, not for a
-        # prompt-wide row.
-        self._tile, self._prefill_rows = prefill_shapes(num_slots,
-                                                        prompt_pad)
+        # hit that leaves a short suffix pays for a row or two, not for a
+        # prompt-wide one.
+        self._tile, self._prefill_rows = prefill_shapes(
+            num_slots, prompt_pad, self.block_size)
         if prompt_pad > self._tile and self._tile % self.block_size:
             raise ValueError(
                 f"prompts longer than {self._tile} are prefilled in tiles "
@@ -560,7 +580,8 @@ class PagedBatcher:
         # Counted for stats(): prefill chunks (one per request and
         # dispatch) and their tokens, the positions the dispatches' rows
         # held (rows x tile: chunk_tokens / padded_tokens is how full they
-        # were), requests that took more than one dispatch; what an
+        # were), how many fused dispatches ran each compiled width (by its
+        # positions), requests that took more than one dispatch; what an
         # expert model's layers
         # report per dispatch (models/afmoe.py MOE_COUNTS); and, per
         # dispatch over owned slots and sliding layers, the positions
@@ -569,6 +590,8 @@ class PagedBatcher:
         self._prefill_counts = {"chunks": 0, "chunk_tokens": 0,
                                 "padded_tokens": 0,
                                 "multi_chunk_requests": 0}
+        self._rung_dispatches = {str(n * self._tile): 0
+                                 for n in self._prefill_rows}
         self._moe_counts = [0, 0, 0, 0]
         self._sliding_layers = sum(
             1 for m, _ in (cfg.layer_kinds or ()) if m == "sliding")
@@ -778,7 +801,9 @@ class PagedBatcher:
                 },
                 "models_resident": list(self._models),
                 "model_id": self._model_id,
-                "prefill": dict(self._prefill_counts),
+                "prefill": dict(
+                    self._prefill_counts,
+                    rung_dispatches=dict(self._rung_dispatches)),
                 "moe": dict(zip(("layer_steps", "routed_rows",
                                  "busiest_expert_rows", "experts_touched"),
                                 self._moe_counts)),
@@ -1232,6 +1257,7 @@ class PagedBatcher:
         self._prefill_counts["chunks"] += len(batch)
         self._prefill_counts["chunk_tokens"] += sum(takes)
         self._prefill_counts["padded_tokens"] += N * T
+        self._rung_dispatches[str(N * T)] += 1
         return tuple(devs), rows
 
     def _decode_dispatch(self, chunk: int) -> tuple:

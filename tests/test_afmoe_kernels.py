@@ -150,3 +150,40 @@ def test_prefix_attention_matches_plain_attention(impl, window):
             np.testing.assert_allclose(got[n, :live], want[:live],
                                        rtol=1e-4, atol=1e-4)
     assert bool(jnp.all(jnp.isfinite(got)))
+
+
+@pytest.mark.parametrize("impl", ["kernel", "reference"])
+@pytest.mark.parametrize("window", [None, 24])
+def test_rows_of_one_request_attend_in_groups(impl, window):
+    """Rows of 16 regrouped into attention rows of up to 64 queries
+    (models/decoding.py QueryGroups) against every row alone: three rows of
+    slot 0 that start inside its table (the last partly filled), six of
+    slot 1 (a full group of four and one of two), a row of slot 2 that
+    does NOT follow the row before it although it starts where that ended,
+    a row of slot 0 again that follows nothing, and padding."""
+    from ray_tpu.models import decoding
+    P, H, hkv, D, bs, W, slots = 16, 4, 2, 32, 16, 24, 4
+    kp, vp, per_slot = _pool(jax.random.PRNGKey(5), slots * W + 1, hkv, bs,
+                             D, slots, W)
+    slot = jnp.asarray([0, 0, 0, 1, 1, 1, 1, 1, 1, 2, 0, 0, 0], jnp.int32)
+    prefix = jnp.asarray([48, 64, 80, 0, 16, 32, 48, 64, 80, 96, 200, 0, 0],
+                         jnp.int32)
+    suffix = jnp.asarray([16, 16, 7, 16, 16, 16, 16, 16, 3, 9, 16, 0, 0],
+                         jnp.int32)
+    valid = suffix > 0
+    q = jax.random.normal(jax.random.PRNGKey(6), (len(slot), P, H, D),
+                          jnp.float32)
+    rows = decoding.prefill_rows(per_slot[slot], prefix, suffix, valid, P,
+                                 bs, slot, slots)
+    g = rows.groups
+    assert g.take.shape == (min(13, (13 + slots * 3) // 4), 4)
+    assert np.asarray(g.suffix_lens).tolist() == [39, 64, 19, 9, 16, 0]
+    assert np.asarray(g.prefix_lens)[:5].tolist() == [48, 0, 64, 96, 200]
+    with jax.default_matmul_precision("highest"):
+        got = decoding._attend_rows(q, kp, vp, rows, impl=impl,
+                                    window=window)
+        want = decoding._attend_rows(q, kp, vp, rows._replace(groups=None),
+                                     impl=impl, window=window)
+    live = np.asarray(rows.live)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=1e-4, atol=1e-4)

@@ -150,8 +150,9 @@ def test_paged_serving_steps_compile_for_v5e(v5e_devices, model,
     caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
         cfg, slots, slots * W, bs, max_len)))
     active = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=on_chip)
-    tile, ladder = llm.prefill_shapes(slots, prompt_pad)
-    assert (tile, ladder) == (64, [1, 2, 4, 8])
+    tile, ladder = llm.prefill_shapes(slots, prompt_pad, bs)
+    # 8 slots of one 64-token prompt each: the widest program is theirs
+    assert tile * ladder[-1] == 8 * 64 and ladder == sorted(set(ladder))
     for N in ladder:
         packed = jax.ShapeDtypeStruct(
             (N + 1, max(tile + 4 + W, slots)), jnp.int32, sharding=on_chip)
@@ -203,8 +204,18 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
         cfg, sv["num_slots"], sv["kv_num_blocks"], sv["kv_block_size"],
         sv["max_len"])))
-    tile, ladder = llm.prefill_shapes(sv["num_slots"], sv["prompt_pad"])
-    assert tile * ladder[-1] == llm.PREFILL_CHUNK and len(ladder) <= 4
+    tile, ladder = llm.prefill_shapes(sv["num_slots"], sv["prompt_pad"],
+                                      sv["kv_block_size"])
+    # the budget is PREFILL_CHUNK tokens whatever the slots are, in at
+    # most six programs, none more than 384 positions wider than the one
+    # before it up to 896
+    for slots in (4, sv["num_slots"]):
+        assert llm.prefill_shapes(slots, sv["prompt_pad"],
+                                  sv["kv_block_size"]) == (tile, ladder)
+    assert tile * ladder[-1] == llm.PREFILL_CHUNK and len(ladder) <= 6
+    widths = [0] + [tile * n for n in ladder]
+    assert all(b - a <= 384 for a, b in zip(widths, widths[1:])
+               if b <= 896)
     for N in ladder:
         packed = jax.ShapeDtypeStruct(
             (N + 1, max(tile + 4 + W, sv["num_slots"])), jnp.int32,

@@ -189,15 +189,16 @@ def test_windowed_paged_kernel_at_afmoe_widths_on_tpu(window):
                                atol=2e-2, rtol=2e-2)
 
 
-def _check_prefix_attention(kp, vp, tables, q, prefix, suffix, window):
-    """The kernel against plain attention, computed row by row over each
-    row's own keys."""
+def _check_prefix_attention(kp, vp, tables, q, prefix, suffix, window,
+                            attend=None):
+    """The kernel (or `attend`, which takes its arguments) against plain
+    attention, computed row by row over each row's own keys."""
     from ray_tpu.ops.paged_attention import prefix_attention
     N, P, H, D = q.shape
     hkv, bs = kp.shape[1], kp.shape[2]
     W = tables.shape[1]
-    got = prefix_attention(q, kp, vp, tables, prefix, suffix, impl="kernel",
-                           window=window)
+    got = (attend or prefix_attention)(q, kp, vp, tables, prefix, suffix,
+                                       impl="kernel", window=window)
 
     @jax.jit
     def plain(kp, vp, qn, table, pre, live):    # the pools as arguments:
@@ -262,6 +263,43 @@ def test_prefix_attention_at_tile_rows_on_tpu(hkv, W, window, tile):
     _check_prefix_attention(
         kp, vp, tables, q, jnp.asarray(prefix + [0] * pad, jnp.int32),
         jnp.asarray(suffix + [0] * pad, jnp.int32), window)
+
+
+@pytest.mark.parametrize("tile", [16, 32])
+@pytest.mark.parametrize("hkv,W,window", [(8, 48, None), (4, 1072, 2048),
+                                          (4, 1072, None)])
+def test_rows_of_one_request_attend_in_groups_on_tpu(hkv, W, window, tile):
+    """The fused prefill's rows of a block or two as the engine packs them
+    (serve/llm.py PREFILL_TILE), regrouped into attention rows of up to 64
+    queries (models/decoding.py QueryGroups), against plain attention row
+    by row: session turns of 17-70 tokens behind cached prefixes (two to
+    five rows of a slot, the last partly filled, starting at a block inside
+    the slot's table), a whole prompt of 200 tokens, and padding up to a
+    width of 768 positions."""
+    from ray_tpu.models import decoding
+    slots, H = 32, 32
+    kp, vp, per_slot = _afmoe_pool(slots, W, seed=5, hkv=hkv)
+    far = W * 16 - 64 - 16
+    turns = [(256, 39), (448, 17), (far, 64), (0, 200), (320, 70), (16, 33)]
+    slot, prefix, suffix = [], [], []
+    for i, (pre, n) in enumerate(turns):
+        for at in range(0, n, tile):
+            slot.append(i)
+            prefix.append(pre + at)
+            suffix.append(min(tile, n - at))
+    pad = 768 // tile - len(slot)
+    slot, prefix, suffix = (jnp.asarray(a + [0] * pad, jnp.int32)
+                            for a in (slot, prefix, suffix))
+    rows = decoding.prefill_rows(per_slot[slot], prefix, suffix, suffix > 0,
+                                 tile, 16, slot, slots)
+    assert np.asarray(rows.groups.suffix_lens)[:9].tolist() == [
+        39, 17, 64, 64, 64, 64, 8, 64, 6]
+    q = jax.random.normal(jax.random.PRNGKey(6),
+                          (len(slot), tile, H, 128), jnp.bfloat16)
+    real = decoding._attend_rows
+    _check_prefix_attention(
+        kp, vp, per_slot[slot], q, prefix, suffix, window,
+        attend=lambda q, kp, vp, *_, **kw: real(q, kp, vp, rows, **kw))
 
 
 @pytest.mark.parametrize("rows", [32, 2048])
