@@ -356,6 +356,7 @@ class NodeService(ObjectPlaneMixin, PlacementGroupMixin,
                 except Exception:
                     pass
         deadline = time.time() + 2.0
+        killed = []
         for w in workers:
             if w.proc is None:
                 continue
@@ -363,6 +364,18 @@ class NodeService(ObjectPlaneMixin, PlacementGroupMixin,
                 w.proc.wait(timeout=max(0.05, deadline - time.time()))
             except subprocess.TimeoutExpired:
                 w.proc.kill()
+                killed.append(w.proc)
+        # A killed worker is REAPED before shutdown returns.  One that held
+        # accelerators gives them back only when the kernel has torn its
+        # mappings down, which takes seconds at 16 GB a chip: a process that
+        # opens the device meanwhile fails ("open(/dev/vfio/0): Device or
+        # resource busy": a benchmark cell started straight after another's
+        # shutdown, PR 34), and no /proc/<pid>/fd shows the chip as held.
+        for proc in killed:
+            try:
+                proc.wait(timeout=60.0)
+            except subprocess.TimeoutExpired:
+                pass
         if self._listener:
             self._listener.close()
         if self.multinode:

@@ -17,6 +17,7 @@ hook points plus the rpc retry that absorbs injected pre-send failures
 
 from __future__ import annotations
 
+import collections
 import pickle
 import socket
 import struct
@@ -190,7 +191,12 @@ class Connection:
                  push_handler: Optional[Callable[[dict], None]] = None,
                  on_disconnect: Optional[Callable[[], None]] = None) -> None:
         self._sock = sock
-        self._send_lock = threading.Lock()
+        # Re-entrant: a finalizer that sends may run INSIDE this thread's own
+        # send (see `_send`); `_sending` and `_deferred` are touched under
+        # the lock only.
+        self._send_lock = threading.RLock()
+        self._sending = False
+        self._deferred: "collections.deque[dict]" = collections.deque()
         self._push_handler = push_handler
         self._on_disconnect = on_disconnect
         self._pending: Dict[int, "_Waiter"] = {}
@@ -240,7 +246,7 @@ class Connection:
         waiter = _Waiter()
         with self._pending_lock:
             self._pending[rid] = waiter
-        send_msg(self._sock, msg, self._send_lock)
+        self._send(msg)
         reply = waiter.wait(timeout)
         if reply is None:
             with self._pending_lock:
@@ -257,7 +263,30 @@ class Connection:
         """One-way message (no reply expected)."""
         if _chaos_gate(msg.get("type", "?"), one_way=True):
             return      # chaos: message dropped on the floor
-        send_msg(self._sock, msg, self._send_lock)
+        self._send(msg)
+
+    def _send(self, msg: dict) -> None:
+        """One frame under the connection's send lock.  The collector may
+        run a finalizer that sends (an actor handle's or an object ref's
+        `__del__` -> `notify`) INSIDE this very thread's send, which holds
+        the lock: waiting for a plain lock there never ends (it hung
+        tests/test_dag_compiled.py for good, PR 34).  The lock is re-entrant
+        and such a frame, which must not split the one in flight, is queued
+        and goes out right after it: also when that one raised, and also
+        when it was queued at the last instant, because the queue is looked
+        at after `_sending` is cleared (from then on a finalizer sends for
+        itself)."""
+        with self._send_lock:
+            if self._sending:
+                self._deferred.append(msg)
+                return
+            self._sending = True
+            try:
+                send_msg(self._sock, msg)
+            finally:
+                self._sending = False
+                while self._deferred:
+                    self._send(self._deferred.popleft())
 
     def close(self) -> None:
         self._closed = True

@@ -189,12 +189,18 @@ def route(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array
             "td,de->te", m.astype(jnp.float32),
             p["w_router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
+        if cfg.moe_score_dtype != jnp.float32:
+            # a comparison's control (XLA drops a pair of casts)
+            info = jnp.finfo(cfg.moe_score_dtype)
+            s = jax.lax.reduce_precision(
+                s, exponent_bits=info.nexp, mantissa_bits=info.nmant)
         _, idx = jax.lax.top_k(s + p["route_bias"].astype(jnp.float32),
                                cfg.moe_top_k)
         picked = jnp.take_along_axis(s, idx, axis=1)
-        w = cfg.moe_route_scale * picked / jnp.sum(picked, axis=1,
-                                                   keepdims=True)
-        return idx.astype(jnp.int32), w
+        total = jnp.sum(picked, axis=1, keepdims=True)
+        if cfg.moe_route_eps:       # 0: not in the program at all
+            total = total + cfg.moe_route_eps
+        return idx.astype(jnp.int32), cfg.moe_route_scale * picked / total
 
 
 def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,

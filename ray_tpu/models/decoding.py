@@ -24,11 +24,13 @@ Everything reuses transformer.py's parameter layout (init_params),
 norms and RoPE, so any trained checkpoint serves unchanged, and the
 tests hold every step to greedy transformer.forward.
 
-Arch "afmoe" (models/afmoe.py: window and full layers mixed, expert
-layers) has its paged steps at the end of this file, built from that
-module's one layer definition; the public entry points
-(paged_prefill_decode_packed, paged_decode_steps, paged_decode_step)
-branch to them and return the expert layers' counts as one more value.
+Architectures of unrolled layers (`cfg.layer_kinds`: models/afmoe.py,
+window and full attention layers mixed; models/lfm2.py, short convolutions
+and attention layers mixed; both with expert layers) have their paged
+steps at the end of this file, built from their module's one layer
+definition; the public entry points (paged_prefill_decode_packed,
+paged_decode_steps, paged_decode_step) branch to them and return the
+expert layers' counts as one more value.
 """
 
 from __future__ import annotations
@@ -40,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (TransformerConfig, _norm, _rope,
-                                        _w_out)
+                                        _w_out, unrolled)
 
 
 def _qkv(p, h, cfg: TransformerConfig, positions):
@@ -99,11 +101,25 @@ class PagedDecodeCaches(NamedTuple):
 
     kp: jax.Array            # [L, NB, Hkv, bs, Dh] block pool — (bs, Dh)
     vp: jax.Array            # minor: the tile the paged kernel loads
-    # (arch "afmoe", whose layers are unrolled: a tuple of L pools
-    # [NB, Hkv, bs, Dh], each its own buffer, written in place)
+    # (unrolled layers: a tuple of L pools, each its own buffer, written in
+    # place: `unrolled_pool_shape`; None at a layer that has no keys)
     block_tables: jax.Array  # [B, W] int32 — physical block per logical
     lengths: jax.Array       # [B] int32 — tokens currently cached
     last_token: jax.Array    # [B] int32 — input to the next decode step
+    # Conv layers' state, where a model has any: per layer (None at the
+    # others) the conv's input `u` at the last K - 1 positions
+    #   tail_pool [NB, (K-1) * D] of every COMPLETED block, under the
+    #                             block's own id (one row of lanes a block:
+    #                             written and read as rows, never reshaped
+    #                             whole): what a prefill row, which
+    #                             starts on a block boundary, starts from
+    #                             (the block before it: an earlier row's, an
+    #                             earlier dispatch's, or a prefix hit's)
+    #   slot_tail [B, K - 1, D]   of every slot: what its next decode step
+    #                             starts from, set by the row that ends its
+    #                             prompt
+    tail_pool: Tuple = ()
+    slot_tail: Tuple = ()
 
 
 def paged_table_width(max_len: int, block_size: int) -> int:
@@ -111,26 +127,57 @@ def paged_table_width(max_len: int, block_size: int) -> int:
     return -(-max_len // block_size)
 
 
+def unrolled_pool_shape(cfg: TransformerConfig, num_blocks: int,
+                        block_size: int) -> Tuple[int, ...]:
+    """One unrolled attention layer's K (or V) pool, scratch block
+    included.  Heads narrower than the 128 lanes lie side by side in one
+    row of lanes where they fill it whole ([NB, Hkv / f, bs, f * Dh], f =
+    128 / Dh): the paged kernels copy pages out of an HBM pool only at
+    whole rows of lanes, and tell the layout from the shapes
+    (ops/paged_attention.py)."""
+    dh, hkv = cfg.head_dim, cfg.kv_heads
+    f = 128 // dh if dh < 128 and 128 % dh == 0 else 1
+    if hkv % f:
+        f = 1
+    return (num_blocks + 1, hkv // f, block_size, dh * f)
+
+
+def block_size_of(caches: PagedDecodeCaches) -> int:
+    if isinstance(caches.kp, tuple):
+        return next(p for p in caches.kp if p is not None).shape[2]
+    return caches.kp.shape[3]
+
+
 def init_paged_caches(cfg: TransformerConfig, num_slots: int,
                       num_blocks: int, block_size: int,
                       max_len: int) -> PagedDecodeCaches:
     """`num_blocks` USABLE blocks; one extra scratch block (id 0) is
-    added internally, so pool ids run 0..num_blocks inclusive."""
+    added internally, so pool ids run 0..num_blocks inclusive.  Unrolled
+    layers get the state their mixer has: keys and values, or a conv's
+    tails."""
     w = paged_table_width(max_len, block_size)
-    shape = (cfg.n_layers, num_blocks + 1, cfg.kv_heads, block_size,
-             cfg.head_dim)
-
-    def pools():
-        if cfg.arch == "afmoe":
-            return tuple(jnp.zeros(shape[1:], cfg.dtype)
-                         for _ in range(cfg.n_layers))
-        return jnp.zeros(shape, cfg.dtype)
-
+    state = {}
+    if cfg.layer_kinds is None:
+        shape = (cfg.n_layers, num_blocks + 1, cfg.kv_heads, block_size,
+                 cfg.head_dim)
+        state.update(kp=jnp.zeros(shape, cfg.dtype),
+                     vp=jnp.zeros(shape, cfg.dtype))
+    else:
+        conv = [m == "conv" for m, _ in cfg.layer_kinds]
+        shape = unrolled_pool_shape(cfg, num_blocks, block_size)
+        for name in ("kp", "vp"):
+            state[name] = tuple(None if c else jnp.zeros(shape, cfg.dtype)
+                                for c in conv)
+        if any(conv):
+            k1, d = cfg.conv_kernel - 1, cfg.d_model
+            for name, shape in (("tail_pool", (num_blocks + 1, k1 * d)),
+                                ("slot_tail", (num_slots, k1, d))):
+                state[name] = tuple(
+                    jnp.zeros(shape, cfg.dtype) if c else None for c in conv)
     return PagedDecodeCaches(
-        kp=pools(), vp=pools(),
         block_tables=jnp.zeros((num_slots, w), jnp.int32),
         lengths=jnp.zeros((num_slots,), jnp.int32),
-        last_token=jnp.zeros((num_slots,), jnp.int32))
+        last_token=jnp.zeros((num_slots,), jnp.int32), **state)
 
 
 # A request's paged prefix is streamed once per this many of its queries:
@@ -165,6 +212,16 @@ class PrefillRows(NamedTuple):
     blocks: jax.Array        # [N, P] pool block each position is written to
     offsets: jax.Array       # [N, P] and where in it (scratch 0: not live)
     groups: Optional[QueryGroups] = None    # rows that attend together
+    # For conv layers.  A row starts on a block boundary (the engine cuts
+    # prompts into whole blocks, and a hit is whole blocks), so what lies
+    # before it is the tail of the block before it.
+    tail_at: Optional[jax.Array] = None      # [m, K-1] a row's positions
+    #                          that are the tails of the m blocks it spans
+    tail_blocks: Optional[jax.Array] = None  # [N, m] the blocks a row
+    #                          completes (scratch 0: not completed)
+    before_block: Optional[jax.Array] = None  # [N] the block before the row
+    close_slots: Optional[jax.Array] = None  # [N] the slot whose prompt the
+    #                          row ends (num_slots, dropped: none)
 
 
 class DecodeRows(NamedTuple):
@@ -176,14 +233,18 @@ class DecodeRows(NamedTuple):
     active: jax.Array        # [B] bool    them; 0 for a slot that is not
     blocks: jax.Array        # [B] where the new position is written
     offsets: jax.Array       # [B]
+    tail_blocks: Optional[jax.Array] = None  # [B] `blocks` where the new
+    #                          position completes its block, else scratch 0
 
 
 def prefill_rows(tables, prefix_lens, suffix_lens, valid, P: int,
-                 block_size: int, slots=None,
-                 num_slots: int = 0) -> PrefillRows:
+                 block_size: int, slots=None, num_slots: int = 0,
+                 closes=None, conv_kernel: int = 0) -> PrefillRows:
     """`slots` [N] (which of `num_slots` requests a row belongs to) lets
     rows narrower than ATTENTION_ROW attend in groups; without it every
-    row attends alone."""
+    row attends alone.  `conv_kernel` > 1: what conv layers need of the
+    rows, and with `closes` [N] (the row ends its slot's prompt) which
+    slots' tails the rows set."""
     M = tables.shape[1] * block_size
     positions = prefix_lens[:, None] + jnp.arange(P, dtype=jnp.int32)
     live = valid[:, None] & (jnp.arange(P)[None, :] < suffix_lens[:, None])
@@ -194,9 +255,20 @@ def prefill_rows(tables, prefix_lens, suffix_lens, valid, P: int,
     if slots is not None and tables.shape[0] > 1 and ATTENTION_ROW // P > 1:
         groups = _query_groups(tables, prefix_lens, suffix_lens, valid,
                                slots, P, num_slots)
+    conv = {}
+    if conv_kernel > 1:
+        ends = jnp.arange(block_size - 1, P, block_size)     # [m]
+        conv = dict(
+            tail_at=ends[:, None] - jnp.arange(conv_kernel - 2, -1, -1),
+            tail_blocks=jnp.where(live[:, ends], blocks[:, ends], 0),
+            before_block=jnp.take_along_axis(
+                tables, jnp.maximum(prefix_lens // block_size - 1, 0)[:, None],
+                axis=1)[:, 0])
+        if closes is not None:
+            conv["close_slots"] = jnp.where(closes & valid, slots, num_slots)
     return PrefillRows(positions, tables, prefix_lens, suffix_lens, live,
                        jnp.where(live, blocks, 0), abs_pos % block_size,
-                       groups)
+                       groups, **conv)
 
 
 def _query_groups(tables, prefix_lens, suffix_lens, valid, slots,
@@ -249,9 +321,11 @@ def decode_rows(tables, lengths, active, block_size: int) -> DecodeRows:
     M = tables.shape[1] * block_size
     pos_c = jnp.minimum(lengths, M - 1)
     blocks = jnp.where(active, tables[jnp.arange(B), pos_c // block_size], 0)
+    offsets = pos_c % block_size
     return DecodeRows(lengths[:, None], tables,
                       jnp.where(active, jnp.minimum(lengths + 1, M), 0),
-                      active, blocks, pos_c % block_size)
+                      active, blocks, offsets,
+                      jnp.where(offsets == block_size - 1, blocks, 0))
 
 
 def _write_rows(pool, blocks, offsets, new):
@@ -349,9 +423,9 @@ def paged_decode_step(params: Dict[str, Any], caches: PagedDecodeCaches,
                       attn_impl: str = "auto"
                       ) -> Tuple[PagedDecodeCaches, jax.Array]:
     """One token for every slot; returns (caches', next_tokens [B]);
-    arch "afmoe" also its expert layers' counts (afmoe.MOE_COUNTS)."""
-    if cfg.arch == "afmoe":
-        caches, tok, _, counts = _afmoe_decode_core(
+    unrolled layers also their expert layers' counts (afmoe.MOE_COUNTS)."""
+    if cfg.layer_kinds is not None:
+        caches, tok, _, counts = _unrolled_decode_core(
             params, caches, active, cfg, attn_impl)
         return caches, tok, counts
     return _paged_decode_core(params, caches, active, cfg, attn_impl)
@@ -365,11 +439,11 @@ def paged_decode_steps(params: Dict[str, Any], caches: PagedDecodeCaches,
                        num_steps: int, attn_impl: str = "auto"
                        ) -> Tuple[PagedDecodeCaches, jax.Array]:
     """num_steps tokens per slot in ONE dispatch (lax.scan): returns
-    (caches', tokens [num_steps, B]); arch "afmoe" also its expert
+    (caches', tokens [num_steps, B]); unrolled layers also their expert
     layers' counts."""
-    if cfg.arch == "afmoe":
-        return _afmoe_decode_scan(params, caches, active, cfg, num_steps,
-                                  attn_impl)
+    if cfg.layer_kinds is not None:
+        return _unrolled_decode_scan(params, caches, active, cfg, num_steps,
+                                     attn_impl)
 
     def body(c, _):
         return _paged_decode_core(params, c, active, cfg, attn_impl)
@@ -404,19 +478,20 @@ def _paged_prefill_core(params: Dict[str, Any],
     length.  A row that is not `valid` writes to the scratch block only."""
     N, P = tokens.shape
     rows = prefill_rows(new_bt, prefix_lens, suffix_lens, valid, P,
-                        caches.kp[0].shape[-2], slots,
-                        caches.lengths.shape[0])
+                        block_size_of(caches), slots,
+                        caches.lengths.shape[0], closes,
+                        cfg.conv_kernel if caches.tail_pool else 0)
     last_ix = (jnp.arange(N), jnp.clip(suffix_lens - 1, 0, P - 1))
-    if cfg.arch == "afmoe":
-        from ray_tpu.models import afmoe
-        x = afmoe.embed(cfg, params["tok_embed"], tokens)
-        x, kp, vp, counts = _afmoe_layers(cfg, params, caches, x, rows,
-                                          paged_prefill_layer, attn_impl)
-        logits = afmoe.logits(cfg, params, x[last_ix])
+    if cfg.layer_kinds is not None:
+        model = unrolled(cfg)
+        x = model.embed(cfg, params["tok_embed"], tokens)
+        x, state, counts = _unrolled_layers(cfg, params, caches, x, rows,
+                                            paged_prefill_layer, attn_impl)
+        logits = model.logits(cfg, params, x[last_ix])
     else:
         x, kp, vp = _dense_prefill_layers(cfg, params, caches, tokens, rows,
                                           attn_impl)
-        counts = None
+        state, counts = dict(kp=kp, vp=vp), None
         last = _norm(x[last_ix], params["final_norm"],
                      params.get("final_norm_b"), cfg.norm_eps,
                      cfg.arch == "llama")                    # [N, D]
@@ -426,13 +501,12 @@ def _paged_prefill_core(params: Dict[str, Any],
     # A scatter whose in-range indices are distinct: at most one row of a
     # slot closes, and every other row is sent out of range and dropped.
     at = jnp.where(closes, slots, caches.lengths.shape[0])
-    return PagedDecodeCaches(
-        kp=kp, vp=vp,
+    return caches._replace(
         block_tables=caches.block_tables.at[at].set(new_bt, mode="drop"),
         lengths=caches.lengths.at[at].set(prefix_lens + suffix_lens,
                                           mode="drop"),
-        last_token=caches.last_token.at[at].set(first_tok, mode="drop")
-    ), first_tok, counts
+        last_token=caches.last_token.at[at].set(first_tok, mode="drop"),
+        **state), first_tok, counts
 
 
 def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
@@ -490,7 +564,7 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
     ATTENTION_ROW queries.  `valid` 0: no row.  1: the row ends its prompt:
     it yields the first token and its slot decodes from this dispatch on.
     2: more of the prompt is to come: its K/V are written and its slot
-    stays out of the decode steps.  Arch "afmoe" returns its expert
+    stays out of the decode steps.  Unrolled layers return their expert
     layers' counts as a fourth value.
     """
     P = prompt_pad
@@ -505,8 +579,8 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
         attn_impl)
     active = (packed[-1, :B] > 0).at[jnp.where(closes, slots, B)].set(
         True, mode="drop")
-    if cfg.arch == "afmoe":
-        caches, toks, more = _afmoe_decode_scan(
+    if cfg.layer_kinds is not None:
+        caches, toks, more = _unrolled_decode_scan(
             params, caches, active, cfg, num_steps, attn_impl)
         return caches, first, toks, counts + more
 
@@ -518,99 +592,137 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
 
 
 # ===========================================================================
-# arch "afmoe": the paged steps over models/afmoe.py's one layer definition
+# unrolled layers: the paged steps over a model module's one layer definition
 # ===========================================================================
-# The layers are unrolled (their kinds differ in shape) and each has a pool
-# of its own.  `paged_prefill_layer` and `paged_decode_layer` are what the
-# engine's dispatches are made of, one layer at a time: a caller that
-# cannot hold every layer's weights at once (the benchmark's comparison
-# with the plain reference at published widths) runs these very functions
-# layer by layer.
+# The layers are unrolled (their kinds differ in shape) and each has state
+# of its own, a pair of arrays: an attention layer's K and V pools, a conv
+# layer's block tails and slot tails (PagedDecodeCaches).
+# `paged_prefill_layer` and `paged_decode_layer` are what the engine's
+# dispatches are made of, one layer at a time: a caller that cannot hold
+# every layer's weights at once (the benchmark's comparison with the plain
+# reference at published widths) runs these very functions layer by layer.
 
 
 def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
                         rows: PrefillRows, attn_impl: str = "auto",
                         tap=None):
-    """One layer over a chunk x [N, P, D]: its K/V go into the pool, its
-    queries attend to the pool (prefix and chunk alike, under the layer's
-    window).  -> (x', k_pool', v_pool', afmoe.MOE_COUNTS)."""
-    from ray_tpu.models import afmoe
-    pools = []
+    """One layer over a chunk x [N, P, D].  An attention layer: its K/V go
+    into the pool, its queries attend to the pool (prefix and chunk alike,
+    under the layer's window).  A conv layer, whose `k_pool` is its block
+    tails and `v_pool` its slot tails: the tails of the blocks the rows
+    complete go into the pool FIRST, then every row starts from the tail of
+    the block before it (so a row that follows its request's row in this
+    call reads what that one just wrote, and never another request's), or
+    from zeros at position 0; a row that ends its prompt leaves its slot
+    the tail its decode starts from.
+    -> (x', k_pool', v_pool', afmoe.MOE_COUNTS)."""
+    model = unrolled(cfg)
+    state = []
 
     def attend(q, k, v):
         kp = _write_rows(k_pool, rows.blocks, rows.offsets, k)
         vp = _write_rows(v_pool, rows.blocks, rows.offsets, v)
-        pools.extend((kp, vp))
+        state.extend((kp, vp))
         return _attend_rows(q, kp, vp, rows, impl=attn_impl,
-                            window=afmoe.window_of(cfg, kind))
+                            window=model.window_of(cfg, kind))
 
-    x, counts = afmoe.layer(cfg, kind, p, x, rows.positions, attend,
+    def before(u):
+        N, _, D = u.shape
+        K1 = v_pool.shape[1]
+        tails = k_pool.at[rows.tail_blocks.reshape(-1)].set(
+            u[:, rows.tail_at].reshape(-1, K1 * D))
+        came = jnp.where((rows.prefix_lens > 0)[:, None, None],
+                         tails[rows.before_block].reshape(N, K1, D), 0)
+        slot = v_pool
+        if rows.close_slots is not None:
+            ext = jnp.concatenate([came, u], axis=1)
+            last = rows.suffix_lens[:, None] + jnp.arange(K1)    # [N, K-1]
+            slot = slot.at[rows.close_slots].set(
+                jnp.take_along_axis(ext, last[..., None], axis=1),
+                mode="drop")
+        state.extend((tails, slot))
+        return came
+
+    x, counts = model.layer(cfg, kind, p, x, rows.positions,
+                            before if kind[0] == "conv" else attend,
                             valid=rows.live, moe_name="moe_experts_prefill",
                             tap=tap)
-    return x, pools[0], pools[1], counts
+    return x, state[0], state[1], counts
 
 
 def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
                        rows: DecodeRows, attn_impl: str = "auto", tap=None):
-    """One layer over one new position per slot, x [B, 1, D]."""
-    from ray_tpu.models import afmoe
+    """One layer over one new position per slot, x [B, 1, D]; the layer's
+    state as `paged_prefill_layer` has it.  A conv layer starts from its
+    slot's tail, moves it on by the new position where the slot is active,
+    and leaves it in the pool where that position completes a block."""
     from ray_tpu.ops import paged_attention as _pa
-    pools = []
+    model = unrolled(cfg)
+    state = []
 
     def attend(q, k, v):
         kp = _write_rows(k_pool, rows.blocks, rows.offsets, k[:, 0])
         vp = _write_rows(v_pool, rows.blocks, rows.offsets, v[:, 0])
-        pools.extend((kp, vp))
+        state.extend((kp, vp))
         return _pa.paged_attention(
             q[:, 0], kp, vp, rows.tables, rows.context_lens,
-            impl=attn_impl, window=afmoe.window_of(cfg, kind))[:, None]
+            impl=attn_impl, window=model.window_of(cfg, kind))[:, None]
 
-    x, counts = afmoe.layer(cfg, kind, p, x, rows.positions, attend,
+    def before(u):
+        moved = jnp.concatenate([v_pool[:, 1:], u], axis=1)
+        state.extend((k_pool.at[rows.tail_blocks].set(
+            moved.reshape(moved.shape[0], -1)),
+            jnp.where(rows.active[:, None, None], moved, v_pool)))
+        return v_pool
+
+    x, counts = model.layer(cfg, kind, p, x, rows.positions,
+                            before if kind[0] == "conv" else attend,
                             valid=rows.active[:, None],
                             moe_name="moe_experts_decode", tap=tap)
-    return x, pools[0], pools[1], counts
+    return x, state[0], state[1], counts
 
 
-def _afmoe_layers(cfg, params, caches, x, rows, layer_fn, attn_impl):
-    from ray_tpu.models import afmoe
-    kps, vps, counts = [], [], afmoe.no_counts()
+def _unrolled_layers(cfg, params, caches, x, rows, layer_fn, attn_impl):
+    """-> (x', the caches' per-layer fields as the layers left them,
+    counts)."""
+    state = {f: list(getattr(caches, f))
+             for f in ("kp", "vp", "tail_pool", "slot_tail")}
+    counts = unrolled(cfg).no_counts()
     for i, (kind, p) in enumerate(zip(cfg.layer_kinds, params["layers"])):
-        x, kp, vp, c = layer_fn(cfg, kind, p, x, caches.kp[i], caches.vp[i],
-                                rows, attn_impl)
-        kps.append(kp)
-        vps.append(vp)
+        a, b = (("tail_pool", "slot_tail") if caches.kp[i] is None
+                else ("kp", "vp"))
+        x, state[a][i], state[b][i], c = layer_fn(
+            cfg, kind, p, x, state[a][i], state[b][i], rows, attn_impl)
         counts = counts + c
-    return x, tuple(kps), tuple(vps), counts
+    return x, {f: tuple(v) for f, v in state.items()}, counts
 
 
-def _afmoe_decode_core(params, caches: PagedDecodeCaches, active, cfg,
-                       attn_impl):
+def _unrolled_decode_core(params, caches: PagedDecodeCaches, active, cfg,
+                          attn_impl):
     """One decode step; -> (caches', next tokens [B], logits [B, V],
     counts).  Slots that are not active attend to nothing, write to the
     scratch block and are routed to no expert."""
-    from ray_tpu.models import afmoe
+    model = unrolled(cfg)
     rows = decode_rows(caches.block_tables, caches.lengths, active,
-                       caches.kp[0].shape[2])
-    x = afmoe.embed(cfg, params["tok_embed"], caches.last_token[:, None])
-    x, kps, vps, counts = _afmoe_layers(cfg, params, caches, x, rows,
+                       block_size_of(caches))
+    x = model.embed(cfg, params["tok_embed"], caches.last_token[:, None])
+    x, state, counts = _unrolled_layers(cfg, params, caches, x, rows,
                                         paged_decode_layer, attn_impl)
-    logits = afmoe.logits(cfg, params, x[:, 0])
+    logits = model.logits(cfg, params, x[:, 0])
     next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return PagedDecodeCaches(
-        kp=kps, vp=vps, block_tables=caches.block_tables,
+    return caches._replace(
         lengths=jnp.where(active, caches.lengths + 1, caches.lengths),
-        last_token=jnp.where(active, next_tok, caches.last_token)
-    ), next_tok, logits, counts
+        last_token=jnp.where(active, next_tok, caches.last_token),
+        **state), next_tok, logits, counts
 
 
-def _afmoe_decode_scan(params, caches, active, cfg, num_steps, attn_impl):
+def _unrolled_decode_scan(params, caches, active, cfg, num_steps, attn_impl):
     def body(carry, _):
         c, counts = carry
-        c, tok, _, more = _afmoe_decode_core(params, c, active, cfg,
-                                             attn_impl)
+        c, tok, _, more = _unrolled_decode_core(params, c, active, cfg,
+                                                attn_impl)
         return (c, counts + more), tok
 
-    from ray_tpu.models import afmoe
     (caches, counts), toks = jax.lax.scan(
-        body, (caches, afmoe.no_counts()), None, length=num_steps)
+        body, (caches, unrolled(cfg).no_counts()), None, length=num_steps)
     return caches, toks, counts

@@ -42,7 +42,8 @@ class TransformerConfig:
     n_kv_heads: Optional[int] = None      # None => MHA
     d_ff: Optional[int] = None            # None => arch default
     max_seq: int = 2048
-    arch: str = "llama"                   # "llama" | "gpt2" | "afmoe"
+    arch: str = "llama"                   # "llama" | "gpt2" | a module of
+    # unrolled layers under models/ ("afmoe", "lfm2": see `layer_kinds`)
     rope_theta: float = 500_000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16             # activation/compute dtype
@@ -77,6 +78,19 @@ class TransformerConfig:
     moe_d_ff: Optional[int] = None
     moe_shared_experts: int = 0
     moe_route_scale: float = 1.0
+    # added to the sum that normalises a token's chosen scores (0: the
+    # program `afmoe.route` always was)
+    moe_route_eps: float = 0.0
+    # the precision `afmoe.route` keeps its scores in.  Anything below
+    # float32 is the comparison's control (benchmarks/kinds/lfm2-moe.py: a
+    # program that scores in bfloat16 must come out not correct), never a
+    # deployment's; float32 leaves the program as it always was.
+    moe_score_dtype: Any = jnp.float32
+    # arch "lfm2" (models/lfm2.py; serving and `forward` only): mixer
+    # "conv" | "full"; a conv layer is a gated depthwise causal convolution
+    # over `conv_kernel` positions, whose state is its input at the last
+    # conv_kernel - 1 of them.
+    conv_kernel: int = 3
 
     def __post_init__(self):
         if self.layer_kinds is not None:
@@ -139,12 +153,22 @@ PRESETS: Dict[str, TransformerConfig] = {
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
+def unrolled(cfg: TransformerConfig):
+    """The module that defines `cfg`'s layers, where they are unrolled by
+    kind (`layer_kinds`) and not one scanned stack: models/<arch>.py,
+    imported when first asked for (a training job or a worker's start
+    imports none).  None for the scanned architectures."""
+    if cfg.layer_kinds is None:
+        return None
+    import importlib
+    return importlib.import_module(f"ray_tpu.models.{cfg.arch}")
+
+
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     """Returns the parameter pytree (per-layer params stacked on axis 0;
-    arch "afmoe": a tuple of per-layer trees, models/afmoe.py)."""
-    if cfg.arch == "afmoe":
-        from ray_tpu.models import afmoe
-        return afmoe.init_params(cfg, key)
+    unrolled layers: a tuple of per-layer trees, models/<arch>.py)."""
+    if cfg.layer_kinds is not None:
+        return unrolled(cfg).init_params(cfg, key)
     keys = jax.random.split(key, 8)
     d, h, hkv, dh, f = (cfg.d_model, cfg.n_heads, cfg.kv_heads,
                         cfg.head_dim, cfg.ff_dim)
@@ -208,9 +232,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
 
 def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
     """Pytree (matching init_params) of logical axis-name tuples."""
-    if cfg.arch == "afmoe":
-        from ray_tpu.models import afmoe
-        return afmoe.logical_axes(cfg)
+    if cfg.layer_kinds is not None:
+        return unrolled(cfg).logical_axes(cfg)
     layer = {
         "attn_norm": ("embed",),
         "wq": ("embed", "heads", "head_dim"),
@@ -445,9 +468,8 @@ def forward_hidden_aux(params: Dict[str, Any], tokens: jax.Array,
                        ) -> Tuple[jax.Array, jax.Array]:
     """tokens: [B, S] int32 -> (final-norm hidden [B, S, D],
     summed MoE aux loss — zero for dense models)."""
-    if cfg.arch == "afmoe":
-        from ray_tpu.models import afmoe
-        return (afmoe.forward_hidden(params, tokens, cfg),
+    if cfg.layer_kinds is not None:
+        return (unrolled(cfg).forward_hidden(params, tokens, cfg),
                 jnp.zeros((), jnp.float32))
     B, S = tokens.shape
     # Shard the indices BEFORE the lookup: a replicated-index gather from
@@ -572,12 +594,12 @@ def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Next-token cross-entropy (+ MoE load-balance aux when MoE).
     tokens: [B, S]; predicts tokens[:,1:]."""
-    if cfg.arch == "afmoe":
+    if cfg.layer_kinds is not None:
         # No quiet fall-back to _moe_block's capacity routing: that drops
-        # tokens, and this architecture's routing drops none.
+        # tokens, and these architectures' routing drops none.
         raise NotImplementedError(
-            "arch 'afmoe' has no training path: its expert layer has no "
-            "backward pass yet (serving and `forward` only)")
+            f"arch {cfg.arch!r} has no training path: its expert layer has "
+            f"no backward pass yet (serving and `forward` only)")
     targets = tokens[:, 1:]
     if cfg.xent_chunk is None:
         x, aux = forward_hidden_aux(params, tokens[:, :-1], cfg, mesh)

@@ -51,6 +51,19 @@ Pool block 0 is reserved as a scratch/null block by the engine (table
 padding and retired-slot writes are redirected there), so garbage reads
 through padded table entries are always masked by context_lens.
 
+Heads side by side.  A pool may hold f = 128 / D narrow heads in one row
+of lanes, [NB, Hkv / f, bs, f * D] (kv head h' * f + j in lanes j * D ..
+(j + 1) * D of row h': the [.., Hkv, D] a layer writes, seen as [.., Hkv /
+f, f * D]); every function here tells it from the shapes (the pool's
+minor dimension against q's).  The kernels then run as they are on heads
+of f * D: a query head's values are placed in its kv head's lanes with
+zeros in the others, so its scores are its own, and of the f * D values
+it gets back, its kv head's lanes are its output (`_side_by_side`).  The
+MXU contracts over 128 lanes whatever D is, so the zeros cost nothing,
+and the pages are copied out of HBM as for D = 128: the work follows what
+is cached, in decode and in prefill, where the narrow form below walks
+the table's whole width and a prefill had only the gather.
+
 A sliding-window layer passes `window`: only the last `window` positions
 are attended, and the kernel's page stream starts at the group that
 holds the first of them.  The second half of this file is
@@ -81,6 +94,33 @@ _GROUP_POSITIONS = 128
 _VMEM_BUDGET = 8 * 2 ** 20
 
 
+def _pool_heads(pages: jax.Array, D: int) -> jax.Array:
+    """pages [..., Hkv / f, bs, f * D] -> [..., Hkv, bs, D]."""
+    *lead, rows, bs, lanes = pages.shape
+    f = lanes // D
+    if f == 1:
+        return pages
+    return jnp.moveaxis(pages.reshape(*lead, rows, bs, f, D), -2, -3
+                        ).reshape(*lead, rows * f, bs, D)
+
+
+def _side_by_side(fwd, q: jax.Array, k_pool: jax.Array, *args) -> jax.Array:
+    """`fwd(q', k_pool, *args)` for query heads q [..., H, D] over a pool
+    whose rows hold f heads of D: q' [..., H, f * D] has each head's values
+    in the lanes of its kv head, and of the result each head keeps those."""
+    H, D = q.shape[-2:]
+    f = k_pool.shape[3] // D
+    if f == 1:
+        return fwd(q, k_pool, *args)
+    G = H // (k_pool.shape[1] * f)
+    lanes = jax.nn.one_hot((jnp.arange(H) // G) % f, f, dtype=q.dtype)
+    wide = (q[..., None, :] * lanes[:, :, None]).reshape(
+        *q.shape[:-1], f * D)
+    o = fwd(wide, k_pool, *args)
+    return jnp.sum(o.reshape(*q.shape[:-1], f, D) * lanes[:, :, None],
+                   axis=-2)
+
+
 # ---------------------------------------------------------------------------
 # Reference implementation (works everywhere; the numerics oracle)
 # ---------------------------------------------------------------------------
@@ -97,14 +137,14 @@ def paged_attention_reference(q: jax.Array, k_pool: jax.Array,
     decode step matches `transformer.forward` at that position.
     """
     B, H, D = q.shape
-    hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    hkv, bs = k_pool.shape[1] * k_pool.shape[3] // D, k_pool.shape[2]
     W = block_tables.shape[1]
     M = W * bs
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
     def rows(pool):         # [B, W, Hkv, bs, D] -> [B, Hkv, M, D]
-        return jnp.take(pool, block_tables, axis=0).transpose(
-            0, 2, 1, 3, 4).reshape(B, hkv, M, D)
+        return _pool_heads(jnp.take(pool, block_tables, axis=0), D
+                           ).transpose(0, 2, 1, 3, 4).reshape(B, hkv, M, D)
 
     k, v = rows(k_pool), rows(v_pool)
     groups = H // hkv
@@ -402,11 +442,12 @@ def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *, scale,
 
 def _validate_paged(q, k_pool, v_pool):
     H, D = q.shape[1], q.shape[2]
-    hkv, bs = k_pool.shape[1], k_pool.shape[2]
-    if k_pool.shape != v_pool.shape or k_pool.shape[3] != D:
+    bs = k_pool.shape[2]
+    if k_pool.shape != v_pool.shape or k_pool.shape[3] % D:
         raise ValueError(
-            f"paged attention: pools must be [NB, Hkv, bs, {D}], got "
-            f"k {k_pool.shape} v {v_pool.shape}")
+            f"paged attention: pools must be [NB, Hkv / f, bs, f * {D}], "
+            f"got k {k_pool.shape} v {v_pool.shape}")
+    hkv = k_pool.shape[1] * k_pool.shape[3] // D
     if H % hkv:
         raise ValueError(
             f"paged attention: H={H} must be a multiple of Hkv={hkv}")
@@ -428,8 +469,9 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, context_lens,
     programs — traced each time, the kernel was most of a warm start."""
     _validate_paged(q, k_pool, v_pool)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[2])
-    return compiled_on_tpu(
-        functools.partial(_paged_fwd, scale=scale, window=window),
+    return _side_by_side(
+        functools.partial(compiled_on_tpu, functools.partial(
+            _paged_fwd, scale=scale, window=window)),
         q, k_pool, v_pool, block_tables, context_lens)
 
 
@@ -490,13 +532,14 @@ def prefix_attention_reference(q, k_pool, v_pool, block_tables,
     """Gather the whole table window and mask by position (small sizes:
     the scores are [N, H, P, W * bs] float32)."""
     N, P, H, D = q.shape
-    hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    hkv, bs = k_pool.shape[1] * k_pool.shape[3] // D, k_pool.shape[2]
     M = block_tables.shape[1] * bs
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
     def rows(pool):         # [N, W, Hkv, bs, D] -> [N, Hkv, M, D]
-        return jnp.take(pool, block_tables, axis=0).transpose(
-            0, 2, 1, 3, 4).reshape(N, hkv, M, D).astype(jnp.float32)
+        return _pool_heads(jnp.take(pool, block_tables, axis=0), D
+                           ).transpose(0, 2, 1, 3, 4).reshape(
+            N, hkv, M, D).astype(jnp.float32)
 
     k, v = rows(k_pool), rows(v_pool)
     qg = q.reshape(N, P, hkv, H // hkv, D).astype(jnp.float32)
@@ -639,8 +682,9 @@ def prefix_attention_kernel(q, k_pool, v_pool, block_tables, prefix_lens,
                             window: Optional[int] = None) -> jax.Array:
     _validate_paged(q[:, 0], k_pool, v_pool)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[3])
-    return compiled_on_tpu(
-        functools.partial(_prefix_fwd, scale=scale, window=window),
+    return _side_by_side(
+        functools.partial(compiled_on_tpu, functools.partial(
+            _prefix_fwd, scale=scale, window=window)),
         q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens)
 
 
@@ -648,16 +692,17 @@ def prefix_attention(q, k_pool, v_pool, block_tables, prefix_lens,
                      suffix_lens, scale: Optional[float] = None,
                      impl: str = "auto",
                      window: Optional[int] = None) -> jax.Array:
-    """Dispatcher: "auto" is the kernel on a TPU backend where the head
-    size is whole 128-lane tiles (Mosaic slices a page out of an HBM pool
-    at no other), the gather elsewhere and for every other head size: the
-    prefill of arch "llama" / "gpt2" gathered every row's table at every
-    head size before it came here."""
+    """Dispatcher: "auto" is the kernel on a TPU backend where the pool's
+    rows are whole 128-lane tiles (one head, or narrow heads side by side:
+    Mosaic slices a page out of an HBM pool at no other), the gather
+    elsewhere and for every other pool: the prefill of arch "llama" /
+    "gpt2" gathered every row's table at every head size before it came
+    here."""
     args = (q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
             scale, window)
     if impl == "kernel" or (impl == "auto"
                             and jax.default_backend() == "tpu"
-                            and q.shape[3] % _LANES == 0):
+                            and k_pool.shape[3] % _LANES == 0):
         return prefix_attention_kernel(*args)
     if impl not in ("auto", "reference"):
         raise ValueError(f"unknown prefix attention impl {impl!r}")

@@ -599,6 +599,12 @@ class PagedBatcher:
         self._sliding_in_window = 0
         self.caches = decoding.init_paged_caches(
             cfg, num_slots, self.num_blocks, self.block_size, max_len)
+        # State that is not positions, where the caches hold any: layers
+        # whose state at a block boundary is a tail kept under the block's
+        # id (decoding.PagedDecodeCaches.tail_pool).  A prefix hit then also
+        # means "start from the tail of the last shared block", which the
+        # prefill's rows read through the table they have anyway: the
+        # engine does nothing more for such a layer, and counts nothing.
         # SLO windows for the serve autoscaler (slo_snapshot): engine
         # TTFT samples and inter-token latency derived from decode
         # entry processing cadence.  Guarded by _slo_lock (processor

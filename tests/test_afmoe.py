@@ -191,7 +191,7 @@ def _engine_logits(cfg, params, prompt, chunk, steps, block=8):
     active = jnp.asarray([True, False])
     logits, toks = [], [int(first[0])]
     for _ in range(steps):
-        caches, tok, lg, counts = decoding._afmoe_decode_core(
+        caches, tok, lg, counts = decoding._unrolled_decode_core(
             params, caches, active, cfg, "reference")
         logits.append(lg[0])
         toks.append(int(tok[0]))

@@ -718,13 +718,19 @@ def test_multiplex_unknown_model_fails_request_not_engine():
         bat.stop()
 
 
-def test_kv_metrics_recorded():
+def test_kv_metrics_recorded(monkeypatch):
     """Engine activity lands in the registered metric cells: the
     block-state gauges (the series state.memory_summary() folds into
     kv_blocks) sum to the pool size and the query/hit counters move.
-    Cells are read directly — no runtime client in this test, so
-    nothing has drained them."""
+    Cells are read directly, and only what THIS engine wrote: its own
+    gauge series (by its tag), and counter cells that nothing drains
+    meanwhile — a runtime client that an earlier test of this process left
+    connected would have the flusher thread push and zero them between
+    the two readings (the suite's one failure, PR 33's run), so for this
+    test there is none."""
     from ray_tpu.serve.llm import _get_kv_metrics
+    from ray_tpu.util import metrics as _metrics
+    monkeypatch.setattr(_metrics, "get_global_client", lambda: None)
     cfg = _tiny_cfg()
     params = _tiny_params()
     km = _get_kv_metrics()
@@ -752,7 +758,6 @@ def test_kv_metrics_recorded():
                for ts, cell in km["blocks"]._cells.items()
                if dict(ts).get("engine") == bat._engine_tag}
     assert stopped == {}
-    from ray_tpu.util import metrics as _metrics
     zeros = [s for s in _metrics._pending
              if s["name"] == _metrics.KV_BLOCKS_METRIC
              and s["tags"].get("engine") == bat._engine_tag]
@@ -763,6 +768,8 @@ def test_kv_metrics_recorded():
         - before_h
     assert d_h >= 1
     assert d_q >= d_h
+    own = bat.kv_stats()["prefix_cache"]
+    assert (own["queries"], own["hits"]) == (2, 1)
 
 
 def test_engine_failure_flushes_prefix_cache():

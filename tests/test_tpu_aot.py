@@ -166,18 +166,32 @@ def test_paged_serving_steps_compile_for_v5e(v5e_devices, model,
 
 # per layer of a fused program: a prefill's `prefix_attention` and a
 # decode step's `paged_attention`; Trinity's 4 expert layers add a grouped
-# product to each
-CELL_KERNELS = {"mistral-7b-l16": 2, "trinity-mini-l5": 5 + 4 + 5 + 4}
+# product to each; LFM2's 2 attention layers (heads of 64, two to a row of
+# lanes in the pool) and 8 expert layers likewise, its 7 conv layers none
+CELL_KERNELS = {"mistral-7b-l16": 2, "trinity-mini-l5": 5 + 4 + 5 + 4,
+                "lfm2-24b-a2b-l9": 2 + 8 + 2 + 8}
 
 
-@pytest.mark.parametrize("config_name", sorted(CELL_KERNELS))
+# LFM2's nine unrolled layers compile ~40 s a program here: one test a
+# program (its four rungs, then the decode-only chunk), each well inside
+# the suite's limit for a test
+CELL_PROGRAMS = [pytest.param("mistral-7b-l16", None, id="mistral-7b-l16"),
+                 pytest.param("trinity-mini-l5", None, id="trinity-mini-l5")
+                 ] + [pytest.param("lfm2-24b-a2b-l9", i,
+                                   id=f"lfm2-24b-a2b-l9-{i}")
+                      for i in range(5)]
+
+
+@pytest.mark.parametrize("config_name,part", CELL_PROGRAMS)
 def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
-                                             config_name):
+                                             config_name, part):
     """The serving cells as they run (benchmarks/configs/): every fused
     prefill + decode of the engine's ladder (rows of PREFILL_TILE tokens:
     Mistral's 32 / 8 heads of 128 under 48-column tables, Trinity's 32 / 4
     under 1,072 columns, with its window in the paged kernel and the
-    grouped expert product) and the decode-only chunk, as Mosaic kernels,
+    grouped expert product, LFM2's 32 / 8 heads of 64 side by side under
+    1,072 columns beside its conv layers' tails) and the decode-only chunk,
+    as Mosaic kernels,
     inside one chip's memory beside the weights.  (`impl="auto"` asks
     jax.default_backend(): steered here, in the test, as it would read on
     the chip.)"""
@@ -198,7 +212,8 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
         spec.model_kind(config["kind"]).transformer_kwargs(
             config, max_seq=sv["max_len"], param_dtype=sv["param_dtype"])))
     W = decoding.paged_table_width(sv["max_len"], sv["kv_block_size"])
-    assert W == {"mistral-7b-l16": 48, "trinity-mini-l5": 1072}[config_name]
+    assert W == {"mistral-7b-l16": 48, "trinity-mini-l5": 1072,
+                 "lfm2-24b-a2b-l9": 1072}[config_name]
     params = shapes(jax.eval_shape(
         lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
     caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
@@ -216,7 +231,7 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     widths = [0] + [tile * n for n in ladder]
     assert all(b - a <= 384 for a, b in zip(widths, widths[1:])
                if b <= 896)
-    for N in ladder:
+    for N in ladder if part is None else ladder[part:part + 1]:
         packed = jax.ShapeDtypeStruct(
             (N + 1, max(tile + 4 + W, sv["num_slots"])), jnp.int32,
             sharding=on_chip)
@@ -227,6 +242,8 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
         mem = fused.memory_analysis()
         assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 < 15.75 * 2 ** 30)
+    if part is not None and part < len(ladder):
+        return
     N = sv["num_slots"]
     active = jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=on_chip)
     assert _custom_calls(decoding.paged_decode_steps.lower(

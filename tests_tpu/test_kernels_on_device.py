@@ -338,3 +338,45 @@ def test_grouped_ffn_at_afmoe_widths_on_tpu(rows):
         (float(err.max()), float(err.mean()), float(np.abs(want).mean()))
     assert int(jnp.sum(sizes)) == int(jnp.sum(valid)) * K
     assert float(jnp.max(jnp.abs(y[~valid].astype(jnp.float32)))) == 0.0
+
+
+# -- heads of 64, two kv heads side by side in a row of 128 lanes -------------
+# At LFM2's widths: 32 query / 8 KV heads of 64, blocks of 16, tables of
+# 1,072 columns; the pool as models/decoding.py unrolled_pool_shape lays it
+# out, [NB, 4, 16, 128].
+def _side_by_side(pool):
+    nb, hkv, bs, d = pool.shape
+    return pool.reshape(nb, hkv // 2, 2, bs, d).transpose(
+        0, 1, 3, 2, 4).reshape(nb, hkv // 2, bs, 2 * d)
+
+
+def test_paged_kernel_over_heads_side_by_side_on_tpu():
+    ctx = jnp.asarray([100, 2048, 16500, 0, 5000, 17151, 1, 33], jnp.int32)
+    kp, vp, tables = _afmoe_pool(8, 1072, seed=7, hkv=8, d=64)
+    q = jax.random.normal(jax.random.PRNGKey(9), (8, 32, 64), jnp.bfloat16)
+    got = paged_attention(q, _side_by_side(kp), _side_by_side(vp), tables,
+                          ctx, impl="kernel")
+    with jax.default_matmul_precision("highest"):
+        want = paged_attention_reference(q, kp, vp, tables, ctx)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+def test_prefix_attention_over_heads_side_by_side_on_tpu():
+    """Attention rows of up to 64 queries as the engine groups them: turns
+    of 17-64 tokens behind prefixes of 0 to 16 k positions, and padding."""
+    from ray_tpu.ops.paged_attention import prefix_attention
+    N, P, H, W = 16, 64, 32, 1072
+    kp, vp, tables = _afmoe_pool(N, W, seed=8, hkv=8, d=64)
+    q = jax.random.normal(jax.random.PRNGKey(2), (N, P, H, 64),
+                          jnp.bfloat16)
+    prefix = [0, 448, 8192, 16384, 12288, 16, W * 16 - 64]
+    suffix = [64, 39, 17, 64, 33, 1, 64]
+    pad = N - len(prefix)
+    packed = (_side_by_side(kp), _side_by_side(vp))
+    _check_prefix_attention(
+        kp, vp, tables, q, jnp.asarray(prefix + [0] * pad, jnp.int32),
+        jnp.asarray(suffix + [0] * pad, jnp.int32), None,
+        attend=lambda q, kp, vp, *a, **kw: prefix_attention(
+            q, *packed, *a, **kw))
