@@ -254,17 +254,26 @@ def _ensure_pallas():
         pltpu = _pltpu
 
 
-def compiled_on_tpu(fn, *args):
+def compiled_on_tpu(fn, *args, gather=None):
     """`fn(*args, interpret=...)`: the compiled Mosaic kernel where the
     program is lowered for a TPU, the Pallas interpreter on any other
     platform.  Chosen per lowering rather than from the process's
     default backend, so an ahead-of-time compile for a TPU topology
     from a CPU host builds the real kernel, and a program lowered for
-    the CPU never asks Mosaic for one."""
+    the CPU never asks Mosaic for one.
+
+    `platform_dependent` traces every branch, lowered or not, and a
+    kernel's body is the costliest thing a serving program traces (~0.2-
+    0.4 s a shape on a chip's host, forty of them in the agents cell's
+    warm start, PERF.md PR 41).  A caller with a plain-JAX form of the
+    same result passes it as `gather`: in a process whose own backend is
+    a TPU it is the other platforms' branch, so the body is traced once
+    and not a second time for an interpreter that host never runs."""
+    other = functools.partial(fn, interpret=True)
+    if gather is not None and jax.default_backend() == "tpu":
+        other = gather
     return jax.lax.platform_dependent(
-        *args,
-        tpu=functools.partial(fn, interpret=False),
-        default=functools.partial(fn, interpret=True))
+        *args, tpu=functools.partial(fn, interpret=False), default=other)
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, group):

@@ -19,16 +19,22 @@ Two implementations behind one dispatcher:
 * `paged_attention_kernel` — a Pallas TPU kernel whose work follows the
   positions that are cached, not the table's width.  The grid is the
   batch: ONE program per sequence, all kv heads inside it.  The pools
-  stay in HBM; the program copies whole physical pages (all heads of
-  one page are contiguous: Hkv * bs * D elements), addressed through
-  the scalar-prefetched block table, into double-buffered VMEM, a
-  group of pages (128 positions) at a time, and runs an online softmax
-  over ceil(context / group) groups — the next group's pages, or the
-  next sequence's first ones, are in flight while a group is computed.
-  A page beyond the context starts no DMA and a sequence of length 0
-  does nothing, so 32 slots with 48-page tables are 32 programs and
-  as many 32 KB copies as there are live pages (the (B, Hkv, W) grid
-  this replaced ran 12,288 one-tile programs whatever was cached).
+  stay in HBM; the (sequence, group of 8 pages = 128 positions) pairs of
+  the whole batch form ONE stream of whole-page copies, addressed
+  through the scalar-prefetched block table, into a ring of VMEM slots
+  (16 groups for Trinity-Mini's and LFM2's pools, 8 for Mistral's:
+  `_ring_shape`), so a sequence finds its first groups landed when its
+  program starts.  A descriptor costs the core the same ~25 ns whether
+  it moves one page or eight, and the core issues nothing while it
+  computes: a full group whose pages lie in a row in the pool (a
+  prompt's blocks come from one allocation) is one descriptor a pool.
+  The online softmax runs over ceil(context / 128) groups, a quarter
+  of the ring (512 positions) an update where the context has them, a
+  group at a time for what is left.  A page beyond the context starts
+  no DMA and a sequence of length 0 does nothing, so 32 slots with
+  48-page tables are 32 programs and as many page copies as there are
+  live pages (the (B, Hkv, W) grid this replaced ran 12,288 one-tile
+  programs whatever was cached).
   Mosaic cannot slice a page out of an HBM pool whose head size is
   not a multiple of 128 lanes; for those (D 64, 72, 192) the same
   group step runs on a (B, groups) grid whose pages arrive as whole-
@@ -80,6 +86,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ray_tpu.ops.attention import NEG_INF, compiled_on_tpu
 
@@ -88,10 +95,12 @@ from ray_tpu.ops.attention import NEG_INF, compiled_on_tpu
 # MHA (G = 1) and narrow GQA alike.
 _SUBLANES = 8
 _LANES = 128
-# Positions one DMA group spans (one lane-wide score tile per kv head),
-# and what its four buffers (k, v, two each) may take of VMEM.
+# Positions one DMA group spans (one lane-wide score tile per kv head), the
+# most groups the ring of the decode kernel holds, and what the ring's k and
+# v buffers together may take of VMEM.
 _GROUP_POSITIONS = 128
-_VMEM_BUDGET = 8 * 2 ** 20
+_RING_GROUPS = 16
+_VMEM_BUDGET = 4 * 2 ** 20
 
 
 def _pool_heads(pages: jax.Array, D: int) -> jax.Array:
@@ -168,14 +177,23 @@ def paged_attention_reference(q: jax.Array, k_pool: jax.Array,
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _pages_per_group(W, hkv, bs, D, itemsize):
-    """Pages one group holds, from static shapes: enough pages for one
-    lane-wide (G, 128) score tile per kv head, no more than the table
-    has, and two k and two v buffers inside the VMEM budget."""
+def _ring_shape(W, hkv, bs, D, itemsize):
+    """(pages, depth, tiles) of the decode kernel's page stream, from static
+    shapes.  `pages` a group: enough for one lane-wide (G, 128) score tile
+    per kv head, no more than the table has, and two groups (k and v)
+    inside the VMEM budget.  `depth` groups in the ring: the largest power
+    of two (the kernel wraps a slot index with a mask), up to
+    `_RING_GROUPS`, that the budget holds.  `tiles` groups
+    one softmax update spans where a context has them: a quarter of the
+    ring, so that three quarters stay in flight under the arithmetic."""
+    page = 2 * hkv * bs * D * itemsize          # its k and its v
     pages = max(1, min(_GROUP_POSITIONS // bs, W))
-    while pages > 1 and 4 * pages * hkv * bs * D * itemsize > _VMEM_BUDGET:
+    while pages > 1 and 2 * pages * page > _VMEM_BUDGET:
         pages //= 2
-    return pages
+    depth = 2
+    while depth < _RING_GROUPS and 2 * depth * pages * page <= _VMEM_BUDGET:
+        depth *= 2
+    return pages, depth, max(1, depth // 4)
 
 
 def _attend_group(q, k, v, first_pos, ctx, scale, m_ref, l_ref, acc_ref,
@@ -188,11 +206,17 @@ def _attend_group(q, k, v, first_pos, ctx, scale, m_ref, l_ref, acc_ref,
     Softmax, accumulation and p . v are f32, like the reference; q and
     k meet on the MXU in their own dtype, which gives the same
     products as f32 copies of them would."""
-    s = jnp.einsum("hgd,htd->hgt", q, k.astype(q.dtype),
-                   preferred_element_type=jnp.float32) * scale
+    s = _scores(q, k) * scale
     kpos = first_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     seen = kpos < ctx if lo is None else (kpos < ctx) & (kpos >= lo)
     _softmax_update(s, seen, v, m_ref, l_ref, acc_ref)
+
+
+def _scores(q, k):
+    """q (Hkv, R, D) . k (Hkv, T, D) -> (Hkv, R, T) f32, on the MXU in q's
+    dtype."""
+    return lax.dot_general(q, k.astype(q.dtype), (((2,), (2,)), ((0,), (0,))),
+                           preferred_element_type=jnp.float32)
 
 
 def _softmax_update(s, seen, v, m_ref, l_ref, acc_ref, rows_differ=False):
@@ -211,9 +235,9 @@ def _softmax_update(s, seen, v, m_ref, l_ref, acc_ref, rows_differ=False):
         p = jnp.where(seen, p, 0.0)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
     m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-        "hgt,htd->hgd", p, v.astype(jnp.float32),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+        p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)         # hgt,htd->hgd
 
 
 def _reset(m_ref, l_ref, acc_ref):
@@ -228,106 +252,200 @@ def _write_out(o_ref, l_ref, acc_ref):
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _copy_pages(bt_ref, row, first, live, block_size, pools, bufs, sem,
-                slot, wait):
+def _cdiv(a, b: int):
+    """ceil(a / b) of a count a >= 0.  Scalar arithmetic inside the
+    kernels is `lax` primitives, not operators: an operator on a traced
+    value goes through a jitted `jnp` wrapper, four times the price, and
+    a kernel's body is traced in Python once for every shape it meets,
+    in every process (a prefill's kernel for every rung: with operators
+    the bodies' tracing was +2.3 s of a Mistral cell's warm start and
+    +11 s of the agents cell's, PERF.md PR 41)."""
+    return lax.div(lax.add(a, b - 1), b)
+
+
+def _copy_group(bt_ref, row, first, live, pools, bufs, sem, slot, wait):
     """Start (or wait for) the copies of `live` pages of `row`'s table,
-    from page `first` on, out of the HBM pools into buffer `slot`: a loop
-    over what is live, not over a group's width, in the kernel and in its
-    trace."""
+    from page `first` on, out of the HBM pools into slot `slot` of the
+    page-major buffers [slots, pages, Hkv, bs, D]: a page lands whole, as
+    it lies in the pool.  Issuing a descriptor holds the core for ~25 ns
+    whatever it moves (PERF.md, PR 41), so a full group whose pages lie in
+    a row in the pool (a prompt's blocks come from one allocation) is ONE
+    descriptor a pool, and a full group is waited for with one wait a pool
+    (a DMA semaphore counts bytes); any other group is a loop over what is
+    live, not over a group's width."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def page(j, carry):
-        phys = bt_ref[row, first + j]
-        dst = pl.ds(pl.multiple_of(j * block_size, block_size), block_size)
-        for i, (pool, buf) in enumerate(zip(pools, bufs)):
-            copy = pltpu.make_async_copy(
-                pool.at[phys], buf.at[slot, :, dst, :], sem.at[i, slot])
-            if wait:
-                copy.wait()
-            else:
-                copy.start()
-        return carry
+    pages = bufs[0].shape[1]
+    whole = lax.eq(live, pages)
+    if not wait:
+        # (a group that is not full may reach past the table: its columns
+        # are read where they are in range, and `whole` is false already)
+        column = lax.min(first, bt_ref.shape[1] - pages)
+        phys0 = bt_ref[row, column]
+        for j in range(1, pages):
+            whole = lax.bitwise_and(whole, lax.eq(
+                bt_ref[row, lax.add(column, j)], lax.add(phys0, j)))
 
-    jax.lax.fori_loop(0, live, page, None)
+    @pl.when(whole)
+    def _():
+        if wait:
+            _wait_group(bufs, sem, slot)
+        else:
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                pltpu.make_async_copy(pool.at[pl.ds(phys0, pages)],
+                                      buf.at[slot], sem.at[i, slot]).start()
+
+    @pl.when(lax.bitwise_not(whole))
+    def _():
+        def page(j, carry):
+            phys = bt_ref[row, lax.add(first, j)]
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                copy = pltpu.make_async_copy(pool.at[phys], buf.at[slot, j],
+                                             sem.at[i, slot])
+                if wait:
+                    copy.wait()
+                else:
+                    copy.start()
+            return carry
+
+        lax.fori_loop(0, live, page, None)
+
+
+def _wait_group(bufs, sem, slot):
+    """Wait for a full group in slot `slot`, however it was started: one
+    wait a pool (it reads only the destination's size)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    for i, buf in enumerate(bufs):
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sem.at[i, slot]).wait()
+
+
+def _group_heads(buf, slot):
+    """Slot `slot` of a page-major buffer as (Hkv, pages * bs, D): a
+    head's (bs, D) slab of a page is whole tiles, so this moves nothing."""
+    _, pages, hkv, bs, D = buf.shape
+    return lax.reshape(lax.transpose(buf[slot], (1, 0, 2, 3)),
+                       (hkv, pages * bs, D))
 
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sem, cursor, m_ref, l_ref, acc_ref, *,
-                  scale, block_size, pages, window):
-    """One sequence: every kv head, a group of `pages` pages at a time.
+                  k_buf, v_buf, sem, ring, m_ref, l_ref, acc_ref, *,
+                  scale, block_size, pages, tiles, window):
+    """One sequence: every kv head, groups of `pages` pages.
 
-    The grid is the batch, run in order, and the (sequence, group)
-    pairs form ONE stream through two VMEM buffers: while a group is
-    computed the next one's pages are in flight — the same sequence's
-    next group or, at its last group, the first group of the next
-    sequence (`cursor` carries the buffer parity across programs).  A
-    page with no live position starts no DMA and a sequence of length 0
-    runs no group at all, so DMAs and loop trips follow what is
-    cached, not the table's width.  With a `window` (a sliding layer) the
-    stream of a sequence starts at the group that holds its first
-    attended position, context - window, and that group is masked."""
+    The grid is the batch, run in order, and the (sequence, group) pairs
+    form ONE stream through a ring of VMEM slots (`ring`, in SMEM, holds
+    the stream's head: the next pair to start, the slot it goes to, the
+    slot the next group is read from, and how many slots are free).
+    Every step first gives the free slots to the stream's next groups,
+    this sequence's or a later one's (the first step of the call fills
+    the whole ring), so a sequence finds its first groups in flight or
+    landed, and an empty sequence neither starts nor waits for anything.
+    A page with no live position starts no DMA, so DMAs and trips follow
+    what is cached, not the table's width.
+
+    A trip is a serial chain (scores -> max -> exp -> sum -> p . v) that
+    nothing overlaps across trips, so where a context has them one
+    softmax update spans `tiles` resident groups (one max, one rescale of
+    the accumulator); what is left over goes a group at a time, and a
+    short context pays for no position it does not hold.  With a `window`
+    (a sliding layer) the stream of a sequence starts at the group that
+    holds its first attended position, context - window, and that group
+    is masked."""
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
-    last_b = pl.num_programs(0) - 1
+    rows = pl.num_programs(0)
+    depth = k_buf.shape[0]
     span = pages * block_size                 # positions per group
     ctx = len_ref[b]
-    n_groups = pl.cdiv(ctx, span)
 
     def first_group(row):
         if window is None:
-            return 0
-        return jnp.maximum(len_ref[row] - window, 0) // span
+            return jnp.int32(0)
+        return lax.div(lax.max(lax.sub(len_ref[row], window), 0), span)
 
-    g0 = first_group(b)
-    trips = n_groups - g0
-    lo = None if window is None else ctx - window
+    def end_group(row):
+        return _cdiv(len_ref[row], span)
 
     def copy_group(row, group, slot, wait=False):
-        first = group * pages
-        live = jnp.clip(pl.cdiv(len_ref[row], block_size) - first, 0, pages)
-        _copy_pages(bt_ref, row, first, live, block_size, (k_hbm, v_hbm),
+        first = lax.mul(group, pages)
+        live = lax.clamp(0, lax.sub(_cdiv(len_ref[row], block_size), first),
+                         pages)
+        _copy_group(bt_ref, row, first, live, (k_hbm, v_hbm),
                     (k_buf, v_buf), sem, slot, wait)
 
-    first_slot = jnp.where(b == 0, 0, cursor[0])
-    next_row = jnp.minimum(b + 1, last_b)
+    def live_row(row):
+        """The first row from `row` on that has anything cached."""
+        return lax.while_loop(
+            lambda r: lax.bitwise_and(
+                lax.lt(r, rows),
+                lax.eq(len_ref[lax.min(r, lax.sub(rows, 1))], 0)),
+            lambda r: lax.add(r, 1), row)
 
-    @pl.when(b == 0)
+    def start_next(*_):
+        row, group, slot = ring[0], ring[1], ring[2]
+
+        @pl.when(lax.lt(row, rows))
+        def _():
+            copy_group(row, group, slot)
+            after_group = lax.add(group, 1)
+            more = lax.lt(after_group, end_group(row))
+            after = live_row(lax.select(more, row, lax.add(row, 1)))
+            ring[0] = after
+            ring[1] = lax.select(
+                more, after_group,
+                first_group(lax.min(after, lax.sub(rows, 1))))
+            ring[2] = lax.bitwise_and(lax.add(slot, 1), depth - 1)
+
+    @pl.when(lax.eq(b, 0))
     def _first():
         # Page slots a partly live group leaves unfilled are read under
         # the mask with p == 0: they must hold numbers (0 * NaN), which
         # fresh VMEM need not.  Later they hold older pool pages.
         v_buf[...] = jnp.zeros_like(v_buf)
-
-    # Every sequence finds its first group in flight: its predecessor
-    # started it — at its own last group or, having nothing cached,
-    # here — and the first sequence starts its own.
-    @pl.when(jnp.where(n_groups == 0, b < last_b, b == 0))
-    def _():
-        row = jnp.where(n_groups == 0, next_row, b)
-        copy_group(row, first_group(row), first_slot)
+        row = live_row(jnp.int32(0))
+        ring[0] = row
+        ring[1] = first_group(lax.min(row, lax.sub(rows, 1)))
+        ring[2] = 0
+        ring[3] = 0
+        ring[4] = depth
 
     _reset(m_ref, l_ref, acc_ref)
+    lo = None if window is None else lax.sub(ctx, window)
 
-    def group_step(i, carry):
-        g = g0 + i
-        slot = (first_slot + i) % 2
-        more = i + 1 < trips
+    def step(n):
+        def attend(g):
+            first = ring[3]
+            slots = [lax.bitwise_and(lax.add(first, t), depth - 1)
+                     for t in range(n)]
+            for slot in slots[:-1]:     # all but a sequence's last are full
+                _wait_group((k_buf, v_buf), sem, slot)
+            copy_group(b, lax.add(g, n - 1), slots[-1], wait=True)
+            k, v = (jnp.concatenate([_group_heads(buf, slot)
+                                     for slot in slots], axis=1)
+                    for buf in (k_buf, v_buf))
+            _attend_group(q_ref[0], k, v, lax.mul(g, span), ctx, scale,
+                          m_ref, l_ref, acc_ref, lo)
+            ring[3] = lax.bitwise_and(lax.add(first, n), depth - 1)
+            ring[4] = n
+            return lax.add(g, n)
+        return attend
 
-        @pl.when(jnp.logical_or(more, b < last_b))
-        def _():
-            copy_group(jnp.where(more, b, next_row),
-                       jnp.where(more, g + 1, first_group(next_row)),
-                       1 - slot)
+    g0 = first_group(b)
+    trips = lax.sub(end_group(b), g0)
+    wide = lax.div(trips, tiles)        # steps of `tiles` groups, then of 1
 
-        copy_group(b, g, slot, wait=True)
-        _attend_group(q_ref[0], k_buf[slot], v_buf[slot], g * span, ctx,
-                      scale, m_ref, l_ref, acc_ref, lo)
-        return carry
+    def trip(i, g):
+        lax.fori_loop(0, ring[4], start_next, None)
+        if tiles == 1:
+            return step(1)(g)
+        return lax.cond(lax.lt(i, wide), step(tiles), step(1), g)
 
-    jax.lax.fori_loop(0, trips, group_step, None)
-    cursor[0] = (first_slot + trips) % 2
+    lax.fori_loop(0, lax.sub(lax.add(wide, trips), lax.mul(wide, tiles)),
+                  trip, g0)
     _write_out(o_ref, l_ref, acc_ref)
 
 
@@ -380,7 +498,7 @@ def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *, scale,
     W = block_tables.shape[1]
     groups = H // hkv
     gp = -(-groups // _SUBLANES) * _SUBLANES
-    pages = _pages_per_group(W, hkv, bs, D, k_pool.dtype.itemsize)
+    pages, depth, tiles = _ring_shape(W, hkv, bs, D, k_pool.dtype.itemsize)
     qg = q.reshape(B, hkv, groups, D).astype(
         jnp.promote_types(q.dtype, k_pool.dtype))
     if gp != groups:
@@ -395,13 +513,14 @@ def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *, scale,
     if D % _LANES == 0:
         # The pools stay in HBM: the body copies whole pages, addressed
         # through the scalar-prefetched block table.
-        kernel, grid = _paged_kernel, (B,)
+        kernel = functools.partial(_paged_kernel, tiles=tiles)
+        grid = (B,)
         kv_specs = [pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)] * 2
         kv_args = [k_pool, v_pool]
-        scratch = [pltpu.VMEM((2, hkv, pages * bs, D), k_pool.dtype),
-                   pltpu.VMEM((2, hkv, pages * bs, D), v_pool.dtype),
-                   pltpu.SemaphoreType.DMA((2, 2)),
-                   pltpu.SMEM((1,), jnp.int32)] + softmax_state
+        scratch = [pltpu.VMEM((depth, pages, hkv, bs, D), k_pool.dtype),
+                   pltpu.VMEM((depth, pages, hkv, bs, D), v_pool.dtype),
+                   pltpu.SemaphoreType.DMA((2, depth)),
+                   pltpu.SMEM((5,), jnp.int32)] + softmax_state
     else:
         def page_index(j):
             def index(b, g, bt_ref, len_ref):
@@ -462,16 +581,18 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, context_lens,
                            scale: Optional[float] = None,
                            window: Optional[int] = None) -> jax.Array:
     """Pallas paged attention: the compiled kernel where the program is
-    lowered for a TPU, the Pallas interpreter elsewhere (CPU parity
-    tests).  Jitted so that a process traces the kernel once per shape:
-    a decode program reaches it through a layer scan inside a step scan
-    and once per platform branch, and an engine warms up six such
-    programs — traced each time, the kernel was most of a warm start."""
+    lowered for a TPU, the Pallas interpreter elsewhere off a TPU host (CPU
+    parity tests; `compiled_on_tpu`).  Jitted so that a process traces
+    the kernel once per shape: a decode program reaches it through a layer
+    scan inside a step scan, and an engine warms up six such programs —
+    traced each time, the kernel was most of a warm start."""
     _validate_paged(q, k_pool, v_pool)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[2])
+    kw = dict(scale=scale, window=window)
     return _side_by_side(
-        functools.partial(compiled_on_tpu, functools.partial(
-            _paged_fwd, scale=scale, window=window)),
+        functools.partial(
+            compiled_on_tpu, functools.partial(_paged_fwd, **kw),
+            gather=functools.partial(paged_attention_reference, **kw)),
         q, k_pool, v_pool, block_tables, context_lens)
 
 
@@ -570,53 +691,54 @@ def _prefix_kernel(bt_ref, pre_ref, suf_ref, qoff_ref, q_ref, k_hbm, v_hbm,
     n, t = pl.program_id(0), pl.program_id(1)
     span = pages * block_size
     pre = pre_ref[n]
-    live_q = jnp.clip(suf_ref[n] - t * tq, 0, tq)
-    first_q = pre + t * tq                    # position of the first query
-    hi = jnp.where(live_q > 0, first_q + live_q, 0)   # keys below hi
-    lo = 0 if window is None else jnp.maximum(first_q - (window - 1), 0)
-    g0 = lo // span
-    trips = jnp.maximum(pl.cdiv(hi, span) - g0, 0)
+    first_q = lax.add(pre, lax.mul(t, tq))    # position of the first query
+    live_q = lax.clamp(0, lax.sub(suf_ref[n], lax.mul(t, tq)), tq)
+    hi = lax.select(lax.gt(live_q, 0), lax.add(first_q, live_q),
+                    jnp.int32(0))                 # keys below hi
+    lo = 0 if window is None else lax.max(lax.sub(first_q, window - 1), 0)
+    g0 = 0 if window is None else lax.div(lo, span)
+    trips = lax.max(lax.sub(_cdiv(hi, span), g0), 0)
 
     def copy_group(group, slot, wait=False):
-        first = group * pages
-        live = jnp.clip(pl.cdiv(hi, block_size) - first, 0, pages)
-        _copy_pages(bt_ref, n, first, live, block_size, (k_hbm, v_hbm),
+        first = lax.mul(group, pages)
+        live = lax.clamp(0, lax.sub(_cdiv(hi, block_size), first), pages)
+        _copy_group(bt_ref, n, first, live, (k_hbm, v_hbm),
                     (k_buf, v_buf), sem, slot, wait)
 
-    @pl.when(jnp.logical_and(n == 0, t == 0))
+    @pl.when(lax.bitwise_and(lax.eq(n, 0), lax.eq(t, 0)))
     def _first():
         # Page slots a partly live group leaves unfilled are read under
         # the mask with p == 0: they must hold numbers, not NaN.
         v_buf[...] = jnp.zeros_like(v_buf)
-
-    @pl.when(trips > 0)
-    def _():
-        copy_group(g0, 0)
 
     _reset(m_ref, l_ref, acc_ref)
     q = q_ref[0]                              # (Hkv, tq * G, D)
     qpos = first_q + qoff_ref[...]            # (tq * G, 1)
 
     def group_step(i, carry):
-        g = g0 + i
-        slot = i % 2
+        """Trip -1 only starts the first group: the stream has one place
+        where copies start."""
+        g = lax.add(g0, i)
+        slot = lax.bitwise_and(i, 1)
 
-        @pl.when(i + 1 < trips)
+        @pl.when(lax.lt(lax.add(i, 1), trips))
         def _():
-            copy_group(g + 1, 1 - slot)
+            copy_group(lax.add(g, 1), lax.sub(1, slot))
 
-        copy_group(g, slot, wait=True)
-        s = jnp.einsum("hrd,htd->hrt", q, k_buf[slot].astype(q.dtype),
-                       preferred_element_type=jnp.float32) * scale
-        kpos = g * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        seen = (kpos <= qpos[None]) & (kpos < hi)
-        if window is not None:
-            seen &= kpos > qpos[None] - window
-        _softmax_update(s, seen, v_buf[slot], m_ref, l_ref, acc_ref,
-                        rows_differ=True)
+        @pl.when(lax.ge(i, 0))
+        def _():
+            copy_group(g, slot, wait=True)
+            s = _scores(q, _group_heads(k_buf, slot)) * scale
+            kpos = g * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            seen = (kpos <= qpos[None]) & (kpos < hi)
+            if window is not None:
+                seen &= kpos > qpos[None] - window
+            _softmax_update(s, seen, _group_heads(v_buf, slot), m_ref,
+                            l_ref, acc_ref, rows_differ=True)
+
         return carry
 
-    jax.lax.fori_loop(0, trips, group_step, None)
+    lax.fori_loop(-1, trips, group_step, None)
     _write_out(o_ref, l_ref, acc_ref)
 
 
@@ -658,8 +780,8 @@ def _prefix_fwd(q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
                       pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
             out_specs=pl.BlockSpec((1, hkv, rows, D), q_index),
             scratch_shapes=[
-                pltpu.VMEM((2, hkv, pages * bs, D), k_pool.dtype),
-                pltpu.VMEM((2, hkv, pages * bs, D), v_pool.dtype),
+                pltpu.VMEM((2, pages, hkv, bs, D), k_pool.dtype),
+                pltpu.VMEM((2, pages, hkv, bs, D), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((hkv, rows, 1), jnp.float32),
                 pltpu.VMEM((hkv, rows, 1), jnp.float32),
@@ -682,9 +804,11 @@ def prefix_attention_kernel(q, k_pool, v_pool, block_tables, prefix_lens,
                             window: Optional[int] = None) -> jax.Array:
     _validate_paged(q[:, 0], k_pool, v_pool)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[3])
+    kw = dict(scale=scale, window=window)
     return _side_by_side(
-        functools.partial(compiled_on_tpu, functools.partial(
-            _prefix_fwd, scale=scale, window=window)),
+        functools.partial(
+            compiled_on_tpu, functools.partial(_prefix_fwd, **kw),
+            gather=functools.partial(prefix_attention_reference, **kw)),
         q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens)
 
 

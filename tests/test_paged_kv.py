@@ -354,41 +354,204 @@ def _ragged_lengths(B, W, bs):
                       + [full, (W - 1) * bs + 1], np.int32)
 
 
-# (H, Hkv, D, bs, W, B): the benchmark's serving cell (Mistral-7B
+def _stream_case(case, B, W, bs, bt, depth):
+    """(lengths, window) of a named walk of the decode kernel's page
+    stream, and `bt` edited in place where the case is about the table.
+    `depth` is the ring's depth in groups of 128 positions."""
+    full, lap = W * bs, depth * 128
+    if case == "ragged":
+        bt[B - 1, :W - 1] = bt[B - 2, :W - 1]     # a shared prefix
+        return _ragged_lengths(B, W, bs), None
+    if case == "ring-edges":
+        # 0, 1, a group and one either side, then N x 128 +- 1 around the
+        # ring's depth and in its second lap
+        edges = [0, 1, 127, 128, 129, lap - 129, lap - 1, lap, lap + 1,
+                 lap + 127, lap + 129, 2 * lap - 1, 2 * lap + 1, full]
+    elif case == "empty-rows":
+        # the first, the last and every other sequence empty: the stream
+        # hands its head from program to program over them
+        edges = [0, lap + 200, 0, 3, 0, 2 * lap + 77, 0, 0, 129, 0]
+    elif case == "window-second-lap":
+        # the first attended position mid-group in the ring's first and
+        # second lap, at a group's edge, and contexts inside the window
+        edges = [lap + 128 + 50 + 300, 300, 299, 301, 0, 128 + 300,
+                 2 * lap + 77 + 300, 1, lap + 300 - 1, full]
+        return np.asarray(edges[:B], np.int32), 300
+    else:
+        assert case == "runs"
+        # consecutive and scattered physical ids in one table: a row all
+        # in a row in the pool, one broken mid-group, one descending, one
+        # that shares the first's pages and goes on scattered
+        free = 1 + bt.max()
+        bt[0] = np.arange(free, free + W)
+        bt[1] = np.arange(free + W, free + 2 * W)
+        bt[1, 11], bt[1, 12] = bt[1, 12], bt[1, 11]
+        bt[2] = np.arange(free + 3 * W - 1, free + 2 * W - 1, -1)
+        bt[3, :W // 2] = bt[0, :W // 2]
+        edges = [full, full - 5, lap + 300, full - 1, lap - 1, 777]
+    assert B <= len(edges) and max(edges) <= full
+    return np.asarray(edges[:B], np.int32), None
+
+
+@pytest.mark.parametrize("kernel,most", [("decode", 170_000),
+                                         ("prefix", 110_000)])
+def test_tracing_a_paged_kernel_stays_cheap(kernel, most):
+    """A Pallas kernel's body is traced in Python once for every shape it
+    meets, for either platform, in every process: an engine's warm start
+    traces `prefix_attention` eight times and more, and a body written
+    with operators on traced scalars (each a jitted `jnp` wrapper) cost
+    the agents cell +29 % of its warm `setup_s` (PERF.md, PR 41).  The
+    count of Python calls one trace makes is what that time follows and
+    is the same on every host: 157 k (decode) and 100 k (prefix) as
+    shipped, 195 k and 130 k with the operators."""
+    import cProfile
+    import pstats
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import paged_attention as pa
+    S, dt = jax.ShapeDtypeStruct, jnp.bfloat16
+    pool = S((64, 4, 16, 128), dt)
+
+    def trace(rows):
+        if kernel == "decode":
+            fn, args = pa._paged_fwd, (S((rows, 8, 128), dt), pool, pool,
+                                       S((rows, 40), jnp.int32),
+                                       S((rows,), jnp.int32))
+        else:
+            fn, args = pa._prefix_fwd, (
+                S((rows, 16, 8, 128), dt), pool, pool,
+                S((rows, 40), jnp.int32), S((rows,), jnp.int32),
+                S((rows,), jnp.int32))
+        jax.make_jaxpr(lambda *a: fn(*a, scale=0.1, window=24,
+                                     interpret=False))(*args)
+
+    trace(3)                        # imports and jnp's own caches
+    prof = cProfile.Profile()
+    prof.enable()
+    trace(5)
+    prof.disable()
+    calls = pstats.Stats(prof).total_calls
+    assert calls < most, calls
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefix"])
+def test_a_tpu_host_traces_a_paged_kernel_once(kernel, monkeypatch):
+    """`platform_dependent` traces every branch: in a process whose backend
+    is a TPU the branch for the other platforms is the gather, so a
+    kernel's body is traced once a shape and not twice; lowered for a TPU
+    the program still holds the one Mosaic call, and run here (no TPU)
+    it is the gather's output, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import paged_attention as pa
+    from test_attention import lower_for_tpu
+    bodies = []
+    name = "_paged_kernel" if kernel == "decode" else "_prefix_kernel"
+    body = getattr(pa, name)
+    monkeypatch.setattr(pa, name, lambda *a, **k: (bodies.append(1),
+                                                   body(*a, **k))[1])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, H, HKV, D, BS, W = 3, 8, 2, 128, 16, 20
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    kp = jax.random.normal(keys[1], (1 + B * W, HKV, BS, D), jnp.bfloat16)
+    vp = jax.random.normal(keys[2], kp.shape, jnp.bfloat16)
+    bt = jnp.asarray(1 + np.arange(B * W, dtype=np.int32).reshape(B, W))
+    lens = jnp.asarray([200, 0, 37], jnp.int32)
+    if kernel == "decode":
+        q = jax.random.normal(keys[0], (B, H, D), jnp.bfloat16)
+        fn, ref = pa.paged_attention_kernel, pa.paged_attention_reference
+        args = (q, kp, vp, bt, lens)
+    else:
+        q = jax.random.normal(keys[0], (B, 16, H, D), jnp.bfloat16)
+        fn, ref = pa.prefix_attention_kernel, pa.prefix_attention_reference
+        args = (q, kp, vp, bt, lens, jnp.asarray([16, 9, 0], jnp.int32))
+    fn = fn.__wrapped__             # past the jit: a trace of its own
+    hlo = lower_for_tpu(fn, *args)
+    assert hlo.count("tpu_custom_call") == 1
+    assert len(bodies) == 1
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(fn)(*args), np.float32),
+        np.asarray(ref(*args), np.float32))
+    assert len(bodies) == 1
+
+
+def test_ring_shape_follows_the_static_shapes():
+    """(pages a group, groups in the ring, groups a softmax update) of
+    the decode kernel's page stream, for the cells' pools and the edges
+    of the rule: the kernel engages on every call, so what it owes the
+    tests is what it derives."""
+    from ray_tpu.ops.paged_attention import _ring_shape
+    for (W, hkv, bs, D, itemsize), want in {
+            (1072, 4, 16, 128, 2): (8, 16, 4),   # Trinity-Mini, LFM2 (x2)
+            (48, 8, 16, 128, 2): (8, 8, 2),      # Mistral-7B
+            (48, 8, 16, 128, 4): (8, 4, 1),      # ... an f32 pool
+            (200, 4, 16, 128, 4): (8, 8, 2),     # the stream tests, f32
+            (288, 2, 16, 128, 2): (8, 16, 4),    # ... bf16
+            (5, 4, 8, 64, 4): (5, 16, 4),        # a table under a group
+            (16, 8, 16, 64, 2): (8, 16, 4),      # llama-1b (narrow: pages)
+            (64, 32, 32, 256, 4): (1, 2, 1),     # two groups are the budget
+    }.items():
+        assert _ring_shape(W, hkv, bs, D, itemsize) == want, (W, hkv, bs, D)
+
+
+# (H, Hkv, D, bs, W, B, case): the benchmark's serving cell (Mistral-7B
 # heads; D 128 takes the kernel's whole-page DMA path), llama-1b and
 # gpt2-small heads (D 64: the block-table BlockSpec path), a small MHA
 # table that is one short group, and tables that are not a whole number
-# of 8-page groups on either path.
-@pytest.mark.parametrize("H,HKV,D,BS,W,B", [
-    (32, 8, 128, 16, 48, 32), (32, 8, 64, 16, 16, 8),
-    (12, 12, 64, 16, 16, 8), (4, 4, 64, 8, 5, 3),
-    (8, 2, 128, 16, 11, 12), (4, 2, 64, 16, 11, 12),
+# of 8-page groups on either path; then the page stream's own edges
+# (`_stream_case`) at a small head count, f32 (a ring of 8 groups, 2 a
+# softmax update) and bf16 (16 and 4), and heads of 64 side by side.
+@pytest.mark.parametrize("H,HKV,D,BS,W,B,case,dtype", [
+    (32, 8, 128, 16, 48, 32, "ragged", "float32"),
+    (32, 8, 64, 16, 16, 8, "ragged", "float32"),
+    (12, 12, 64, 16, 16, 8, "ragged", "float32"),
+    (4, 4, 64, 8, 5, 3, "ragged", "float32"),
+    (8, 2, 128, 16, 11, 12, "ragged", "float32"),
+    (4, 2, 64, 16, 11, 12, "ragged", "float32"),
+    (4, 4, 128, 16, 200, 14, "ring-edges", "float32"),
+    (4, 2, 128, 16, 288, 14, "ring-edges", "bfloat16"),
+    (4, 4, 128, 16, 200, 10, "empty-rows", "float32"),
+    (4, 2, 128, 16, 288, 10, "empty-rows", "bfloat16"),
+    (4, 4, 128, 16, 200, 10, "window-second-lap", "float32"),
+    (4, 2, 128, 16, 288, 10, "window-second-lap", "bfloat16"),
+    (4, 4, 128, 16, 200, 6, "runs", "float32"),
+    (4, 2, 128, 16, 288, 6, "runs", "bfloat16"),
+    (8, 8, 64, 16, 200, 6, "runs", "float32"),
+    (8, 4, 64, 16, 288, 10, "window-second-lap", "bfloat16"),
+    (8, 8, 64, 16, 200, 14, "ring-edges", "float32"),
 ], ids=str)
-def test_paged_attention_kernel_matches_reference(H, HKV, D, BS, W, B):
+def test_paged_attention_kernel_matches_reference(H, HKV, D, BS, W, B, case,
+                                                  dtype):
     """Pallas kernel (interpret mode off-TPU) == gather reference, for
     ragged lengths and with two rows sharing physical pages (a common
-    prefix) while a third row's table is scattered differently."""
+    prefix) while a third row's table is scattered differently.  The
+    stream cases with D 64 hold two heads side by side in a pool row of
+    128 lanes (LFM2's pool), so they too take the whole-page DMA path."""
     import jax
     import jax.numpy as jnp
-    from ray_tpu.ops.paged_attention import (paged_attention_kernel,
+    from ray_tpu.ops.paged_attention import (_ring_shape,
+                                             paged_attention_kernel,
                                              paged_attention_reference)
     NB = 1 + B * W
+    dtype = jnp.dtype(dtype)
+    f = 2 if (D == 64 and case != "ragged") else 1      # heads a pool row
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(k1, (B, H, D), jnp.float32)
-    kp = jax.random.normal(k2, (NB, HKV, BS, D), jnp.float32)
-    vp = jax.random.normal(k3, (NB, HKV, BS, D), jnp.float32)
+    q = jax.random.normal(k1, (B, H, D), dtype)
+    pool = (NB + 3 * W, HKV // f, BS, f * D)    # ("runs" adds three rows)
+    kp = jax.random.normal(k2, pool, dtype)
+    vp = jax.random.normal(k3, pool, dtype)
     rng = np.random.RandomState(0)
     bt = rng.permutation(np.arange(1, NB, dtype=np.int32)).reshape(B, W)
-    lens = _ragged_lengths(B, W, BS)
-    bt[B - 1, :W - 1] = bt[B - 2, :W - 1]     # a shared prefix
-    ref = paged_attention_reference(q, kp, vp, jnp.asarray(bt),
-                                    jnp.asarray(lens))
-    out = paged_attention_kernel(q, kp, vp, jnp.asarray(bt),
-                                 jnp.asarray(lens))
-    assert np.isfinite(np.asarray(out)).all()
-    np.testing.assert_array_equal(np.asarray(out)[lens == 0], 0.0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+    depth = _ring_shape(W, HKV // f, BS, f * D, dtype.itemsize)[1]
+    lens, window = _stream_case(case, B, W, BS, bt, depth)
+    args = (q, kp, vp, jnp.asarray(bt), jnp.asarray(lens))
+    ref = paged_attention_reference(*args, window=window)
+    out = paged_attention_kernel(*args, window=window)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[lens == 0], 0.0)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
 
 
 def test_paged_attention_kernel_bf16_pool_and_dead_table_entries():
@@ -415,13 +578,17 @@ def test_paged_attention_kernel_bf16_pool_and_dead_table_entries():
     np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
 
 
-@pytest.mark.parametrize("h,hkv,d,w,b", [(32, 8, 64, 16, 8),
-                                         (12, 12, 64, 16, 8),
-                                         (32, 8, 128, 48, 32)])
-def test_paged_kernel_lowers_for_tpu(h, hkv, d, w, b):
+@pytest.mark.parametrize("h,hkv,d,w,b,f", [(32, 8, 64, 16, 8, 1),
+                                           (12, 12, 64, 16, 8, 1),
+                                           (32, 8, 128, 48, 32, 1),
+                                           (32, 4, 128, 1072, 32, 1),
+                                           (32, 8, 64, 1072, 32, 2)])
+def test_paged_kernel_lowers_for_tpu(h, hkv, d, w, b, f):
     """The compiled kernel at the llama-1b (GQA 32/8) and gpt2-small
-    (MHA 12/12) head layouts and at the benchmark's serving cell
-    (Mistral-7B heads of 128, 32 slots, 48-page tables), lowered for
+    (MHA 12/12) head layouts and at the benchmark's serving cells
+    (Mistral-7B heads of 128, 32 slots, 48-page tables; Trinity-Mini's 4
+    kv heads of 128 under 1,072-column tables; LFM2's 8 of 64, `f` = 2 side
+    by side in a pool row), lowered for
     the TPU platform from this CPU host (tests/test_attention.py
     lower_for_tpu): ONE Mosaic call whatever the path, so a trace
     counts one `paged_attention` operation per layer and step.  (What
@@ -432,7 +599,8 @@ def test_paged_kernel_lowers_for_tpu(h, hkv, d, w, b):
     from test_attention import lower_for_tpu
     BS = 16
     S = jax.ShapeDtypeStruct
-    pool = S((1 + b * w, hkv, BS, d), jnp.bfloat16)
+    pool = S((8193 if w > 48 else 1 + b * w, hkv // f, BS, f * d),
+             jnp.bfloat16)
     hlo = lower_for_tpu(paged_attention_kernel,
                         S((b, h, d), jnp.bfloat16), pool, pool,
                         S((b, w), jnp.int32), S((b,), jnp.int32))

@@ -380,3 +380,101 @@ def test_prefix_attention_over_heads_side_by_side_on_tpu():
         jnp.asarray(suffix + [0] * pad, jnp.int32), None,
         attend=lambda q, kp, vp, *a, **kw: prefix_attention(
             q, *packed, *a, **kw))
+
+
+# -- the serving cells' decode calls, as the engine makes them (PR 41) ------
+# 32 slots.  "lfm2" / "trinity": 1,072-column tables over an [8192, 4, 16,
+# 128] pool under the agents' mix: four tenants' system prompts of 448 /
+# 8,192 / 12,288 / 16,384 positions, each primed in one allocation (its
+# blocks lie in a row in the pool) and shared by 8 slots, and 40-400
+# positions of history a slot whose blocks are scattered.  "mistral":
+# 48-column tables over [1536, 8, 16, 128], contexts log-uniform 64-768,
+# every block scattered.
+_CELLS = {"lfm2": (64, None), "trinity-full": (128, None),
+          "trinity-window": (128, 2048), "mistral": (128, None)}
+_HBM_BYTES_PER_S = 819e9            # TPU v5e (benchmarks/lib/peaks.py)
+
+
+def _cell_call(cell, seed=0):
+    d, window = _CELLS[cell]
+    rng = np.random.RandomState(seed)
+    B, bs = 32, 16
+    if cell == "mistral":
+        W, NB, hkv = 48, 1536, 8
+        lens = np.exp(rng.uniform(np.log(64), np.log(768), B)).astype(
+            np.int32)
+        prompts = [np.zeros(0, np.int32)] * 4
+        first_free = 1
+    else:
+        W, NB, hkv = 1072, 8192, 4
+        sizes = [448, 8192, 12288, 16384]
+        starts = np.cumsum([1] + [s // bs for s in sizes])
+        prompts = [np.arange(a, a + s // bs, dtype=np.int32)
+                   for a, s in zip(starts, sizes)]
+        first_free = int(starts[-1])
+        lens = np.asarray([sizes[b % 4] + rng.randint(40, 400)
+                           for b in range(B)], np.int32)
+    scattered = iter(rng.permutation(np.arange(first_free, NB,
+                                               dtype=np.int32)))
+    bt = np.zeros((B, W), np.int32)
+    for b in range(B):
+        shared = prompts[b % 4]
+        own = -(-int(lens[b]) // bs) - len(shared)
+        bt[b, :len(shared) + own] = np.concatenate(
+            [shared, [next(scattered) for _ in range(own)]])
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(k1, (B, 32, d), jnp.bfloat16)
+    kp = jax.random.normal(k2, (NB, hkv, bs, 128), jnp.bfloat16)
+    vp = jax.random.normal(k3, (NB, hkv, bs, 128), jnp.bfloat16)
+    return (q, kp, vp, jnp.asarray(bt), jnp.asarray(lens)), window
+
+
+@pytest.mark.parametrize("cell", list(_CELLS))
+def test_paged_kernel_at_the_cells_shapes_on_tpu(cell):
+    (q, kp, vp, bt, lens), window = _cell_call(cell)
+    got = np.asarray(paged_attention(q, kp, vp, bt, lens, impl="kernel",
+                                     window=window), np.float32)
+    assert np.isfinite(got).all()
+    with jax.default_matmul_precision("highest"):
+        for i in range(0, q.shape[0], 4):       # the gather is 4 rows' wide
+            rows = slice(i, i + 4)
+            want = paged_attention_reference(q[rows], kp, vp, bt[rows],
+                                             lens[rows], window=window)
+            np.testing.assert_allclose(got[rows],
+                                       np.asarray(want, np.float32),
+                                       atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("cell", list(_CELLS))
+def test_paged_kernel_call_time_on_tpu(cell):
+    """Prints (`-s`) what a call takes beside what its pages' bytes take
+    at the HBM peak: 200 calls in chains of 20 inside one program, each
+    call's q depending on the call before, fenced."""
+    import time
+    args, window = _cell_call(cell)
+    lens, bs = np.asarray(args[4]), 16
+    first = 0 if window is None else np.maximum(lens - window, 0) // 128 * 8
+    pages = int((-(-lens // bs) - first).sum())
+    page_bytes = int(np.prod(args[1].shape[1:])) * args[1].dtype.itemsize
+    floor = 2 * pages * page_bytes / _HBM_BYTES_PER_S
+
+    @jax.jit
+    def chain(q, *rest):
+        def call(_, q):
+            o = paged_attention(q, *rest, impl="kernel", window=window)
+            return q + (o * 0).astype(q.dtype)
+        return jax.lax.fori_loop(0, 20, call, q)
+
+    chain(*args).block_until_ready()
+    times = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = chain(*args)
+        out.block_until_ready()
+        times.append((time.perf_counter() - t0) / 200)
+    took = min(times)
+    print(f"\npaged_attention {cell}: {took * 1e6:.1f} us a call, "
+          f"{2 * pages * page_bytes / 1e6:.1f} MB of pages = "
+          f"{floor * 1e6:.1f} us at 819 GB/s: {100 * floor / took:.1f} %")
+    assert 0 < floor / took < 1.05
