@@ -85,6 +85,7 @@ from __future__ import annotations
 import itertools
 import os
 import queue
+import sys
 import threading
 import time
 from collections import deque
@@ -94,8 +95,58 @@ from typing import Any, Dict, Iterator, List, Optional
 import numpy as np
 
 from ray_tpu.devtools import leaksan
+from ray_tpu.util.profiling import host_span
 
 _STREAM_END = object()
+
+# Where the engine's two threads are, on the device profiler's clock
+# (profiling.host_span; profiling.idle_attribution lays the device's idle
+# gaps at them).  A dispatch's spans on both threads carry its `seq`.
+# Dispatcher thread (_engine_loop):
+SPAN_PERMIT_WAIT = "engine.permit_wait"  # _slots_sem.acquire: device ahead
+SPAN_STARVED = "engine.starved"          # _work.wait: nothing live or queued
+SPAN_DISPATCH = "engine.dispatch"        # every _dispatch; its stats: seq,
+#   kind fused / decode / none (a tick that found nothing to launch, the
+#   rest left out), live, positions (the rung, 0 for decode), rows, admitted
+SPAN_ADMIT = "engine.admit"              # ↳ _pop_admissions; stat: waiting
+SPAN_PACK = "engine.pack"                # ↳ _fused_dispatch to the jitted call
+SPAN_LAUNCH = "engine.launch"            # ↳ the jitted call + copy_to_host_async
+SPAN_POST_ADMIT = "engine.post_admit"    # ↳ _post_admit: radix insert, gauges
+# Processor thread (_process_loop), beside the dispatcher:
+SPAN_READ_WAIT = "engine.read_wait"      # np.asarray(devs[0]); stat: seq
+SPAN_HAND_OUT = "engine.hand_out"        # _hand_out; stat: seq
+
+# A stretch with nothing on the device and work present that lasts this
+# long is worth a line on stderr, traced or not.
+DEVICE_EMPTY_WARN_S = 1.0
+
+
+class _Phase:
+    """A stretch of the dispatcher thread under one name: a host_span, its
+    `seconds` (added to host_s[`key`] where it has one), and its name in
+    `engine._where` for the processor's "device empty" line.  `with` gives
+    the span, whose set_metadata() takes what is known only inside."""
+
+    __slots__ = ("_eng", "_name", "_key", "_span", "_t0", "_outer",
+                 "seconds")
+
+    def __init__(self, eng: "PagedBatcher", name: str, key: str = "",
+                 **attrs) -> None:
+        self._eng, self._name, self._key = eng, name, key
+        self._span = host_span(name, **attrs)
+
+    def __enter__(self):
+        self._outer, self._eng._where = self._eng._where, self._name
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self._span
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        if self._key:
+            self._eng.host_s[self._key] += self.seconds
+        self._eng._where = self._outer
 
 
 @dataclass
@@ -632,8 +683,34 @@ class PagedBatcher:
         # (the device is ahead: healthy), building and launching a
         # dispatch, and starved (no live slot, nothing waiting).
         # Processor: waiting for a dispatch's tokens, and handing them out.
+        # Inside `dispatch`: admit, pack, launch, post_admit (the spans of
+        # the table above), and inside those the radix walks and the
+        # eviction sweeps; counts of launched dispatches.  And whether the
+        # DEVICE had anything to run, on this clock: from a result's
+        # arrival (_process_entry) at which every launched dispatch has
+        # been read back, to the next launch's return, nothing is in
+        # flight.  Such seconds with a request waiting or a slot live are
+        # `device_starved` (the host's doing), the rest `device_unasked`
+        # (the traffic's).  About a lower bound of the device's idle time:
+        # a result is seen after the device finished it; a launch is
+        # stamped when its call returns, which the device's start may
+        # precede, so a stretch can over-read by up to `launch` ÷
+        # `dispatches`.
         self.host_s = {"permit_wait": 0.0, "dispatch": 0.0, "starved": 0.0,
-                       "read_wait": 0.0, "process": 0.0}
+                       "read_wait": 0.0, "process": 0.0,
+                       "admit": 0.0, "pack": 0.0, "launch": 0.0,
+                       "post_admit": 0.0, "radix_match": 0.0,
+                       "radix_insert": 0.0, "evict": 0.0,
+                       "dispatches": 0,
+                       "device_starved": 0.0, "device_unasked": 0.0}
+        # Shared by the two threads, under _dev_lock for stamps only: when
+        # the device went empty (None while a dispatch is in flight), and
+        # whether this stretch has had its line.  _where: the dispatcher's
+        # innermost span.
+        self._dev_lock = threading.Lock()
+        self._empty_since: Optional[float] = None
+        self._empty_warned = False
+        self._where = "warm-up"
         # Device-resident active-mask cache: skips one host->device
         # transfer per decode dispatch.  In steady state the mask rarely
         # changes (drained-readmission keeps slots full), so the device
@@ -786,6 +863,13 @@ class PagedBatcher:
             for state in ("used", "cached", "free"):
                 km["blocks"].remove(tags={"state": state,
                                           "engine": self._engine_tag})
+
+    def host_stats(self) -> Dict[str, float]:
+        """host_s (stats()["host"]) and `work`: the dispatcher loop's
+        seconds outside `starved`, what `device_starved` is a share of."""
+        h = dict(self.host_s)
+        h["work"] = h["permit_wait"] + h["dispatch"]
+        return h
 
     def kv_stats(self) -> Dict[str, Any]:
         """Block-pool + prefix-cache occupancy (also what the bench
@@ -961,8 +1045,10 @@ class PagedBatcher:
         with self._kv_lock:
             prefix_blocks: List[int] = []
             if self.prefix_cache_enabled:
+                t_m = time.perf_counter()
                 prefix_blocks = self._radix_for(req.model_id).match(
                     req.prompt)
+                self.host_s["radix_match"] += time.perf_counter() - t_m
                 # Hold the matched blocks BEFORE the eviction sweep so
                 # it can never reclaim them out from under the hit (the
                 # sweep skips refcount > 0).
@@ -970,7 +1056,9 @@ class PagedBatcher:
             try:
                 need = total_blocks - len(prefix_blocks)
                 if need > self._alloc.available():
+                    t_e = time.perf_counter()
                     self._evict_locked(need - self._alloc.available())
+                    self.host_s["evict"] += time.perf_counter() - t_e
                 if need > self._alloc.available():
                     # backpressure: undo hold
                     self._alloc.decref_many(prefix_blocks)
@@ -1144,6 +1232,9 @@ class PagedBatcher:
             except IndexError:
                 break
             self._slots_sem.release()
+        with self._dev_lock:        # nothing is in flight any more
+            if self._empty_since is None:
+                self._empty_since = time.perf_counter()
         # _post_admit inserts a batch's blocks into the radix tree at
         # LAUNCH, so a dispatch that later fails device-side leaves
         # cached blocks whose KV was never written — a prefix hit on
@@ -1227,32 +1318,35 @@ class PagedBatcher:
         comes back with the next dispatch.  -> (device arrays, [(row of
         its last tile, slot, req)])."""
         T = self._tile
-        room = self._prefill_rows[-1]
-        takes = []
-        for _, req in batch:
-            tiles = min(self._tiles_left(req), room)
-            takes.append(min(len(req.prompt) - req._prefilled, tiles * T))
-            room -= tiles
-        N = next(n for n in self._prefill_rows
-                 if n >= self._prefill_rows[-1] - room)
-        packed = self._pack(N)
-        rows, row = [], 0
-        for (slot, req), take in zip(batch, takes):
-            done, end = req._prefilled, req._prefilled + take
-            first = row
-            for start in range(done, end, T):
-                n = min(T, end - start)
-                packed[row, :n] = req.prompt[start:start + n]
-                packed[row, T:T + 4] = (n, start, slot, 2)
-                row += 1
-            packed[first:row, T + 4:T + 4 + len(req._blocks)] = req._blocks
-            if end == len(req.prompt):
-                packed[row - 1, T + 3] = 1
-            rows.append((row - 1, slot, req))
-        packed[N, :self.num_slots] = active
-        self.caches, *devs = self._dec.paged_prefill_decode_packed(
+        with _Phase(self, SPAN_PACK, "pack"):
+            room = self._prefill_rows[-1]
+            takes = []
+            for _, req in batch:
+                tiles = min(self._tiles_left(req), room)
+                takes.append(min(len(req.prompt) - req._prefilled,
+                                 tiles * T))
+                room -= tiles
+            N = next(n for n in self._prefill_rows
+                     if n >= self._prefill_rows[-1] - room)
+            packed = self._pack(N)
+            rows, row = [], 0
+            for (slot, req), take in zip(batch, takes):
+                done, end = req._prefilled, req._prefilled + take
+                first = row
+                for start in range(done, end, T):
+                    n = min(T, end - start)
+                    packed[row, :n] = req.prompt[start:start + n]
+                    packed[row, T:T + 4] = (n, start, slot, 2)
+                    row += 1
+                packed[first:row,
+                       T + 4:T + 4 + len(req._blocks)] = req._blocks
+                if end == len(req.prompt):
+                    packed[row - 1, T + 3] = 1
+                rows.append((row - 1, slot, req))
+            packed[N, :self.num_slots] = active
+        devs = self._launch(lambda: self._dec.paged_prefill_decode_packed(
             self.params, self.caches, jnp.asarray(packed),
-            self.cfg, chunk, T, attn_impl=self._attn_impl)
+            self.cfg, chunk, T, attn_impl=self._attn_impl))
         # Launched: only now do the requests move on.
         for (_, req), take in zip(batch, takes):
             req._prefilled += take
@@ -1264,20 +1358,41 @@ class PagedBatcher:
         self._prefill_counts["chunk_tokens"] += sum(takes)
         self._prefill_counts["padded_tokens"] += N * T
         self._rung_dispatches[str(N * T)] += 1
-        return tuple(devs), rows
+        return devs, rows, N
 
     def _decode_dispatch(self, chunk: int) -> tuple:
         """Decode-only device step for every slot; returns (dtoks
         [chunk, B], ...)."""
         if chunk > 1:
-            self.caches, *devs = self._dec.paged_decode_steps(
+            return self._launch(lambda: self._dec.paged_decode_steps(
                 self.params, self.caches, self._active_dev,
-                self.cfg, chunk, attn_impl=self._attn_impl)
-            return tuple(devs)
-        self.caches, tok, *extras = self._dec.paged_decode_step(
-            self.params, self.caches, self._active_dev, self.cfg,
-            attn_impl=self._attn_impl)
-        return (tok[None], *extras)
+                self.cfg, chunk, attn_impl=self._attn_impl))
+
+        def one_step():
+            caches, tok, *extras = self._dec.paged_decode_step(
+                self.params, self.caches, self._active_dev, self.cfg,
+                attn_impl=self._attn_impl)
+            return (caches, tok[None], *extras)
+        return self._launch(one_step)
+
+    def _launch(self, call) -> tuple:
+        """`call()` -> (caches, *device arrays): one jitted program handed
+        to the device and its results asked for.  When it returns the
+        device has work again: the launch is counted and stamped."""
+        with _Phase(self, SPAN_LAUNCH, "launch"):
+            self.caches, *devs = call()
+            for dev in devs:
+                try:
+                    dev.copy_to_host_async()
+                except Exception:
+                    pass
+        now = time.perf_counter()
+        with self._dev_lock:
+            if self._empty_since is not None:
+                self.host_s["device_starved"] += now - self._empty_since
+                self._empty_since = None
+            self.host_s["dispatches"] += 1
+        return tuple(devs)
 
     def _count_dispatch(self, extras: tuple) -> None:
         """What a dispatch returned beyond its tokens (an expert model's
@@ -1299,18 +1414,21 @@ class PagedBatcher:
         # batch run concurrently — so same-batch duplicates must miss
         # (each keeps a private copy) and only future admissions share.
         if self.prefix_cache_enabled:
+            t_i = time.perf_counter()
             with self._kv_lock:
                 for _, _, req in rows:
                     self._radix_for(req.model_id).insert(
                         req.prompt[:req._prefilled], req._blocks,
                         self._alloc)
+            self.host_s["radix_insert"] += time.perf_counter() - t_i
         self._update_kv_gauges()
 
-    def _dispatch(self, jnp) -> bool:
+    def _dispatch(self, jnp, span) -> bool:
         """One device dispatch per tick: chunked decode of every live
         slot, with any waiting admissions FUSED into the same dispatch
         (paged_prefill_decode_packed), so an admission costs no
-        dispatch of its own."""
+        dispatch of its own.  `span` is the tick's engine.dispatch, which
+        is told here what was launched; -> whether anything was."""
         with self._state_lock:
             # A slot is admittable when empty OR "drained": every token
             # its current request needs is already covered by in-flight
@@ -1336,7 +1454,10 @@ class PagedBatcher:
                        > r._pos_cap and self._tail_throttle(r)
                        for i, r in live)
         chunk = 1 if tail else self.decode_chunk
-        batch = self._pop_admissions(free, tail)
+        seq = self.host_s["dispatches"]
+        admit = _Phase(self, SPAN_ADMIT, waiting=self.queue_depth())
+        with admit:
+            batch = self._pop_admissions(free, tail)
         # NOTE: slots whose request already has max_new covered by
         # in-flight dispatches stay in the batch anyway — the decode is
         # fixed-shape, so excluding them saves nothing, while skipping
@@ -1344,7 +1465,11 @@ class PagedBatcher:
         # and costs ~30% throughput (measured).  Their extra tokens are
         # dropped at processing time.
         if not live and not batch:
+            span.set_metadata(kind="none")
             return False
+        # Only a tick that launches adds to `admit`, as only it adds to
+        # `dispatch` and `dispatches` (_engine_loop).
+        self.host_s["admit"] += admit.seconds
         active = np.zeros((self.num_slots,), bool)
         for i, _ in live:
             active[i] = True
@@ -1355,7 +1480,8 @@ class PagedBatcher:
             # lands in prefill_s, not queue_s.
             admit_t = time.time()
             try:
-                devs, rows = self._fused_dispatch(jnp, batch, active, chunk)
+                devs, rows, N = self._fused_dispatch(jnp, batch, active,
+                                                     chunk)
             except Exception as e:
                 # The batch is already out of _waiting/_pending with
                 # KV blocks held, but not yet in _owner — _fail_all
@@ -1378,20 +1504,22 @@ class PagedBatcher:
                     self._disp_len[slot] = (
                         req._prefilled if req._prefilling
                         else len(req.prompt) + chunk)
-            self._post_admit(rows)
+            with _Phase(self, SPAN_POST_ADMIT, "post_admit"):
+                self._post_admit(rows)
             pairs = live + [(slot, req) for _, slot, req in admitted]
-            entry = ("fused", devs, (admitted, pairs))
+            entry = ("fused", devs, (admitted, pairs), seq)
+            span.set_metadata(kind="fused", live=len(live),
+                              positions=N * self._tile, rows=N,
+                              admitted=len(batch))
         else:
             key = active.tobytes()
             if key != self._active_key:
                 self._active_key = key
                 self._active_dev = jnp.asarray(active)
-            entry = ("decode", self._decode_dispatch(chunk), (None, live))
-        for dev in entry[1]:
-            try:
-                dev.copy_to_host_async()
-            except Exception:
-                pass
+            entry = ("decode", self._decode_dispatch(chunk), (None, live),
+                     seq)
+            span.set_metadata(kind="decode", live=len(live), positions=0,
+                              rows=0, admitted=0)
         admitted_slots = ({slot for _, slot, _ in entry[2][0]}
                           if entry[0] == "fused" else set())
         with self._state_lock:
@@ -1417,15 +1545,47 @@ class PagedBatcher:
         return True
 
     def _process_entry(self, entry) -> None:
-        kind, devs, (admitted, pairs) = entry
+        kind, devs, (admitted, pairs), seq = entry
         t_read = time.perf_counter()
-        first_dev = np.asarray(devs[0])     # waits for the dispatch
+        with host_span(SPAN_READ_WAIT, seq=seq):
+            first_dev = np.asarray(devs[0])     # waits for the dispatch
         t_got = time.perf_counter()
         self.host_s["read_wait"] += t_got - t_read
+        with self._dev_lock:
+            # In-order processing: the newest launch read back means
+            # nothing is in flight.
+            if seq + 1 == self.host_s["dispatches"]:
+                self._empty_since = t_got
+                self._empty_warned = False
         try:
-            self._hand_out(kind, devs, first_dev, admitted, pairs)
+            with host_span(SPAN_HAND_OUT, seq=seq):
+                self._hand_out(kind, devs, first_dev, admitted, pairs)
         finally:
             self.host_s["process"] += time.perf_counter() - t_got
+
+    def _warn_device_empty(self) -> None:
+        """The processor's idle tick: one line for a stretch of
+        DEVICE_EMPTY_WARN_S with nothing in flight and work present, so
+        that a run far from the rest leaves its cause in its own log."""
+        with self._dev_lock:
+            since, warned = self._empty_since, self._empty_warned
+        if since is None or warned:
+            return
+        empty = time.perf_counter() - since
+        if empty < DEVICE_EMPTY_WARN_S:
+            return
+        waiting = self.queue_depth()
+        with self._state_lock:
+            live = sum(r is not None for r in self._owner)
+        if not waiting and not live:
+            return
+        with self._dev_lock:
+            if self._empty_since != since:
+                return              # a launch went out meanwhile
+            self._empty_warned = True
+        print(f"[engine] device empty {empty:.1f} s with {waiting} waiting "
+              f"/ {live} live; dispatcher in {self._where}",
+              file=sys.stderr, flush=True)
 
     def _hand_out(self, kind, devs, first_dev, admitted, pairs) -> None:
         now = time.time()
@@ -1512,23 +1672,40 @@ class PagedBatcher:
             return
         self.warmup_s = time.time() - t0
         self._warmed = True
+        self._where = "loop"
+        with self._dev_lock:
+            self._empty_since = time.perf_counter()
         while not self._shutdown:
             try:
                 # Acquire a pipeline slot, then dispatch; the processor
                 # releases slots as it drains entries.
                 t_a = time.perf_counter()
-                got = self._slots_sem.acquire(timeout=0.05)
+                with _Phase(self, SPAN_PERMIT_WAIT):
+                    got = self._slots_sem.acquire(timeout=0.05)
                 t_b = time.perf_counter()
                 self.host_s["permit_wait"] += t_b - t_a
                 if not got:
                     continue
-                if self._dispatch(jnp):
+                with _Phase(self, SPAN_DISPATCH,
+                            seq=self.host_s["dispatches"]) as span:
+                    launched = self._dispatch(jnp, span)
+                if launched:
                     self.host_s["dispatch"] += time.perf_counter() - t_b
                 else:
                     self._slots_sem.release()
-                    self._work.wait(timeout=0.05)
-                    self._work.clear()
-                    self.host_s["starved"] += time.perf_counter() - t_b
+                    # Requests a dispatch could not admit (no blocks, a
+                    # swap that waits) are work the device is kept from.
+                    blocked = bool(self._waiting)
+                    with _Phase(self, SPAN_STARVED):
+                        self._work.wait(timeout=0.05)
+                        self._work.clear()
+                    now = time.perf_counter()
+                    self.host_s["starved"] += now - t_b
+                    with self._dev_lock:
+                        if self._empty_since is not None and not blocked:
+                            self.host_s["device_unasked"] += (
+                                now - self._empty_since)
+                            self._empty_since = now
             except Exception as e:
                 # An engine failure (e.g. device error) must surface to
                 # every waiting caller, not die with the thread and
@@ -1548,6 +1725,7 @@ class PagedBatcher:
                 # trip the autoscaler's ITL SLO at light load.
                 with self._slo_lock:
                     self._last_entry_t = None
+                self._warn_device_empty()
                 self._proc_wake.wait(timeout=0.05)
                 self._proc_wake.clear()
                 continue
@@ -1712,4 +1890,4 @@ class LLMDeployment:
                 "peak_bytes": (dev.memory_stats() or {}).get(
                     "peak_bytes_in_use"),
                 "pid": os.getpid(), "chips": ray_tpu.get_tpu_ids(),
-                "host": dict(b.host_s), **b.kv_stats()}
+                "host": b.host_stats(), **b.kv_stats()}

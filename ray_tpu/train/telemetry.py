@@ -60,6 +60,7 @@ from typing import Any, Dict, Iterable, List, Optional
 from ray_tpu._private.config import config
 from ray_tpu.devtools import leaksan
 from ray_tpu.util import metrics as metrics_mod
+from ray_tpu.util.profiling import host_span
 
 # Explicit phases a step can attribute time to; anything left over in
 # the step's wall clock lands in the implicit "idle" bucket.
@@ -162,21 +163,27 @@ def _median_low(sorted_vals: List[float]) -> float:
 
 
 class _PhaseTimer:
-    """Context manager attributing its body's wall time to one phase."""
+    """Context manager attributing its body's wall time to one phase,
+    and putting it on the device profiler's clock as the span
+    ``train.<phase>`` of this step (profiling.host_span)."""
 
-    __slots__ = ("_tel", "_phase", "_t0")
+    __slots__ = ("_tel", "_phase", "_t0", "_span")
 
     def __init__(self, tel: "TrainTelemetry", phase: str) -> None:
         self._tel = tel
         self._phase = phase
 
     def __enter__(self) -> "_PhaseTimer":
+        self._span = host_span(f"train.{self._phase}",
+                               step=self._tel._step_index)
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         self._tel._add_phase(self._phase,
                              time.perf_counter() - self._t0)
+        self._span.__exit__(*exc)
 
 
 class _DeviceStepTimer:
@@ -184,7 +191,7 @@ class _DeviceStepTimer:
     registered jitted callable's cache grew across it (a shape-change
     step paid tracing/lowering), else ``step``."""
 
-    __slots__ = ("_tel", "_tokens", "_t0", "_jit0")
+    __slots__ = ("_tel", "_tokens", "_t0", "_jit0", "_span")
 
     def __init__(self, tel: "TrainTelemetry",
                  tokens: Optional[int]) -> None:
@@ -192,12 +199,17 @@ class _DeviceStepTimer:
         self._tokens = tokens
 
     def __enter__(self) -> "_DeviceStepTimer":
+        # One name: compile or step is known only at the exit.
+        self._span = host_span("train.device_step",
+                               step=self._tel._step_index)
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         self._jit0 = self._tel._jit_cache_size()
         return self
 
     def __exit__(self, *exc) -> None:
         dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
         jit1 = self._tel._jit_cache_size()
         compiled = self._jit0 >= 0 and jit1 > self._jit0
         self._tel._add_phase("compile" if compiled else "step", dt)
@@ -453,7 +465,13 @@ class TrainTelemetry:
         """Finalize the current step: record the wall split, update
         the rolling window, ledger, decayed rates, metrics, and the
         (rate-limited, batched) timeline span.  Returns the step
-        record."""
+        record.  Its own time is the span ``train.end_step``."""
+        with self._lock:
+            step = self._step_index
+        with host_span("train.end_step", step=step):
+            return self._end_step(tokens)
+
+    def _end_step(self, tokens: Optional[int]) -> Dict[str, Any]:
         now_p = time.perf_counter()
         now_w = time.time()
         with self._lock:

@@ -1,14 +1,18 @@
-"""Tracing/profiling: runtime timeline + user spans + TPU profiler.
+"""Tracing/profiling: runtime timeline + user spans + host spans on
+the device profiler's clock.
 
-Reference surface:
-* `ray.timeline(filename)` (python/ray/_private/state.py chrome-trace
-  export of profile events),
-* `ray.util.tracing` span instrumentation — here `span()` /
-  `@profiled`, recorded into the same per-node event ring workers feed
-  with task execution spans,
-* TPU side: `tpu_trace()` wraps `jax.profiler.trace`, producing the
-  XLA/TensorBoard profile (the tool that actually explains device time
-  — the runtime timeline explains scheduling time).
+Two clocks, two tools:
+* scheduling time: `timeline(filename)` (reference `ray.timeline`, a
+  chrome-trace export of profile events) with `span()` / `@profiled` /
+  `record_span()` recorded into the same per-node event ring workers
+  feed with task execution spans; `export_otlp()` hands them on;
+* device time: `host_span(name, **attrs)` puts what a host thread is
+  doing into `jax.profiler`'s own trace (a `TraceAnnotation`: about a
+  microsecond with no trace running), where the engine's `engine.*`
+  and the trainer's `train.*` spans already are, and
+  `idle_attribution(path)` reads one such trace back and lays every
+  gap of the device's timeline at the span the host was in.  Start
+  the trace itself with `jax.profiler.start_trace` / `trace`.
 """
 
 from __future__ import annotations
@@ -17,8 +21,12 @@ import contextlib
 import functools
 import json
 import os
+import re
+import statistics
+import sys
 import time
-from typing import Any, Dict, List, Optional
+from bisect import bisect_left, bisect_right
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ray_tpu._private import tracing
 from ray_tpu._private.client import get_global_client
@@ -184,21 +192,206 @@ def profiled(fn=None, *, name: Optional[str] = None):
     return deco(fn) if fn is not None else deco
 
 
-@contextlib.contextmanager
-def tpu_trace(logdir: str):
-    """XLA device profile via jax.profiler (view in TensorBoard /
-    xprof).  This captures MXU utilization, HBM traffic, and fusion
-    timing — the device-side complement to the runtime timeline."""
-    import jax
-    with jax.profiler.trace(logdir):
-        yield
+_TraceAnnotation = None
 
 
-def annotate(name: str):
-    """Device-side named region (jax.profiler.TraceAnnotation) so jit
-    regions show under `name` in the xprof timeline."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
+class _NoProfiler:
+    """host_span in a process that has not imported jax: there is no
+    profiler to write to, and importing jax (seconds) is not a span's
+    to pay."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **attrs) -> None:
+        return None
+
+
+_NO_PROFILER = _NoProfiler()
+
+
+def host_span(name: str, **attrs):
+    """What this thread does from here on, on the device profiler's
+    clock: a `jax.profiler.TraceAnnotation` context manager (plane
+    `/host:CPU` of the trace, `attrs` as the event's stats; its
+    `set_metadata(**attrs)` adds what is known only inside).  No client,
+    no RPC; with no trace running, entering and leaving costs about a
+    microsecond, so callers enter it always.  jax is never imported for
+    it: a process without jax (a jax-free train loop) gets a span that
+    does nothing."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return _NO_PROFILER
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name, **attrs)
+
+
+# -- reading a device trace back: which span was the host in when the
+# device had nothing to run ------------------------------------------------
+SPAN_PREFIXES = ("engine.", "train.")
+# The thread that feeds the device is the one whose line holds these: the
+# engine's dispatcher, the train loop.  What runs beside it (the engine's
+# processor thread) is on another line of the trace.
+FEEDER_SPANS = ("engine.dispatch", "train.device_step")
+NO_SPAN = "no_span"
+# An "XLA Ops" event is named by its HLO instruction ("%while.3 = ...");
+# control-flow containers span their bodies' events.
+_CONTAINER = re.compile(r"^%?(while|conditional|call)[.\d]*(?=[\s=(]|$)")
+
+Interval = Tuple[float, float]
+
+
+def attribute_gaps(gaps: Sequence[Interval],
+                   spans: Sequence[Tuple[str, float, float]],
+                   top: int = 10) -> Dict[str, Any]:
+    """Lay each of `gaps` [(start, end)] at one of `spans` [(name, start,
+    end)]: the spans of ONE host thread (nested or apart, never
+    crossing), both in one unit on one clock.  A gap goes to the
+    innermost span that covers more than half of it; where none does, to
+    the span that covers most of it; `no_span` where none touches it.
+    -> {"gaps": the `top` longest as [span, length, that span's own
+    length, the median length of its name], "by_span": {span: summed
+    lengths of ALL gaps}}.  A span far longer than its name's median was
+    stretched by something (the tracer, the GIL, the OS); one near it is
+    long by nature."""
+    order = sorted(range(len(spans)),
+                   key=lambda i: (spans[i][1], -spans[i][2]))
+    cuts = sorted({t for _, s, e in spans for t in (s, e)})
+    # stacks[k]: the spans open between cuts[k] and cuts[k + 1], outermost
+    # first (a parent starts no later and ends no sooner than its child).
+    stacks: List[List[int]] = [[] for _ in cuts[1:]]
+    for i in order:
+        _, s, e = spans[i]
+        for k in range(bisect_left(cuts, s), bisect_left(cuts, e)):
+            stacks[k].append(i)
+    lengths: Dict[str, List[float]] = {}
+    for name, s, e in spans:
+        lengths.setdefault(name, []).append(e - s)
+    medians = {n: statistics.median(v) for n, v in lengths.items()}
+
+    def owner(gs: float, ge: float) -> Optional[int]:
+        cover: Dict[int, float] = {}
+        first = max(bisect_right(cuts, gs) - 1, 0)
+        for k in range(first, min(bisect_left(cuts, ge), len(stacks))):
+            part = min(ge, cuts[k + 1]) - max(gs, cuts[k])
+            if part > 0:
+                for i in stacks[k]:
+                    cover[i] = cover.get(i, 0.0) + part
+        if not cover:
+            return None
+
+        def span_len(i: int) -> float:
+            return spans[i][2] - spans[i][1]
+        most = [i for i, c in cover.items() if c > (ge - gs) / 2]
+        if most:
+            return min(most, key=span_len)
+        return max(cover, key=lambda i: (cover[i], -span_len(i)))
+
+    rows = []
+    by_span: Dict[str, float] = {}
+    for gs, ge in gaps:
+        i = owner(gs, ge)
+        name = NO_SPAN if i is None else spans[i][0]
+        by_span[name] = by_span.get(name, 0.0) + (ge - gs)
+        rows.append((ge - gs, i))
+    rows.sort(key=lambda r: -r[0])
+    listed = [[NO_SPAN, g, None, None] if i is None else
+              [spans[i][0], g, spans[i][2] - spans[i][1],
+               medians[spans[i][0]]] for g, i in rows[:top]]
+    return {"gaps": listed, "by_span": by_span}
+
+
+def _profile(trace):
+    """`trace`: the path of an `.xplane.pb`, or one already read."""
+    if isinstance(trace, (str, os.PathLike)):
+        from jax.profiler import ProfileData
+        return ProfileData.from_file(os.fspath(trace))
+    return trace
+
+
+def read_host_spans(trace, prefixes: Sequence[str] = SPAN_PREFIXES
+                    ) -> List[Dict[str, Any]]:
+    """Every `host_span` of an `.xplane.pb` whose name starts with one of
+    `prefixes`: [{name, start, end (seconds on the trace's clock), line
+    (its thread: the line's number among the trace's host lines),
+    stats}]."""
+    out = []
+    prefixes = tuple(prefixes)
+    lines = (line for plane in _profile(trace).planes
+             if plane.name.startswith("/host:") for line in plane.lines)
+    for n, line in enumerate(lines):
+        for ev in line.events:
+            if ev.name.startswith(prefixes):
+                out.append({"name": ev.name, "start": ev.start_ns / 1e9,
+                            "end": (ev.start_ns + ev.duration_ns) / 1e9,
+                            "line": n, "stats": dict(ev.stats)})
+    return out
+
+
+def feeder_spans(spans: Sequence[Dict[str, Any]]
+                 ) -> List[Tuple[str, float, float]]:
+    """Of `read_host_spans`' spans, those of the ONE thread that feeds the
+    device, as `attribute_gaps` takes them: the line that spends the
+    most seconds in FEEDER_SPANS (where a process holds several engines
+    or loops, the busiest).  [] where no line has one."""
+    seconds: Dict[int, float] = {}
+    for sp in spans:
+        if sp["name"] in FEEDER_SPANS:
+            seconds[sp["line"]] = (seconds.get(sp["line"], 0.0)
+                                   + sp["end"] - sp["start"])
+    if not seconds:
+        return []
+    line = max(seconds, key=seconds.get)
+    return [(sp["name"], sp["start"], sp["end"]) for sp in spans
+            if sp["line"] == line]
+
+
+def device_gaps(trace) -> List[List[Interval]]:
+    """The idle gaps of each device timeline in an `.xplane.pb`, one list
+    a `/device:` plane, in seconds on the trace's clock: what lies
+    between the merged "XLA Ops" events, control-flow containers (while,
+    conditional, call) left out because they span their bodies."""
+    planes: List[List[Interval]] = []
+    for plane in _profile(trace).planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            busy: List[List[float]] = []
+            for s, e in sorted(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns)
+                    for ev in line.events
+                    if not _CONTAINER.match(ev.name)):
+                if busy and s <= busy[-1][1]:
+                    busy[-1][1] = max(busy[-1][1], e)
+                else:
+                    busy.append([s, e])
+            planes.append([(a[1] / 1e9, b[0] / 1e9)
+                           for a, b in zip(busy, busy[1:])])
+    return planes
+
+
+def idle_attribution(path: str, top: int = 10) -> Dict[str, Any]:
+    """From one `.xplane.pb`: every idle gap of the device laid at the
+    `engine.*` / `train.*` span that the thread which feeds it was in
+    (`feeder_spans` picks the thread, `attribute_gaps` has the rule and
+    the result's form; seconds).  Of several devices (one program across
+    chips) each one's gaps are laid separately: "gaps" lists the longest
+    of any device, "by_span" is the mean over "devices" of them, one
+    device's idle seconds."""
+    trace = _profile(path)
+    planes = device_gaps(trace)
+    out = attribute_gaps([g for gaps in planes for g in gaps],
+                         feeder_spans(read_host_spans(trace)), top)
+    out["by_span"] = {name: v / len(planes)
+                      for name, v in out["by_span"].items()}
+    out["devices"] = len(planes)
+    return out
 
 
 def export_otlp(filename: Optional[str] = None,
