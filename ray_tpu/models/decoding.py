@@ -19,6 +19,12 @@ the decode loop itself, shaped for XLA:
 * A prefill runs rows of a prompt's uncached SUFFIX through the layers
   (causal within the prompt), against what the request already has in
   the pool: the cached prefix is never recomputed.
+* A fused dispatch's prefill pass IS its first decode step: beside the
+  prompt rows it carries the next position of every slot that was already
+  decoding, so every dense product of a layer reads its weights once for
+  both (only attention is two calls, one per kind of row), and
+  `num_steps` tokens a slot cost `num_steps` walks of the weights, as in
+  a decode-only dispatch.
 
 Everything reuses transformer.py's parameter layout (init_params),
 norms and RoPE, so any trained checkpoint serves unchanged, and the
@@ -328,6 +334,38 @@ def decode_rows(tables, lengths, active, block_size: int) -> DecodeRows:
                       jnp.where(offsets == block_size - 1, blocks, 0))
 
 
+def _pass_tokens(rows: PrefillRows, step: Optional[DecodeRows]):
+    """The T = N * P + B tokens one pass over the layers sees side by side:
+    the prompt rows' positions, then the next position of each of `step`'s B
+    slots (None: B = 0) -> (positions [1, T], valid [1, T], blocks [T],
+    offsets [T])."""
+    fields = [(rows.positions, rows.live, rows.blocks, rows.offsets)]
+    if step is not None:
+        fields.append((step.positions, step.active, step.blocks,
+                       step.offsets))
+    positions, valid, blocks, offsets = (
+        jnp.concatenate([a.reshape(-1) for a in field])
+        for field in zip(*fields))
+    return positions[None], valid[None], blocks, offsets
+
+
+def _attend_pass(q, k_pool, v_pool, rows: PrefillRows,
+                 step: Optional[DecodeRows], first_block=0, **kw):
+    """Attention of one pass's queries [1, T, H, D], its K/V already in the
+    pool: the prompt rows' through `_attend_rows`, the decode rows' through
+    paged_attention, each kernel called as a prefill or a decode step alone
+    calls it."""
+    from ray_tpu.ops import paged_attention as _pa
+    (N, P), (H, D) = rows.positions.shape, q.shape[2:]
+    o = _attend_rows(q[0, :N * P].reshape(N, P, H, D), k_pool, v_pool, rows,
+                     first_block, **kw).reshape(N * P, H, D)
+    if step is not None:
+        o = jnp.concatenate([o, _pa.paged_attention(
+            q[0, N * P:], k_pool, v_pool, first_block + step.tables,
+            step.context_lens, **kw).astype(o.dtype)])
+    return o[None]
+
+
 def _write_rows(pool, blocks, offsets, new):
     """pool [NB, Hkv, bs, D] with new [..., Hkv, D] written at (blocks,
     :, offsets) [...].  As a scatter of D-wide rows into the pool seen as
@@ -457,10 +495,18 @@ def _paged_prefill_core(params: Dict[str, Any],
                         suffix_lens: jax.Array, prefix_lens: jax.Array,
                         slots: jax.Array, valid: jax.Array,
                         closes: jax.Array, new_bt: jax.Array,
-                        cfg: TransformerConfig, attn_impl: str = "auto"):
+                        cfg: TransformerConfig, attn_impl: str = "auto",
+                        carried: Optional[jax.Array] = None):
     """Prefill of N rows of P tokens against what their requests have in
     the pool (traceable) -> (caches', first tokens [N], an expert model's
-    counts or None).
+    counts or None, the carried slots' next tokens [B] or None).
+
+    `carried` [B] bool: the slots whose next decode step rides in this pass
+    (none of them a slot that a row of this call closes).  Their one
+    position each lies beside the rows' N * P in every product of every
+    layer, so a weight is read once for both; they are written, attended
+    and moved on as `_paged_decode_core` does it.  Slots not carried write
+    to the scratch block and are routed to no expert.
 
     A row is a TILE of one request's uncached tokens: tokens [N, P] hold
     suffix_lens[n] of them, at absolute positions prefix_lens[n] + i (RoPE /
@@ -477,46 +523,62 @@ def _paged_prefill_core(params: Dict[str, Any],
     yields the request's first token and hands the slot its table and
     length.  A row that is not `valid` writes to the scratch block only."""
     N, P = tokens.shape
-    rows = prefill_rows(new_bt, prefix_lens, suffix_lens, valid, P,
-                        block_size_of(caches), slots,
-                        caches.lengths.shape[0], closes,
+    B = caches.lengths.shape[0]
+    bs = block_size_of(caches)
+    rows = prefill_rows(new_bt, prefix_lens, suffix_lens, valid, P, bs,
+                        slots, B, closes,
                         cfg.conv_kernel if caches.tail_pool else 0)
-    last_ix = (jnp.arange(N), jnp.clip(suffix_lens - 1, 0, P - 1))
+    # The pass's tokens [1, T] and which of them yield one: every row's
+    # last live position, then every decode row.
+    tokens = tokens.reshape(1, N * P)
+    yields = jnp.arange(N) * P + jnp.clip(suffix_lens - 1, 0, P - 1)
+    step = None
+    if carried is not None:
+        step = decode_rows(caches.block_tables, caches.lengths, carried, bs)
+        tokens = jnp.concatenate([tokens, caches.last_token[None]], axis=1)
+        yields = jnp.concatenate([yields, N * P + jnp.arange(B)])
     if cfg.layer_kinds is not None:
         model = unrolled(cfg)
         x = model.embed(cfg, params["tok_embed"], tokens)
-        x, state, counts = _unrolled_layers(cfg, params, caches, x, rows,
-                                            paged_prefill_layer, attn_impl)
-        logits = model.logits(cfg, params, x[last_ix])
+        x, state, counts = _unrolled_layers(
+            cfg, params, caches, x, rows,
+            functools.partial(paged_prefill_layer, step=step), attn_impl)
+        logits = model.logits(cfg, params, x[0, yields])
     else:
         x, kp, vp = _dense_prefill_layers(cfg, params, caches, tokens, rows,
-                                          attn_impl)
+                                          step, attn_impl)
         state, counts = dict(kp=kp, vp=vp), None
-        last = _norm(x[last_ix], params["final_norm"],
+        last = _norm(x[0, yields], params["final_norm"],
                      params.get("final_norm_b"), cfg.norm_eps,
-                     cfg.arch == "llama")                    # [N, D]
+                     cfg.arch == "llama")                    # [N + B, D]
         logits = last.astype(jnp.float32) @ _w_out(params, cfg).astype(
             jnp.float32)
-    first_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    first_tok, step_tok = tok[:N], None
+    lengths, last_token = caches.lengths, caches.last_token
+    if carried is not None:
+        step_tok = tok[N:]
+        lengths = jnp.where(carried, lengths + 1, lengths)
+        last_token = jnp.where(carried, step_tok, last_token)
     # A scatter whose in-range indices are distinct: at most one row of a
     # slot closes, and every other row is sent out of range and dropped.
-    at = jnp.where(closes, slots, caches.lengths.shape[0])
+    at = jnp.where(closes, slots, B)
     return caches._replace(
         block_tables=caches.block_tables.at[at].set(new_bt, mode="drop"),
-        lengths=caches.lengths.at[at].set(prefix_lens + suffix_lens,
-                                          mode="drop"),
-        last_token=caches.last_token.at[at].set(first_tok, mode="drop"),
-        **state), first_tok, counts
+        lengths=lengths.at[at].set(prefix_lens + suffix_lens, mode="drop"),
+        last_token=last_token.at[at].set(first_tok, mode="drop"),
+        **state), first_tok, counts, step_tok
 
 
 def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
-                          attn_impl):
-    """Arch "llama" / "gpt2": the layer scan of one prefill over the
-    stacked pool -> (x' [N, P, D], kp', vp')."""
-    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [N,P,D]
+                          step: Optional[DecodeRows], attn_impl):
+    """Arch "llama" / "gpt2": the layer scan of one pass (`_pass_tokens`:
+    tokens [1, T]) over the stacked pool -> (x' [1, T, D], kp', vp')."""
+    positions, _, blocks, offsets = _pass_tokens(rows, step)
+    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [1,T,D]
     if cfg.arch == "gpt2":
         x = x + params["pos_embed"][
-            jnp.clip(rows.positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
+            jnp.clip(positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
     rms = cfg.arch == "llama"
 
     def layer(carry, inputs):
@@ -524,11 +586,11 @@ def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
         p, first = inputs
         h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
                   cfg.norm_eps, rms)
-        q, k, v = _qkv(p, h, cfg, rows.positions)
-        k_pool = _write_rows(k_pool, first + rows.blocks, rows.offsets, k)
-        v_pool = _write_rows(v_pool, first + rows.blocks, rows.offsets, v)
-        o = _attend_rows(q, k_pool, v_pool, rows, first,
-                         impl=attn_impl)                     # [N,P,H,Dh]
+        q, k, v = _qkv(p, h, cfg, positions)
+        k_pool = _write_rows(k_pool, first + blocks, offsets, k[0])
+        v_pool = _write_rows(v_pool, first + blocks, offsets, v[0])
+        o = _attend_pass(q, k_pool, v_pool, rows, step, first,
+                         impl=attn_impl)                     # [1,T,H,Dh]
         attn = jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
                           p["wo"].astype(cfg.dtype))
         return (_mlp(p, x + attn, cfg), k_pool, v_pool), None
@@ -544,10 +606,19 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
                                 packed: jax.Array,
                                 cfg: TransformerConfig, num_steps: int,
                                 prompt_pad: int, attn_impl: str = "auto"
-                                ) -> Tuple[PagedDecodeCaches, jax.Array,
-                                           jax.Array]:
+                                ) -> Tuple[PagedDecodeCaches, jax.Array]:
     """Fused suffix-prefill + chunked decode with ALL host inputs in
     ONE int32 upload: one host->device transfer per dispatch.
+    -> (caches', tokens [num_steps, B]); unrolled layers also their expert
+    layers' counts.
+
+    The prefill pass is the dispatch's FIRST decode step: it carries the
+    next position of every slot that was active before this call (and that
+    no row of this call closes), and `num_steps - 1` decode steps of every
+    active slot follow.  So the call walks the weights `num_steps` times,
+    like `paged_decode_steps`, and every active slot gets `num_steps`
+    tokens: tokens[0] is the pass's (for a slot a row closes, its prompt's
+    first token), tokens[1:] the steps'.
 
     packed: [N+1, Wp] int32 with W = table width, P = prompt_pad (the
     width of a row: serve/llm.py PREFILL_TILE) and
@@ -562,10 +633,11 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
     one after the other, in this call or over several (the host loop:
     serve/llm.py), and those of one call attend in groups of up to
     ATTENTION_ROW queries.  `valid` 0: no row.  1: the row ends its prompt:
-    it yields the first token and its slot decodes from this dispatch on.
-    2: more of the prompt is to come: its K/V are written and its slot
-    stays out of the decode steps.  Unrolled layers return their expert
-    layers' counts as a fourth value.
+    it yields the first token and its slot decodes from this dispatch on
+    (a slot the host still marks active for the request before is the new
+    request's: it gets no decode row in the pass).  2: more of the prompt
+    is to come: its K/V are written and its slot stays out of the decode
+    steps.
     """
     P = prompt_pad
     B = caches.lengths.shape[0]
@@ -573,22 +645,25 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
     flag = packed[:-1, P + 3]
     closes = flag == 1
     slots = packed[:-1, P + 2]
-    caches, first, counts = _paged_prefill_core(
+    was_active = packed[-1, :B] > 0
+    at = jnp.where(closes, slots, B)
+    closed = jnp.zeros((B,), bool).at[at].set(True, mode="drop")
+    caches, first, counts, tok = _paged_prefill_core(
         params, caches, packed[:-1, :P], packed[:-1, P], packed[:-1, P + 1],
         slots, flag > 0, closes, packed[:-1, P + 4:P + 4 + W], cfg,
-        attn_impl)
-    active = (packed[-1, :B] > 0).at[jnp.where(closes, slots, B)].set(
-        True, mode="drop")
+        attn_impl, carried=was_active & ~closed)
+    tok = tok.at[at].set(first, mode="drop")[None]
+    active = was_active | closed
     if cfg.layer_kinds is not None:
         caches, toks, more = _unrolled_decode_scan(
-            params, caches, active, cfg, num_steps, attn_impl)
-        return caches, first, toks, counts + more
+            params, caches, active, cfg, num_steps - 1, attn_impl)
+        return caches, jnp.concatenate([tok, toks]), counts + more
 
     def body(c, _):
         return _paged_decode_core(params, c, active, cfg, attn_impl)
 
-    caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
-    return caches, first, toks
+    caches, toks = jax.lax.scan(body, caches, None, length=num_steps - 1)
+    return caches, jnp.concatenate([tok, toks])
 
 
 # ===========================================================================
@@ -605,7 +680,7 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
 
 def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
                         rows: PrefillRows, attn_impl: str = "auto",
-                        tap=None):
+                        tap=None, step: Optional[DecodeRows] = None):
     """One layer over a chunk x [N, P, D].  An attention layer: its K/V go
     into the pool, its queries attend to the pool (prefix and chunk alike,
     under the layer's window).  A conv layer, whose `k_pool` is its block
@@ -615,39 +690,56 @@ def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
     call reads what that one just wrote, and never another request's), or
     from zeros at position 0; a row that ends its prompt leaves its slot
     the tail its decode starts from.
+
+    With `step`, B slots' next decode position rides in the same pass: x
+    is then the pass's tokens side by side [1, N * P + B, D]
+    (`_pass_tokens`), one set of products for both kinds of row; a decode
+    row is written, attended and (a conv layer) started from its slot's
+    tail as `paged_decode_layer` does it.
     -> (x', k_pool', v_pool', afmoe.MOE_COUNTS)."""
     model = unrolled(cfg)
     state = []
+    (N, P), D = rows.positions.shape, x.shape[-1]
+    positions, valid, blocks, offsets = _pass_tokens(rows, step)
 
     def attend(q, k, v):
-        kp = _write_rows(k_pool, rows.blocks, rows.offsets, k)
-        vp = _write_rows(v_pool, rows.blocks, rows.offsets, v)
+        kp = _write_rows(k_pool, blocks, offsets, k[0])
+        vp = _write_rows(v_pool, blocks, offsets, v[0])
         state.extend((kp, vp))
-        return _attend_rows(q, kp, vp, rows, impl=attn_impl,
+        return _attend_pass(q, kp, vp, rows, step, impl=attn_impl,
                             window=model.window_of(cfg, kind))
 
     def before(u):
-        N, _, D = u.shape
         K1 = v_pool.shape[1]
+        up = u[0, :N * P].reshape(N, P, D)
         tails = k_pool.at[rows.tail_blocks.reshape(-1)].set(
-            u[:, rows.tail_at].reshape(-1, K1 * D))
+            up[:, rows.tail_at].reshape(-1, K1 * D))
         came = jnp.where((rows.prefix_lens > 0)[:, None, None],
                          tails[rows.before_block].reshape(N, K1, D), 0)
         slot = v_pool
         if rows.close_slots is not None:
-            ext = jnp.concatenate([came, u], axis=1)
+            ext = jnp.concatenate([came, up], axis=1)
             last = rows.suffix_lens[:, None] + jnp.arange(K1)    # [N, K-1]
             slot = slot.at[rows.close_slots].set(
                 jnp.take_along_axis(ext, last[..., None], axis=1),
                 mode="drop")
+        prev = [t.reshape(1, N * P, D) for t in model.taps(came, up)]
+        if step is not None:
+            ud = u[0, N * P:, None]                              # [B, 1, D]
+            moved = jnp.concatenate([v_pool[:, 1:], ud], axis=1)
+            tails = tails.at[step.tail_blocks].set(
+                moved.reshape(moved.shape[0], -1))
+            slot = jnp.where(step.active[:, None, None], moved, slot)
+            prev = [jnp.concatenate([a, b.reshape(1, -1, D)], axis=1)
+                    for a, b in zip(prev, model.taps(v_pool, ud))]
         state.extend((tails, slot))
-        return came
+        return prev
 
-    x, counts = model.layer(cfg, kind, p, x, rows.positions,
+    y, counts = model.layer(cfg, kind, p, x.reshape(1, -1, D), positions,
                             before if kind[0] == "conv" else attend,
-                            valid=rows.live, moe_name="moe_experts_prefill",
+                            valid=valid, moe_name="moe_experts_prefill",
                             tap=tap)
-    return x, state[0], state[1], counts
+    return y.reshape(x.shape), state[0], state[1], counts
 
 
 def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
@@ -673,7 +765,7 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
         state.extend((k_pool.at[rows.tail_blocks].set(
             moved.reshape(moved.shape[0], -1)),
             jnp.where(rows.active[:, None, None], moved, v_pool)))
-        return v_pool
+        return model.taps(v_pool, u)
 
     x, counts = model.layer(cfg, kind, p, x, rows.positions,
                             before if kind[0] == "conv" else attend,

@@ -155,19 +155,25 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 # the layer
 # ---------------------------------------------------------------------------
+def taps(prev: jax.Array, u: jax.Array) -> list:
+    """u [B, S, D] after `prev` [B, K - 1, D], the positions before each row
+    (zeros at a sequence's start) -> u's K - 1 predecessors at every
+    position, farthest first: K - 1 arrays like u."""
+    ext = jnp.concatenate([prev.astype(u.dtype), u], axis=1)
+    return [ext[:, j:j + u.shape[1]] for j in range(prev.shape[1])]
+
+
 def short_conv(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
                before: Callable) -> jax.Array:
     """The conv operator on a [B, S, D]: `before(u)` gives u's K - 1
-    predecessors of each row [B, K - 1, D] (zeros at a sequence's start)."""
+    predecessors at every position (`taps`: the caller knows what lies
+    before each of its rows)."""
     with jax.named_scope("short_conv"):
-        K = cfg.conv_kernel
         bcz = jnp.einsum("bsd,dcf->bscf", a, p["w_in"].astype(a.dtype))
         u = bcz[:, :, 0] * bcz[:, :, 2]
-        ext = jnp.concatenate([before(u).astype(u.dtype), u], axis=1)
-        S = u.shape[1]
         w = p["w_conv"].astype(jnp.float32)
-        c = sum(w[j] * ext[:, j:j + S].astype(jnp.float32)
-                for j in range(K))
+        c = sum(w[j] * t.astype(jnp.float32)
+                for j, t in enumerate(before(u) + [u]))
         y = (bcz[:, :, 1].astype(jnp.float32) * c).astype(a.dtype)
         return jnp.einsum("bsf,fd->bsd", y, p["w_out"].astype(a.dtype))
 
@@ -180,8 +186,8 @@ def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
           ) -> Tuple[jax.Array, jax.Array]:
     """x [B, S, D] at `positions` [B, S] -> (x', MOE_COUNTS of this call).
     `mix` is the caller's, built for this layer's mixer: `mix(q, k, v)` ->
-    attention output [B, S, H, Dh] of a full layer, `mix(u)` -> the
-    positions before each row of u, [B, conv_kernel - 1, D], of a conv
+    attention output [B, S, H, Dh] of a full layer, `mix(u)` -> u's
+    conv_kernel - 1 predecessors at every position (`taps`), of a conv
     layer.  `tap`, if given, is shown an expert layer's input [B * S, D]
     and its picks [B * S, k] (a comparison's way to see them; the serving
     path passes none)."""
@@ -228,7 +234,8 @@ def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
     attend = afmoe._attend_plain(cfg, None)
 
     def nothing_before(u):
-        return jnp.zeros((B, cfg.conv_kernel - 1, u.shape[2]), u.dtype)
+        return taps(jnp.zeros((B, cfg.conv_kernel - 1, u.shape[2]), u.dtype),
+                    u)
 
     for kind, p in zip(cfg.layer_kinds, params["layers"]):
         x, _ = layer(cfg, kind, p, x, positions,

@@ -32,8 +32,13 @@ A dispatch is one device program per tick of the dispatcher thread:
 `decode_chunk` decode steps of every live slot, with whatever prefill
 is waiting FUSED into the same program (paged_prefill_decode_packed),
 so an admission costs no dispatch of its own; with nothing to admit it
-is the decode-only program (paged_decode_steps).  All host inputs of a
-dispatch travel in one int32 upload.
+is the decode-only program (paged_decode_steps).  Either way a dispatch
+is `decode_chunk` walks of the weights and returns `decode_chunk` tokens
+for every slot in it: the fused program's prefill pass carries the live
+slots' first decode step (their one position each beside the prompt rows,
+`prefill.carried_rows` counts them), `decode_chunk - 1` steps follow, and
+for a request the dispatch admits the first of its tokens is its prompt's
+first token.  All host inputs of a dispatch travel in one int32 upload.
 
 Prefill: `prompt_pad` is the longest prompt `submit` accepts.  A row of
 the fused prefill is a TILE of PREFILL_TILE tokens (a KV block or two) of
@@ -640,7 +645,8 @@ class PagedBatcher:
         # every layer, so none beyond a window is freed yet).
         self._prefill_counts = {"chunks": 0, "chunk_tokens": 0,
                                 "padded_tokens": 0,
-                                "multi_chunk_requests": 0}
+                                "multi_chunk_requests": 0,
+                                "carried_rows": 0}
         self._rung_dispatches = {str(n * self._tile): 0
                                  for n in self._prefill_rows}
         self._moe_counts = [0, 0, 0, 0]
@@ -1167,20 +1173,6 @@ class PagedBatcher:
         return batch
 
     # -- engine ------------------------------------------------------------
-    def _push_token(self, req: _Request, tok: int) -> None:
-        req.tokens.append(tok)
-        if req.stream_q is not None:
-            req.stream_q.put(tok)
-
-    def _finished(self, req: _Request, tok: int) -> bool:
-        if self.eos_id is not None and tok == self.eos_id:
-            req.finish_reason = "eos"
-            return True
-        if len(req.tokens) >= req.max_new:
-            req.finish_reason = "length"
-            return True
-        return False
-
     def _retire(self, slot: int, req: _Request) -> None:
         with self._state_lock:
             if self._owner[slot] is req:
@@ -1315,8 +1307,8 @@ class PagedBatcher:
         """`batch` as _pop_admissions cut it: every request in it gets at
         least one row.  Its uncached tokens go into rows of `_tile`, in
         order, until the widest program is full; a request cut short there
-        comes back with the next dispatch.  -> (device arrays, [(row of
-        its last tile, slot, req)])."""
+        comes back with the next dispatch.  -> (device arrays, rows of the
+        program that ran)."""
         T = self._tile
         with _Phase(self, SPAN_PACK, "pack"):
             room = self._prefill_rows[-1]
@@ -1329,7 +1321,7 @@ class PagedBatcher:
             N = next(n for n in self._prefill_rows
                      if n >= self._prefill_rows[-1] - room)
             packed = self._pack(N)
-            rows, row = [], 0
+            row = 0
             for (slot, req), take in zip(batch, takes):
                 done, end = req._prefilled, req._prefilled + take
                 first = row
@@ -1342,7 +1334,6 @@ class PagedBatcher:
                        T + 4:T + 4 + len(req._blocks)] = req._blocks
                 if end == len(req.prompt):
                     packed[row - 1, T + 3] = 1
-                rows.append((row - 1, slot, req))
             packed[N, :self.num_slots] = active
         devs = self._launch(lambda: self._dec.paged_prefill_decode_packed(
             self.params, self.caches, jnp.asarray(packed),
@@ -1358,7 +1349,7 @@ class PagedBatcher:
         self._prefill_counts["chunk_tokens"] += sum(takes)
         self._prefill_counts["padded_tokens"] += N * T
         self._rung_dispatches[str(N * T)] += 1
-        return devs, rows, N
+        return devs, N
 
     def _decode_dispatch(self, chunk: int) -> tuple:
         """Decode-only device step for every slot; returns (dtoks
@@ -1403,7 +1394,7 @@ class PagedBatcher:
                 self._moe_counts = [a + b for a, b in
                                     zip(self._moe_counts, counts)]
 
-    def _post_admit(self, rows: List[tuple]) -> None:
+    def _post_admit(self, batch: List[tuple]) -> None:
         """Bookkeeping after a fused dispatch launched: radix insertion
         and the gauges."""
         # Optimistic radix insertion AFTER the batch is packed, of the
@@ -1416,7 +1407,7 @@ class PagedBatcher:
         if self.prefix_cache_enabled:
             t_i = time.perf_counter()
             with self._kv_lock:
-                for _, _, req in rows:
+                for _, req in batch:
                     self._radix_for(req.model_id).insert(
                         req.prompt[:req._prefilled], req._blocks,
                         self._alloc)
@@ -1480,8 +1471,7 @@ class PagedBatcher:
             # lands in prefill_s, not queue_s.
             admit_t = time.time()
             try:
-                devs, rows, N = self._fused_dispatch(jnp, batch, active,
-                                                     chunk)
+                devs, N = self._fused_dispatch(jnp, batch, active, chunk)
             except Exception as e:
                 # The batch is already out of _waiting/_pending with
                 # KV blocks held, but not yet in _owner — _fail_all
@@ -1495,19 +1485,24 @@ class PagedBatcher:
                 raise
             # A row whose prompt has chunks still to come holds its slot
             # and yields no token yet; the others are admitted for good.
-            admitted = [a for a in rows if not a[2]._prefilling]
+            admitted = [a for a in batch if not a[1]._prefilling]
             with self._state_lock:
-                for _, slot, req in rows:
+                for slot, req in batch:
                     self._owner[slot] = req
                     req._admit_t = req._admit_t or admit_t
-                    # prompt + the chunk the fused step decodes for it
+                    # prompt + the steps after the pass (its first token
+                    # is the pass's: `chunk` tokens, like every live slot)
                     self._disp_len[slot] = (
                         req._prefilled if req._prefilling
-                        else len(req.prompt) + chunk)
+                        else len(req.prompt) + chunk - 1)
             with _Phase(self, SPAN_POST_ADMIT, "post_admit"):
-                self._post_admit(rows)
-            pairs = live + [(slot, req) for _, slot, req in admitted]
-            entry = ("fused", devs, (admitted, pairs), seq)
+                self._post_admit(batch)
+            entry = (devs, admitted, live + admitted, seq)
+            # The live slots whose step rode in the pass: all but those
+            # this dispatch re-admits (drained: the new request's now).
+            admitted_slots = {slot for slot, _ in admitted}
+            self._prefill_counts["carried_rows"] += len(
+                {i for i, _ in live} - admitted_slots)
             span.set_metadata(kind="fused", live=len(live),
                               positions=N * self._tile, rows=N,
                               admitted=len(batch))
@@ -1516,16 +1511,14 @@ class PagedBatcher:
             if key != self._active_key:
                 self._active_key = key
                 self._active_dev = jnp.asarray(active)
-            entry = ("decode", self._decode_dispatch(chunk), (None, live),
-                     seq)
+            entry = (self._decode_dispatch(chunk), (), live, seq)
+            admitted_slots = set()
             span.set_metadata(kind="decode", live=len(live), positions=0,
                               rows=0, admitted=0)
-        admitted_slots = ({slot for _, slot, _ in entry[2][0]}
-                          if entry[0] == "fused" else set())
         with self._state_lock:
             for i, _ in live:
                 # A drained-readmitted slot already had its _disp_len
-                # reset to prompt + chunk above; adding chunk again
+                # reset to prompt + chunk - 1 above; adding chunk again
                 # would report it "drained" one chunk early and strand
                 # its final chunk.
                 if i not in admitted_slots:
@@ -1545,10 +1538,10 @@ class PagedBatcher:
         return True
 
     def _process_entry(self, entry) -> None:
-        kind, devs, (admitted, pairs), seq = entry
+        devs, admitted, pairs, seq = entry
         t_read = time.perf_counter()
         with host_span(SPAN_READ_WAIT, seq=seq):
-            first_dev = np.asarray(devs[0])     # waits for the dispatch
+            toks = np.asarray(devs[0])          # waits for the dispatch
         t_got = time.perf_counter()
         self.host_s["read_wait"] += t_got - t_read
         with self._dev_lock:
@@ -1559,7 +1552,8 @@ class PagedBatcher:
                 self._empty_warned = False
         try:
             with host_span(SPAN_HAND_OUT, seq=seq):
-                self._hand_out(kind, devs, first_dev, admitted, pairs)
+                self._count_dispatch(devs[1:])
+                self._hand_out(toks, admitted, pairs)
         finally:
             self.host_s["process"] += time.perf_counter() - t_got
 
@@ -1587,25 +1581,18 @@ class PagedBatcher:
               f"/ {live} live; dispatcher in {self._where}",
               file=sys.stderr, flush=True)
 
-    def _hand_out(self, kind, devs, first_dev, admitted, pairs) -> None:
+    def _hand_out(self, rows, admitted, pairs) -> None:
+        """`rows`: a dispatch's tokens [chunk, B], of either program.  Every
+        pair takes its column; for a request this dispatch admitted the
+        column's first token is its prompt's first token, and its arrival
+        is the request's TTFT."""
         now = time.time()
-        if kind == "fused":
-            firsts = first_dev
-            for row, slot, req in admitted:
-                req.ttft_s = now - req._t0
-                admit = req._admit_t or now
-                req.queue_s = max(admit - req._t0, 0.0)
-                req.prefill_s = max(now - admit, 0.0)
-                req.slot = slot
-                tok = int(firsts[row])
-                self._push_token(req, tok)
-                if self._finished(req, tok):
-                    self._retire(slot, req)
-            rows = np.asarray(devs[1])
-            self._count_dispatch(devs[2:])
-        else:
-            rows = first_dev
-            self._count_dispatch(devs[1:])
+        for slot, req in admitted:
+            req.ttft_s = now - req._t0
+            admit = req._admit_t or now
+            req.queue_s = max(admit - req._t0, 0.0)
+            req.prefill_s = max(now - admit, 0.0)
+            req.slot = slot
         # SLO windows (serve autoscaler): TTFT for this entry's
         # admissions; an inter-token-latency sample from the entry
         # cadence — each entry carries len(rows) decode steps, so
@@ -1613,7 +1600,7 @@ class PagedBatcher:
         # the per-token latency a streaming client observes.
         t_proc = time.time()
         with self._slo_lock:
-            for _, _, req in (admitted or ()):
+            for _, req in admitted:
                 self._ttft_win.append((t_proc, req.ttft_s))
             if pairs:
                 if self._last_entry_t is not None:

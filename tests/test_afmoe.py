@@ -182,7 +182,7 @@ def _engine_logits(cfg, params, prompt, chunk, steps, block=8):
         take = min(chunk, len(prompt) - done)
         toks = jnp.zeros((1, chunk), jnp.int32).at[0, :take].set(
             jnp.asarray(prompt[done:done + take]))
-        caches, first, _ = decoding._paged_prefill_core(
+        caches, first, *_ = decoding._paged_prefill_core(
             params, caches, toks, jnp.asarray([take]), jnp.asarray([done]),
             jnp.asarray([0]), jnp.asarray([True]),
             jnp.asarray([done + take == len(prompt)]), table, cfg,

@@ -320,7 +320,7 @@ class Device:
         while len(packed) < (rows or len(packed)):
             packed.append(([0] * T, 0, 0, 0, False, False, [0] * self.width))
         cols = [jnp.asarray(c) for c in zip(*packed)]
-        self.caches, first, _ = decoding._paged_prefill_core(
+        self.caches, first, *_ = decoding._paged_prefill_core(
             self.params, self.caches, *cols, self.cfg, "reference")
         return {slot: int(first[row]) for slot, row in ends.items()}
 

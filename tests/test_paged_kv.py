@@ -684,9 +684,10 @@ def test_warmup_failure_is_loud_not_a_healthy_looking_engine():
 
 
 def test_paged_decode_matches_full_forward():
-    """One packed prefill + 6 decode steps (paged_prefill_decode_packed,
-    the engine's fused program) == greedy transformer.forward, token for
-    token (the tier-1 CPU reference-path parity check)."""
+    """One packed prefill + 5 decode steps, six tokens a slot
+    (paged_prefill_decode_packed, the engine's fused program) == greedy
+    transformer.forward, token for token (the tier-1 CPU reference-path
+    parity check)."""
     import jax.numpy as jnp
     from ray_tpu.models import decoding
     cfg = _tiny_cfg()
@@ -707,16 +708,15 @@ def test_paged_decode_matches_full_forward():
         packed_p[row, P + 4:P + 4 + W] = np.arange(
             1 + row * W, 1 + (row + 1) * W)
     steps = 6
-    paged, first, toks = decoding.paged_prefill_decode_packed(
+    paged, toks = decoding.paged_prefill_decode_packed(
         params, paged, jnp.asarray(packed_p), cfg, steps, P,
         attn_impl="reference")
-    first, toks = np.asarray(first), np.asarray(toks)
+    toks = np.asarray(toks)
     for row, p in enumerate(prompts):
-        # The prefill's first token, then one per decode step.
-        assert [int(first[row])] + toks[:, row].tolist() == _greedy(
-            params, cfg, p, 1 + steps), p
+        # The prefill's first token, then one per decode step after it.
+        assert toks[:, row].tolist() == _greedy(params, cfg, p, steps), p
     np.testing.assert_array_equal(np.asarray(paged.lengths),
-                                  [len(p) + steps for p in prompts])
+                                  [len(p) + steps - 1 for p in prompts])
 
 
 @pytest.mark.parametrize("arch", ["llama", "gpt2"])

@@ -72,7 +72,7 @@ def test_quantize_params_structure():
 
 def _prefilled(params, prompts, block_size=8, max_len=32):
     """Paged caches holding `prompts`, one per slot, after the serving
-    path's packed prefill and its first decode step."""
+    path's packed prefill, and each prompt's first token."""
     B, P = len(prompts), 8
     W = decoding.paged_table_width(max_len, block_size)
     packed = np.zeros((B + 1, max(P + 4 + W, B)), np.int32)
@@ -81,7 +81,7 @@ def _prefilled(params, prompts, block_size=8, max_len=32):
         packed[row, P:P + 4] = (len(prompt), 0, row, 1)
         packed[row, P + 4:P + 4 + W] = 1 + row * W + np.arange(W)
     caches = decoding.init_paged_caches(CFG, B, B * W, block_size, max_len)
-    caches, _, toks = decoding.paged_prefill_decode_packed(
+    caches, toks = decoding.paged_prefill_decode_packed(
         params, caches, jnp.asarray(packed), CFG, 1, P)
     return caches, toks[0]
 

@@ -61,7 +61,8 @@ def _table(slot, shared=()):
 def _prefill(cfg, params, caches, reqs, width, steps=3):
     """`reqs` [(slot, prompt, positions already in the pool, table)] as the
     engine packs them into rows of `width`, empty rows up to the next of
-    4, 7, 14 and 28 -> (caches', each request's first token, the decode
+    4, 7, 14 and 28 -> (caches', each request's first token, the
+    dispatch's tokens [steps, SLOTS]: the pass's, then the decode
     steps')."""
     rows, closing = [], []
     for slot, toks, done, table in reqs:
@@ -69,17 +70,17 @@ def _prefill(cfg, params, caches, reqs, width, steps=3):
             n = min(width, len(toks) - start)
             rows.append((toks[start:start + n], n, start, slot, 2, table))
         rows[-1] = rows[-1][:4] + (1, table)
-        closing.append(len(rows) - 1)
+        closing.append(slot)
     N = next(n for n in (4, 7, 14, 28) if n >= len(rows))
     packed = np.zeros((N + 1, max(width + 4 + W, SLOTS)), np.int32)
     for r, (toks, n, start, slot, flag, table) in enumerate(rows):
         packed[r, :n] = toks
         packed[r, width:width + 4] = (n, start, slot, flag)
         packed[r, width + 4:width + 4 + W] = table
-    caches, first, toks = decoding.paged_prefill_decode_packed(
+    caches, toks = decoding.paged_prefill_decode_packed(
         params, caches, jnp.asarray(packed), cfg, steps, width,
-        attn_impl="reference")[:3]
-    return caches, [int(first[r]) for r in closing], np.asarray(toks)
+        attn_impl="reference")[:2]
+    return caches, [int(toks[0, s]) for s in closing], np.asarray(toks)
 
 
 def _same_state(a, b):
@@ -99,8 +100,8 @@ def _same_state(a, b):
                                   "ragged_batch", "hit_under_a_tile"])
 def test_tiled_rows_fill_the_pool_as_whole_rows_do(model, case, tile):
     """Rows of one block or two against rows of 32 (one a request): the
-    same first tokens, the same three decode steps of every admitted slot,
-    the same pool, tables, lengths and last tokens."""
+    same first tokens, the same two decode steps of every admitted slot
+    after them, the same pool, tables, lengths and last tokens."""
     cfg, params = model
     lens = {"one_tile": [3], "two_rows_one_slot": [13],
             "two_requests_in_a_rung_not_full": [6, 11],
@@ -125,7 +126,7 @@ def test_tiled_rows_fill_the_pool_as_whole_rows_do(model, case, tile):
     np.testing.assert_array_equal(toks_t[:, live], toks_w[:, live])
     _same_state(tiled, whole)
     assert np.asarray(tiled.lengths)[live].tolist() == [
-        len(r[1]) + 3 for r in reqs]
+        len(r[1]) + 2 for r in reqs]
 
 
 def test_rows_of_one_slot_leave_one_winner(model):
@@ -141,7 +142,7 @@ def test_rows_of_one_slot_leave_one_winner(model):
     rows = np.zeros((3, 8), np.int32)
     for r, s in enumerate((0, 8, 16)):
         rows[r, :len(toks[s:s + 8])] = toks[s:s + 8]
-    new, first, _ = decoding._paged_prefill_core(
+    new, first, *_ = decoding._paged_prefill_core(
         params, caches, jnp.asarray(rows), jnp.asarray([8, 8, 4]), starts,
         jnp.asarray([2, 2, 2]), jnp.ones((3,), bool),
         jnp.asarray([False, False, True]), table, cfg, "reference")
@@ -321,6 +322,7 @@ def test_a_hit_whose_suffix_is_under_a_tile_takes_one_row(model,
     assert seen[0] == (6, [(8, a.slot, 2), (8, a.slot, 2), (8, a.slot, 1)])
     assert st == {"chunks": 2, "chunk_tokens": 24 + 3,
                   "padded_tokens": (6 + 2) * 8, "multi_chunk_requests": 0,
+                  "carried_rows": 0,      # nothing was live at either
                   "rung_dispatches": {"16": 1, "48": 1}}
 
 

@@ -478,3 +478,84 @@ def test_paged_kernel_call_time_on_tpu(cell):
           f"{2 * pages * page_bytes / 1e6:.1f} MB of pages = "
           f"{floor * 1e6:.1f} us at 819 GB/s: {100 * floor / took:.1f} %")
     assert 0 < floor / took < 1.05
+
+
+def test_fused_dispatch_against_decode_only_on_tpu():
+    """Prints (`-s`) and keeps (chiprun_out/pr43/fused_vs_decode.json) what
+    the 256-position fused program takes beside the decode-only one, at
+    Mistral-7B's widths with 16 layers, 32 live slots at the batch cell's
+    contexts and a chunk of 8: the difference is what a prefill of 256
+    positions adds to a dispatch whose first decode step rides in its pass
+    (the two programs walk the weights eight times each)."""
+    import json
+    import os
+    import time
+    from ray_tpu.models import decoding
+    from ray_tpu.models import transformer as tfm
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=32000, d_model=4096, n_layers=16, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq=768, arch="llama",
+        rope_theta=10000.0, norm_eps=1e-5, tie_embeddings=False,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    B, bs, max_len, NB, T, chunk = 32, 16, 768, 1536, 16, 8
+    params = jax.block_until_ready(jax.jit(
+        lambda key: tfm.init_params(cfg, key))(jax.random.PRNGKey(0)))
+    caches = decoding.init_paged_caches(cfg, B, NB, bs, max_len)
+    W = caches.block_tables.shape[1]
+    rng = np.random.RandomState(0)
+    lens = np.exp(rng.uniform(np.log(64), np.log(600), B)).astype(np.int32)
+    held = 40                                   # blocks a live slot holds
+    tables = np.zeros((B, W), np.int32)
+    tables[:, :held] = 1 + np.arange(B * held).reshape(B, held)
+    N = 256 // T
+    packed = np.zeros((N + 1, max(T + 4 + W, B)), np.int32)
+    packed[N, :B] = 1
+    idle = np.array(packed)                     # the same program, no row
+    packed[:N, :T] = rng.randint(1, 32000, (N, T))
+    # one request's 256 positions in blocks of its own, flag 2 throughout:
+    # it closes no slot, so all 32 ride in the pass and no new slot joins
+    # the steps (both programs decode the same 32)
+    packed[:N, T:T + 4] = [[T, r * T, 0, 2] for r in range(N)]
+    packed[:N, T + 4:T + 4 + N] = 1 + B * held + np.arange(N)
+    assert B * held + N <= NB and lens.max() + 2 * chunk <= held * bs
+
+    def fresh():
+        return caches._replace(
+            block_tables=jnp.asarray(tables), lengths=jnp.asarray(lens),
+            last_token=jnp.asarray(rng.randint(1, 32000, B), jnp.int32))
+
+    def timed(call, reps=5):
+        c = fresh()
+        c = call(c)[0]
+        jax.block_until_ready(c)
+        times = []
+        for _ in range(3):
+            c = c._replace(lengths=jnp.asarray(lens))
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                c = call(c)[0]
+                c = c._replace(lengths=jnp.asarray(lens))
+            jax.block_until_ready(c)
+            times.append((time.perf_counter() - t0) / reps)
+        return min(times) * 1e3, c
+
+    active = jnp.ones((B,), bool)
+    decode_ms, caches = timed(lambda c: decoding.paged_decode_steps(
+        params, c, active, cfg, chunk, attn_impl="kernel"))
+    fused_ms, caches = timed(lambda c: decoding.paged_prefill_decode_packed(
+        params, c, jnp.asarray(packed), cfg, chunk, T, attn_impl="kernel"))
+    empty_ms, caches = timed(lambda c: decoding.paged_prefill_decode_packed(
+        params, c, jnp.asarray(idle), cfg, chunk, T, attn_impl="kernel"))
+    out = {"decode_only_ms": decode_ms, "fused_256_ms": fused_ms,
+           "fused_256_no_row_ms": empty_ms,
+           "prefill_adds_ms": fused_ms - decode_ms, "live_slots": B,
+           "decode_chunk": chunk, "mean_context": float(lens.mean()),
+           "device": jax.devices()[0].device_kind}
+    print(f"\nfused 256 {fused_ms:.2f} ms, decode-only {decode_ms:.2f} ms: "
+          f"+{fused_ms - decode_ms:.2f} ms ({json.dumps(out)})")
+    os.makedirs("chiprun_out/pr43", exist_ok=True)
+    with open("chiprun_out/pr43/fused_vs_decode.json", "w") as f:
+        json.dump(out, f)
+    # eight walks each: the prefill may add its own products, never a step
+    assert decode_ms < fused_ms < decode_ms + 3 * decode_ms / chunk
