@@ -45,10 +45,11 @@ from ray_tpu.models.transformer import (TransformerConfig, _norm, _rope,
 from ray_tpu.ops.grouped_ffn import grouped_ffn
 
 # What one expert layer counts per call (a decode step or a prefill chunk):
-# calls, (token, pick) rows routed, the largest expert's rows, experts with
-# at least one row.
+# calls, (token, pick) rows routed to an expert held here, the largest
+# expert's rows, experts with at least one row, and the (token, pick) rows
+# of valid tokens whose expert is not held here (0 where every expert is).
 MOE_COUNTS = ("layer_steps", "routed_rows", "busiest_expert_rows",
-              "experts_touched")
+              "experts_touched", "absent_rows")
 
 
 def no_counts() -> jax.Array:
@@ -182,8 +183,8 @@ def _ffn(m, w_gate, w_up, w_down):
 def route(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array
           ) -> Tuple[jax.Array, jax.Array]:
     """m [T, D] -> (expert ids [T, k] int32, weights [T, k] float32).
-    Scores in float32 at full precision; the bias only selects; ties as
-    `jax.lax.top_k` breaks them."""
+    Scores in float32 at full precision; the bias (where the layer has
+    one) only selects; ties as `jax.lax.top_k` breaks them."""
     with jax.named_scope("moe_route"):
         s = jax.nn.sigmoid(jnp.einsum(
             "td,de->te", m.astype(jnp.float32),
@@ -194,8 +195,10 @@ def route(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array
             info = jnp.finfo(cfg.moe_score_dtype)
             s = jax.lax.reduce_precision(
                 s, exponent_bits=info.nexp, mantissa_bits=info.nmant)
-        _, idx = jax.lax.top_k(s + p["route_bias"].astype(jnp.float32),
-                               cfg.moe_top_k)
+        biased = s
+        if "route_bias" in p:
+            biased = s + p["route_bias"].astype(jnp.float32)
+        _, idx = jax.lax.top_k(biased, cfg.moe_top_k)
         picked = jnp.take_along_axis(s, idx, axis=1)
         total = jnp.sum(picked, axis=1, keepdims=True)
         if cfg.moe_route_eps:       # 0: not in the program at all
@@ -210,7 +213,15 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
     """m [B, S, D], valid [B, S] (None: every row) -> (y, MOE_COUNTS).
     Rows that are not valid are routed nowhere and are not counted.
     `tap`, if given, is shown the picks [B * S, k] (a comparison's way to
-    see them; the serving path passes none)."""
+    see them; the serving path passes none).
+
+    A layer that holds a share of its experts (`cfg.moe_router_width`: the
+    weights here are experts `moe_experts_first` .. + `moe_experts` - 1 of
+    that many) routes, picks and normalises over the router's whole width;
+    a (token, pick) pair whose expert is not held is routed nowhere, as an
+    invalid row is, and counted as absent.  What comes back is the held
+    experts' part of the layer's output plus the shared expert's, which is
+    computed for every token."""
     B, S, D = m.shape
     m2 = m.reshape(B * S, D)
     ok = (jnp.ones((B * S,), bool) if valid is None
@@ -218,6 +229,12 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
     idx, w = route(cfg, p, m2)
     if tap is not None:
         tap(idx)
+    absent = jnp.zeros((), jnp.int32)
+    if cfg.moe_router_width:
+        local = idx - cfg.moe_experts_first
+        held = (local >= 0) & (local < cfg.moe_experts)
+        absent = jnp.sum((ok[:, None] & ~held).astype(jnp.int32))
+        idx, ok = jnp.clip(local, 0, cfg.moe_experts - 1), ok[:, None] & held
     y, sizes = grouped_ffn(m2, idx, w, ok, p["w_gate"], p["w_up"],
                            p["w_down"], name=name)
     y = y.reshape(B, S, D)
@@ -225,7 +242,7 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
         y = y + _ffn(m, p["ws_gate"], p["ws_up"], p["ws_down"])
     counts = jnp.stack([jnp.ones((), jnp.int32), jnp.sum(sizes),
                         jnp.max(sizes),
-                        jnp.sum((sizes > 0).astype(jnp.int32))])
+                        jnp.sum((sizes > 0).astype(jnp.int32)), absent])
     return y, counts
 
 

@@ -32,7 +32,8 @@ tests hold every step to greedy transformer.forward.
 
 Architectures of unrolled layers (`cfg.layer_kinds`: models/afmoe.py,
 window and full attention layers mixed; models/lfm2.py, short convolutions
-and attention layers mixed; both with expert layers) have their paged
+and attention layers mixed; models/axk1.py, latent attention whose cache
+row has no head axis; all with expert layers) have their paged
 steps at the end of this file, built from their module's one layer
 definition; the public entry points (paged_prefill_decode_packed,
 paged_decode_steps, paged_decode_step) branch to them and return the
@@ -108,7 +109,9 @@ class PagedDecodeCaches(NamedTuple):
     kp: jax.Array            # [L, NB, Hkv, bs, Dh] block pool — (bs, Dh)
     vp: jax.Array            # minor: the tile the paged kernel loads
     # (unrolled layers: a tuple of L pools, each its own buffer, written in
-    # place: `unrolled_pool_shape`; None at a layer that has no keys)
+    # place: `unrolled_pool_shape`; None at a layer that has no keys; a
+    # latent layer has ONE pool, `kp`, of rows with no head axis, and its
+    # `vp` is None)
     block_tables: jax.Array  # [B, W] int32 — physical block per logical
     lengths: jax.Array       # [B] int32 — tokens currently cached
     last_token: jax.Array    # [B] int32 — input to the next decode step
@@ -134,13 +137,23 @@ def paged_table_width(max_len: int, block_size: int) -> int:
 
 
 def unrolled_pool_shape(cfg: TransformerConfig, num_blocks: int,
-                        block_size: int) -> Tuple[int, ...]:
+                        block_size: int, mixer: str = "full"
+                        ) -> Tuple[int, ...]:
     """One unrolled attention layer's K (or V) pool, scratch block
     included.  Heads narrower than the 128 lanes lie side by side in one
     row of lanes where they fill it whole ([NB, Hkv / f, bs, f * Dh], f =
     128 / Dh): the paged kernels copy pages out of an HBM pool only at
     whole rows of lanes, and tell the layout from the shapes
-    (ops/paged_attention.py)."""
+    (ops/paged_attention.py).  A "latent" layer's one pool holds a row a
+    position with no head axis, kv_lora_rank + qk_rope_dim values in whole
+    rows of lanes ([NB, 1, bs, 640] for 576: a tenth more cache and reads
+    than the model needs, for ONE page stream and a value that is the key's
+    own lanes; the other layout, the latent and the rotated part in pools of
+    their own, is two streams and two writes a layer)."""
+    if mixer == "latent":
+        from ray_tpu.ops.paged_attention import latent_lanes
+        return (num_blocks + 1, 1, block_size,
+                latent_lanes(cfg.kv_lora_rank + cfg.qk_rope_dim))
     dh, hkv = cfg.head_dim, cfg.kv_heads
     f = 128 // dh if dh < 128 and 128 % dh == 0 else 1
     if hkv % f:
@@ -159,8 +172,8 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
                       max_len: int) -> PagedDecodeCaches:
     """`num_blocks` USABLE blocks; one extra scratch block (id 0) is
     added internally, so pool ids run 0..num_blocks inclusive.  Unrolled
-    layers get the state their mixer has: keys and values, or a conv's
-    tails."""
+    layers get the state their mixer has: keys and values, a latent
+    layer's one pool of rows, or a conv's tails."""
     w = paged_table_width(max_len, block_size)
     state = {}
     if cfg.layer_kinds is None:
@@ -170,10 +183,11 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
                      vp=jnp.zeros(shape, cfg.dtype))
     else:
         conv = [m == "conv" for m, _ in cfg.layer_kinds]
-        shape = unrolled_pool_shape(cfg, num_blocks, block_size)
-        for name in ("kp", "vp"):
-            state[name] = tuple(None if c else jnp.zeros(shape, cfg.dtype)
-                                for c in conv)
+        for name, none in (("kp", ("conv",)), ("vp", ("conv", "latent"))):
+            state[name] = tuple(
+                None if m in none else jnp.zeros(unrolled_pool_shape(
+                    cfg, num_blocks, block_size, m), cfg.dtype)
+                for m, _ in cfg.layer_kinds)
         if any(conv):
             k1, d = cfg.conv_kernel - 1, cfg.d_model
             for name, shape in (("tail_pool", (num_blocks + 1, k1 * d)),
@@ -304,22 +318,30 @@ def _query_groups(tables, prefix_lens, suffix_lens, valid, slots,
         back=jnp.clip(group * K + place, 0, R * K - 1))
 
 
+def _pools(k_pool, v_pool, plain, latent):
+    """The attention function of a layer's state and the pools it takes:
+    keys and values, or a latent layer's one pool (`v_pool` None)."""
+    if v_pool is None:
+        return latent, (k_pool,)
+    return plain, (k_pool, v_pool)
+
+
 def _attend_rows(q, k_pool, v_pool, rows: PrefillRows, first_block=0,
                  **kw):
     """prefix_attention of a prefill's queries [N, P, H, D], in the rows'
     groups where they have any; `first_block` is added to the tables (a
     layer's pool inside the stacked one)."""
     from ray_tpu.ops import paged_attention as _pa
+    attend, pools = _pools(k_pool, v_pool, _pa.prefix_attention,
+                           _pa.mla_prefix_attention)
     g = rows.groups
     if g is None:
-        return _pa.prefix_attention(q, k_pool, v_pool,
-                                    first_block + rows.tables,
-                                    rows.prefix_lens, rows.suffix_lens, **kw)
+        return attend(q, *pools, first_block + rows.tables,
+                      rows.prefix_lens, rows.suffix_lens, **kw)
     (R, K), (N, P, H, D) = g.take.shape, q.shape
-    o = _pa.prefix_attention(q[g.take].reshape(R, K * P, H, D), k_pool,
-                             v_pool, first_block + g.tables, g.prefix_lens,
-                             g.suffix_lens, **kw)
-    return o.reshape(R * K, P, H, D)[g.back]
+    o = attend(q[g.take].reshape(R, K * P, H, D), *pools,
+               first_block + g.tables, g.prefix_lens, g.suffix_lens, **kw)
+    return o.reshape(R * K, P, H, -1)[g.back]
 
 
 def decode_rows(tables, lengths, active, block_size: int) -> DecodeRows:
@@ -358,10 +380,12 @@ def _attend_pass(q, k_pool, v_pool, rows: PrefillRows,
     from ray_tpu.ops import paged_attention as _pa
     (N, P), (H, D) = rows.positions.shape, q.shape[2:]
     o = _attend_rows(q[0, :N * P].reshape(N, P, H, D), k_pool, v_pool, rows,
-                     first_block, **kw).reshape(N * P, H, D)
+                     first_block, **kw).reshape(N * P, H, -1)
     if step is not None:
-        o = jnp.concatenate([o, _pa.paged_attention(
-            q[0, N * P:], k_pool, v_pool, first_block + step.tables,
+        attend, pools = _pools(k_pool, v_pool, _pa.paged_attention,
+                               _pa.mla_paged_attention)
+        o = jnp.concatenate([o, attend(
+            q[0, N * P:], *pools, first_block + step.tables,
             step.context_lens, **kw).astype(o.dtype)])
     return o[None]
 
@@ -380,6 +404,14 @@ def _write_rows(pool, blocks, offsets, new):
           + offsets[..., None]).reshape(-1)
     return pool.reshape(NB * hkv * bs, D).at[at].set(
         new.reshape(-1, D).astype(pool.dtype)).reshape(pool.shape)
+
+
+def _write_latent(pool, blocks, offsets, rows):
+    """A latent layer's ONE write: rows [..., 1, c + r] into pool [NB, 1,
+    bs, Dp] at (blocks, offsets), the lanes past c + r zero."""
+    from ray_tpu.ops.paged_attention import to_lanes
+    with jax.named_scope("mla_kv"):
+        return _write_rows(pool, blocks, offsets, to_lanes(rows, pool))
 
 
 def _scan_layers(layer, x, layers, caches: PagedDecodeCaches):
@@ -671,7 +703,8 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
 # ===========================================================================
 # The layers are unrolled (their kinds differ in shape) and each has state
 # of its own, a pair of arrays: an attention layer's K and V pools, a conv
-# layer's block tails and slot tails (PagedDecodeCaches).
+# layer's block tails and slot tails, a latent layer's one pool of rows and
+# None (PagedDecodeCaches).
 # `paged_prefill_layer` and `paged_decode_layer` are what the engine's
 # dispatches are made of, one layer at a time: a caller that cannot hold
 # every layer's weights at once (the benchmark's comparison with the plain
@@ -709,6 +742,12 @@ def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
         return _attend_pass(q, kp, vp, rows, step, impl=attn_impl,
                             window=model.window_of(cfg, kind))
 
+    def attend_latent(q, row):
+        kp = _write_latent(k_pool, blocks, offsets, row[0])
+        state.extend((kp, None))
+        return _attend_pass(q, kp, None, rows, step, impl=attn_impl,
+                            **model.latent_kw(cfg))
+
     def before(u):
         K1 = v_pool.shape[1]
         up = u[0, :N * P].reshape(N, P, D)
@@ -735,10 +774,10 @@ def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
         state.extend((tails, slot))
         return prev
 
+    mix = {"conv": before, "latent": attend_latent}.get(kind[0], attend)
     y, counts = model.layer(cfg, kind, p, x.reshape(1, -1, D), positions,
-                            before if kind[0] == "conv" else attend,
-                            valid=valid, moe_name="moe_experts_prefill",
-                            tap=tap)
+                            mix, valid=valid,
+                            moe_name="moe_experts_prefill", tap=tap)
     return y.reshape(x.shape), state[0], state[1], counts
 
 
@@ -760,6 +799,13 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
             q[:, 0], kp, vp, rows.tables, rows.context_lens,
             impl=attn_impl, window=model.window_of(cfg, kind))[:, None]
 
+    def attend_latent(q, row):
+        kp = _write_latent(k_pool, rows.blocks, rows.offsets, row[:, 0])
+        state.extend((kp, None))
+        return _pa.mla_paged_attention(
+            q[:, 0], kp, rows.tables, rows.context_lens, impl=attn_impl,
+            **model.latent_kw(cfg))[:, None]
+
     def before(u):
         moved = jnp.concatenate([v_pool[:, 1:], u], axis=1)
         state.extend((k_pool.at[rows.tail_blocks].set(
@@ -767,8 +813,8 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
             jnp.where(rows.active[:, None, None], moved, v_pool)))
         return model.taps(v_pool, u)
 
-    x, counts = model.layer(cfg, kind, p, x, rows.positions,
-                            before if kind[0] == "conv" else attend,
+    mix = {"conv": before, "latent": attend_latent}.get(kind[0], attend)
+    x, counts = model.layer(cfg, kind, p, x, rows.positions, mix,
                             valid=rows.active[:, None],
                             moe_name="moe_experts_decode", tap=tap)
     return x, state[0], state[1], counts
@@ -781,7 +827,7 @@ def _unrolled_layers(cfg, params, caches, x, rows, layer_fn, attn_impl):
              for f in ("kp", "vp", "tail_pool", "slot_tail")}
     counts = unrolled(cfg).no_counts()
     for i, (kind, p) in enumerate(zip(cfg.layer_kinds, params["layers"])):
-        a, b = (("tail_pool", "slot_tail") if caches.kp[i] is None
+        a, b = (("tail_pool", "slot_tail") if kind[0] == "conv"
                 else ("kp", "vp"))
         x, state[a][i], state[b][i], c = layer_fn(
             cfg, kind, p, x, state[a][i], state[b][i], rows, attn_impl)

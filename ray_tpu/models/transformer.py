@@ -43,7 +43,8 @@ class TransformerConfig:
     d_ff: Optional[int] = None            # None => arch default
     max_seq: int = 2048
     arch: str = "llama"                   # "llama" | "gpt2" | a module of
-    # unrolled layers under models/ ("afmoe", "lfm2": see `layer_kinds`)
+    # unrolled layers under models/ ("afmoe", "lfm2", "axk1": see
+    # `layer_kinds`)
     rope_theta: float = 500_000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16             # activation/compute dtype
@@ -91,6 +92,33 @@ class TransformerConfig:
     # over `conv_kernel` positions, whose state is its input at the last
     # conv_kernel - 1 of them.
     conv_kernel: int = 3
+    # arch "axk1" (models/axk1.py; serving and `forward` only): mixer
+    # "latent", multi-head latent attention.  Queries go down to
+    # `q_lora_rank` and up to `n_heads` x (`qk_nope_dim` + `qk_rope_dim`);
+    # keys and values come from ONE row per position of `kv_lora_rank`
+    # latent values + `qk_rope_dim` rotated ones, shared by every head, which
+    # is all a position leaves in the cache; a head's value is `v_head_dim`
+    # wide.  The rotary embedding is yarn's over `qk_rope_dim`:
+    # `rope_factor` 1 leaves the plain frequencies.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # An expert layer that holds a SHARE of its experts (expert parallelism
+    # seen from one chip): the router scores `moe_router_width` experts (0:
+    # `moe_experts`, every expert is here) and picks and normalises over all
+    # of them; the `moe_experts` whose weights are here are experts
+    # `moe_experts_first` .. + `moe_experts` - 1, and a pick outside them is
+    # routed nowhere (ops/grouped_ffn.py, models/afmoe.py `experts`).
+    moe_router_width: int = 0
+    moe_experts_first: int = 0
 
     def __post_init__(self):
         if self.layer_kinds is not None:
@@ -113,6 +141,10 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def router_width(self) -> int:
+        return self.moe_router_width or self.moe_experts
 
     @property
     def ff_dim(self) -> int:

@@ -177,16 +177,17 @@ def paged_attention_reference(q: jax.Array, k_pool: jax.Array,
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
-def _ring_shape(W, hkv, bs, D, itemsize):
+def _ring_shape(W, hkv, bs, D, itemsize, pools=2):
     """(pages, depth, tiles) of the decode kernel's page stream, from static
-    shapes.  `pages` a group: enough for one lane-wide (G, 128) score tile
-    per kv head, no more than the table has, and two groups (k and v)
-    inside the VMEM budget.  `depth` groups in the ring: the largest power
+    shapes (`pools` 1: a latent pool, whose values are its keys' lanes).
+    `pages` a group: enough for one lane-wide (G, 128) score tile per kv
+    head, no more than the table has, and two groups (k and v) inside the
+    VMEM budget.  `depth` groups in the ring: the largest power
     of two (the kernel wraps a slot index with a mask), up to
     `_RING_GROUPS`, that the budget holds.  `tiles` groups
     one softmax update spans where a context has them: a quarter of the
     ring, so that three quarters stay in flight under the arithmetic."""
-    page = 2 * hkv * bs * D * itemsize          # its k and its v
+    page = pools * hkv * bs * D * itemsize      # its k and its v
     pages = max(1, min(_GROUP_POSITIONS // bs, W))
     while pages > 1 and 2 * pages * page > _VMEM_BUDGET:
         pages //= 2
@@ -197,7 +198,7 @@ def _ring_shape(W, hkv, bs, D, itemsize):
 
 
 def _attend_group(q, k, v, first_pos, ctx, scale, m_ref, l_ref, acc_ref,
-                  lo=None):
+                  lo=None, pv_in=jnp.float32):
     """Online-softmax update with one group of cached positions.
     q: (Hkv, G, D); k, v: (Hkv, span, D), position `first_pos` first;
     positions in [lo, ctx) are attended (lo None: from the first).
@@ -209,7 +210,7 @@ def _attend_group(q, k, v, first_pos, ctx, scale, m_ref, l_ref, acc_ref,
     s = _scores(q, k) * scale
     kpos = first_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     seen = kpos < ctx if lo is None else (kpos < ctx) & (kpos >= lo)
-    _softmax_update(s, seen, v, m_ref, l_ref, acc_ref)
+    _softmax_update(s, seen, v, m_ref, l_ref, acc_ref, pv_in=pv_in)
 
 
 def _scores(q, k):
@@ -219,8 +220,10 @@ def _scores(q, k):
                            preferred_element_type=jnp.float32)
 
 
-def _softmax_update(s, seen, v, m_ref, l_ref, acc_ref, rows_differ=False):
-    """s: (Hkv, R, span) f32 scores, `seen` which of them count.  Where
+def _softmax_update(s, seen, v, m_ref, l_ref, acc_ref, rows_differ=False,
+                    pv_in=jnp.float32):
+    """s: (Hkv, R, span) f32 scores, `seen` which of them count; `pv_in`
+    the type p and v meet in (accumulated in f32 either way).  Where
     every row sees something in the first group it meets (one query a
     sequence), a masked score's exp(NEG_INF - m) is 0 by itself; with
     `rows_differ` (a chunk's queries, each with its own causal and window
@@ -235,8 +238,10 @@ def _softmax_update(s, seen, v, m_ref, l_ref, acc_ref, rows_differ=False):
         p = jnp.where(seen, p, 0.0)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
     m_ref[...] = m_new
+    if pv_in != jnp.float32:
+        p = p.astype(pv_in)
     acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
-        p, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        p, v.astype(pv_in), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)         # hgt,htd->hgd
 
 
@@ -330,10 +335,15 @@ def _group_heads(buf, slot):
                        (hkv, pages * bs, D))
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sem, ring, m_ref, l_ref, acc_ref, *,
-                  scale, block_size, pages, tiles, window):
-    """One sequence: every kv head, groups of `pages` pages.
+def _paged_kernel(bt_ref, len_ref, q_ref, *refs, scale, block_size, pages,
+                  tiles, window, v_lanes=None):
+    """One sequence: every kv head, groups of `pages` pages.  `refs`: the
+    HBM pools, the output, a page-major buffer a pool, then the DMA
+    semaphores, the ring's state and the softmax state.  Two pools are keys
+    and values; ONE is a latent pool (`v_lanes`): the values are the first
+    `v_lanes` lanes of the keys' own buffer, so nothing is copied twice, and
+    p and v meet in the pool's type (64 query heads share every position: the
+    product is as large as the scores').
 
     The grid is the batch, run in order, and the (sequence, group) pairs
     form ONE stream through a ring of VMEM slots (`ring`, in SMEM, holds
@@ -356,9 +366,13 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     is masked."""
     from jax.experimental import pallas as pl
 
+    np_ = (len(refs) - 6) // 2                # pools
+    pools, o_ref, bufs = refs[:np_], refs[np_], refs[np_ + 1:2 * np_ + 1]
+    sem, ring, m_ref, l_ref, acc_ref = refs[2 * np_ + 1:]
+    pv_in = jnp.float32 if v_lanes is None else bufs[0].dtype
     b = pl.program_id(0)
     rows = pl.num_programs(0)
-    depth = k_buf.shape[0]
+    depth = bufs[0].shape[0]
     span = pages * block_size                 # positions per group
     ctx = len_ref[b]
 
@@ -374,8 +388,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         first = lax.mul(group, pages)
         live = lax.clamp(0, lax.sub(_cdiv(len_ref[row], block_size), first),
                          pages)
-        _copy_group(bt_ref, row, first, live, (k_hbm, v_hbm),
-                    (k_buf, v_buf), sem, slot, wait)
+        _copy_group(bt_ref, row, first, live, pools, bufs, sem, slot, wait)
 
     def live_row(row):
         """The first row from `row` on that has anything cached."""
@@ -405,7 +418,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         # Page slots a partly live group leaves unfilled are read under
         # the mask with p == 0: they must hold numbers (0 * NaN), which
         # fresh VMEM need not.  Later they hold older pool pages.
-        v_buf[...] = jnp.zeros_like(v_buf)
+        bufs[-1][...] = jnp.zeros_like(bufs[-1])
         row = live_row(jnp.int32(0))
         ring[0] = row
         ring[1] = first_group(lax.min(row, lax.sub(rows, 1)))
@@ -422,13 +435,15 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             slots = [lax.bitwise_and(lax.add(first, t), depth - 1)
                      for t in range(n)]
             for slot in slots[:-1]:     # all but a sequence's last are full
-                _wait_group((k_buf, v_buf), sem, slot)
+                _wait_group(bufs, sem, slot)
             copy_group(b, lax.add(g, n - 1), slots[-1], wait=True)
-            k, v = (jnp.concatenate([_group_heads(buf, slot)
+            mats = [jnp.concatenate([_group_heads(buf, slot)
                                      for slot in slots], axis=1)
-                    for buf in (k_buf, v_buf))
+                    for buf in bufs]
+            k = mats[0]
+            v = mats[-1] if v_lanes is None else k[..., :v_lanes]
             _attend_group(q_ref[0], k, v, lax.mul(g, span), ctx, scale,
-                          m_ref, l_ref, acc_ref, lo)
+                          m_ref, l_ref, acc_ref, lo, pv_in)
             ring[3] = lax.bitwise_and(lax.add(first, n), depth - 1)
             ring[4] = n
             return lax.add(g, n)
@@ -678,16 +693,21 @@ def prefix_attention_reference(q, k_pool, v_pool, block_tables,
     return o.reshape(N, P, H, D).astype(q.dtype)
 
 
-def _prefix_kernel(bt_ref, pre_ref, suf_ref, qoff_ref, q_ref, k_hbm, v_hbm,
-                   o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref, *,
-                   scale, block_size, pages, tq, window):
+def _prefix_kernel(bt_ref, pre_ref, suf_ref, qoff_ref, q_ref, *refs,
+                   scale, block_size, pages, tq, window, v_lanes=None):
     """One (sequence, tile of `tq` queries) program: every kv head, the
     tile's queries of every head of the group as rows (query-major), a
     group of `pages` pages at a time from the first position any of the
     tile's live queries may see to the last, double-buffered.  A tile
-    with no live query does nothing."""
+    with no live query does nothing.  `refs`: the HBM pools, the output, a
+    buffer a pool, the semaphores and the softmax state; ONE pool is a
+    latent pool, as in `_paged_kernel`."""
     from jax.experimental import pallas as pl
 
+    np_ = (len(refs) - 5) // 2                # pools
+    pools, o_ref, bufs = refs[:np_], refs[np_], refs[np_ + 1:2 * np_ + 1]
+    sem, m_ref, l_ref, acc_ref = refs[2 * np_ + 1:]
+    pv_in = jnp.float32 if v_lanes is None else bufs[0].dtype
     n, t = pl.program_id(0), pl.program_id(1)
     span = pages * block_size
     pre = pre_ref[n]
@@ -702,14 +722,13 @@ def _prefix_kernel(bt_ref, pre_ref, suf_ref, qoff_ref, q_ref, k_hbm, v_hbm,
     def copy_group(group, slot, wait=False):
         first = lax.mul(group, pages)
         live = lax.clamp(0, lax.sub(_cdiv(hi, block_size), first), pages)
-        _copy_group(bt_ref, n, first, live, (k_hbm, v_hbm),
-                    (k_buf, v_buf), sem, slot, wait)
+        _copy_group(bt_ref, n, first, live, pools, bufs, sem, slot, wait)
 
     @pl.when(lax.bitwise_and(lax.eq(n, 0), lax.eq(t, 0)))
     def _first():
         # Page slots a partly live group leaves unfilled are read under
         # the mask with p == 0: they must hold numbers, not NaN.
-        v_buf[...] = jnp.zeros_like(v_buf)
+        bufs[-1][...] = jnp.zeros_like(bufs[-1])
 
     _reset(m_ref, l_ref, acc_ref)
     q = q_ref[0]                              # (Hkv, tq * G, D)
@@ -728,13 +747,16 @@ def _prefix_kernel(bt_ref, pre_ref, suf_ref, qoff_ref, q_ref, k_hbm, v_hbm,
         @pl.when(lax.ge(i, 0))
         def _():
             copy_group(g, slot, wait=True)
-            s = _scores(q, _group_heads(k_buf, slot)) * scale
+            k = _group_heads(bufs[0], slot)
+            s = _scores(q, k) * scale
             kpos = g * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
             seen = (kpos <= qpos[None]) & (kpos < hi)
             if window is not None:
                 seen &= kpos > qpos[None] - window
-            _softmax_update(s, seen, _group_heads(v_buf, slot), m_ref,
-                            l_ref, acc_ref, rows_differ=True)
+            v = (_group_heads(bufs[1], slot) if v_lanes is None
+                 else k[..., :v_lanes])
+            _softmax_update(s, seen, v, m_ref, l_ref, acc_ref,
+                            rows_differ=True, pv_in=pv_in)
 
         return carry
 
@@ -831,3 +853,251 @@ def prefix_attention(q, k_pool, v_pool, block_tables, prefix_lens,
     if impl not in ("auto", "reference"):
         raise ValueError(f"unknown prefix attention impl {impl!r}")
     return prefix_attention_reference(*args)
+
+
+# ===========================================================================
+# Latent rows (MLA, absorbed): ONE pool, no head axis
+# ===========================================================================
+# A position of a latent-attention layer leaves one row behind: c values of
+# compressed keys-and-values and r rotated key values that every head shares
+# (models/axk1.py).  In the absorbed form every one of the H query heads
+# scores its [c + r] query against that row, and what it gets back is the
+# softmax-weighted sum of the rows' first c values: multi-query attention
+# over ONE key head whose value is the key's first `v_dim` lanes.
+#
+# pool:  [NB, 1, bs, Dp]   a row in Dp >= c + r lanes, Dp whole 128-lane
+#                          rows (576 values lie in 640: Mosaic copies a page
+#                          out of an HBM pool only at whole rows of lanes),
+#                          the lanes past c + r zero
+# q:     [.., H, c + r]    (padded with zeros to Dp here)
+# -> out [.., H, v_dim]
+#
+# The kernels are `_paged_kernel` and `_prefix_kernel` with one pool: the
+# same page stream, ring and `_copy_group`, one DMA a page, and p . v taken
+# from the buffer the scores were taken from.  `scale` is the caller's (the
+# model's: it is not the row's width to the -1/2).
+def latent_lanes(width: int) -> int:
+    """The lanes a latent row of `width` values takes in the pool."""
+    return -(-width // _LANES) * _LANES
+
+
+def _validate_latent(q, pool, v_dim):
+    if pool.ndim != 4 or pool.shape[1] != 1 or pool.shape[3] < q.shape[-1] \
+            or not 0 < v_dim <= pool.shape[3]:
+        raise ValueError(
+            f"latent attention: the pool must be [NB, 1, bs, >= "
+            f"{q.shape[-1]}] and hold {v_dim} value lanes, got {pool.shape}")
+
+
+def to_lanes(x, pool):
+    """x [..., width] (a query, or rows to write) with zeros up to the
+    pool's lanes."""
+    pad = pool.shape[3] - x.shape[-1]
+    return x if pad == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def mla_paged_attention_reference(q, pool, block_tables, context_lens, *,
+                                  scale: float, v_dim: int) -> jax.Array:
+    """Gather-based (any backend): q [B, H, Dq] over each sequence's rows."""
+    B, H, _ = q.shape
+    bs, Dp = pool.shape[2], pool.shape[3]
+    M = block_tables.shape[1] * bs
+    k = jnp.take(pool[:, 0], block_tables, axis=0).reshape(
+        B, M, Dp).astype(jnp.float32)
+    s = jnp.einsum("bhd,bmd->bhm", to_lanes(q, pool).astype(jnp.float32),
+                   k) * scale
+    mask = (jnp.arange(M)[None, :] < context_lens[:, None])[:, None]
+    w = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    w = jnp.where(mask, w, 0.0)             # a zero-length row -> zeros
+    return jnp.einsum("bhm,bmc->bhc", w, k[..., :v_dim]).astype(q.dtype)
+
+
+def mla_prefix_attention_reference(q, pool, block_tables, prefix_lens,
+                                   suffix_lens, *, scale: float,
+                                   v_dim: int) -> jax.Array:
+    """Gather the whole table window and mask by position (small sizes: the
+    scores are [N, H, P, W * bs] float32)."""
+    N, P, H, _ = q.shape
+    bs, Dp = pool.shape[2], pool.shape[3]
+    M = block_tables.shape[1] * bs
+    k = jnp.take(pool[:, 0], block_tables, axis=0).reshape(
+        N, M, Dp).astype(jnp.float32)
+    s = jnp.einsum("nphd,nmd->nhpm", to_lanes(q, pool).astype(jnp.float32),
+                   k) * scale
+    qpos = prefix_lens[:, None] + jnp.arange(P)[None, :]        # [N, P]
+    kpos = jnp.arange(M)[None, None, :]
+    seen = ((kpos <= qpos[..., None])
+            & (kpos < (prefix_lens + suffix_lens)[:, None, None]))[:, None]
+    w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    w = jnp.where(seen, w, 0.0)             # a dead query row -> zeros
+    return jnp.einsum("nhpm,nmc->nphc", w, k[..., :v_dim]).astype(q.dtype)
+
+
+def _mla_paged_fwd(q, pool, block_tables, context_lens, *, scale, v_dim,
+                   interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, _ = q.shape
+    bs, Dp = pool.shape[2], pool.shape[3]
+    W = block_tables.shape[1]
+    if Dp % _LANES or v_dim % _LANES or bs % _SUBLANES:
+        raise ValueError(
+            f"latent paged attention kernel: pool rows of {Dp} lanes, values "
+            f"of {v_dim}, blocks of {bs}: whole 128-lane rows and whole "
+            f"{_SUBLANES}-row tiles only")
+    gp = -(-H // _SUBLANES) * _SUBLANES
+    pages, depth, tiles = _ring_shape(W, 1, bs, Dp, pool.dtype.itemsize,
+                                      pools=1)
+    qg = to_lanes(q, pool).astype(jnp.promote_types(q.dtype, pool.dtype))[
+        :, None]                                            # [B, 1, H, Dp]
+    if gp != H:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - H), (0, 0)))
+
+    def q_index(b, *_):
+        return (b, 0, 0, 0)
+
+    context_lens = jnp.minimum(context_lens.astype(jnp.int32), W * bs)
+    o = pl.pallas_call(
+        functools.partial(_paged_kernel, scale=scale, block_size=bs,
+                          pages=pages, tiles=tiles, window=None,
+                          v_lanes=v_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[pl.BlockSpec((1, 1, gp, Dp), q_index),
+                      pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+            out_specs=pl.BlockSpec((1, 1, gp, v_dim), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((depth, pages, 1, bs, Dp), pool.dtype),
+                pltpu.SemaphoreType.DMA((1, depth)),
+                pltpu.SMEM((5,), jnp.int32),
+                pltpu.VMEM((1, gp, 1), jnp.float32),
+                pltpu.VMEM((1, gp, 1), jnp.float32),
+                pltpu.VMEM((1, gp, v_dim), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, gp, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="mla_paged_attention",
+    )(block_tables.astype(jnp.int32), context_lens, qg, pool)
+    return o[:, 0, :H]
+
+
+# Query rows (queries x heads) one program of the latent prefix kernel
+# holds: with 64 heads a query, 16 queries.  A suffix of a few tens of
+# tokens then pays for few dead queries' arithmetic (139 kFLOP a query and
+# position, against 1.3 KB read), at the price of streaming the prefix once
+# a tile.
+_LATENT_PREFIX_ROWS = 1024
+
+
+def _mla_prefix_fwd(q, pool, block_tables, prefix_lens, suffix_lens, *,
+                    scale, v_dim, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, P, H, _ = q.shape
+    bs, Dp = pool.shape[2], pool.shape[3]
+    W = block_tables.shape[1]
+    if Dp % _LANES or v_dim % _LANES:
+        raise ValueError(
+            f"latent prefix attention kernel: pool rows of {Dp} lanes, "
+            f"values of {v_dim}: whole 128-lane rows only")
+    tq = min(P, max(_SUBLANES, _LATENT_PREFIX_ROWS // H))
+    if P % tq:
+        raise ValueError(f"latent prefix attention kernel: a chunk of {P} "
+                         f"queries must be a multiple of {tq}")
+    pages = max(1, min(_PREFIX_SPAN // bs, W))
+    rows = tq * H
+    # query-major rows: row r of a tile is query r // H, head r % H
+    qg = to_lanes(q, pool).reshape(N, 1, P * H, Dp).astype(
+        jnp.promote_types(q.dtype, pool.dtype))
+    qoff = (jnp.arange(rows, dtype=jnp.int32) // H)[:, None]
+
+    def q_index(n, t, *_):
+        return (n, 0, t, 0)
+
+    hi_all = jnp.minimum(prefix_lens + suffix_lens, W * bs).astype(jnp.int32)
+    prefix_lens = jnp.minimum(prefix_lens.astype(jnp.int32), hi_all)
+    o = pl.pallas_call(
+        functools.partial(_prefix_kernel, scale=scale, block_size=bs,
+                          pages=pages, tq=tq, window=None, v_lanes=v_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(N, P // tq),
+            in_specs=[pl.BlockSpec((rows, 1), lambda n, t, *_: (0, 0)),
+                      pl.BlockSpec((1, 1, rows, Dp), q_index),
+                      pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+            out_specs=pl.BlockSpec((1, 1, rows, v_dim), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages, 1, bs, Dp), pool.dtype),
+                pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.VMEM((1, rows, 1), jnp.float32),
+                pltpu.VMEM((1, rows, 1), jnp.float32),
+                pltpu.VMEM((1, rows, v_dim), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((N, 1, P * H, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 * 2 ** 20),
+        interpret=interpret,
+        name="mla_prefix_attention",
+    )(block_tables.astype(jnp.int32), prefix_lens,
+      (hi_all - prefix_lens).astype(jnp.int32), qoff, qg, pool)
+    return o.reshape(N, P, H, v_dim)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "v_dim"))
+def mla_paged_attention_kernel(q, pool, block_tables, context_lens, *,
+                               scale: float, v_dim: int) -> jax.Array:
+    _validate_latent(q, pool, v_dim)
+    kw = dict(scale=scale, v_dim=v_dim)
+    return compiled_on_tpu(
+        functools.partial(_mla_paged_fwd, **kw), q, pool, block_tables,
+        context_lens,
+        gather=functools.partial(mla_paged_attention_reference, **kw))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "v_dim"))
+def mla_prefix_attention_kernel(q, pool, block_tables, prefix_lens,
+                                suffix_lens, *, scale: float,
+                                v_dim: int) -> jax.Array:
+    _validate_latent(q, pool, v_dim)
+    kw = dict(scale=scale, v_dim=v_dim)
+    return compiled_on_tpu(
+        functools.partial(_mla_prefix_fwd, **kw), q, pool, block_tables,
+        prefix_lens, suffix_lens,
+        gather=functools.partial(mla_prefix_attention_reference, **kw))
+
+
+def _latent_impl(impl: str, kernel, reference, what: str):
+    if impl == "kernel" or (impl == "auto"
+                            and jax.default_backend() == "tpu"):
+        return kernel
+    if impl not in ("auto", "reference"):
+        raise ValueError(f"unknown {what} impl {impl!r}")
+    return reference
+
+
+def mla_paged_attention(q, pool, block_tables, context_lens, *, scale: float,
+                        v_dim: int, impl: str = "auto") -> jax.Array:
+    """One query a sequence, q [B, H, c + r], over the sequence's latent
+    rows -> [B, H, v_dim].  Dispatcher as `paged_attention`: "auto" is the
+    kernel on a TPU backend, the gather on any other."""
+    _validate_latent(q, pool, v_dim)
+    return _latent_impl(impl, mla_paged_attention_kernel,
+                        mla_paged_attention_reference,
+                        "latent paged attention")(
+        q, pool, block_tables, context_lens, scale=scale, v_dim=v_dim)
+
+
+def mla_prefix_attention(q, pool, block_tables, prefix_lens, suffix_lens, *,
+                         scale: float, v_dim: int,
+                         impl: str = "auto") -> jax.Array:
+    """A chunk's queries q [N, P, H, c + r], the query at position
+    prefix_lens[n] + p, over the rows before and among them -> [N, P, H,
+    v_dim] (rows p >= suffix_lens[n]: unspecified)."""
+    _validate_latent(q, pool, v_dim)
+    return _latent_impl(impl, mla_prefix_attention_kernel,
+                        mla_prefix_attention_reference,
+                        "latent prefix attention")(
+        q, pool, block_tables, prefix_lens, suffix_lens, scale=scale,
+        v_dim=v_dim)

@@ -649,7 +649,7 @@ class PagedBatcher:
                                 "carried_rows": 0}
         self._rung_dispatches = {str(n * self._tile): 0
                                  for n in self._prefill_rows}
-        self._moe_counts = [0, 0, 0, 0]
+        self._moe_counts = [0, 0, 0, 0, 0]
         self._sliding_layers = sum(
             1 for m, _ in (cfg.layer_kinds or ()) if m == "sliding")
         self._sliding_held = 0
@@ -900,9 +900,14 @@ class PagedBatcher:
                 "prefill": dict(
                     self._prefill_counts,
                     rung_dispatches=dict(self._rung_dispatches)),
+                # (models/afmoe.py MOE_COUNTS, and the picks of valid
+                # tokens: those routed here and those whose expert is held
+                # elsewhere)
                 "moe": dict(zip(("layer_steps", "routed_rows",
-                                 "busiest_expert_rows", "experts_touched"),
-                                self._moe_counts)),
+                                 "busiest_expert_rows", "experts_touched",
+                                 "absent_rows"), self._moe_counts),
+                            picked_rows=self._moe_counts[1]
+                            + self._moe_counts[4]),
                 "kv": {"sliding_positions_held": self._sliding_held,
                        "sliding_positions_in_window":
                            self._sliding_in_window,

@@ -2,8 +2,8 @@
 (models/decoding.py paged_prefill_decode_packed, serve/llm.py _dispatch /
 _hand_out): the live slots' next position rides beside the prompt rows, the
 scan that follows is one step shorter, and every slot gets `decode_chunk`
-tokens a dispatch.  Toy twins of the three architectures (arch "llama",
-"afmoe", "lfm2") in float32 on the reference attention path: (a) the
+tokens a dispatch.  Toy twins of the four architectures (arch "llama",
+"afmoe", "lfm2", "axk1") in float32 on the reference attention path: (a) the
 program against the prefill core, one decode step and the decode scan run
 one after the other, (b) the engine's bookkeeping token for token against
 the plain forward pass, (c) the program's shape read off its jaxpr."""
@@ -42,6 +42,15 @@ CONFIGS = {
                  moe_route_eps=1e-6,
                  layer_kinds=(("conv", "dense"), ("full", "experts"),
                               ("conv", "experts"), ("conv", "experts"))),
+    # latent rows in one pool a layer; 4 of the router's 8 experts held
+    "axk1": dict(MOE, n_layers=3, d_ff=32, arch="axk1", rope_theta=1e4,
+                 q_lora_rank=24, kv_lora_rank=20, qk_nope_dim=8,
+                 qk_rope_dim=4, v_head_dim=8, rope_factor=32.0,
+                 rope_mscale_all_dim=1.0, moe_shared_experts=1,
+                 moe_route_scale=2.5, moe_experts=4, moe_router_width=8,
+                 moe_experts_first=2,
+                 layer_kinds=(("latent", "dense"), ("latent", "experts"),
+                              ("latent", "experts"))),
 }
 
 

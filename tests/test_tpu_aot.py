@@ -169,10 +169,14 @@ def test_paged_serving_steps_compile_for_v5e(v5e_devices, model,
 # slots' step that rides in it, a decode step `paged_attention`; Trinity's 4
 # expert layers add one grouped product to each (the pass's is ONE over both
 # kinds of row); LFM2's 2 attention layers (heads of 64, two to a row of
-# lanes in the pool) and 8 expert layers likewise, its 7 conv layers none
+# lanes in the pool) and 8 expert layers likewise, its 7 conv layers none;
+# A.X-K1's 7 latent layers call `mla_prefix_attention` and
+# `mla_paged_attention` (rows of 640 lanes, [64, 640] query tiles), its 6
+# expert layers the product over 4 blocks of F
 CELL_KERNELS = {"mistral-7b-l16": (1 + 1, 1),
                 "trinity-mini-l5": (5 + 5 + 4, 5 + 4),
-                "lfm2-24b-a2b-l9": (2 + 2 + 8, 2 + 8)}
+                "lfm2-24b-a2b-l9": (2 + 2 + 8, 2 + 8),
+                "axk1-l7-ep16": (7 + 7 + 6, 7 + 6)}
 
 
 # LFM2's nine unrolled layers compile ~40 s a program here: one test a
@@ -180,8 +184,8 @@ CELL_KERNELS = {"mistral-7b-l16": (1 + 1, 1),
 # the suite's limit for a test
 CELL_PROGRAMS = [pytest.param("mistral-7b-l16", None, id="mistral-7b-l16"),
                  pytest.param("trinity-mini-l5", None, id="trinity-mini-l5")
-                 ] + [pytest.param("lfm2-24b-a2b-l9", i,
-                                   id=f"lfm2-24b-a2b-l9-{i}")
+                 ] + [pytest.param(name, i, id=f"{name}-{i}")
+                      for name in ("lfm2-24b-a2b-l9", "axk1-l7-ep16")
                       for i in range(5)]
 
 
@@ -193,8 +197,9 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     Mistral's 32 / 8 heads of 128 under 48-column tables, Trinity's 32 / 4
     under 1,072 columns, with its window in the paged kernel and the
     grouped expert product, LFM2's 32 / 8 heads of 64 side by side under
-    1,072 columns beside its conv layers' tails) and the decode-only chunk,
-    as Mosaic kernels,
+    1,072 columns beside its conv layers' tails, A.X-K1's 64 heads over
+    latent rows of 640 lanes with its experts in 4 blocks of F) and the
+    decode-only chunk, as Mosaic kernels,
     inside one chip's memory beside the weights.  (`impl="auto"` asks
     jax.default_backend(): steered here, in the test, as it would read on
     the chip.)"""
@@ -216,7 +221,7 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
             config, max_seq=sv["max_len"], param_dtype=sv["param_dtype"])))
     W = decoding.paged_table_width(sv["max_len"], sv["kv_block_size"])
     assert W == {"mistral-7b-l16": 48, "trinity-mini-l5": 1072,
-                 "lfm2-24b-a2b-l9": 1072}[config_name]
+                 "lfm2-24b-a2b-l9": 1072, "axk1-l7-ep16": 1072}[config_name]
     params = shapes(jax.eval_shape(
         lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
     caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
@@ -252,3 +257,42 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     assert _custom_calls(decoding.paged_decode_steps.lower(
         params, caches, active, cfg, sv["decode_chunk"],
         attn_impl="kernel").compile()) >= CELL_KERNELS[config_name][1]
+
+
+def test_latent_and_blocked_kernels_compile_for_v5e(v5e_devices):
+    """The kernels arch "axk1" brings, alone at the cell's shapes, so that a
+    Mosaic refusal (a 640-lane row, a [64, 640] query tile, two 22 MB weight
+    blocks in VMEM) shows here: `mla_paged_attention` at 64 slots,
+    `mla_prefix_attention` at a rung's 80 attention rows of 64 queries and
+    at ungrouped rows of 16, the expert product at hidden 7168 x width 2048
+    for a decode step's rows (tiles of 16) and a pass's (tiles of 256)."""
+    from ray_tpu.ops import grouped_ffn as gf
+    from ray_tpu.ops import paged_attention as pa
+
+    on_chip, _ = _on_chip_shapes(v5e_devices)
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    kw = dict(scale=0.130861, v_dim=512, impl="kernel")
+    pool, i32 = S((8193, 1, 16, 640)), jnp.int32
+    assert _custom_calls(jax.jit(
+        lambda q, p, bt, n: pa.mla_paged_attention(q, p, bt, n, **kw)).lower(
+        S((64, 64, 576)), pool, S((64, 1072), i32), S((64,), i32)
+    ).compile()) == 1
+    for N, P in ((80, 64), (16, 16)):
+        assert _custom_calls(jax.jit(
+            lambda q, p, bt, a, b: pa.mla_prefix_attention(
+                q, p, bt, a, b, **kw)).lower(
+            S((N, P, 64, 576)), pool, S((N, 1072), i32), S((N,), i32),
+            S((N,), i32)).compile()) == 1
+    D, F, E = 7168, 2048, 12
+    assert gf._f_blocks(D, F, 2) == 4
+    for T in (64, 2112):
+        assert _custom_calls(jax.jit(
+            lambda x, i, w, v, a, b, c: gf.grouped_ffn(
+                x, i, w, v, a, b, c, name="moe_experts_decode",
+                impl="kernel")).lower(
+            S((T, D)), S((T, 8), i32), S((T, 8), jnp.float32),
+            S((T, 8), jnp.bool_), S((E, D, F)), S((E, D, F)), S((E, F, D))
+        ).compile()) == 1
