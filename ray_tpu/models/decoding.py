@@ -390,28 +390,79 @@ def _attend_pass(q, k_pool, v_pool, rows: PrefillRows,
     return o[None]
 
 
-def _write_rows(pool, blocks, offsets, new):
-    """pool [NB, Hkv, bs, D] with new [..., Hkv, D] written at (blocks,
-    :, offsets) [...].  As a scatter of D-wide rows into the pool seen as
-    [NB * Hkv * bs, D]: a scatter indexed on dimensions 0 and 2 makes XLA
-    keep the pool in a layout of its own and copy the WHOLE pool to and
-    from the kernel's on every layer and step.  Positions that are not
-    live arrive here pointed at the scratch block 0, which nothing reads
-    unmasked, so they are written like any other (no gather of what was
-    there)."""
+def _write_rows(pool, blocks, offsets, new, prompt=(0, 1)):
+    """pool [NB, Hkv', bs, lanes] with new [T, Hkv, D] written at (blocks,
+    :, offsets) [T]: ([Hkv, D] is the pool's [Hkv', lanes] row by row, heads
+    side by side or not).  The first N * P of the T are `prompt` = (N, P):
+    N prefill rows of P positions; the others are one position of a slot
+    each.  The pool is written BY PAGE, indexed by the block alone (its
+    dimension 0: a scatter indexed on dimensions 0 and 2 makes XLA keep the
+    pool in a layout of its own and copy the WHOLE pool to and from the
+    kernel's on every layer and step, and one of D-wide rows costs the
+    device by the row, not by the byte):
+
+    * a prefill row's P / bs blocks go in as slabs [Hkv', bs, lanes], one
+      update a block.  That rests on what the engine keeps (serve/llm.py):
+      (i) a block that a row writes belongs to that row's request alone: a
+      prefix hit is whole blocks BEFORE `prefix_lens` and is never written;
+      (ii) a row (or a block of one) with no live position goes to the
+      scratch block 0, which nothing reads unmasked; (iii) the dead
+      positions of a request's last, partly filled block are written with
+      what the padding produced: nothing reads a position at or beyond its
+      sequence's length, and decode writes each before the length passes
+      it; (iv) a conv layer's `tail_pool` and `slot_tail` are not pools of
+      positions and do not come here.  A row starts on a block boundary
+      (`PrefillRows`), so where P is whole blocks (static, as `bs` is) the
+      slabs are the row's tokens as they lie; where it is not (no engine:
+      odd shapes in tests) every position goes in as Hkv' rows of lanes;
+    * a slot's one position is a sixteenth of a page: the slot's page is
+      read by its block id, the new row selected in at its offset, and the
+      page written back, one update a slot (an inactive slot's goes to the
+      scratch block, like any position that is not live)."""
     NB, hkv, bs, D = pool.shape
-    at = ((blocks[..., None] * hkv + jnp.arange(hkv)) * bs
-          + offsets[..., None]).reshape(-1)
-    return pool.reshape(NB * hkv * bs, D).at[at].set(
-        new.reshape(-1, D).astype(pool.dtype)).reshape(pool.shape)
+    new = new.reshape(-1, hkv, D).astype(pool.dtype)
+    blocks, offsets = blocks.reshape(-1), offsets.reshape(-1)
+    (N, P), T = prompt, blocks.shape[0]
+    if N and P % bs:
+        at = ((blocks[:, None] * hkv + jnp.arange(hkv)) * bs
+              + offsets[:, None]).reshape(-1)
+        return pool.reshape(NB * hkv * bs, D).at[at].set(
+            new.reshape(-1, D)).reshape(pool.shape)
+    if N:
+        pool = pool.at[blocks[:N * P:bs]].set(
+            new[:N * P].reshape(-1, bs, hkv, D).transpose(0, 2, 1, 3))
+    if T > N * P:
+        at = blocks[N * P:]
+        here = (jnp.arange(bs) == offsets[N * P:, None])[:, None, :, None]
+        pool = pool.at[at].set(
+            jnp.where(here, new[N * P:, :, None], pool[at]))
+    return pool
 
 
-def _write_latent(pool, blocks, offsets, rows):
+def _write_latent(pool, blocks, offsets, rows, prompt=(0, 1)):
     """A latent layer's ONE write: rows [..., 1, c + r] into pool [NB, 1,
     bs, Dp] at (blocks, offsets), the lanes past c + r zero."""
     from ray_tpu.ops.paged_attention import to_lanes
     with jax.named_scope("mla_kv"):
-        return _write_rows(pool, blocks, offsets, to_lanes(rows, pool))
+        return _write_rows(pool, blocks, offsets, to_lanes(rows, pool),
+                           prompt)
+
+
+def pool_updates(caches: PagedDecodeCaches, rows: int, P: int,
+                 steps: int) -> Tuple[int, int]:
+    """(page updates, row updates) the pools take in one dispatch whose pass
+    holds `rows` prefill rows of P positions (0: a decode-only dispatch) and
+    which moves every slot on by `steps` positions: host arithmetic over
+    static shapes, by the rule `_write_rows` writes by.  A serving engine
+    reads row updates 0: its rows are whole blocks."""
+    if isinstance(caches.kp, tuple):
+        heads = [p.shape[1] for p in caches.kp + caches.vp if p is not None]
+    else:
+        heads = [caches.kp.shape[2]] * (2 * caches.kp.shape[0])
+    bs, B = block_size_of(caches), caches.lengths.shape[0]
+    if rows and P % bs:
+        return (steps - 1) * B * len(heads), (rows * P + B) * sum(heads)
+    return (rows * P // bs + steps * B) * len(heads), 0
 
 
 def _scan_layers(layer, x, layers, caches: PagedDecodeCaches):
@@ -607,6 +658,7 @@ def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
     """Arch "llama" / "gpt2": the layer scan of one pass (`_pass_tokens`:
     tokens [1, T]) over the stacked pool -> (x' [1, T, D], kp', vp')."""
     positions, _, blocks, offsets = _pass_tokens(rows, step)
+    prompt = rows.positions.shape       # (N, P)
     x = params["tok_embed"][tokens].astype(cfg.dtype)        # [1,T,D]
     if cfg.arch == "gpt2":
         x = x + params["pos_embed"][
@@ -619,8 +671,8 @@ def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
         h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
                   cfg.norm_eps, rms)
         q, k, v = _qkv(p, h, cfg, positions)
-        k_pool = _write_rows(k_pool, first + blocks, offsets, k[0])
-        v_pool = _write_rows(v_pool, first + blocks, offsets, v[0])
+        k_pool = _write_rows(k_pool, first + blocks, offsets, k[0], prompt)
+        v_pool = _write_rows(v_pool, first + blocks, offsets, v[0], prompt)
         o = _attend_pass(q, k_pool, v_pool, rows, step, first,
                          impl=attn_impl)                     # [1,T,H,Dh]
         attn = jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
@@ -736,14 +788,14 @@ def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
     positions, valid, blocks, offsets = _pass_tokens(rows, step)
 
     def attend(q, k, v):
-        kp = _write_rows(k_pool, blocks, offsets, k[0])
-        vp = _write_rows(v_pool, blocks, offsets, v[0])
+        kp = _write_rows(k_pool, blocks, offsets, k[0], (N, P))
+        vp = _write_rows(v_pool, blocks, offsets, v[0], (N, P))
         state.extend((kp, vp))
         return _attend_pass(q, kp, vp, rows, step, impl=attn_impl,
                             window=model.window_of(cfg, kind))
 
     def attend_latent(q, row):
-        kp = _write_latent(k_pool, blocks, offsets, row[0])
+        kp = _write_latent(k_pool, blocks, offsets, row[0], (N, P))
         state.extend((kp, None))
         return _attend_pass(q, kp, None, rows, step, impl=attn_impl,
                             **model.latent_kw(cfg))

@@ -650,6 +650,9 @@ class PagedBatcher:
         self._rung_dispatches = {str(n * self._tile): 0
                                  for n in self._prefill_rows}
         self._moe_counts = [0, 0, 0, 0, 0]
+        # Updates the dispatches' K/V writes made to the pools, by page and
+        # by D-wide row (decoding.pool_updates, from static shapes).
+        self._pool_updates = {"page_updates": 0, "row_updates": 0}
         self._sliding_layers = sum(
             1 for m, _ in (cfg.layer_kinds or ()) if m == "sliding")
         self._sliding_held = 0
@@ -908,6 +911,7 @@ class PagedBatcher:
                                  "absent_rows"), self._moe_counts),
                             picked_rows=self._moe_counts[1]
                             + self._moe_counts[4]),
+                "writes": dict(self._pool_updates),
                 "kv": {"sliding_positions_held": self._sliding_held,
                        "sliding_positions_in_window":
                            self._sliding_in_window,
@@ -1354,7 +1358,14 @@ class PagedBatcher:
         self._prefill_counts["chunk_tokens"] += sum(takes)
         self._prefill_counts["padded_tokens"] += N * T
         self._rung_dispatches[str(N * T)] += 1
+        self._count_writes(N, chunk)
         return devs, N
+
+    def _count_writes(self, rows: int, chunk: int) -> None:
+        pages, by_row = self._dec.pool_updates(self.caches, rows,
+                                               self._tile, chunk)
+        self._pool_updates["page_updates"] += pages
+        self._pool_updates["row_updates"] += by_row
 
     def _decode_dispatch(self, chunk: int) -> tuple:
         """Decode-only device step for every slot; returns (dtoks

@@ -10,6 +10,8 @@ before any chip time is spent.  About a minute; `slow` lane.
 """
 
 import dataclasses
+import math
+import re
 
 import numpy as np
 import pytest
@@ -30,6 +32,39 @@ def v5e_devices():
 def _custom_calls(compiled) -> int:
     return sum("tpu_custom_call" in line
                for line in compiled.as_text().splitlines())
+
+
+def _assert_pools_written_by_page(compiled, caches):
+    """What a serving program does to its KV pools, read off the compiled
+    text: every scatter into a pool is indexed by the block alone and its
+    update is whole pages [.., Hkv', bs, lanes] (a scatter of D-wide rows
+    costs the chip ~68 ns a row: PERF.md, PR 45), and nothing copies or
+    converts a whole pool (the pool is one buffer updated in place through
+    the layer scans and the unrolled layers)."""
+    pools = {tuple(p.shape) for name in ("kp", "vp")
+             for p in jax.tree.leaves(getattr(caches, name))}
+    # the stacked pool [L, NB, ...] is carried as ONE pool of L * NB blocks
+    pools |= {(s[0] * s[1],) + s[2:] for s in pools if len(s) == 5}
+    # (XLA drops a dimension of 1: a latent pool's head axis)
+    pools = {tuple(d for d in s if d > 1) for s in pools if len(s) == 4}
+    sizes = {math.prod(s) for s in pools}
+    shape = re.compile(r"= \(?[a-z0-9]+\[([0-9,]+)\]")
+    scatters = 0
+    for line in compiled.as_text().splitlines():
+        op = re.search(r"[\]}] (scatter|copy|convert)\(", line)
+        found = shape.search(line)
+        if not op or not found:
+            continue
+        dims = tuple(int(d) for d in found.group(1).split(","))
+        if math.prod(dims) not in sizes:
+            continue
+        assert op.group(1) == "scatter", line[:200]
+        assert dims in pools, line[:200]
+        window = ",".join(str(d) for d in range(1, len(dims)))
+        assert f"update_window_dims={{{window}}}" in line \
+            and "scatter_dims_to_operand_dims={0}" in line, line[:300]
+        scatters += 1
+    assert scatters, "no write into a pool found"
 
 
 def _per_chip_bytes(compiled, what: str) -> int:
@@ -247,6 +282,7 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
             params, caches, packed, cfg, sv["decode_chunk"], tile,
             attn_impl="kernel").compile()
         assert _custom_calls(fused) >= sum(CELL_KERNELS[config_name])
+        _assert_pools_written_by_page(fused, caches)
         mem = fused.memory_analysis()
         assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 < 15.75 * 2 ** 30)
@@ -254,9 +290,11 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
         return
     N = sv["num_slots"]
     active = jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=on_chip)
-    assert _custom_calls(decoding.paged_decode_steps.lower(
+    steps = decoding.paged_decode_steps.lower(
         params, caches, active, cfg, sv["decode_chunk"],
-        attn_impl="kernel").compile()) >= CELL_KERNELS[config_name][1]
+        attn_impl="kernel").compile()
+    assert _custom_calls(steps) >= CELL_KERNELS[config_name][1]
+    _assert_pools_written_by_page(steps, caches)
 
 
 def test_latent_and_blocked_kernels_compile_for_v5e(v5e_devices):
