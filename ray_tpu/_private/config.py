@@ -245,6 +245,10 @@ _D("kv_num_blocks", int, 0,
    "Paged-KV serving: usable blocks in the shared pool.  0 = auto "
    "(num_slots * ceil(max_len / kv_block_size) — same HBM footprint "
    "as the dense per-slot cache, with sharing as pure upside).")
+_D("kv_num_states", int, 0,
+   "State ids of an LLM engine whose model keeps per-sequence recurrent "
+   "state (serve/llm.py StateAllocator: one a live slot, the rest "
+   "checkpoints the prefix cache owns).  0 = five a slot.")
 _D("prefix_cache_enabled", bool, True,
    "Paged-KV serving: keep retired requests' full prompt blocks in a "
    "per-model radix tree so later prompts sharing the prefix decode "

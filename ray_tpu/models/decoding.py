@@ -33,7 +33,9 @@ tests hold every step to greedy transformer.forward.
 Architectures of unrolled layers (`cfg.layer_kinds`: models/afmoe.py,
 window and full attention layers mixed; models/lfm2.py, short convolutions
 and attention layers mixed; models/axk1.py, latent attention whose cache
-row has no head axis; all with expert layers) have their paged
+row has no head axis; all with expert layers; models/olmo_hybrid.py, gated
+delta-rule layers whose state is a matrix a head a SEQUENCE, kept by state
+id beside the pages) have their paged
 steps at the end of this file, built from their module's one layer
 definition; the public entry points (paged_prefill_decode_packed,
 paged_decode_steps, paged_decode_step) branch to them and return the
@@ -129,6 +131,21 @@ class PagedDecodeCaches(NamedTuple):
     #                             prompt
     tail_pool: Tuple = ()
     slot_tail: Tuple = ()
+    # Linear (gated delta-rule) layers' state, where a model has any: what
+    # a SEQUENCE leaves behind whatever its length, indexed by a STATE ID
+    # (id 0 scratch; the engine hands them out: a slot owns one while it
+    # lives, the rest are checkpoints the radix cache owns), per layer (None
+    # at the others)
+    #   state_pool [NS + 1, H / g, dk, g * dv] float32: the recurrence's
+    #                             carry, g heads side by side in whole rows
+    #                             of lanes (ops/gated_delta.py)
+    #   conv_pool  [NS + 1, K - 1, C]: the convolution's input at the last
+    #                             K - 1 positions
+    # and slot_state [B] int32: the id each slot decodes from, set by the
+    # row that ends its prompt.
+    state_pool: Tuple = ()
+    conv_pool: Tuple = ()
+    slot_state: Optional[jax.Array] = None
 
 
 def paged_table_width(max_len: int, block_size: int) -> int:
@@ -169,11 +186,13 @@ def block_size_of(caches: PagedDecodeCaches) -> int:
 
 def init_paged_caches(cfg: TransformerConfig, num_slots: int,
                       num_blocks: int, block_size: int,
-                      max_len: int) -> PagedDecodeCaches:
+                      max_len: int, num_states: int = 0
+                      ) -> PagedDecodeCaches:
     """`num_blocks` USABLE blocks; one extra scratch block (id 0) is
     added internally, so pool ids run 0..num_blocks inclusive.  Unrolled
     layers get the state their mixer has: keys and values, a latent
-    layer's one pool of rows, or a conv's tails."""
+    layer's one pool of rows, a conv's tails, or a linear layer's
+    `num_states` USABLE states (and scratch id 0)."""
     w = paged_table_width(max_len, block_size)
     state = {}
     if cfg.layer_kinds is None:
@@ -183,7 +202,9 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
                      vp=jnp.zeros(shape, cfg.dtype))
     else:
         conv = [m == "conv" for m, _ in cfg.layer_kinds]
-        for name, none in (("kp", ("conv",)), ("vp", ("conv", "latent"))):
+        linear = [m == "linear" for m, _ in cfg.layer_kinds]
+        for name, none in (("kp", ("conv", "linear")),
+                           ("vp", ("conv", "latent", "linear"))):
             state[name] = tuple(
                 None if m in none else jnp.zeros(unrolled_pool_shape(
                     cfg, num_blocks, block_size, m), cfg.dtype)
@@ -194,6 +215,17 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
                                 ("slot_tail", (num_slots, k1, d))):
                 state[name] = tuple(
                     jnp.zeros(shape, cfg.dtype) if c else None for c in conv)
+        if any(linear):
+            from ray_tpu.ops import gated_delta
+            shapes = (("state_pool", gated_delta.pool_shape(
+                num_states, cfg.linear_heads, cfg.linear_key_dim,
+                cfg.linear_value_dim), jnp.float32),
+                ("conv_pool", (num_states + 1, cfg.conv_kernel - 1,
+                               unrolled(cfg).conv_width(cfg)), cfg.dtype))
+            for name, shape, dtype in shapes:
+                state[name] = tuple(
+                    jnp.zeros(shape, dtype) if c else None for c in linear)
+            state["slot_state"] = jnp.zeros((num_slots,), jnp.int32)
     return PagedDecodeCaches(
         block_tables=jnp.zeros((num_slots, w), jnp.int32),
         lengths=jnp.zeros((num_slots,), jnp.int32),
@@ -242,6 +274,15 @@ class PrefillRows(NamedTuple):
     before_block: Optional[jax.Array] = None  # [N] the block before the row
     close_slots: Optional[jax.Array] = None  # [N] the slot whose prompt the
     #                          row ends (num_slots, dropped: none)
+    # For linear layers: rows of one request follow each other in order.
+    state_from: Optional[jax.Array] = None   # [N] what a row starts from: -1
+    #                          the row before it, 0 zeros (position 0), else
+    #                          a state id (a checkpoint after a hit; its slot's
+    #                          where an earlier dispatch left the prompt)
+    state_to: Optional[jax.Array] = None     # [N, 2] the ids a row's END state
+    #                          is written to (0: nowhere): its slot's where the
+    #                          row ends this call's share of the prompt, a
+    #                          fresh checkpoint's where the engine asked
 
 
 class DecodeRows(NamedTuple):
@@ -255,16 +296,20 @@ class DecodeRows(NamedTuple):
     offsets: jax.Array       # [B]
     tail_blocks: Optional[jax.Array] = None  # [B] `blocks` where the new
     #                          position completes its block, else scratch 0
+    state_ids: Optional[jax.Array] = None    # [B] a slot's state id, scratch
+    #                          0 where it is not active (linear layers)
 
 
 def prefill_rows(tables, prefix_lens, suffix_lens, valid, P: int,
                  block_size: int, slots=None, num_slots: int = 0,
-                 closes=None, conv_kernel: int = 0) -> PrefillRows:
+                 closes=None, conv_kernel: int = 0,
+                 states=None) -> PrefillRows:
     """`slots` [N] (which of `num_slots` requests a row belongs to) lets
     rows narrower than ATTENTION_ROW attend in groups; without it every
     row attends alone.  `conv_kernel` > 1: what conv layers need of the
     rows, and with `closes` [N] (the row ends its slot's prompt) which
-    slots' tails the rows set."""
+    slots' tails the rows set.  `states` (state_from [N], state_to [N, 2]):
+    what linear layers need of the rows."""
     M = tables.shape[1] * block_size
     positions = prefix_lens[:, None] + jnp.arange(P, dtype=jnp.int32)
     live = valid[:, None] & (jnp.arange(P)[None, :] < suffix_lens[:, None])
@@ -286,6 +331,10 @@ def prefill_rows(tables, prefix_lens, suffix_lens, valid, P: int,
                 axis=1)[:, 0])
         if closes is not None:
             conv["close_slots"] = jnp.where(closes & valid, slots, num_slots)
+    if states is not None:
+        conv.update(
+            state_from=jnp.where(valid, states[0], -1),
+            state_to=jnp.where(valid[:, None], states[1], 0))
     return PrefillRows(positions, tables, prefix_lens, suffix_lens, live,
                        jnp.where(live, blocks, 0), abs_pos % block_size,
                        groups, **conv)
@@ -344,7 +393,8 @@ def _attend_rows(q, k_pool, v_pool, rows: PrefillRows, first_block=0,
     return o.reshape(R * K, P, H, -1)[g.back]
 
 
-def decode_rows(tables, lengths, active, block_size: int) -> DecodeRows:
+def decode_rows(tables, lengths, active, block_size: int,
+                slot_state=None) -> DecodeRows:
     B = lengths.shape[0]
     M = tables.shape[1] * block_size
     pos_c = jnp.minimum(lengths, M - 1)
@@ -353,7 +403,9 @@ def decode_rows(tables, lengths, active, block_size: int) -> DecodeRows:
     return DecodeRows(lengths[:, None], tables,
                       jnp.where(active, jnp.minimum(lengths + 1, M), 0),
                       active, blocks, offsets,
-                      jnp.where(offsets == block_size - 1, blocks, 0))
+                      jnp.where(offsets == block_size - 1, blocks, 0),
+                      None if slot_state is None
+                      else jnp.where(active, slot_state, 0))
 
 
 def _pass_tokens(rows: PrefillRows, step: Optional[DecodeRows]):
@@ -579,7 +631,8 @@ def _paged_prefill_core(params: Dict[str, Any],
                         slots: jax.Array, valid: jax.Array,
                         closes: jax.Array, new_bt: jax.Array,
                         cfg: TransformerConfig, attn_impl: str = "auto",
-                        carried: Optional[jax.Array] = None):
+                        carried: Optional[jax.Array] = None,
+                        states=None):
     """Prefill of N rows of P tokens against what their requests have in
     the pool (traceable) -> (caches', first tokens [N], an expert model's
     counts or None, the carried slots' next tokens [B] or None).
@@ -604,20 +657,24 @@ def _paged_prefill_core(params: Dict[str, Any],
     one row of up to ATTENTION_ROW queries (QueryGroups: the prefix is
     streamed once for them); only the row that ends its prompt (`closes`)
     yields the request's first token and hands the slot its table and
-    length.  A row that is not `valid` writes to the scratch block only."""
+    length.  A row that is not `valid` writes to the scratch block only.
+    `states` (state_from [N], state_to [N, 2]: PrefillRows), where the model
+    has linear layers: the closing row's first `state_to` is its slot's id
+    from then on."""
     N, P = tokens.shape
     B = caches.lengths.shape[0]
     bs = block_size_of(caches)
     rows = prefill_rows(new_bt, prefix_lens, suffix_lens, valid, P, bs,
                         slots, B, closes,
-                        cfg.conv_kernel if caches.tail_pool else 0)
+                        cfg.conv_kernel if caches.tail_pool else 0, states)
     # The pass's tokens [1, T] and which of them yield one: every row's
     # last live position, then every decode row.
     tokens = tokens.reshape(1, N * P)
     yields = jnp.arange(N) * P + jnp.clip(suffix_lens - 1, 0, P - 1)
     step = None
     if carried is not None:
-        step = decode_rows(caches.block_tables, caches.lengths, carried, bs)
+        step = decode_rows(caches.block_tables, caches.lengths, carried, bs,
+                           caches.slot_state)
         tokens = jnp.concatenate([tokens, caches.last_token[None]], axis=1)
         yields = jnp.concatenate([yields, N * P + jnp.arange(B)])
     if cfg.layer_kinds is not None:
@@ -646,6 +703,9 @@ def _paged_prefill_core(params: Dict[str, Any],
     # A scatter whose in-range indices are distinct: at most one row of a
     # slot closes, and every other row is sent out of range and dropped.
     at = jnp.where(closes, slots, B)
+    if states is not None:
+        state["slot_state"] = caches.slot_state.at[at].set(
+            states[1][:, 0], mode="drop")
     return caches._replace(
         block_tables=caches.block_tables.at[at].set(new_bt, mode="drop"),
         lengths=lengths.at[at].set(prefix_lens + suffix_lens, mode="drop"),
@@ -710,6 +770,9 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
       rows 0..N-1: [tokens[0:P] | suffix_len | prefix_len |
                     slot | valid | block_table[0:W]]
       row  N:      active mask for the B decode slots in cols 0..B-1.
+    A model with linear layers (caches.state_pool) has three more columns
+    after the table, Wp = max(P + 7 + W, num_slots): [state_from |
+    state_to[0] | state_to[1]] (PrefillRows).
 
     A row is a tile of one request's uncached tokens (a KV block or two:
     N x P positions are what the dense products see, N one of the host
@@ -732,10 +795,13 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
     was_active = packed[-1, :B] > 0
     at = jnp.where(closes, slots, B)
     closed = jnp.zeros((B,), bool).at[at].set(True, mode="drop")
+    states = None
+    if caches.state_pool:
+        states = (packed[:-1, P + 4 + W], packed[:-1, P + 5 + W:P + 7 + W])
     caches, first, counts, tok = _paged_prefill_core(
         params, caches, packed[:-1, :P], packed[:-1, P], packed[:-1, P + 1],
         slots, flag > 0, closes, packed[:-1, P + 4:P + 4 + W], cfg,
-        attn_impl, carried=was_active & ~closed)
+        attn_impl, carried=was_active & ~closed, states=states)
     tok = tok.at[at].set(first, mode="drop")[None]
     active = was_active | closed
     if cfg.layer_kinds is not None:
@@ -756,7 +822,8 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
 # The layers are unrolled (their kinds differ in shape) and each has state
 # of its own, a pair of arrays: an attention layer's K and V pools, a conv
 # layer's block tails and slot tails, a latent layer's one pool of rows and
-# None (PagedDecodeCaches).
+# None, a linear layer's states and conv inputs by state id
+# (PagedDecodeCaches; `_LAYER_STATE` names the fields by mixer).
 # `paged_prefill_layer` and `paged_decode_layer` are what the engine's
 # dispatches are made of, one layer at a time: a caller that cannot hold
 # every layer's weights at once (the benchmark's comparison with the plain
@@ -826,7 +893,58 @@ def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
         state.extend((tails, slot))
         return prev
 
-    mix = {"conv": before, "latent": attend_latent}.get(kind[0], attend)
+    def linear_before(u):
+        """A linear layer, whose `k_pool` is its states and `v_pool` its
+        conv inputs, both by state id: a row starts from what `state_from`
+        names, or from the row before it (its own request's)."""
+        conv, K1, C = v_pool, v_pool.shape[1], u.shape[-1]
+        up = u[0, :N * P].reshape(N, P, C)
+        src = rows.state_from
+        came = jnp.where(
+            (src < 0)[:, None, None], jnp.roll(up[:, P - K1:], 1, axis=0),
+            jnp.where((src > 0)[:, None, None], conv[jnp.maximum(src, 0)],
+                      0))
+        ext = jnp.concatenate([came, up], axis=1)
+        last = rows.suffix_lens[:, None] + jnp.arange(K1)        # [N, K-1]
+        ends = jnp.take_along_axis(ext, last[..., None], axis=1)
+        conv = conv.at[rows.state_to.reshape(-1)].set(
+            jnp.repeat(ends, 2, axis=0))
+        prev = [t.reshape(1, N * P, C) for t in model.taps(came, up)]
+        if step is not None:
+            ud = u[0, N * P:, None]                              # [B, 1, C]
+            mine = v_pool[step.state_ids]
+            conv = conv.at[step.state_ids].set(
+                jnp.concatenate([mine[:, 1:], ud], axis=1))
+            prev = [jnp.concatenate([a, b.reshape(1, -1, C)], axis=1)
+                    for a, b in zip(prev, model.taps(mine, ud))]
+        state.append(conv)
+        return prev
+
+    def linear_rule(q, k, v, log_a, beta):
+        from ray_tpu.ops import gated_delta
+        live = valid[0, :, None]
+        log_a, beta = jnp.where(live, log_a[0], 0), jnp.where(live, beta[0],
+                                                              0)
+
+        def rows_of(a):
+            return a[:N * P].reshape(N, P, *a.shape[1:])
+
+        o, pool = gated_delta.gated_delta_chunk(
+            k_pool, rows.state_from, rows.state_to,
+            *(rows_of(a) for a in (q[0], k[0], v[0], log_a, beta)),
+            impl=attn_impl)
+        o = o.reshape(N * P, *o.shape[2:])
+        if step is not None:
+            od, pool = gated_delta.gated_delta_step(
+                pool, step.state_ids,
+                *(a[N * P:] for a in (q[0], k[0], v[0], log_a, beta)),
+                impl=attn_impl)
+            o = jnp.concatenate([o, od])
+        state.insert(0, pool)
+        return o[None]
+
+    mix = {"conv": before, "latent": attend_latent,
+           "linear": (linear_before, linear_rule)}.get(kind[0], attend)
     y, counts = model.layer(cfg, kind, p, x.reshape(1, -1, D), positions,
                             mix, valid=valid,
                             moe_name="moe_experts_prefill", tap=tap)
@@ -865,22 +983,45 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
             jnp.where(rows.active[:, None, None], moved, v_pool)))
         return model.taps(v_pool, u)
 
-    mix = {"conv": before, "latent": attend_latent}.get(kind[0], attend)
+    def linear_before(u):
+        mine = v_pool[rows.state_ids]
+        state.append(v_pool.at[rows.state_ids].set(
+            jnp.concatenate([mine[:, 1:], u], axis=1)))
+        return model.taps(mine, u)
+
+    def linear_rule(q, k, v, log_a, beta):
+        from ray_tpu.ops import gated_delta
+        live = rows.active[:, None]
+        o, pool = gated_delta.gated_delta_step(
+            k_pool, rows.state_ids, q[:, 0], k[:, 0], v[:, 0],
+            jnp.where(live, log_a[:, 0], 0), jnp.where(live, beta[:, 0], 0),
+            impl=attn_impl)
+        state.insert(0, pool)
+        return o[:, None]
+
+    mix = {"conv": before, "latent": attend_latent,
+           "linear": (linear_before, linear_rule)}.get(kind[0], attend)
     x, counts = model.layer(cfg, kind, p, x, rows.positions, mix,
                             valid=rows.active[:, None],
                             moe_name="moe_experts_decode", tap=tap)
     return x, state[0], state[1], counts
 
 
+# The pair of PagedDecodeCaches fields that holds a layer's state, by mixer
+# (any other: its K and V pools).
+_LAYER_STATE = {"conv": ("tail_pool", "slot_tail"),
+                "linear": ("state_pool", "conv_pool")}
+
+
 def _unrolled_layers(cfg, params, caches, x, rows, layer_fn, attn_impl):
     """-> (x', the caches' per-layer fields as the layers left them,
     counts)."""
     state = {f: list(getattr(caches, f))
-             for f in ("kp", "vp", "tail_pool", "slot_tail")}
+             for pair in (("kp", "vp"), *_LAYER_STATE.values())
+             for f in pair}
     counts = unrolled(cfg).no_counts()
     for i, (kind, p) in enumerate(zip(cfg.layer_kinds, params["layers"])):
-        a, b = (("tail_pool", "slot_tail") if kind[0] == "conv"
-                else ("kp", "vp"))
+        a, b = _LAYER_STATE.get(kind[0], ("kp", "vp"))
         x, state[a][i], state[b][i], c = layer_fn(
             cfg, kind, p, x, state[a][i], state[b][i], rows, attn_impl)
         counts = counts + c
@@ -894,7 +1035,7 @@ def _unrolled_decode_core(params, caches: PagedDecodeCaches, active, cfg,
     scratch block and are routed to no expert."""
     model = unrolled(cfg)
     rows = decode_rows(caches.block_tables, caches.lengths, active,
-                       block_size_of(caches))
+                       block_size_of(caches), caches.slot_state)
     x = model.embed(cfg, params["tok_embed"], caches.last_token[:, None])
     x, state, counts = _unrolled_layers(cfg, params, caches, x, rows,
                                         paged_decode_layer, attn_impl)

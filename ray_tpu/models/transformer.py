@@ -43,9 +43,9 @@ class TransformerConfig:
     d_ff: Optional[int] = None            # None => arch default
     max_seq: int = 2048
     arch: str = "llama"                   # "llama" | "gpt2" | a module of
-    # unrolled layers under models/ ("afmoe", "lfm2", "axk1": see
-    # `layer_kinds`)
-    rope_theta: float = 500_000.0
+    # unrolled layers under models/ ("afmoe", "lfm2", "axk1",
+    # "olmo_hybrid": see `layer_kinds`)
+    rope_theta: Optional[float] = 500_000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16             # activation/compute dtype
     param_dtype: Any = jnp.float32
@@ -119,6 +119,20 @@ class TransformerConfig:
     # routed nowhere (ops/grouped_ffn.py, models/afmoe.py `experts`).
     moe_router_width: int = 0
     moe_experts_first: int = 0
+    # arch "olmo_hybrid" (models/olmo_hybrid.py; serving and `forward`
+    # only): mixer "linear" | "full".  A linear layer is a gated delta rule
+    # (ops/gated_delta.py) over `linear_heads` heads with keys of
+    # `linear_key_dim` and values of `linear_value_dim`, its q, k and v each
+    # through a causal depthwise convolution of `conv_kernel` taps; its
+    # write strength is in (0, 2) where `linear_neg_eigval` (else (0, 1)).
+    # `rope_theta` None: a full layer carries no rotary embedding.
+    # `norm_after_branch`: a branch reads the residual stream as it is and
+    # its OUTPUT is normed before it is added (False: normed input).
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_neg_eigval: bool = False
+    norm_after_branch: bool = False
 
     def __post_init__(self):
         if self.layer_kinds is not None:

@@ -190,6 +190,17 @@ class _Request:
     _pos_cap: int = 0
     _blocks: List[int] = field(default_factory=list)
     _blocks_freed: bool = False
+    # A model with per-sequence recurrent state (StateAllocator): the id the
+    # request's state lives in, what its next prefill row starts from (a
+    # checkpoint held until the dispatch that restores it is launched, then
+    # its own id; 0: zeros), and the checkpoints it was asked to leave,
+    # {blocks into the prompt: id}, until the radix tree owns them.
+    _state_id: int = 0
+    _state_from: int = 0
+    _state_held: int = 0
+    _ckpt_base: int = 0     # blocks its hit used: what a checkpoint it
+    #                         leaves at depth d cost to reach is d - base
+    _ckpts: Dict[int, int] = field(default_factory=dict)
     # Set for streaming consumers: tokens are ALSO pushed here as the
     # engine processes decode reads, ending with _STREAM_END.
     stream_q: Optional["queue.Queue"] = None
@@ -380,8 +391,103 @@ class BlockAllocator:
                 "free": len(self._free)}
 
 
+class StateAllocator:
+    """Ids 1..num_states of per-sequence recurrent state (models/decoding.py
+    `state_pool`; id 0 is the scratch state and is never handed out), for
+    a model whose layers keep a recurrence's carry and not only positions.
+
+    An id is a live request's own (taken at admission, given back when the
+    request retires) or a CHECKPOINT: a copy of the state after a whole
+    block of some prompt, owned by the radix node of that block
+    (`_RadixNode.state`), so that a prefix hit can start from it.
+    Checkpoints are evicted in LRU order of their last use, one that a
+    request is about to restore (`hold`) never, and a DEAR one (it took more
+    than `dear_blocks` blocks of prefill to reach from the checkpoint before
+    it: a tenant's 16 k system prompt) only when no cheap one is left: a
+    turn's checkpoint costs a block or two to make again, a system
+    prompt's a thousand, and an LRU alone lets the many cheap ones push the
+    few dear ones out.  An id given back `later`
+    is handed out again only after `settle()`: the dispatcher calls that at
+    the start of a dispatch, so a decode step launched for the id's last
+    owner can never run after the id was given to another request.
+    NOT thread-safe (engine _kv_lock)."""
+
+    def __init__(self, num_states: int, dear_blocks: int = 128) -> None:
+        from collections import OrderedDict
+        if num_states < 1:
+            raise ValueError("a state pool needs at least one id")
+        self.num_states = num_states
+        self.dear_blocks = dear_blocks
+        self._free: List[int] = list(range(num_states, 0, -1))
+        self._limbo: List[int] = []
+        self._holds: Dict[int, int] = {}
+        # checkpoint id -> its radix node, least recently used first, and
+        # the blocks of prefill each cost
+        self._ckpts: "OrderedDict[int, _RadixNode]" = OrderedDict()
+        self._cost: Dict[int, int] = {}
+
+    def used(self) -> int:
+        return self.num_states - len(self._free) - len(self._limbo)
+
+    def checkpoints(self) -> int:
+        return len(self._ckpts)
+
+    def alloc(self) -> Optional[int]:
+        return self._free.pop() if self._free else None
+
+    def free(self, sid: int, later: bool = False) -> None:
+        (self._limbo if later else self._free).append(sid)
+
+    def settle(self) -> None:
+        if self._limbo:
+            self._free.extend(self._limbo)
+            self._limbo.clear()
+
+    def adopt(self, sid: int, node: "_RadixNode", cost: int = 0) -> None:
+        """`node` now owns checkpoint `sid` (newest in the LRU), which took
+        `cost` blocks of prefill to reach."""
+        node.state = sid
+        self._ckpts[sid] = node
+        self._cost[sid] = cost
+
+    def touch(self, sid: int) -> None:
+        self._ckpts.move_to_end(sid)
+
+    def hold(self, sid: int) -> None:
+        self._holds[sid] = self._holds.get(sid, 0) + 1
+
+    def release(self, sid: int) -> None:
+        n = self._holds.get(sid, 0) - 1
+        if n > 0:
+            self._holds[sid] = n
+        else:
+            self._holds.pop(sid, None)
+
+    def drop(self, sid: int) -> None:
+        """A checkpoint's node is gone (or gives it up): the id is free."""
+        node = self._ckpts.pop(sid)
+        node.state = None
+        self._cost.pop(sid, None)
+        self.free(sid)
+
+    def evict_lru(self) -> bool:
+        """Free the least recently used cheap checkpoint nobody holds, a
+        dear one where there is none; its block stays cached."""
+        for dear in (False, True):
+            for sid in self._ckpts:
+                if sid not in self._holds and (
+                        dear or self._cost[sid] <= self.dear_blocks):
+                    self.drop(sid)
+                    return True
+        return False
+
+    def drop_all(self) -> None:
+        for sid in list(self._ckpts):
+            self.drop(sid)
+
+
 class _RadixNode:
-    __slots__ = ("children", "parent", "key", "block", "last_used")
+    __slots__ = ("children", "parent", "key", "block", "last_used", "state")
 
     def __init__(self, parent=None, key=None, block=None):
         self.children: Dict[tuple, "_RadixNode"] = {}
@@ -389,6 +495,9 @@ class _RadixNode:
         self.key = key
         self.block = block
         self.last_used = 0
+        # a checkpoint of the recurrent state AFTER this node's block
+        # (StateAllocator), where the model has such state and one was taken
+        self.state: Optional[int] = None
 
 
 class RadixCache:
@@ -425,29 +534,45 @@ class RadixCache:
         len(tokens) - 1 so at least one token is always left for the
         suffix prefill (the request needs fresh last-position logits).
         Returns the physical block ids, root-first."""
+        return self.match_with_state(tokens)[0]
+
+    def match_with_state(self, tokens: List[int]):
+        """`match` for a model with per-sequence recurrent state: a hit can
+        only be USED as far as a checkpoint of that state reaches.  ->
+        (the matched blocks m, root-first, h <= m: the depth of the deepest
+        matched node that carries a checkpoint, that checkpoint's id or 0).
+        K/V of the blocks between h and m are recomputed with the rest."""
         bs = self.block_size
         out: List[int] = []
         node = self.root
-        limit = (len(tokens) - 1) // bs
+        h, sid = 0, 0
         now = self._now()
-        for i in range(limit):
-            chunk = tuple(tokens[i * bs:(i + 1) * bs])
-            child = node.children.get(chunk)
+        for i in range((len(tokens) - 1) // bs):
+            child = node.children.get(tuple(tokens[i * bs:(i + 1) * bs]))
             if child is None:
                 break
             child.last_used = now
             out.append(child.block)
+            if child.state is not None:
+                h, sid = i + 1, child.state
             node = child
-        return out
+        return out, h, sid
 
     def insert(self, tokens: List[int], blocks: List[int],
-               allocator: BlockAllocator) -> int:
+               allocator: BlockAllocator,
+               states: Optional[Dict[int, int]] = None,
+               state_alloc: Optional[StateAllocator] = None,
+               base: int = 0) -> int:
         """Cache every full-block chunk of `tokens` along one path.
         `blocks` is the request's block table (position-ordered), so
         blocks[i] holds chunk i's KV.  Existing nodes win collisions
         (the caller's duplicate block stays private and is freed at
         retire); new nodes mark their block cached.  Returns the
-        number of NEW nodes."""
+        number of NEW nodes.  `states` {depth: checkpoint id}: the node
+        at `depth` blocks takes the checkpoint (`state_alloc.adopt`) if it
+        has none, and the entry leaves `states`: what is left found no node,
+        or one that has a checkpoint already.  `base`: the depth the
+        request started from, so that a checkpoint knows what it cost."""
         bs = self.block_size
         node = self.root
         added = 0
@@ -469,6 +594,8 @@ class RadixCache:
                 pass
             child.last_used = now
             node = child
+            if states and child.state is None and i + 1 in states:
+                state_alloc.adopt(states.pop(i + 1), child, i + 1 - base)
         return added
 
     def evictable(self) -> List[tuple]:
@@ -560,7 +687,8 @@ class PagedBatcher:
                  adapters: Optional[Dict[str, Any]] = None,
                  max_resident_models: int = 3,
                  attn_impl: str = "auto",
-                 max_queue: int = 0) -> None:
+                 max_queue: int = 0,
+                 num_states: Optional[int] = None) -> None:
         from collections import OrderedDict
 
         from ray_tpu._private.config import config
@@ -657,8 +785,26 @@ class PagedBatcher:
             1 for m, _ in (cfg.layer_kinds or ()) if m == "sliding")
         self._sliding_held = 0
         self._sliding_in_window = 0
+        # Per-sequence recurrent state, where the model has linear layers:
+        # `num_states` ids, one a live slot and the rest checkpoints that
+        # the radix cache owns (default: four checkpoints a slot).  No other
+        # model has an allocator, and nothing below runs for it.
+        self._states: Optional[StateAllocator] = None
+        self.num_states = 0
+        if any(m == "linear" for m, _ in (cfg.layer_kinds or ())):
+            self.num_states = int(num_states or config.kv_num_states
+                                  or 5 * num_slots)
+            if self.num_states < num_slots:
+                raise ValueError(
+                    f"{self.num_states} state ids for {num_slots} slots")
+            self._states = StateAllocator(
+                self.num_states, PREFILL_CHUNK // self.block_size)
+        self._state_counts = {"restores": 0, "snapshots": 0,
+                              "snapshot_evictions": 0, "snapshots_skipped": 0,
+                              "matched_tokens": 0, "unbacked_tokens": 0}
         self.caches = decoding.init_paged_caches(
-            cfg, num_slots, self.num_blocks, self.block_size, max_len)
+            cfg, num_slots, self.num_blocks, self.block_size, max_len,
+            self.num_states)
         # State that is not positions, where the caches hold any: layers
         # whose state at a block boundary is a tail kept under the block's
         # id (decoding.PagedDecodeCaches.tail_pool).  A prefix hit then also
@@ -694,7 +840,9 @@ class PagedBatcher:
         # Processor: waiting for a dispatch's tokens, and handing them out.
         # Inside `dispatch`: admit, pack, launch, post_admit (the spans of
         # the table above), and inside those the radix walks and the
-        # eviction sweeps; counts of launched dispatches.  And whether the
+        # eviction sweeps, and `state`: the state allocator and the
+        # checkpoint bookkeeping of a model with recurrent state (inside
+        # admit and post_admit); counts of launched dispatches.  And whether the
         # DEVICE had anything to run, on this clock: from a result's
         # arrival (_process_entry) at which every launched dispatch has
         # been read back, to the next launch's return, nothing is in
@@ -709,7 +857,7 @@ class PagedBatcher:
                        "read_wait": 0.0, "process": 0.0,
                        "admit": 0.0, "pack": 0.0, "launch": 0.0,
                        "post_admit": 0.0, "radix_match": 0.0,
-                       "radix_insert": 0.0, "evict": 0.0,
+                       "radix_insert": 0.0, "evict": 0.0, "state": 0.0,
                        "dispatches": 0,
                        "device_starved": 0.0, "device_unasked": 0.0}
         # Shared by the two threads, under _dev_lock for stamps only: when
@@ -912,6 +1060,10 @@ class PagedBatcher:
                             picked_rows=self._moe_counts[1]
                             + self._moe_counts[4]),
                 "writes": dict(self._pool_updates),
+                **({} if self._states is None else {"state": dict(
+                    self._state_counts, ids_used=self._states.used(),
+                    checkpoints=self._states.checkpoints(),
+                    num_states=self.num_states)}),
                 "kv": {"sliding_positions_held": self._sliding_held,
                        "sliding_positions_in_window":
                            self._sliding_in_window,
@@ -954,6 +1106,9 @@ class PagedBatcher:
                 if node.children or node.parent is None:
                     continue       # a sibling eviction re-parented it
                 tree.remove_leaf(node, self._alloc)
+                if node.state is not None:  # its checkpoint goes with it
+                    self._states.drop(node.state)
+                    self._state_counts["snapshot_evictions"] += 1
                 freed += 1
                 self._evictions += 1
         if freed:
@@ -984,6 +1139,18 @@ class PagedBatcher:
                 stack.extend(node.children.values())
                 self._alloc.release_cached(node.block)
         self._radix = {}
+        if self._states is not None:
+            self._states.drop_all()
+
+    def _take_state_locked(self) -> Optional[int]:
+        """A free state id, at the price of the least recently used
+        checkpoint nobody holds where none is free.  Caller holds
+        _kv_lock."""
+        sid = self._states.alloc()
+        if sid is None and self._states.evict_lru():
+            self._state_counts["snapshot_evictions"] += 1
+            sid = self._states.alloc()
+        return sid
 
     # -- multiplexing ------------------------------------------------------
     def _load_model(self, model_id: str):
@@ -1061,8 +1228,19 @@ class PagedBatcher:
             prefix_blocks: List[int] = []
             if self.prefix_cache_enabled:
                 t_m = time.perf_counter()
-                prefix_blocks = self._radix_for(req.model_id).match(
-                    req.prompt)
+                tree = self._radix_for(req.model_id)
+                if self._states is None:
+                    prefix_blocks = tree.match(req.prompt)
+                else:
+                    # usable as far as a checkpoint reaches: h of m blocks
+                    matched, h, state_from = tree.match_with_state(
+                        req.prompt)
+                    prefix_blocks = matched[:h]
+                    # held BEFORE any sweep, as the blocks are: taking this
+                    # request's own id may cost a checkpoint, never this one
+                    req._state_held = state_from
+                    if state_from:
+                        self._states.hold(state_from)
                 self.host_s["radix_match"] += time.perf_counter() - t_m
                 # Hold the matched blocks BEFORE the eviction sweep so
                 # it can never reclaim them out from under the hit (the
@@ -1077,7 +1255,16 @@ class PagedBatcher:
                 if need > self._alloc.available():
                     # backpressure: undo hold
                     self._alloc.decref_many(prefix_blocks)
+                    self._unhold_state_locked(req)
                     return None
+                if self._states is not None:
+                    t_s = time.perf_counter()
+                    own_state = self._take_state_locked()
+                    self.host_s["state"] += time.perf_counter() - t_s
+                    if own_state is None:   # every id a live request's
+                        self._alloc.decref_many(prefix_blocks)
+                        self._unhold_state_locked(req)
+                        return None
                 # Count queries/hits per ADMITTED request, not per
                 # attempt: a backpressured request retries admission
                 # every tick and would otherwise inflate the hit ratio.
@@ -1093,17 +1280,75 @@ class PagedBatcher:
                             km["hits"].inc()
                 new_blocks = self._alloc.alloc(need)
                 req._blocks = prefix_blocks + (new_blocks or [])
+                if self._states is not None:
+                    t_s = time.perf_counter()
+                    self._admit_state_locked(
+                        req, own_state,
+                        len(matched) if self.prefix_cache_enabled else 0,
+                        len(prefix_blocks))
+                    self.host_s["state"] += time.perf_counter() - t_s
             except Exception:
                 # Exception edge between incref and handoff (a raising
                 # eviction sweep / metric sink): the prefix holds would
                 # leak forever — _retire only frees blocks that made it
                 # into req._blocks.  RT013 self-finding.
                 self._alloc.decref_many(prefix_blocks)
+                self._unhold_state_locked(req)
                 raise
         req.cached_tokens = req._prefilled = len(prefix_blocks) * bs
         req.cache_hit = bool(prefix_blocks)
         req._pos_cap = alloc_tokens
         return True
+
+    def _unhold_state_locked(self, req: "_Request") -> None:
+        if self._states is not None and req._state_held:
+            self._states.release(req._state_held)
+            req._state_held = 0
+
+    def _admit_state_locked(self, req: "_Request", sid: int, m: int,
+                            h: int) -> None:
+        """The state side of an admission whose radix match was `m` blocks
+        of which `h` are used (the deepest checkpoint, held since the match
+        as `req._state_held`, until the dispatch that reads it is
+        launched): `sid`
+        for the request's own state, and at most two checkpoints to leave
+        behind, known here, before the dispatch: at block m where m > h
+        (where this prompt left what was cached: the next prompt to branch
+        there hits in full) and at the prompt's last whole block (what the
+        conversation's next turn matches through).  A checkpoint id that
+        cannot be had is skipped and counted; an admission never waits on
+        one.  Caller holds _kv_lock."""
+        counts = self._state_counts
+        req._state_id = sid
+        req._state_from = req._ckpt_base = 0
+        if req._state_held:
+            req._state_from, req._ckpt_base = req._state_held, h
+            self._states.touch(req._state_held)
+            counts["restores"] += 1
+        counts["matched_tokens"] += m * self.block_size
+        counts["unbacked_tokens"] += (m - h) * self.block_size
+        if self.prefix_cache_enabled and self._tile == self.block_size:
+            for depth in sorted({m, len(req.prompt) // self.block_size}):
+                if depth <= h:
+                    continue
+                ckpt = self._take_state_locked()
+                if ckpt is None:
+                    counts["snapshots_skipped"] += 1
+                else:
+                    req._ckpts[depth] = ckpt
+
+    def _release_state_locked(self, req: "_Request") -> None:
+        """What a request still has of the state pool goes back: its own id
+        (`later`: a decode step of it may have been launched already), the
+        checkpoints no node took, its hold.  Caller holds _kv_lock."""
+        if req._state_held:
+            self._states.release(req._state_held)
+            req._state_held = 0
+        for sid in (req._state_id, *req._ckpts.values()):
+            if sid:
+                self._states.free(sid, later=True)
+        req._state_id = 0
+        req._ckpts = {}
 
     def _tiles_left(self, req: "_Request") -> int:
         return -(-(len(req.prompt) - req._prefilled) // self._tile)
@@ -1193,6 +1438,8 @@ class PagedBatcher:
             if req._blocks and not req._blocks_freed:
                 req._blocks_freed = True
                 self._alloc.decref_many(req._blocks)
+            if self._states is not None:
+                self._release_state_locked(req)
         self._update_kv_gauges()
 
     def _finish_request(self, req: "_Request",
@@ -1286,8 +1533,10 @@ class PagedBatcher:
     def _pack(self, rows: int):
         """The fused dispatch's one upload, empty (decoding.
         paged_prefill_decode_packed has the format)."""
-        return np.zeros((rows + 1, max(self._tile + 4 + self.table_width,
-                                       self.num_slots)), np.int32)
+        cols = self._tile + 4 + self.table_width
+        if self._states is not None:    # state_from, state_to[0], [1]
+            cols += 3
+        return np.zeros((rows + 1, max(cols, self.num_slots)), np.int32)
 
     def _warmup(self, jnp) -> None:
         """Compile every dispatch shape up front (each fused width + the
@@ -1343,6 +1592,20 @@ class PagedBatcher:
                        T + 4:T + 4 + len(req._blocks)] = req._blocks
                 if end == len(req.prompt):
                     packed[row - 1, T + 3] = 1
+                if self._states is not None:
+                    # the request's first row of this call starts from a
+                    # checkpoint, its own state or zeros, the others from
+                    # the row before; the last leaves the state in its id,
+                    # a row that ends where a checkpoint was asked for also
+                    # there
+                    at = T + 4 + self.table_width
+                    packed[first:row, at] = -1
+                    packed[first, at] = req._state_from
+                    packed[row - 1, at + 1] = req._state_id
+                    for depth, ckpt in req._ckpts.items():
+                        r = first + (depth * self.block_size - done) // T - 1
+                        if first <= r < row:
+                            packed[r, at + 2] = ckpt
             packed[N, :self.num_slots] = active
         devs = self._launch(lambda: self._dec.paged_prefill_decode_packed(
             self.params, self.caches, jnp.asarray(packed),
@@ -1350,6 +1613,7 @@ class PagedBatcher:
         # Launched: only now do the requests move on.
         for (_, req), take in zip(batch, takes):
             req._prefilled += take
+            req._state_from = req._state_id     # where the rows left it
             more = req._prefilled < len(req.prompt)
             if more and not req._prefilling:    # the first of several
                 self._prefill_counts["multi_chunk_requests"] += 1
@@ -1424,10 +1688,33 @@ class PagedBatcher:
             t_i = time.perf_counter()
             with self._kv_lock:
                 for _, req in batch:
+                    ready = None
+                    if req._ckpts:      # those whose rows are dispatched
+                        ready = {d: req._ckpts.pop(d) for d in list(
+                            req._ckpts) if d * self.block_size
+                            <= req._prefilled}
+                    added = len(ready or ())
                     self._radix_for(req.model_id).insert(
                         req.prompt[:req._prefilled], req._blocks,
-                        self._alloc)
+                        self._alloc, ready, self._states, req._ckpt_base)
+                    if ready is not None:
+                        t_s = time.perf_counter()
+                        self._state_counts["snapshots"] += added - len(ready)
+                        for sid in ready.values():  # a node had one already
+                            self._states.free(sid, later=True)
+                        self.host_s["state"] += time.perf_counter() - t_s
             self.host_s["radix_insert"] += time.perf_counter() - t_i
+        if self._states is not None:
+            # the dispatch that reads the checkpoints is launched: they may
+            # be evicted again (never handed out as a `state_to` of the
+            # dispatch that reads them)
+            t_s = time.perf_counter()
+            with self._kv_lock:
+                for _, req in batch:
+                    if req._state_held:
+                        self._states.release(req._state_held)
+                        req._state_held = 0
+            self.host_s["state"] += time.perf_counter() - t_s
         self._update_kv_gauges()
 
     def _dispatch(self, jnp, span) -> bool:
@@ -1436,6 +1723,9 @@ class PagedBatcher:
         (paged_prefill_decode_packed), so an admission costs no
         dispatch of its own.  `span` is the tick's engine.dispatch, which
         is told here what was launched; -> whether anything was."""
+        if self._states is not None:
+            with self._kv_lock:     # before `live` is read: StateAllocator
+                self._states.settle()
         with self._state_lock:
             # A slot is admittable when empty OR "drained": every token
             # its current request needs is already covered by in-flight
@@ -1766,7 +2056,8 @@ class LLMDeployment:
                  prefix_cache: Optional[bool] = None,
                  adapters: Optional[Dict[str, Any]] = None,
                  max_resident_models: int = 3,
-                 max_queue: int = 0) -> None:
+                 max_queue: int = 0,
+                 num_states: Optional[int] = None) -> None:
         import jax
         from ray_tpu.models import transformer
         cfg = transformer.TransformerConfig(**cfg_kwargs)
@@ -1783,7 +2074,7 @@ class LLMDeployment:
             kv_num_blocks=kv_num_blocks,
             prefix_cache=prefix_cache, adapters=adapters,
             max_resident_models=max_resident_models,
-            max_queue=max_queue)
+            max_queue=max_queue, num_states=num_states)
         # Router probe hook: multiplex-aware pow-2 prefers replicas
         # whose engine already holds the requested adapter merged.
         self.__rtpu_resident_models__ = self.batcher.resident_models

@@ -207,11 +207,15 @@ def test_paged_serving_steps_compile_for_v5e(v5e_devices, model,
 # lanes in the pool) and 8 expert layers likewise, its 7 conv layers none;
 # A.X-K1's 7 latent layers call `mla_prefix_attention` and
 # `mla_paged_attention` (rows of 640 lanes, [64, 640] query tiles), its 6
-# expert layers the product over 4 blocks of F
+# expert layers the product over 4 blocks of F; Olmo-Hybrid's 3 full layers
+# the two paged kernels at 30 heads, its 9 linear layers `gated_delta_chunk`
+# for a pass's prompt rows and `gated_delta_step` for its carried rows, a
+# decode step `gated_delta_step`
 CELL_KERNELS = {"mistral-7b-l16": (1 + 1, 1),
                 "trinity-mini-l5": (5 + 5 + 4, 5 + 4),
                 "lfm2-24b-a2b-l9": (2 + 2 + 8, 2 + 8),
-                "axk1-l7-ep16": (7 + 7 + 6, 7 + 6)}
+                "axk1-l7-ep16": (7 + 7 + 6, 7 + 6),
+                "olmo-hybrid-7b-l12": (3 + 3 + 9 + 9, 3 + 9)}
 
 
 # LFM2's nine unrolled layers compile ~40 s a program here: one test a
@@ -220,8 +224,47 @@ CELL_KERNELS = {"mistral-7b-l16": (1 + 1, 1),
 CELL_PROGRAMS = [pytest.param("mistral-7b-l16", None, id="mistral-7b-l16"),
                  pytest.param("trinity-mini-l5", None, id="trinity-mini-l5")
                  ] + [pytest.param(name, i, id=f"{name}-{i}")
-                      for name in ("lfm2-24b-a2b-l9", "axk1-l7-ep16")
+                      for name in ("lfm2-24b-a2b-l9", "axk1-l7-ep16",
+                                   "olmo-hybrid-7b-l12")
                       for i in range(5)]
+
+
+def _cell_shapes(v5e_devices, config_name):
+    """A serving cell as it runs (benchmarks/configs/<name>.json), as shapes
+    on one v5e chip -> (cfg, serve sizes, params, caches, tile, ladder, the
+    packed upload of N rows, the active mask)."""
+    import json
+    import os
+
+    from benchmarks.lib import spec, worker_util
+    from ray_tpu.models import decoding, transformer as tfm
+    from ray_tpu.serve import llm
+
+    on_chip, shapes = _on_chip_shapes(v5e_devices)
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           config_name + ".json")) as f:
+        config = json.load(f)
+    sv = config["serve"]
+    cfg = tfm.TransformerConfig(**worker_util.with_dtypes(
+        spec.model_kind(config["kind"]).transformer_kwargs(
+            config, max_seq=sv["max_len"], param_dtype=sv["param_dtype"])))
+    W = decoding.paged_table_width(sv["max_len"], sv["kv_block_size"])
+    params = shapes(jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
+        cfg, sv["num_slots"], sv["kv_num_blocks"], sv["kv_block_size"],
+        sv["max_len"], *([sv["num_states"]] if "num_states" in sv else []))))
+    tile, ladder = llm.prefill_shapes(sv["num_slots"], sv["prompt_pad"],
+                                      sv["kv_block_size"])
+    cols = tile + 4 + W + (3 if sv.get("num_states") else 0)
+
+    def packed(N):
+        return jax.ShapeDtypeStruct((N + 1, max(cols, sv["num_slots"])),
+                                    jnp.int32, sharding=on_chip)
+
+    active = jax.ShapeDtypeStruct((sv["num_slots"],), jnp.bool_,
+                                  sharding=on_chip)
+    return cfg, sv, params, caches, tile, ladder, packed, active
 
 
 @pytest.mark.parametrize("config_name,part", CELL_PROGRAMS)
@@ -233,37 +276,23 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     under 1,072 columns, with its window in the paged kernel and the
     grouped expert product, LFM2's 32 / 8 heads of 64 side by side under
     1,072 columns beside its conv layers' tails, A.X-K1's 64 heads over
-    latent rows of 640 lanes with its experts in 4 blocks of F) and the
+    latent rows of 640 lanes with its experts in 4 blocks of F,
+    Olmo-Hybrid's 30 / 30 heads of 128 beside nine layers' states of
+    [15, 96, 384] by state id) and the
     decode-only chunk, as Mosaic kernels,
     inside one chip's memory beside the weights.  (`impl="auto"` asks
     jax.default_backend(): steered here, in the test, as it would read on
     the chip.)"""
-    import json
-    import os
-
-    from benchmarks.lib import spec, worker_util
-    from ray_tpu.models import decoding, transformer as tfm
+    from ray_tpu.models import decoding
     from ray_tpu.serve import llm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    on_chip, shapes = _on_chip_shapes(v5e_devices)
-    with open(os.path.join(spec.BENCH_DIR, "configs",
-                           config_name + ".json")) as f:
-        config = json.load(f)
-    sv = config["serve"]
-    cfg = tfm.TransformerConfig(**worker_util.with_dtypes(
-        spec.model_kind(config["kind"]).transformer_kwargs(
-            config, max_seq=sv["max_len"], param_dtype=sv["param_dtype"])))
-    W = decoding.paged_table_width(sv["max_len"], sv["kv_block_size"])
-    assert W == {"mistral-7b-l16": 48, "trinity-mini-l5": 1072,
-                 "lfm2-24b-a2b-l9": 1072, "axk1-l7-ep16": 1072}[config_name]
-    params = shapes(jax.eval_shape(
-        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
-    caches = shapes(jax.eval_shape(lambda: decoding.init_paged_caches(
-        cfg, sv["num_slots"], sv["kv_num_blocks"], sv["kv_block_size"],
-        sv["max_len"])))
-    tile, ladder = llm.prefill_shapes(sv["num_slots"], sv["prompt_pad"],
-                                      sv["kv_block_size"])
+    cfg, sv, params, caches, tile, ladder, packed, active = _cell_shapes(
+        v5e_devices, config_name)
+    assert caches.block_tables.shape[1] == {
+        "mistral-7b-l16": 48, "trinity-mini-l5": 1072,
+        "lfm2-24b-a2b-l9": 1072, "axk1-l7-ep16": 1072,
+        "olmo-hybrid-7b-l12": 1072}[config_name]
     # the budget is PREFILL_CHUNK tokens whatever the slots are, in at
     # most six programs, none more than 384 positions wider than the one
     # before it up to 896
@@ -275,11 +304,8 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     assert all(b - a <= 384 for a, b in zip(widths, widths[1:])
                if b <= 896)
     for N in ladder if part is None else ladder[part:part + 1]:
-        packed = jax.ShapeDtypeStruct(
-            (N + 1, max(tile + 4 + W, sv["num_slots"])), jnp.int32,
-            sharding=on_chip)
         fused = decoding.paged_prefill_decode_packed.lower(
-            params, caches, packed, cfg, sv["decode_chunk"], tile,
+            params, caches, packed(N), cfg, sv["decode_chunk"], tile,
             attn_impl="kernel").compile()
         assert _custom_calls(fused) >= sum(CELL_KERNELS[config_name])
         _assert_pools_written_by_page(fused, caches)
@@ -288,13 +314,133 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
                 < 15.75 * 2 ** 30)
     if part is not None and part < len(ladder):
         return
-    N = sv["num_slots"]
-    active = jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=on_chip)
     steps = decoding.paged_decode_steps.lower(
         params, caches, active, cfg, sv["decode_chunk"],
         attn_impl="kernel").compile()
     assert _custom_calls(steps) >= CELL_KERNELS[config_name][1]
     _assert_pools_written_by_page(steps, caches)
+
+
+# The serving configurations whose programs must stay what they were when a
+# PR adds an architecture beside them: the lowered text of every program a
+# cell's engine warms up (its fused rungs, the decode-only chunk, the single
+# step), hashed.  tests/data/serving_program_hashes.json holds the hashes of
+# the tree that last meant to change one; `UPDATE_PROGRAM_HASHES=1` writes it
+# anew (a PR that changes a program on purpose says so and does).
+HASHED_CONFIGS = ("mistral-7b-l16", "trinity-mini-l5", "lfm2-24b-a2b-l9",
+                  "axk1-l7-ep16")
+HASH_FILE = "serving_program_hashes.json"
+
+
+def _location_free(text: str) -> str:
+    """A lowered program's text with every Mosaic kernel's serialized body
+    (MLIR bytecode, which carries the checkout's path and the line of every
+    call site) replaced by the hash of its text without locations: the
+    same program from another directory, or after an edit that only moves
+    lines, reads the same."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+
+    def body(found):
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(found.group(1))
+                                  ).operation.get_asm(enable_debug_info=False)
+        return "body=" + hashlib.sha256(asm.encode()).hexdigest()
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+
+
+@pytest.mark.parametrize("config_name", HASHED_CONFIGS)
+def test_accepted_cells_compile_the_programs_they_did(v5e_devices,
+                                                      monkeypatch,
+                                                      config_name):
+    import hashlib
+    import json
+    import os
+
+    from ray_tpu.models import decoding
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, sv, params, caches, tile, ladder, packed, active = _cell_shapes(
+        v5e_devices, config_name)
+    lowered = {f"fused-{N * tile}": decoding.paged_prefill_decode_packed.lower(
+        params, caches, packed(N), cfg, sv["decode_chunk"], tile,
+        attn_impl="kernel") for N in ladder}
+    lowered["decode-steps"] = decoding.paged_decode_steps.lower(
+        params, caches, active, cfg, sv["decode_chunk"], attn_impl="kernel")
+    lowered["decode-step"] = decoding.paged_decode_step.lower(
+        params, caches, active, cfg, attn_impl="kernel")
+    got = {name: hashlib.sha256(_location_free(low.as_text()).encode()
+                                ).hexdigest()
+           for name, low in lowered.items()}
+    path = os.path.join(os.path.dirname(__file__), "data", HASH_FILE)
+    try:
+        with open(path) as f:
+            kept = json.load(f)
+    except FileNotFoundError:
+        kept = {}
+    if os.environ.get("UPDATE_PROGRAM_HASHES"):
+        kept[config_name] = got
+        with open(path, "w") as f:
+            json.dump(kept, f, indent=1, sort_keys=True)
+            f.write("\n")
+    assert kept.get(config_name) == got, (
+        f"{config_name}'s serving programs are not the ones "
+        f"tests/data/{HASH_FILE} holds: "
+        f"{[n for n in got if kept.get(config_name, {}).get(n) != got[n]]}")
+
+
+def test_delta_kernels_and_thirty_heads_compile_for_v5e(v5e_devices):
+    """The kernels arch "olmo_hybrid" brings, alone at the cell's shapes, so
+    that a Mosaic refusal (a pair of heads in 384 lanes, keys of 96, the
+    products with the state in float32, the state's copies by id) shows
+    here: `gated_delta_step` at 32 slots, `gated_delta_chunk` at the
+    narrowest and the widest rung's rows; and the two paged kernels at 30 kv
+    heads of 128 with a group of 1, under 1,072-column tables (the ring
+    holds 2 groups of 8 pages there, where Mistral's 8 heads hold 8)."""
+    from ray_tpu.ops import gated_delta as gd
+    from ray_tpu.ops import paged_attention as pa
+
+    on_chip, _ = _on_chip_shapes(v5e_devices)
+    f32, i32 = jnp.float32, jnp.int32
+
+    def S(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    H, dk, dv, B = 30, 96, 192, 32
+    pool = S(gd.pool_shape(128, H, dk, dv))
+    assert pool.shape == (129, 15, 96, 384)
+    assert _custom_calls(jax.jit(
+        lambda *a: gd.gated_delta_step(*a, impl="kernel")).lower(
+        pool, S((B,), i32), S((B, H, dk)), S((B, H, dk)), S((B, H, dv)),
+        S((B, H)), S((B, H))).compile()) == 1
+    for N in (16, 128):
+        assert _custom_calls(jax.jit(
+            lambda *a: gd.gated_delta_chunk(*a, impl="kernel")).lower(
+            pool, S((N,), i32), S((N, 2), i32), S((N, 16, H, dk)),
+            S((N, 16, H, dk)), S((N, 16, H, dv)), S((N, 16, H)),
+            S((N, 16, H))).compile()) == 1
+    bf = jnp.bfloat16
+    kp = S((4097, 30, 16, 128), bf)
+    assert pa._ring_shape(1072, 30, 16, 128, 2) == (8, 2, 1)
+    assert pa._ring_shape(48, 8, 16, 128, 2)[1] == 8
+    assert _custom_calls(jax.jit(
+        lambda q, k, v, bt, n: pa.paged_attention(
+            q, k, v, bt, n, impl="kernel")).lower(
+        S((B, 30, 128), bf), kp, kp, S((B, 1072), i32), S((B,), i32)
+    ).compile()) == 1
+    for N, P in ((32, 64), (16, 16)):
+        assert _custom_calls(jax.jit(
+            lambda q, k, v, bt, a, b: pa.prefix_attention(
+                q, k, v, bt, a, b, impl="kernel")).lower(
+            S((N, P, 30, 128), bf), kp, kp, S((N, 1072), i32), S((N,), i32),
+            S((N,), i32)).compile()) == 1
 
 
 def test_latent_and_blocked_kernels_compile_for_v5e(v5e_devices):
