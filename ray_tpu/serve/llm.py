@@ -400,8 +400,14 @@ class StateAllocator:
     request retires) or a CHECKPOINT: a copy of the state after a whole
     block of some prompt, owned by the radix node of that block
     (`_RadixNode.state`), so that a prefix hit can start from it.
-    Checkpoints are evicted in LRU order of their last use, one that a
-    request is about to restore (`hold`) never, and a DEAR one (it took more
+    Which checkpoint goes when an id is needed (`evict_lru`): one that a
+    request is about to restore (`hold`) never; a SUPERSEDED one first (a
+    deeper checkpoint lies below its node before the path branches: a
+    conversation's turn k once turn k + 1 has left its own, which every
+    prompt that still matches through it matches on to; read off the tree
+    when the id is needed, so a tenant's system prompt, where every
+    conversation branches, is never one, however often it was restored);
+    then the least recently used; and a DEAR one (it took more
     than `dear_blocks` blocks of prefill to reach from the checkpoint before
     it: a tenant's 16 k system prompt) only when no cheap one is left: a
     turn's checkpoint costs a block or two to make again, a system
@@ -425,6 +431,7 @@ class StateAllocator:
         # the blocks of prefill each cost
         self._ckpts: "OrderedDict[int, _RadixNode]" = OrderedDict()
         self._cost: Dict[int, int] = {}
+        self.superseded_evictions = 0
 
     def used(self) -> int:
         return self.num_states - len(self._free) - len(self._limbo)
@@ -470,15 +477,36 @@ class StateAllocator:
         self._cost.pop(sid, None)
         self.free(sid)
 
+    def superseded(self, sid: int) -> bool:
+        """Whether checkpoint `sid` has a deeper one below it before its
+        path branches (the blocks between two turns' checkpoints: a leaf or
+        a node with several children ends the walk at once)."""
+        node = self._ckpts[sid]
+        while len(node.children) == 1:
+            (node,) = node.children.values()
+            if node.state is not None:
+                return True
+        return False
+
     def evict_lru(self) -> bool:
-        """Free the least recently used cheap checkpoint nobody holds, a
-        dear one where there is none; its block stays cached."""
+        """Free a cheap checkpoint nobody holds: the oldest superseded one,
+        the least recently used where none is; a dear one, in the same
+        order, where no cheap one is left.  Its block stays cached."""
         for dear in (False, True):
-            for sid in self._ckpts:
-                if sid not in self._holds and (
+            oldest = 0
+            for sid, node in self._ckpts.items():
+                if sid in self._holds or not (
                         dear or self._cost[sid] <= self.dear_blocks):
+                    continue
+                # (most are leaves, a conversation's newest: no call)
+                if len(node.children) == 1 and self.superseded(sid):
+                    self.superseded_evictions += 1
                     self.drop(sid)
                     return True
+                oldest = oldest or sid
+            if oldest:
+                self.drop(oldest)
+                return True
         return False
 
     def drop_all(self) -> None:
@@ -597,6 +625,20 @@ class RadixCache:
             if states and child.state is None and i + 1 in states:
                 state_alloc.adopt(states.pop(i + 1), child, i + 1 - base)
         return added
+
+    def branches_at(self, tokens: List[int], depth: int) -> bool:
+        """Whether the node `depth` cached blocks down `tokens`' path has a
+        second child once `tokens`' next block is inserted below it (not
+        where the path has been evicted since it was matched: `tokens`
+        will make it anew, alone)."""
+        bs = self.block_size
+        node = self.root
+        for i in range(depth):
+            node = node.children.get(tuple(tokens[i * bs:(i + 1) * bs]))
+            if node is None:
+                return False
+        nxt = tuple(tokens[depth * bs:(depth + 1) * bs])
+        return len(node.children) + (nxt not in node.children) > 1
 
     def evictable(self) -> List[tuple]:
         """(last_used, node) for every LEAF whose block no request
@@ -801,7 +843,8 @@ class PagedBatcher:
                 self.num_states, PREFILL_CHUNK // self.block_size)
         self._state_counts = {"restores": 0, "snapshots": 0,
                               "snapshot_evictions": 0, "snapshots_skipped": 0,
-                              "matched_tokens": 0, "unbacked_tokens": 0}
+                              "matched_tokens": 0, "unbacked_tokens": 0,
+                              "full_restores": 0}
         self.caches = decoding.init_paged_caches(
             cfg, num_slots, self.num_blocks, self.block_size, max_len,
             self.num_states)
@@ -1063,6 +1106,7 @@ class PagedBatcher:
                 **({} if self._states is None else {"state": dict(
                     self._state_counts, ids_used=self._states.used(),
                     checkpoints=self._states.checkpoints(),
+                    superseded_evictions=self._states.superseded_evictions,
                     num_states=self.num_states)}),
                 "kv": {"sliding_positions_held": self._sliding_held,
                        "sliding_positions_in_window":
@@ -1143,9 +1187,9 @@ class PagedBatcher:
             self._states.drop_all()
 
     def _take_state_locked(self) -> Optional[int]:
-        """A free state id, at the price of the least recently used
-        checkpoint nobody holds where none is free.  Caller holds
-        _kv_lock."""
+        """A free state id, at the price of a checkpoint nobody holds
+        (`StateAllocator.evict_lru`'s choice) where none is free.  Caller
+        holds _kv_lock."""
         sid = self._states.alloc()
         if sid is None and self._states.evict_lru():
             self._state_counts["snapshot_evictions"] += 1
@@ -1313,11 +1357,13 @@ class PagedBatcher:
         launched): `sid`
         for the request's own state, and at most two checkpoints to leave
         behind, known here, before the dispatch: at block m where m > h
-        (where this prompt left what was cached: the next prompt to branch
-        there hits in full) and at the prompt's last whole block (what the
-        conversation's next turn matches through).  A checkpoint id that
-        cannot be had is skipped and counted; an admission never waits on
-        one.  Caller holds _kv_lock."""
+        and the tree BRANCHES there (where this prompt left what was
+        cached: the next prompt to branch there hits in full; where the
+        prompt only lengthens the path, the checkpoint below would
+        supersede this one the moment both are adopted) and at the prompt's
+        last whole block (what the conversation's next turn matches
+        through).  A checkpoint id that cannot be had is skipped and
+        counted; an admission never waits on one.  Caller holds _kv_lock."""
         counts = self._state_counts
         req._state_id = sid
         req._state_from = req._ckpt_base = 0
@@ -1327,9 +1373,13 @@ class PagedBatcher:
             counts["restores"] += 1
         counts["matched_tokens"] += m * self.block_size
         counts["unbacked_tokens"] += (m - h) * self.block_size
+        if 0 < m == h:
+            counts["full_restores"] += 1
         if self.prefix_cache_enabled and self._tile == self.block_size:
-            for depth in sorted({m, len(req.prompt) // self.block_size}):
-                if depth <= h:
+            last = len(req.prompt) // self.block_size
+            for depth in sorted({m, last}):
+                if depth <= h or (depth < last and not self._radix_for(
+                        req.model_id).branches_at(req.prompt, depth)):
                     continue
                 ckpt = self._take_state_locked()
                 if ckpt is None:

@@ -13,6 +13,7 @@ import json
 import math
 import os
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -496,6 +497,78 @@ def test_state_allocator_holds_limbo_and_lru():
     assert b.evict_lru() and kept[0].state is None and not b.evict_lru()
 
 
+@pytest.mark.parametrize("restores", [1, 5],
+                         ids=["restored-once", "restored-many-times"])
+def test_superseded_checkpoints_go_first(restores):
+    """A system prompt (dear) under which three conversations of three turns
+    branch, each turn restoring the checkpoint before it and leaving its own
+    two blocks deeper.  `evict_lru` takes the turns that a deeper checkpoint
+    has superseded, oldest first, then the conversations' newest in LRU
+    order, then the dear ones, superseded first; never a held one; and the
+    system prompt's, where the tree branches, is no superseded one however
+    often it was restored (PR 47's first rule, "a checkpoint restored once
+    goes first", threw it out)."""
+    bs = 2
+    tree, blocks = llm.RadixCache(bs), llm.BlockAllocator(256)
+    a = llm.StateAllocator(16, dear_blocks=4)
+    label = {}
+
+    def leave(prompt, base, name):
+        depth, sid = len(prompt) // bs, a.alloc()
+        left = {depth: sid}
+        tree.insert(prompt, blocks.alloc(depth), blocks, left, a, base)
+        assert not left
+        label[sid] = name
+        return sid
+
+    system = list(range(12))        # 6 blocks to make again: dear
+    newest = [leave(system, 0, "system")] * 3
+    for turn in range(3):
+        for conv in range(3):
+            if turn or conv < restores:
+                a.touch(newest[conv])       # the restore
+            history = system + [100 * (conv + 1) + i
+                                for i in range(4 * (turn + 1))]
+            # conversation 2 fell back to nothing twice: dear checkpoints
+            base = 0 if conv == 2 and turn != 1 else 4 + 2 * turn
+            newest[conv] = leave(history, base, f"c{conv}.t{turn}")
+    assert label[1] == "system"
+    for _ in range(restores - 3):   # further conversations start from it
+        a.touch(1)
+    assert not a.superseded(1)
+    assert [a.superseded(s) for s in newest] == [False] * 3
+    order = [label[s] for s in a._ckpts]
+    held = next(s for s, n in label.items() if n == "c0.t0")
+    a.hold(held)
+    classes = [{"c1.t0", "c0.t1", "c1.t1", "c2.t1"},     # cheap, superseded
+               {"c0.t2", "c1.t2"},                       # cheap, newest
+               {"c2.t0"},                                # dear, superseded
+               {"system", "c2.t2"}]                      # dear
+    want = [n for cls in classes for n in order if n in cls]
+    went = []
+    while True:
+        before = set(a._ckpts)
+        if not a.evict_lru():
+            break
+        (gone,) = before - set(a._ckpts)
+        went.append(label[gone])
+    assert went == want
+    assert a.superseded_evictions == 5 and list(a._ckpts) == [held]
+    a.release(held)
+    assert a.evict_lru() and a.superseded_evictions == 5 and a.used() == 0
+
+
+def test_branches_at_reads_the_tree():
+    tree, alloc = llm.RadixCache(2), llm.BlockAllocator(16)
+    a = [1, 2, 3, 4, 5, 6]
+    tree.insert(a, alloc.alloc(3), alloc)
+    assert not tree.branches_at(a, 2)           # a's own child, alone
+    assert not tree.branches_at(a + [7, 8], 3)  # a leaf: the path lengthens
+    assert tree.branches_at(a[:4] + [9, 9], 2)  # a second child
+    assert tree.branches_at([1, 2, 9, 9], 1)
+    assert not tree.branches_at([8, 8, 9, 9], 1)    # no such path (evicted)
+
+
 def test_match_is_cut_back_to_the_deepest_checkpoint():
     tree = llm.RadixCache(4)
     alloc, states = llm.BlockAllocator(16), llm.StateAllocator(4)
@@ -737,13 +810,125 @@ def test_no_state_id_leaks_over_two_hundred_admissions(model):
         assert eng._states.used() == 0
 
 
+def test_a_branch_point_keeps_its_checkpoint(model):
+    """A checkpoint at block m where m > h is left only where the tree
+    branches there.  B leaves A's path after 3 blocks: the node at 3 gets a
+    second child and a checkpoint, and a prompt that goes on from A's 3
+    blocks, one from B's 5 and one from A's own 5 all hit in full the second
+    time.  C only lengthens a path whose checkpoint went: none at the old
+    end, where C's own would supersede it at once."""
+    cfg, params = model
+    eng = _engine(model, max_len=192, prompt_pad=160)
+    try:
+        a = tokens(5 * BS + 3, seed=90)
+        b = a[:3 * BS] + tokens(2 * BS + 3, seed=91)
+        _run(eng, a)
+        assert _run(eng, b).cached_tokens == 0
+        st = eng.kv_stats()["state"]
+        assert st["snapshots"] == 3 and st["unbacked_tokens"] == 3 * BS
+        again = [a[:3 * BS] + tokens(7, seed=92), b[:5 * BS] + tokens(7, 93),
+                 a[:5 * BS] + tokens(7, seed=94)]
+        for prompt, blocks in zip(again, (3, 5, 5)):
+            hit = _run(eng, prompt)
+            assert hit.cached_tokens == blocks * BS
+            assert _is_greedy(cfg, params, prompt, hit.tokens)
+        st = eng.kv_stats()["state"]
+        assert st["full_restores"] == 3 and st["unbacked_tokens"] == 3 * BS
+        assert st["snapshots"] == 3         # each at a node that has one
+        with eng._kv_lock:
+            eng._states.drop_all()
+        c = a[:5 * BS] + tokens(2 * BS + 3, seed=95)
+        assert _run(eng, c).cached_tokens == 0
+        st = eng.kv_stats()["state"]
+        assert st["snapshots"] == 4 and st["checkpoints"] == 1
+        assert st["full_restores"] == 3     # matched 5, used none: short
+        assert _run(eng, c[:7 * BS] + tokens(5, seed=96)).cached_tokens \
+            == 7 * BS
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("order", ["superseded-first", "plain-lru"])
+def test_conversations_restore_in_full_from_their_newest_checkpoint(
+        model, monkeypatch, order):
+    """The cell's traffic scaled down: 8 slots, 16 callers, 4 tenants
+    primed first (three of them dear), conversations of 5 / 4 / 4 / 3 turns,
+    replies of 8-24 at 8 tokens a dispatch; turn k + 1's prompt is turn k's
+    + its reply + a message.  A turn needs its conversation's newest
+    checkpoint: with the superseded ones going first, 36 ids hold them all
+    (16 + 4 tenants, the slots' 8, and as many again for the checkpoints a
+    dispatch's admissions are about to leave and the ids of requests whose
+    slot was admitted anew before they retired) and a hit is used whole
+    (0-5 short ones of 128 as the threads fall); in plain LRU order (the
+    parent's, PR 47) the turns' old checkpoints, and the branch checkpoints
+    a short restore leaves, push live ones out: 40-51 of 128 are short.
+    (At 32 ids, the cell's three checkpoints a slot, which 4 tenants and 8
+    slots do not scale down to: 17-21 against 64; at 40: 0-1 against
+    21-25.)"""
+    monkeypatch.setattr(llm, "PREFILL_CHUNK", 256)  # one program; dear: 16
+    if order == "plain-lru":
+        monkeypatch.setattr(llm.StateAllocator, "superseded",
+                            lambda self, sid: False)
+        monkeypatch.setattr(llm.RadixCache, "branches_at",
+                            lambda self, tokens, depth: True)
+    slots = 8
+    eng = _engine(model, num_slots=slots, num_states=36, max_len=704,
+                  prompt_pad=640, decode_chunk=8, kv_num_blocks=1200)
+    systems = [tokens(n * BS, seed=200 + n) for n in (4, 18, 20, 22)]
+    turns = (5, 4, 4, 3)
+    errors = []
+
+    def caller(i):
+        try:
+            for conv in range(2):
+                tenant = (i + conv) % 4
+                history = list(systems[tenant])
+                for turn in range(turns[tenant]):
+                    seed = 10_000 + 100 * i + 10 * conv + turn
+                    message, reply = np.random.default_rng(seed).integers(
+                        (8, 8), (33, 25))
+                    history += tokens(int(message), seed=seed)
+                    history += _run(eng, history, max_new=int(reply)).tokens
+        except Exception as e:      # shown by the test's own thread, below
+            errors.append(e)
+
+    try:
+        for system in systems:
+            _run(eng, system + tokens(8, seed=199), max_new=2)
+        assert eng.kv_stats()["state"]["checkpoints"] == 4
+        callers = [threading.Thread(target=caller, args=(i,))
+                   for i in range(2 * slots)]
+        for t in callers:
+            t.start()
+        for t in callers:
+            t.join(600)
+        assert not errors, errors
+        st = eng.kv_stats()
+        hits = st["prefix_cache"]["queries"] - 4
+        assert hits == 2 * 4 * sum(turns)
+        short = hits - st["state"]["full_restores"]
+        if order == "plain-lru":
+            assert short > hits // 5
+            assert st["state"]["superseded_evictions"] == 0
+        else:
+            assert short <= hits // 8
+            assert (st["state"]["unbacked_tokens"] > 0) == (short > 0)
+            assert st["state"]["superseded_evictions"] > hits // 2
+            assert st["state"]["snapshots_skipped"] == 0
+        assert st["state"]["ids_used"] == st["state"]["checkpoints"] <= 36
+    finally:
+        eng.stop()
+    with eng._kv_lock:
+        assert eng._states.used() == 0
+
+
 # -- the benchmark's names -----------------------------------------------------
 def test_the_cell_resolves_its_names():
     loaded = spec.load_cell("serve-olmoh-agent-sessions")
     assert {m["name"] for m in loaded["end_to_end"]} == {
         "decode_tokens_per_s", "setup_s"}
     names = [m["name"] for m in loaded["layer_metrics"]]
-    assert len(names) == 15 and all(n.startswith("olmoh_") for n in names)
+    assert len(names) == 16 and all(n.startswith("olmoh_") for n in names)
     assert loaded["traffic"]["name"] == "agent-sessions"
     assert loaded["config"]["serve"]["num_states"] == 128
     for fn in ("gated_delta_step", "gated_delta_chunk"):
