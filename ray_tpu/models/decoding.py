@@ -146,6 +146,10 @@ class PagedDecodeCaches(NamedTuple):
     state_pool: Tuple = ()
     conv_pool: Tuple = ()
     slot_state: Optional[jax.Array] = None
+    # The sets of slots whose tables agree over their first blocks
+    # (ops/paged_attention.py SharedPrefixes), as the engine found them when
+    # it last changed a table: a decode step reads such a prefix once a set.
+    shared: Optional[Any] = None
 
 
 def paged_table_width(max_len: int, block_size: int) -> int:
@@ -226,6 +230,9 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
                 state[name] = tuple(
                     jnp.zeros(shape, dtype) if c else None for c in linear)
             state["slot_state"] = jnp.zeros((num_slots,), jnp.int32)
+    if num_slots > 1:
+        from ray_tpu.ops.paged_attention import no_shared_prefixes
+        state["shared"] = no_shared_prefixes(num_slots)
     return PagedDecodeCaches(
         block_tables=jnp.zeros((num_slots, w), jnp.int32),
         lengths=jnp.zeros((num_slots,), jnp.int32),
@@ -298,6 +305,8 @@ class DecodeRows(NamedTuple):
     #                          position completes its block, else scratch 0
     state_ids: Optional[jax.Array] = None    # [B] a slot's state id, scratch
     #                          0 where it is not active (linear layers)
+    shared: Optional[Any] = None    # ops/paged_attention.py SharedRows: the
+    #                          prefixes that sets of the slots share
 
 
 def prefill_rows(tables, prefix_lens, suffix_lens, valid, P: int,
@@ -394,18 +403,23 @@ def _attend_rows(q, k_pool, v_pool, rows: PrefillRows, first_block=0,
 
 
 def decode_rows(tables, lengths, active, block_size: int,
-                slot_state=None) -> DecodeRows:
+                slot_state=None, shared=None) -> DecodeRows:
+    """`shared`: the caches' SharedPrefixes (None: every slot attends
+    alone)."""
     B = lengths.shape[0]
     M = tables.shape[1] * block_size
     pos_c = jnp.minimum(lengths, M - 1)
     blocks = jnp.where(active, tables[jnp.arange(B), pos_c // block_size], 0)
     offsets = pos_c % block_size
-    return DecodeRows(lengths[:, None], tables,
-                      jnp.where(active, jnp.minimum(lengths + 1, M), 0),
+    context_lens = jnp.where(active, jnp.minimum(lengths + 1, M), 0)
+    if shared is not None:
+        from ray_tpu.ops.paged_attention import shared_rows
+        shared = shared_rows(shared, tables, context_lens)
+    return DecodeRows(lengths[:, None], tables, context_lens,
                       active, blocks, offsets,
                       jnp.where(offsets == block_size - 1, blocks, 0),
                       None if slot_state is None
-                      else jnp.where(active, slot_state, 0))
+                      else jnp.where(active, slot_state, 0), shared)
 
 
 def _pass_tokens(rows: PrefillRows, step: Optional[DecodeRows]):
@@ -438,8 +452,16 @@ def _attend_pass(q, k_pool, v_pool, rows: PrefillRows,
                                _pa.mla_paged_attention)
         o = jnp.concatenate([o, attend(
             q[0, N * P:], *pools, first_block + step.tables,
-            step.context_lens, **kw).astype(o.dtype)])
+            step.context_lens, shared=_shared_from(step.shared, first_block),
+            **kw).astype(o.dtype)])
     return o[None]
+
+
+def _shared_from(shared, first_block):
+    """A step's SharedRows over a layer's pool inside the stacked one."""
+    if shared is None:
+        return None
+    return shared._replace(tables=first_block + shared.tables)
 
 
 def _write_rows(pool, blocks, offsets, new, prompt=(0, 1)):
@@ -549,7 +571,7 @@ def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
     from ray_tpu.ops import paged_attention as _pa
 
     rows = decode_rows(caches.block_tables, caches.lengths, active,
-                       caches.kp.shape[3])
+                       caches.kp.shape[3], shared=caches.shared)
     x = params["tok_embed"][caches.last_token[:, None]].astype(cfg.dtype)
     if cfg.arch == "gpt2":
         x = x + params["pos_embed"][
@@ -569,7 +591,8 @@ def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
                              v_new[:, 0])
         o = _pa.paged_attention(q[:, 0], k_pool, v_pool,
                                 first + rows.tables, rows.context_lens,
-                                impl=attn_impl)              # [B,H,Dh]
+                                impl=attn_impl,
+                                shared=_shared_from(rows.shared, first))
         attn = jnp.einsum("bshk,hkd->bsd", o[:, None].astype(cfg.dtype),
                           p["wo"].astype(cfg.dtype))
         return (_mlp(p, x + attn, cfg), k_pool, v_pool), None
@@ -583,10 +606,8 @@ def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
     next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     new_last = jnp.where(active, next_tok, caches.last_token)
     new_len = jnp.where(active, caches.lengths + 1, caches.lengths)
-    return PagedDecodeCaches(kp=kp_all, vp=vp_all,
-                             block_tables=caches.block_tables,
-                             lengths=new_len,
-                             last_token=new_last), next_tok
+    return caches._replace(kp=kp_all, vp=vp_all, lengths=new_len,
+                           last_token=new_last), next_tok
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "attn_impl"),
@@ -673,8 +694,10 @@ def _paged_prefill_core(params: Dict[str, Any],
     yields = jnp.arange(N) * P + jnp.clip(suffix_lens - 1, 0, P - 1)
     step = None
     if carried is not None:
+        # (under the sets the caches hold: the slots that were active have
+        # the tables they had when the engine found them)
         step = decode_rows(caches.block_tables, caches.lengths, carried, bs,
-                           caches.slot_state)
+                           caches.slot_state, caches.shared)
         tokens = jnp.concatenate([tokens, caches.last_token[None]], axis=1)
         yields = jnp.concatenate([yields, N * P + jnp.arange(B)])
     if cfg.layer_kinds is not None:
@@ -785,6 +808,12 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
     request's: it gets no decode row in the pass).  2: more of the prompt
     is to come: its K/V are written and its slot stays out of the decode
     steps.
+
+    Row N goes on, where it is wide enough (`shared_columns`), with the
+    prefixes that sets of the slots share once the rows have set their
+    tables (ops/paged_attention.py SharedPrefixes, B // 2 programs: members
+    as slot + 1 [B // 2 * 8] | leader [B // 2] | shared_len [B // 2]; zeros:
+    none); the caches keep them for the decode-only dispatches that follow.
     """
     P = prompt_pad
     B = caches.lengths.shape[0]
@@ -804,6 +833,9 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
         attn_impl, carried=was_active & ~closed, states=states)
     tok = tok.at[at].set(first, mode="drop")[None]
     active = was_active | closed
+    if caches.shared is not None:
+        # The rows changed tables: what the slots share from here on.
+        caches = caches._replace(shared=_uploaded_shared(packed[-1], B))
     if cfg.layer_kinds is not None:
         caches, toks, more = _unrolled_decode_scan(
             params, caches, active, cfg, num_steps - 1, attn_impl)
@@ -814,6 +846,24 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
 
     caches, toks = jax.lax.scan(body, caches, None, length=num_steps - 1)
     return caches, jnp.concatenate([tok, toks])
+
+
+def shared_columns(num_slots: int) -> int:
+    """The columns of the fused upload's last row: the active mask and the
+    slots' shared prefixes (paged_prefill_decode_packed)."""
+    from ray_tpu.ops.paged_attention import SHARED_MEMBERS
+    return num_slots + num_slots // 2 * (SHARED_MEMBERS + 2)
+
+
+def _uploaded_shared(row, B: int):
+    """SharedPrefixes out of the upload's last row; a row that is not
+    `shared_columns` wide has none."""
+    from ray_tpu.ops import paged_attention as _pa
+    if row.shape[0] < shared_columns(B):
+        return _pa.no_shared_prefixes(B)
+    P, K = B // 2, _pa.SHARED_MEMBERS
+    members, rest = row[B:B + P * K], row[B + P * K:B + P * (K + 2)]
+    return _pa.SharedPrefixes(members.reshape(P, K) - 1, rest[:P], rest[P:])
 
 
 # ===========================================================================
@@ -967,14 +1017,15 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
         state.extend((kp, vp))
         return _pa.paged_attention(
             q[:, 0], kp, vp, rows.tables, rows.context_lens,
-            impl=attn_impl, window=model.window_of(cfg, kind))[:, None]
+            impl=attn_impl, window=model.window_of(cfg, kind),
+            shared=rows.shared)[:, None]
 
     def attend_latent(q, row):
         kp = _write_latent(k_pool, rows.blocks, rows.offsets, row[:, 0])
         state.extend((kp, None))
         return _pa.mla_paged_attention(
             q[:, 0], kp, rows.tables, rows.context_lens, impl=attn_impl,
-            **model.latent_kw(cfg))[:, None]
+            shared=rows.shared, **model.latent_kw(cfg))[:, None]
 
     def before(u):
         moved = jnp.concatenate([v_pool[:, 1:], u], axis=1)
@@ -1035,7 +1086,8 @@ def _unrolled_decode_core(params, caches: PagedDecodeCaches, active, cfg,
     scratch block and are routed to no expert."""
     model = unrolled(cfg)
     rows = decode_rows(caches.block_tables, caches.lengths, active,
-                       block_size_of(caches), caches.slot_state)
+                       block_size_of(caches), caches.slot_state,
+                       caches.shared)
     x = model.embed(cfg, params["tok_embed"], caches.last_token[:, None])
     x, state, counts = _unrolled_layers(cfg, params, caches, x, rows,
                                         paged_decode_layer, attn_impl)

@@ -72,7 +72,19 @@ the table's whole width and a prefill had only the gather.
 
 A sliding-window layer passes `window`: only the last `window` positions
 are attended, and the kernel's page stream starts at the group that
-holds the first of them.  The second half of this file is
+holds the first of them.
+
+A prefix that several slots share is read once.  Slots whose tables agree
+over their first blocks (a tenant's system prompt, shared through the radix
+cache) come as `SharedRows`: the decode step of a sequence is then its
+attention over the positions that are its own (the same kernel, its page
+stream starting at the sequence's first own position, the softmax left
+unnormalised) merged by the softmax statistics with its set's attention over
+the shared positions (the same kernel again, one program per up to 8 members:
+their queries side by side in the q block, the leader's table row read to
+the shared length).  The same softmax over the same positions, so the
+reference forms are the oracle as they are; a step with no set skips the
+second call and the merge behind a `lax.cond`.  The second half of this file is
 `prefix_attention`: a prefill chunk's queries over their paged prefix,
 group by group with a running softmax, for prompts longer than one
 prefill.
@@ -82,7 +94,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -101,6 +113,61 @@ _LANES = 128
 _GROUP_POSITIONS = 128
 _RING_GROUPS = 16
 _VMEM_BUDGET = 4 * 2 ** 20
+# Slots one program of a shared prefix holds: with one query head a kv head
+# the q block is a sublane tile of 8 rows anyway, seven of them padding.
+SHARED_MEMBERS = _SUBLANES
+# The shortest prefix worth a program: the kernel's own DMA group.
+SHARED_MIN_POSITIONS = _GROUP_POSITIONS
+
+
+class SharedPrefixes(NamedTuple):
+    """Sets of decoding slots whose block tables agree over their first
+    blocks, as the engine found them (serve/llm.py), in P programs of up to
+    `SHARED_MEMBERS` slots (a set of nine is two programs).  A slot is in at
+    most one program."""
+
+    members: jax.Array       # [P, SHARED_MEMBERS] slot ids, -1: nobody
+    leader: jax.Array        # [P] the slot whose table row the program reads
+    shared_len: jax.Array    # [P] positions its members share, 0: no program
+
+
+class SharedRows(NamedTuple):
+    """`SharedPrefixes` as one decode step's attention calls take them."""
+
+    members: jax.Array       # [P, SHARED_MEMBERS] slot ids (nobody: slot 0)
+    tables: jax.Array        # [P, W] each program's leader's table row
+    lens: jax.Array          # [P] positions shared
+    place: jax.Array         # [B] a slot's p * SHARED_MEMBERS + j, -1: none
+    starts: jax.Array        # [B] a slot's first position of its own
+    some: jax.Array          # [] bool: there is a program (once a step, not
+    #                          once a layer)
+
+
+def no_shared_prefixes(num_slots: int) -> SharedPrefixes:
+    P = num_slots // 2          # a set has two members or more
+    return SharedPrefixes(jnp.full((P, SHARED_MEMBERS), -1, jnp.int32),
+                          jnp.zeros((P,), jnp.int32),
+                          jnp.zeros((P,), jnp.int32))
+
+
+def shared_rows(shared: SharedPrefixes, block_tables: jax.Array,
+                context_lens: jax.Array) -> SharedRows:
+    """One step's `SharedRows`.  A member keeps its place only where it
+    attends beyond what its program shares (a slot that is not active
+    attends to nothing): every position of a sequence is then read by
+    exactly one of the two calls, whatever the engine handed in."""
+    B = block_tables.shape[0]
+    flat = shared.members.reshape(-1)
+    place = jnp.full((B,), -1, jnp.int32).at[
+        jnp.where(flat < 0, B, flat)].set(
+        jnp.arange(flat.shape[0], dtype=jnp.int32), mode="drop")
+    mine = shared.shared_len[jnp.maximum(place, 0) // SHARED_MEMBERS]
+    member = (place >= 0) & (mine < context_lens)
+    return SharedRows(jnp.maximum(shared.members, 0),
+                      block_tables[shared.leader], shared.shared_len,
+                      jnp.where(member, place, -1),
+                      jnp.where(member, mine, 0),
+                      jnp.any(shared.shared_len > 0))
 
 
 def _pool_heads(pages: jax.Array, D: int) -> jax.Array:
@@ -335,15 +402,21 @@ def _group_heads(buf, slot):
                        (hkv, pages * bs, D))
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, *refs, scale, block_size, pages,
-                  tiles, window, v_lanes=None):
+def _paged_kernel(bt_ref, len_ref, *refs, scale, block_size, pages,
+                  tiles, window, v_lanes=None, partial=False):
     """One sequence: every kv head, groups of `pages` pages.  `refs`: the
-    HBM pools, the output, a page-major buffer a pool, then the DMA
-    semaphores, the ring's state and the softmax state.  Two pools are keys
-    and values; ONE is a latent pool (`v_lanes`): the values are the first
-    `v_lanes` lanes of the keys' own buffer, so nothing is copied twice, and
-    p and v meet in the pool's type (64 query heads share every position: the
-    product is as large as the scores').
+    queries, the HBM pools, the output, a page-major buffer a pool, then the
+    DMA semaphores, the ring's state and the softmax state.  Two pools are
+    keys and values; ONE is a latent pool (`v_lanes`): the values are the
+    first `v_lanes` lanes of the keys' own buffer, so nothing is copied
+    twice, and p and v meet in the pool's type (64 query heads share every
+    position: the product is as large as the scores').
+
+    `partial`: one of the two calls of a step with shared prefixes.  `refs`
+    then start with a third prefetched scalar a sequence, its first attended
+    position (where a `window` has context - window), and two outputs follow
+    the first, the softmax as it stands: the accumulator, and the running max
+    (lane 0) and normalizer (the other lanes) in one row of lanes.
 
     The grid is the batch, run in order, and the (sequence, group) pairs
     form ONE stream through a ring of VMEM slots (`ring`, in SMEM, holds
@@ -366,9 +439,15 @@ def _paged_kernel(bt_ref, len_ref, q_ref, *refs, scale, block_size, pages,
     is masked."""
     from jax.experimental import pallas as pl
 
-    np_ = (len(refs) - 6) // 2                # pools
-    pools, o_ref, bufs = refs[:np_], refs[np_], refs[np_ + 1:2 * np_ + 1]
-    sem, ring, m_ref, l_ref, acc_ref = refs[2 * np_ + 1:]
+    start_ref = None
+    if partial:
+        start_ref, *refs = refs
+    q_ref, *refs = refs
+    outs = 3 if partial else 1
+    np_ = (len(refs) - outs - 5) // 2         # pools
+    pools, o_refs = refs[:np_], refs[np_:np_ + outs]
+    bufs = refs[np_ + outs:2 * np_ + outs]
+    sem, ring, m_ref, l_ref, acc_ref = refs[2 * np_ + outs:]
     pv_in = jnp.float32 if v_lanes is None else bufs[0].dtype
     b = pl.program_id(0)
     rows = pl.num_programs(0)
@@ -377,6 +456,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, *refs, scale, block_size, pages,
     ctx = len_ref[b]
 
     def first_group(row):
+        if partial:
+            return lax.div(start_ref[row], span)
         if window is None:
             return jnp.int32(0)
         return lax.div(lax.max(lax.sub(len_ref[row], window), 0), span)
@@ -428,6 +509,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, *refs, scale, block_size, pages,
 
     _reset(m_ref, l_ref, acc_ref)
     lo = None if window is None else lax.sub(ctx, window)
+    if partial:
+        lo = start_ref[b]
 
     def step(n):
         def attend(g):
@@ -461,7 +544,12 @@ def _paged_kernel(bt_ref, len_ref, q_ref, *refs, scale, block_size, pages,
 
     lax.fori_loop(0, lax.sub(lax.add(wide, trips), lax.mul(wide, tiles)),
                   trip, g0)
-    _write_out(o_ref, l_ref, acc_ref)
+    _write_out(o_refs[0], l_ref, acc_ref)
+    if partial:
+        _, acc_out, ml_out = o_refs
+        acc_out[0] = acc_ref[...]
+        lane = lax.broadcasted_iota(jnp.int32, ml_out.shape[1:], 2)
+        ml_out[0] = jnp.where(lax.eq(lane, 0), m_ref[...], l_ref[...])
 
 
 def _paged_kernel_narrow(bt_ref, len_ref, q_ref, *refs, scale, block_size,
@@ -503,75 +591,185 @@ def _paged_kernel_narrow(bt_ref, len_ref, q_ref, *refs, scale, block_size,
         _write_out(o_ref, l_ref, acc_ref)
 
 
-def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *, scale,
-               window, interpret):
+def _stream_call(qg, pools, block_tables, context_lens, starts=None, *,
+                 scale, interpret, name, window=None, v_lanes=None):
+    """`_paged_kernel` over qg [N, Hkv, R, D] (R whole sublane tiles: the
+    rows one program holds of every kv head) and the N rows of the table
+    -> o [N, Hkv, R, Dv].  With `starts` [N] the call is `partial`: -> (o,
+    accumulator [N, Hkv, R, Dv], max and normalizer [N, Hkv, R, 128]), the
+    last two float32."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, D = q.shape
-    hkv, bs = k_pool.shape[1], k_pool.shape[2]
-    W = block_tables.shape[1]
-    groups = H // hkv
-    gp = -(-groups // _SUBLANES) * _SUBLANES
-    pages, depth, tiles = _ring_shape(W, hkv, bs, D, k_pool.dtype.itemsize)
-    qg = q.reshape(B, hkv, groups, D).astype(
-        jnp.promote_types(q.dtype, k_pool.dtype))
-    if gp != groups:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - groups), (0, 0)))
-    softmax_state = [pltpu.VMEM((hkv, gp, 1), jnp.float32),
-                     pltpu.VMEM((hkv, gp, 1), jnp.float32),
-                     pltpu.VMEM((hkv, gp, D), jnp.float32)]
+    N, hkv, R, D = qg.shape
+    pool = pools[0]
+    bs, W = pool.shape[2], block_tables.shape[1]
+    dv = D if v_lanes is None else v_lanes
+    pages, depth, tiles = _ring_shape(W, hkv, bs, D, pool.dtype.itemsize,
+                                      pools=len(pools))
 
     def q_index(b, *_):
         return (b, 0, 0, 0)
 
-    if D % _LANES == 0:
-        # The pools stay in HBM: the body copies whole pages, addressed
-        # through the scalar-prefetched block table.
-        kernel = functools.partial(_paged_kernel, tiles=tiles)
-        grid = (B,)
-        kv_specs = [pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)] * 2
-        kv_args = [k_pool, v_pool]
-        scratch = [pltpu.VMEM((depth, pages, hkv, bs, D), k_pool.dtype),
-                   pltpu.VMEM((depth, pages, hkv, bs, D), v_pool.dtype),
-                   pltpu.SemaphoreType.DMA((2, depth)),
-                   pltpu.SMEM((5,), jnp.int32)] + softmax_state
-    else:
-        def page_index(j):
-            def index(b, g, bt_ref, len_ref):
-                # Page j of group g or, where that page is dead, the
-                # last live page this operand held in the row (page j
-                # itself in a row too short to have one).
-                live = pl.cdiv(len_ref[b], bs)
-                held = jnp.maximum((live - 1 - j) // pages * pages + j, j)
-                return (bt_ref[b, jnp.minimum(g * pages + j, held)],
-                        0, 0, 0)
-            return index
+    def rows(lanes):
+        return pl.BlockSpec((1, hkv, R, lanes), q_index)
 
-        kernel, grid = _paged_kernel_narrow, (B, -(-W // pages))
-        kv_specs = [pl.BlockSpec((1, hkv, bs, D), page_index(j))
-                    for j in range(pages)] * 2
-        kv_args = [k_pool] * pages + [v_pool] * pages
-        scratch = softmax_state
+    partial = starts is not None
+    # A q block of hundreds of rows (the latent pool's 8 members x 64 heads)
+    # takes more than the default scoped VMEM, as `_mla_prefix_fwd`'s does.
+    # Asked for only there: with it on every shared call the TPU compiler
+    # crashed on LFM2's fused programs (its memory-space assignment; PR 49).
+    vmem_limit = 48 * 2 ** 20 if hkv * R * dv * 4 >= 2 ** 20 else None
+    scalars = (block_tables.astype(jnp.int32), context_lens)
+    out_specs = rows(dv)
+    out_shape = jax.ShapeDtypeStruct((N, hkv, R, dv), qg.dtype)
+    if partial:
+        scalars += (starts.astype(jnp.int32),)
+        out_specs = [out_specs, rows(dv), rows(_LANES)]
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct((N, hkv, R, lanes), jnp.float32)
+            for lanes in (dv, _LANES)]
+    # The pools stay in HBM: the body copies whole pages, addressed
+    # through the scalar-prefetched block table.
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, scale=scale, block_size=bs,
+                          pages=pages, tiles=tiles, window=window,
+                          v_lanes=v_lanes, partial=partial),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(N,),
+            in_specs=[rows(D)] + [pl.BlockSpec(
+                memory_space=pltpu.MemorySpace.ANY)] * len(pools),
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((depth, pages, hkv, bs, D), p.dtype)
+                for p in pools] + [
+                pltpu.SemaphoreType.DMA((len(pools), depth)),
+                pltpu.SMEM((5,), jnp.int32),
+                pltpu.VMEM((hkv, R, 1), jnp.float32),
+                pltpu.VMEM((hkv, R, 1), jnp.float32),
+                pltpu.VMEM((hkv, R, dv), jnp.float32)]),
+        out_shape=out_shape,
+        # Softmax state and the DMA stream cross programs: in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+        name=name,
+    )(*scalars, qg, *pools)
 
+
+def _sublane_rows(qg):
+    """qg [.., R, D] with zero rows up to whole sublane tiles."""
+    pad = -qg.shape[-2] % _SUBLANES
+    if not pad:
+        return qg
+    return jnp.pad(qg, [(0, 0)] * (qg.ndim - 2) + [(0, pad), (0, 0)])
+
+
+def _attend_shared(qg, pools, block_tables, context_lens, shared: SharedRows,
+                   **kw):
+    """qg [B, Hkv, G, D] -> [B, Hkv, G, Dv]: every sequence over what is
+    its own, every program of `shared` over what its members share (their
+    queries side by side in its q block: row j * G + g is member j's),
+    merged by the softmax statistics in float32.  A step with no program is
+    the first call alone, normalised by the kernel as ever."""
+    (B, hkv, G, D), (P, K) = qg.shape, shared.members.shape
+    o, acc, ml = _stream_call(_sublane_rows(qg), pools, block_tables,
+                              context_lens, shared.starts, **kw)
+
+    def merged():
+        mine, m, l = acc[:, :, :G], ml[:, :, :G, :1], ml[:, :, :G, 1:2]
+        qs = qg[shared.members].transpose(0, 2, 1, 3, 4).reshape(
+            P, hkv, K * G, D)
+        _, acc_s, ml_s = _stream_call(
+            qs, pools, shared.tables, shared.lens,
+            jnp.zeros_like(shared.lens), **kw)
+
+        def of_slots(a, none):      # [P, Hkv, K * G, x] -> [B, Hkv, G, x]
+            a = a.reshape(P, hkv, K, G, -1).transpose(0, 2, 1, 3, 4).reshape(
+                P * K, hkv, G, -1)[jnp.maximum(shared.place, 0)]
+            return jnp.where((shared.place >= 0)[:, None, None, None], a,
+                             none)
+
+        m_s, l_s = of_slots(ml_s[..., :1], NEG_INF), of_slots(ml_s[..., 1:2],
+                                                              0.0)
+        m_new = jnp.maximum(m, m_s)
+        a, b = jnp.exp(m - m_new), jnp.exp(m_s - m_new)
+        l_new = l * a + l_s * b
+        return ((mine * a + of_slots(acc_s, 0.0) * b)   # zero-length row ->
+                / jnp.where(l_new == 0.0, 1.0, l_new)).astype(o.dtype)  # 0
+
+    return lax.cond(shared.some, merged, lambda: o[:, :, :G])
+
+
+def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *shared, scale,
+               window, interpret):
+    B, H, D = q.shape
+    hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    W = block_tables.shape[1]
+    groups = H // hkv
+    qg = q.reshape(B, hkv, groups, D).astype(
+        jnp.promote_types(q.dtype, k_pool.dtype))
     # The kernels walk the table as far as the lengths say.
     context_lens = jnp.minimum(context_lens.astype(jnp.int32), W * bs)
-    o = pl.pallas_call(
-        functools.partial(kernel, scale=scale, block_size=bs, pages=pages,
-                          window=window),
+    kw = dict(scale=scale, interpret=interpret, name="paged_attention")
+    if shared:
+        o = _attend_shared(qg, (k_pool, v_pool), block_tables, context_lens,
+                           SharedRows(*shared), **kw)
+    elif D % _LANES == 0:
+        o = _stream_call(_sublane_rows(qg), (k_pool, v_pool), block_tables,
+                         context_lens, window=window, **kw)
+    else:
+        o = _narrow_call(_sublane_rows(qg), k_pool, v_pool, block_tables,
+                         context_lens, scale=scale, window=window,
+                         interpret=interpret)
+    return o[:, :, :groups].reshape(B, H, D).astype(q.dtype)
+
+
+def _narrow_call(qg, k_pool, v_pool, block_tables, context_lens, *, scale,
+                 window, interpret):
+    """`_paged_kernel_narrow` over qg [B, Hkv, R, D]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, hkv, gp, D = qg.shape
+    bs, W = k_pool.shape[2], block_tables.shape[1]
+    pages = _ring_shape(W, hkv, bs, D, k_pool.dtype.itemsize)[0]
+
+    def q_index(b, *_):
+        return (b, 0, 0, 0)
+
+    def page_index(j):
+        def index(b, g, bt_ref, len_ref):
+            # Page j of group g or, where that page is dead, the
+            # last live page this operand held in the row (page j
+            # itself in a row too short to have one).
+            live = pl.cdiv(len_ref[b], bs)
+            held = jnp.maximum((live - 1 - j) // pages * pages + j, j)
+            return (bt_ref[b, jnp.minimum(g * pages + j, held)],
+                    0, 0, 0)
+        return index
+
+    return pl.pallas_call(
+        functools.partial(_paged_kernel_narrow, scale=scale, block_size=bs,
+                          pages=pages, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid,
-            in_specs=[pl.BlockSpec((1, hkv, gp, D), q_index)] + kv_specs,
+            num_scalar_prefetch=2, grid=(B, -(-W // pages)),
+            in_specs=[pl.BlockSpec((1, hkv, gp, D), q_index)] + [
+                pl.BlockSpec((1, hkv, bs, D), page_index(j))
+                for j in range(pages)] * 2,
             out_specs=pl.BlockSpec((1, hkv, gp, D), q_index),
-            scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((B, hkv, gp, D), q.dtype),
-        # Softmax state (and the DMA stream) cross programs: in order.
+            scratch_shapes=[pltpu.VMEM((hkv, gp, 1), jnp.float32),
+                            pltpu.VMEM((hkv, gp, 1), jnp.float32),
+                            pltpu.VMEM((hkv, gp, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, hkv, gp, D), qg.dtype),
+        # Softmax state crosses programs: in order.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * len(grid)),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(block_tables.astype(jnp.int32), context_lens, qg, *kv_args)
-    return o[:, :, :groups].reshape(B, H, D)
+    )(block_tables.astype(jnp.int32), context_lens, qg,
+      *([k_pool] * pages + [v_pool] * pages))
 
 
 def _validate_paged(q, k_pool, v_pool):
@@ -591,10 +789,19 @@ def _validate_paged(q, k_pool, v_pool):
             f"multiple of {_SUBLANES} (the (bs, D) tile's sublane dim)")
 
 
+def _unshared(reference):
+    """A reference form as the other platforms' branch of a kernel that takes
+    `SharedRows`: a grouping changes no result."""
+    def gather(q, *args):
+        return reference(q, *args[:len(args) - len(SharedRows._fields)])
+    return gather
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "window"))
 def paged_attention_kernel(q, k_pool, v_pool, block_tables, context_lens,
                            scale: Optional[float] = None,
-                           window: Optional[int] = None) -> jax.Array:
+                           window: Optional[int] = None,
+                           shared: Optional[SharedRows] = None) -> jax.Array:
     """Pallas paged attention: the compiled kernel where the program is
     lowered for a TPU, the Pallas interpreter elsewhere off a TPU host (CPU
     parity tests; `compiled_on_tpu`).  Jitted so that a process traces
@@ -604,36 +811,41 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, context_lens,
     _validate_paged(q, k_pool, v_pool)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[2])
     kw = dict(scale=scale, window=window)
+    reference = functools.partial(paged_attention_reference, **kw)
+    if window is not None or k_pool.shape[3] % _LANES:
+        shared = None       # a sliding layer and the narrow form: as they were
+    if shared is not None:
+        reference = _unshared(reference)
     return _side_by_side(
         functools.partial(
             compiled_on_tpu, functools.partial(_paged_fwd, **kw),
-            gather=functools.partial(paged_attention_reference, **kw)),
-        q, k_pool, v_pool, block_tables, context_lens)
+            gather=reference),
+        q, k_pool, v_pool, block_tables, context_lens, *(shared or ()))
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, context_lens: jax.Array,
                     scale: Optional[float] = None,
                     impl: str = "auto",
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    shared: Optional[SharedRows] = None) -> jax.Array:
     """Dispatcher.  "auto" is the Pallas kernel on a TPU backend — a
     shape the kernel cannot take raises there, it never quietly becomes
     the gather — and the gather reference on any other backend.  With a
     `window` only the last `window` positions are attended (the query,
-    at context_lens - 1, among them).
+    at context_lens - 1, among them).  `shared`: the prefixes that sets of
+    the B sequences share, which the kernel then reads once a set (the
+    reference gathers every sequence's own table whatever is shared).
 
     Decode has no backward pass, so there is no custom VJP — the
     reference path stays differentiable by construction if anyone ever
     scores with it.
     """
-    if impl == "reference":
-        return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                         context_lens, scale, window)
     if impl == "kernel" or (impl == "auto"
                             and jax.default_backend() == "tpu"):
         return paged_attention_kernel(q, k_pool, v_pool, block_tables,
-                                      context_lens, scale, window)
-    if impl != "auto":
+                                      context_lens, scale, window, shared)
+    if impl not in ("auto", "reference"):
         raise ValueError(f"unknown paged attention impl {impl!r}")
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
                                      context_lens, scale, window)
@@ -933,11 +1145,8 @@ def mla_prefix_attention_reference(q, pool, block_tables, prefix_lens,
     return jnp.einsum("nhpm,nmc->nphc", w, k[..., :v_dim]).astype(q.dtype)
 
 
-def _mla_paged_fwd(q, pool, block_tables, context_lens, *, scale, v_dim,
-                   interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
+def _mla_paged_fwd(q, pool, block_tables, context_lens, *shared, scale,
+                   v_dim, interpret):
     B, H, _ = q.shape
     bs, Dp = pool.shape[2], pool.shape[3]
     W = block_tables.shape[1]
@@ -946,41 +1155,18 @@ def _mla_paged_fwd(q, pool, block_tables, context_lens, *, scale, v_dim,
             f"latent paged attention kernel: pool rows of {Dp} lanes, values "
             f"of {v_dim}, blocks of {bs}: whole 128-lane rows and whole "
             f"{_SUBLANES}-row tiles only")
-    gp = -(-H // _SUBLANES) * _SUBLANES
-    pages, depth, tiles = _ring_shape(W, 1, bs, Dp, pool.dtype.itemsize,
-                                      pools=1)
     qg = to_lanes(q, pool).astype(jnp.promote_types(q.dtype, pool.dtype))[
         :, None]                                            # [B, 1, H, Dp]
-    if gp != H:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - H), (0, 0)))
-
-    def q_index(b, *_):
-        return (b, 0, 0, 0)
-
     context_lens = jnp.minimum(context_lens.astype(jnp.int32), W * bs)
-    o = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, block_size=bs,
-                          pages=pages, tiles=tiles, window=None,
-                          v_lanes=v_dim),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B,),
-            in_specs=[pl.BlockSpec((1, 1, gp, Dp), q_index),
-                      pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
-            out_specs=pl.BlockSpec((1, 1, gp, v_dim), q_index),
-            scratch_shapes=[
-                pltpu.VMEM((depth, pages, 1, bs, Dp), pool.dtype),
-                pltpu.SemaphoreType.DMA((1, depth)),
-                pltpu.SMEM((5,), jnp.int32),
-                pltpu.VMEM((1, gp, 1), jnp.float32),
-                pltpu.VMEM((1, gp, 1), jnp.float32),
-                pltpu.VMEM((1, gp, v_dim), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, 1, gp, v_dim), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="mla_paged_attention",
-    )(block_tables.astype(jnp.int32), context_lens, qg, pool)
-    return o[:, 0, :H]
+    kw = dict(scale=scale, v_lanes=v_dim, interpret=interpret,
+              name="mla_paged_attention")
+    if shared:
+        o = _attend_shared(qg, (pool,), block_tables, context_lens,
+                           SharedRows(*shared), **kw)
+    else:
+        o = _stream_call(_sublane_rows(qg), (pool,), block_tables,
+                         context_lens, **kw)
+    return o[:, 0, :H].astype(q.dtype)
 
 
 # Query rows (queries x heads) one program of the latent prefix kernel
@@ -1047,13 +1233,17 @@ def _mla_prefix_fwd(q, pool, block_tables, prefix_lens, suffix_lens, *,
 
 @functools.partial(jax.jit, static_argnames=("scale", "v_dim"))
 def mla_paged_attention_kernel(q, pool, block_tables, context_lens, *,
-                               scale: float, v_dim: int) -> jax.Array:
+                               scale: float, v_dim: int,
+                               shared: Optional[SharedRows] = None
+                               ) -> jax.Array:
     _validate_latent(q, pool, v_dim)
     kw = dict(scale=scale, v_dim=v_dim)
+    reference = functools.partial(mla_paged_attention_reference, **kw)
+    if shared is not None:
+        reference = _unshared(reference)
     return compiled_on_tpu(
         functools.partial(_mla_paged_fwd, **kw), q, pool, block_tables,
-        context_lens,
-        gather=functools.partial(mla_paged_attention_reference, **kw))
+        context_lens, *(shared or ()), gather=reference)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "v_dim"))
@@ -1078,15 +1268,20 @@ def _latent_impl(impl: str, kernel, reference, what: str):
 
 
 def mla_paged_attention(q, pool, block_tables, context_lens, *, scale: float,
-                        v_dim: int, impl: str = "auto") -> jax.Array:
+                        v_dim: int, impl: str = "auto",
+                        shared: Optional[SharedRows] = None) -> jax.Array:
     """One query a sequence, q [B, H, c + r], over the sequence's latent
     rows -> [B, H, v_dim].  Dispatcher as `paged_attention`: "auto" is the
-    kernel on a TPU backend, the gather on any other."""
+    kernel on a TPU backend, the gather on any other; `shared` as there (a
+    program's q block is then 8 members x H heads over the one pool)."""
     _validate_latent(q, pool, v_dim)
-    return _latent_impl(impl, mla_paged_attention_kernel,
-                        mla_paged_attention_reference,
-                        "latent paged attention")(
-        q, pool, block_tables, context_lens, scale=scale, v_dim=v_dim)
+    attend = _latent_impl(impl, mla_paged_attention_kernel,
+                          mla_paged_attention_reference,
+                          "latent paged attention")
+    kw = dict(scale=scale, v_dim=v_dim)
+    if attend is mla_paged_attention_kernel:
+        kw.update(shared=shared)
+    return attend(q, pool, block_tables, context_lens, **kw)
 
 
 def mla_prefix_attention(q, pool, block_tables, prefix_lens, suffix_lens, *,

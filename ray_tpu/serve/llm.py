@@ -189,6 +189,7 @@ class _Request:
     # a reference on.
     _pos_cap: int = 0
     _blocks: List[int] = field(default_factory=list)
+    _table: Optional[np.ndarray] = None     # `_blocks` as the uploads take it
     _blocks_freed: bool = False
     # A model with per-sequence recurrent state (StateAllocator): the id the
     # request's state lives in, what its next prefill row starts from (a
@@ -701,6 +702,47 @@ def prefill_shapes(num_slots: int, prompt_pad: int, block_size: int):
                         | {widest})
 
 
+def find_shared_prefixes(tables: Dict[int, np.ndarray], block_size: int,
+                         num_slots: int):
+    """The prefixes that sets of decoding slots share, from their block lists
+    (`tables`: slot -> its request's blocks; requests hold the SAME blocks
+    over what the radix cache gave them both) -> (members [P, 8] slot ids, -1
+    nobody; leader [P]; shared_len [P] positions, 0: no program), P =
+    num_slots // 2: ops/paged_attention.py SharedPrefixes.  One level: a
+    set is the slots whose lists start on one block, what it shares the
+    longest prefix common to ALL of them (a tenant's system prompt; where
+    some of a set share more, a conversation's earlier turns, that part is
+    read by each); it counts from the one DMA group of the paged kernel
+    on, and is cut into programs of 8 members."""
+    from ray_tpu.ops.paged_attention import (SHARED_MEMBERS,
+                                             SHARED_MIN_POSITIONS)
+    P = num_slots // 2
+    members = np.full((P, SHARED_MEMBERS), -1, np.int32)
+    leader, shared_len = np.zeros((P,), np.int32), np.zeros((P,), np.int32)
+    sets: Dict[int, List[int]] = {}
+    for slot, table in tables.items():
+        if len(table):
+            sets.setdefault(int(table[0]), []).append(slot)
+    p = 0
+    for slots in sets.values():
+        if len(slots) < 2:
+            continue
+        n = min(len(tables[s]) for s in slots)
+        first = tables[slots[0]][:n]
+        same = np.ones((n,), bool)
+        for s in slots[1:]:
+            same &= tables[s][:n] == first
+        blocks = n if same.all() else int(same.argmin())
+        if blocks * block_size < SHARED_MIN_POSITIONS:
+            continue
+        for i in range(0, len(slots), SHARED_MEMBERS):
+            part = slots[i:i + SHARED_MEMBERS]
+            members[p, :len(part)] = part
+            leader[p], shared_len[p] = part[0], blocks * block_size
+            p += 1
+    return members, leader, shared_len
+
+
 class PagedBatcher:
     """Slot-based continuous batching engine over a paged KV cache: a
     block pool, a radix prefix cache and multiplexed adapter hot-swap
@@ -823,6 +865,20 @@ class PagedBatcher:
         # Updates the dispatches' K/V writes made to the pools, by page and
         # by D-wide row (decoding.pool_updates, from static shapes).
         self._pool_updates = {"page_updates": 0, "row_updates": 0}
+        # What the decode steps' attention had to read and what it read,
+        # in cached positions a step: every decoding slot's context, and
+        # the same with a prefix that a set shares counted once a program
+        # (find_shared_prefixes).  `_own_start` [slot] and `_shared_read`
+        # are the sets the device holds: the last fused dispatch's.
+        self._decode_reads = {"context_positions": 0,
+                              "streamed_positions": 0,
+                              # summed over fused dispatches: the programs
+                              # of their sets, the slots in them and the
+                              # positions a program shares
+                              "shared_programs": 0, "shared_members": 0,
+                              "shared_positions": 0}
+        self._own_start = np.zeros((num_slots,), np.int64)
+        self._shared_read = 0
         self._sliding_layers = sum(
             1 for m, _ in (cfg.layer_kinds or ()) if m == "sliding")
         self._sliding_held = 0
@@ -1103,6 +1159,7 @@ class PagedBatcher:
                             picked_rows=self._moe_counts[1]
                             + self._moe_counts[4]),
                 "writes": dict(self._pool_updates),
+                "decode": dict(self._decode_reads),
                 **({} if self._states is None else {"state": dict(
                     self._state_counts, ids_used=self._states.used(),
                     checkpoints=self._states.checkpoints(),
@@ -1324,6 +1381,7 @@ class PagedBatcher:
                             km["hits"].inc()
                 new_blocks = self._alloc.alloc(need)
                 req._blocks = prefix_blocks + (new_blocks or [])
+                req._table = np.asarray(req._blocks, np.int32)
                 if self._states is not None:
                     t_s = time.perf_counter()
                     self._admit_state_locked(
@@ -1586,7 +1644,8 @@ class PagedBatcher:
         cols = self._tile + 4 + self.table_width
         if self._states is not None:    # state_from, state_to[0], [1]
             cols += 3
-        return np.zeros((rows + 1, max(cols, self.num_slots)), np.int32)
+        return np.zeros((rows + 1, max(
+            cols, self._dec.shared_columns(self.num_slots))), np.int32)
 
     def _warmup(self, jnp) -> None:
         """Compile every dispatch shape up front (each fused width + the
@@ -1610,13 +1669,14 @@ class PagedBatcher:
             attn_impl=self._attn_impl)[:2]
         np.asarray(toks)
 
-    def _fused_dispatch(self, jnp, batch: List[tuple], active,
-                        chunk: int):
+    def _fused_dispatch(self, jnp, batch: List[tuple], live: List[tuple],
+                        active, chunk: int):
         """`batch` as _pop_admissions cut it: every request in it gets at
         least one row.  Its uncached tokens go into rows of `_tile`, in
         order, until the widest program is full; a request cut short there
-        comes back with the next dispatch.  -> (device arrays, rows of the
-        program that ran)."""
+        comes back with the next dispatch.  `live`: the (slot, request)
+        pairs of `active`.  -> (device arrays, rows of the program that
+        ran)."""
         T = self._tile
         with _Phase(self, SPAN_PACK, "pack"):
             room = self._prefill_rows[-1]
@@ -1639,7 +1699,7 @@ class PagedBatcher:
                     packed[row, T:T + 4] = (n, start, slot, 2)
                     row += 1
                 packed[first:row,
-                       T + 4:T + 4 + len(req._blocks)] = req._blocks
+                       T + 4:T + 4 + len(req._table)] = req._table
                 if end == len(req.prompt):
                     packed[row - 1, T + 3] = 1
                 if self._states is not None:
@@ -1657,6 +1717,18 @@ class PagedBatcher:
                         if first <= r < row:
                             packed[r, at + 2] = ckpt
             packed[N, :self.num_slots] = active
+            # The tables the slots decode from once this dispatch's rows
+            # have set theirs, and what sets of them share.
+            tables = {slot: req._table for slot, req in live}
+            for (slot, req), take in zip(batch, takes):
+                tables.pop(slot, None)
+                if req._prefilled + take == len(req.prompt):
+                    tables[slot] = req._table
+            shared = find_shared_prefixes(tables, self.block_size,
+                                          self.num_slots)
+            packed[N, self.num_slots:self._dec.shared_columns(
+                self.num_slots)] = np.concatenate(
+                [shared[0].reshape(-1) + 1, *shared[1:]])
         devs = self._launch(lambda: self._dec.paged_prefill_decode_packed(
             self.params, self.caches, jnp.asarray(packed),
             self.cfg, chunk, T, attn_impl=self._attn_impl))
@@ -1673,7 +1745,41 @@ class PagedBatcher:
         self._prefill_counts["padded_tokens"] += N * T
         self._rung_dispatches[str(N * T)] += 1
         self._count_writes(N, chunk)
+        # The pass's carried steps are read under the sets of the dispatch
+        # before, the steps after it under these.
+        admitted = {slot: len(req.prompt) for slot, req in batch}
+        self._count_decode_reads(
+            [i for i, _ in live if i not in admitted], 1)
+        self._hold_shared(shared[0], shared[2])
+        self._count_decode_reads(list(tables), chunk - 1, admitted)
         return devs, N
+
+    def _hold_shared(self, members, shared_len) -> None:
+        """The sets the device holds from here on (find_shared_prefixes)."""
+        self._own_start[:] = 0
+        for row, positions in zip(members, shared_len):
+            self._own_start[row[row >= 0]] = positions
+        self._shared_read = int(shared_len.sum())
+        with self._kv_lock:
+            reads = self._decode_reads
+            reads["shared_programs"] += int((shared_len > 0).sum())
+            reads["shared_members"] += int((members >= 0).sum())
+            reads["shared_positions"] += self._shared_read
+
+    def _count_decode_reads(self, slots: List[int], steps: int,
+                            admitted: Optional[Dict[int, int]] = None
+                            ) -> None:
+        """`steps` decode steps of `slots`, each at the context the
+        dispatches before this one left it (`admitted`: at its prompt),
+        under the sets the device holds."""
+        with self._state_lock:
+            context = sum((admitted or {}).get(i, self._disp_len[i])
+                          for i in slots)
+        streamed = context + self._shared_read - int(
+            self._own_start[slots].sum())
+        with self._kv_lock:
+            self._decode_reads["context_positions"] += steps * context
+            self._decode_reads["streamed_positions"] += steps * streamed
 
     def _count_writes(self, rows: int, chunk: int) -> None:
         pages, by_row = self._dec.pool_updates(self.caches, rows,
@@ -1827,7 +1933,8 @@ class PagedBatcher:
             # lands in prefill_s, not queue_s.
             admit_t = time.time()
             try:
-                devs, N = self._fused_dispatch(jnp, batch, active, chunk)
+                devs, N = self._fused_dispatch(jnp, batch, live, active,
+                                               chunk)
             except Exception as e:
                 # The batch is already out of _waiting/_pending with
                 # KV blocks held, but not yet in _owner — _fail_all
@@ -1868,6 +1975,7 @@ class PagedBatcher:
                 self._active_key = key
                 self._active_dev = jnp.asarray(active)
             entry = (self._decode_dispatch(chunk), (), live, seq)
+            self._count_decode_reads([i for i, _ in live], chunk)
             admitted_slots = set()
             span.set_metadata(kind="decode", live=len(live), positions=0,
                               rows=0, admitted=0)

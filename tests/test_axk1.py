@@ -246,6 +246,31 @@ def test_latent_kernels_in_bfloat16_meet_the_gather():
     assert KIND.rel_rms(got, want) < 0.01
 
 
+@pytest.mark.parametrize("H,W,B,case,dtype", [
+    (64, 24, 40, "sets", "bfloat16"), (8, 24, 40, "sets", "float32"),
+    (8, 200, 6, "laps", "bfloat16"), (4, 24, 40, "none", "float32")], ids=str)
+def test_latent_rows_that_slots_share_are_read_once(H, W, B, case, dtype):
+    """`mla_paged_attention` with `shared` (tests/test_paged_kv.py
+    shared_scene: sets of 17, 9, 8 and 2 and what lies beside them) == the
+    gather, which knows of no sets: a program's q block is its 8 members'
+    H heads over the one pool."""
+    from test_paged_kv import shared_scene
+    dtype = jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(6), 2)
+    pool = jnp.pad(jax.random.normal(ks[0], (1 + B * W, 1, BS, 160)),
+                   ((0, 0), (0, 0), (0, 0), (0, 96))).astype(dtype)
+    q = jax.random.normal(ks[1], (B, H, 160)).astype(dtype)
+    tables, lens, shared = shared_scene(case, B, W, BS)
+    kw = dict(scale=0.1, v_dim=128)
+    want = pa.mla_paged_attention_reference(q, pool, tables, lens, **kw)
+    got = pa.mla_paged_attention(q, pool, tables, lens, impl="kernel",
+                                 shared=shared, **kw)
+    live = np.asarray(lens) > 0
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    assert KIND.rel_rms(got[live], want[live]) < (
+        1e-5 if dtype == jnp.float32 else 0.01)
+
+
 def test_latent_attention_refuses_what_it_cannot_take():
     pool = jnp.zeros((4, 1, BS, 128))
     q = jnp.zeros((2, 4, 48))

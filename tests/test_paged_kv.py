@@ -554,6 +554,85 @@ def test_paged_attention_kernel_matches_reference(H, HKV, D, BS, W, B, case,
     np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
 
 
+def shared_scene(case, B, W, bs):
+    """(tables, lengths, SharedRows) of a decode step in which sets of slots
+    share prefixes, the sets found as the engine finds them.  "sets": B = 40
+    slots in sets of 17 (three programs), 9 (two, the second of one
+    member), 8 and 2, shared lengths that are and are not whole groups of
+    128 positions, a member that is not active, and slots in no set, active
+    and not.  "laps": a prefix longer than the ring is deep, shared by
+    three.  "none": nothing shared."""
+    import jax.numpy as jnp
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.serve.llm import find_shared_prefixes
+    rng = np.random.RandomState(3)
+    bt = rng.permutation(np.arange(1, 1 + B * W, dtype=np.int32)
+                         ).reshape(B, W)
+    sets = {"sets": [(range(0, 17), 9), (range(17, 26), 16),
+                     (range(26, 34), 13), (range(34, 36), 8)],
+            "laps": [(range(0, 3), W - 44), (range(4, 6), 9)],
+            "none": []}[case]
+    lens = rng.randint(1, W * bs + 1, size=B)
+    for slots, blocks in sets:
+        for s in slots:
+            bt[s, :blocks] = bt[slots[0], :blocks]
+            lens[s] = rng.randint(blocks * bs + 1, W * bs + 1)
+        lens[slots[0]] = blocks * bs + 1        # one position of its own
+    if case == "sets":
+        lens[[3, 37]] = 0
+    found = find_shared_prefixes(
+        {s: bt[s] for s in range(B) if lens[s]}, bs, B)
+    want = sorted((len([s for s in slots if lens[s]]), blocks * bs)
+                  for slots, blocks in sets)
+    got = {}
+    for row, leader, positions in zip(*found):
+        if positions:
+            assert leader == row[0] and lens[leader]
+            key = (int(bt[leader, 0]), int(positions))
+            got[key] = got.get(key, 0) + int((row >= 0).sum())
+    assert sorted((n, key[1]) for key, n in got.items()) == want
+    tables, lens = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+    shared = pa.SharedPrefixes(*(jnp.asarray(a) for a in found))
+    return tables, lens, pa.shared_rows(shared, tables, lens)
+
+
+# Olmo-Hybrid's 30 multi-head kv heads, Mistral's groups of 4, LFM2's heads
+# of 64 side by side, a prefix that laps the ring, and a step with no set
+# (the branch not taken).
+@pytest.mark.parametrize("H,HKV,D,W,B,case,dtype", [
+    (30, 30, 128, 24, 40, "sets", "bfloat16"),
+    (8, 2, 128, 24, 40, "sets", "float32"),
+    (8, 4, 64, 24, 40, "sets", "float32"),
+    (4, 2, 128, 200, 6, "laps", "bfloat16"),
+    (4, 4, 128, 200, 6, "laps", "float32"),
+    (8, 2, 128, 24, 40, "none", "float32"),
+], ids=str)
+def test_shared_prefixes_are_read_once_and_change_nothing(H, HKV, D, W, B,
+                                                          case, dtype):
+    """The kernel with `shared` (every sequence over what is its own, every
+    program over what its members share, merged by the softmax statistics)
+    == the gather reference, which knows of no sets."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.paged_attention import (paged_attention_kernel,
+                                             paged_attention_reference)
+    BS, dtype = 16, jnp.dtype(dtype)
+    f = 128 // D
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(k1, (B, H, D), dtype)
+    kp = jax.random.normal(k2, (1 + B * W, HKV // f, BS, f * D), dtype)
+    vp = jax.random.normal(k3, kp.shape, dtype)
+    tables, lens, shared = shared_scene(case, B, W, BS)
+    assert bool(shared.some) == (case != "none")
+    ref = paged_attention_reference(q, kp, vp, tables, lens)
+    out = paged_attention_kernel(q, kp, vp, tables, lens, shared=shared)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.isfinite(out).all()
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+
+
 def test_paged_attention_kernel_bf16_pool_and_dead_table_entries():
     """The serving dtype on the whole-page DMA path: bf16 q and pools,
     table entries beyond each row's length pointing at scratch block 0
@@ -1040,3 +1119,77 @@ def test_try_admit_undoes_prefix_holds_on_exception():
                 bat._alloc.decref(b)
     finally:
         bat.stop()
+
+
+def test_engine_reads_a_shared_prompt_once_and_says_the_same(monkeypatch):
+    """Conversations on two shared system prompts through the engine with the
+    Pallas kernels (interpreter; heads of 128, the whole-page path): token
+    for token what the same engine gives with the sets emptied, in the decode
+    steps and in a pass's carried step; a slot admitted in a dispatch is in
+    its set in that dispatch; and the counters
+    say what was read (`decode.streamed_positions` under
+    `decode.context_positions` with sets, equal to it without)."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import transformer
+    from ray_tpu.models.transformer import TransformerConfig
+    from ray_tpu.serve import llm
+    cfg = TransformerConfig(vocab_size=97, d_model=256, n_heads=2,
+                            n_kv_heads=1, n_layers=2, d_ff=64, max_seq=256,
+                            dtype=jnp.float32, remat=False)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.RandomState(0)
+    systems = [rng.randint(1, 97, size=n).tolist() for n in (144, 160)]
+    turns = [system + rng.randint(1, 97, size=5 + 3 * i).tolist()
+             for i in range(3) for system in systems]
+    found = llm.find_shared_prefixes
+
+    def serve(find):
+        calls = []
+
+        def spy(tables, *a):
+            calls.append((set(tables), find(tables, *a)))
+            return calls[-1][1]
+
+        monkeypatch.setattr(llm, "find_shared_prefixes", spy)
+        bat = PagedBatcher(params, cfg, num_slots=6, max_len=208,
+                           prompt_pad=192, decode_chunk=4, pipeline_depth=2,
+                           kv_block_size=16, attn_impl="kernel")
+        try:
+            for system in systems:          # the prompts reach the cache
+                bat.generate(system + [1, 2, 3], max_new=2, timeout=240)
+            admitted = len(calls)
+            # four turns, and two more once those decode: the dispatch that
+            # admits the two carries the four's next step in its pass
+            reqs = [bat.submit(p, max_new=24) for p in turns[:4]]
+            while not all(r.tokens or r.done.is_set() for r in reqs):
+                time.sleep(0.01)
+            reqs += [bat.submit(p, max_new=10) for p in turns[4:]]
+            for r in reqs:
+                assert r.done.wait(240) and r.error is None
+            return ([r.tokens for r in reqs], bat.kv_stats()["decode"],
+                    calls[admitted:], [r.slot for r in reqs])
+        finally:
+            bat.stop()
+
+    got, reads, calls, slots = serve(found)
+    assert 0 < reads["streamed_positions"] < reads["context_positions"]
+    # the dispatch that admitted the last turns found all six as two sets
+    # of three over their system prompts, whole blocks of it
+    sets = [{(frozenset(row[row >= 0].tolist()), int(n))
+             for row, n in zip(members, lens) if n}
+            for _, (members, _, lens) in calls]
+    want = {(frozenset(slots[i::2]), len(systems[i]) // 16 * 16)
+            for i in range(2)}
+    assert want in sets
+    first = sets.index(want)
+    before = calls[first - 1][0] if first else set()
+    assert set(slots) <= calls[first][0] and set(slots) - before
+
+    def none(tables, block_size, num_slots):
+        return found({}, block_size, num_slots)
+
+    alone, reads, _, _ = serve(none)
+    assert reads["streamed_positions"] == reads["context_positions"] > 0
+    assert got == alone
+    assert [len(t) for t in got] == [24] * 4 + [10] * 2

@@ -480,3 +480,43 @@ def test_latent_and_blocked_kernels_compile_for_v5e(v5e_devices):
             S((T, D)), S((T, 8), i32), S((T, 8), jnp.float32),
             S((T, 8), jnp.bool_), S((E, D, F)), S((E, D, F)), S((E, F, D))
         ).compile()) == 1
+
+
+@pytest.mark.parametrize("pool_kind", ["keys-and-values", "latent"])
+def test_shared_prefix_kernels_compile_for_v5e(v5e_devices, pool_kind):
+    """The decode kernels with `shared` (a step's two calls behind their
+    branch), alone at the cells' shapes, so that a Mosaic refusal shows
+    before the chip: Olmo-Hybrid's 30 kv heads with 8 members' rows a head
+    and Mistral's 8 with 32, under 1,072-column tables; the latent pool's q
+    block of 8 members x 64 heads = 512 rows of 640 lanes."""
+    from ray_tpu.ops import paged_attention as pa
+
+    on_chip, _ = _on_chip_shapes(v5e_devices)
+    bf, i32 = jnp.bfloat16, jnp.int32
+
+    def S(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    def shared(B, W=1072):
+        P = B // 2
+        return pa.SharedRows(S((P, pa.SHARED_MEMBERS), i32), S((P, W), i32),
+                             S((P,), i32), S((B,), i32), S((B,), i32),
+                             S((), jnp.bool_))
+
+    if pool_kind == "latent":
+        kw = dict(scale=0.130861, v_dim=512, impl="kernel")
+        compiled = jax.jit(
+            lambda q, p, bt, n, sh: pa.mla_paged_attention(
+                q, p, bt, n, shared=sh, **kw)).lower(
+            S((64, 64, 576)), S((8193, 1, 16, 640)), S((64, 1072), i32),
+            S((64,), i32), shared(64)).compile()
+        assert _custom_calls(compiled) == 2
+        return
+    for B, H, hkv in ((32, 30, 30), (32, 32, 8)):
+        kp = S((4097, hkv, 16, 128))
+        compiled = jax.jit(
+            lambda q, k, v, bt, n, sh: pa.paged_attention(
+                q, k, v, bt, n, impl="kernel", shared=sh)).lower(
+            S((B, H, 128)), kp, kp, S((B, 1072), i32), S((B,), i32),
+            shared(B)).compile()
+        assert _custom_calls(compiled) == 2

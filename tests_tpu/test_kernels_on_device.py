@@ -392,11 +392,14 @@ def test_prefix_attention_over_heads_side_by_side_on_tpu():
 # every block scattered.
 _CELLS = {"lfm2": (64, None), "trinity-full": (128, None),
           "trinity-window": (128, 2048), "mistral": (128, None)}
+# Olmo-Hybrid's full layers under the same mix: 30 / 30 heads of 128.
+_HEADS = {"olmoh": (30, 30)}
 _HBM_BYTES_PER_S = 819e9            # TPU v5e (benchmarks/lib/peaks.py)
 
 
 def _cell_call(cell, seed=0):
-    d, window = _CELLS[cell]
+    d, window = _CELLS.get(cell, (128, None))
+    H, heads = _HEADS.get(cell, (32, 4))
     rng = np.random.RandomState(seed)
     B, bs = 32, 16
     if cell == "mistral":
@@ -406,7 +409,7 @@ def _cell_call(cell, seed=0):
         prompts = [np.zeros(0, np.int32)] * 4
         first_free = 1
     else:
-        W, NB, hkv = 1072, 8192, 4
+        W, NB, hkv = 1072, 8192, heads
         sizes = [448, 8192, 12288, 16384]
         starts = np.cumsum([1] + [s // bs for s in sizes])
         prompts = [np.arange(a, a + s // bs, dtype=np.int32)
@@ -423,7 +426,7 @@ def _cell_call(cell, seed=0):
         bt[b, :len(shared) + own] = np.concatenate(
             [shared, [next(scattered) for _ in range(own)]])
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(k1, (B, 32, d), jnp.bfloat16)
+    q = jax.random.normal(k1, (B, H, d), jnp.bfloat16)
     kp = jax.random.normal(k2, (NB, hkv, bs, 128), jnp.bfloat16)
     vp = jax.random.normal(k3, (NB, hkv, bs, 128), jnp.bfloat16)
     return (q, kp, vp, jnp.asarray(bt), jnp.asarray(lens)), window
@@ -478,6 +481,84 @@ def test_paged_kernel_call_time_on_tpu(cell):
           f"{2 * pages * page_bytes / 1e6:.1f} MB of pages = "
           f"{floor * 1e6:.1f} us at 819 GB/s: {100 * floor / took:.1f} %")
     assert 0 < floor / took < 1.05
+
+
+def _found_shared(bt, lens, bs=16):
+    """The sets of a step's slots as the engine finds them -> SharedRows."""
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.serve.llm import find_shared_prefixes
+    bt, lens = np.asarray(bt), np.asarray(lens)
+    found = find_shared_prefixes(
+        {b: bt[b, :-(-int(lens[b]) // bs)] for b in range(len(lens))
+         if lens[b]}, bs, len(lens))
+    return pa.shared_rows(
+        pa.SharedPrefixes(*(jnp.asarray(a) for a in found)),
+        jnp.asarray(bt), jnp.asarray(lens))
+
+
+@pytest.mark.parametrize("cell", ["olmoh", "trinity-full", "lfm2", "mistral"])
+def test_shared_prefixes_read_once_at_the_cells_shapes_on_tpu(cell):
+    """The decode call with the slots' sets (the cells' parity runs the
+    layers without them, so this is the chip's only word on the merge):
+    against the gather in value, against the call without sets in time
+    (printed with `-s`, kept in chiprun_out/pr49/kernels.jsonl).  Mistral's
+    contexts share nothing: its call with the empty sets is the branch."""
+    import json
+    import os
+    import time
+    args, _ = _cell_call(cell)
+    q, kp, vp, bt, lens = args
+    shared = _found_shared(bt, lens)
+    sets = [(int((np.asarray(shared.place) // 8 == p).sum()), int(n))
+            for p, n in enumerate(np.asarray(shared.lens)) if n]
+    assert bool(sets) == (cell != "mistral")
+    got = np.asarray(paged_attention(*args, impl="kernel", shared=shared),
+                     np.float32)
+    assert np.isfinite(got).all()
+    with jax.default_matmul_precision("highest"):
+        for i in range(0, q.shape[0], 4):       # the gather is 4 rows' wide
+            rows = slice(i, i + 4)
+            want = paged_attention_reference(q[rows], kp, vp, bt[rows],
+                                             lens[rows])
+            np.testing.assert_allclose(got[rows],
+                                       np.asarray(want, np.float32),
+                                       atol=2e-2, rtol=2e-2)
+
+    @jax.jit
+    def chain(q, kp, vp, bt, lens, *sets):
+        def call(_, q):
+            o = paged_attention(q, kp, vp, bt, lens, impl="kernel",
+                                shared=type(shared)(*sets) if sets else None)
+            return q + (o * 0).astype(q.dtype)
+        return jax.lax.fori_loop(0, 20, call, q)
+
+    took = {}
+    for name, extra in (("alone", ()), ("shared", tuple(shared))):
+        chain(*args, *extra).block_until_ready()
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = chain(*args, *extra)
+            out.block_until_ready()
+            times.append((time.perf_counter() - t0) / 100)
+        took[name] = min(times) * 1e6
+    lens = np.asarray(lens)
+    page_bytes = 2 * int(np.prod(kp.shape[1:])) * kp.dtype.itemsize
+    every = int((-(-lens // 16)).sum())
+    read = every - sum((n - 1) * blocks // 16 for n, blocks in sets)
+    floor = read * page_bytes / _HBM_BYTES_PER_S * 1e6
+    print(f"\npaged_attention {cell}: {took['alone']:.1f} us a call alone, "
+          f"{took['shared']:.1f} us with sets {sets}; pages {every} -> "
+          f"{read}, {floor:.1f} us at 819 GB/s: "
+          f"{100 * floor / took['shared']:.1f} %")
+    os.makedirs("chiprun_out/pr49", exist_ok=True)
+    with open("chiprun_out/pr49/kernels.jsonl", "a") as f:
+        f.write(json.dumps(dict(
+            what="paged_attention", cell=cell, sets=sets, alone_us=took[
+                "alone"], shared_us=took["shared"], pages=every,
+            pages_read=read, floor_us=floor)) + "\n")
+    assert 0 < floor / took["shared"] < 1.05
 
 
 def test_fused_dispatch_against_decode_only_on_tpu():

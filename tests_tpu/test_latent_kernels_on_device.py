@@ -137,6 +137,62 @@ def test_mla_paged_kernel_call_time_on_tpu(slots, lanes):
     assert 0 < pool_s / took < 1.05 and flops_s / took < 1.05
 
 
+def test_latent_rows_that_slots_share_are_read_once_on_tpu():
+    """`mla_paged_attention` at 64 live slots with the slots' sets (16 slots
+    a tenant: two programs of 8 members x 64 heads = 512 rows a prompt)
+    against the gather in value and against the call without sets in time
+    (chiprun_out/pr49/kernels.jsonl; PR 44 read 1,550 us for the latter)."""
+    from test_kernels_on_device import _found_shared
+    rng = np.random.RandomState(1)
+    bt, lens = _agents_tables(64, rng)
+    shared = _found_shared(bt, lens)
+    lens = jnp.asarray(lens)
+    sets = [(int((np.asarray(shared.place) // 8 == p).sum()), int(n))
+            for p, n in enumerate(np.asarray(shared.lens)) if n]
+    assert len(sets) == 8
+    pool = _pool(2)
+    q = jax.random.normal(jax.random.PRNGKey(3), (64, H, WIDTH), jnp.bfloat16)
+    got = np.asarray(pa.mla_paged_attention(
+        q, pool, bt, lens, impl="kernel", shared=shared, **KW), np.float32)
+    assert np.isfinite(got).all()
+    with jax.default_matmul_precision("highest"):
+        for i in range(0, 64, 8):
+            rows = slice(i, i + 8)
+            want = pa.mla_paged_attention_reference(q[rows], pool, bt[rows],
+                                                    lens[rows], **KW)
+            np.testing.assert_allclose(got[rows], np.asarray(want, np.float32),
+                                       atol=2e-2, rtol=2e-2)
+
+    @jax.jit
+    def chain(q, pool, bt, lens, *sets):
+        def call(_, q):
+            o = pa.mla_paged_attention(
+                q, pool, bt, lens, impl="kernel",
+                shared=pa.SharedRows(*sets) if sets else None, **KW)
+            return q + jnp.pad(o * 0, ((0, 0), (0, 0), (0, WIDTH - 512))
+                               ).astype(q.dtype)
+        return jax.lax.fori_loop(0, 20, call, q)
+
+    alone = _timed(chain, q, pool, bt, lens) * 1e6
+    grouped = _timed(chain, q, pool, bt, lens, *shared) * 1e6
+    positions = int(lens.sum())
+    read = positions - sum((n - 1) * blocks for n, blocks in sets)
+    floor = read * LANES * 2 / _HBM_BYTES_PER_S * 1e6
+    flops = 2.0 * H * (WIDTH + 512) * positions / _PEAK_FLOPS * 1e6
+    print(f"\nmla_paged_attention 64 live: {alone:.1f} us a call alone, "
+          f"{grouped:.1f} us with sets {sets}; positions {positions} -> "
+          f"{read}, the pool's bytes {floor:.1f} us, arithmetic at peak "
+          f"{flops:.1f} us ({100 * flops / grouped:.1f} %)")
+    os.makedirs(os.path.join(os.path.dirname(OUT), "pr49"), exist_ok=True)
+    with open(os.path.join(os.path.dirname(OUT), "pr49", "kernels.jsonl"),
+              "a") as f:
+        f.write(json.dumps(dict(
+            what="mla_paged_attention", live=64, sets=sets, alone_us=alone,
+            shared_us=grouped, positions=positions, positions_read=read,
+            floor_us=floor, flops_us=flops)) + "\n")
+    assert max(floor, flops) / grouped < 1.05
+
+
 def _prefix_scene(seed=4):
     """13 admissions of a fused dispatch as the engine groups them: rows of
     64 queries, a turn's 17-40 uncached tokens behind the tenants' prompts
