@@ -37,9 +37,11 @@ row has no head axis; all with expert layers; models/olmo_hybrid.py, gated
 delta-rule layers whose state is a matrix a head a SEQUENCE, kept by state
 id beside the pages) have their paged
 steps at the end of this file, built from their module's one layer
-definition; the public entry points (paged_prefill_decode_packed,
-paged_decode_steps, paged_decode_step) branch to them and return the
-expert layers' counts as one more value.
+definition; the two programs an engine runs (paged_prefill_decode_packed,
+paged_decode_steps) branch to them.  Both return (caches', tokens
+[num_steps, B], counts): the expert layers' counts (afmoe.MOE_COUNTS), None
+where a model has no unrolled layers.  What the host uploads to the first is
+laid out by `FusedUpload`, here and nowhere else.
 """
 
 from __future__ import annotations
@@ -49,6 +51,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models.transformer import (TransformerConfig, _norm, _rope,
                                         _w_out, unrolled)
@@ -486,22 +489,21 @@ def _write_rows(pool, blocks, offsets, new, prompt=(0, 1)):
       sequence's length, and decode writes each before the length passes
       it; (iv) a conv layer's `tail_pool` and `slot_tail` are not pools of
       positions and do not come here.  A row starts on a block boundary
-      (`PrefillRows`), so where P is whole blocks (static, as `bs` is) the
-      slabs are the row's tokens as they lie; where it is not (no engine:
-      odd shapes in tests) every position goes in as Hkv' rows of lanes;
+      (`PrefillRows`) and P is whole blocks (static, as `bs` is; any other
+      P is refused), so the slabs are the row's tokens as they lie;
     * a slot's one position is a sixteenth of a page: the slot's page is
       read by its block id, the new row selected in at its offset, and the
       page written back, one update a slot (an inactive slot's goes to the
       scratch block, like any position that is not live)."""
-    NB, hkv, bs, D = pool.shape
+    _, hkv, bs, D = pool.shape
     new = new.reshape(-1, hkv, D).astype(pool.dtype)
     blocks, offsets = blocks.reshape(-1), offsets.reshape(-1)
     (N, P), T = prompt, blocks.shape[0]
     if N and P % bs:
-        at = ((blocks[:, None] * hkv + jnp.arange(hkv)) * bs
-              + offsets[:, None]).reshape(-1)
-        return pool.reshape(NB * hkv * bs, D).at[at].set(
-            new.reshape(-1, D)).reshape(pool.shape)
+        raise ValueError(
+            f"prefill rows of {P} positions are not whole blocks of {bs}: "
+            f"the pools are written by page (serve/llm.py prefill_shapes "
+            f"cuts prompts into tiles of whole blocks)")
     if N:
         pool = pool.at[blocks[:N * P:bs]].set(
             new[:N * P].reshape(-1, bs, hkv, D).transpose(0, 2, 1, 3))
@@ -520,23 +522,6 @@ def _write_latent(pool, blocks, offsets, rows, prompt=(0, 1)):
     with jax.named_scope("mla_kv"):
         return _write_rows(pool, blocks, offsets, to_lanes(rows, pool),
                            prompt)
-
-
-def pool_updates(caches: PagedDecodeCaches, rows: int, P: int,
-                 steps: int) -> Tuple[int, int]:
-    """(page updates, row updates) the pools take in one dispatch whose pass
-    holds `rows` prefill rows of P positions (0: a decode-only dispatch) and
-    which moves every slot on by `steps` positions: host arithmetic over
-    static shapes, by the rule `_write_rows` writes by.  A serving engine
-    reads row updates 0: its rows are whole blocks."""
-    if isinstance(caches.kp, tuple):
-        heads = [p.shape[1] for p in caches.kp + caches.vp if p is not None]
-    else:
-        heads = [caches.kp.shape[2]] * (2 * caches.kp.shape[0])
-    bs, B = block_size_of(caches), caches.lengths.shape[0]
-    if rows and P % bs:
-        return (steps - 1) * B * len(heads), (rows * P + B) * sum(heads)
-    return (rows * P // bs + steps * B) * len(heads), 0
 
 
 def _scan_layers(layer, x, layers, caches: PagedDecodeCaches):
@@ -610,31 +595,10 @@ def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
                            last_token=new_last), next_tok
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "attn_impl"),
-                   donate_argnums=(1,))
-def paged_decode_step(params: Dict[str, Any], caches: PagedDecodeCaches,
-                      active: jax.Array, cfg: TransformerConfig,
-                      attn_impl: str = "auto"
-                      ) -> Tuple[PagedDecodeCaches, jax.Array]:
-    """One token for every slot; returns (caches', next_tokens [B]);
-    unrolled layers also their expert layers' counts (afmoe.MOE_COUNTS)."""
-    if cfg.layer_kinds is not None:
-        caches, tok, _, counts = _unrolled_decode_core(
-            params, caches, active, cfg, attn_impl)
-        return caches, tok, counts
-    return _paged_decode_core(params, caches, active, cfg, attn_impl)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "num_steps", "attn_impl"),
-                   donate_argnums=(1,))
-def paged_decode_steps(params: Dict[str, Any], caches: PagedDecodeCaches,
-                       active: jax.Array, cfg: TransformerConfig,
-                       num_steps: int, attn_impl: str = "auto"
-                       ) -> Tuple[PagedDecodeCaches, jax.Array]:
-    """num_steps tokens per slot in ONE dispatch (lax.scan): returns
-    (caches', tokens [num_steps, B]); unrolled layers also their expert
-    layers' counts."""
+def _decode_scan(params, caches: PagedDecodeCaches, active, cfg,
+                 num_steps: int, attn_impl):
+    """`num_steps` decode steps of the active slots (traceable) -> (caches',
+    tokens [num_steps, B], unrolled layers' expert counts or None)."""
     if cfg.layer_kinds is not None:
         return _unrolled_decode_scan(params, caches, active, cfg, num_steps,
                                      attn_impl)
@@ -643,7 +607,20 @@ def paged_decode_steps(params: Dict[str, Any], caches: PagedDecodeCaches,
         return _paged_decode_core(params, c, active, cfg, attn_impl)
 
     caches, toks = jax.lax.scan(body, caches, None, length=num_steps)
-    return caches, toks
+    return caches, toks, None
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "num_steps", "attn_impl"),
+                   donate_argnums=(1,))
+def paged_decode_steps(params: Dict[str, Any], caches: PagedDecodeCaches,
+                       active: jax.Array, cfg: TransformerConfig,
+                       num_steps: int, attn_impl: str = "auto"
+                       ) -> Tuple[PagedDecodeCaches, jax.Array, Any]:
+    """num_steps tokens per slot in ONE dispatch (lax.scan): returns
+    (caches', tokens [num_steps, B], counts): unrolled layers' expert
+    counts (afmoe.MOE_COUNTS), else None."""
+    return _decode_scan(params, caches, active, cfg, num_steps, attn_impl)
 
 
 def _paged_prefill_core(params: Dict[str, Any],
@@ -765,6 +742,100 @@ def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
     return _scan_layers(layer, x, params["layers"], caches)
 
 
+class FusedUpload(NamedTuple):
+    """The fused dispatch's ONE upload, [N + 1, width] int32: every host
+    input of `paged_prefill_decode_packed` in one host->device transfer.
+    The engine (serve/llm.py `_fused_dispatch`) writes it through these
+    columns and the program reads it back through them.
+
+      rows 0..N-1: [tokens[0:P] | suffix_len | prefix_len | slot | flag |
+                    table[0:W]] and, where the caches hold linear layers'
+                   states, [state_from | state_to[0] | state_to[1]]
+                   (PrefillRows)
+      row  N:      [active[0:B]] and, where the upload carries them (`sets`),
+                   the prefixes that sets of the slots share once the rows
+                   have set their tables (ops/paged_attention.py
+                   SharedPrefixes, B // 2 programs): [members as slot + 1
+                   [B // 2 * 8] | leader [B // 2] | shared_len [B // 2]],
+                   zeros: none
+
+    A row is a tile of P of one request's uncached tokens, `suffix_len` of
+    them live, at positions `prefix_len` on, under the request's block
+    `table`; `flag` says what the row is (NO_ROW, CLOSES, MORE).  An upload
+    narrower than the last row's sets carries none (`sets` False: the
+    slots attend alone)."""
+
+    P: int                   # tokens a row: the engine's tile (prompt_pad)
+    W: int                   # a block table's columns
+    B: int                   # slots
+    states: bool = False     # the three state columns
+    sets: bool = True        # the last row's shared prefixes
+
+    # `flag`: no row; the row ends its prompt: it yields the first token and
+    # its slot decodes from this dispatch on (a slot the host still marks
+    # active for the request before is the new request's: it gets no decode
+    # row in the pass); more of the prompt is to come: its K/V are written
+    # and its slot stays out of the decode steps.
+    NO_ROW, CLOSES, MORE = 0, 1, 2
+
+    @classmethod
+    def of(cls, prompt_pad: int, caches: PagedDecodeCaches,
+           width: Optional[int] = None) -> "FusedUpload":
+        """The layout of an upload for `caches`; `width`: of an upload that
+        is there already (what it is too narrow for, it does not carry)."""
+        B, W = caches.block_tables.shape
+        up = cls(prompt_pad, W, B, bool(caches.state_pool))
+        return up._replace(sets=width is None or width >= up._sets[3])
+
+    tokens = property(lambda up: slice(0, up.P))
+    suffix_len = property(lambda up: up.P)
+    prefix_len = property(lambda up: up.P + 1)
+    slot = property(lambda up: up.P + 2)
+    flag = property(lambda up: up.P + 3)
+    scalars = property(lambda up: slice(up.P, up.P + 4))    # the four
+    table = property(lambda up: slice(up.P + 4, up.P + 4 + up.W))
+    state_from = property(lambda up: up.P + 4 + up.W)
+    state_to = property(lambda up: slice(up.P + 5 + up.W, up.P + 7 + up.W))
+    active = property(lambda up: slice(0, up.B))
+
+    @property
+    def _sets(self) -> Tuple[int, int, int, int]:
+        """Where the last row's members, leaders and shared lengths start,
+        and where they end."""
+        from ray_tpu.ops.paged_attention import SHARED_MEMBERS
+        programs = self.B // 2
+        leader = self.B + programs * SHARED_MEMBERS
+        return self.B, leader, leader + programs, leader + 2 * programs
+
+    @property
+    def width(self) -> int:
+        return max(self.P + 4 + self.W + (3 if self.states else 0),
+                   self._sets[3] if self.sets else self.B)
+
+    def empty(self, rows: int):
+        """The upload of `rows` rows with nothing in it (numpy, to be
+        written in place): no row, no active slot, no set."""
+        return np.zeros((rows + 1, self.width), np.int32)
+
+    def put_sets(self, packed, members, leader, shared_len) -> None:
+        """SharedPrefixes' three arrays (numpy) into an upload's last row."""
+        at, _, _, end = self._sets
+        packed[-1, at:end] = np.concatenate(
+            [members.reshape(-1) + 1, leader, shared_len])
+
+    def shared_sets(self, packed):
+        """SharedPrefixes out of an upload's last row; none where the
+        upload carries no sets."""
+        from ray_tpu.ops import paged_attention as _pa
+        if not self.sets:
+            return _pa.no_shared_prefixes(self.B)
+        row, programs = packed[-1], self.B // 2
+        at, leader, _, end = self._sets
+        members, rest = row[at:leader], row[leader:end]
+        return _pa.SharedPrefixes(members.reshape(programs, -1) - 1,
+                                  rest[:programs], rest[programs:])
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "num_steps",
                                              "prompt_pad", "attn_impl"),
                    donate_argnums=(1,))
@@ -773,11 +844,12 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
                                 packed: jax.Array,
                                 cfg: TransformerConfig, num_steps: int,
                                 prompt_pad: int, attn_impl: str = "auto"
-                                ) -> Tuple[PagedDecodeCaches, jax.Array]:
+                                ) -> Tuple[PagedDecodeCaches, jax.Array, Any]:
     """Fused suffix-prefill + chunked decode with ALL host inputs in
-    ONE int32 upload: one host->device transfer per dispatch.
-    -> (caches', tokens [num_steps, B]); unrolled layers also their expert
-    layers' counts.
+    ONE int32 upload (`packed`: FusedUpload, rows of `prompt_pad` tokens):
+    one host->device transfer per dispatch.
+    -> (caches', tokens [num_steps, B], counts): unrolled layers' expert
+    counts (afmoe.MOE_COUNTS), else None.
 
     The prefill pass is the dispatch's FIRST decode step: it carries the
     next position of every slot that was active before this call (and that
@@ -787,83 +859,38 @@ def paged_prefill_decode_packed(params: Dict[str, Any],
     tokens: tokens[0] is the pass's (for a slot a row closes, its prompt's
     first token), tokens[1:] the steps'.
 
-    packed: [N+1, Wp] int32 with W = table width, P = prompt_pad (the
-    width of a row: serve/llm.py PREFILL_TILE) and
-    Wp = max(P + 4 + W, num_slots);
-      rows 0..N-1: [tokens[0:P] | suffix_len | prefix_len |
-                    slot | valid | block_table[0:W]]
-      row  N:      active mask for the B decode slots in cols 0..B-1.
-    A model with linear layers (caches.state_pool) has three more columns
-    after the table, Wp = max(P + 7 + W, num_slots): [state_from |
-    state_to[0] | state_to[1]] (PrefillRows).
-
     A row is a tile of one request's uncached tokens (a KV block or two:
     N x P positions are what the dense products see, N one of the host
     loop's ladder of widths); a request longer than P takes several rows,
     one after the other, in this call or over several (the host loop:
     serve/llm.py), and those of one call attend in groups of up to
-    ATTENTION_ROW queries.  `valid` 0: no row.  1: the row ends its prompt:
-    it yields the first token and its slot decodes from this dispatch on
-    (a slot the host still marks active for the request before is the new
-    request's: it gets no decode row in the pass).  2: more of the prompt
-    is to come: its K/V are written and its slot stays out of the decode
-    steps.
-
-    Row N goes on, where it is wide enough (`shared_columns`), with the
-    prefixes that sets of the slots share once the rows have set their
-    tables (ops/paged_attention.py SharedPrefixes, B // 2 programs: members
-    as slot + 1 [B // 2 * 8] | leader [B // 2] | shared_len [B // 2]; zeros:
-    none); the caches keep them for the decode-only dispatches that follow.
-    """
-    P = prompt_pad
-    B = caches.lengths.shape[0]
-    W = caches.block_tables.shape[1]
-    flag = packed[:-1, P + 3]
-    closes = flag == 1
-    slots = packed[:-1, P + 2]
-    was_active = packed[-1, :B] > 0
+    ATTENTION_ROW queries.  The caches keep the upload's sets for the
+    decode-only dispatches that follow."""
+    up = FusedUpload.of(prompt_pad, caches, packed.shape[1])
+    B = up.B
+    flag = packed[:-1, up.flag]
+    closes = flag == up.CLOSES
+    slots = packed[:-1, up.slot]
+    was_active = packed[-1, up.active] > 0
     at = jnp.where(closes, slots, B)
     closed = jnp.zeros((B,), bool).at[at].set(True, mode="drop")
     states = None
-    if caches.state_pool:
-        states = (packed[:-1, P + 4 + W], packed[:-1, P + 5 + W:P + 7 + W])
+    if up.states:
+        states = (packed[:-1, up.state_from], packed[:-1, up.state_to])
     caches, first, counts, tok = _paged_prefill_core(
-        params, caches, packed[:-1, :P], packed[:-1, P], packed[:-1, P + 1],
-        slots, flag > 0, closes, packed[:-1, P + 4:P + 4 + W], cfg,
-        attn_impl, carried=was_active & ~closed, states=states)
+        params, caches, packed[:-1, up.tokens], packed[:-1, up.suffix_len],
+        packed[:-1, up.prefix_len], slots, flag > up.NO_ROW, closes,
+        packed[:-1, up.table], cfg, attn_impl,
+        carried=was_active & ~closed, states=states)
     tok = tok.at[at].set(first, mode="drop")[None]
     active = was_active | closed
     if caches.shared is not None:
         # The rows changed tables: what the slots share from here on.
-        caches = caches._replace(shared=_uploaded_shared(packed[-1], B))
-    if cfg.layer_kinds is not None:
-        caches, toks, more = _unrolled_decode_scan(
-            params, caches, active, cfg, num_steps - 1, attn_impl)
-        return caches, jnp.concatenate([tok, toks]), counts + more
-
-    def body(c, _):
-        return _paged_decode_core(params, c, active, cfg, attn_impl)
-
-    caches, toks = jax.lax.scan(body, caches, None, length=num_steps - 1)
-    return caches, jnp.concatenate([tok, toks])
-
-
-def shared_columns(num_slots: int) -> int:
-    """The columns of the fused upload's last row: the active mask and the
-    slots' shared prefixes (paged_prefill_decode_packed)."""
-    from ray_tpu.ops.paged_attention import SHARED_MEMBERS
-    return num_slots + num_slots // 2 * (SHARED_MEMBERS + 2)
-
-
-def _uploaded_shared(row, B: int):
-    """SharedPrefixes out of the upload's last row; a row that is not
-    `shared_columns` wide has none."""
-    from ray_tpu.ops import paged_attention as _pa
-    if row.shape[0] < shared_columns(B):
-        return _pa.no_shared_prefixes(B)
-    P, K = B // 2, _pa.SHARED_MEMBERS
-    members, rest = row[B:B + P * K], row[B + P * K:B + P * (K + 2)]
-    return _pa.SharedPrefixes(members.reshape(P, K) - 1, rest[:P], rest[P:])
+        caches = caches._replace(shared=up.shared_sets(packed))
+    caches, toks, more = _decode_scan(params, caches, active, cfg,
+                                      num_steps - 1, attn_impl)
+    return (caches, jnp.concatenate([tok, toks]),
+            None if counts is None else counts + more)
 
 
 # ===========================================================================

@@ -38,7 +38,12 @@ for every slot in it: the fused program's prefill pass carries the live
 slots' first decode step (their one position each beside the prompt rows,
 `prefill.carried_rows` counts them), `decode_chunk - 1` steps follow, and
 for a request the dispatch admits the first of its tokens is its prompt's
-first token.  All host inputs of a dispatch travel in one int32 upload.
+first token.  All host inputs of a dispatch travel in one int32 upload
+(decoding.FusedUpload).  These two programs are all an engine compiles: a
+request within a chunk of its last position, be it `max_new` or the cap that
+`max_len` put on its allocation, rides the ordinary chunk, `_hand_out` cuts
+its column there, and the steps past it write to blocks and state that are
+its own until it retires, or to the scratch block.
 
 Prefill: `prompt_pad` is the longest prompt `submit` accepts.  A row of
 the fused prefill is a TILE of PREFILL_TILE tokens (a KV block or two) of
@@ -118,7 +123,7 @@ SPAN_PACK = "engine.pack"                # ↳ _fused_dispatch to the jitted cal
 SPAN_LAUNCH = "engine.launch"            # ↳ the jitted call + copy_to_host_async
 SPAN_POST_ADMIT = "engine.post_admit"    # ↳ _post_admit: radix insert, gauges
 # Processor thread (_process_loop), beside the dispatcher:
-SPAN_READ_WAIT = "engine.read_wait"      # np.asarray(devs[0]); stat: seq
+SPAN_READ_WAIT = "engine.read_wait"      # np.asarray(tokens); stat: seq
 SPAN_HAND_OUT = "engine.hand_out"        # _hand_out; stat: seq
 
 # A stretch with nothing on the device and work present that lasts this
@@ -818,11 +823,11 @@ class PagedBatcher:
         # prompt-wide one.
         self._tile, self._prefill_rows = prefill_shapes(
             num_slots, prompt_pad, self.block_size)
-        if prompt_pad > self._tile and self._tile % self.block_size:
+        if self._tile % self.block_size:
             raise ValueError(
-                f"prompts longer than {self._tile} are prefilled in tiles "
-                f"of that many tokens, which must be whole blocks of "
-                f"kv_block_size {self.block_size}")
+                f"prompts are prefilled in tiles of {self._tile} tokens "
+                f"(prefill_shapes: prompt_pad {prompt_pad}), which must be "
+                f"whole blocks of kv_block_size {self.block_size}")
         self._alloc = BlockAllocator(self.num_blocks)
         self._radix: Dict[str, RadixCache] = {}
         # One LRU clock shared by every model's tree (comparable
@@ -862,9 +867,6 @@ class PagedBatcher:
         self._rung_dispatches = {str(n * self._tile): 0
                                  for n in self._prefill_rows}
         self._moe_counts = [0, 0, 0, 0, 0]
-        # Updates the dispatches' K/V writes made to the pools, by page and
-        # by D-wide row (decoding.pool_updates, from static shapes).
-        self._pool_updates = {"page_updates": 0, "row_updates": 0}
         # What the decode steps' attention had to read and what it read,
         # in cached positions a step: every decoding slot's context, and
         # the same with a prefix that a set shares counted once a program
@@ -904,6 +906,7 @@ class PagedBatcher:
         self.caches = decoding.init_paged_caches(
             cfg, num_slots, self.num_blocks, self.block_size, max_len,
             self.num_states)
+        self._upload = decoding.FusedUpload.of(self._tile, self.caches)
         # State that is not positions, where the caches hold any: layers
         # whose state at a block boundary is a tail kept under the block's
         # id (decoding.PagedDecodeCaches.tail_pool).  A prefix hit then also
@@ -923,11 +926,11 @@ class PagedBatcher:
         self._owner: List[Optional[_Request]] = [None] * num_slots
         self._disp_len = [0] * num_slots
         self._pending: "queue.Queue[_Request]" = queue.Queue()
-        # In-flight dispatches, oldest first: (kind, device arrays,
-        # (admitted, pairs)) with
-        #   "fused":  admitted [(row, slot, req)] take a first token,
-        #             pairs [(slot, req)] the chunk's decode tokens
-        #   "decode": admitted None
+        # In-flight dispatches, oldest first: ((tokens, counts) on the
+        # device, admitted, pairs, seq) with
+        #   fused:  admitted [(slot, req)] take a first token,
+        #           pairs [(slot, req)] the chunk's decode tokens
+        #   decode: admitted ()
         self._inflight: deque = deque()
         self._shutdown = False
         self._work = threading.Event()
@@ -1158,7 +1161,6 @@ class PagedBatcher:
                                  "absent_rows"), self._moe_counts),
                             picked_rows=self._moe_counts[1]
                             + self._moe_counts[4]),
-                "writes": dict(self._pool_updates),
                 "decode": dict(self._decode_reads),
                 **({} if self._states is None else {"state": dict(
                     self._state_counts, ids_used=self._states.used(),
@@ -1495,8 +1497,7 @@ class PagedBatcher:
             room -= self._tiles_left(req)
         return admitted
 
-    def _pop_admissions(self, free: List[int],
-                        tail: bool) -> List[tuple]:
+    def _pop_admissions(self, free: List[int]) -> List[tuple]:
         """This dispatch's prefill: [(slot, req)], requests that hold a
         slot with tiles still to come first, then new admissions."""
         # Apply a parked failure BEFORE pulling new submissions out of
@@ -1512,8 +1513,6 @@ class PagedBatcher:
                 self._waiting.append(self._pending.get_nowait())
             except queue.Empty:
                 break
-        if tail:
-            return []
         # One dispatch carries at most the widest program's rows.  Requests
         # whose prompt has tiles still to come go first, oldest admission
         # first (they hold their slots already; one admission's requests
@@ -1621,16 +1620,6 @@ class PagedBatcher:
             if not req.done.is_set():
                 self._finish_request(req, error=e)
 
-    def _tail_throttle(self, req: "_Request") -> bool:
-        # Only a capacity-CLAMPED allocation needs the single-token
-        # tail (it must run all the way to its cap before the "cache"
-        # truncation).  An unclamped request ends exactly at max_new
-        # via the processing take-bound, and its overshoot writes land
-        # in private tail blocks / scratch block 0 — throttling the
-        # whole engine for every non-chunk-aligned max_new would cost
-        # ~chunk x dispatch overhead and starve admissions.
-        return req._pos_cap < len(req.prompt) + req.max_new
-
     def _drained(self, slot: int, req: "_Request") -> bool:
         """Everything `req` needs is already dispatched (caller holds
         _state_lock)."""
@@ -1638,46 +1627,29 @@ class PagedBatcher:
         return (gen >= req.max_new
                 or self._disp_len[slot] >= req._pos_cap)
 
-    def _pack(self, rows: int):
-        """The fused dispatch's one upload, empty (decoding.
-        paged_prefill_decode_packed has the format)."""
-        cols = self._tile + 4 + self.table_width
-        if self._states is not None:    # state_from, state_to[0], [1]
-            cols += 3
-        return np.zeros((rows + 1, max(
-            cols, self._dec.shared_columns(self.num_slots))), np.int32)
-
     def _warmup(self, jnp) -> None:
         """Compile every dispatch shape up front (each fused width + the
         decode-only chunk) so no request ever stalls behind a mid-run
         XLA compile."""
-        active = jnp.zeros((self.num_slots,), bool)
         for N in self._prefill_rows:
-            self.caches = self._dec.paged_prefill_decode_packed(
-                self.params, self.caches, jnp.asarray(self._pack(N)),
-                self.cfg, self.decode_chunk, self._tile,
-                attn_impl=self._attn_impl)[0]
-        if self.decode_chunk > 1:
-            self.caches, toks = self._dec.paged_decode_steps(
-                self.params, self.caches, active, self.cfg,
-                self.decode_chunk, attn_impl=self._attn_impl)[:2]
-            np.asarray(toks)
-        # Single-step shape too: the tail of a clamped allocation falls
-        # back to it.
-        self.caches, toks = self._dec.paged_decode_step(
-            self.params, self.caches, active, self.cfg,
-            attn_impl=self._attn_impl)[:2]
+            self.caches, _, _ = self._dec.paged_prefill_decode_packed(
+                self.params, self.caches,
+                jnp.asarray(self._upload.empty(N)), self.cfg,
+                self.decode_chunk, self._tile, attn_impl=self._attn_impl)
+        self.caches, toks, _ = self._dec.paged_decode_steps(
+            self.params, self.caches, jnp.zeros((self.num_slots,), bool),
+            self.cfg, self.decode_chunk, attn_impl=self._attn_impl)
         np.asarray(toks)
 
     def _fused_dispatch(self, jnp, batch: List[tuple], live: List[tuple],
-                        active, chunk: int):
+                        active):
         """`batch` as _pop_admissions cut it: every request in it gets at
         least one row.  Its uncached tokens go into rows of `_tile`, in
         order, until the widest program is full; a request cut short there
         comes back with the next dispatch.  `live`: the (slot, request)
         pairs of `active`.  -> (device arrays, rows of the program that
         ran)."""
-        T = self._tile
+        T, up, chunk = self._tile, self._upload, self.decode_chunk
         with _Phase(self, SPAN_PACK, "pack"):
             room = self._prefill_rows[-1]
             takes = []
@@ -1688,7 +1660,7 @@ class PagedBatcher:
                 room -= tiles
             N = next(n for n in self._prefill_rows
                      if n >= self._prefill_rows[-1] - room)
-            packed = self._pack(N)
+            packed = up.empty(N)
             row = 0
             for (slot, req), take in zip(batch, takes):
                 done, end = req._prefilled, req._prefilled + take
@@ -1696,27 +1668,25 @@ class PagedBatcher:
                 for start in range(done, end, T):
                     n = min(T, end - start)
                     packed[row, :n] = req.prompt[start:start + n]
-                    packed[row, T:T + 4] = (n, start, slot, 2)
+                    packed[row, up.scalars] = (n, start, slot, up.MORE)
                     row += 1
-                packed[first:row,
-                       T + 4:T + 4 + len(req._table)] = req._table
+                packed[first:row, up.table][:, :len(req._table)] = req._table
                 if end == len(req.prompt):
-                    packed[row - 1, T + 3] = 1
+                    packed[row - 1, up.flag] = up.CLOSES
                 if self._states is not None:
                     # the request's first row of this call starts from a
                     # checkpoint, its own state or zeros, the others from
                     # the row before; the last leaves the state in its id,
                     # a row that ends where a checkpoint was asked for also
                     # there
-                    at = T + 4 + self.table_width
-                    packed[first:row, at] = -1
-                    packed[first, at] = req._state_from
-                    packed[row - 1, at + 1] = req._state_id
+                    packed[first:row, up.state_from] = -1
+                    packed[first, up.state_from] = req._state_from
+                    packed[row - 1, up.state_to][0] = req._state_id
                     for depth, ckpt in req._ckpts.items():
                         r = first + (depth * self.block_size - done) // T - 1
                         if first <= r < row:
-                            packed[r, at + 2] = ckpt
-            packed[N, :self.num_slots] = active
+                            packed[r, up.state_to][1] = ckpt
+            packed[N, up.active] = active
             # The tables the slots decode from once this dispatch's rows
             # have set theirs, and what sets of them share.
             tables = {slot: req._table for slot, req in live}
@@ -1726,9 +1696,7 @@ class PagedBatcher:
                     tables[slot] = req._table
             shared = find_shared_prefixes(tables, self.block_size,
                                           self.num_slots)
-            packed[N, self.num_slots:self._dec.shared_columns(
-                self.num_slots)] = np.concatenate(
-                [shared[0].reshape(-1) + 1, *shared[1:]])
+            up.put_sets(packed, *shared)
         devs = self._launch(lambda: self._dec.paged_prefill_decode_packed(
             self.params, self.caches, jnp.asarray(packed),
             self.cfg, chunk, T, attn_impl=self._attn_impl))
@@ -1744,7 +1712,6 @@ class PagedBatcher:
         self._prefill_counts["chunk_tokens"] += sum(takes)
         self._prefill_counts["padded_tokens"] += N * T
         self._rung_dispatches[str(N * T)] += 1
-        self._count_writes(N, chunk)
         # The pass's carried steps are read under the sets of the dispatch
         # before, the steps after it under these.
         admitted = {slot: len(req.prompt) for slot, req in batch}
@@ -1781,36 +1748,19 @@ class PagedBatcher:
             self._decode_reads["context_positions"] += steps * context
             self._decode_reads["streamed_positions"] += steps * streamed
 
-    def _count_writes(self, rows: int, chunk: int) -> None:
-        pages, by_row = self._dec.pool_updates(self.caches, rows,
-                                               self._tile, chunk)
-        self._pool_updates["page_updates"] += pages
-        self._pool_updates["row_updates"] += by_row
-
-    def _decode_dispatch(self, chunk: int) -> tuple:
-        """Decode-only device step for every slot; returns (dtoks
-        [chunk, B], ...)."""
-        if chunk > 1:
-            return self._launch(lambda: self._dec.paged_decode_steps(
-                self.params, self.caches, self._active_dev,
-                self.cfg, chunk, attn_impl=self._attn_impl))
-
-        def one_step():
-            caches, tok, *extras = self._dec.paged_decode_step(
-                self.params, self.caches, self._active_dev, self.cfg,
-                attn_impl=self._attn_impl)
-            return (caches, tok[None], *extras)
-        return self._launch(one_step)
-
     def _launch(self, call) -> tuple:
-        """`call()` -> (caches, *device arrays): one jitted program handed
-        to the device and its results asked for.  When it returns the
-        device has work again: the launch is counted and stamped."""
+        """`call()`: one of the engine's two programs (decoding.
+        paged_prefill_decode_packed, paged_decode_steps), its upload among
+        its arguments, handed to the device and its results asked for ->
+        (tokens [decode_chunk, B], an expert model's counts or None), on the
+        device.  When it returns the device has work again: the launch is
+        counted and stamped."""
         with _Phase(self, SPAN_LAUNCH, "launch"):
-            self.caches, *devs = call()
-            for dev in devs:
+            self.caches, toks, counts = call()
+            for dev in (toks, counts):
                 try:
-                    dev.copy_to_host_async()
+                    if dev is not None:
+                        dev.copy_to_host_async()
                 except Exception:
                     pass
         now = time.perf_counter()
@@ -1819,13 +1769,13 @@ class PagedBatcher:
                 self.host_s["device_starved"] += now - self._empty_since
                 self._empty_since = None
             self.host_s["dispatches"] += 1
-        return tuple(devs)
+        return toks, counts
 
-    def _count_dispatch(self, extras: tuple) -> None:
-        """What a dispatch returned beyond its tokens (an expert model's
-        counts), once it has been read."""
-        if extras:
-            counts = np.asarray(extras[0]).tolist()
+    def _count_dispatch(self, counts) -> None:
+        """A dispatch's expert counts (None: the model has no expert
+        layers), once it has been read."""
+        if counts is not None:
+            counts = np.asarray(counts).tolist()
             with self._kv_lock:
                 self._moe_counts = [a + b for a, b in
                                     zip(self._moe_counts, counts)]
@@ -1896,21 +1846,19 @@ class PagedBatcher:
             free = [i for i, r in enumerate(self._owner)
                     if r is None or (self.eos_id is None
                                      and self._drained(i, r))]
+            # (a slot short of its cap takes the whole chunk: `_disp_len`
+            # passes the cap, as it passes prompt + max_new, by up to
+            # decode_chunk - 1 steps whose writes stay in the request's own
+            # blocks and state, or the scratch block, and whose tokens
+            # `_hand_out` drops)
             live = [(i, r) for i, r in enumerate(self._owner)
                     if r is not None and not r._prefilling
                     and self._disp_len[i] < r._pos_cap]
-            # Near the end of a clamped allocation, fall back to
-            # single-token dispatches (and no admissions) so the request
-            # runs all the way to its cap instead of being truncated a
-            # chunk early.
-            tail = any(self._disp_len[i] + self.decode_chunk
-                       > r._pos_cap and self._tail_throttle(r)
-                       for i, r in live)
-        chunk = 1 if tail else self.decode_chunk
+        chunk = self.decode_chunk
         seq = self.host_s["dispatches"]
         admit = _Phase(self, SPAN_ADMIT, waiting=self.queue_depth())
         with admit:
-            batch = self._pop_admissions(free, tail)
+            batch = self._pop_admissions(free)
         # NOTE: slots whose request already has max_new covered by
         # in-flight dispatches stay in the batch anyway — the decode is
         # fixed-shape, so excluding them saves nothing, while skipping
@@ -1933,8 +1881,7 @@ class PagedBatcher:
             # lands in prefill_s, not queue_s.
             admit_t = time.time()
             try:
-                devs, N = self._fused_dispatch(jnp, batch, live, active,
-                                               chunk)
+                devs, N = self._fused_dispatch(jnp, batch, live, active)
             except Exception as e:
                 # The batch is already out of _waiting/_pending with
                 # KV blocks held, but not yet in _owner — _fail_all
@@ -1974,7 +1921,9 @@ class PagedBatcher:
             if key != self._active_key:
                 self._active_key = key
                 self._active_dev = jnp.asarray(active)
-            entry = (self._decode_dispatch(chunk), (), live, seq)
+            entry = (self._launch(lambda: self._dec.paged_decode_steps(
+                self.params, self.caches, self._active_dev, self.cfg, chunk,
+                attn_impl=self._attn_impl)), (), live, seq)
             self._count_decode_reads([i for i, _ in live], chunk)
             admitted_slots = set()
             span.set_metadata(kind="decode", live=len(live), positions=0,
@@ -2002,10 +1951,10 @@ class PagedBatcher:
         return True
 
     def _process_entry(self, entry) -> None:
-        devs, admitted, pairs, seq = entry
+        (toks, counts), admitted, pairs, seq = entry
         t_read = time.perf_counter()
         with host_span(SPAN_READ_WAIT, seq=seq):
-            toks = np.asarray(devs[0])          # waits for the dispatch
+            toks = np.asarray(toks)             # waits for the dispatch
         t_got = time.perf_counter()
         self.host_s["read_wait"] += t_got - t_read
         with self._dev_lock:
@@ -2016,7 +1965,7 @@ class PagedBatcher:
                 self._empty_warned = False
         try:
             with host_span(SPAN_HAND_OUT, seq=seq):
-                self._count_dispatch(devs[1:])
+                self._count_dispatch(counts)
                 self._hand_out(toks, admitted, pairs)
         finally:
             self.host_s["process"] += time.perf_counter() - t_got

@@ -637,9 +637,10 @@ def test_pairs_flagged_one_by_one_are_routed_nowhere():
 
 # -- the engine ----------------------------------------------------------------
 def _is_greedy(cfg, params, prompt, got):
-    seq = jnp.asarray(list(prompt) + list(got))
+    n = len(prompt) + len(got)     # padded: one compiled shape for many
+    seq = jnp.asarray(list(prompt) + list(got) + [0] * (-n % 64))
     lg = KIND.reference_logits(KIND.hyper(cfg), params, seq)[
-        len(prompt) - 1:-1]
+        len(prompt) - 1:n - 1]
     top2 = jnp.sort(lg, axis=-1)[:, -2:]
     assert float(jnp.min(top2[:, 1] - top2[:, 0])) > 1e-4, "a tie"
     return jnp.argmax(lg, axis=-1).tolist() == list(got)

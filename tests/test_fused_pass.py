@@ -65,14 +65,17 @@ def prompt(n, seed):
 
 
 # -- (a) the device function --------------------------------------------------
+UP = decoding.FusedUpload(P, W, SLOTS, sets=False)
+
+
 def _pack(rows, active, N):
     """rows [(tokens, start, slot, flag, table)] -> the program's upload."""
-    packed = np.zeros((N + 1, max(P + 4 + W, SLOTS)), np.int32)
+    packed = UP.empty(N)
     for r, (toks, start, slot, flag, table) in enumerate(rows):
         packed[r, :len(toks)] = toks
-        packed[r, P:P + 4] = (len(toks), start, slot, flag)
-        packed[r, P + 4:P + 4 + W] = table
-    packed[N, :SLOTS] = active
+        packed[r, UP.scalars] = (len(toks), start, slot, flag)
+        packed[r, UP.table] = table
+    packed[N, UP.active] = active
     return jnp.asarray(packed)
 
 
@@ -88,18 +91,19 @@ def _scene(cfg, params):
     active for the request before, is closed again by a new request with a
     table of its own.  -> (caches, rows, the host's active mask)."""
     caches = decoding.init_paged_caches(cfg, SLOTS, 8 * W, BLOCK, MAX_LEN)
-    before = [(prompt(5, 1), 0, 0, 1, _table(0)),
-              (prompt(6, 2), 0, 1, 1, _table(1)),
-              (prompt(7, 3), 0, 5, 1, _table(5))]
+    before = [(prompt(5, 1), 0, 0, UP.CLOSES, _table(0)),
+              (prompt(6, 2), 0, 1, UP.CLOSES, _table(1)),
+              (prompt(7, 3), 0, 5, UP.CLOSES, _table(5))]
     # two tokens each: lengths 6, 7 (offset 3 of a block of 4) and 8
     caches = decoding.paged_prefill_decode_packed(
         params, caches, _pack(before, np.zeros(SLOTS), 4), cfg, 2, P,
         attn_impl="reference")[0]
     assert np.asarray(caches.lengths).tolist() == [6, 7, 0, 0, 0, 8]
     new, long, again = prompt(11, 4), prompt(20, 5), prompt(3, 6)
-    rows = [(new[:8], 0, 2, 2, _table(2)), (new[8:], 8, 2, 1, _table(2)),
-            (long[:8], 0, 3, 2, _table(3)),
-            (again, 0, 5, 1, _table(6))]
+    rows = [(new[:8], 0, 2, UP.MORE, _table(2)),
+            (new[8:], 8, 2, UP.CLOSES, _table(2)),
+            (long[:8], 0, 3, UP.MORE, _table(3)),
+            (again, 0, 5, UP.CLOSES, _table(6))]
     return caches, rows, np.array([1, 1, 0, 0, 0, 1])
 
 
@@ -108,24 +112,24 @@ def _one_after_the_other(cfg, params, caches, rows, active, N, steps):
     the slots that were active and are not closed, then `steps - 1` of
     every active slot: what the pass and its scan must equal."""
     packed = _pack(rows, active, N)
-    flag, slots = packed[:-1, P + 3], packed[:-1, P + 2]
-    closes = flag == 1
+    flag, slots = packed[:-1, UP.flag], packed[:-1, UP.slot]
+    closes = flag == UP.CLOSES
     caches, first, *_ = decoding._paged_prefill_core(
-        params, caches, packed[:-1, :P], packed[:-1, P], packed[:-1, P + 1],
-        slots, flag > 0, closes, packed[:-1, P + 4:P + 4 + W], cfg,
-        "reference")
+        params, caches, packed[:-1, UP.tokens], packed[:-1, UP.suffix_len],
+        packed[:-1, UP.prefix_len], slots, flag > UP.NO_ROW, closes,
+        packed[:-1, UP.table], cfg, "reference")
     closed = np.zeros(SLOTS, bool)
     closed[np.asarray(slots)[np.asarray(closes)]] = True
     was = np.asarray(active) > 0
-    caches, tok = decoding.paged_decode_step(
-        params, caches, jnp.asarray(was & ~closed), cfg, "reference")[:2]
+    caches, tok, _ = decoding.paged_decode_steps(
+        params, caches, jnp.asarray(was & ~closed), cfg, 1, "reference")
     tok = np.asarray(tok).copy()
     for r in np.flatnonzero(np.asarray(closes)):
-        tok[int(slots[r])] = int(first[r])
-    caches, toks = decoding.paged_decode_steps(
+        tok[0, int(slots[r])] = int(first[r])
+    caches, toks, _ = decoding.paged_decode_steps(
         params, caches, jnp.asarray(was | closed), cfg, steps - 1,
-        "reference")[:2]
-    return caches, np.concatenate([tok[None], np.asarray(toks)])
+        "reference")
+    return caches, np.concatenate([tok, np.asarray(toks)])
 
 
 def _same_state(a, b):
@@ -156,7 +160,7 @@ def test_the_pass_is_the_first_decode_step(model):
     want_c, want = _one_after_the_other(cfg, params, caches, rows, active,
                                         N, steps)
     caches, rows, active = _scene(cfg, params)      # the first were donated
-    got_c, got, *counts = decoding.paged_prefill_decode_packed(
+    got_c, got, counts = decoding.paged_prefill_decode_packed(
         params, caches, _pack(rows, active, N), cfg, steps, P,
         attn_impl="reference")
     assert got.shape == (steps, SLOTS)
@@ -166,19 +170,105 @@ def test_the_pass_is_the_first_decode_step(model):
     # 0 and 1 rode in the pass (+ 3); 2 and 5 closed (prompt + 2); 3 waits
     assert np.asarray(got_c.lengths).tolist() == [9, 10, 13, 0, 0, 5]
     np.testing.assert_array_equal(got_c.block_tables[5], _table(6))
-    if counts:          # expert layers: the pass is ONE call a layer
+    if counts is not None:  # expert layers: the pass is ONE call a layer
         n_moe = sum(f == "experts" for _, f in cfg.layer_kinds)
-        assert int(counts[0][0]) == n_moe * steps
+        assert int(counts[0]) == n_moe * steps
+
+
+def test_both_programs_return_caches_tokens_and_counts(model):
+    """One result shape: (caches', tokens [num_steps, B], counts), the
+    counts an expert model's (afmoe.MOE_COUNTS), None where the layers are
+    stacked: the host reads either program's result alike."""
+    from ray_tpu.models import afmoe
+
+    cfg, params = model
+    caches, rows, active = _scene(cfg, params)
+    fused = decoding.paged_prefill_decode_packed(
+        params, caches, _pack(rows, active, 7), cfg, 3, P,
+        attn_impl="reference")
+    steps = decoding.paged_decode_steps(
+        params, fused[0], jnp.asarray(active > 0), cfg, 2, "reference")
+    for (caches, toks, counts), n in ((fused, 3), (steps, 2)):
+        assert isinstance(caches, decoding.PagedDecodeCaches)
+        assert toks.shape == (n, SLOTS) and toks.dtype == jnp.int32
+        if cfg.layer_kinds is None:
+            assert counts is None
+        else:
+            assert counts.shape == (len(afmoe.MOE_COUNTS),)
+
+
+# What a [2 + 1, width] upload of P 2, W 3, B 4 holds once the rows below
+# are written through the layout, byte for byte: the columns as every engine
+# and program since PR 49 has had them.
+_ROWS = [[11, 12, 2, 8, 3, 2, 5, 6, 7], [13, 0, 1, 10, 3, 1, 5, 6, 7]]
+_STATES = [[9, 0, 0], [-1, 4, 2]]
+_LAST = [1, 0, 1, 1] + [1, 3] + 14 * [0] + [0, 0] + [64, 0]
+GOLDEN = {
+    "plain": [r + 15 * [0] for r in _ROWS] + [_LAST],
+    "states": [r + s + 12 * [0] for r, s in zip(_ROWS, _STATES)] + [_LAST],
+    "no-sets": _ROWS + [_LAST[:4] + 5 * [0]],
+}
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN))
+def test_the_upload_is_read_as_it_was_written(case):
+    """decoding.FusedUpload is the one description of the fused dispatch's
+    upload: what the host writes through it in numpy, the program's side
+    reads back through it in jnp, field for field, with and without the
+    three state columns; an upload too narrow for the last row's sets
+    carries none."""
+    from ray_tpu.ops import paged_attention as pa
+
+    B, width, tile = 4, 3, 2
+    zeros = jnp.zeros((B,), jnp.int32)
+    caches = decoding.PagedDecodeCaches(
+        None, None, jnp.zeros((B, width), jnp.int32), zeros, zeros,
+        state_pool=(zeros,) if case == "states" else ())
+    up = decoding.FusedUpload.of(tile, caches)
+    assert up.sets and up.states == (case == "states")
+    if case == "no-sets":
+        up = up._replace(sets=False)
+    fields = dict(tokens=[[11, 12], [13, 0]], suffix_len=[2, 1],
+                  prefix_len=[8, 10], slot=[3, 3],
+                  flag=[up.MORE, up.CLOSES], table=[[5, 6, 7]] * 2)
+    if up.states:
+        fields.update(state_from=[9, -1], state_to=[[0, 0], [4, 2]])
+    members = np.full((B // 2, pa.SHARED_MEMBERS), -1, np.int32)
+    members[0, :2] = (0, 2)
+    sets = (members, np.array([0, 0], np.int32), np.array([64, 0], np.int32))
+    packed = up.empty(2)
+    for name, value in fields.items():
+        packed[:-1, getattr(up, name)] = value
+    packed[-1, up.active] = (1, 0, 1, 1)
+    if up.sets:
+        up.put_sets(packed, *sets)
+    assert packed.dtype == np.int32 and packed.tolist() == GOLDEN[case]
+    np.testing.assert_array_equal(packed[:-1, up.scalars],
+                                  [[2, 8, 3, 2], [1, 10, 3, 1]])
+
+    dev = jnp.asarray(packed)       # as the program finds its layout
+    read = decoding.FusedUpload.of(tile, caches, dev.shape[1])
+    assert read == up
+    for name, value in fields.items():
+        np.testing.assert_array_equal(dev[:-1, getattr(read, name)], value)
+    np.testing.assert_array_equal(dev[-1, read.active], (1, 0, 1, 1))
+    got = read.shared_sets(dev)
+    for a, b in zip(got, sets if up.sets else pa.no_shared_prefixes(B)):
+        np.testing.assert_array_equal(a, b)
 
 
 # -- (b) the engine -----------------------------------------------------------
+def _engine(model):
+    cfg, params = model
+    return llm.PagedBatcher(params, cfg, num_slots=4, max_len=MAX_LEN,
+                            prompt_pad=32, decode_chunk=CHUNK,
+                            kv_block_size=BLOCK, kv_num_blocks=96,
+                            attn_impl="reference")
+
+
 @pytest.fixture(scope="module")
 def engine(model):
-    cfg, params = model
-    eng = llm.PagedBatcher(params, cfg, num_slots=4, max_len=MAX_LEN,
-                           prompt_pad=32, decode_chunk=CHUNK,
-                           kv_block_size=BLOCK, kv_num_blocks=96,
-                           attn_impl="reference")
+    eng = _engine(model)
     yield eng
     eng.stop()
 
@@ -198,11 +288,13 @@ def held(eng):
 
 def _greedy_all_the_way(cfg, params, req):
     """One forward pass over prompt + reply: every token of the reply is
-    the argmax at the position before it."""
-    seq = jnp.asarray(req.prompt + req.tokens)
+    the argmax at the position before it.  (Padded to MAX_LEN, which a
+    causal model does not see: one compiled shape a model, not one a
+    length.)"""
+    n = len(req.prompt) + len(req.tokens)
+    seq = jnp.asarray(req.prompt + req.tokens + [0] * (MAX_LEN - n))
     logits = tfm.forward(params, seq[None], cfg)[0]
-    want = np.asarray(jnp.argmax(logits, axis=-1))[
-        len(req.prompt) - 1:len(seq) - 1]
+    want = np.asarray(jnp.argmax(logits, axis=-1))[len(req.prompt) - 1:n - 1]
     assert req.tokens == want.tolist(), (len(req.prompt), req.max_new)
 
 
@@ -240,6 +332,37 @@ def test_admitted_beside_live_slots_token_for_token(model, engine, max_new):
     _greedy_all_the_way(cfg, params, long)
     # `long` was live when the others were admitted: its step rode along
     assert engine.kv_stats()["prefill"]["carried_rows"] > carried
+
+
+def test_a_clamped_request_rides_whole_chunks_past_its_cap(model, engine):
+    """No single step for the tail of an allocation that max_len clamped:
+    the request takes ordinary chunks, the last of which runs 2 steps past
+    its cap (through a conv layer's tails and a latent pool like through
+    K/V: into blocks of its own, or the scratch block), and its reply is
+    cut at the cap.  Alone in an engine of its own (the module's shapes):
+    34 tokens at 4 a dispatch are 9 dispatches, where the one-token tail
+    made them 8 chunks and 3 steps.  Then in the module's engine beside a
+    slot that lives on: both replies are their own greedy continuations."""
+    cfg, params = model
+    alone = _engine(model)
+    try:
+        req = alone.submit(prompt(30, 7), max_new=100)
+        assert req.done.wait(300) and req.error is None
+        time.sleep(0.3)         # nothing is live: nothing more is launched
+        assert alone.host_stats()["dispatches"] == 9
+    finally:
+        alone.stop()
+    assert req.finish_reason == "cache" and len(req.tokens) == MAX_LEN - 30
+    _greedy_all_the_way(cfg, params, req)
+
+    with held(engine):
+        other = engine.submit(prompt(9, 8), max_new=MAX_LEN - 10)
+        beside = engine.submit(prompt(30, 7), max_new=100)
+    for r in (other, beside):
+        assert r.done.wait(300) and r.error is None
+    assert beside.finish_reason == "cache" and beside.tokens == req.tokens
+    assert other.finish_reason == "length"
+    _greedy_all_the_way(cfg, params, other)
 
 
 # -- (c) the program's shape --------------------------------------------------

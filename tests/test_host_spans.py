@@ -204,10 +204,10 @@ def test_a_slow_admission_starves_the_device_and_says_so(monkeypatch, capfd):
         time.sleep(0.2)
         real = eng._pop_admissions
 
-        def slow(free, tail):
+        def slow(free):
             if eng.queue_depth():
                 time.sleep(0.3)
-            return real(free, tail)
+            return real(free)
         monkeypatch.setattr(eng, "_pop_admissions", slow)
         t0, before = time.perf_counter(), eng.host_stats()
         req = eng.submit(prompt(20, 5), max_new=2)

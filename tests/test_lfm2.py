@@ -3,10 +3,11 @@
 configuration (tests/data/lfm2_tiny.json: the same nine layers, heads of
 64): `transformer.forward`, the paged prefill and decode layers the engine's
 dispatches are made of (K/V pools and conv tails), rows of several requests
-in one call, prefix hits that restore a conv layer's tail, the engine's
-host loop and its counters, and the limits of the benchmark's `correct`
-shown to refuse four wrong programs and the control.  Logits are compared,
-not tokens; a small model on the CPU."""
+in one call, prefix hits that restore a conv layer's tail, and the limits
+of the benchmark's `correct` shown to refuse four wrong programs and the
+control (the engine's host loop: tests/test_lfm2_engine.py, on the same
+twin, tests/lfm2_twin.py).  Logits are compared, not tokens; a small model
+on the CPU."""
 
 import json
 import os
@@ -21,40 +22,9 @@ from benchmarks.lib import spec
 from ray_tpu.models import afmoe, decoding, lfm2
 from ray_tpu.models import transformer as tfm
 from ray_tpu.ops import paged_attention as pa
-from ray_tpu.serve import llm
 
-KIND = spec.model_kind("lfm2-moe")
-HERE = os.path.dirname(__file__)
-with open(os.path.join(HERE, "data", "lfm2_tiny.json")) as f:
-    TWIN = json.load(f)
-LIMIT = KIND.TOLERANCES["logits_prefill_err"]
-# bf16 at this toy's width of 256 errs more than at the published 2048: its
-# own bound, still well under what the wrong programs and the control read
-TOY_BF16 = {"logits_prefill_err": 0.04, "logits_decode_err": 0.04,
-            "conv_tail_err": 0.05, "logits_after_hit_err": 1e-6,
-            "route_mismatch_share": 0.02,
-            "route_own_input_mismatch_share":
-                KIND.TOLERANCES["route_own_input_mismatch_share"]}
-T = BS = 16                 # the engine's tile and the block
-
-
-def tiny(dtype="float32", **kw):
-    kwargs = KIND.transformer_kwargs(TWIN, max_seq=256, param_dtype=dtype,
-                                     dtype=dtype, **kw)
-    for k in ("dtype", "param_dtype"):
-        kwargs[k] = jnp.dtype(kwargs[k]).type
-    return tfm.TransformerConfig(**kwargs)
-
-
-@pytest.fixture(scope="module")
-def model():
-    cfg = tiny()
-    return cfg, tfm.init_params(cfg, jax.random.PRNGKey(0))
-
-
-def tokens(n, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (n,), 0,
-                              TWIN["vocab_size"]).tolist()
+from lfm2_twin import (BS, KIND, LIMIT, T, TOY_BF16, TWIN,  # noqa: F401
+                       model, tiny, tokens)
 
 
 # -- the reference and the plain forward --------------------------------------
@@ -426,70 +396,6 @@ def test_a_hit_that_restores_nothing_is_refused(monkeypatch):
     bad = KIND.compare(cfg, 7, _sizes(cfg), attn_impl="reference")
     assert bad["logits_after_hit_err"] > 50 * \
         KIND.TOLERANCES["logits_after_hit_err"]
-
-
-# -- the engine ----------------------------------------------------------------
-def _is_greedy(cfg, params, prompt, got):
-    seq = jnp.asarray(list(prompt) + list(got))
-    lg = KIND.reference_logits(KIND.hyper(cfg), params, seq)[
-        len(prompt) - 1:-1]
-    top2 = jnp.sort(lg, axis=-1)[:, -2:]
-    assert float(jnp.min(top2[:, 1] - top2[:, 0])) > 1e-4, "a tie"
-    return jnp.argmax(lg, axis=-1).tolist() == list(got)
-
-
-def test_engine_restores_tails_on_every_hit(model):
-    """PagedBatcher end to end: a 5-block prompt cold, then requests that
-    share its first n blocks for every n, each equal to the reference's
-    greedy continuation, decoding across a block boundary."""
-    cfg, params = model
-    eng = llm.PagedBatcher(params, cfg, num_slots=2, max_len=160,
-                           prompt_pad=128, decode_chunk=4, kv_block_size=BS,
-                           kv_num_blocks=80, attn_impl="reference")
-    try:
-        assert sum(t is not None for t in eng.caches.tail_pool) == 7
-        base = tokens(5 * BS + 3, seed=11)
-        cold = eng.submit(base, max_new=20)
-        assert cold.done.wait(300) and cold.error is None
-        assert not cold.cache_hit
-        assert _is_greedy(cfg, params, base, cold.tokens)
-        for n in range(1, 6):
-            prompt = base[:n * BS] + tokens(9, seed=20 + n)
-            hit = eng.submit(prompt, max_new=6)
-            assert hit.done.wait(300) and hit.error is None
-            assert hit.cached_tokens == n * BS
-            assert _is_greedy(cfg, params, prompt, hit.tokens)
-        # a slot used before, no hit: its conv layers start from zeros
-        fresh = tokens(30, seed=40)
-        again = eng.submit(fresh, max_new=5)
-        assert again.done.wait(300) and not again.cache_hit
-        assert _is_greedy(cfg, params, fresh, again.tokens)
-        assert eng.kv_stats()["prefix_cache"]["hit_tokens"] == \
-            (1 + 2 + 3 + 4 + 5) * BS
-    finally:
-        eng.stop()
-
-
-def test_engine_cuts_a_long_prompt_by_the_token_budget(model, monkeypatch):
-    """Prompts longer than one dispatch's budget (cut to 32 here) beside a
-    short request that decodes on meanwhile: both the reference's."""
-    monkeypatch.setattr(llm, "PREFILL_CHUNK", 32)
-    cfg, params = model
-    eng = llm.PagedBatcher(params, cfg, num_slots=2, max_len=160,
-                           prompt_pad=128, decode_chunk=2, kv_block_size=BS,
-                           kv_num_blocks=40, attn_impl="reference",
-                           prefix_cache=False)
-    try:
-        short, long_ = tokens(9, seed=8), tokens(100, seed=9)
-        a = eng.submit(short, max_new=20)
-        b = eng.submit(long_, max_new=6)
-        assert a.done.wait(300) and b.done.wait(300)
-        assert _is_greedy(cfg, params, short, a.tokens)
-        assert _is_greedy(cfg, params, long_, b.tokens)
-        assert eng.kv_stats()["prefill"]["multi_chunk_requests"] == 1
-    finally:
-        eng.stop()
-
 
 # -- the benchmark's names -----------------------------------------------------
 @pytest.mark.parametrize("cell", [w["name"] for w in

@@ -777,17 +777,15 @@ def test_paged_decode_matches_full_forward():
     paged = decoding.init_paged_caches(cfg, num_slots,
                                        num_slots * W, bs, max_len)
     P = 8
-    packed_p = np.zeros((num_slots + 1,
-                         max(P + 4 + W, num_slots)), np.int32)
+    up = decoding.FusedUpload.of(P, paged)
+    packed_p = up.empty(num_slots)
     for row, p in enumerate(prompts):
         packed_p[row, :len(p)] = p
-        packed_p[row, P] = len(p)          # suffix == whole prompt
-        packed_p[row, P + 1] = 0           # no cached prefix
-        packed_p[row, P + 2:P + 4] = (row, 1)
-        packed_p[row, P + 4:P + 4 + W] = np.arange(
-            1 + row * W, 1 + (row + 1) * W)
+        # suffix == whole prompt, no cached prefix
+        packed_p[row, up.scalars] = (len(p), 0, row, up.CLOSES)
+        packed_p[row, up.table] = np.arange(1 + row * W, 1 + (row + 1) * W)
     steps = 6
-    paged, toks = decoding.paged_prefill_decode_packed(
+    paged, toks, _ = decoding.paged_prefill_decode_packed(
         params, paged, jnp.asarray(packed_p), cfg, steps, P,
         attn_impl="reference")
     toks = np.asarray(toks)
@@ -883,6 +881,54 @@ def test_request_capped_by_table_width_truncates_with_cache():
         assert len(req.tokens) == 11
     finally:
         bat.stop()
+
+
+def _is_greedy(params, cfg, req):
+    """One forward pass over prompt + reply: every token of the reply is
+    the argmax at the position before it."""
+    from ray_tpu.models import transformer
+    seq = np.asarray([req.prompt + req.tokens], np.int32)
+    want = np.argmax(np.asarray(transformer.forward(params, seq, cfg)[0]),
+                     axis=-1)[len(req.prompt) - 1:-1]
+    assert req.tokens == want.tolist()
+
+
+def test_a_clamped_request_rides_the_chunks_beside_a_live_slot():
+    """A request that max_len clamps decodes in ordinary chunks, the last
+    of which runs past its cap, beside a slot that lives on: its reply is
+    the first cap - len(prompt) tokens its prompt yields, cut there with
+    reason "cache", and the other slot's reply is what it yields alone."""
+    cfg, params = _tiny_cfg(), _tiny_params()
+    bat = _paged(params, cfg)               # max_len 48, chunks of 4
+    try:
+        other = bat.submit([3, 1, 4], max_new=42)
+        clamped = bat.submit(list(range(20, 34)), max_new=64)
+        for r in (other, clamped):
+            assert r.done.wait(120) and r.error is None
+    finally:
+        bat.stop()
+    assert clamped.finish_reason == "cache" and len(clamped.tokens) == 48 - 14
+    assert other.finish_reason == "length" and len(other.tokens) == 42
+    _is_greedy(params, cfg, clamped)
+    _is_greedy(params, cfg, other)
+
+
+def test_a_lone_clamped_request_takes_whole_chunks_to_its_cap():
+    """Two programs, no single step: 34 tokens to the cap at 4 a dispatch
+    are 9 dispatches (the engine with a one-token tail launched 8 chunks
+    and 3 single steps); past the cap nothing is live and nothing more is
+    launched."""
+    cfg, params = _tiny_cfg(), _tiny_params()
+    bat = _paged(params, cfg)
+    try:
+        req = bat.submit(list(range(20, 34)), max_new=64)
+        assert req.done.wait(120) and req.error is None
+        time.sleep(0.3)
+        assert bat.host_stats()["dispatches"] == 9
+    finally:
+        bat.stop()
+    assert req.finish_reason == "cache" and len(req.tokens) == 34
+    _is_greedy(params, cfg, req)
 
 
 def test_unaligned_max_len_caps_at_max_len_not_table():

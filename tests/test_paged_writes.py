@@ -6,9 +6,9 @@ indices the programs themselves build (`prefill_rows`, `decode_rows`,
 `_pass_tokens`), to the write it replaced, a scatter of D-wide rows: equal
 bit for bit on every LIVE position, every block no live position lies in
 untouched (a shared prefix block among them), the rest of a slot's page as
-it was; and through the engine: a prefix hit's blocks are byte-identical
-after the dispatch that hits them, and `kv_stats()["writes"]` counts no row
-update."""
+it was; rows that are not whole blocks are refused; and through the engine:
+a prefix hit's blocks are byte-identical after the dispatch that hits
+them."""
 
 import time
 
@@ -170,29 +170,24 @@ def test_a_decode_step_patches_each_slots_page(form):
 
 
 @pytest.mark.parametrize("form", sorted(FORMS))
-def test_rows_that_are_not_whole_blocks_keep_the_row_scatter(form):
-    """P % bs != 0 (no engine: `prefill_shapes` cuts rows in whole blocks):
-    every position goes in as rows of lanes, chosen by that static test."""
+def test_rows_that_are_not_whole_blocks_are_refused(form):
+    """P % bs != 0: there is no write but by page, and the refusal names
+    where an engine's rows come from (`prefill_shapes` cuts them in whole
+    blocks; an engine whose tile is not whole blocks is refused when it is
+    built).  A decode step's rows alone (no prompt row) are written
+    whatever P is."""
     shape, (hkv, D), layers = FORMS[form]
     P = BS // 2
     pr, step, N = _scene("partial", P)
-    _, valid, blocks, offsets = decoding._pass_tokens(pr, step)
-    pool0 = jax.random.normal(jax.random.PRNGKey(5), (NB,) + shape[1:],
-                              jnp.bfloat16)
-    new = jax.random.normal(jax.random.PRNGKey(6),
-                            (blocks.shape[0], hkv, D), jnp.bfloat16)
+    _, _, blocks, offsets = decoding._pass_tokens(pr, step)
+    pool0 = jnp.zeros((NB,) + shape[1:], jnp.bfloat16)
+    new = jnp.zeros((blocks.shape[0], hkv, D), jnp.bfloat16)
     write = _writer(form)
-    jaxpr = str(jax.make_jaxpr(write, static_argnums=4)(
-        pool0, blocks, offsets, new, (N, P)))
-    assert f"[{NB * shape[1] * BS},{shape[3]}]" in jaxpr.replace(" ", "")
-    out = jax.jit(write, static_argnums=4)(pool0, blocks, offsets, new,
-                                           (N, P))
-    live = np.asarray(valid).reshape(-1)
-    want = _row_scatter(np.asarray(pool0, np.float32),
-                        np.asarray(blocks)[live], np.asarray(offsets)[live],
-                        _lanes(new, shape)[live])
-    got = np.asarray(out, np.float32)
-    np.testing.assert_array_equal(got[1:], want[1:])    # all but scratch
+    with pytest.raises(ValueError, match="prefill_shapes"):
+        jax.make_jaxpr(write, static_argnums=4)(pool0, blocks, offsets, new,
+                                                (N, P))
+    jax.make_jaxpr(write, static_argnums=4)(
+        pool0, blocks[N * P:], offsets[N * P:], new[N * P:], (0, P))
 
 
 @pytest.mark.parametrize("form", sorted(FORMS))
@@ -215,19 +210,6 @@ def test_the_written_program_indexes_the_pool_by_block_alone(form):
         dn = e.params["dimension_numbers"]
         assert dn.scatter_dims_to_operand_dims == (0,)
         assert dn.update_window_dims == (1, 2, 3)
-
-
-def test_pool_updates_counts_pages():
-    cfg = tfm.TransformerConfig(vocab_size=97, d_model=32, n_heads=4,
-                                n_kv_heads=2, n_layers=2, d_ff=64, max_seq=128)
-    caches = jax.eval_shape(lambda: decoding.init_paged_caches(
-        cfg, SLOTS, 24, BS, 64))
-    # 2 layers x (k, v); a pass of 5 rows of one block + SLOTS carried, then
-    # 7 steps; a decode-only dispatch of 8 steps; rows of half a block
-    assert decoding.pool_updates(caches, 5, BS, 8) == (4 * (5 + 8 * SLOTS), 0)
-    assert decoding.pool_updates(caches, 0, BS, 8) == (4 * 8 * SLOTS, 0)
-    assert decoding.pool_updates(caches, 5, BS // 2, 8) == (
-        4 * 7 * SLOTS, 4 * 2 * (5 * BS // 2 + SLOTS))
 
 
 # -- through the engine ---------------------------------------------------
@@ -268,12 +250,14 @@ def test_a_shared_prefix_block_is_byte_identical_after_a_hit(engine):
         assert was.tobytes() == now[:, shared].tobytes()
 
 
-def test_the_engine_counts_its_writes_by_page(engine):
-    made = engine.kv_stats()["writes"]
-    r = engine.submit([3, 4, 5, 6, 7], max_new=6)
-    assert r.done.wait(300) and r.error is None
-    now = engine.kv_stats()["writes"]
-    assert now["row_updates"] == made["row_updates"] == 0
-    # two layers x two pools; at least the admitting dispatch's one row of
-    # 16 (four blocks) and four steps of four slots
-    assert now["page_updates"] - made["page_updates"] >= 4 * (4 + 4 * 4)
+def test_an_engine_whose_tile_is_not_whole_blocks_is_refused_when_built():
+    """prefill_shapes cuts a prompt_pad under PREFILL_TILE into one tile of
+    that many tokens: where that is not whole blocks the constructor says
+    so, before a thread is started or a program traced."""
+    cfg = tfm.TransformerConfig(vocab_size=97, d_model=32, n_heads=4,
+                                n_kv_heads=2, n_layers=2, d_ff=64,
+                                max_seq=128, dtype=jnp.float32, remat=False)
+    assert llm.prefill_shapes(4, 6, 4)[0] == 6
+    with pytest.raises(ValueError, match="whole blocks"):
+        llm.PagedBatcher(None, cfg, num_slots=4, max_len=64, prompt_pad=6,
+                         kv_block_size=4, attn_impl="reference")

@@ -75,13 +75,14 @@ def _prefilled(params, prompts, block_size=8, max_len=32):
     path's packed prefill, and each prompt's first token."""
     B, P = len(prompts), 8
     W = decoding.paged_table_width(max_len, block_size)
-    packed = np.zeros((B + 1, max(P + 4 + W, B)), np.int32)
+    caches = decoding.init_paged_caches(CFG, B, B * W, block_size, max_len)
+    up = decoding.FusedUpload.of(P, caches)
+    packed = up.empty(B)
     for row, prompt in enumerate(prompts):
         packed[row, :len(prompt)] = prompt
-        packed[row, P:P + 4] = (len(prompt), 0, row, 1)
-        packed[row, P + 4:P + 4 + W] = 1 + row * W + np.arange(W)
-    caches = decoding.init_paged_caches(CFG, B, B * W, block_size, max_len)
-    caches, toks = decoding.paged_prefill_decode_packed(
+        packed[row, up.scalars] = (len(prompt), 0, row, up.CLOSES)
+        packed[row, up.table] = 1 + row * W + np.arange(W)
+    caches, toks, _ = decoding.paged_prefill_decode_packed(
         params, caches, jnp.asarray(packed), CFG, 1, P)
     return caches, toks[0]
 
@@ -103,9 +104,9 @@ def test_quantized_prefill_decode_close_to_fp():
     caches_q, tq = _prefilled(qp, prompts)
     agree = int(jnp.sum(t == tq))
     for _ in range(7):
-        caches, t = decoding.paged_decode_step(p, caches, active, CFG)
-        caches_q, tq = decoding.paged_decode_step(qp, caches_q, active,
-                                                  CFG)
+        caches, t, _ = decoding.paged_decode_steps(p, caches, active, CFG, 1)
+        caches_q, tq, _ = decoding.paged_decode_steps(qp, caches_q, active,
+                                                      CFG, 1)
         agree += int(jnp.sum(t == tq))
     # Random tiny model: near-argmax ties can flip, but the two decodes
     # must be substantially the same trajectory.
@@ -117,8 +118,8 @@ def test_init_quantized_params_no_f32_stage():
     assert isinstance(qp["layers"]["w_up"], QuantizedArray)
     caches = decoding.init_paged_caches(CFG, 4, 16, 16, 64)
     active = jnp.ones((4,), bool)
-    _, tok = decoding.paged_decode_step(qp, caches, active, CFG)
-    assert tok.shape == (4,) and tok.dtype == jnp.int32
+    _, tok, _ = decoding.paged_decode_steps(qp, caches, active, CFG, 1)
+    assert tok.shape == (1, 4) and tok.dtype == jnp.int32
 
 
 def test_8b_memory_math_fits_v5e():

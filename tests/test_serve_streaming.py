@@ -119,7 +119,7 @@ def test_llm_deployment_streams_tokens(serve_session, arch):
         cfg_kwargs=dict(vocab_size=128, d_model=64, n_layers=2,
                         n_heads=2, max_seq=64, arch=arch,
                         remat=False, attn_impl="reference"),
-        num_slots=2, max_len=48, prompt_pad=8)
+        num_slots=2, max_len=48, prompt_pad=8, kv_block_size=4)
     h = serve.run(dep, name="llm")
     # Generous timeouts: under a full parallel suite on the 1-vCPU
     # host, engine warmup compiles contend with every other test.

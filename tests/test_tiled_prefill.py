@@ -64,22 +64,24 @@ def _prefill(cfg, params, caches, reqs, width, steps=3):
     4, 7, 14 and 28 -> (caches', each request's first token, the
     dispatch's tokens [steps, SLOTS]: the pass's, then the decode
     steps')."""
+    up = decoding.FusedUpload(width, W, SLOTS, sets=False)
     rows, closing = [], []
     for slot, toks, done, table in reqs:
         for start in range(done, len(toks), width):
             n = min(width, len(toks) - start)
-            rows.append((toks[start:start + n], n, start, slot, 2, table))
-        rows[-1] = rows[-1][:4] + (1, table)
+            rows.append((toks[start:start + n], n, start, slot, up.MORE,
+                         table))
+        rows[-1] = rows[-1][:4] + (up.CLOSES, table)
         closing.append(slot)
     N = next(n for n in (4, 7, 14, 28) if n >= len(rows))
-    packed = np.zeros((N + 1, max(width + 4 + W, SLOTS)), np.int32)
+    packed = up.empty(N)
     for r, (toks, n, start, slot, flag, table) in enumerate(rows):
         packed[r, :n] = toks
-        packed[r, width:width + 4] = (n, start, slot, flag)
-        packed[r, width + 4:width + 4 + W] = table
-    caches, toks = decoding.paged_prefill_decode_packed(
+        packed[r, up.scalars] = (n, start, slot, flag)
+        packed[r, up.table] = table
+    caches, toks, _ = decoding.paged_prefill_decode_packed(
         params, caches, jnp.asarray(packed), cfg, steps, width,
-        attn_impl="reference")[:2]
+        attn_impl="reference")
     return caches, [int(toks[0, s]) for s in closing], np.asarray(toks)
 
 
@@ -188,9 +190,10 @@ def _record(monkeypatch):
     real = decoding.paged_prefill_decode_packed
 
     def spy(params, caches, packed, cfg, chunk, width, **kw):
+        up = decoding.FusedUpload.of(width, caches)
         p = np.asarray(packed)[:-1]
-        live = [(int(r[width]), int(r[width + 2]), int(r[width + 3]))
-                for r in p if r[width + 3]]
+        live = [(int(r[up.suffix_len]), int(r[up.slot]), int(r[up.flag]))
+                for r in p if r[up.flag]]
         if live:                        # warm-up's calls carry no row
             seen.append((len(p), live))
         return real(params, caches, packed, cfg, chunk, width, **kw)

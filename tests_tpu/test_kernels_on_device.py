@@ -590,15 +590,16 @@ def test_fused_dispatch_against_decode_only_on_tpu():
     tables = np.zeros((B, W), np.int32)
     tables[:, :held] = 1 + np.arange(B * held).reshape(B, held)
     N = 256 // T
-    packed = np.zeros((N + 1, max(T + 4 + W, B)), np.int32)
-    packed[N, :B] = 1
+    up = decoding.FusedUpload.of(T, caches)._replace(sets=False)
+    packed = up.empty(N)
+    packed[N, up.active] = 1
     idle = np.array(packed)                     # the same program, no row
     packed[:N, :T] = rng.randint(1, 32000, (N, T))
     # one request's 256 positions in blocks of its own, flag 2 throughout:
     # it closes no slot, so all 32 ride in the pass and no new slot joins
     # the steps (both programs decode the same 32)
-    packed[:N, T:T + 4] = [[T, r * T, 0, 2] for r in range(N)]
-    packed[:N, T + 4:T + 4 + N] = 1 + B * held + np.arange(N)
+    packed[:N, up.scalars] = [[T, r * T, 0, up.MORE] for r in range(N)]
+    packed[:N, up.table][:, :N] = 1 + B * held + np.arange(N)
     assert B * held + N <= NB and lens.max() + 2 * chunk <= held * bs
 
     def fresh():
