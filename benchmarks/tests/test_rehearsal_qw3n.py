@@ -1,0 +1,75 @@
+"""The cell `serve-qw3n-agent-sessions` end to end at a toy size on the CPU
+(kind `gated-delta-moe`, traffic `agent-sessions`): the reference path of
+every kernel, the runtime's own workers, the toy twin the program's tests use
+(tests/data/qwen3_next_tiny.json).  Never a device number.  About three
+minutes; run by the builder, not by tier-1."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from benchmarks.lib import spec
+
+TMP = os.path.join(spec.BENCH_DIR, "tests", ".tmp")
+CELL = "serve-qw3n-agent-sessions"
+
+
+@pytest.fixture(scope="module")
+def rehearsal_benchmark():
+    bench = spec.load_benchmark()
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    for c in bench["configs"]:
+        if c["name"] == cell["config"]:
+            c["file"] = "tests/data/qwen3_next_tiny.json"
+    cell["traffic"] = "../tests/data/tiny-agent-sessions"
+    os.makedirs(TMP, exist_ok=True)
+    path = os.path.join(TMP, "BENCHMARK.rehearsal-qw3n.json")
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    return os.path.relpath(path, spec.ROOT)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_qw3n_agent_sessions_rehearses_on_cpu(rehearsal_benchmark, trace):
+    proc = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", CELL,
+         "--seed", "3000000001", "--seconds", "6", "--trace", str(trace),
+         "--rehearsal", "--benchmark", rehearsal_benchmark],
+        cwd=spec.ROOT, capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["failed"] == 0 and line["attempted"] > 0
+    assert line["rehearsal"] is True
+    assert line["device"]["platform"] == "cpu"
+    compared = line["checks"]["compared"]
+    assert sorted(compared) == sorted(
+        spec.model_kind("gated-delta-moe").CHECKS["serve"])
+    # bf16 at the toy's width of 128 errs more than the limits set at 2048
+    # allow (tests/test_qwen3_next.py); what a checkpoint restores, and what
+    # float32 makes of the program's own inputs, is exact
+    assert compared["logits_after_hit_err"][0] == 0.0
+    assert compared["route_own_input_mismatch_share"][0] == 0.0
+    assert compared["state_own_input_err"][0] < 1e-5
+    c = line["counters"]
+    assert c["prefill.chunk_tokens"] > c["prefill.chunks"] > 0
+    assert c["prefix_cache.hit_tokens"] > 0
+    # the serve key reached the engine; hits were restored from checkpoints
+    # the radix cache owns; the expert layers counted their share
+    assert line["checks"].get("engine_warmup_s", 0) > 0
+    assert c["state.restores"] > 0 and c["state.snapshots"] > 0
+    assert c["moe.picked_rows"] == c["moe.routed_rows"] + c["moe.absent_rows"]
+    assert c["moe.padded_rows"] > c["moe.routed_rows"] > 0
+    if trace:       # the counter metrics read; the trace ones need a chip
+        m = line["metrics"]
+        assert 0 < m["qw3n_prefix_hit_share"]["value"] < 100
+        assert 50 < m["qw3n_state_usable_share"]["value"] <= 100
+        assert 10 < m["qw3n_expert_local_share"]["value"] < 50
+        assert 0 < m["qw3n_expert_tile_fill_share"]["value"] < 100
+        assert m["qw3n_state_host_ms_per_dispatch"]["value"] > 0
+        assert "qw3n_delta_step_roofline" not in m
+    else:
+        assert line["metrics"]["decode_tokens_per_s"]["value"] > 0
+        assert line["metrics"]["setup_s"]["value"] > 0

@@ -34,6 +34,7 @@ with l, so one layer can be made alone.  There is no training path:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -42,7 +43,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (TransformerConfig, _norm, _rope,
                                         _w_out)
-from ray_tpu.ops.grouped_ffn import grouped_ffn
+from ray_tpu.ops.grouped_ffn import grouped_ffn, tile_rows
 
 # What one expert layer counts per call (a decode step or a prefill chunk):
 # calls, (token, pick) rows routed to an expert held here, the largest
@@ -50,6 +51,10 @@ from ray_tpu.ops.grouped_ffn import grouped_ffn
 # of valid tokens whose expert is not held here (0 where every expert is).
 MOE_COUNTS = ("layer_steps", "routed_rows", "busiest_expert_rows",
               "experts_touched", "absent_rows")
+# ... and, where a module asks `experts` for it (`count_padded`; its own
+# MOE_COUNTS then ends with this name), the rows the grouped product
+# computed, each expert's group padded to whole tiles (ops/grouped_ffn.py).
+PADDED_ROWS = "padded_rows"
 
 
 def no_counts() -> jax.Array:
@@ -183,10 +188,15 @@ def _ffn(m, w_gate, w_up, w_down):
 def route(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array
           ) -> Tuple[jax.Array, jax.Array]:
     """m [T, D] -> (expert ids [T, k] int32, weights [T, k] float32).
-    Scores in float32 at full precision; the bias (where the layer has
-    one) only selects; ties as `jax.lax.top_k` breaks them."""
+    Scores in float32 at full precision: each logit's sigmoid, or
+    (`cfg.moe_score_fn` "softmax") the softmax over the router's whole
+    width; the bias (where the layer has one) only selects; the weights are
+    the picks' scores over their sum; ties as `jax.lax.top_k` breaks them."""
+    score = {"sigmoid": jax.nn.sigmoid,
+             "softmax": functools.partial(jax.nn.softmax, axis=-1)
+             }[cfg.moe_score_fn]
     with jax.named_scope("moe_route"):
-        s = jax.nn.sigmoid(jnp.einsum(
+        s = score(jnp.einsum(
             "td,de->te", m.astype(jnp.float32),
             p["w_router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
@@ -208,9 +218,10 @@ def route(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array
 
 def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
             valid: Optional[jax.Array], name: str,
-            tap: Optional[Callable] = None
+            tap: Optional[Callable] = None, count_padded: bool = False
             ) -> Tuple[jax.Array, jax.Array]:
-    """m [B, S, D], valid [B, S] (None: every row) -> (y, MOE_COUNTS).
+    """m [B, S, D], valid [B, S] (None: every row) -> (y, MOE_COUNTS, and
+    with `count_padded` PADDED_ROWS after them).
     Rows that are not valid are routed nowhere and are not counted.
     `tap`, if given, is shown the picks [B * S, k] (a comparison's way to
     see them; the serving path passes none).
@@ -221,7 +232,8 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
     a (token, pick) pair whose expert is not held is routed nowhere, as an
     invalid row is, and counted as absent.  What comes back is the held
     experts' part of the layer's output plus the shared expert's, which is
-    computed for every token."""
+    computed for every token (`cfg.moe_shared_gate`: times sigmoid(w . m),
+    a scalar a token, in float32)."""
     B, S, D = m.shape
     m2 = m.reshape(B * S, D)
     ok = (jnp.ones((B * S,), bool) if valid is None
@@ -239,11 +251,21 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
                            p["w_down"], name=name)
     y = y.reshape(B, S, D)
     if cfg.moe_shared_experts:
-        y = y + _ffn(m, p["ws_gate"], p["ws_up"], p["ws_down"])
-    counts = jnp.stack([jnp.ones((), jnp.int32), jnp.sum(sizes),
-                        jnp.max(sizes),
-                        jnp.sum((sizes > 0).astype(jnp.int32)), absent])
-    return y, counts
+        with jax.named_scope("moe_shared"):
+            shared = _ffn(m, p["ws_gate"], p["ws_up"], p["ws_down"])
+            if cfg.moe_shared_gate:
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "bsd,d->bs", m.astype(jnp.float32),
+                    p["w_shared_gate"].astype(jnp.float32)))
+                shared = (gate[..., None] * shared.astype(jnp.float32)
+                          ).astype(y.dtype)
+            y = y + shared
+    counts = [jnp.ones((), jnp.int32), jnp.sum(sizes), jnp.max(sizes),
+              jnp.sum((sizes > 0).astype(jnp.int32)), absent]
+    if count_padded:
+        tm = tile_rows(idx.size)
+        counts.append(jnp.sum((sizes + (tm - 1)) // tm) * tm)
+    return y, jnp.stack(counts)
 
 
 def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],

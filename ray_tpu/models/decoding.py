@@ -35,7 +35,8 @@ window and full attention layers mixed; models/lfm2.py, short convolutions
 and attention layers mixed; models/axk1.py, latent attention whose cache
 row has no head axis; all with expert layers; models/olmo_hybrid.py, gated
 delta-rule layers whose state is a matrix a head a SEQUENCE, kept by state
-id beside the pages) have their paged
+id beside the pages; models/qwen3_next.py, that state beside gated attention
+at heads of 256, an expert layer in every layer) have their paged
 steps at the end of this file, built from their module's one layer
 definition; the two programs an engine runs (paged_prefill_decode_packed,
 paged_decode_steps) branch to them.  Both return (caches', tokens

@@ -70,9 +70,16 @@ def _check(cfg: TransformerConfig) -> None:
                          "and linear_value_dim")
 
 
+def key_heads(cfg: TransformerConfig) -> int:
+    """A linear layer's key (and query) heads: `linear_heads` unless
+    `linear_key_heads` says that fewer are shared."""
+    return cfg.linear_key_heads or cfg.linear_heads
+
+
 def conv_width(cfg: TransformerConfig) -> int:
     """Channels of a linear layer's convolution: q, k and v side by side."""
-    return cfg.linear_heads * (2 * cfg.linear_key_dim + cfg.linear_value_dim)
+    return (2 * key_heads(cfg) * cfg.linear_key_dim
+            + cfg.linear_heads * cfg.linear_value_dim)
 
 
 # ---------------------------------------------------------------------------
@@ -187,9 +194,12 @@ def delta_mixer(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
     """The linear layer's branch on a [B, S, D] (see the module docstring:
     `before`, `rule` are the caller's).  `tap`, if given, is shown what the
     rule is handed: q, k, v, log_a, beta (a comparison's way to see them; the
-    serving path passes none)."""
+    serving path passes none).  Where the layer has fewer key heads than
+    value heads (`key_heads`; wq, wk [D, Hk, dk]) value head h uses key
+    head h // (H / Hk): q and k are repeated before the rule sees them."""
     B, S, _ = a.shape
     H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    Hk = key_heads(cfg)
     with jax.named_scope("delta_proj"):
         def proj(w):
             return jnp.einsum("bsd,dhk->bshk", a, w.astype(a.dtype)
@@ -199,9 +209,11 @@ def delta_mixer(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
         w = p["w_conv"].astype(jnp.float32)
         c = jax.nn.silu(sum(w[j] * t.astype(jnp.float32)
                             for j, t in enumerate(before(u) + [u])))
-        q = _l2(c[..., :H * dk].reshape(B, S, H, dk)) * dk ** -0.5
-        k = _l2(c[..., H * dk:2 * H * dk].reshape(B, S, H, dk))
-        v = c[..., 2 * H * dk:].reshape(B, S, H, dv)
+        q = _l2(c[..., :Hk * dk].reshape(B, S, Hk, dk)) * dk ** -0.5
+        k = _l2(c[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk))
+        v = c[..., 2 * Hk * dk:].reshape(B, S, H, dv)
+        if Hk != H:
+            q, k = (jnp.repeat(x, H // Hk, axis=2) for x in (q, k))
         af = a.astype(jnp.float32)
         beta = jax.nn.sigmoid(af @ p["wb"].astype(jnp.float32)) * (
             2.0 if cfg.linear_neg_eigval else 1.0)

@@ -44,7 +44,7 @@ class TransformerConfig:
     max_seq: int = 2048
     arch: str = "llama"                   # "llama" | "gpt2" | a module of
     # unrolled layers under models/ ("afmoe", "lfm2", "axk1",
-    # "olmo_hybrid": see `layer_kinds`)
+    # "olmo_hybrid", "qwen3_next": see `layer_kinds`)
     rope_theta: Optional[float] = 500_000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16             # activation/compute dtype
@@ -133,6 +133,29 @@ class TransformerConfig:
     linear_value_dim: int = 0
     linear_neg_eigval: bool = False
     norm_after_branch: bool = False
+    # arch "qwen3_next" (models/qwen3_next.py; serving and `forward` only):
+    # mixer "linear" | "full", an expert layer in every layer.  What no key
+    # above expresses, each with a default that leaves every other
+    # architecture's tree and programs as they are:
+    # `linear_key_heads` key (and query) heads of a linear layer, each shared
+    # by linear_heads / linear_key_heads value heads in a row (0: as many as
+    # `linear_heads`, which counts the VALUE heads, the state's);
+    # `rotary_dim` leading dims of a head that carry the rotary embedding
+    # (0: the whole head);
+    # `moe_score_fn` what `afmoe.route` makes of the router's logits:
+    # "sigmoid", or "softmax" over the router's whole width;
+    # `moe_shared_gate` the shared expert scaled by sigmoid(w . m), a scalar
+    # a token;
+    # `attn_output_gate` a full layer's q projection twice as wide, a head's
+    # query dims then its gate dims, the heads' output times sigmoid(gate)
+    # before W_o;
+    # `norm_zero_centered` an RMSNorm's weight w applied as (1 + w).
+    linear_key_heads: int = 0
+    rotary_dim: int = 0
+    moe_score_fn: str = "sigmoid"
+    moe_shared_gate: bool = False
+    attn_output_gate: bool = False
+    norm_zero_centered: bool = False
 
     def __post_init__(self):
         if self.layer_kinds is not None:

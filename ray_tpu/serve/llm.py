@@ -1153,12 +1153,13 @@ class PagedBatcher:
                 "prefill": dict(
                     self._prefill_counts,
                     rung_dispatches=dict(self._rung_dispatches)),
-                # (models/afmoe.py MOE_COUNTS, and the picks of valid
-                # tokens: those routed here and those whose expert is held
-                # elsewhere)
+                # (models/afmoe.py MOE_COUNTS, then PADDED_ROWS where the
+                # model's layers count it, and the picks of valid tokens:
+                # those routed here and those whose expert is held elsewhere)
                 "moe": dict(zip(("layer_steps", "routed_rows",
                                  "busiest_expert_rows", "experts_touched",
-                                 "absent_rows"), self._moe_counts),
+                                 "absent_rows", "padded_rows"),
+                                self._moe_counts),
                             picked_rows=self._moe_counts[1]
                             + self._moe_counts[4]),
                 "decode": dict(self._decode_reads),
@@ -1777,8 +1778,8 @@ class PagedBatcher:
         if counts is not None:
             counts = np.asarray(counts).tolist()
             with self._kv_lock:
-                self._moe_counts = [a + b for a, b in
-                                    zip(self._moe_counts, counts)]
+                self._moe_counts = [a + b for a, b in itertools.zip_longest(
+                    self._moe_counts, counts, fillvalue=0)]
 
     def _post_admit(self, batch: List[tuple]) -> None:
         """Bookkeeping after a fused dispatch launched: radix insertion

@@ -209,12 +209,16 @@ def test_paged_serving_steps_compile_for_v5e(v5e_devices, model,
 # expert layers the product over 4 blocks of F; Olmo-Hybrid's 3 full layers
 # the two paged kernels at 30 heads, its 9 linear layers `gated_delta_chunk`
 # for a pass's prompt rows and `gated_delta_step` for its carried rows, a
-# decode step `gated_delta_step`
+# decode step `gated_delta_step`; Qwen3-Next's 2 full layers the two paged
+# kernels at 16 / 2 heads of 256, its 6 linear layers the two delta kernels
+# at 32 heads of [128, 128], and all 8 layers the grouped product over 128
+# held experts of 2048 x 512
 CELL_KERNELS = {"mistral-7b-l16": (1 + 1, 1),
                 "trinity-mini-l5": (5 + 5 + 4, 5 + 4),
                 "lfm2-24b-a2b-l9": (2 + 2 + 8, 2 + 8),
                 "axk1-l7-ep16": (7 + 7 + 6, 7 + 6),
-                "olmo-hybrid-7b-l12": (3 + 3 + 9 + 9, 3 + 9)}
+                "olmo-hybrid-7b-l12": (3 + 3 + 9 + 9, 3 + 9),
+                "qwen3-next-80b-a3b-l8-ep4": (2 + 2 + 6 + 6 + 8, 2 + 6 + 8)}
 
 
 # LFM2's nine unrolled layers compile ~40 s a program here: one test a
@@ -224,7 +228,8 @@ CELL_PROGRAMS = [pytest.param("mistral-7b-l16", None, id="mistral-7b-l16"),
                  pytest.param("trinity-mini-l5", None, id="trinity-mini-l5")
                  ] + [pytest.param(name, i, id=f"{name}-{i}")
                       for name in ("lfm2-24b-a2b-l9", "axk1-l7-ep16",
-                                   "olmo-hybrid-7b-l12")
+                                   "olmo-hybrid-7b-l12",
+                                   "qwen3-next-80b-a3b-l8-ep4")
                       for i in range(5)]
 
 
@@ -278,7 +283,8 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     1,072 columns beside its conv layers' tails, A.X-K1's 64 heads over
     latent rows of 640 lanes with its experts in 4 blocks of F,
     Olmo-Hybrid's 30 / 30 heads of 128 beside nine layers' states of
-    [15, 96, 384] by state id) and the
+    [15, 96, 384] by state id, Qwen3-Next's 16 / 2 heads of 256 beside six
+    layers' states of [32, 128, 128] and 128 held experts a layer) and the
     decode-only chunk, as Mosaic kernels,
     inside one chip's memory beside the weights.  (`impl="auto"` asks
     jax.default_backend(): steered here, in the test, as it would read on
@@ -292,7 +298,8 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     assert caches.block_tables.shape[1] == {
         "mistral-7b-l16": 48, "trinity-mini-l5": 1072,
         "lfm2-24b-a2b-l9": 1072, "axk1-l7-ep16": 1072,
-        "olmo-hybrid-7b-l12": 1072}[config_name]
+        "olmo-hybrid-7b-l12": 1072,
+        "qwen3-next-80b-a3b-l8-ep4": 1072}[config_name]
     # the budget is PREFILL_CHUNK tokens whatever the slots are, in at
     # most six programs, none more than 384 positions wider than the one
     # before it up to 896
@@ -327,7 +334,8 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
 # the tree that last meant to change one; `UPDATE_PROGRAM_HASHES=1` writes it
 # anew (a PR that changes a program on purpose says so and does).
 HASHED_CONFIGS = ("mistral-7b-l16", "trinity-mini-l5", "lfm2-24b-a2b-l9",
-                  "axk1-l7-ep16", "olmo-hybrid-7b-l12")
+                  "axk1-l7-ep16", "olmo-hybrid-7b-l12",
+                  "qwen3-next-80b-a3b-l8-ep4")
 HASH_FILE = "serving_program_hashes.json"
 
 
