@@ -248,7 +248,8 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
         absent = jnp.sum((ok[:, None] & ~held).astype(jnp.int32))
         idx, ok = jnp.clip(local, 0, cfg.moe_experts - 1), ok[:, None] & held
     y, sizes = grouped_ffn(m2, idx, w, ok, p["w_gate"], p["w_up"],
-                           p["w_down"], name=name)
+                           p["w_down"], name=name,
+                           router_width=cfg.router_width)
     y = y.reshape(B, S, D)
     if cfg.moe_shared_experts:
         with jax.named_scope("moe_shared"):
@@ -263,7 +264,7 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
     counts = [jnp.ones((), jnp.int32), jnp.sum(sizes), jnp.max(sizes),
               jnp.sum((sizes > 0).astype(jnp.int32)), absent]
     if count_padded:
-        tm = tile_rows(idx.size)
+        tm = tile_rows(idx.size, cfg.router_width)
         counts.append(jnp.sum((sizes + (tm - 1)) // tm) * tm)
     return y, jnp.stack(counts)
 

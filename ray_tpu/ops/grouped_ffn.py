@@ -24,28 +24,37 @@ fit (`_f_blocks`); wider experts (hidden 7168 x width 2048 is 88 MB an
 expert in bf16) go through a second, inner grid axis over blocks of the
 expert width F, the down-projection accumulated over them in float32.
 
-The sort, the padding plan and the weighted sum back into token order are
-`jax.numpy` (gathers, under the `moe_route` scope); only the tiled FFN is
-the Pallas kernel, named `moe_experts_decode` or `moe_experts_prefill` by
-its caller.  `impl="reference"` runs the same plan with a gathered einsum in
-place of the kernel (any backend; what the CPU tests compare the kernel
-with).
+The padding plan and the weighted sum back into token order are
+`jax.numpy` under the `moe_route` scope (`_plan`): ONE sort (of the keys
+`expert * T + token`), no scatter, counts and running counts over [T, E] in
+place of an inverse permutation, and two gathers of rows (x into the tiles,
+the tiles' output back to the pairs).  Only the tiled FFN is the Pallas
+kernel, named `moe_experts_decode` or `moe_experts_prefill` by its caller.
+`impl="reference"` runs the same plan with a gathered einsum in place of
+the kernel (any backend; what the CPU tests compare the kernel with).
+
+The tile follows the rows an expert of the ROUTER gets, from shapes alone
+(`tile_rows`, the one place it is decided: the product and the
+`moe.padded_rows` counter of models/afmoe.py both ask it): the plan pads
+every held expert's group to whole tiles and lays out `pairs + E * (tm - 1)`
+rows whatever was routed here, so a tile far over the mean group is rows
+gathered and computed for nothing; a tile under it is more programs, each
+of which pushes the expert's weights through the MXU again.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import compiled_on_tpu
 
-# Rows a tile holds: one packed bf16 sublane tile for a decode step's few
-# rows per expert, a full MXU pass for a prefill chunk's many.
-_TM_SMALL, _TM_LARGE = 16, 256
-_SMALL_ROWS = 4096          # (token, pick) pairs up to which tiles are small
+# Rows a tile may hold: from one packed bf16 sublane tile (a decode step's
+# few rows an expert) to two MXU passes.
+_TILES = (16, 32, 64, 128, 256)
 _VMEM_LIMIT = 64 * 2 ** 20  # two sets of one expert's three matrices
 # Wider experts: two sets of the three matrices' BLOCKS may take this much,
 # under a limit that leaves the row tiles and the accumulator their room.
@@ -53,8 +62,16 @@ _BLOCK_BUDGET = 48 * 2 ** 20
 _VMEM_LIMIT_BLOCKED = 100 * 2 ** 20
 
 
-def tile_rows(pairs: int) -> int:
-    return _TM_SMALL if pairs <= _SMALL_ROWS else _TM_LARGE
+def tile_rows(pairs: int, router_width: int) -> int:
+    """Rows a tile holds, for `pairs` (token, pick) pairs picked over a
+    router `router_width` experts wide: of `_TILES` the one nearest (as a
+    ratio) to the mean rows an expert of the router gets.  Fitted on the
+    chip at the four expert configurations' decode steps and fused rungs
+    (tests_tpu/expert_sweep.py; PERF.md, PR 52): means of 1-19 rows ran
+    fastest at 16, 29-42 at 32 or 64, 58 at 64, 88-130 at 128, the rule's
+    own tile within 2.2 % of the best everywhere."""
+    mean = max(pairs / router_width, 1.0)
+    return min(_TILES, key=lambda tm: max(tm, mean) / min(tm, mean))
 
 
 def _f_blocks(D: int, F: int, itemsize: int) -> int:
@@ -82,38 +99,72 @@ def _plan(idx: jax.Array, valid: jax.Array, n_experts: int, tm: int):
     """Where every (token, pick) pair goes.  idx [T, K] expert ids, valid
     [T] or [T, K].  Returns (row_token [R], dest [T, K], tile_expert [n_tiles],
     n_used [1], group_sizes [E]); R = n_tiles * tm rows, the last tile is
-    never used: it takes what is routed nowhere."""
+    never used: it takes what is routed nowhere.
+
+    An expert's rows are its pairs in (token, pick) order.  The per-pair
+    work is counting, the per-row work one sort:
+      pair -> row: a pair's rank inside its expert is the picks of that
+        expert by the tokens before it (a running count down [T, E]) plus
+        those among its own token's earlier picks; no inverse permutation.
+      row -> token: the keys `expert * T + token` sorted ONCE lay every
+        expert's tokens side by side in that order; a tile reads `tm` of
+        them from its first sorted position, as far as it holds rows.
+        What a tile needs (its expert, its first position, where its
+        expert's rows end) is tables of E and n_tiles entries, spread over
+        its rows by broadcast.
+    No scatter; beside the rows' own two gathers (x into tiles, the tiles'
+    output back to pairs) ONE gather is as long as the padded rows (the
+    sorted keys), the others have n_tiles indices.  (A gather of n_tiles
+    windows of `tm` keys is a loop of n_tiles copies on the chip.)"""
     T, K = idx.shape
     E = n_experts
     pairs = T * K
     n_tiles = -(-(pairs + E * (tm - 1)) // tm) + 1
-    e = jnp.where(_per_pair(valid), idx, E).reshape(pairs).astype(jnp.int32)
-    order = jnp.argsort(e, stable=True).astype(jnp.int32)  # sorted -> pair
-    sizes = jnp.zeros((E + 1,), jnp.int32).at[e].add(1)[:E]
+    if (E + 1) * T >= 2 ** 31:
+        raise ValueError(f"grouped_ffn: {E} experts x {T} tokens do not "
+                         f"fit the int32 sort key")
+    i32 = jnp.int32
+    experts = jnp.arange(E, dtype=i32)
+    e = jnp.where(_per_pair(valid), idx, E).astype(i32)   # E: nowhere
+    hot = e[:, :, None] == experts                        # [T, K, E]
+    picks = jnp.sum(hot, axis=1, dtype=i32)               # [T, E]
+    before = jnp.cumsum(picks, axis=0) - picks    # by the tokens before t
+    sizes = before[-1] + picks[-1]
     tiles_per = (sizes + tm - 1) // tm
-    tile_end = jnp.cumsum(tiles_per)
-    n_used = tile_end[-1]
-    start = jnp.cumsum(sizes) - sizes            # first sorted position
-    pstart = (tile_end - tiles_per) * tm         # first padded row
+    upto = experts[:, None] >= experts            # running sums over E
+    tile_end = jnp.sum(jnp.where(upto, tiles_per, 0), axis=1)
+    stop = jnp.sum(jnp.where(upto, sizes, 0), axis=1)  # past the last sorted
+    n_used = jnp.sum(tiles_per)
+    pstart = (tile_end - tiles_per) * tm          # first padded row
+    # pair -> its padded row (the unused last tile where routed nowhere)
+    earlier = jnp.sum((e[:, :, None] == e[:, None, :])
+                      & (jnp.arange(K)[:, None] > jnp.arange(K)), axis=2,
+                      dtype=i32)
+    dest = jnp.sum(jnp.where(hot, (before + pstart)[:, None, :], 0), axis=2)
+    dest = jnp.where(e < E, dest + earlier, n_tiles * tm - 1)
     # tile -> expert; a tile past the last used one keeps the last expert
     # (its weight blocks are then not fetched again).
-    tiles = jnp.arange(n_tiles, dtype=jnp.int32)
-    raw = jnp.searchsorted(tile_end, tiles, side="right").astype(jnp.int32)
-    last = raw[jnp.maximum(n_used - 1, 0)]
-    tile_expert = jnp.minimum(jnp.where(tiles < n_used, raw, last), E - 1)
-    # padded row -> the token it holds (token 0 where it holds none)
-    rows = jnp.arange(n_tiles * tm, dtype=jnp.int32)
-    g = jnp.minimum(raw[rows // tm], E - 1)
-    rank = rows - pstart[g]
-    held = (rows // tm < n_used) & (rank < sizes[g])
-    src = jnp.clip(start[g] + rank, 0, pairs - 1)
-    row_token = jnp.where(held, order[src] // K, 0)
-    # pair -> its padded row (the unused last tile where routed nowhere)
-    inv = jnp.zeros((pairs,), jnp.int32).at[order].set(
-        jnp.arange(pairs, dtype=jnp.int32))
-    ge = jnp.minimum(e, E - 1)
-    dest = jnp.where(e < E, pstart[ge] + inv - start[ge],
-                     n_tiles * tm - 1).reshape(T, K)
+    tiles = jnp.arange(n_tiles, dtype=i32)
+    used = tiles < n_used
+    past = tile_end[:-1] <= tiles[:, None]        # [n_tiles, E - 1]: e < g
+
+    def of_tile(table):     # table[g], as a sum of its steps up to g
+        return table[0] + jnp.sum(
+            jnp.where(past, table[1:] - table[:-1], 0), axis=1)
+
+    g = of_tile(experts)
+    last = jnp.max(jnp.where(sizes > 0, experts, 0))
+    tile_expert = jnp.where(used, g, last)
+    # padded row -> the token it holds (token 0 where it holds none): row r
+    # of expert g is sorted position r - pstart[g] + start[g].
+    keys = jax.lax.sort((e * T + jnp.arange(T, dtype=i32)[:, None]
+                         ).reshape(pairs), is_stable=False)
+    at = (tiles * tm + of_tile(stop - sizes - pstart))[:, None] \
+        + jnp.arange(tm, dtype=i32)
+    held = used[:, None] & (at < of_tile(stop)[:, None])
+    src = jnp.where(held, at, 0).reshape(n_tiles * tm)
+    row_token = jnp.where(held, keys[src].reshape(n_tiles, tm)
+                          - g[:, None] * T, 0).reshape(n_tiles * tm)
     return row_token, dest, tile_expert, n_used[None], sizes
 
 
@@ -247,20 +298,23 @@ def _ffn_tiles_reference(xs, tile_expert, n_used, w_gate, w_up, w_down, *,
     return y.reshape(R, D).astype(xs.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("name", "impl"))
+@functools.partial(jax.jit,
+                   static_argnames=("name", "impl", "router_width"))
 def grouped_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
                 valid: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                 w_down: jax.Array, name: str = "moe_experts_prefill",
-                impl: str = "auto") -> Tuple[jax.Array, jax.Array]:
+                impl: str = "auto", router_width: Optional[int] = None
+                ) -> Tuple[jax.Array, jax.Array]:
     """sum_k weights[t, k] * FFN_{idx[t, k]}(x[t]) for every valid token.
 
     x [T, D]; idx, weights [T, K]; valid [T] (or [T, K], a flag a pair)
     bool; w_gate, w_up [E, D, F]; w_down [E, F, D] -> (y [T, D] in x's
-    dtype, rows per expert [E] int32).
+    dtype, rows per expert [E] int32).  `router_width`: how many experts
+    the picks were made over, where these E are a share of them.
     """
     T, K = idx.shape
     E = w_gate.shape[0]
-    tm = tile_rows(T * K)
+    tm = tile_rows(T * K, router_width or E)
     w_gate, w_up, w_down = (w.astype(x.dtype)
                             for w in (w_gate, w_up, w_down))
     with jax.named_scope("moe_route"):
@@ -278,10 +332,24 @@ def grouped_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
     else:
         raise ValueError(f"unknown grouped_ffn impl {impl!r}")
     with jax.named_scope("moe_route"):
-        w = jnp.where(_per_pair(valid), weights, 0.0).astype(jnp.float32)
+        # [K, T]: the picks are added up over the MAJOR axis, slabs of
+        # [T, D]; K rows side by side in the sublanes of a tile are laid out
+        # again first where K is not a multiple of 8 (1.2 ms of Qwen3-Next's
+        # widest pass: PERF.md, PR 52).
+        w = jnp.where(_per_pair(valid), weights, 0.0).astype(jnp.float32).T
+        dest = dest.T
+        if T % _TILES[0]:   # whole packed tiles of tokens: a slab of another
+            # length did not come back from the chip (PR 52); what is added
+            # is pairs routed nowhere
+            more = _TILES[0] - T % _TILES[0]
+            w = jnp.pad(w, ((0, 0), (0, more)))
+            dest = jnp.pad(dest, ((0, 0), (0, more)),
+                           constant_values=xs.shape[0] - 1)
         # A row routed nowhere reads the unused tile, which holds whatever
-        # was there: its weight is 0 and the product must not be NaN.
-        picked = jnp.where((w != 0.0)[..., None],
-                           ys[dest].astype(jnp.float32), 0.0)
-        y = jnp.sum(picked * w[..., None], axis=1)
+        # was there: its weight is 0 and the product must not be NaN.  (The
+        # rows are chosen in their own dtype, so that the conversion is part
+        # of the sum and not a float32 copy of them.)
+        picked = jnp.where((w != 0.0)[..., None], ys[dest],
+                           jnp.zeros((), ys.dtype))
+        y = jnp.sum(picked.astype(jnp.float32) * w[..., None], axis=0)[:T]
     return y.astype(x.dtype), sizes

@@ -44,7 +44,6 @@ def test_grouped_ffn_matches_a_per_expert_loop(T, K, E, impl):
     idx = jnp.argsort(jax.random.uniform(ks[4], (T, E)), axis=1)[:, :K]
     w = jax.random.uniform(ks[5], (T, K), minval=0.1)
     valid = jnp.arange(T) % 5 != 3
-    assert gf.tile_rows(T * K) == (16 if T == 24 else 256)
     with jax.default_matmul_precision("highest"):
         y, sizes = gf.grouped_ffn(x, idx.astype(jnp.int32), w, valid,
                                   wg, wu, wd, name="moe_experts_decode",
@@ -73,6 +72,139 @@ def test_grouped_ffn_with_every_row_on_one_expert():
         want = _expert_loop(x, idx, w, valid, wg, wu, wd)
     np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-3)
     assert sizes.tolist() == [0, 0, 40, 0]
+
+
+# pairs = tokens x picks, the router's width -> the tile: the four expert
+# cells' decode steps and fused rungs (slots + 256 / 640 / 896 / 2,048
+# positions), as tests_tpu/expert_sweep.py timed them on the chip
+@pytest.mark.parametrize("tokens,picks,width,tile", [
+    (32, 8, 128, 16), (288, 8, 128, 16), (672, 8, 128, 32),     # Trinity-Mini
+    (928, 8, 128, 64), (2080, 8, 128, 128),
+    (32, 4, 64, 16), (672, 4, 64, 32), (2080, 4, 64, 128),      # LFM2
+    (64, 8, 192, 16), (704, 8, 192, 32), (2112, 8, 192, 64),    # A.X-K1
+    (64, 10, 512, 16), (704, 10, 512, 16), (960, 10, 512, 16),  # Qwen3-Next
+    (2112, 10, 512, 32),
+    (24, 2, 8, 16), (2100, 2, 4, 256), (0, 2, 4, 16)])
+def test_the_tile_follows_the_rows_an_expert_of_the_router_gets(
+        tokens, picks, width, tile):
+    """`tile_rows`: the power of two from 16 to 256 nearest the mean rows
+    an expert of the router gets, whatever share of them is held here."""
+    assert gf.tile_rows(tokens * picks, width) == tile
+    mean = tokens * picks / width
+    assert tile in (16, 256) or tile / 2 ** 0.5 <= mean <= tile * 2 ** 0.5
+
+
+def _plan_by_sort_and_scatters(idx, valid, E, tm):
+    """The plan as it was before PR 52, in numpy: a stable sort of the
+    pairs by expert, rows laid out group by group.  -> (row_token with -1
+    where a row holds none, dest, tile_expert of the used tiles, sizes)."""
+    T, K = idx.shape
+    pairs = T * K
+    n_tiles = -(-(pairs + E * (tm - 1)) // tm) + 1
+    ok = valid if valid.ndim == 2 else valid[:, None]
+    e = np.where(ok, idx, E).reshape(pairs)
+    order = np.argsort(e, kind="stable")
+    sizes = np.bincount(e, minlength=E + 1)[:E]
+    tiles_per = -(-sizes // tm)
+    pstart = (np.cumsum(tiles_per) - tiles_per) * tm
+    start = np.cumsum(sizes) - sizes
+    row_token = np.full(n_tiles * tm, -1)
+    dest = np.full(pairs, n_tiles * tm - 1)
+    for g in range(E):
+        mine = order[start[g]:start[g] + sizes[g]]
+        row_token[pstart[g]:pstart[g] + sizes[g]] = mine // K
+        dest[mine] = pstart[g] + np.arange(sizes[g])
+    return (row_token, dest.reshape(T, K), np.repeat(np.arange(E), tiles_per),
+            sizes)
+
+
+# tokens, picks, held experts, router width, tile, how the picks fall
+@pytest.mark.parametrize("T,K,E,width,tm,picks", [
+    (72, 8, 16, 16, 16, "valid-by-token"),      # Trinity-Mini: all held
+    (72, 4, 8, 8, 32, "valid-by-token"),        # LFM2: all held, 4 picks
+    (80, 10, 16, 64, 16, "valid-by-pair"),      # Qwen3-Next: a quarter
+    (80, 10, 16, 64, 64, "valid-by-pair"),
+    (80, 8, 4, 64, 256, "valid-by-pair"),       # A.X-K1: a sixteenth
+    (40, 3, 8, 8, 16, "twice-the-same"),        # a token picks e twice
+    (40, 1, 4, 4, 16, "all-on-one"),
+    (40, 2, 4, 4, 16, "none-valid"),
+    (1, 2, 4, 4, 16, "valid-by-token")])
+def test_the_plan_gives_every_routed_pair_a_row_of_its_expert(
+        T, K, E, width, tm, picks):
+    """`_plan` at the four cells' shapes (toy widths): every valid pair on a
+    held expert has a row of its own in a used tile of that expert, holding
+    its token; a pair routed nowhere lands on the last tile, which is never
+    used; `sizes` and `n_used` are the counts; rows, tiles and counts are
+    those of a stable sort by expert (the plan before PR 52: same tile,
+    same rows, bit for bit); and the product through it is the per-expert
+    loop's."""
+    rng = np.random.default_rng(T * K + tm)
+    chosen = np.argsort(rng.random((T, width)), axis=1)[:, :K]
+    if picks == "twice-the-same":
+        chosen = rng.integers(0, E, (T, K))
+    if picks == "all-on-one":
+        chosen[:] = 2
+    live = rng.random(T) < 0.56 if T > 1 else np.ones(1, bool)
+    if picks == "all-on-one":
+        live[:] = True
+    if picks == "none-valid":
+        live[:] = False
+    idx = np.clip(chosen, 0, E - 1).astype(np.int32)
+    valid = (chosen < E) & live[:, None] if picks == "valid-by-pair" \
+        else live
+    row_token, dest, tile_expert, n_used, sizes = (
+        np.asarray(a) for a in gf._plan(jnp.asarray(idx), jnp.asarray(valid),
+                                        E, tm))
+    ok = np.broadcast_to(valid if valid.ndim == 2 else valid[:, None],
+                         (T, K))
+    n_used = int(n_used[0])
+    np.testing.assert_array_equal(
+        sizes, np.bincount(idx[ok], minlength=E))
+    assert n_used == int((-(-sizes // tm)).sum())
+    assert len(row_token) == len(tile_expert) * tm
+    assert n_used < len(tile_expert)
+    routed = dest[ok]
+    assert len(set(routed.tolist())) == len(routed)     # a row of its own
+    assert (routed // tm < n_used).all()
+    np.testing.assert_array_equal(tile_expert[routed // tm], idx[ok])
+    np.testing.assert_array_equal(row_token[routed],
+                                  np.nonzero(ok)[0])    # its token's
+    assert (dest[~ok] == len(row_token) - 1).all()
+    assert (tile_expert[n_used:] == tile_expert[max(n_used - 1, 0)]).all()
+    # ... and the very rows a stable sort by expert gives
+    was_token, was_dest, was_expert, was_sizes = _plan_by_sort_and_scatters(
+        idx, valid, E, tm)
+    np.testing.assert_array_equal(dest, was_dest)
+    np.testing.assert_array_equal(tile_expert[:n_used], was_expert)
+    np.testing.assert_array_equal(sizes, was_sizes)
+    np.testing.assert_array_equal(row_token,
+                                  np.where(was_token < 0, 0, was_token))
+    # the product through the plan
+    D, F = 32, 48
+    ks = jax.random.split(jax.random.PRNGKey(T), 5)
+    x = jax.random.normal(ks[0], (T, D), jnp.float32)
+    wg, wu = (jax.random.normal(k, (E, D, F)) / math.sqrt(D) for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (E, F, D)) / math.sqrt(F)
+    w = jax.random.uniform(ks[4], (T, K), minval=0.1)
+    with jax.default_matmul_precision("highest"):
+        y, counted = gf.grouped_ffn(x, jnp.asarray(idx), w,
+                                    jnp.asarray(valid), wg, wu, wd,
+                                    impl="reference", router_width=width)
+        want = sum(_expert_loop(x, jnp.asarray(idx[:, k:k + 1]), w[:, k:k + 1],
+                                jnp.asarray(ok[:, k]), wg, wu, wd)
+                   for k in range(K))
+        # ... and the sum over the picks as it was taken before PR 52 (a
+        # token's pairs side by side, [T, K, D]) from the same rows: the
+        # same float32 terms, added up slab by slab now
+        ys = gf._ffn_tiles_reference(
+            x[row_token], jnp.asarray(tile_expert), None, wg, wu, wd, tm=tm)
+        wk = jnp.where(jnp.asarray(ok), w, 0.0)
+        was = jnp.sum(jnp.where((wk != 0.0)[..., None], ys[dest], 0.0)
+                      * wk[..., None], axis=1)
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(counted, sizes)
+    if gf.tile_rows(T * K, width) == tm:
+        np.testing.assert_allclose(y, was, rtol=0, atol=5e-7)
 
 
 def _plain_attention(q, k, v, qpos, total, window):

@@ -565,7 +565,7 @@ def _tiles(T=40, K=2, E=4, D=128, F=512, seed=0, dtype=jnp.float32):
     valid = jax.random.uniform(ks[2], (T,)) > 0.2
     w = [(jax.random.normal(k, s) / np.sqrt(s[1])).astype(dtype)
          for k, s in zip(ks[3:], [(E, D, F), (E, D, F), (E, F, D)])]
-    tm = gf.tile_rows(T * K)
+    tm = gf.tile_rows(T * K, E)
     row_token, dest, tile_expert, n_used, sizes = gf._plan(idx, valid, E, tm)
     return x[row_token], tile_expert, n_used, w, tm
 

@@ -487,6 +487,53 @@ def test_latent_and_blocked_kernels_compile_for_v5e(v5e_devices):
         ).compile()) == 1
 
 
+@pytest.mark.parametrize("tokens", [64, 2112])
+def test_expert_plan_is_one_sort_and_no_scatter_on_v5e(v5e_devices, tokens):
+    """What `grouped_ffn` runs around its kernel, read off the compiled text
+    at Qwen3-Next's decode step (64 slots) and widest pass (2,048 positions
+    + 64 slots' rows), 10 picks over a router of 512 of which 128 are held:
+    under the scope `moe_route` ONE sort, no scatter (a scattered element
+    costs the chip tens of ns: PERF.md, PR 45 and 52), no loop (a
+    `searchsorted`, a gather of windows), and beside the two gathers of rows
+    (x into the tiles, the tiles' output back to the pairs) at most one
+    gather with more indices than there are tiles."""
+    from ray_tpu.ops import grouped_ffn as gf
+
+    on_chip, _ = _on_chip_shapes(v5e_devices)
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    E, width, K, D, F = 128, 512, 10, 2048, 512
+    tm = gf.tile_rows(tokens * K, width)
+    n_tiles = -(-(tokens * K + E * (tm - 1)) // tm) + 1
+    compiled = jax.jit(
+        lambda x, i, w, v, a, b, c: gf.grouped_ffn(
+            x, i, w, v, a, b, c, name="moe_experts_prefill", impl="kernel",
+            router_width=width)).lower(
+        S((tokens, D)), S((tokens, K), jnp.int32),
+        S((tokens, K), jnp.float32), S((tokens, K), jnp.bool_),
+        S((E, D, F)), S((E, D, F)), S((E, F, D))).compile()
+    assert _custom_calls(compiled) == 1
+    found = {"sort": 0, "scatter": 0, "while": 0, "rows": 0, "long": 0}
+    for line in compiled.as_text().splitlines():
+        op = re.search(r"[\]})] (sort|scatter|while|gather)\(", line)
+        if not op or "moe_route" not in line:
+            continue
+        if op.group(1) != "gather":
+            found[op.group(1)] += 1
+            continue
+        out = math.prod(int(d) for d in re.search(
+            r"= [a-z0-9]+\[([0-9,]+)\]", line).group(1).split(","))
+        taken = math.prod(int(d) for d in re.search(
+            r"slice_sizes=\{([0-9,]+)\}", line).group(1).split(","))
+        if out // taken > n_tiles:
+            found["rows" if taken == D else "long"] += 1
+    assert found["sort"] == 1 and not found["scatter"] \
+        and not found["while"], found
+    assert found["rows"] == 2 and found["long"] <= 1, found
+
+
 @pytest.mark.parametrize("pool_kind", ["keys-and-values", "latent"])
 def test_shared_prefix_kernels_compile_for_v5e(v5e_devices, pool_kind):
     """The decode kernels with `shared` (a step's two calls behind their

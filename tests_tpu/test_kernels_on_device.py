@@ -340,6 +340,21 @@ def test_grouped_ffn_at_afmoe_widths_on_tpu(rows):
     assert float(jnp.max(jnp.abs(y[~valid].astype(jnp.float32)))) == 0.0
 
 
+@pytest.mark.parametrize("tokens,live,name", [
+    (32, 0.8, "moe_experts_decode")] + [
+    (rung + 32, 0.56, "moe_experts_prefill")
+    for rung in (256, 640, 896, 2048)])
+@pytest.mark.parametrize("config", ["trinity-mini", "lfm2"])
+def test_tile_sweep_at_afmoe_and_lfm2_shapes_on_tpu(config, tokens, live,
+                                                    name):
+    """Every tile (16 ... 256) at a decode step's and the four rungs' shapes
+    of the two configurations that hold every expert of their router (128
+    of 2048 x 1024 with 8 picks; 64 of 2048 x 1536 with 4): what
+    `grouped_ffn.tile_rows` is fitted from (`expert_sweep.py`)."""
+    import expert_sweep as es
+    es.check_rule(*es.sweep(config, tokens, live, name))
+
+
 # -- heads of 64, two kv heads side by side in a row of 128 lanes -------------
 # At LFM2's widths: 32 query / 8 KV heads of 64, blocks of 16, tables of
 # 1,072 columns; the pool as models/decoding.py unrolled_pool_shape lays it

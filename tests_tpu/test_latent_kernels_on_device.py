@@ -334,3 +334,16 @@ def test_blocked_expert_product_call_time_on_tpu(tokens):
         tokens=tokens, rows=int(held.sum()), touched=touched,
         us=took * 1e6, bytes_share=bytes_s / took))
     assert 0 < bytes_s / took < 1.05
+
+
+@pytest.mark.parametrize("tokens,live,name", [
+    (64, 0.8, "moe_experts_decode")] + [
+    (rung + 64, 0.56, "moe_experts_prefill")
+    for rung in (256, 640, 896, 2048)])
+def test_tile_sweep_at_axk1_shapes_on_tpu(tokens, live, name):
+    """Every tile (16 ... 256) through the blocked product (12 of 192
+    experts held, rows of 7,168, 4 blocks of F: a tile reads its expert's
+    88 MB whatever it holds): what `grouped_ffn.tile_rows` is fitted from
+    (`expert_sweep.py`)."""
+    import expert_sweep as es
+    es.check_rule(*es.sweep("axk1", tokens, live, name))

@@ -3,9 +3,12 @@ arch "qwen3_next" brings, none of which had run before: `gated_delta_step`
 and `gated_delta_chunk` at 32 heads of [128, 128] (a head a row of lanes: no
 pair masks), the two paged kernels at 16 query / 2 kv heads of 256, and the
 grouped expert product over 128 held experts of 2048 x 512 of a router 512
-wide, for a decode step's 64 tokens and a fused pass's 650; each against its
-plain-JAX form.  What a call takes beside what its bytes take at the HBM peak
-is printed (`-s`) and kept in chiprun_out/pr51/kernels.jsonl.
+wide, for a decode step's 64 tokens, a fused pass's 650 and the pass the cell
+mostly runs (2,048 positions + 64 slots' rows, 56 % of them live); each
+against its plain-JAX form.  What a call takes beside what its bytes take at
+the HBM peak is printed (`-s`) and kept in chiprun_out/pr51/kernels.jsonl
+(the expert product's, and its tile sweep: chiprun_out/pr52/kernels.jsonl,
+`expert_sweep.py`).
 
     python -m pytest tests_tpu/test_qw3n_kernels_on_device.py -q -s
 """
@@ -19,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import expert_sweep as es
 from ray_tpu.ops import gated_delta as gd
 from ray_tpu.ops import grouped_ffn as gf
 from ray_tpu.ops import paged_attention as pa
@@ -279,30 +283,47 @@ def test_prefix_attention_at_heads_of_256_on_tpu():
 E, WIDTH, TOP_K, HID, F = 128, 512, 10, 2048, 512
 
 
-@pytest.mark.parametrize("tokens,name", [(64, "moe_experts_decode"),
-                                         (650, "moe_experts_prefill")])
-def test_grouped_product_over_many_small_experts_on_tpu(tokens, name):
+@pytest.mark.parametrize("tokens,live,name", [
+    (64, 1.0, "moe_experts_decode"), (650, 1.0, "moe_experts_prefill"),
+    (2112, 0.56, "moe_experts_prefill")])
+def test_grouped_product_over_many_small_experts_on_tpu(tokens, live, name):
     """Even routing over the router's 512: a quarter of the picks fall on
-    the 128 held experts, the others are routed nowhere."""
-    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    the 128 held experts, the others are routed nowhere; of the widest
+    pass's 2,112 tokens 56 % are live (the others padding, routed
+    nowhere)."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 7)
     x = jax.random.normal(ks[0], (tokens, HID), jnp.bfloat16)
     picks = jnp.argsort(jax.random.uniform(ks[1], (tokens, WIDTH)),
                         axis=1)[:, :TOP_K].astype(jnp.int32)
-    held = picks < E
+    held = (picks < E) & (jax.random.uniform(ks[6], (tokens, 1)) < live)
     idx = jnp.clip(picks, 0, E - 1)
     w = jax.nn.softmax(jax.random.normal(ks[2], (tokens, TOP_K)), axis=-1)
     wg, wu = (jax.random.normal(k, (E, HID, F), jnp.bfloat16) * HID ** -0.5
               for k in ks[3:5])
     wd = jax.random.normal(ks[5], (E, F, HID), jnp.bfloat16) * F ** -0.5
-    y, sizes = gf.grouped_ffn(x, idx, w, held, wg, wu, wd, name=name,
-                              impl="kernel")
-    want, _ = gf.grouped_ffn(x, idx, w, held, wg, wu, wd, name=name,
-                             impl="reference")
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=3e-2, rtol=3e-2)
+    kw = dict(name=name, router_width=WIDTH)
+    y, sizes = gf.grouped_ffn(x, idx, w, held, wg, wu, wd, impl="kernel",
+                              **kw)
+
+    @jax.jit
+    def loop(x, idx, w, held, wg, wu, wd):      # one held expert at a time
+        x32 = x.astype(jnp.float32)
+
+        def one(acc, e):
+            out = (jax.nn.silu(x32 @ wg[e].astype(jnp.float32))
+                   * (x32 @ wu[e].astype(jnp.float32))) \
+                @ wd[e].astype(jnp.float32)
+            weight = jnp.sum(jnp.where((idx == e) & held, w, 0.0), axis=1)
+            return acc + weight[:, None] * out, None
+        return jax.lax.scan(one, jnp.zeros_like(x32), jnp.arange(E))[0]
+
+    with jax.default_matmul_precision("highest"):
+        want = loop(x, idx, w, held, wg, wu, wd)
+    err = np.abs(np.asarray(y, np.float32) - np.asarray(want))
+    assert float(err.max()) < 0.1 and float(err.mean()) < 0.01, \
+        (float(err.max()), float(err.mean()), float(np.abs(want).mean()))
     sizes = np.asarray(sizes)
-    tm = gf.tile_rows(tokens * TOP_K)
+    tm = gf.tile_rows(tokens * TOP_K, WIDTH)
     rows, touched = int(sizes.sum()), int((sizes > 0).sum())
     padded = int((-(-sizes // tm) * tm).sum())
     assert rows == int(held.sum())
@@ -310,8 +331,8 @@ def test_grouped_product_over_many_small_experts_on_tpu(tokens, name):
     @jax.jit
     def chain(x, wg, wu, wd):       # weights as arguments, not constants
         def call(_, x):
-            y, _ = gf.grouped_ffn(x, idx, w, held, wg, wu, wd, name=name,
-                                  impl="kernel")
+            y, _ = gf.grouped_ffn(x, idx, w, held, wg, wu, wd,
+                                  impl="kernel", **kw)
             return x + (y * 0).astype(x.dtype)
         return jax.lax.fori_loop(0, 20, call, x)
 
@@ -322,6 +343,16 @@ def test_grouped_product_over_many_small_experts_on_tpu(tokens, name):
           f"{100 * rows / padded:.1f} %); {took * 1e6:.1f} us a call (the "
           f"plan and the gathers included), the touched experts' bytes "
           f"{least * 1e6:.1f} us ({100 * least / took:.1f} %)")
-    _keep(name, dict(tokens=tokens, rows=rows, touched=touched, tile=tm,
-                     padded=padded, us=took * 1e6, bytes_share=least / took))
+    es.keep(name, dict(tokens=tokens, rows=rows, touched=touched, tile=tm,
+                       padded=padded, us=took * 1e6,
+                       bytes_share=least / took))
     assert 0 < least / took < 1.05
+
+
+@pytest.mark.parametrize("tokens,live,name", [
+    (64, 0.8, "moe_experts_decode")] + [
+    (rung + 64, 0.56, "moe_experts_prefill") for rung in es.RUNGS])
+def test_tile_sweep_at_qw3n_shapes_on_tpu(tokens, live, name):
+    """Every tile at a decode step's and the four rungs' shapes; the rule's
+    own may not lose to the best by more than the runs' spread."""
+    es.check_rule(*es.sweep("qw3n", tokens, live, name))
