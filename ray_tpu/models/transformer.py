@@ -465,6 +465,36 @@ def _attention(cfg: TransformerConfig, mesh, q, k, v):
                          out_specs=q_spec, check_vma=False)(q, k, v)
 
 
+# The stacked layer weights that `_layer_body` and `_moe_block` cast WHOLE
+# to the compute dtype before a product (dense and expert alike).  Norms,
+# biases and the router are read in float32; the embedding and the head
+# are not layer weights, and their gradients accumulate (a scatter-add of
+# many tokens into a row, a sum over the loss's trips).
+PRODUCT_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def with_product_weights_cast(params: Dict[str, Any],
+                              cfg: TransformerConfig) -> Dict[str, Any]:
+    """`params` with the layers' `PRODUCT_WEIGHTS` cast to `cfg.dtype` ONCE,
+    for a caller that differentiates (train/train_step.py): the `.astype`
+    calls of `_layer_body` / `_moe_block` are then no-ops, and the gradient
+    of such a leaf is the one the product's transpose computed, in
+    `cfg.dtype`, written once into its layer's slot of the backward scan's
+    stack.  With respect to a float32 parameter that same value comes back
+    widened by the cast's transpose, INSIDE the scan: a float32 stack of
+    compute-precision values, twice the bytes and no more information (no
+    layer's slot is accumulated into), which the caller can widen where it
+    reads them instead.  A leaf that already is `cfg.dtype` is passed
+    through, so a run with parameters in the compute dtype traces what it
+    always did; the unrolled architectures have no training path and are
+    returned as they are."""
+    if cfg.layer_kinds is not None:
+        return params
+    layers = {k: v.astype(cfg.dtype) if k in PRODUCT_WEIGHTS else v
+              for k, v in params["layers"].items()}
+    return {**params, "layers": layers}
+
+
 def _layer_body(cfg: TransformerConfig, mesh, x, p, positions):
     """One decoder layer. x: [B, S, D]."""
     rms = cfg.arch == "llama"

@@ -62,7 +62,18 @@ def make_optimizer(learning_rate: float = 3e-4, warmup_steps: int = 100,
 
 
 class CompiledTrainStep:
-    """Holds the jitted step + sharded state constructors for one model."""
+    """Holds the jitted step + sharded state constructors for one model.
+
+    The step differentiates the loss with respect to the parameters as the
+    model computes with them: the layers' product weights
+    (`transformer.PRODUCT_WEIGHTS`) cast to `cfg.dtype` once, everything
+    else (norms, embedding, head, biases, router) as it is kept.  A product
+    weight's gradient leaves the MXU in `cfg.dtype` and nothing is added to
+    it before the optimizer, so it stays in that precision through the
+    backward scan and is widened to the parameter's dtype at the
+    optimizer's input and at `grad_norm`: the values the cast's transpose
+    gave, bit for bit, in half the bytes while the scan holds them (1.75 GiB
+    a chip in the training cells, PERF.md PR 53)."""
 
     def __init__(self, cfg: transformer.TransformerConfig, mesh,
                  optimizer: Optional[optax.GradientTransformation] = None,
@@ -98,10 +109,7 @@ class CompiledTrainStep:
 
         def step_fn(state: TrainState, tokens) -> Tuple[TrainState, Dict]:
             with use_mesh(mesh):
-                grad_fn = jax.value_and_grad(
-                    lambda p: transformer.loss_fn(p, tokens, cfg, mesh),
-                    has_aux=True)
-                (loss, metrics), grads = grad_fn(state.params)
+                metrics, grads = self.metrics_and_grads(state.params, tokens)
                 updates, new_opt = self.optimizer.update(
                     grads, state.opt_state, state.params)
                 new_params = optax.apply_updates(state.params, updates)
@@ -115,6 +123,19 @@ class CompiledTrainStep:
             in_shardings=(self.state_shardings, self.data_sharding),
             out_shardings=(self.state_shardings, None),
             donate_argnums=(0,) if donate_state else ())
+
+    def metrics_and_grads(self, params, tokens) -> Tuple[Dict, Any]:
+        """The loss's metrics and its gradient in `params`' own dtypes, as
+        the step takes them (to be traced under `use_mesh(self.mesh)`)."""
+        cfg = self.cfg
+        grad_fn = jax.value_and_grad(
+            lambda p: transformer.loss_fn(p, tokens, cfg, self.mesh),
+            has_aux=True)
+        (_, metrics), grads = grad_fn(
+            transformer.with_product_weights_cast(params, cfg))
+        # widened where the optimizer and `grad_norm` read them
+        return metrics, jax.tree.map(lambda g, p: g.astype(p.dtype),
+                                     grads, params)
 
     def _state_shardings(self, state_shape, params_axes):
         from jax.sharding import NamedSharding, PartitionSpec

@@ -126,11 +126,55 @@ def test_llama_1b_train_step_compiles_for_v5e(v5e_devices, chips, batch):
     _assert_loss_keeps_its_tokens(compiled, cfg)
 
 
+def _products(text: str):
+    """Every matrix product of a compiled step (loops' bodies once) as
+    (the instruction that runs it: the fusion around the convolution, the
+    einsum it came from)."""
+    from test_xent_sharding import computations
+    bodies = computations(text)
+    called_by = {}          # a fusion's body -> the fusion instruction
+    for lines in bodies.values():
+        for line in lines:
+            name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+            for callee in re.findall(r"calls=%?([\w.\-]+)", line):
+                called_by[callee] = name.group(1)
+    found = []
+    for computation, lines in bodies.items():
+        for line in lines:
+            if re.search(r"[\]})] convolution\(", line):
+                einsum = re.search(r'op_name="[^"]*?([\w,>\-]+)/dot_general',
+                                   line)
+                found.append((called_by.get(computation, computation),
+                              einsum.group(1) if einsum else "?"))
+    return found
+
+
+def _loop_carried(text: str):
+    """(dtype, dims) of every array a `while` of the program carries."""
+    for line in text.splitlines():
+        carried = re.search(r" = \((.*?)\) while\(", line)
+        if carried:
+            for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]",
+                                          carried.group(1)):
+                yield dtype, tuple(int(d) for d in dims.split(","))
+
+
 def test_fsdp4_cell_step_keeps_its_tokens(v5e_devices):
     """`train-4k-fsdp4`'s own step (benchmarks/lib/train_cell.py: the
     `train` block of mistral-7b-l16, batch 16 x 4,097 under fsdp=4).  Its
-    plan over-states (the chip runs it: PERF.md), so only the loss's
-    invariant is held here."""
+    plan over-states (the chip runs it: PERF.md), so the loss's invariant is
+    held here, and the census of PR 53 (the layers' weight gradients stay in
+    the compute dtype through the backward scan, train/train_step.py):
+
+    * 31 products, the one-chip step's count (a layer's 11 of FFN size and
+      12 of attention size, the loss's 4, ...), and none in an instruction
+      XLA's rematerialisation pass cloned (`.remat` in its name): with
+      float32 stacks of the gradients the plan was 1.75 GiB a chip short
+      and the pass ran `dy . W_down^T` and the `wo` recompute twice a layer;
+    * no loop carries a float32 array of a stacked product weight's shape
+      (on one chip of four: any one dimension split);
+    * the plan's temporaries at least 1.5 GiB under the 17.52 GiB they
+      were."""
     import json
     import os
 
@@ -152,6 +196,24 @@ def test_fsdp4_cell_step_keeps_its_tokens(v5e_devices):
         make_optimizer(total_steps=10_000, kind=tr["optimizer"]))
     _per_chip_bytes(compiled, "mistral-7b-l16 fsdp=4")
     _assert_loss_keeps_its_tokens(compiled, cfg)
+
+    text = compiled.as_text()
+    products = _products(text)
+    cloned = [p for p in products if ".remat" in p[0]]
+    assert not cloned and len(products) == 31, (len(products), cloned)
+    stacks = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))["layers"]
+    on_a_chip = {tuple(sorted(d // chips if i == split else d
+                              for i, d in enumerate(stacks[name].shape)))
+                 for name in tfm.PRODUCT_WEIGHTS
+                 for split in range(1, stacks[name].ndim)}
+    carried = list(_loop_carried(text))
+    assert ("bf16", (16, 1024, 14336)) in carried       # the parser sees them
+    widened = [(dtype, dims) for dtype, dims in carried
+               if dtype == "f32" and tuple(sorted(dims)) in on_a_chip]
+    assert not widened, widened
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries <= (17.52 - 1.5) * 2 ** 30, temporaries / 2 ** 30
 
 
 def _on_chip_shapes(v5e_devices):

@@ -38,10 +38,8 @@ SHAPES = {
 }
 
 
-def loop_collectives(hlo: str, ops=("all-reduce", "all-to-all")):
-    """[(op, elements, line)] for every `ops` instruction of a compiled
-    program's text that runs inside a `while` (its body, its condition
-    and whatever they call), with the elements of its largest result."""
+def computations(hlo: str):
+    """A compiled program's text as {computation's name: its lines}."""
     comps, name = {}, None
     for line in hlo.splitlines():
         head = re.match(r"(?:ENTRY )?(%?[\w.\-]+) \(.*\{\s*$", line)
@@ -50,6 +48,14 @@ def loop_collectives(hlo: str, ops=("all-reduce", "all-to-all")):
             comps[name] = []
         elif name is not None:
             comps[name].append(line)
+    return comps
+
+
+def loop_collectives(hlo: str, ops=("all-reduce", "all-to-all")):
+    """[(op, elements, line)] for every `ops` instruction of a compiled
+    program's text that runs inside a `while` (its body, its condition
+    and whatever they call), with the elements of its largest result."""
+    comps = computations(hlo)
     calls = {c: {t for line in lines
                  for t in re.findall(r"%?([\w.\-]+)", line.split(" = ")[-1])
                  if t in comps and t != c}

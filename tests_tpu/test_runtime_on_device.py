@@ -149,3 +149,81 @@ def test_engine_streaming_on_tpu():
         assert out["tokens"] == toks     # stream == non-stream
     finally:
         bat.stop()
+
+
+def test_step_gradients_against_float32_parameter_form_on_tpu():
+    """`train-4k-1chip`'s own step (benchmarks/configs/mistral-7b-l4.json,
+    batch 4 x 4,097): the gradient as `CompiledTrainStep` takes it (product
+    weights differentiated in bf16, widened after) beside the form that
+    differentiates with respect to the float32 parameters, each reduced
+    INSIDE its program to a leaf's sum of squares and a wrapping uint32 sum
+    of its bits.  tests/test_train_step_grads.py holds the two equal bit
+    for bit on the CPU; the chip's two programs fuse the work around the
+    products differently and agree to the size of their own rounding
+    (PERF.md §6, PR 53): same loss, every leaf's norm within 1e-4.  Prints
+    the leaves whose bits differ and both programs' seconds."""
+    import json
+    import os
+    import time
+
+    from benchmarks.lib import spec, worker_util
+    from ray_tpu.models import transformer as tfm
+    from ray_tpu.parallel.mesh import MeshSpec, make_mesh
+    from ray_tpu.parallel.sharding import use_mesh
+    from ray_tpu.train.train_step import CompiledTrainStep, make_optimizer
+
+    with open(os.path.join(spec.BENCH_DIR, "configs",
+                           "mistral-7b-l4.json")) as f:
+        mc = json.load(f)
+    tr, seq = mc["train"], 4096
+    cfg = tfm.TransformerConfig(**worker_util.with_dtypes(
+        spec.model_kind(mc["kind"]).transformer_kwargs(
+            mc, max_seq=seq, param_dtype=tr["param_dtype"], remat=True,
+            remat_policy=tr["remat_policy"], xent_chunk=tr["xent_chunk"],
+            attn_block_k=tr["attn_block_k"], attn_impl="flash")))
+    mesh = make_mesh(MeshSpec(), devices=jax.devices()[:1])
+    step = CompiledTrainStep(cfg, mesh, optimizer=make_optimizer(
+        total_steps=10_000, kind=tr["optimizer"]))
+    params = step.init_state(seed=31).params
+    tokens = step.shard_batch(np.random.default_rng(31).integers(
+        0, cfg.vocab_size, (tr["batch_per_chip"], seq + 1), dtype=np.int32))
+
+    def reduced(loss, grads):
+        return loss, jax.tree.map(lambda g: (
+            jnp.sum(jnp.square(g.astype(jnp.float32))),
+            jnp.sum(jax.lax.bitcast_convert_type(g.astype(jnp.float32),
+                                                 jnp.uint32),
+                    dtype=jnp.uint32)), grads)
+
+    def own(params, tokens):
+        with use_mesh(mesh):
+            metrics, grads = step.metrics_and_grads(params, tokens)
+            return reduced(metrics["loss"], grads)
+
+    def float32_form(params, tokens):
+        with use_mesh(mesh):
+            (loss, _), grads = jax.value_and_grad(
+                lambda p: tfm.loss_fn(p, tokens, cfg, mesh),
+                has_aux=True)(params)
+            return reduced(loss, grads)
+
+    got = {}
+    for name, fn in (("own", own), ("float32", float32_form)):
+        t0 = time.perf_counter()
+        loss, leaves = jax.block_until_ready(jax.jit(fn)(params, tokens))
+        got[name] = (float(loss), {
+            jax.tree_util.keystr(path): (float(sq), int(bits))
+            for path, (sq, bits) in jax.tree_util.tree_leaves_with_path(
+                leaves, is_leaf=lambda x: isinstance(x, tuple))})
+        print(f"{name}: loss {float(loss).hex()}, compile + run "
+              f"{time.perf_counter() - t0:.1f} s")
+    (loss, mine), (want_loss, theirs) = got["own"], got["float32"]
+    assert mine.keys() == theirs.keys() and len(mine) == 12
+    print("leaves whose bits differ:",
+          sorted(k for k in mine if mine[k][1] != theirs[k][1]))
+    assert abs(loss - want_loss) <= 1e-6 * abs(want_loss), (loss, want_loss)
+    off = {k: abs(mine[k][0] - theirs[k][0]) / theirs[k][0] for k in mine}
+    print("sum of squares, relative difference:",
+          {k: float(f"{v:.2g}") for k, v in off.items()})
+    assert all(mine[k][0] > 0 for k in mine)
+    assert max(off.values()) <= 2e-4, off
