@@ -36,7 +36,9 @@ and attention layers mixed; models/axk1.py, latent attention whose cache
 row has no head axis; all with expert layers; models/olmo_hybrid.py, gated
 delta-rule layers whose state is a matrix a head a SEQUENCE, kept by state
 id beside the pages; models/qwen3_next.py, that state beside gated attention
-at heads of 256, an expert layer in every layer) have their paged
+at heads of 256, an expert layer in every layer; models/mimo_v2.py, window
+layers whose keys and values are a ring a sequence, by the same state ids,
+beside full layers with keys wider than values) have their paged
 steps at the end of this file, built from their module's one layer
 definition; the two programs an engine runs (paged_prefill_decode_packed,
 paged_decode_steps) branch to them.  Both return (caches', tokens
@@ -150,6 +152,13 @@ class PagedDecodeCaches(NamedTuple):
     state_pool: Tuple = ()
     conv_pool: Tuple = ()
     slot_state: Optional[jax.Array] = None
+    # Ring (sliding-window) layers' keys and values, where a model has any:
+    # the last `sliding_window` positions of a SEQUENCE, position p in slot
+    # p mod window, by the same state ids (ops/window_ring.py), per layer
+    # (None at the others); such a layer has no pages
+    #   ring_k [NS + 1, Hkv, window, lanes(dk)],  ring_v [.., lanes(dv)]
+    ring_k: Tuple = ()
+    ring_v: Tuple = ()
     # The sets of slots whose tables agree over their first blocks
     # (ops/paged_attention.py SharedPrefixes), as the engine found them when
     # it last changed a table: a decode step reads such a prefix once a set.
@@ -161,10 +170,15 @@ def paged_table_width(max_len: int, block_size: int) -> int:
     return -(-max_len // block_size)
 
 
+# The mixers whose layers keep state by SEQUENCE, under a state id the
+# engine hands out (serve/llm.py StateAllocator), beside or in place of pages.
+STATE_MIXERS = ("linear", "ring")
+
+
 def unrolled_pool_shape(cfg: TransformerConfig, num_blocks: int,
-                        block_size: int, mixer: str = "full"
-                        ) -> Tuple[int, ...]:
-    """One unrolled attention layer's K (or V) pool, scratch block
+                        block_size: int, mixer: str = "full",
+                        values: bool = False) -> Tuple[int, ...]:
+    """One unrolled attention layer's K (`values`: V) pool, scratch block
     included.  Heads narrower than the 128 lanes lie side by side in one
     row of lanes where they fill it whole ([NB, Hkv / f, bs, f * Dh], f =
     128 / Dh): the paged kernels copy pages out of an HBM pool only at
@@ -174,12 +188,20 @@ def unrolled_pool_shape(cfg: TransformerConfig, num_blocks: int,
     rows of lanes ([NB, 1, bs, 640] for 576: a tenth more cache and reads
     than the model needs, for ONE page stream and a value that is the key's
     own lanes; the other layout, the latent and the rotated part in pools of
-    their own, is two streams and two writes a layer)."""
+    their own, is two streams and two writes a layer).  Values `v_head_dim`
+    wide have a pool of that width; a head wider than 128 lanes that does not
+    fill whole rows of them lies alone in the next whole row, zeros past its
+    width (keys of 192 in 256 lanes: a third more key bytes, for the same
+    page stream as any other head; ops/paged_attention.py `key_lanes`)."""
+    from ray_tpu.ops.paged_attention import latent_lanes
     if mixer == "latent":
-        from ray_tpu.ops.paged_attention import latent_lanes
         return (num_blocks + 1, 1, block_size,
                 latent_lanes(cfg.kv_lora_rank + cfg.qk_rope_dim))
     dh, hkv = cfg.head_dim, cfg.kv_heads
+    if values and cfg.v_head_dim:
+        dh = cfg.v_head_dim
+    if dh > 128:
+        dh = latent_lanes(dh)
     f = 128 // dh if dh < 128 and 128 % dh == 0 else 1
     if hkv % f:
         f = 1
@@ -211,11 +233,12 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
     else:
         conv = [m == "conv" for m, _ in cfg.layer_kinds]
         linear = [m == "linear" for m, _ in cfg.layer_kinds]
-        for name, none in (("kp", ("conv", "linear")),
-                           ("vp", ("conv", "latent", "linear"))):
+        ring = [m == "ring" for m, _ in cfg.layer_kinds]
+        for name, none in (("kp", ("conv", "linear", "ring")),
+                           ("vp", ("conv", "latent", "linear", "ring"))):
             state[name] = tuple(
                 None if m in none else jnp.zeros(unrolled_pool_shape(
-                    cfg, num_blocks, block_size, m), cfg.dtype)
+                    cfg, num_blocks, block_size, m, name == "vp"), cfg.dtype)
                 for m, _ in cfg.layer_kinds)
         if any(conv):
             k1, d = cfg.conv_kernel - 1, cfg.d_model
@@ -233,6 +256,16 @@ def init_paged_caches(cfg: TransformerConfig, num_slots: int,
             for name, shape, dtype in shapes:
                 state[name] = tuple(
                     jnp.zeros(shape, dtype) if c else None for c in linear)
+        if any(ring):
+            from ray_tpu.ops import window_ring
+            shapes = window_ring.ring_shapes(
+                num_states, cfg.sliding_kv_heads or cfg.kv_heads,
+                cfg.sliding_window, cfg.head_dim,
+                cfg.v_head_dim or cfg.head_dim)
+            for name, shape in zip(("ring_k", "ring_v"), shapes):
+                state[name] = tuple(
+                    jnp.zeros(shape, cfg.dtype) if c else None for c in ring)
+        if any(linear) or any(ring):
             state["slot_state"] = jnp.zeros((num_slots,), jnp.int32)
     if num_slots > 1:
         from ray_tpu.ops.paged_attention import no_shared_prefixes
@@ -497,6 +530,10 @@ def _write_rows(pool, blocks, offsets, new, prompt=(0, 1)):
       page written back, one update a slot (an inactive slot's goes to the
       scratch block, like any position that is not live)."""
     _, hkv, bs, D = pool.shape
+    if new.shape[-1] < D and D % new.shape[-1]:
+        # a head alone in rows of lanes it does not fill: zeros past it
+        from ray_tpu.ops.paged_attention import to_lanes
+        new = to_lanes(new, pool)
     new = new.reshape(-1, hkv, D).astype(pool.dtype)
     blocks, offsets = blocks.reshape(-1), offsets.reshape(-1)
     (N, P), T = prompt, blocks.shape[0]
@@ -785,7 +822,7 @@ class FusedUpload(NamedTuple):
         """The layout of an upload for `caches`; `width`: of an upload that
         is there already (what it is too narrow for, it does not carry)."""
         B, W = caches.block_tables.shape
-        up = cls(prompt_pad, W, B, bool(caches.state_pool))
+        up = cls(prompt_pad, W, B, bool(caches.state_pool or caches.ring_k))
         return up._replace(sets=width is None or width >= up._sets[3])
 
     tokens = property(lambda up: slice(0, up.P))
@@ -1021,8 +1058,31 @@ def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
         state.insert(0, pool)
         return o[None]
 
+    def ring_attend(q, k, v, sink):
+        """A ring layer, whose `k_pool` and `v_pool` are its rings by state
+        id: the rows as a linear layer's (`state_from`, `state_to`), a
+        decode row through its slot's ring."""
+        from ray_tpu.ops import window_ring
+
+        def rows_of(a):
+            return a[0, :N * P].reshape(N, P, *a.shape[2:])
+
+        o, rk, rv = window_ring.window_ring_chunk(
+            k_pool, v_pool, rows.state_from, rows.state_to, rows.prefix_lens,
+            rows.suffix_lens, rows_of(q), rows_of(k), rows_of(v), sink,
+            impl=attn_impl)
+        o = o.reshape(N * P, *o.shape[2:])
+        if step is not None:
+            od, rk, rv = window_ring.window_ring_step(
+                rk, rv, step.state_ids, step.positions[:, 0], q[0, N * P:],
+                k[0, N * P:], v[0, N * P:], sink, impl=attn_impl)
+            o = jnp.concatenate([o, od])
+        state.extend((rk, rv))
+        return o[None]
+
     mix = {"conv": before, "latent": attend_latent,
-           "linear": (linear_before, linear_rule)}.get(kind[0], attend)
+           "linear": (linear_before, linear_rule),
+           "ring": ring_attend}.get(kind[0], attend)
     y, counts = model.layer(cfg, kind, p, x.reshape(1, -1, D), positions,
                             mix, valid=valid,
                             moe_name="moe_experts_prefill", tap=tap)
@@ -1078,8 +1138,17 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
         state.insert(0, pool)
         return o[:, None]
 
+    def ring_attend(q, k, v, sink):
+        from ray_tpu.ops import window_ring
+        o, rk, rv = window_ring.window_ring_step(
+            k_pool, v_pool, rows.state_ids, rows.positions[:, 0], q[:, 0],
+            k[:, 0], v[:, 0], sink, impl=attn_impl)
+        state.extend((rk, rv))
+        return o[:, None]
+
     mix = {"conv": before, "latent": attend_latent,
-           "linear": (linear_before, linear_rule)}.get(kind[0], attend)
+           "linear": (linear_before, linear_rule),
+           "ring": ring_attend}.get(kind[0], attend)
     x, counts = model.layer(cfg, kind, p, x, rows.positions, mix,
                             valid=rows.active[:, None],
                             moe_name="moe_experts_decode", tap=tap)
@@ -1089,7 +1158,8 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
 # The pair of PagedDecodeCaches fields that holds a layer's state, by mixer
 # (any other: its K and V pools).
 _LAYER_STATE = {"conv": ("tail_pool", "slot_tail"),
-                "linear": ("state_pool", "conv_pool")}
+                "linear": ("state_pool", "conv_pool"),
+                "ring": ("ring_k", "ring_v")}
 
 
 def _unrolled_layers(cfg, params, caches, x, rows, layer_fn, attn_impl):

@@ -44,7 +44,7 @@ class TransformerConfig:
     max_seq: int = 2048
     arch: str = "llama"                   # "llama" | "gpt2" | a module of
     # unrolled layers under models/ ("afmoe", "lfm2", "axk1",
-    # "olmo_hybrid", "qwen3_next": see `layer_kinds`)
+    # "olmo_hybrid", "qwen3_next", "mimo_v2": see `layer_kinds`)
     rope_theta: Optional[float] = 500_000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16             # activation/compute dtype
@@ -156,6 +156,21 @@ class TransformerConfig:
     moe_shared_gate: bool = False
     attn_output_gate: bool = False
     norm_zero_centered: bool = False
+    # arch "mimo_v2" (models/mimo_v2.py; serving and `forward` only): mixer
+    # "ring" | "full".  A ring layer is sliding-window attention over the
+    # last `sliding_window` positions whose keys and values a sequence keeps
+    # as a ring by state id (ops/window_ring.py), with a learned sink a query
+    # head in its softmax; keys are `d_head` wide, values `v_head_dim` (0:
+    # `d_head`), the rotary embedding on the first `rotary_dim` dims.  What no
+    # key above expresses, each with a default that leaves every other
+    # architecture's tree and programs as they are:
+    # `sliding_kv_heads` kv heads of a ring layer (0: `n_kv_heads`, which
+    # counts a full layer's);
+    # `sliding_rope_theta` a ring layer's rotary base (None: `rope_theta`);
+    # `attn_value_scale` multiplies every layer's values.
+    sliding_kv_heads: int = 0
+    sliding_rope_theta: Optional[float] = None
+    attn_value_scale: float = 1.0
 
     def __post_init__(self):
         if self.layer_kinds is not None:

@@ -197,6 +197,21 @@ def _side_by_side(fwd, q: jax.Array, k_pool: jax.Array, *args) -> jax.Array:
                    axis=-2)
 
 
+def key_lanes(q: jax.Array, k_pool: jax.Array) -> jax.Array:
+    """q [..., H, D] with zeros up to the key pool's lanes where a key head
+    of D values lies alone in whole rows of lanes that D does not fill (192
+    in 256: the lanes past D are zero in the pool, `decoding._write_rows`);
+    any other q as it is.  The scale is taken from D BEFORE this."""
+    return q if k_pool.shape[3] % q.shape[-1] == 0 else to_lanes(q, k_pool)
+
+
+def _value_dim(D: int, k_pool: jax.Array, v_pool: jax.Array) -> int:
+    """A value head's width: the key head's, unless the value pool has rows
+    of lanes of its own (keys of 192 in 256 lanes beside values of 128)."""
+    hkv = k_pool.shape[1] * k_pool.shape[3] // D
+    return v_pool.shape[1] * v_pool.shape[3] // hkv
+
+
 # ---------------------------------------------------------------------------
 # Reference implementation (works everywhere; the numerics oracle)
 # ---------------------------------------------------------------------------
@@ -212,17 +227,19 @@ def paged_attention_reference(q: jax.Array, k_pool: jax.Array,
     mask beyond context_lens, softmax, f32 weighted sum — so a paged
     decode step matches `transformer.forward` at that position.
     """
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[2])
+    q = key_lanes(q, k_pool)
     B, H, D = q.shape
     hkv, bs = k_pool.shape[1] * k_pool.shape[3] // D, k_pool.shape[2]
+    dv = _value_dim(D, k_pool, v_pool)
     W = block_tables.shape[1]
     M = W * bs
-    scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
-    def rows(pool):         # [B, W, Hkv, bs, D] -> [B, Hkv, M, D]
-        return _pool_heads(jnp.take(pool, block_tables, axis=0), D
-                           ).transpose(0, 2, 1, 3, 4).reshape(B, hkv, M, D)
+    def rows(pool, d):      # [B, W, Hkv, bs, d] -> [B, Hkv, M, d]
+        return _pool_heads(jnp.take(pool, block_tables, axis=0), d
+                           ).transpose(0, 2, 1, 3, 4).reshape(B, hkv, M, d)
 
-    k, v = rows(k_pool), rows(v_pool)
+    k, v = rows(k_pool, D), rows(v_pool, dv)
     groups = H // hkv
     qg = q.reshape(B, hkv, groups, D)
     s = jnp.einsum("bhgk,bhmk->bhgm", qg.astype(jnp.float32),
@@ -238,7 +255,7 @@ def paged_attention_reference(q: jax.Array, k_pool: jax.Array,
     # impls stay interchangeable for padded/inactive rows.
     w = jnp.where(mask, w, 0.0)
     o = jnp.einsum("bhgm,bhmk->bhgk", w, v.astype(jnp.float32))
-    return o.reshape(B, H, D).astype(q.dtype)
+    return o.reshape(B, H, dv).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -604,9 +621,12 @@ def _stream_call(qg, pools, block_tables, context_lens, starts=None, *,
     N, hkv, R, D = qg.shape
     pool = pools[0]
     bs, W = pool.shape[2], block_tables.shape[1]
-    dv = D if v_lanes is None else v_lanes
-    pages, depth, tiles = _ring_shape(W, hkv, bs, D, pool.dtype.itemsize,
-                                      pools=len(pools))
+    # (a value pool may have rows of lanes of its own: keys of 192 in 256
+    # lanes beside values of 128)
+    dv = pools[-1].shape[3] if v_lanes is None else v_lanes
+    pages, depth, tiles = _ring_shape(
+        W, hkv, bs, sum(p.shape[3] for p in pools) // len(pools),
+        pool.dtype.itemsize, pools=len(pools))
 
     def q_index(b, *_):
         return (b, 0, 0, 0)
@@ -641,7 +661,7 @@ def _stream_call(qg, pools, block_tables, context_lens, starts=None, *,
                 memory_space=pltpu.MemorySpace.ANY)] * len(pools),
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((depth, pages, hkv, bs, D), p.dtype)
+                pltpu.VMEM((depth, pages, hkv, bs, p.shape[3]), p.dtype)
                 for p in pools] + [
                 pltpu.SemaphoreType.DMA((len(pools), depth)),
                 pltpu.SMEM((5,), jnp.int32),
@@ -723,7 +743,7 @@ def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *shared, scale,
         o = _narrow_call(_sublane_rows(qg), k_pool, v_pool, block_tables,
                          context_lens, scale=scale, window=window,
                          interpret=interpret)
-    return o[:, :, :groups].reshape(B, H, D).astype(q.dtype)
+    return o[:, :, :groups].reshape(B, H, o.shape[-1]).astype(q.dtype)
 
 
 def _narrow_call(qg, k_pool, v_pool, block_tables, context_lens, *, scale,
@@ -774,12 +794,18 @@ def _narrow_call(qg, k_pool, v_pool, block_tables, context_lens, *, scale,
 
 def _validate_paged(q, k_pool, v_pool):
     H, D = q.shape[1], q.shape[2]
-    bs = k_pool.shape[2]
-    if k_pool.shape != v_pool.shape or k_pool.shape[3] % D:
+    bs, lanes = k_pool.shape[2], k_pool.shape[3]
+    # heads side by side (f of D in a row of lanes) share one layout for
+    # keys and values; a head alone in its rows of lanes (D, or D padded
+    # with zeros: `key_lanes`) may have values of another width
+    alone = lanes % D != 0 or lanes == D
+    if k_pool.shape[:3] != v_pool.shape[:3] or lanes < D or (
+            not alone and k_pool.shape != v_pool.shape):
         raise ValueError(
-            f"paged attention: pools must be [NB, Hkv / f, bs, f * {D}], "
+            f"paged attention: pools must be [NB, Hkv / f, bs, f * {D}] (or "
+            f"keys [NB, Hkv, bs, >= {D}] beside values [NB, Hkv, bs, Dv]), "
             f"got k {k_pool.shape} v {v_pool.shape}")
-    hkv = k_pool.shape[1] * k_pool.shape[3] // D
+    hkv = k_pool.shape[1] * (1 if alone else lanes // D)
     if H % hkv:
         raise ValueError(
             f"paged attention: H={H} must be a multiple of Hkv={hkv}")
@@ -810,6 +836,7 @@ def paged_attention_kernel(q, k_pool, v_pool, block_tables, context_lens,
     traced each time, the kernel was most of a warm start."""
     _validate_paged(q, k_pool, v_pool)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[2])
+    q = key_lanes(q, k_pool)
     kw = dict(scale=scale, window=window)
     reference = functools.partial(paged_attention_reference, **kw)
     if window is not None or k_pool.shape[3] % _LANES:
@@ -879,17 +906,19 @@ def prefix_attention_reference(q, k_pool, v_pool, block_tables,
                                window: Optional[int] = None) -> jax.Array:
     """Gather the whole table window and mask by position (small sizes:
     the scores are [N, H, P, W * bs] float32)."""
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[3])
+    q = key_lanes(q, k_pool)
     N, P, H, D = q.shape
     hkv, bs = k_pool.shape[1] * k_pool.shape[3] // D, k_pool.shape[2]
+    dv = _value_dim(D, k_pool, v_pool)
     M = block_tables.shape[1] * bs
-    scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
-    def rows(pool):         # [N, W, Hkv, bs, D] -> [N, Hkv, M, D]
-        return _pool_heads(jnp.take(pool, block_tables, axis=0), D
+    def rows(pool, d):      # [N, W, Hkv, bs, d] -> [N, Hkv, M, d]
+        return _pool_heads(jnp.take(pool, block_tables, axis=0), d
                            ).transpose(0, 2, 1, 3, 4).reshape(
-            N, hkv, M, D).astype(jnp.float32)
+            N, hkv, M, d).astype(jnp.float32)
 
-    k, v = rows(k_pool), rows(v_pool)
+    k, v = rows(k_pool, D), rows(v_pool, dv)
     qg = q.reshape(N, P, hkv, H // hkv, D).astype(jnp.float32)
     s = jnp.einsum("nphgd,nhmd->nhgpm", qg, k) * scale
     qpos = prefix_lens[:, None] + jnp.arange(P)[None, :]        # [N, P]
@@ -902,7 +931,7 @@ def prefix_attention_reference(q, k_pool, v_pool, block_tables,
     w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
     w = jnp.where(seen, w, 0.0)             # a dead query row -> zeros
     o = jnp.einsum("nhgpm,nhmd->nphgd", w, v)
-    return o.reshape(N, P, H, D).astype(q.dtype)
+    return o.reshape(N, P, H, dv).astype(q.dtype)
 
 
 def _prefix_kernel(bt_ref, pre_ref, suf_ref, qoff_ref, q_ref, *refs,
@@ -983,6 +1012,7 @@ def _prefix_fwd(q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
 
     N, P, H, D = q.shape
     hkv, bs = k_pool.shape[1], k_pool.shape[2]
+    dv = v_pool.shape[3]        # (its own rows of lanes: `_value_dim`)
     W = block_tables.shape[1]
     G = H // hkv
     tq = min(_PREFIX_QUERIES, P)
@@ -1012,15 +1042,15 @@ def _prefix_fwd(q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
                       pl.BlockSpec((1, hkv, rows, D), q_index),
                       pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
                       pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
-            out_specs=pl.BlockSpec((1, hkv, rows, D), q_index),
+            out_specs=pl.BlockSpec((1, hkv, rows, dv), q_index),
             scratch_shapes=[
                 pltpu.VMEM((2, pages, hkv, bs, D), k_pool.dtype),
-                pltpu.VMEM((2, pages, hkv, bs, D), v_pool.dtype),
+                pltpu.VMEM((2, pages, hkv, bs, dv), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((hkv, rows, 1), jnp.float32),
                 pltpu.VMEM((hkv, rows, 1), jnp.float32),
-                pltpu.VMEM((hkv, rows, D), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((N, hkv, P * G, D), q.dtype),
+                pltpu.VMEM((hkv, rows, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((N, hkv, P * G, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=48 * 2 ** 20),
@@ -1028,8 +1058,8 @@ def _prefix_fwd(q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
         name="prefix_attention",
     )(block_tables.astype(jnp.int32), prefix_lens,
       (hi_all - prefix_lens).astype(jnp.int32), qoff, qg, k_pool, v_pool)
-    return o.reshape(N, hkv, P, G, D).transpose(0, 2, 1, 3, 4).reshape(
-        N, P, H, D)
+    return o.reshape(N, hkv, P, G, dv).transpose(0, 2, 1, 3, 4).reshape(
+        N, P, H, dv)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window"))
@@ -1038,6 +1068,7 @@ def prefix_attention_kernel(q, k_pool, v_pool, block_tables, prefix_lens,
                             window: Optional[int] = None) -> jax.Array:
     _validate_paged(q[:, 0], k_pool, v_pool)
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[3])
+    q = key_lanes(q, k_pool)
     kw = dict(scale=scale, window=window)
     return _side_by_side(
         functools.partial(

@@ -885,13 +885,15 @@ class PagedBatcher:
             1 for m, _ in (cfg.layer_kinds or ()) if m == "sliding")
         self._sliding_held = 0
         self._sliding_in_window = 0
-        # Per-sequence recurrent state, where the model has linear layers:
+        # Per-sequence state, where the model has layers that keep any (a
+        # linear layer's carry, a ring layer's window):
         # `num_states` ids, one a live slot and the rest checkpoints that
         # the radix cache owns (default: four checkpoints a slot).  No other
         # model has an allocator, and nothing below runs for it.
         self._states: Optional[StateAllocator] = None
         self.num_states = 0
-        if any(m == "linear" for m, _ in (cfg.layer_kinds or ())):
+        if any(m in decoding.STATE_MIXERS
+               for m, _ in (cfg.layer_kinds or ())):
             self.num_states = int(num_states or config.kv_num_states
                                   or 5 * num_slots)
             if self.num_states < num_slots:

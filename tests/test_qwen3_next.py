@@ -566,5 +566,5 @@ def test_the_cell_resolves_its_names():
         assert not any(m["name"].startswith("qw3n_")
                        for m in spec.load_cell(other)["layer_metrics"])
     bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 10 and len(bench["configs"]) == 7
+    assert len(bench["workloads"]) == 11 and len(bench["configs"]) == 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1

@@ -280,7 +280,8 @@ CELL_KERNELS = {"mistral-7b-l16": (1 + 1, 1),
                 "lfm2-24b-a2b-l9": (2 + 2 + 8, 2 + 8),
                 "axk1-l7-ep16": (7 + 7 + 6, 7 + 6),
                 "olmo-hybrid-7b-l12": (3 + 3 + 9 + 9, 3 + 9),
-                "qwen3-next-80b-a3b-l8-ep4": (2 + 2 + 6 + 6 + 8, 2 + 6 + 8)}
+                "qwen3-next-80b-a3b-l8-ep4": (2 + 2 + 6 + 6 + 8, 2 + 6 + 8),
+                "mimo-v2-flash-l7-ep16": (2 + 2 + 5 + 5 + 6, 2 + 5 + 6)}
 
 
 # LFM2's nine unrolled layers compile ~40 s a program here: one test a
@@ -291,7 +292,8 @@ CELL_PROGRAMS = [pytest.param("mistral-7b-l16", None, id="mistral-7b-l16"),
                  ] + [pytest.param(name, i, id=f"{name}-{i}")
                       for name in ("lfm2-24b-a2b-l9", "axk1-l7-ep16",
                                    "olmo-hybrid-7b-l12",
-                                   "qwen3-next-80b-a3b-l8-ep4")
+                                   "qwen3-next-80b-a3b-l8-ep4",
+                                   "mimo-v2-flash-l7-ep16")
                       for i in range(5)]
 
 
@@ -346,7 +348,9 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
     latent rows of 640 lanes with its experts in 4 blocks of F,
     Olmo-Hybrid's 30 / 30 heads of 128 beside nine layers' states of
     [15, 96, 384] by state id, Qwen3-Next's 16 / 2 heads of 256 beside six
-    layers' states of [32, 128, 128] and 128 held experts a layer) and the
+    layers' states of [32, 128, 128] and 128 held experts a layer,
+    MiMo-V2's 64 / 4 heads with keys of 192 in 256 lanes beside values of 128
+    and five layers' rings of [8, 128, 256 | 128] by state id) and the
     decode-only chunk, as Mosaic kernels,
     inside one chip's memory beside the weights.  (`impl="auto"` asks
     jax.default_backend(): steered here, in the test, as it would read on
@@ -361,7 +365,8 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
         "mistral-7b-l16": 48, "trinity-mini-l5": 1072,
         "lfm2-24b-a2b-l9": 1072, "axk1-l7-ep16": 1072,
         "olmo-hybrid-7b-l12": 1072,
-        "qwen3-next-80b-a3b-l8-ep4": 1072}[config_name]
+        "qwen3-next-80b-a3b-l8-ep4": 1072,
+        "mimo-v2-flash-l7-ep16": 1072}[config_name]
     # the budget is PREFILL_CHUNK tokens whatever the slots are, in at
     # most six programs, none more than 384 positions wider than the one
     # before it up to 896
@@ -397,7 +402,7 @@ def test_cells_fused_ladder_compiles_for_v5e(v5e_devices, monkeypatch,
 # anew (a PR that changes a program on purpose says so and does).
 HASHED_CONFIGS = ("mistral-7b-l16", "trinity-mini-l5", "lfm2-24b-a2b-l9",
                   "axk1-l7-ep16", "olmo-hybrid-7b-l12",
-                  "qwen3-next-80b-a3b-l8-ep4")
+                  "qwen3-next-80b-a3b-l8-ep4", "mimo-v2-flash-l7-ep16")
 HASH_FILE = "serving_program_hashes.json"
 
 
@@ -508,6 +513,84 @@ def test_delta_kernels_and_thirty_heads_compile_for_v5e(v5e_devices):
                 q, k, v, bt, a, b, impl="kernel")).lower(
             S((N, P, 30, 128), bf), kp, kp, S((N, 1072), i32), S((N,), i32),
             S((N,), i32)).compile()) == 1
+
+
+def test_ring_kernels_and_keys_of_192_compile_for_v5e(v5e_devices,
+                                                      monkeypatch):
+    """The kernels arch "mimo_v2" brings, alone at the cell's shapes, so that
+    a Mosaic refusal shows here: `window_ring_step` at 64 slots over rings of
+    [8, 128, 256 | 128] by state id (the 16 slots around the new one written
+    back in place), `window_ring_chunk` at the narrowest and the widest
+    rung's rows; and the two paged kernels at 64 query / 4 kv heads with keys
+    of 192 in pools of 256 lanes beside values of 128 under 1,072-column
+    tables, alone and with the sets of a shared prefix: NONE through the
+    narrow form (a head size that is no multiple of 128 took it before)."""
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.ops import window_ring as wr
+
+    def no_narrow(*args, **kw):
+        raise AssertionError("the narrow paged form")
+
+    monkeypatch.setattr(pa, "_narrow_call", no_narrow)
+    on_chip, _ = _on_chip_shapes(v5e_devices)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+    def S(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    H, hkv, W, dk, dv, B = 64, 8, 128, 192, 128, 64
+    P = B // 2
+    shk, shv = wr.ring_shapes(256, hkv, W, dk, dv)
+    assert (shk, shv) == ((257, 8, 128, 256), (257, 8, 128, 128))
+    assert _custom_calls(jax.jit(
+        lambda *a: wr.window_ring_step(*a, impl="kernel"),
+        donate_argnums=(0, 1)).lower(
+        S(shk), S(shv), S((B,), i32), S((B,), i32), S((B, H, dk)),
+        S((B, hkv, dk)), S((B, hkv, dv)), S((H,), f32)).compile()) == 1
+    for N in (16, 128):
+        assert _custom_calls(jax.jit(
+            lambda *a: wr.window_ring_chunk(*a, impl="kernel"),
+            donate_argnums=(0, 1)).lower(
+            S(shk), S(shv), S((N,), i32), S((N, 2), i32), S((N,), i32),
+            S((N,), i32), S((N, 16, H, dk)), S((N, 16, hkv, dk)),
+            S((N, 16, hkv, dv)), S((H,), f32)).compile()) == 1
+    kp, vp = S((8193, 4, 16, 256)), S((8193, 4, 16, 128))
+    assert pa._ring_shape(1072, 4, 16, 192, 2) == (8, 8, 2)
+    alone = jax.jit(lambda q, k, v, bt, n: pa.paged_attention(
+        q, k, v, bt, n, impl="kernel")).lower(
+        S((B, H, dk)), kp, vp, S((B, 1072), i32), S((B,), i32))
+    assert alone.out_info.shape == (B, H, dv)
+    assert _custom_calls(alone.compile()) == 1
+    together = jax.jit(lambda q, k, v, bt, n, *rows: pa.paged_attention(
+        q, k, v, bt, n, impl="kernel", shared=pa.SharedRows(*rows))).lower(
+        S((B, H, dk)), kp, vp, S((B, 1072), i32), S((B,), i32),
+        S((P, 8), i32), S((P, 1072), i32), S((P,), i32), S((B,), i32),
+        S((B,), i32), S((), jnp.bool_))
+    assert _custom_calls(together.compile()) == 2
+    # the exact-bytes key layout the on-chip test times against this one
+    # (tests_tpu/test_mimo_kernels_on_device.py): pairs of kv heads side by
+    # side in 384 | 256 lanes through the same kernel
+    pairs = jax.jit(lambda q, k, v, bt, n: pa.paged_attention(
+        q, k, v, bt, n, impl="kernel", scale=dk ** -0.5)).lower(
+        S((B, H, 2 * dk)), S((8193, 2, 16, 2 * dk)),
+        S((8193, 2, 16, 2 * dv)), S((B, 1072), i32), S((B,), i32))
+    assert pairs.out_info.shape == (B, H, 2 * dv)
+    assert _custom_calls(pairs.compile()) == 1
+    assert _custom_calls(jax.jit(
+        lambda q, k, v, bt, n, *rows: pa.paged_attention(
+            q, k, v, bt, n, impl="kernel", scale=dk ** -0.5,
+            shared=pa.SharedRows(*rows))).lower(
+        S((B, H, 2 * dk)), S((8193, 2, 16, 2 * dk)),
+        S((8193, 2, 16, 2 * dv)), S((B, 1072), i32), S((B,), i32),
+        S((P, 8), i32), S((P, 1072), i32), S((P,), i32), S((B,), i32),
+        S((B,), i32), S((), jnp.bool_)).compile()) == 2
+    for N, rows in ((32, 64), (16, 16)):
+        prefix = jax.jit(lambda q, k, v, bt, a, b: pa.prefix_attention(
+            q, k, v, bt, a, b, impl="kernel")).lower(
+            S((N, rows, H, dk)), kp, vp, S((N, 1072), i32), S((N,), i32),
+            S((N,), i32))
+        assert prefix.out_info.shape == (N, rows, H, dv)
+        assert _custom_calls(prefix.compile()) == 1
 
 
 def test_latent_and_blocked_kernels_compile_for_v5e(v5e_devices):
