@@ -29,8 +29,8 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.attention import attention_with_lse, uses_flash
 from ray_tpu.ops.ring_attention import ring_attention
-from ray_tpu.parallel.sharding import (_mesh_trivial, constrain,
-                                       shard_count, spec_for)
+from ray_tpu.parallel.sharding import (_current_mesh, _mesh_trivial,
+                                       constrain, shard_count, spec_for)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -646,32 +646,58 @@ def fused_cross_entropy(x: jax.Array, w_out: jax.Array, targets: jax.Array,
     """Chunked softmax cross-entropy that never materializes the full
     [B, S, V] logits (f32 logits for gpt2-small at B=32,S=1k are ~6 GB).
 
-    A scan over BLOCKS; each trip computes one block's float32 logits,
-    reduces them to per-token nll, and is rematerialized in the backward
-    pass (jax.checkpoint), so peak memory is one block.
+    A scan over BLOCKS; each trip computes one block's float32 logits and
+    reduces them to per-token nll, so peak memory is one block.  Under
+    differentiation the SAME trip also makes the block's gradients: the
+    loss is a scalar mean, so for a cotangent of 1 a block's
+    `d = (softmax(logits) - onehot(target)) * [target >= 0] / (B * S)` is
+    known while its logits are there.  A trip then runs three head-size
+    products (logits, `dx = d . wd^T`, `dW += xc^T . d`) and the backward
+    pass none: it scales the stacked `dx` and the summed `dW` by the
+    loss's cotangent.  (Up to PR 54 the trip was under `jax.checkpoint`
+    and the backward trip made the logits product a second time: four.)
+    An undifferentiated call runs the one logits product a trip.
+
+    The `jax.custom_vjp` sits INSIDE everything this function does before
+    the scan (the layout into blocks, the padding, the head's cast and its
+    gather), so their transposes stay autodiff's and the head's gradient
+    is reduced once, after the scan.  The arithmetic is what the
+    checkpointed scan compiled to (read off the parent's step for v5e):
+    softmax, `lse` and the picked logit float32; `d` float32 and
+    autodiff's own (`jax.vjp` of the block's nll with respect to its
+    logits, called in the trip with the cotangent `1 / (B * S)`: the mean
+    is folded into `d`, as autodiff folded it); the two gradient products
+    take `d` float32 beside a `cfg.dtype` operand and accumulate float32;
+    `dx` is rounded to x's dtype a trip and the head's gradient is a
+    `cfg.dtype` carry over the trips.  What a caller loses: the loss
+    cannot be differentiated twice, nor in forward mode (`custom_vjp`);
+    nothing in `ray_tpu` does (no `hessian`, `jacfwd` or `jvp(` anywhere
+    in the package).
 
     On one device a block is `cfg.xent_chunk` consecutive tokens of the
     flattened batch.  Under a mesh that splits "batch" b ways (read off
     the ambient mesh through the rule table) EVERY device flattens the
     rows it holds and a block is the same `xent_chunk` tokens of each of
-    them, [b, chunk, D] with b constrained to "batch" (flattened to
-    [b * chunk, D] inside the trip): `xent_chunk` stays the bound on one
-    device's logits block whatever the mesh, the scanned dimension is
-    never a sharded one, and no token leaves its device.  Where "seq" is
-    split (`sp`) the sequence is gathered first, as the unchunked loss in
-    `loss_fn` has it: the sp devices of one batch shard compute the same
-    blocks.  Each device's tokens are padded to whole blocks; padded
-    positions carry target -1 and add nothing.
+    them, [b, chunk, D] with b constrained to "batch": `xent_chunk` stays
+    the bound on one device's logits block whatever the mesh, the scanned
+    dimension is never a sharded one, and no token leaves its device.
+    Where "seq" is split (`sp`) the sequence is gathered first, as the
+    unchunked loss in `loss_fn` has it: the sp devices of one batch shard
+    compute the same blocks.  Each device's tokens are padded to whole
+    blocks; padded positions carry target -1 and add nothing.
 
     The head is cast and gathered ONCE, before the scan: its "embed"
     (contraction) dimension is sharded under `fsdp`, and a product with a
     shard of it leaves partial logits that must be all-reduced, one
-    logits-sized collective a trip, forward and backward.  Gathered, the
-    tokens stay where they are, the head's gradient is summed on each
-    device over the trips and reduced once after the scan, and under `tp`
-    ("vocab") only [tokens]-sized maxima, sums and picks cross devices.
+    logits-sized collective a trip.  Gathered, the tokens stay where they
+    are.  The scan sees the head as [b, D, V] with b constrained to
+    "batch" (every device its own copy: no bytes move), so a trip's `dW`
+    is [b, D, V] too: every device sums its own tokens' over the trips,
+    and the broadcast's transpose reduces them once after the scan.
+    Under `tp` ("vocab") only [tokens]-sized maxima, sums and picks and
+    the [tokens, D] partial `dx` cross devices.
     Invariant (tests/test_tpu_aot.py, tests/test_xent_sharding.py): NO
-    COLLECTIVE OF LOGITS SIZE INSIDE THE SCAN.
+    COLLECTIVE OF LOGITS SIZE OR OF THE HEAD'S SIZE INSIDE THE SCAN.
     """
     B, S, D = x.shape
     b = shard_count("batch")
@@ -686,22 +712,64 @@ def fused_cross_entropy(x: jax.Array, w_out: jax.Array, targets: jax.Array,
     tb = constrain(jnp.moveaxis(tb.reshape(b, n, chunk), 1, 0),
                    (None, "batch", None))
     wd = constrain(w_out.astype(cfg.dtype), (None, "vocab"))
+    wb = constrain(jnp.broadcast_to(wd, (b,) + wd.shape),
+                   ("batch", None, "vocab"))
+    # The rules below can be traced after this call has returned: they
+    # take the mesh of the call.
+    pin = functools.partial(constrain, mesh=_current_mesh())
 
-    def body(carry, inp):
-        xc, tc = inp[0].reshape(b * chunk, D), inp[1].reshape(b * chunk)
-        logits = constrain(
-            jnp.einsum("cd,dv->cv", xc, wd,
-                       preferred_element_type=jnp.float32),
-            ("batch", "vocab"))
+    def block_logits(xc, wb):
+        return pin(jnp.einsum("bcd,bdv->bcv", xc, wb,
+                              preferred_element_type=jnp.float32),
+                   ("batch", None, "vocab"))
+
+    def nll_sum(logits, tc):
+        # Rows flattened: the pick's transpose then compiles to a select
+        # inside the products' fusions, not to a scatter.
+        logits = pin(logits.reshape(b * chunk, -1), ("batch", "vocab"))
+        tc = tc.reshape(b * chunk)
         lse = jax.nn.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(
             logits, jnp.maximum(tc, 0)[:, None], axis=1)[:, 0]
-        nll = jnp.where(tc >= 0, lse - tgt, 0.0)
-        return carry + jnp.sum(nll), None
+        return jnp.sum(jnp.where(tc >= 0, lse - tgt, 0.0))
 
-    total, _ = jax.lax.scan(
-        jax.checkpoint(body), jnp.zeros((), jnp.float32), (xb, tb))
-    return total / (B * S)
+    @jax.custom_vjp
+    def mean_nll(xb, wb, tb):
+        def body(total, inp):
+            return total + nll_sum(block_logits(inp[0], wb), inp[1]), None
+
+        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xb, tb))
+        return total / (B * S)
+
+    def mean_nll_fwd(xb, wb, tb):
+        def body(carry, inp):
+            total, dw = carry
+            # The block as an array of its own: left to slice it from the
+            # stack inside the products' fusions, the TPU compiler runs
+            # `xc^T . d` through a slower emitter (4.1 ms a trip against
+            # 3.2 at train-4k-1chip's size; PERF.md, PR 55).
+            xc, tc = jax.lax.optimization_barrier(inp[0]), inp[1]
+            nll, pull = jax.vjp(lambda logits: nll_sum(logits, tc),
+                                block_logits(xc, wb))
+            d, = pull(jnp.float32(1.0) / (B * S))
+            dxc = jnp.einsum("bcv,bdv->bcd", d, wb,
+                             preferred_element_type=jnp.float32)
+            dwc = jnp.einsum("bcd,bcv->bdv", xc, d,
+                             preferred_element_type=jnp.float32)
+            return ((total + nll,
+                     pin(dw + dwc.astype(dw.dtype), ("batch", None, "vocab"))),
+                    pin(dxc.astype(xc.dtype), ("batch", None, None)))
+
+        (total, dw), dxb = jax.lax.scan(
+            body, (jnp.zeros((), jnp.float32), jnp.zeros_like(wb)), (xb, tb))
+        return total / (B * S), (dxb, dw)
+
+    def mean_nll_bwd(grads, ct):
+        return tuple((ct * g.astype(jnp.float32)).astype(g.dtype)
+                     for g in grads) + (None,)
+
+    mean_nll.defvjp(mean_nll_fwd, mean_nll_bwd)
+    return mean_nll(xb, wb, tb)
 
 
 def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None

@@ -9,6 +9,7 @@ the chip (numerics: tests_tpu/), but what the compiler refuses is caught
 before any chip time is spent.  About a minute; `slow` lane.
 """
 
+import collections
 import dataclasses
 import math
 import re
@@ -93,10 +94,11 @@ def _compile_fsdp_step(cfg, devices, chips, batch, seq, optimizer):
 
 def _assert_loss_keeps_its_tokens(compiled, cfg):
     """`fused_cross_entropy`'s invariant: no all-reduce or all-to-all of
-    logits size (one device's `xent_chunk` x vocabulary) inside a loop."""
+    logits size (one device's `xent_chunk` x vocabulary) inside a loop,
+    nor one of the head's size (d_model x vocabulary)."""
     from test_xent_sharding import loop_collectives
     big = [c for c in loop_collectives(compiled.as_text())
-           if c[1] >= cfg.xent_chunk * cfg.vocab_size]
+           if c[1] >= min(cfg.xent_chunk, cfg.d_model) * cfg.vocab_size]
     assert not big, big
 
 
@@ -124,29 +126,74 @@ def test_llama_1b_train_step_compiles_for_v5e(v5e_devices, chips, batch):
     per_chip = _per_chip_bytes(compiled, f"llama-1b fsdp={chips}")
     assert per_chip < 15.75 * 2 ** 30, f"{per_chip / 2 ** 30:.2f} GiB"
     _assert_loss_keeps_its_tokens(compiled, cfg)
+    _assert_loss_runs_three_products(compiled, cfg)
+
+
+Product = collections.namedtuple(
+    "Product", "instruction einsum dims op_name computation")
 
 
 def _products(text: str):
-    """Every matrix product of a compiled step (loops' bodies once) as
-    (the instruction that runs it: the fusion around the convolution, the
-    einsum it came from)."""
+    """Every matrix product of a compiled step (loops' bodies once): the
+    instruction that runs it (the fusion around the convolution), the
+    einsum it came from, the dimensions of its result and operands, its
+    whole `op_name`, and the computation that instruction stands in.
+    (`dot`: what the CPU compiler keeps them as.)"""
     from test_xent_sharding import computations
     bodies = computations(text)
-    called_by = {}          # a fusion's body -> the fusion instruction
-    for lines in bodies.values():
+    called_by = {}     # a fusion's body -> (the fusion instruction, its home)
+    for computation, lines in bodies.items():
         for line in lines:
             name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
             for callee in re.findall(r"calls=%?([\w.\-]+)", line):
-                called_by[callee] = name.group(1)
+                called_by[callee] = (name.group(1), computation)
     found = []
     for computation, lines in bodies.items():
+        dims = {m.group(1): m.group(2) for m in (
+            re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]", line)
+            for line in lines) if m}
         for line in lines:
-            if re.search(r"[\]})] convolution\(", line):
+            product = re.search(
+                r"%?([\w.\-]+) = .*[\]})] (?:convolution|dot)\((.*?)\)", line)
+            if product:
+                names = [product.group(1)] + [
+                    n.strip().lstrip("%") for n in product.group(2).split(",")]
+                op_name = re.search(r'op_name="([^"]*)"', line)
                 einsum = re.search(r'op_name="[^"]*?([\w,>\-]+)/dot_general',
                                    line)
-                found.append((called_by.get(computation, computation),
-                              einsum.group(1) if einsum else "?"))
+                instruction, home = called_by.get(
+                    computation, (product.group(1), computation))
+                found.append(Product(
+                    instruction, einsum.group(1) if einsum else "?",
+                    {int(d) for n in names for d in dims[n].split(",") if d},
+                    op_name.group(1) if op_name else "", home))
     return found
+
+
+def _head_products(text: str, cfg):
+    """The products with the head's dimensions (d_model and the vocabulary
+    among their result's and operands'), each with the `while` body that
+    runs it (or None) in `computation`'s place."""
+    loops = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    return [p._replace(computation=p.computation
+                       if p.computation in loops else None)
+            for p in _products(text)
+            if {cfg.d_model, cfg.vocab_size} <= p.dims]
+
+
+def _assert_loss_runs_three_products(compiled, cfg):
+    """PR 55: the chunked loss makes its gradients in the trip that has the
+    logits.  Exactly three products with the head's dimensions (logits,
+    `dx`, the head's gradient), all in ONE loop's body, and that loop is
+    the forward scan: a fourth, or one in a loop the backward pass runs,
+    is the logits product made again."""
+    head = _head_products(compiled.as_text(), cfg)
+    assert len(head) == 3, head
+    assert len({p.computation for p in head}) == 1 and head[0].computation
+    # (what names the LOOP comes before "/while": a forward trip may well
+    # transpose inside itself)
+    assert not [p for p in head
+                if "transpose(jvp" in p.op_name.split("/while")[0]], head
 
 
 def _loop_carried(text: str):
@@ -166,15 +213,19 @@ def test_fsdp4_cell_step_keeps_its_tokens(v5e_devices):
     held here, and the census of PR 53 (the layers' weight gradients stay in
     the compute dtype through the backward scan, train/train_step.py):
 
-    * 31 products, the one-chip step's count (a layer's 11 of FFN size and
-      12 of attention size, the loss's 4, ...), and none in an instruction
+    * 30 products, the one-chip step's count (a layer's 11 of FFN size and
+      12 of attention size, the loss's 3, ...; 31 with the loss's 4 up to
+      PR 54), and none in an instruction
       XLA's rematerialisation pass cloned (`.remat` in its name): with
       float32 stacks of the gradients the plan was 1.75 GiB a chip short
       and the pass ran `dy . W_down^T` and the `wo` recompute twice a layer;
+    * the loss's three in ONE loop's body, the forward scan's, and no other
+      loop holds a product with the head's dimensions (PR 55: a regression
+      to four would show in the backward scan);
     * no loop carries a float32 array of a stacked product weight's shape
       (on one chip of four: any one dimension split);
     * the plan's temporaries at least 1.5 GiB under the 17.52 GiB they
-      were."""
+      were (PR 53 left 15.77 GiB; PR 55's loss 14.90)."""
     import json
     import os
 
@@ -199,8 +250,9 @@ def test_fsdp4_cell_step_keeps_its_tokens(v5e_devices):
 
     text = compiled.as_text()
     products = _products(text)
-    cloned = [p for p in products if ".remat" in p[0]]
-    assert not cloned and len(products) == 31, (len(products), cloned)
+    cloned = [p for p in products if ".remat" in p.instruction]
+    assert not cloned and len(products) == 30, (len(products), cloned)
+    _assert_loss_runs_three_products(compiled, cfg)
     stacks = jax.eval_shape(
         lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))["layers"]
     on_a_chip = {tuple(sorted(d // chips if i == split else d
@@ -213,6 +265,8 @@ def test_fsdp4_cell_step_keeps_its_tokens(v5e_devices):
                if dtype == "f32" and tuple(sorted(dims)) in on_a_chip]
     assert not widened, widened
     temporaries = compiled.memory_analysis().temp_size_in_bytes
+    print(f"temporaries {temporaries / 2 ** 30:.2f} GiB "
+          f"(the parent's, PR 54: 15.77)")
     assert temporaries <= (17.52 - 1.5) * 2 ** 30, temporaries / 2 ** 30
 
 

@@ -227,3 +227,82 @@ def test_step_gradients_against_float32_parameter_form_on_tpu():
           {k: float(f"{v:.2g}") for k, v in off.items()})
     assert all(mine[k][0] > 0 for k in mine)
     assert max(off.values()) <= 2e-4, off
+
+
+def test_chunked_loss_gradient_against_checkpointed_form_on_tpu():
+    """The chunked loss at `train-4k-1chip`'s size (4 x 4,096 tokens of
+    4,096, a head of 32,000, blocks of 2,048, bf16 operands): since PR 55
+    its gradients are made in the trip that has the logits
+    (`fused_cross_entropy`'s `custom_vjp`); beside it the form it replaced,
+    the same trip under `jax.checkpoint` differentiated by autodiff, kept
+    HERE as the reference.  Same loss, `dx` and the head's gradient: sums
+    of squares within 2e-4 (this file's limit) and every entry within 3e-2
+    of the largest (the head's gradient is a bf16 carry that the backward
+    scan summed over the trips last to first and this one first to last),
+    with a cotangent of 1 and of 3.  The benchmark's `correct` does not look at the loss's gradient
+    (benchmarks/lib/train_cell.py: `flash_err`, the first loss against
+    ln(vocab), finiteness), so this is what holds it on the chip.  Prints
+    the readings and both programs' seconds a call."""
+    import dataclasses
+    import time
+
+    from ray_tpu.models import transformer as tfm
+
+    B, S, D, V, chunk = 4, 4096, 4096, 32000, 2048
+    cfg = dataclasses.replace(tfm.PRESETS["llama-1b"], d_model=D,
+                              vocab_size=V, xent_chunk=chunk,
+                              dtype=jnp.bfloat16)
+    kx, kw, kt = jax.random.split(jax.random.PRNGKey(55), 3)
+    x = jax.random.normal(kx, (B, S, D), jnp.bfloat16)
+    w = 0.02 * jax.random.normal(kw, (D, V), jnp.float32)
+    targets = jax.random.randint(kt, (B, S), 0, V, jnp.int32)
+
+    def checkpointed(x, w, targets):
+        xb, tb = x.reshape(-1, chunk, D), targets.reshape(-1, chunk)
+        wd = w.astype(cfg.dtype)
+
+        def body(carry, inp):
+            logits = jnp.einsum("cd,dv->cv", inp[0], wd,
+                                preferred_element_type=jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            tgt = jnp.take_along_axis(logits, inp[1][:, None], axis=1)[:, 0]
+            return carry + jnp.sum(lse - tgt), None
+
+        total, _ = jax.lax.scan(jax.checkpoint(body),
+                                jnp.zeros((), jnp.float32), (xb, tb))
+        return total / tb.size
+
+    def own(x, w, targets):
+        return tfm.fused_cross_entropy(x, w, targets, cfg)
+
+    def reduced(loss, scale):
+        def f(x, w, targets):
+            value, (gx, gw) = jax.value_and_grad(
+                lambda x, w: scale * loss(x, w, targets), argnums=(0, 1))(x, w)
+            return value / scale, gx, gw
+        return jax.jit(f)
+
+    for scale in (1.0, 3.0):
+        got = {}
+        for name, loss in (("own", own), ("checkpointed", checkpointed)):
+            fn = reduced(loss, scale)
+            jax.block_until_ready(fn(x, w, targets))
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = jax.block_until_ready(fn(x, w, targets))
+            got[name] = out
+            print(f"cotangent {scale}: {name}: loss {float(out[0]).hex()}, "
+                  f"{(time.perf_counter() - t0) / 5 * 1e3:.2f} ms a call")
+        (loss, gx, gw), (want, want_gx, want_gw) = (
+            got["own"], got["checkpointed"])
+        assert abs(float(loss) - float(want)) <= 1e-6 * abs(float(want))
+        for name, g, wg in (("dx", gx, want_gx), ("head", gw, want_gw)):
+            g, wg = g.astype(jnp.float32), wg.astype(jnp.float32)
+            sq, want_sq = float(jnp.sum(g * g)), float(jnp.sum(wg * wg))
+            apart = float(jnp.max(jnp.abs(g - wg)) / jnp.max(jnp.abs(wg)))
+            print(f"cotangent {scale}: {name}: sum of squares {sq:.6e} "
+                  f"against {want_sq:.6e}, largest difference {apart:.2g} "
+                  f"of the largest entry, "
+                  f"{int(jnp.sum(g != wg))} of {g.size} entries differ")
+            assert want_sq > 0 and abs(sq - want_sq) <= 2e-4 * want_sq
+            assert apart <= 3e-2    # bf16: tests/test_xent_sharding.py's
