@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (TransformerConfig, _norm, _rope,
                                         _w_out)
+from ray_tpu.ops import scopes
 from ray_tpu.ops.grouped_ffn import grouped_ffn, tile_rows
 
 # What one expert layer counts per call (a decode step or a prefill chunk):
@@ -174,15 +175,21 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 # the layer
 # ---------------------------------------------------------------------------
+@jax.named_scope(scopes.NORM)
 def _rms(x, w, cfg):
     return _norm(x, w, None, cfg.norm_eps, True)
 
 
-def _ffn(m, w_gate, w_up, w_down):
-    gate = jnp.einsum("bsd,df->bsf", m, w_gate.astype(m.dtype))
-    up = jnp.einsum("bsd,df->bsf", m, w_up.astype(m.dtype))
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(m.dtype) * up
-    return jnp.einsum("bsf,fd->bsd", act, w_down.astype(m.dtype))
+def _ffn(m, w_gate, w_up, w_down,
+         names=(scopes.FFN_GATE_UP, scopes.FFN_DOWN)):
+    """A gated feed-forward, its two halves under `names` (ops/scopes.py:
+    a dense layer's; the shared expert gives its own)."""
+    with jax.named_scope(names[0]):
+        gate = jnp.einsum("bsd,df->bsf", m, w_gate.astype(m.dtype))
+        up = jnp.einsum("bsd,df->bsf", m, w_up.astype(m.dtype))
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(m.dtype) * up
+    with jax.named_scope(names[1]):
+        return jnp.einsum("bsf,fd->bsd", act, w_down.astype(m.dtype))
 
 
 def route(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array
@@ -195,7 +202,7 @@ def route(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array
     score = {"sigmoid": jax.nn.sigmoid,
              "softmax": functools.partial(jax.nn.softmax, axis=-1)
              }[cfg.moe_score_fn]
-    with jax.named_scope("moe_route"):
+    with jax.named_scope(scopes.MOE_ROUTE):
         s = score(jnp.einsum(
             "td,de->te", m.astype(jnp.float32),
             p["w_router"].astype(jnp.float32),
@@ -252,8 +259,9 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
                            router_width=cfg.router_width)
     y = y.reshape(B, S, D)
     if cfg.moe_shared_experts:
-        with jax.named_scope("moe_shared"):
-            shared = _ffn(m, p["ws_gate"], p["ws_up"], p["ws_down"])
+        with jax.named_scope(scopes.MOE_SHARED):
+            shared = _ffn(m, p["ws_gate"], p["ws_up"], p["ws_down"],
+                          names=(scopes.MOE_SHARED,) * 2)
             if cfg.moe_shared_gate:
                 gate = jax.nn.sigmoid(jnp.einsum(
                     "bsd,d->bs", m.astype(jnp.float32),
@@ -272,7 +280,7 @@ def experts(cfg: TransformerConfig, p: Dict[str, Any], m: jax.Array,
 def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
           x: jax.Array, positions: jax.Array, attend: Callable,
           valid: Optional[jax.Array] = None,
-          moe_name: str = "moe_experts_prefill",
+          moe_name: str = scopes.MOE_EXPERTS_PREFILL,
           tap: Optional[Callable] = None
           ) -> Tuple[jax.Array, jax.Array]:
     """x [B, S, D] at `positions` [B, S] -> (x', MOE_COUNTS of this call).
@@ -281,18 +289,20 @@ def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
     (the caller knows the mixer: it built `attend` for it)."""
     mixer, ffn = kind
     a = _rms(x, p["attn_norm"], cfg)
-    q = jnp.einsum("bsd,dhk->bshk", a, p["wq"].astype(a.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", a, p["wk"].astype(a.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", a, p["wv"].astype(a.dtype))
-    q, k = _rms(q, p["q_norm"], cfg), _rms(k, p["k_norm"], cfg)
-    gate = jax.nn.sigmoid(jnp.einsum(
-        "bsd,dhk->bshk", a, p["wg"].astype(a.dtype)).astype(jnp.float32))
-    if mixer == "sliding":
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+    with jax.named_scope(scopes.ATTN_QKV):
+        q = jnp.einsum("bsd,dhk->bshk", a, p["wq"].astype(a.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", a, p["wk"].astype(a.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", a, p["wv"].astype(a.dtype))
+        q, k = _rms(q, p["q_norm"], cfg), _rms(k, p["k_norm"], cfg)
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dhk->bshk", a, p["wg"].astype(a.dtype)).astype(jnp.float32))
+        if mixer == "sliding":
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
     o = attend(q, k, v)
-    o = (gate * o.astype(jnp.float32)).astype(x.dtype)
-    attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
+    with jax.named_scope(scopes.ATTN_OUT):
+        o = (gate * o.astype(jnp.float32)).astype(x.dtype)
+        attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
     x = x + _rms(attn, p["post_attn_norm"], cfg)
     m = _rms(x, p["mlp_norm"], cfg)
     if ffn == "dense":
@@ -307,12 +317,14 @@ def window_of(cfg: TransformerConfig, kind: Tuple[str, str]
     return cfg.sliding_window if kind[0] == "sliding" else None
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(cfg: TransformerConfig, table: jax.Array,
           tokens: jax.Array) -> jax.Array:
     return (table[tokens].astype(jnp.float32) * math.sqrt(cfg.d_model)
             ).astype(cfg.dtype)
 
 
+@jax.named_scope(scopes.HEAD)
 def logits(cfg: TransformerConfig, params: Dict[str, Any],
            x: jax.Array) -> jax.Array:
     """x [..., D] -> float32 logits [..., V]; `params` holds final_norm and

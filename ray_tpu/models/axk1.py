@@ -61,6 +61,7 @@ import numpy as np
 from ray_tpu.models.afmoe import (_ffn, _rms, experts,  # noqa: F401
                                   init_head, logits, no_counts)
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.ops import scopes
 
 MIXERS = ("latent",)
 
@@ -240,7 +241,7 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 def latent_queries(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
                    positions: jax.Array) -> jax.Array:
     """a [B, S, D] -> the absorbed queries [q~ | q_rope] [B, S, H, c + r]."""
-    with jax.named_scope("mla_q"):
+    with jax.named_scope(scopes.MLA_Q):
         cq = _rms(jnp.einsum("bsd,dr->bsr", a, p["w_dq"].astype(a.dtype)),
                   p["q_norm"], cfg)
         q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"].astype(a.dtype))
@@ -255,7 +256,7 @@ def latent_row(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
                positions: jax.Array) -> jax.Array:
     """a [B, S, D] -> what each position leaves behind, [B, S, 1, c + r]:
     the latent after its norm, the shared key part after its rotation."""
-    with jax.named_scope("mla_kv"):
+    with jax.named_scope(scopes.MLA_KV):
         ckv = jnp.einsum("bsd,dk->bsk", a, p["w_dkv"].astype(a.dtype))
         c = _rms(ckv[..., :cfg.kv_lora_rank], p["kv_norm"], cfg)
         k_r = rope(cfg, ckv[:, :, None, cfg.kv_lora_rank:], positions)
@@ -265,7 +266,7 @@ def latent_row(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
 def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
           x: jax.Array, positions: jax.Array, mix: Callable,
           valid: Optional[jax.Array] = None,
-          moe_name: str = "moe_experts_prefill",
+          moe_name: str = scopes.MOE_EXPERTS_PREFILL,
           tap: Optional[Callable] = None
           ) -> Tuple[jax.Array, jax.Array]:
     """x [B, S, D] at `positions` [B, S] -> (x', MOE_COUNTS of this call).
@@ -276,7 +277,7 @@ def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
     a = _rms(x, p["attn_norm"], cfg)
     o = mix(latent_queries(cfg, p, a, positions),
             latent_row(cfg, p, a, positions)).astype(x.dtype)
-    with jax.named_scope("mla_out"):
+    with jax.named_scope(scopes.MLA_OUT):
         ov = jnp.einsum("bshc,hcv->bshv", o, p["w_uv"].astype(o.dtype))
         x = x + jnp.einsum("bshv,hvd->bsd", ov, p["w_o"].astype(o.dtype))
     m = _rms(x, p["ffn_norm"], cfg)
@@ -288,6 +289,7 @@ def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
     return x + y, counts
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(cfg: TransformerConfig, table: jax.Array,
           tokens: jax.Array) -> jax.Array:
     return table[tokens].astype(cfg.dtype)

@@ -58,8 +58,10 @@ import numpy as np
 
 from ray_tpu.models.transformer import (TransformerConfig, _norm, _rope,
                                         _w_out, unrolled)
+from ray_tpu.ops import scopes
 
 
+@jax.named_scope(scopes.ATTN_QKV)
 def _qkv(p, h, cfg: TransformerConfig, positions):
     q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(h.dtype))
     k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(h.dtype))
@@ -72,19 +74,22 @@ def _qkv(p, h, cfg: TransformerConfig, positions):
 
 def _mlp(p, x, cfg: TransformerConfig):
     rms = cfg.arch == "llama"
-    h = _norm(x, p["mlp_norm"], p.get("mlp_norm_b"), cfg.norm_eps, rms)
-    if cfg.arch == "llama":
-        gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"].astype(h.dtype))
-        up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(h.dtype))
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
-    else:
-        up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(h.dtype))
-        up = up + p["b_up"].astype(h.dtype)
-        act = jax.nn.gelu(up.astype(jnp.float32)).astype(h.dtype)
-    down = jnp.einsum("bsf,fd->bsd", act, p["w_down"].astype(act.dtype))
-    if cfg.arch == "gpt2":
-        down = down + p["b_down"].astype(down.dtype)
-    return x + down
+    with jax.named_scope(scopes.NORM):
+        h = _norm(x, p["mlp_norm"], p.get("mlp_norm_b"), cfg.norm_eps, rms)
+    with jax.named_scope(scopes.FFN_GATE_UP):
+        if cfg.arch == "llama":
+            gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"].astype(h.dtype))
+            up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(h.dtype))
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
+        else:
+            up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(h.dtype))
+            up = up + p["b_up"].astype(h.dtype)
+            act = jax.nn.gelu(up.astype(jnp.float32)).astype(h.dtype)
+    with jax.named_scope(scopes.FFN_DOWN):
+        down = jnp.einsum("bsf,fd->bsd", act, p["w_down"].astype(act.dtype))
+        if cfg.arch == "gpt2":
+            down = down + p["b_down"].astype(down.dtype)
+        return x + down
 
 
 # ===========================================================================
@@ -474,6 +479,7 @@ def _pass_tokens(rows: PrefillRows, step: Optional[DecodeRows]):
     return positions[None], valid[None], blocks, offsets
 
 
+@jax.named_scope(scopes.ATTN)
 def _attend_pass(q, k_pool, v_pool, rows: PrefillRows,
                  step: Optional[DecodeRows], first_block=0, **kw):
     """Attention of one pass's queries [1, T, H, D], its K/V already in the
@@ -501,6 +507,7 @@ def _shared_from(shared, first_block):
     return shared._replace(tables=first_block + shared.tables)
 
 
+@jax.named_scope(scopes.KV_WRITE)
 def _write_rows(pool, blocks, offsets, new, prompt=(0, 1)):
     """pool [NB, Hkv', bs, lanes] with new [T, Hkv, D] written at (blocks,
     :, offsets) [T]: ([Hkv, D] is the pool's [Hkv', lanes] row by row, heads
@@ -557,7 +564,7 @@ def _write_latent(pool, blocks, offsets, rows, prompt=(0, 1)):
     """A latent layer's ONE write: rows [..., 1, c + r] into pool [NB, 1,
     bs, Dp] at (blocks, offsets), the lanes past c + r zero."""
     from ray_tpu.ops.paged_attention import to_lanes
-    with jax.named_scope("mla_kv"):
+    with jax.named_scope(scopes.MLA_KV):
         return _write_rows(pool, blocks, offsets, to_lanes(rows, pool),
                            prompt)
 
@@ -595,38 +602,45 @@ def _paged_decode_core(params: Dict[str, Any], caches: PagedDecodeCaches,
 
     rows = decode_rows(caches.block_tables, caches.lengths, active,
                        caches.kp.shape[3], shared=caches.shared)
-    x = params["tok_embed"][caches.last_token[:, None]].astype(cfg.dtype)
-    if cfg.arch == "gpt2":
-        x = x + params["pos_embed"][
-            jnp.clip(rows.positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
+    with jax.named_scope(scopes.EMBED):
+        x = params["tok_embed"][caches.last_token[:, None]].astype(cfg.dtype)
+        if cfg.arch == "gpt2":
+            x = x + params["pos_embed"][jnp.clip(
+                rows.positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
     rms = cfg.arch == "llama"
 
     def layer(carry, inputs):
         x, k_pool, v_pool = carry
         p, first = inputs
-        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
-                  cfg.norm_eps, rms)
+        with jax.named_scope(scopes.NORM):
+            h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
+                      cfg.norm_eps, rms)
         q, k_new, v_new = _qkv(p, h, cfg, rows.positions)
         # one [Hkv, Dh] row per slot
         k_pool = _write_rows(k_pool, first + rows.blocks, rows.offsets,
                              k_new[:, 0])
         v_pool = _write_rows(v_pool, first + rows.blocks, rows.offsets,
                              v_new[:, 0])
-        o = _pa.paged_attention(q[:, 0], k_pool, v_pool,
-                                first + rows.tables, rows.context_lens,
-                                impl=attn_impl,
-                                shared=_shared_from(rows.shared, first))
-        attn = jnp.einsum("bshk,hkd->bsd", o[:, None].astype(cfg.dtype),
-                          p["wo"].astype(cfg.dtype))
-        return (_mlp(p, x + attn, cfg), k_pool, v_pool), None
+        with jax.named_scope(scopes.ATTN):
+            o = _pa.paged_attention(q[:, 0], k_pool, v_pool,
+                                    first + rows.tables, rows.context_lens,
+                                    impl=attn_impl,
+                                    shared=_shared_from(rows.shared, first))
+        with jax.named_scope(scopes.ATTN_OUT):
+            attn = x + jnp.einsum(
+                "bshk,hkd->bsd", o[:, None].astype(cfg.dtype),
+                p["wo"].astype(cfg.dtype))
+        return (_mlp(p, attn, cfg), k_pool, v_pool), None
 
     x, kp_all, vp_all = _scan_layers(layer, x, params["layers"], caches)
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"),
-              cfg.norm_eps, rms)
-    logits = jnp.einsum(
-        "bsd,dv->bsv", x.astype(jnp.float32),
-        _w_out(params, cfg).astype(jnp.float32))[:, 0]       # [B,V]
-    next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope(scopes.NORM):
+        x = _norm(x, params["final_norm"], params.get("final_norm_b"),
+                  cfg.norm_eps, rms)
+    with jax.named_scope(scopes.HEAD):
+        logits = jnp.einsum(
+            "bsd,dv->bsv", x.astype(jnp.float32),
+            _w_out(params, cfg).astype(jnp.float32))[:, 0]       # [B,V]
+        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     new_last = jnp.where(active, next_tok, caches.last_token)
     new_len = jnp.where(active, caches.lengths + 1, caches.lengths)
     return caches._replace(kp=kp_all, vp=vp_all, lengths=new_len,
@@ -726,12 +740,15 @@ def _paged_prefill_core(params: Dict[str, Any],
         x, kp, vp = _dense_prefill_layers(cfg, params, caches, tokens, rows,
                                           step, attn_impl)
         state, counts = dict(kp=kp, vp=vp), None
-        last = _norm(x[0, yields], params["final_norm"],
-                     params.get("final_norm_b"), cfg.norm_eps,
-                     cfg.arch == "llama")                    # [N + B, D]
-        logits = last.astype(jnp.float32) @ _w_out(params, cfg).astype(
-            jnp.float32)
-    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope(scopes.NORM):
+            last = _norm(x[0, yields], params["final_norm"],
+                         params.get("final_norm_b"), cfg.norm_eps,
+                         cfg.arch == "llama")                # [N + B, D]
+        with jax.named_scope(scopes.HEAD):
+            logits = last.astype(jnp.float32) @ _w_out(params, cfg).astype(
+                jnp.float32)
+    with jax.named_scope(scopes.HEAD):
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     first_tok, step_tok = tok[:N], None
     lengths, last_token = caches.lengths, caches.last_token
     if carried is not None:
@@ -757,25 +774,28 @@ def _dense_prefill_layers(cfg, params, caches, tokens, rows: PrefillRows,
     tokens [1, T]) over the stacked pool -> (x' [1, T, D], kp', vp')."""
     positions, _, blocks, offsets = _pass_tokens(rows, step)
     prompt = rows.positions.shape       # (N, P)
-    x = params["tok_embed"][tokens].astype(cfg.dtype)        # [1,T,D]
-    if cfg.arch == "gpt2":
-        x = x + params["pos_embed"][
-            jnp.clip(positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
+    with jax.named_scope(scopes.EMBED):
+        x = params["tok_embed"][tokens].astype(cfg.dtype)    # [1,T,D]
+        if cfg.arch == "gpt2":
+            x = x + params["pos_embed"][
+                jnp.clip(positions, 0, cfg.max_seq - 1)].astype(cfg.dtype)
     rms = cfg.arch == "llama"
 
     def layer(carry, inputs):
         x, k_pool, v_pool = carry
         p, first = inputs
-        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
-                  cfg.norm_eps, rms)
+        with jax.named_scope(scopes.NORM):
+            h = _norm(x, p["attn_norm"], p.get("attn_norm_b"),
+                      cfg.norm_eps, rms)
         q, k, v = _qkv(p, h, cfg, positions)
         k_pool = _write_rows(k_pool, first + blocks, offsets, k[0], prompt)
         v_pool = _write_rows(v_pool, first + blocks, offsets, v[0], prompt)
         o = _attend_pass(q, k_pool, v_pool, rows, step, first,
                          impl=attn_impl)                     # [1,T,H,Dh]
-        attn = jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
-                          p["wo"].astype(cfg.dtype))
-        return (_mlp(p, x + attn, cfg), k_pool, v_pool), None
+        with jax.named_scope(scopes.ATTN_OUT):
+            attn = x + jnp.einsum("bshk,hkd->bsd", o.astype(cfg.dtype),
+                                  p["wo"].astype(cfg.dtype))
+        return (_mlp(p, attn, cfg), k_pool, v_pool), None
 
     return _scan_layers(layer, x, params["layers"], caches)
 
@@ -1085,7 +1105,7 @@ def paged_prefill_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
            "ring": ring_attend}.get(kind[0], attend)
     y, counts = model.layer(cfg, kind, p, x.reshape(1, -1, D), positions,
                             mix, valid=valid,
-                            moe_name="moe_experts_prefill", tap=tap)
+                            moe_name=scopes.MOE_EXPERTS_PREFILL, tap=tap)
     return y.reshape(x.shape), state[0], state[1], counts
 
 
@@ -1103,17 +1123,19 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
         kp = _write_rows(k_pool, rows.blocks, rows.offsets, k[:, 0])
         vp = _write_rows(v_pool, rows.blocks, rows.offsets, v[:, 0])
         state.extend((kp, vp))
-        return _pa.paged_attention(
-            q[:, 0], kp, vp, rows.tables, rows.context_lens,
-            impl=attn_impl, window=model.window_of(cfg, kind),
-            shared=rows.shared)[:, None]
+        with jax.named_scope(scopes.ATTN):
+            return _pa.paged_attention(
+                q[:, 0], kp, vp, rows.tables, rows.context_lens,
+                impl=attn_impl, window=model.window_of(cfg, kind),
+                shared=rows.shared)[:, None]
 
     def attend_latent(q, row):
         kp = _write_latent(k_pool, rows.blocks, rows.offsets, row[:, 0])
         state.extend((kp, None))
-        return _pa.mla_paged_attention(
-            q[:, 0], kp, rows.tables, rows.context_lens, impl=attn_impl,
-            shared=rows.shared, **model.latent_kw(cfg))[:, None]
+        with jax.named_scope(scopes.ATTN):
+            return _pa.mla_paged_attention(
+                q[:, 0], kp, rows.tables, rows.context_lens, impl=attn_impl,
+                shared=rows.shared, **model.latent_kw(cfg))[:, None]
 
     def before(u):
         moved = jnp.concatenate([v_pool[:, 1:], u], axis=1)
@@ -1151,7 +1173,7 @@ def paged_decode_layer(cfg: TransformerConfig, kind, p, x, k_pool, v_pool,
            "ring": ring_attend}.get(kind[0], attend)
     x, counts = model.layer(cfg, kind, p, x, rows.positions, mix,
                             valid=rows.active[:, None],
-                            moe_name="moe_experts_decode", tap=tap)
+                            moe_name=scopes.MOE_EXPERTS_DECODE, tap=tap)
     return x, state[0], state[1], counts
 
 
@@ -1190,7 +1212,8 @@ def _unrolled_decode_core(params, caches: PagedDecodeCaches, active, cfg,
     x, state, counts = _unrolled_layers(cfg, params, caches, x, rows,
                                         paged_decode_layer, attn_impl)
     logits = model.logits(cfg, params, x[:, 0])
-    next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope(scopes.HEAD):
+        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return caches._replace(
         lengths=jnp.where(active, caches.lengths + 1, caches.lengths),
         last_token=jnp.where(active, next_tok, caches.last_token),

@@ -46,6 +46,7 @@ from ray_tpu.models import afmoe
 from ray_tpu.models.afmoe import (_ffn, _rms, experts,  # noqa: F401
                                   init_head, logits, no_counts)
 from ray_tpu.models.transformer import TransformerConfig, _rope
+from ray_tpu.ops import scopes
 
 MIXERS = ("conv", "full")
 
@@ -168,7 +169,7 @@ def short_conv(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
     """The conv operator on a [B, S, D]: `before(u)` gives u's K - 1
     predecessors at every position (`taps`: the caller knows what lies
     before each of its rows)."""
-    with jax.named_scope("short_conv"):
+    with jax.named_scope(scopes.SHORT_CONV):
         bcz = jnp.einsum("bsd,dcf->bscf", a, p["w_in"].astype(a.dtype))
         u = bcz[:, :, 0] * bcz[:, :, 2]
         w = p["w_conv"].astype(jnp.float32)
@@ -181,7 +182,7 @@ def short_conv(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
 def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
           x: jax.Array, positions: jax.Array, mix: Callable,
           valid: Optional[jax.Array] = None,
-          moe_name: str = "moe_experts_prefill",
+          moe_name: str = scopes.MOE_EXPERTS_PREFILL,
           tap: Optional[Callable] = None
           ) -> Tuple[jax.Array, jax.Array]:
     """x [B, S, D] at `positions` [B, S] -> (x', MOE_COUNTS of this call).
@@ -196,13 +197,15 @@ def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
     if mixer == "conv":
         x = x + short_conv(cfg, p, a, mix)
     else:
-        q = jnp.einsum("bsd,dhk->bshk", a, p["wq"].astype(a.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", a, p["wk"].astype(a.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", a, p["wv"].astype(a.dtype))
-        q = _rope(_rms(q, p["q_norm"], cfg), positions, cfg.rope_theta)
-        k = _rope(_rms(k, p["k_norm"], cfg), positions, cfg.rope_theta)
+        with jax.named_scope(scopes.ATTN_QKV):
+            q = jnp.einsum("bsd,dhk->bshk", a, p["wq"].astype(a.dtype))
+            k = jnp.einsum("bsd,dhk->bshk", a, p["wk"].astype(a.dtype))
+            v = jnp.einsum("bsd,dhk->bshk", a, p["wv"].astype(a.dtype))
+            q = _rope(_rms(q, p["q_norm"], cfg), positions, cfg.rope_theta)
+            k = _rope(_rms(k, p["k_norm"], cfg), positions, cfg.rope_theta)
         o = mix(q, k, v).astype(x.dtype)
-        x = x + jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
+        with jax.named_scope(scopes.ATTN_OUT):
+            x = x + jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
     m = _rms(x, p["ffn_norm"], cfg)
     if ffn == "dense":
         return x + _ffn(m, p["w_gate"], p["w_up"], p["w_down"]), no_counts()
@@ -217,6 +220,7 @@ def window_of(cfg: TransformerConfig, kind: Tuple[str, str]
     return None
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(cfg: TransformerConfig, table: jax.Array,
           tokens: jax.Array) -> jax.Array:
     return table[tokens].astype(cfg.dtype)

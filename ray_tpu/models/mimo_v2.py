@@ -73,6 +73,7 @@ from ray_tpu.models import afmoe
 from ray_tpu.models.afmoe import (_ffn, _rms, experts,  # noqa: F401
                                   init_head, logits, route)
 from ray_tpu.models.transformer import TransformerConfig, _rope
+from ray_tpu.ops import scopes
 
 MIXERS = ("ring", "full")
 # every expert layer's counts: models/afmoe.py's and the rows the grouped
@@ -229,7 +230,8 @@ def attention(cfg: TransformerConfig, mixer: str, p: Dict[str, Any],
     """A layer's attention branch on a [B, S, D]; `mix` is the caller's
     (see the module docstring)."""
     ring = mixer == "ring"
-    with jax.named_scope("ring_attn_qkv" if ring else "full_attn_qkv"):
+    with jax.named_scope(scopes.RING_ATTN_QKV if ring
+                         else scopes.FULL_ATTN_QKV):
         q = jnp.einsum("bsd,dhk->bshk", a, p["wq"].astype(a.dtype))
         k = jnp.einsum("bsd,dhk->bshk", a, p["wk"].astype(a.dtype))
         v = jnp.einsum("bsd,dhk->bshk", a, p["wv"].astype(a.dtype))
@@ -240,18 +242,18 @@ def attention(cfg: TransformerConfig, mixer: str, p: Dict[str, Any],
         q = _partial_rope(cfg, q, positions, theta)
         k = _partial_rope(cfg, k, positions, theta)
     if ring:
-        with jax.named_scope("ring_attn"):
+        with jax.named_scope(scopes.RING_ATTN):
             o = mix(q, k, v, p["sink"].astype(jnp.float32)).astype(a.dtype)
     else:
         o = mix(q, k, v).astype(a.dtype)
-    with jax.named_scope("ring_out" if ring else "full_attn_out"):
+    with jax.named_scope(scopes.RING_OUT if ring else scopes.FULL_ATTN_OUT):
         return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
 
 
 def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
           x: jax.Array, positions: jax.Array, mix,
           valid: Optional[jax.Array] = None,
-          moe_name: str = "moe_experts_prefill",
+          moe_name: str = scopes.MOE_EXPERTS_PREFILL,
           tap: Optional[Callable] = None) -> Tuple[jax.Array, jax.Array]:
     """x [B, S, D] at `positions` [B, S] -> (x', this module's MOE_COUNTS of
     this call).  `mix` is the caller's, built for this layer's mixer (the
@@ -280,6 +282,7 @@ def window_of(cfg: TransformerConfig, kind: Tuple[str, str]
     return None
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(cfg: TransformerConfig, table: jax.Array,
           tokens: jax.Array) -> jax.Array:
     return table[tokens].astype(cfg.dtype)

@@ -51,6 +51,7 @@ from ray_tpu.models.afmoe import (_ffn, _rms, init_head, logits,  # noqa: F401
                                   no_counts)
 from ray_tpu.models.lfm2 import taps  # noqa: F401
 from ray_tpu.models.transformer import TransformerConfig, _rope
+from ray_tpu.ops import scopes
 
 MIXERS = ("linear", "full")
 L2_EPS = 1e-6
@@ -200,7 +201,7 @@ def delta_mixer(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
     B, S, _ = a.shape
     H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
     Hk = key_heads(cfg)
-    with jax.named_scope("delta_proj"):
+    with jax.named_scope(scopes.DELTA_PROJ):
         def proj(w):
             return jnp.einsum("bsd,dhk->bshk", a, w.astype(a.dtype)
                               ).reshape(B, S, -1)
@@ -223,9 +224,9 @@ def delta_mixer(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
         g = jnp.einsum("bsd,dhk->bshk", a, p["wg"].astype(a.dtype))
     if tap is not None:
         tap(q, k, v, log_a, beta)
-    with jax.named_scope("delta_rule"):
+    with jax.named_scope(scopes.DELTA_RULE):
         o = rule(q, k, v, log_a, beta)          # all float32
-    with jax.named_scope("delta_out"):
+    with jax.named_scope(scopes.DELTA_OUT):
         y = _rms(o, p["o_norm"], cfg).astype(jnp.float32) * jax.nn.silu(
             g.astype(jnp.float32))
         return jnp.einsum("bshk,hkd->bsd", y.astype(a.dtype),
@@ -251,16 +252,18 @@ def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
 
     def full(a):
         B, S, _ = a.shape
-        q = jnp.einsum("bsd,dhk->bshk", a, p["wq"].astype(a.dtype))
-        k = jnp.einsum("bsd,dhk->bshk", a, p["wk"].astype(a.dtype))
-        v = jnp.einsum("bsd,dhk->bshk", a, p["wv"].astype(a.dtype))
-        q = _rms(q.reshape(B, S, -1), p["q_norm"], cfg).reshape(q.shape)
-        k = _rms(k.reshape(B, S, -1), p["k_norm"], cfg).reshape(k.shape)
-        if cfg.rope_theta is not None:
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+        with jax.named_scope(scopes.ATTN_QKV):
+            q = jnp.einsum("bsd,dhk->bshk", a, p["wq"].astype(a.dtype))
+            k = jnp.einsum("bsd,dhk->bshk", a, p["wk"].astype(a.dtype))
+            v = jnp.einsum("bsd,dhk->bshk", a, p["wv"].astype(a.dtype))
+            q = _rms(q.reshape(B, S, -1), p["q_norm"], cfg).reshape(q.shape)
+            k = _rms(k.reshape(B, S, -1), p["k_norm"], cfg).reshape(k.shape)
+            if cfg.rope_theta is not None:
+                q = _rope(q, positions, cfg.rope_theta)
+                k = _rope(k, positions, cfg.rope_theta)
         o = mix(q, k, v).astype(a.dtype)
-        return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
+        with jax.named_scope(scopes.ATTN_OUT):
+            return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
 
     if kind[0] == "linear":
         x = branch("attn_norm",
@@ -277,6 +280,7 @@ def window_of(cfg: TransformerConfig, kind: Tuple[str, str]
     return None
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(cfg: TransformerConfig, table: jax.Array,
           tokens: jax.Array) -> jax.Array:
     return table[tokens].astype(cfg.dtype)

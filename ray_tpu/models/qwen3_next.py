@@ -79,6 +79,7 @@ from ray_tpu.models.olmo_hybrid import (conv_width, delta_mixer,  # noqa: F401
                                         key_heads)
 from ray_tpu.models.transformer import (TransformerConfig, _norm, _rope,
                                         _w_out)
+from ray_tpu.ops import scopes
 
 MIXERS = ("linear", "full")
 # every layer's counts: models/afmoe.py's and the rows the grouped product
@@ -238,6 +239,7 @@ def logical_axes(cfg: TransformerConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 # the layer
 # ---------------------------------------------------------------------------
+@jax.named_scope(scopes.NORM)
 def _rms(x, w, cfg):
     if cfg.norm_zero_centered:
         w = 1.0 + w.astype(jnp.float32)
@@ -260,7 +262,7 @@ def gated_attention(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
     """The full layer's branch on a [B, S, D]; `mix(q, k, v)` is the
     caller's attention."""
     dh = cfg.head_dim
-    with jax.named_scope("gated_attn_q"):
+    with jax.named_scope(scopes.GATED_ATTN_Q):
         q = jnp.einsum("bsd,dhk->bshk", a, p["wq"].astype(a.dtype))
         k = jnp.einsum("bsd,dhk->bshk", a, p["wk"].astype(a.dtype))
         v = jnp.einsum("bsd,dhk->bshk", a, p["wv"].astype(a.dtype))
@@ -272,7 +274,7 @@ def gated_attention(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
             q = _partial_rope(cfg, q, positions)
             k = _partial_rope(cfg, k, positions)
     o = mix(q, k, v).astype(a.dtype)
-    with jax.named_scope("gated_attn_out"):
+    with jax.named_scope(scopes.GATED_ATTN_OUT):
         if gate is not None:
             o = (o.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(a.dtype)
@@ -282,7 +284,7 @@ def gated_attention(cfg: TransformerConfig, p: Dict[str, Any], a: jax.Array,
 def layer(cfg: TransformerConfig, kind: Tuple[str, str], p: Dict[str, Any],
           x: jax.Array, positions: jax.Array, mix,
           valid: Optional[jax.Array] = None,
-          moe_name: str = "moe_experts_prefill",
+          moe_name: str = scopes.MOE_EXPERTS_PREFILL,
           tap: Optional[Callable] = None) -> Tuple[jax.Array, jax.Array]:
     """x [B, S, D] at `positions` [B, S] -> (x', this module's MOE_COUNTS of
     this call).  `mix` is the caller's, built for this layer's mixer:
@@ -313,11 +315,13 @@ def window_of(cfg: TransformerConfig, kind: Tuple[str, str]
     return None
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(cfg: TransformerConfig, table: jax.Array,
           tokens: jax.Array) -> jax.Array:
     return table[tokens].astype(cfg.dtype)
 
 
+@jax.named_scope(scopes.HEAD)
 def logits(cfg: TransformerConfig, params: Dict[str, Any],
            x: jax.Array) -> jax.Array:
     """x [..., D] -> float32 logits [..., V]; `params` holds final_norm and

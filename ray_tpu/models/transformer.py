@@ -27,6 +27,7 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import attention_with_lse, uses_flash
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import (_current_mesh, _mesh_trivial,
@@ -513,47 +514,54 @@ def with_product_weights_cast(params: Dict[str, Any],
 def _layer_body(cfg: TransformerConfig, mesh, x, p, positions):
     """One decoder layer. x: [B, S, D]."""
     rms = cfg.arch == "llama"
-    h = _norm(x, p["attn_norm"], p.get("attn_norm_b"), cfg.norm_eps, rms)
-    q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(h.dtype))
-    k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(h.dtype))
-    v = jnp.einsum("bsd,dhk->bshk", h, p["wv"].astype(h.dtype))
-    if cfg.arch == "llama":
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-    q = q.transpose(0, 2, 1, 3)   # [B, H, S, Dh]
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    q = constrain(q, ("batch", "heads", "seq", None), mesh=mesh)
-    k = constrain(k, ("batch", "kv_heads", "seq", None), mesh=mesh)
-    v = constrain(v, ("batch", "kv_heads", "seq", None), mesh=mesh)
+    with jax.named_scope(scopes.NORM):
+        h = _norm(x, p["attn_norm"], p.get("attn_norm_b"), cfg.norm_eps, rms)
+    with jax.named_scope(scopes.ATTN_QKV):
+        q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(h.dtype))
+        k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(h.dtype))
+        v = jnp.einsum("bsd,dhk->bshk", h, p["wv"].astype(h.dtype))
+        if cfg.arch == "llama":
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        q = q.transpose(0, 2, 1, 3)   # [B, H, S, Dh]
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+        q = constrain(q, ("batch", "heads", "seq", None), mesh=mesh)
+        k = constrain(k, ("batch", "kv_heads", "seq", None), mesh=mesh)
+        v = constrain(v, ("batch", "kv_heads", "seq", None), mesh=mesh)
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         o = ring_attention(q, k, v, mesh, axis_name="sp", causal=True)
         o = jax.ad_checkpoint.checkpoint_name(o, "attn_out")
     else:
-        o = _attention(cfg, mesh, q, k, v)
-    o = o.transpose(0, 2, 1, 3)   # [B, S, H, Dh]
-    attn_out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
-    x = x + constrain(attn_out, ("batch", "seq", "embed"), mesh=mesh)
+        with jax.named_scope(scopes.ATTN):
+            o = _attention(cfg, mesh, q, k, v)
+    with jax.named_scope(scopes.ATTN_OUT):
+        o = o.transpose(0, 2, 1, 3)   # [B, S, H, Dh]
+        attn_out = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
+        x = x + constrain(attn_out, ("batch", "seq", "embed"), mesh=mesh)
 
-    h = _norm(x, p["mlp_norm"], p.get("mlp_norm_b"), cfg.norm_eps, rms)
+    with jax.named_scope(scopes.NORM):
+        h = _norm(x, p["mlp_norm"], p.get("mlp_norm_b"), cfg.norm_eps, rms)
     if cfg.moe_experts > 0:
         moe_out, aux = _moe_block(cfg, mesh, h, p)
         x = x + constrain(moe_out, ("batch", "seq", "embed"), mesh=mesh)
         return x, aux
-    if cfg.arch == "llama":
-        gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"].astype(h.dtype))
-        up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(h.dtype))
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
-    else:
-        up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(h.dtype))
-        up = up + p["b_up"].astype(h.dtype)
-        act = jax.nn.gelu(up.astype(jnp.float32)).astype(h.dtype)
-    act = constrain(act, ("batch", "seq", "mlp"), mesh=mesh)
-    down = jnp.einsum("bsf,fd->bsd", act, p["w_down"].astype(act.dtype))
-    if cfg.arch == "gpt2":
-        down = down + p["b_down"].astype(down.dtype)
-    down = jax.ad_checkpoint.checkpoint_name(down, "ffn_out")
-    x = x + constrain(down, ("batch", "seq", "embed"), mesh=mesh)
+    with jax.named_scope(scopes.FFN_GATE_UP):
+        if cfg.arch == "llama":
+            gate = jnp.einsum("bsd,df->bsf", h, p["w_gate"].astype(h.dtype))
+            up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(h.dtype))
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
+        else:
+            up = jnp.einsum("bsd,df->bsf", h, p["w_up"].astype(h.dtype))
+            up = up + p["b_up"].astype(h.dtype)
+            act = jax.nn.gelu(up.astype(jnp.float32)).astype(h.dtype)
+        act = constrain(act, ("batch", "seq", "mlp"), mesh=mesh)
+    with jax.named_scope(scopes.FFN_DOWN):
+        down = jnp.einsum("bsf,fd->bsd", act, p["w_down"].astype(act.dtype))
+        if cfg.arch == "gpt2":
+            down = down + p["b_down"].astype(down.dtype)
+        down = jax.ad_checkpoint.checkpoint_name(down, "ffn_out")
+        x = x + constrain(down, ("batch", "seq", "embed"), mesh=mesh)
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -593,14 +601,15 @@ def forward_hidden_aux(params: Dict[str, Any], tokens: jax.Array,
     # (batch, seq)-sharded indices the gather lands directly in
     # activation layout and the table's shards are all-gathered once —
     # the same all-gather ZeRO-3 pays anyway when a weight is used.
-    tokens = constrain(tokens, ("batch", "seq"), mesh=mesh)
-    emb = constrain(params["tok_embed"], (None, None), mesh=mesh)
-    x = emb[tokens].astype(cfg.dtype)
-    x = constrain(x, ("batch", "seq", "embed"), mesh=mesh)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    if cfg.arch == "gpt2":
-        x = x + params["pos_embed"][:S][None].astype(cfg.dtype)
-    x = constrain(x, ("batch", "seq", "embed"), mesh=mesh)
+    with jax.named_scope(scopes.EMBED):
+        tokens = constrain(tokens, ("batch", "seq"), mesh=mesh)
+        emb = constrain(params["tok_embed"], (None, None), mesh=mesh)
+        x = emb[tokens].astype(cfg.dtype)
+        x = constrain(x, ("batch", "seq", "embed"), mesh=mesh)
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        if cfg.arch == "gpt2":
+            x = x + params["pos_embed"][:S][None].astype(cfg.dtype)
+        x = constrain(x, ("batch", "seq", "embed"), mesh=mesh)
 
     body = functools.partial(_layer_body, cfg, mesh, positions=positions)
     if cfg.remat:
@@ -615,8 +624,9 @@ def forward_hidden_aux(params: Dict[str, Any], tokens: jax.Array,
         scan_fn, (x, jnp.zeros((), jnp.float32)), params["layers"])
 
     rms = cfg.arch == "llama"
-    return _norm(x, params["final_norm"], params.get("final_norm_b"),
-                 cfg.norm_eps, rms), aux
+    with jax.named_scope(scopes.NORM):
+        return _norm(x, params["final_norm"], params.get("final_norm_b"),
+                     cfg.norm_eps, rms), aux
 
 
 def forward_hidden(params: Dict[str, Any], tokens: jax.Array,
@@ -641,6 +651,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array,
     return constrain(logits, ("batch", "seq", "vocab"), mesh=mesh)
 
 
+@jax.named_scope(scopes.XENT)
 def fused_cross_entropy(x: jax.Array, w_out: jax.Array, targets: jax.Array,
                         cfg: TransformerConfig) -> jax.Array:
     """Chunked softmax cross-entropy that never materializes the full

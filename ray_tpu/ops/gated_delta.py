@@ -53,6 +53,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import compiled_on_tpu
 
 _HI = jax.lax.Precision.HIGHEST
@@ -271,7 +272,7 @@ def _step_call(pool, ids, kq, v, a, b, *, g, dv, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret, name="gated_delta_step",
+        interpret=interpret, name=scopes.GATED_DELTA_STEP,
     )(ids, kq, v, a, b, pool)
     return o, pool
 
@@ -457,7 +458,7 @@ def _chunk_call(pool, src, dst, W, Qg, Kd, M, U, decay, *, g, dv,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret, name="gated_delta_chunk",
+        interpret=interpret, name=scopes.GATED_DELTA_CHUNK,
     )(src, dst, W, Qg, Kd, M, U, decay, pool)
     return o, pool
 

@@ -50,6 +50,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import compiled_on_tpu
 
 # Rows a tile may hold: from one packed bf16 sublane tile (a decode step's
@@ -302,7 +303,7 @@ def _ffn_tiles_reference(xs, tile_expert, n_used, w_gate, w_up, w_down, *,
                    static_argnames=("name", "impl", "router_width"))
 def grouped_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
                 valid: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-                w_down: jax.Array, name: str = "moe_experts_prefill",
+                w_down: jax.Array, name: str = scopes.MOE_EXPERTS_PREFILL,
                 impl: str = "auto", router_width: Optional[int] = None
                 ) -> Tuple[jax.Array, jax.Array]:
     """sum_k weights[t, k] * FFN_{idx[t, k]}(x[t]) for every valid token.
@@ -317,7 +318,7 @@ def grouped_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
     tm = tile_rows(T * K, router_width or E)
     w_gate, w_up, w_down = (w.astype(x.dtype)
                             for w in (w_gate, w_up, w_down))
-    with jax.named_scope("moe_route"):
+    with jax.named_scope(scopes.MOE_ROUTE):
         row_token, dest, tile_expert, n_used, sizes = _plan(idx, valid, E,
                                                             tm)
         xs = x[row_token]
@@ -331,7 +332,7 @@ def grouped_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
                                   w_down, tm=tm)
     else:
         raise ValueError(f"unknown grouped_ffn impl {impl!r}")
-    with jax.named_scope("moe_route"):
+    with jax.named_scope(scopes.MOE_ROUTE):
         # [K, T]: the picks are added up over the MAJOR axis, slabs of
         # [T, D]; K rows side by side in the sublanes of a tile are laid out
         # again first where K is not a multiple of 8 (1.2 ms of Qwen3-Next's

@@ -100,6 +100,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import NEG_INF, compiled_on_tpu
 
 # The query-group rows of one kv head are padded up to the f32 sublane
@@ -732,7 +733,7 @@ def _paged_fwd(q, k_pool, v_pool, block_tables, context_lens, *shared, scale,
         jnp.promote_types(q.dtype, k_pool.dtype))
     # The kernels walk the table as far as the lengths say.
     context_lens = jnp.minimum(context_lens.astype(jnp.int32), W * bs)
-    kw = dict(scale=scale, interpret=interpret, name="paged_attention")
+    kw = dict(scale=scale, interpret=interpret, name=scopes.PAGED_ATTENTION)
     if shared:
         o = _attend_shared(qg, (k_pool, v_pool), block_tables, context_lens,
                            SharedRows(*shared), **kw)
@@ -787,7 +788,7 @@ def _narrow_call(qg, k_pool, v_pool, block_tables, context_lens, *, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="paged_attention",
+        name=scopes.PAGED_ATTENTION,
     )(block_tables.astype(jnp.int32), context_lens, qg,
       *([k_pool] * pages + [v_pool] * pages))
 
@@ -1055,7 +1056,7 @@ def _prefix_fwd(q, k_pool, v_pool, block_tables, prefix_lens, suffix_lens,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=48 * 2 ** 20),
         interpret=interpret,
-        name="prefix_attention",
+        name=scopes.PREFIX_ATTENTION,
     )(block_tables.astype(jnp.int32), prefix_lens,
       (hi_all - prefix_lens).astype(jnp.int32), qoff, qg, k_pool, v_pool)
     return o.reshape(N, hkv, P, G, dv).transpose(0, 2, 1, 3, 4).reshape(
@@ -1190,7 +1191,7 @@ def _mla_paged_fwd(q, pool, block_tables, context_lens, *shared, scale,
         :, None]                                            # [B, 1, H, Dp]
     context_lens = jnp.minimum(context_lens.astype(jnp.int32), W * bs)
     kw = dict(scale=scale, v_lanes=v_dim, interpret=interpret,
-              name="mla_paged_attention")
+              name=scopes.MLA_PAGED_ATTENTION)
     if shared:
         o = _attend_shared(qg, (pool,), block_tables, context_lens,
                            SharedRows(*shared), **kw)
@@ -1256,7 +1257,7 @@ def _mla_prefix_fwd(q, pool, block_tables, prefix_lens, suffix_lens, *,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=48 * 2 ** 20),
         interpret=interpret,
-        name="mla_prefix_attention",
+        name=scopes.MLA_PREFIX_ATTENTION,
     )(block_tables.astype(jnp.int32), prefix_lens,
       (hi_all - prefix_lens).astype(jnp.int32), qoff, qg, pool)
     return o.reshape(N, P, H, v_dim)

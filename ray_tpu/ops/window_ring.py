@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.ops import scopes
 from ray_tpu.ops.attention import NEG_INF, compiled_on_tpu
 from ray_tpu.ops.paged_attention import latent_lanes as lanes_of
 from ray_tpu.ops.paged_attention import to_lanes as _to_lanes
@@ -200,7 +201,7 @@ def _step_on_device(rk, rv, ids, positions, q, k, v, sink, *, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret, name="window_ring_step",
+        interpret=interpret, name=scopes.WINDOW_RING_STEP,
     )(ids, positions, qg, new(k, rk), new(v, rv), sk, rk, rv)
     return o[:, :, :G, :dv].reshape(B, H, dv), rk, rv
 
@@ -421,7 +422,7 @@ def _chunk_on_device(rk, rv, src, dst, starts, lives, q, k, v, sink, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret, name="window_ring_chunk",
+        interpret=interpret, name=scopes.WINDOW_RING_CHUNK,
     )(src, dst, starts, lives, qoff, qg, kl, vl, sk, rk, rv)
     o = o.reshape(N, hkv, C, G, lv).transpose(0, 2, 1, 3, 4)
     return o.reshape(N, C, H, lv)[..., :dv], rk, rv

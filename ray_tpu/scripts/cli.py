@@ -17,6 +17,8 @@ status`) + `ray list/summary` (util/state CLI) + `ray job` (job CLI).
                               store usage, plus leak suspects
     stack [task_id] [--flame] cluster-wide worker stack dumps; target
                               one task, or sample into a flamegraph
+    device-time <trace>       a jax.profiler trace's device time by
+                              program and, inside one, by named scope
     metrics                   Prometheus text from the head
     job {submit,status,logs,list,stop}
     microbench                core-runtime perf harness
@@ -377,6 +379,27 @@ def cmd_timeline(args) -> int:
         print(f"wrote {len(events)} events to {args.out}")
     else:
         print(json.dumps(events, indent=1, default=str))
+    return 0
+
+
+def cmd_device_time(args) -> int:
+    """Where a traced window's device time went (`jax.profiler` left an
+    `.xplane.pb` under <dir>/plugins/profile/<time>/): a line a program,
+    under it its scopes (profiling.device_time; --json for the whole
+    result)."""
+    import glob
+
+    from ray_tpu.util import profiling
+    path = args.trace
+    if os.path.isdir(path):
+        found = sorted(glob.glob(os.path.join(
+            path, "**", "*.xplane.pb"), recursive=True))
+        if not found:
+            raise SystemExit(f"no .xplane.pb under {path}")
+        path = found[-1]
+    result = profiling.device_time(path)
+    print(json.dumps(result) if args.json
+          else profiling.format_device_time(result, args.scopes))
     return 0
 
 
@@ -1252,6 +1275,17 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="minimum age before an object can be a leak "
                         "suspect")
     p.set_defaults(fn=cmd_memory)
+
+    p = sub.add_parser(
+        "device-time",
+        help="a profiler trace's device time by program and scope")
+    p.add_argument("trace", help="an .xplane.pb, or a directory that "
+                                 "holds one (the newest is read)")
+    p.add_argument("--scopes", type=int, default=12,
+                   help="rows to print under each program")
+    p.add_argument("--json", action="store_true",
+                   help="print profiling.device_time's whole result")
+    p.set_defaults(fn=cmd_device_time)
 
     p = sub.add_parser("metrics", help="Prometheus metrics dump")
     p.add_argument("--dashboard-url", default=None)

@@ -928,8 +928,8 @@ class PagedBatcher:
         self._owner: List[Optional[_Request]] = [None] * num_slots
         self._disp_len = [0] * num_slots
         self._pending: "queue.Queue[_Request]" = queue.Queue()
-        # In-flight dispatches, oldest first: ((tokens, counts) on the
-        # device, admitted, pairs, seq) with
+        # In-flight dispatches, oldest first: (_launch's (tokens, counts,
+        # label, stamp), admitted, pairs, seq) with
         #   fused:  admitted [(slot, req)] take a first token,
         #           pairs [(slot, req)] the chunk's decode tokens
         #   decode: admitted ()
@@ -964,6 +964,24 @@ class PagedBatcher:
                        "radix_insert": 0.0, "evict": 0.0, "state": 0.0,
                        "dispatches": 0,
                        "device_starved": 0.0, "device_unasked": 0.0}
+        # What each of the engine's programs costs on the device, on this
+        # clock (stats()["program"]): by label ("decode", a fused pass's
+        # positions as `_rung_dispatches` keys them), the dispatches read
+        # back and their device seconds.  A dispatch's seconds run from the
+        # arrival of the dispatch before it, or from its own launch's
+        # return where the device was empty then, to its own arrival
+        # (_process_entry): with two dispatches in flight the device runs
+        # them back to back, so arrival to arrival is the program's time,
+        # and a stretch the device sat empty before a launch is left out
+        # (it is `device_starved` / `device_unasked` above).  It over-reads
+        # by how late the processor thread saw the result (it was handing
+        # out the one before), and the next dispatch under-reads by the
+        # same: sums hold, and a program's mean is off only as far as the
+        # lateness follows the program kind.
+        labels = ["decode"] + list(self._rung_dispatches)
+        self.program = {"device_s": dict.fromkeys(labels, 0.0),
+                        "dispatches": dict.fromkeys(labels, 0)}
+        self._last_got = 0.0        # the newest arrival (processor thread)
         # Shared by the two threads, under _dev_lock for stamps only: when
         # the device went empty (None while a dispatch is in flight), and
         # whether this stretch has had its line.  _where: the dispatcher's
@@ -1131,6 +1149,13 @@ class PagedBatcher:
         h = dict(self.host_s)
         h["work"] = h["permit_wait"] + h["dispatch"]
         return h
+
+    def program_stats(self) -> Dict[str, Dict[str, float]]:
+        """`self.program` (stats()["program"]): by program label, the
+        dispatches read back and their device seconds on the host's
+        clock."""
+        return {name: dict(by_label)
+                for name, by_label in self.program.items()}
 
     def kv_stats(self) -> Dict[str, Any]:
         """Block-pool + prefix-cache occupancy (also what the bench
@@ -1702,7 +1727,7 @@ class PagedBatcher:
             up.put_sets(packed, *shared)
         devs = self._launch(lambda: self._dec.paged_prefill_decode_packed(
             self.params, self.caches, jnp.asarray(packed),
-            self.cfg, chunk, T, attn_impl=self._attn_impl))
+            self.cfg, chunk, T, attn_impl=self._attn_impl), str(N * T))
         # Launched: only now do the requests move on.
         for (_, req), take in zip(batch, takes):
             req._prefilled += take
@@ -1751,13 +1776,14 @@ class PagedBatcher:
             self._decode_reads["context_positions"] += steps * context
             self._decode_reads["streamed_positions"] += steps * streamed
 
-    def _launch(self, call) -> tuple:
+    def _launch(self, call, label: str) -> tuple:
         """`call()`: one of the engine's two programs (decoding.
         paged_prefill_decode_packed, paged_decode_steps), its upload among
         its arguments, handed to the device and its results asked for ->
-        (tokens [decode_chunk, B], an expert model's counts or None), on the
-        device.  When it returns the device has work again: the launch is
-        counted and stamped."""
+        (tokens [decode_chunk, B], an expert model's counts or None, both on
+        the device; `label`, the program's key in `self.program`; the
+        moment the call returned).  When it returns the device has work
+        again: the launch is counted and stamped."""
         with _Phase(self, SPAN_LAUNCH, "launch"):
             self.caches, toks, counts = call()
             for dev in (toks, counts):
@@ -1772,7 +1798,7 @@ class PagedBatcher:
                 self.host_s["device_starved"] += now - self._empty_since
                 self._empty_since = None
             self.host_s["dispatches"] += 1
-        return toks, counts
+        return toks, counts, label, now
 
     def _count_dispatch(self, counts) -> None:
         """A dispatch's expert counts (None: the model has no expert
@@ -1926,7 +1952,7 @@ class PagedBatcher:
                 self._active_dev = jnp.asarray(active)
             entry = (self._launch(lambda: self._dec.paged_decode_steps(
                 self.params, self.caches, self._active_dev, self.cfg, chunk,
-                attn_impl=self._attn_impl)), (), live, seq)
+                attn_impl=self._attn_impl), "decode"), (), live, seq)
             self._count_decode_reads([i for i, _ in live], chunk)
             admitted_slots = set()
             span.set_metadata(kind="decode", live=len(live), positions=0,
@@ -1954,12 +1980,16 @@ class PagedBatcher:
         return True
 
     def _process_entry(self, entry) -> None:
-        (toks, counts), admitted, pairs, seq = entry
+        (toks, counts, label, t_launch), admitted, pairs, seq = entry
         t_read = time.perf_counter()
         with host_span(SPAN_READ_WAIT, seq=seq):
             toks = np.asarray(toks)             # waits for the dispatch
         t_got = time.perf_counter()
         self.host_s["read_wait"] += t_got - t_read
+        self.program["device_s"][label] += t_got - max(t_launch,
+                                                       self._last_got)
+        self.program["dispatches"][label] += 1
+        self._last_got = t_got
         with self._dev_lock:
             # In-order processing: the newest launch read back means
             # nothing is in flight.
@@ -2294,4 +2324,5 @@ class LLMDeployment:
                 "peak_bytes": (dev.memory_stats() or {}).get(
                     "peak_bytes_in_use"),
                 "pid": os.getpid(), "chips": ray_tpu.get_tpu_ids(),
-                "host": b.host_stats(), **b.kv_stats()}
+                "host": b.host_stats(), "program": b.program_stats(),
+                **b.kv_stats()}

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 
 from ray_tpu.models import transformer
+from ray_tpu.ops import scopes
 from ray_tpu.parallel.sharding import (DEFAULT_RULES, Rules, tree_specs,
                                        tree_shardings, use_mesh)
 
@@ -110,11 +111,12 @@ class CompiledTrainStep:
         def step_fn(state: TrainState, tokens) -> Tuple[TrainState, Dict]:
             with use_mesh(mesh):
                 metrics, grads = self.metrics_and_grads(state.params, tokens)
-                updates, new_opt = self.optimizer.update(
-                    grads, state.opt_state, state.params)
-                new_params = optax.apply_updates(state.params, updates)
-                metrics = dict(metrics)
-                metrics["grad_norm"] = optax.global_norm(grads)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    updates, new_opt = self.optimizer.update(
+                        grads, state.opt_state, state.params)
+                    new_params = optax.apply_updates(state.params, updates)
+                    metrics = dict(metrics)
+                    metrics["grad_norm"] = optax.global_norm(grads)
                 return TrainState(state.step + 1, new_params,
                                   new_opt), metrics
 

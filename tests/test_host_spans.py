@@ -326,7 +326,8 @@ def cpu_counters():
 def test_new_metric_file_reads_a_number_in_its_cells(cpu_counters, name):
     entry, = [m for m in spec.load_benchmark()["per_layer"]
               if m["name"] == name]
-    assert entry["workloads"] == NEW_METRICS[name]
+    # (among its cells: a later PR may list more)
+    assert set(NEW_METRICS[name]) <= set(entry["workloads"])
     assert (entry["source"], entry["layer"], entry["better"]) == (
         "program_counter", "Engine host loop", "lower")
     obs = {"counters": cpu_counters, "series": {}, "trace": {}}

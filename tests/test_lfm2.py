@@ -18,6 +18,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import benchmark_names
 from benchmarks.lib import spec
 from ray_tpu.models import afmoe, decoding, lfm2
 from ray_tpu.models import transformer as tfm
@@ -405,13 +406,10 @@ def test_every_cell_resolves_its_names(cell):
     name resolution: each cell loads, reports an end-to-end metric beside
     setup_s and at least one per-layer metric, and the new cell's metrics
     are its own."""
-    loaded = spec.load_cell(cell)
-    assert {m["name"] for m in loaded["end_to_end"]} > {"setup_s"}
-    names = [m["name"] for m in loaded["layer_metrics"]]
-    assert names
-    ours = cell == "serve-lfm2-agent-sessions"
-    assert any(n.startswith("lfm2_") for n in names) == ours
-    # What is not this cell's own is one of the metrics several cells share
-    # (PR 42: the engine's host counters).
-    assert all(n.startswith(("lfm2_", "engine_")) == ours
-               or n.startswith("engine_") for n in names)
+    loaded, kernels = benchmark_names.resolved(cell)
+    if cell == "serve-lfm2-agent-sessions":
+        # its rooflines read the prefill kernel and the expert product
+        assert kernels == {"prefix_attention", "moe_experts_decode"}
+        assert loaded["config"]["kind"] == "lfm2-moe"
+    else:
+        assert loaded["config"]["kind"] != "lfm2-moe"

@@ -14,6 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import benchmark_names
 from benchmarks.lib import spec
 from ray_tpu.models import decoding, mimo_v2
 from ray_tpu.ops import paged_attention as pa
@@ -224,11 +225,12 @@ def test_limits_refuse_a_wrong_program(model, wrong):
 
 # -- the benchmark's names -----------------------------------------------------
 def test_the_cell_resolves_its_names():
-    loaded = spec.load_cell(CELL)
+    loaded, kernels = benchmark_names.resolved(CELL)
     assert {m["name"] for m in loaded["end_to_end"]} == {
         "decode_tokens_per_s", "setup_s"}
-    names = [m["name"] for m in loaded["layer_metrics"]]
-    assert len(names) == 17 and all(n.startswith("mimo_") for n in names)
+    # its rooflines read the ring's two kernels and the expert product
+    assert kernels == {"window_ring_step", "window_ring_chunk",
+                       "moe_experts_decode"}
     assert loaded["cell"]["chips"] == 1
     assert loaded["traffic"]["name"] == "agent-sessions"
     sv = loaded["config"]["serve"]
@@ -249,8 +251,8 @@ def test_the_cell_resolves_its_names():
     assert touched * 50_331_648 < b < touched * 50_331_648 * 1.01
     for other in ("serve-qw3n-agent-sessions", "serve-agent-sessions",
                   "serve-batch-saturated"):
-        assert not any(m["name"].startswith("mimo_")
-                       for m in spec.load_cell(other)["layer_metrics"])
+        assert not benchmark_names.resolved(other)[1] & {
+            "window_ring_step", "window_ring_chunk"}
     bench = spec.load_benchmark()
     assert len(bench["workloads"]) == 11 and len(bench["configs"]) == 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1

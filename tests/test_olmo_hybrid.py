@@ -21,6 +21,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import benchmark_names
 from benchmarks.lib import spec
 from ray_tpu.models import decoding, olmo_hybrid
 from ray_tpu.models import transformer as tfm
@@ -566,12 +567,11 @@ def test_match_is_cut_back_to_the_deepest_checkpoint():
 
 # -- the benchmark's names -----------------------------------------------------
 def test_the_cell_resolves_its_names():
-    loaded = spec.load_cell("serve-olmoh-agent-sessions")
+    loaded, kernels = benchmark_names.resolved("serve-olmoh-agent-sessions")
     assert {m["name"] for m in loaded["end_to_end"]} == {
         "decode_tokens_per_s", "setup_s"}
-    names = [m["name"] for m in loaded["layer_metrics"]]
-    assert len(names) == 17 and all(n.startswith("olmoh_") for n in names
-                                    if n != "engine_decode_streamed_share")
+    # its rooflines read the delta rule's two kernels
+    assert kernels == {"gated_delta_step", "gated_delta_chunk"}
     assert loaded["traffic"]["name"] == "agent-sessions"
     assert loaded["config"]["serve"]["num_states"] == 128
     for fn in ("gated_delta_step", "gated_delta_chunk"):
@@ -584,5 +584,4 @@ def test_the_cell_resolves_its_names():
                                                   {"slots": 32})
     assert 32 * 2 * 2_211_840 < b < 32 * 2 * 2_211_840 * 1.02
     for other in ("serve-lfm2-agent-sessions", "serve-batch-saturated"):
-        assert not any(m["name"].startswith("olmoh_")
-                       for m in spec.load_cell(other)["layer_metrics"])
+        assert not benchmark_names.resolved(other)[1] & kernels

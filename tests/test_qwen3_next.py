@@ -22,6 +22,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import benchmark_names
 from benchmarks.lib import reference, spec
 from ray_tpu.models import afmoe, decoding, qwen3_next
 from ray_tpu.models import transformer as tfm
@@ -540,11 +541,12 @@ def test_carried_decode_rows_in_a_fused_pass_are_a_decode_step(model):
 
 # -- the benchmark's names -----------------------------------------------------
 def test_the_cell_resolves_its_names():
-    loaded = spec.load_cell(CELL)
+    loaded, kernels = benchmark_names.resolved(CELL)
     assert {m["name"] for m in loaded["end_to_end"]} == {
         "decode_tokens_per_s", "setup_s"}
-    names = [m["name"] for m in loaded["layer_metrics"]]
-    assert len(names) == 22 and all(n.startswith("qw3n_") for n in names)
+    # its rooflines read the delta rule's two kernels and the expert product
+    assert kernels == {"gated_delta_step", "gated_delta_chunk",
+                       "moe_experts_decode"}
     assert loaded["cell"]["chips"] == 1
     assert loaded["traffic"]["name"] == "agent-sessions"
     sv = loaded["config"]["serve"]
@@ -561,10 +563,13 @@ def test_the_cell_resolves_its_names():
     touched = 128 * (1 - (511 / 512) ** 320)
     assert 59 < touched < 60
     assert touched * 6_291_456 < b < touched * 6_291_456 * 1.01
-    for other in ("serve-olmoh-agent-sessions", "serve-axk1-agent-sessions",
-                  "serve-batch-saturated"):
-        assert not any(m["name"].startswith("qw3n_")
-                       for m in spec.load_cell(other)["layer_metrics"])
+    # (Olmo-Hybrid's cell reads the same two delta kernels through its own
+    # kind's cost functions; no other cell does)
+    for other in ("serve-axk1-agent-sessions", "serve-batch-saturated"):
+        assert not benchmark_names.resolved(other)[1] & {
+            "gated_delta_step", "gated_delta_chunk"}
+    assert spec.load_cell("serve-olmoh-agent-sessions")["cost_fns"][
+        "gated_delta_step"] is not loaded["cost_fns"]["gated_delta_step"]
     bench = spec.load_benchmark()
     assert len(bench["workloads"]) == 11 and len(bench["configs"]) == 8
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
